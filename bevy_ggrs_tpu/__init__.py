@@ -46,4 +46,4 @@ from bevy_ggrs_tpu.state import (
 #   bevy_ggrs_tpu.ops          — Pallas TPU kernels (checksum, pairwise)
 #   bevy_ggrs_tpu.utils        — metrics, persistence (checkpoint/resume)
 
-__version__ = "0.4.0"
+__version__ = "0.5.0"

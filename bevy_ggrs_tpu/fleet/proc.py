@@ -737,9 +737,36 @@ class _Child:
         self.sock.close()
 
 
+def _require_default_device() -> None:
+    """Initialise this child's backend, and die if the accelerator this
+    installation is built for was not to be had. A chip serves one process
+    at a time; jax's TPU factory fails quietly, so a child that finds the
+    chip held (by its parent, or by a sibling) would otherwise come up on
+    the CPU and serve from there without a word. The error reaches the
+    parent through the child's stderr log (see
+    :meth:`ProcFleet.spawn_server`). A CPU fleet is one asked for by name
+    (``JAX_PLATFORMS=cpu``, as the tests do)."""
+    import importlib.util
+
+    import jax
+
+    platform = jax.devices()[0].platform
+    if (
+        platform == "cpu"
+        and not jax.config.jax_platforms
+        and importlib.util.find_spec("libtpu") is not None
+    ):
+        raise RuntimeError(
+            "fleet child: the TPU runtime is installed but jax came up on "
+            "the CPU — the chip is held by another process (one process "
+            "per chip). Run a CPU fleet by name with JAX_PLATFORMS=cpu."
+        )
+
+
 def _child_main(argv: List[str]) -> int:
     cfg = dict(DEFAULT_CONFIG)
     cfg.update(json.loads(argv[0]))
+    _require_default_device()
     child = _Child(cfg)
     child.run()
     return 0
@@ -992,12 +1019,19 @@ class ProcFleet:
                     raise TimeoutError(f"server {sid} never became ready")
                 if not proc.alive():
                     raise RuntimeError(
-                        f"server {sid} died during startup "
-                        f"(see srv{sid}.stderr.log)"
+                        f"server {sid} died during startup:\n"
+                        + self._stderr_tail(sid)
                     )
                 self.pump()
                 time.sleep(0.02)
         return sid
+
+    def _stderr_tail(self, sid: int, max_bytes: int = 2000) -> str:
+        """The end of a child's stderr log — how a child that died says
+        why (its device was not to be had, an import failed)."""
+        path = os.path.join(self.root_dir, f"srv{sid}.stderr.log")
+        with open(path, "rb") as f:
+            return f.read()[-max_bytes:].decode(errors="replace")
 
     # -- event + heartbeat pump ------------------------------------------
 
@@ -1226,7 +1260,10 @@ class ProcFleet:
             if silent or exited_early:
                 m.alive = False
                 dead.append(sid)
-                self.events.append({"event": "dead", "server": sid})
+                ev = {"event": "dead", "server": sid}
+                if exited_early:
+                    ev["stderr"] = self._stderr_tail(sid)
+                self.events.append(ev)
         return dead
 
     def _parent_codec(self):

@@ -6,8 +6,8 @@ Load-delimited segment into one device call, and round 4's speculative
 runner added a SECOND device call per tick for the next branch rollout —
 plus, on a speculation hit, two branch gathers and a ring absorb (four
 calls on the recovery critical path). On any dispatch-latency-bound host
-(a remote-TPU tunnel's ~4 ms floor, or just a busy CPU host's enqueue
-cost) those extra calls sit directly on the 16.7 ms tick budget
+(a device behind a slow link, or just a busy CPU host's enqueue cost)
+those extra calls sit directly on the 16.7 ms tick budget
 (round-4 verdict weak #2).
 
 The three phases are data-dependent in exactly one direction —

@@ -469,15 +469,16 @@ def make_sharded_flock_system(mesh, entity_axis: str = "entity",
                                             p.shape[0], 0)
 
     def _shard(fn):
-        from bevy_ggrs_tpu.parallel.sharding import shard_map_compat
-
-        return shard_map_compat(
+        # check_vma=False: the per-shard bodies place their own
+        # collectives, which the replication inference would reject.
+        return jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(
                 P(entity_axis, None), P(entity_axis, None), P(entity_axis)
             ),
             out_specs=P(entity_axis, None),
+            check_vma=False,
         )
 
     sharded_force = _shard(per_shard)

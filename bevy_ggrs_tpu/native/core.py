@@ -2,8 +2,8 @@
 
 The session layer constructs its per-player input queues and its
 misprediction tracker through :func:`make_queue_set` / :func:`make_tracker`.
-When the C++ core (``session_core.cpp``) builds, those return thin ctypes
-wrappers whose surface is identical to the pure-Python
+By default those build the C++ core (``session_core.cpp``) and return thin
+ctypes wrappers whose surface is identical to the pure-Python
 :class:`~bevy_ggrs_tpu.session.input_queue.InputQueue` / tracker logic they
 replace — sessions are agnostic. Set ``BEVY_GGRS_TPU_NATIVE=0`` to force the
 Python path (parity tests run both).
@@ -35,12 +35,14 @@ def _invalid_request(msg: str) -> Exception:
     return InvalidRequest(msg)
 
 _lib = None
-_load_failed = False
 
 
 def _load():
-    global _lib, _load_failed
-    if _lib is not None or _load_failed:
+    """The native core, or None when the Python plane was asked for. A
+    build or load failure raises: the Python plane is the parity
+    reference, several times slower, and never a silent substitute."""
+    global _lib
+    if _lib is not None:
         return _lib
     if os.environ.get("BEVY_GGRS_TPU_NATIVE", "1").lower() in ("0", "false"):
         return None
@@ -48,13 +50,9 @@ def _load():
     # (GGRS_NO_NATIVE=1), to keep both paths green.
     if os.environ.get("GGRS_NO_NATIVE", "0").lower() in ("1", "true"):
         return None
-    try:
-        from bevy_ggrs_tpu.native.build import ensure_core_built
+    from bevy_ggrs_tpu.native.build import ensure_core_built
 
-        lib = ctypes.CDLL(ensure_core_built())
-    except Exception:
-        _load_failed = True  # don't re-attempt the compile per constructor
-        return None
+    lib = ctypes.CDLL(ensure_core_built())
     u8p = ctypes.POINTER(ctypes.c_uint8)
     i32p = ctypes.POINTER(ctypes.c_int32)
     lib.ggrs_qs_new.argtypes = [ctypes.c_int, ctypes.c_int, u8p, i32p]

@@ -200,7 +200,7 @@ class AttributionProbe:
 
 def _declared_peak_flops() -> Optional[float]:
     """The device's peak FLOP/s, only if the operator declared it
-    (``GGRS_PEAK_FLOPS``, plain float, e.g. ``1.97e14`` for a v4 chip).
+    (``GGRS_PEAK_FLOPS``, plain float, e.g. ``1.97e14``, the v5e bf16 peak).
     No built-in device table: an undeclared peak yields no ``mfu``
     column rather than a number computed against a guess."""
     import os

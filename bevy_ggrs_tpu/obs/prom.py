@@ -178,7 +178,10 @@ def export_prometheus(
             lines.append(f"{base}_sum {_num(cs['total_ms'])}")
             lines.append(f"{base}_count {_num(cs['count'])}")
             counters = _xla.compile_counters()
-            for key in ("backend_compiles", "cache_tasks", "cache_hits"):
+            for key in (
+                "backend_compiles", "cache_tasks", "cache_hits",
+                "cache_misses",
+            ):
                 name = f"{namespace}_xla_{key}_total"
                 type_line(name, "counter")
                 lines.append(f"{name} {_num(counters.get(key, 0))}")

@@ -21,7 +21,7 @@ Numerics: accumulation order over candidates is identical to the XLA grid
 path's ``jnp.sum`` over a [.., .., M] axis only up to reassociation — like
 the dense kernels, grid-Pallas vs grid-XLA is allclose, not bitwise; each
 impl is bitwise-reproducible with itself per platform+shape. Off-TPU the
-kernel runs in interpret mode (same convention as ``ops.pairwise``).
+kernel runs in interpret mode (``ops.interpret``).
 """
 
 from __future__ import annotations
@@ -31,13 +31,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from bevy_ggrs_tpu.ops.interpret import pallas_interpret
+
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
 def cell_slot_forces_pallas(kernel, rowvals, colvals, *, cell_block: int = 8,
-                            col_chunk: int = 512, interpret=None):
+                            col_chunk: int = 512):
     """Per-cell interaction outputs, tuple of ``out_dim`` [C, K] arrays.
 
     ``rowvals``/``colvals`` map ``kernel.row_names``/``col_names`` to
@@ -60,8 +62,6 @@ def cell_slot_forces_pallas(kernel, rowvals, colvals, *, cell_block: int = 8,
         row_arrays = [jnp.pad(a, ((0, 0), (0, kp - k))) for a in row_arrays]
     if mp != m:
         col_arrays = [jnp.pad(a, ((0, 0), (0, mp - m))) for a in col_arrays]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     n_row, n_col = len(row_arrays), len(col_arrays)
     n_out, n_terms = kernel.out_dim, kernel.n_terms
@@ -118,7 +118,7 @@ def cell_slot_forces_pallas(kernel, rowvals, colvals, *, cell_block: int = 8,
         out_specs=[row_spec] * n_out,
         out_shape=[jax.ShapeDtypeStruct((c, kp), jnp.float32)] * n_out,
         scratch_shapes=[pltpu.VMEM((cb, kp), jnp.float32)] * n_terms,
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(*row_arrays, *col_arrays)
     if n_out == 1:
         outs = (outs,) if not isinstance(outs, (list, tuple)) else outs

@@ -27,6 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bevy_ggrs_tpu import state as state_lib
+from bevy_ggrs_tpu.ops.interpret import pallas_interpret
 from bevy_ggrs_tpu.state import WorldState
 
 # The bitwise contract with state.checksum is enforced by sharing the hash
@@ -73,15 +74,10 @@ def _hash_kernel(words_ref, alive_ref, out_ref, *, n_words: int):
     out_ref[pl.program_id(0), 1] = jnp.sum(h_i32[1], dtype=jnp.int32)
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def _entity_hash_sum(
     words_t: jnp.ndarray,  # uint32[W, capacity]
     alive_u32: jnp.ndarray,  # uint32[1, capacity]
-    interpret: bool = False,
 ) -> jnp.ndarray:
     n_words, cap = words_t.shape
     blk = min(_LANE_BLOCK, max(128, cap))
@@ -103,7 +99,7 @@ def _entity_hash_sum(
             (n_blocks, 2), lambda i: (0, 0), memory_space=pltpu.SMEM
         ),
         out_shape=jax.ShapeDtypeStruct((n_blocks, 2), jnp.int32),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(words_t, alive_u32)
     return jnp.sum(
         jax.lax.bitcast_convert_type(partials, jnp.uint32), axis=0,
@@ -129,7 +125,7 @@ def checksum_pallas(state: WorldState) -> jnp.ndarray:
     """Drop-in, bit-identical replacement for :func:`state.checksum`."""
     words_t = _word_matrix(state)
     alive = state.alive.astype(jnp.uint32)[None, :]
-    total = _entity_hash_sum(words_t, alive, interpret=_use_interpret())
+    total = _entity_hash_sum(words_t, alive)
     return total + state_lib._resources_checksum(state.resources)
 
 

@@ -329,10 +329,12 @@ def build_grid_tables(pos, active, config: GridConfig,
         )
     # Sentinel-padded arrays built by SCATTER into fresh zeros, not
     # concatenate: under GSPMD auto-sharding (entity-sharded jit), gathers
-    # from an operand that inherited the entity sharding are miscompiled
-    # by this jaxlib's SPMD gather partitioner (out-of-shard indices clamp
-    # into local padding and duplicate contributions — measured, not
-    # hypothetical); a scatter-built operand gathers correctly. The
+    # from an operand that inherited the entity sharding were seen
+    # miscompiled by the SPMD gather partitioner of the jaxlib this was
+    # written against (out-of-shard indices clamp into local padding and
+    # duplicate contributions — measured then, not re-measured since); a
+    # scatter-built operand gathers correctly, and the bitwise
+    # serial-vs-sharded tests in tests/test_neighbor.py pin this form. The
     # shard_map path doesn't care (per-shard arrays are local), but the
     # same tables serve plain-jit executables over sharded state.
     iota = jnp.arange(n, dtype=jnp.int32)

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from bevy_ggrs_tpu.ops.interpret import pallas_interpret
+
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
@@ -112,7 +114,6 @@ def _force_kernel(
         "w_cohesion",
         "row_block",
         "col_block",
-        "interpret",
     ),
 )
 def pairwise_force_rows_pallas(
@@ -130,12 +131,9 @@ def pairwise_force_rows_pallas(
     w_cohesion: float,
     row_block: int = 512,
     col_block: int = 1024,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Same contract as :func:`models.boids.pairwise_force_rows` (separation /
     alignment / cohesion force per row boid from all boids), tiled on-chip."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     R, N = row_pos.shape[0], all_pos.shape[0]
     r_blk = min(row_block, _round_up(R, 8))
     c_blk = min(col_block, _round_up(N, 128))
@@ -183,7 +181,7 @@ def pairwise_force_rows_pallas(
             jax.ShapeDtypeStruct((R + r_pad, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((r_blk, 1), jnp.float32)] * 7,
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(*rows, *cols)
     return jnp.concatenate([fx[:R], fy[:R]], axis=1)
 
@@ -406,7 +404,6 @@ def _force_kernel_mxu2(
         "w_cohesion",
         "row_block",
         "col_block",
-        "interpret",
     ),
 )
 def pairwise_force_rows_mxu2(
@@ -424,12 +421,9 @@ def pairwise_force_rows_mxu2(
     w_cohesion: float,
     row_block: int = 512,
     col_block: int = 1024,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Same contract as :func:`pairwise_force_rows_pallas`, reductions on
     the MXU in feature-major orientation (see :func:`_force_kernel_mxu2`)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     R, N = row_pos.shape[0], all_pos.shape[0]
     r_blk = min(row_block, _round_up(R, 8))
     c_blk = min(col_block, _round_up(N, 128))
@@ -489,15 +483,22 @@ def pairwise_force_rows_mxu2(
             pltpu.VMEM((6, r_blk), jnp.float32),
             pltpu.VMEM((r_blk, 2), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(*trows, *cols, feat_t, sep_t)
     return jnp.concatenate([fx[0, :R, None], fy[0, :R, None]], axis=1)
 
 
 
 def _hi_lo(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    hi = x.astype(jnp.bfloat16)
-    return hi, (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    """``x = hi + lo`` in two bf16 halves. The rounding to bf16 precision
+    is a ``reduce_precision``, which XLA must keep: written as a convert
+    round trip (``x.astype(bf16).astype(f32)``) the TPU compiler, allowed
+    excess precision, folds it to ``x`` and ``lo`` comes out all zero —
+    measured on the v5e (chip_smoke.py, PR 21) as force errors of 6e-2 to
+    3e0 through the separation term's cancellation. Same values bit for
+    bit wherever the round trip was honoured (the CPU)."""
+    hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+    return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
 
 
 # ---------------------------------------------------------------------------
@@ -610,7 +611,6 @@ def _force_kernel_tri(
         "w_alignment",
         "w_cohesion",
         "block",
-        "interpret",
     ),
 )
 def pairwise_force_square_mxu_tri(
@@ -624,15 +624,12 @@ def pairwise_force_square_mxu_tri(
     w_alignment: float,
     w_cohesion: float,
     block: int = 1024,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """All-vs-all flocking force with symmetry-halved pair work (see
     :func:`_force_kernel_tri`). Square case only — every entity is both a
     row and a column, which is what makes the triangle reuse valid; the
     sharded row-subset contract keeps using
     :func:`pairwise_force_rows_mxu2`."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     N = pos.shape[0]
     b0 = min(block, _round_up(N, 128))
     pad = _round_up(N, b0) - N
@@ -687,7 +684,7 @@ def pairwise_force_square_mxu_tri(
             pltpu.VMEM((6, NB), jnp.float32),
             pltpu.VMEM((b0, 2), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(*trows, trows[0], trows[1], feat_t, sep_t, feat_t, sep_t)
     return jnp.concatenate([fx[0, :N, None], fy[0, :N, None]], axis=1)
 

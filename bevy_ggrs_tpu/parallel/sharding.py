@@ -30,26 +30,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the jax API churn: newer releases expose it
-    at the top level with ``check_vma``, older ones only under
-    ``jax.experimental.shard_map`` with ``check_rep``. Both flags do the
-    same job here (skip the replication-inference check that rejects our
-    manually-collective per-shard bodies); models call this instead of
-    hardcoding one spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def branch_mesh(
     devices: Optional[Sequence] = None,
     entity_shards: int = 1,

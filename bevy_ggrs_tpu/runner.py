@@ -244,7 +244,7 @@ class RollbackRunner:
                 # exchanges only every CHECKSUM_SEND_INTERVAL-th confirmed
                 # frame — most bursts then complete without any host sync,
                 # which matters when the host-device round trip is the
-                # latency floor (remote-TPU tunnels).
+                # latency floor.
                 wants = getattr(session, "wants_checksum", None)
                 report = [
                     (t, sf) for t, sf in enumerate(save_frames)

@@ -51,8 +51,8 @@ class SessionBuilder:
     def __init__(self, input_spec: InputSpec = InputSpec()):
         # Product default: every session process gets the persistent XLA
         # compilation cache (cold start = disk read instead of recompiling
-        # the fused tick + rollout programs). GGRS_XLA_CACHE=0 opts out;
-        # an explicitly configured cache dir wins. See utils/xla_cache.py.
+        # the fused tick + rollout programs). A cache directory configured
+        # from outside wins. See utils/xla_cache.py.
         from bevy_ggrs_tpu.utils.xla_cache import (
             ensure_persistent_compilation_cache,
         )

@@ -15,17 +15,21 @@ path exercised is identical.
 from __future__ import annotations
 
 import argparse
-import os
+from typing import Optional
 
 
-def force_platform(platform: str) -> None:
-    """Select the JAX platform BEFORE first backend use. ``cpu`` avoids the
-    TPU claim for quick local runs; ``tpu``/default uses the real chip."""
+def force_platform(platform: Optional[str]) -> None:
+    """Select the JAX platform BEFORE first backend use and say which one
+    the example runs on. ``None`` is JAX's own choice (the accelerator
+    where there is one); a named platform that is not there is an error —
+    never a quiet run somewhere else."""
     import jax
 
-    if platform == "cpu":
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        jax.config.update("jax_platforms", "cpu")
+    if platform is not None:
+        jax.config.update("jax_platforms", platform)
+    dev = jax.devices()[0]
+    print(f"[platform] {dev.platform} ({dev.device_kind}) "
+          f"x{len(jax.devices())}")
 
 
 import numpy as np  # noqa: E402
@@ -160,8 +164,11 @@ def add_common_args(parser: argparse.ArgumentParser) -> None:
     parser.add_argument("--frames", type=int, default=300,
                         help="render frames to run (headless bound)")
     parser.add_argument("--fps", type=int, default=60)
-    parser.add_argument("--platform", choices=["cpu", "tpu"], default="cpu",
-                        help="JAX platform (cpu avoids the TPU claim)")
+    parser.add_argument("--platform", choices=["cpu", "tpu"], default=None,
+                        help="JAX platform to pin (default: JAX's own "
+                             "choice); fails if it is not available. A "
+                             "chip serves one process: peers sharing a "
+                             "machine pass --platform cpu")
     parser.add_argument("--profile", metavar="DIR", default=None,
                         help="capture a JAX/XLA profiler trace of the run "
                              "into DIR (view with TensorBoard)")

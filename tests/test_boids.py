@@ -84,6 +84,8 @@ class TestEntitySharding:
         """branch x entity mesh: numerics match the unsharded run to float
         tolerance (cross-device reduction order may differ, so this is
         allclose, not bitwise — bitwise holds within a fixed topology)."""
+        if len(jax.devices()) < 2:
+            pytest.skip("needs a multi-device mesh")
         mesh = branch_mesh(entity_shards=2)  # 4 x 2 over 8 virtual devices
         n_branch = 8
         frames = 3
@@ -117,6 +119,8 @@ class TestEntitySharding:
     def test_2d_mesh_reproducible_within_topology(self):
         """Same mesh, same inputs → bitwise-identical checksums (the
         determinism contract peers must share a topology for)."""
+        if len(jax.devices()) < 2:
+            pytest.skip("needs a multi-device mesh")
         mesh = branch_mesh(entity_shards=2)
         state = make_state(32)
         bits = jnp.asarray(
