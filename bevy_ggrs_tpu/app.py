@@ -29,6 +29,11 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from bevy_ggrs_tpu.obs.trace import (
+    Instrumented,
+    attach_process_events,
+    detach_process_events,
+)
 from bevy_ggrs_tpu.runner import RollbackRunner
 from bevy_ggrs_tpu.schedule import InputSpec, Schedule
 from bevy_ggrs_tpu.session.common import (
@@ -144,9 +149,12 @@ class RollbackApp:
                 self.update(now)
 
 
-class GGRSStage:
+class GGRSStage(Instrumented):
     """Fixed-timestep driver executing the session request protocol on the
-    device-resident runner."""
+    device-resident runner. With a real ``metrics`` sink each
+    :meth:`run` is the span ``stage_update`` and the session layer is
+    timed at its boundary here (``poll``, ``session_advance``); collector
+    pauses and compiles are recorded to the sink until :meth:`close`."""
 
     def __init__(
         self,
@@ -165,9 +173,7 @@ class GGRSStage:
         entity_axis: str = "entity",
         branch_axis: str = "branch",
     ):
-        from bevy_ggrs_tpu.utils.metrics import null_metrics
-
-        self.metrics = metrics if metrics is not None else null_metrics
+        self._set_sinks(metrics)
         self.input_system = input_system
         self.update_frequency = int(update_frequency)
         if speculation:
@@ -208,6 +214,11 @@ class GGRSStage:
         # Observability counters (survey §5 "add: per-phase timing" seed).
         self.steps_total = 0
         self.frames_skipped = 0
+        attach_process_events(self)
+
+    def close(self) -> None:
+        """Stop receiving ``gc_pause`` / ``compile`` events."""
+        detach_process_events(self)
 
     def reset(self) -> None:
         """Driver state clear when the session resource disappears
@@ -219,6 +230,10 @@ class GGRSStage:
     # ------------------------------------------------------------------
 
     def run(self, app: RollbackApp, now: Optional[float] = None) -> int:
+        with self.span("stage_update"):
+            return self._run(app, now)
+
+    def _run(self, app: RollbackApp, now: Optional[float]) -> int:
         now = self._clock() if now is None else now
         if app.session is None:
             self.reset()
@@ -242,7 +257,7 @@ class GGRSStage:
             flush = getattr(self.runner, "flush_reports", None)
             if flush is not None:
                 flush(app.session)
-            with self.metrics.timer("poll"):
+            with self.span("poll"):
                 app.session.poll_remote_clients(now)
             app.events.extend(app.session.events())
 
@@ -273,10 +288,13 @@ class GGRSStage:
         if session.current_state() != SessionState.RUNNING:
             return
         self.run_slow = session.frames_ahead() > 0
-        for handle in session.local_player_handles():
-            session.add_local_input(handle, self.input_system(handle, app))
         try:
-            requests = session.advance_frame()
+            with self.span("session_advance", frame=session.current_frame):
+                for handle in session.local_player_handles():
+                    session.add_local_input(
+                        handle, self.input_system(handle, app)
+                    )
+                requests = session.advance_frame()
         except PredictionThreshold:
             self.frames_skipped += 1  # `ggrs_stage.rs:251-253`: skip + log
             return

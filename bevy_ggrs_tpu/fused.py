@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bevy_ggrs_tpu.obs.trace import null_span
 from bevy_ggrs_tpu.rollout import rollout_burst
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.state import SnapshotRing, WorldState, ring_load
@@ -187,7 +188,13 @@ class FusedTickExecutor:
         entity_axis: Optional[str] = None,
         state_template: Optional[WorldState] = None,
         session_axis: int = 0,
+        span=None,
     ):
+        # The owning runner's one instrument (its bound ``span``): each
+        # dispatch splits into ``tick_stage_args`` (host arrays, cached
+        # scalars, host-to-device puts) and ``tick_enqueue`` (the call of
+        # the jitted program alone).
+        self.span = span if span is not None else null_span
         self.schedule = schedule
         self.burst_frames = int(burst_frames)
         self.num_branches = int(num_branches)
@@ -385,14 +392,16 @@ class FusedTickExecutor:
     ):
         """Dispatch the absorb-only program (full-hit fast path). Returns
         ``(ring, state, checksums[burst_frames])``."""
-        return self._absorb(
-            ring, prev_rings, prev_states,
-            self._i32(branch),
-            self._i32(first_frame),
-            self._i32(n_frames),
-            self._i32(prev_anchor),
-            self._i32(prev_total),
-        )
+        with self.span("tick_stage_args"):
+            args = (
+                self._i32(branch),
+                self._i32(first_frame),
+                self._i32(n_frames),
+                self._i32(prev_anchor),
+                self._i32(prev_total),
+            )
+        with self.span("tick_enqueue", program="absorb"):
+            return self._absorb(ring, prev_rings, prev_states, *args)
 
     def run(
         self,
@@ -423,6 +432,22 @@ class FusedTickExecutor:
         Returns ``(ring, state, absorb_cs, burst_cs, spec_rings,
         spec_states, spec_cs)`` — all device-resident, nothing synced.
         """
+        with self.span("tick_stage_args"):
+            args = self._stage_args(
+                branch, absorb_first, absorb_n, prev_anchor, prev_total,
+                load_frame, start_frame, bits, status, n_burst,
+                spec_anchor, spec_from_live, branch_bits,
+            )
+        with self.span("tick_enqueue", program="fused"):
+            return self._fn(ring, state, prev_rings, prev_states, *args)
+
+    def _stage_args(
+        self, branch, absorb_first, absorb_n, prev_anchor, prev_total,
+        load_frame, start_frame, bits, status, n_burst,
+        spec_anchor, spec_from_live, branch_bits,
+    ) -> tuple:
+        """Every argument of the fused program after the four device
+        pytrees: padded host tensors, memoized device scalars and masks."""
         if n_burst > self.burst_frames:
             raise ValueError(
                 f"burst of {n_burst} frames exceeds {self.burst_frames}"
@@ -474,9 +499,8 @@ class FusedTickExecutor:
                 (self.spec_frames, P), PREDICTED, dtype=jnp.int32
             )
         do_load = load_frame is not None
-        return self._fn(
-            ring, state,
-            prev_rings, prev_states, self._i32(branch),
+        return (
+            self._i32(branch),
             self._i32(absorb_first),
             self._i32(absorb_n),
             self._i32(prev_anchor),

@@ -1,42 +1,58 @@
-"""Span tracer: nested wall-clock phase spans over the session stage loop.
+"""The one span instrument, and the host-side ring it can record into.
 
 The reference ships only `log`-crate warnings (survey §5: "no spans, no
-profiler hooks"); `utils.metrics` added counters and flat phase timers.
-This tracer adds the missing *timeline*: ``with trace.span("net_poll")``
-style nesting recorded as begin/end events on a monotonic microsecond
-clock, exported as
+profiler hooks"). Here every layer boundary opens ONE context manager,
+``with self.span("tick_dispatch", frame=f):`` (:class:`Instrumented`),
+which reads the clock once at entry and once at exit and feeds three sinks:
 
-- Chrome-trace / Perfetto JSON (:meth:`SpanTracer.export_perfetto` — load
-  the file in https://ui.perfetto.dev or ``chrome://tracing``),
-- a JSONL event stream (:meth:`SpanTracer.export_jsonl`),
-- a per-span-name aggregate (:meth:`SpanTracer.summary`, the per-phase
-  attribution BENCH rounds embed).
+1. the ``utils.metrics.Metrics`` series ``<name>_ms`` (Prometheus, the
+   benchmark's ``metrics_series`` reader);
+2. the :class:`SpanTracer` ring, if the object was given a tracer: nested
+   begin/end events exported as Chrome-trace / Perfetto JSON
+   (:meth:`SpanTracer.export_perfetto`), a JSONL stream
+   (:meth:`SpanTracer.export_jsonl`) and a per-name aggregate
+   (:meth:`SpanTracer.summary`); the span also keeps the per-thread stack
+   of open span names the sampling profiler reads (``obs/profiler.py``);
+3. a ``jax.profiler.TraceAnnotation("ggrs/<name>", **args)``: whenever a
+   profiler session is on, the span is in the xplane's host plane on the
+   same clock as ``XLA Modules`` / ``XLA Ops``, so an idle gap of the
+   device can be put down to what the program was doing
+   (``tools/trace_spans.py``).
 
 Design notes:
 
-- Events are appended in runtime order, so begin/end matching and nesting
-  are correct *by construction*; export never has to re-derive a stack
-  from timestamps. The export pass only repairs the two edge cases a
-  bounded ring introduces (orphan ends whose begin was evicted, and spans
-  still open at export time, which are auto-closed at the final
-  timestamp).
-- The disabled path is the null-object pattern `utils.metrics` uses:
-  :data:`null_tracer` hands out one shared no-op span, so an instrumented
-  hot loop pays one attribute lookup + context enter/exit per span —
-  guarded under 2 % of a 500-frame loopback session by
-  ``tests/test_obs.py``.
-- Host-side only. For kernel-level profiles wrap the run with
-  ``jax.profiler.trace(logdir)``; both timelines compose (the XLA trace
-  carries device lanes, this one carries the session phases).
+- A span's parent is the span open on the thread when it starts: spans are
+  context managers, so nesting is correct *by construction* in all three
+  sinks. Ring export only repairs the two edge cases a bounded ring
+  introduces (orphan ends whose begin was evicted, and spans still open at
+  export time, which are auto-closed at the final timestamp).
+- Off means both sinks null: :meth:`Instrumented.span` hands out the one
+  shared :data:`NULL_SPAN` and constructs nothing, so an instrumented hot
+  loop pays one method call + context enter/exit per span — guarded under
+  2 % of a 500-frame loopback session by ``tests/test_obs.py``.
+- Two program events come from outside any ``with``: collector pauses
+  (``gc_pause``, a ``gc.callbacks`` hook) and executables obtained
+  (``compile``, fed by ``utils.xla_cache``'s listener). Both go to every
+  live object that called :func:`attach_process_events`.
 """
 
 from __future__ import annotations
 
 import collections
+import gc
 import json
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
+
+from bevy_ggrs_tpu.utils.metrics import null_metrics
+
+# Every program span's name on the profiler's host plane starts with this
+# (the benchmark's own spans start with ``bench/``).
+TRACE_PREFIX = "ggrs/"
 
 # Event tuples: ("B", name, ts_us, args) / ("E", name, ts_us, None)
 #             / ("I", name, ts_us, args)   (instant)
@@ -108,41 +124,58 @@ def open_span_stack(thread_ident: int) -> Tuple[str, ...]:
 
 
 class _Span:
-    __slots__ = ("_tr", "_name", "_args", "_t0", "_tok")
+    """One open span: Metrics series + tracer ring + trace annotation.
+    ``ms`` is the duration once closed, for a site that derives a sum or
+    a self time from its spans."""
 
-    def __init__(self, tracer: "SpanTracer", name: str, args):
+    __slots__ = (
+        "_metrics", "_tr", "_name", "_args", "_series", "_t0", "_b_us",
+        "_tok", "_ann", "ms",
+    )
+
+    def __init__(self, metrics, tracer, name: str, args, series: bool = True):
+        self._metrics = metrics
         self._tr = tracer
         self._name = name
         self._args = args
-        self._t0 = 0
-        self._tok = None
+        self._series = series
+        self.ms = 0.0
 
     def __enter__(self):
-        tr = self._tr
-        self._t0 = tr._now_us()
-        tr._events.append(("B", self._name, self._t0, self._args))
-        tr._depth += 1
-        self._tok = push_span(self._name)
+        name, args = self._name, self._args
+        self._ann = ann = TraceAnnotation(TRACE_PREFIX + name, **args)
+        ann.__enter__()
+        self._tok = push_span(name)
+        self._t0 = t0 = time.perf_counter()
+        if self._tr is not null_tracer:
+            self._b_us = self._tr._begin(name, t0, args or None)
         return self
 
     def __exit__(self, *exc):
-        tr = self._tr
-        end = tr._now_us()
-        tr._events.append(("E", self._name, end, None))
-        tr._depth -= 1
-        if self._tok is not None:
-            pop_span(self._tok)
-            self._tok = None
-        dur = (end - self._t0) / 1000.0
-        agg = tr._agg.get(self._name)
-        if agg is None:
-            tr._agg[self._name] = [1, dur, dur]
-        else:
-            agg[0] += 1
-            agg[1] += dur
-            if dur > agg[2]:
-                agg[2] = dur
+        t1 = time.perf_counter()
+        self.ms = ms = (t1 - self._t0) * 1000.0
+        if self._series:
+            self._metrics.observe(self._name + "_ms", ms)
+        if self._tr is not null_tracer:
+            self._tr._end(self._name, t1, self._b_us)
+        pop_span(self._tok)
+        self._ann.__exit__(*exc)
         return False
+
+
+class _NullSpan:
+    __slots__ = ()
+
+    ms = 0.0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+NULL_SPAN = _NullSpan()
 
 
 # Span-name prefix -> (track offset, track name). Export assigns each
@@ -200,10 +233,40 @@ class SpanTracer:
     def _now_us(self) -> int:
         return int((self._clock() - self._origin) * 1e6)
 
+    def _ts_us(self, t_perf: float) -> int:
+        """Ring timestamp of a ``time.perf_counter()`` reading a span
+        already took: the reading itself on the default clock, the
+        tracer's own clock (a test's virtual network time) otherwise."""
+        if self._clock is time.perf_counter:
+            return int((t_perf - self._origin) * 1e6)
+        return self._now_us()
+
+    def _begin(self, name: str, t_perf: float, args) -> int:
+        ts = self._ts_us(t_perf)
+        self._events.append(("B", name, ts, args))
+        self._depth += 1
+        return ts
+
+    def _end(self, name: str, t_perf: float, begin_us: int) -> None:
+        end = self._ts_us(t_perf)
+        self._events.append(("E", name, end, None))
+        self._depth -= 1
+        dur = (end - begin_us) / 1000.0
+        agg = self._agg.get(name)
+        if agg is None:
+            self._agg[name] = [1, dur, dur]
+        else:
+            agg[0] += 1
+            agg[1] += dur
+            if dur > agg[2]:
+                agg[2] = dur
+
     # -- instruments ----------------------------------------------------
 
     def span(self, name: str, **args) -> _Span:
-        return _Span(self, name, args or None)
+        """A span on this ring and on the trace clock, with no series:
+        what an object that was given a tracer and no ``Metrics`` opens."""
+        return _Span(null_metrics, self, name, args)
 
     def instant(self, name: str, **args) -> None:
         self._events.append(("I", name, self._now_us(), args or None))
@@ -232,7 +295,9 @@ class SpanTracer:
         out = []
         stack: List[str] = []
         last_ts = 0
-        for ph, name, ts, args in self._events:
+        # A snapshot: a collection during the walk appends its
+        # ``gc_pause`` to the ring of an attached owner.
+        for ph, name, ts, args in list(self._events):
             if ts < last_ts:
                 ts = last_ts
             last_ts = ts
@@ -322,16 +387,6 @@ class SpanTracer:
         return n
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
 class _NullTracer:
     """Shared no-op tracer: every instrument is O(1) and allocation-free
     (mirrors ``utils.metrics.null_metrics``)."""
@@ -339,10 +394,9 @@ class _NullTracer:
     __slots__ = ()
 
     enabled = False
-    _span = _NullSpan()
 
     def span(self, name: str, **args) -> _NullSpan:
-        return self._span
+        return NULL_SPAN
 
     def instant(self, name: str, **args) -> None:
         pass
@@ -358,3 +412,114 @@ class _NullTracer:
 
 
 null_tracer = _NullTracer()
+
+
+class Instrumented:
+    """Mixin of every instrumented object: its two sinks (``metrics=`` and
+    ``tracer=`` arguments, either may stay null) and the one
+    :meth:`span` over them. The sinks are read at each call, so assigning
+    ``obj.tracer = t`` after construction takes effect; an object that
+    lends its instrument to a part of itself (the runner to its
+    executors) passes its bound ``span``."""
+
+    metrics = null_metrics
+    tracer = null_tracer
+    # An object with a third listener for its spans' durations (the
+    # serving core's rolling ``timeseries``) keeps them on for it.
+    _span_always = False
+
+    def _set_sinks(self, metrics=None, tracer=None) -> None:
+        self.metrics = metrics if metrics is not None else null_metrics
+        self.tracer = tracer if tracer is not None else null_tracer
+
+    def span(self, name: str, *, series: bool = True, **args):
+        """``with self.span("tick_dispatch", frame=f):`` — series
+        ``<name>_ms``, tracer ring, ``ggrs/<name>`` on the trace clock.
+        ``series=False`` for a span whose duration (``.ms`` once closed)
+        the site folds into a series of another key: a sum or a self
+        time."""
+        if (
+            self.metrics is null_metrics
+            and self.tracer is null_tracer
+            and not self._span_always
+        ):
+            return NULL_SPAN
+        return _Span(self.metrics, self.tracer, name, args, series)
+
+    def timed_span(self, name: str, **args):
+        """A span that measures with the sinks off too, for a site whose
+        duration feeds a counter the object keeps regardless."""
+        return _Span(self.metrics, self.tracer, name, args, False)
+
+
+def null_span(name: str, **args) -> _NullSpan:
+    """The ``span`` of a part nobody lent an instrument to."""
+    return NULL_SPAN
+
+
+# -- program events from outside any ``with`` ---------------------------
+#
+# A collection of the interpreter's garbage collector and an executable
+# obtained by jax are not bracketed by program code; each is recorded, as
+# a span that already ended, to the sinks of every live object that
+# attached itself (a GGRSStage or MatchServer with a real sink). Objects
+# are held weakly: one that is dropped without close() stops receiving
+# (the hook then idles until the next attach or detach).
+
+_PROCESS_SINKS: "weakref.WeakSet[Instrumented]" = weakref.WeakSet()
+_gc_open: Optional[Tuple[float, TraceAnnotation]] = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """Every collection, of any generation, as a span ``gc_pause``."""
+    global _gc_open
+    if phase == "start":
+        if _PROCESS_SINKS:
+            ann = TraceAnnotation(
+                TRACE_PREFIX + "gc_pause", generation=info["generation"]
+            )
+            ann.__enter__()
+            _gc_open = (time.perf_counter(), ann)
+    elif _gc_open is not None:
+        t1 = time.perf_counter()
+        (t0, ann), _gc_open = _gc_open, None
+        ann.__exit__(None, None, None)
+        args = {"generation": info["generation"]}
+        for obj in tuple(_PROCESS_SINKS):
+            obj.metrics.observe("gc_pause_ms", (t1 - t0) * 1000.0)
+            tr = obj.tracer
+            if tr is not null_tracer:
+                tr._end("gc_pause", t1, tr._begin("gc_pause", t0, args))
+
+
+def attach_process_events(obj: Instrumented) -> bool:
+    """Send ``gc_pause`` and ``compile`` events to ``obj``'s sinks from
+    now until :func:`detach_process_events` (or until it is dropped).
+    Nothing is attached, and False returned, while both sinks are null.
+    At most one ``gc.callbacks`` hook exists per process."""
+    if obj.metrics is null_metrics and obj.tracer is null_tracer:
+        return False
+    _PROCESS_SINKS.add(obj)
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    return True
+
+
+def detach_process_events(obj: Instrumented) -> None:
+    _PROCESS_SINKS.discard(obj)
+    if not _PROCESS_SINKS and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+def record_compile(program: str, ms: float, cache: Optional[str]) -> None:
+    """One executable obtained (``utils.xla_cache``'s listener calls this
+    when jax reports the duration): series ``compile_ms``, the tracer
+    ring, and an instant ``ggrs/compile`` on the trace clock."""
+    if not _PROCESS_SINKS:
+        return
+    args = {"program": program, "cache": cache or "", "ms": round(ms, 3)}
+    with TraceAnnotation(TRACE_PREFIX + "compile", **args):
+        pass
+    for obj in tuple(_PROCESS_SINKS):
+        obj.metrics.observe("compile_ms", ms)
+        obj.tracer.instant("compile", **args)

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bevy_ggrs_tpu.obs.trace import Instrumented
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.state import SnapshotRing, WorldState
 from bevy_ggrs_tpu.rollout import rollout_burst
@@ -166,7 +167,7 @@ class SpecResult:
     num_frames: int
 
 
-class SpeculativeExecutor:
+class SpeculativeExecutor(Instrumented):
     """Jit-compiled B-branch × F-frame rollout bound to one schedule + shapes.
 
     With a mesh, the branch axis is laid out over the mesh's ``branch`` axis
@@ -193,15 +194,13 @@ class SpeculativeExecutor:
         (boids all-pairs forces): annotate, and GSPMD inserts the
         gathers/reductions over ICI.
         """
-        from bevy_ggrs_tpu.obs.trace import null_tracer
-
         self.schedule = schedule
         self.num_branches = int(num_branches)
         self.max_frames = int(max_frames)
         self.mesh = mesh
         self.branch_axis = branch_axis
         self.entity_axis = entity_axis
-        self.tracer = tracer if tracer is not None else null_tracer
+        self._set_sinks(tracer=tracer)
 
         run = functools.partial(self._run_impl, schedule, self.max_frames)
         commit = self._commit_impl
@@ -305,7 +304,7 @@ class SpeculativeExecutor:
         num_players = branch_bits.shape[2]
         if status is None:
             status = jnp.full((f, num_players), PREDICTED, dtype=jnp.int32)
-        with self.tracer.span("spec_branch_dispatch", branches=b, frames=f):
+        with self.span("spec_branch_dispatch", branches=b, frames=f):
             rings, states, checksums = self._run(
                 state, jnp.asarray(start_frame, jnp.int32), branch_bits,
                 jnp.asarray(status, jnp.int32),
@@ -323,7 +322,7 @@ class SpeculativeExecutor:
         """Gather branch ``branch``'s (ring, state) — the confirmed-branch
         select + scatter-back (survey §2.3). One collective gather when the
         branch axis is sharded."""
-        with self.tracer.span("spec_branch_commit"):
+        with self.span("spec_branch_commit"):
             branch = jnp.asarray(branch, jnp.int32)
             ring = self._commit(result.rings, branch)
             state = self._commit(result.states, branch)

@@ -29,6 +29,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from bevy_ggrs_tpu.obs.trace import Instrumented
 from bevy_ggrs_tpu.rollout import RolloutExecutor
 from bevy_ggrs_tpu.schedule import Schedule
 from bevy_ggrs_tpu.session.requests import (
@@ -46,7 +47,7 @@ class _Step:
     adv: Optional[AdvanceFrame] = None
 
 
-class RollbackRunner:
+class RollbackRunner(Instrumented):
     def __init__(
         self,
         schedule: Schedule,
@@ -62,11 +63,8 @@ class RollbackRunner:
         ledger=None,
     ):
         from bevy_ggrs_tpu.obs.ledger import null_ledger
-        from bevy_ggrs_tpu.obs.trace import null_tracer
-        from bevy_ggrs_tpu.utils.metrics import null_metrics
 
-        self.metrics = metrics if metrics is not None else null_metrics
-        self.tracer = tracer if tracer is not None else null_tracer
+        self._set_sinks(metrics, tracer)
         self.ledger = ledger if ledger is not None else null_ledger
         # One-shot outcome handoff from the speculative matcher: when a
         # match was attempted and missed, _try_commit stashes the causal
@@ -127,7 +125,7 @@ class RollbackRunner:
         (supervisor recovery) splits the list: everything before it executes
         first, then the restore replaces state/ring/frame, then execution
         resumes from the adopted frame."""
-        with self.tracer.span("handle_requests"):
+        with self.span("handle_requests"):
             self._handle_requests(requests, session)
 
     def _handle_requests(self, requests: Sequence[object], session=None) -> None:
@@ -224,9 +222,7 @@ class RollbackRunner:
             save_mask = np.array([s.save_frame is not None for s in steps])
             adv_mask = np.array([s.adv is not None for s in steps])
             self.device_dispatches_total += 1
-            with self.metrics.timer("dispatch"), self.tracer.span(
-                "dispatch", frames=n
-            ):
+            with self.span("dispatch", frames=n):
                 self.ring, self.state, checksums = self.executor.run(
                     self.ring,
                     self.state,
@@ -251,9 +247,7 @@ class RollbackRunner:
                     if sf is not None and (wants is None or wants(sf))
                 ]
                 if report:
-                    with self.metrics.timer("checksum_sync"), self.tracer.span(
-                        "checksum_sync"
-                    ):
+                    with self.span("checksum_sync"):
                         cs_host = np.asarray(checksums)  # [T, 2] lo/hi lanes
                     for t, sf in report:
                         session.report_checksum(sf, combine64(cs_host[t]))
@@ -342,9 +336,7 @@ class RollbackRunner:
         before = integrity.host_row(self.ring, corrupt[0] % self.ring.depth)
         pre_live = np.asarray(integrity._state_digest(self.state))
         n = len(used)
-        with self.metrics.timer("sdc_repair"), self.tracer.span(
-            "sdc_repair", frames=n
-        ):
+        with self.span("sdc_repair", frames=n):
             pos = base
             while pos < self.frame:
                 take = min(self.frame - pos, self.max_prediction + 2)

@@ -55,7 +55,6 @@ from __future__ import annotations
 import contextlib
 import functools
 import os
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -65,7 +64,7 @@ import numpy as np
 from bevy_ggrs_tpu.fused import FusedTickExecutor, _i32_cached
 from bevy_ggrs_tpu.native import spec as native_spec
 from bevy_ggrs_tpu.obs.ledger import blame_divergence
-from bevy_ggrs_tpu.obs.trace import pop_span, push_span
+from bevy_ggrs_tpu.obs.trace import NULL_SPAN, Instrumented
 from bevy_ggrs_tpu.parallel.speculate import match_branch
 from bevy_ggrs_tpu.predict.batch import BatchedRanker
 from bevy_ggrs_tpu.predict.model import resolve_predictor
@@ -230,7 +229,7 @@ class _Slot:
         self.res_from_live = True
 
 
-class BatchedSessionCore:
+class BatchedSessionCore(Instrumented):
     """S fixed-capacity match slots over stacked device state, advanced by
     one :class:`BatchedTickExecutor` dispatch per tick round.
 
@@ -273,11 +272,8 @@ class BatchedSessionCore:
     ):
         from bevy_ggrs_tpu.obs.ledger import null_ledger
         from bevy_ggrs_tpu.obs.timeseries import null_timeseries
-        from bevy_ggrs_tpu.obs.trace import null_tracer
-        from bevy_ggrs_tpu.utils.metrics import null_metrics
 
-        self.metrics = metrics if metrics is not None else null_metrics
-        self.tracer = tracer if tracer is not None else null_tracer
+        self._set_sinks(metrics, tracer)
         self.timeseries = (
             timeseries if timeseries is not None else null_timeseries
         )
@@ -285,12 +281,11 @@ class BatchedSessionCore:
         # passes a scoped view so entries carry fleet-unique flat slot
         # ids; entries here label the local match_slot.
         self.ledger = ledger if ledger is not None else null_ledger
-        # Host-work decomposition arms only when someone is listening —
-        # the clock reads would otherwise tax the per-slot loop for
-        # nothing (the telemetry-off determinism guard stays exact).
-        self._measure_host = (
-            self.metrics is not null_metrics or self.timeseries.enabled
-        )
+        # The host-work spans arm only when someone is listening (a sink,
+        # or the rolling timeseries): the clock reads would otherwise tax
+        # the loop for nothing (the telemetry-off determinism guard stays
+        # exact).
+        self._span_always = bool(self.timeseries.enabled)
         self.schedule = schedule
         self.num_players = int(num_players)
         self.input_spec = input_spec
@@ -642,7 +637,7 @@ class BatchedSessionCore:
                 slot: segs[r] for slot, segs in per_slot.items()
                 if r < len(segs)
             }
-            with self.tracer.span("serve_dispatch", round=r):
+            with self.span("serve_round", round=r, slots=len(batch)):
                 self._dispatch(batch)
 
     def flush_reports(self) -> None:
@@ -651,11 +646,18 @@ class BatchedSessionCore:
         if not self._pending_reports:
             return
         pending, self._pending_reports = self._pending_reports, []
-        with self.metrics.timer("checksum_sync"):
+        with self.span("checksum_sync"):
             host = [(np.asarray(arr), rows) for arr, rows in pending]
         for cs_host, rows in host:
             for slot, t, frame, session in rows:
                 session.report_checksum(frame, combine64(cs_host[slot, t]))
+
+    def _record_predictor_rank(self, rank_ms: float) -> None:
+        self.last_predictor_rank_ms = rank_ms
+        self.predictor_rank_ms_total += rank_ms
+        self.predictor_rank_dispatches += 1
+        self.metrics.observe("predictor_rank_ms", rank_ms)
+        self.timeseries.observe("predictor_rank_ms", rank_ms)
 
     def _build_branches(self, s: _Slot, anchor: int, end: int, session,
                         seed=None):
@@ -736,197 +738,190 @@ class BatchedSessionCore:
         post: Dict[int, tuple] = {}
         reports: List[tuple] = []
 
-        measure = self._measure_host
-        t_loop = time.perf_counter() if measure else 0.0
-        # Span-stack marker for the sampling profiler: everything in the
-        # per-slot loop folds under serve_arg_assembly unless a nested
-        # marker (branch build / predictor rank) claims it — mirroring
-        # exactly how arg_ms is computed below. Armed only alongside the
-        # clock reads so the telemetry-off path stays untouched.
-        tok_loop = push_span("serve_arg_assembly") if measure else None
-        bb_ms = 0.0
-        rank_ms = 0.0
-        # Pass 1 — as-used log writes + anchor geometry for every batched
-        # slot, hoisted ahead of the build loop so the batched predictor
-        # ranking sees all post-write windows in ONE vmapped call.
-        geom: Dict[int, tuple] = {}
-        for i, (load_frame, steps, confirmed, _session) in batch.items():
-            s = self.slots[i]
-            start = s.frame if load_frame is None else load_frame
-            end = start + len(steps)
-            anchor = end if confirmed is None else confirmed + 1
-            # As-used log BEFORE match/build (forward-fill reads anchor-1,
-            # which this very burst may advance).
-            for t, st in enumerate(steps):
-                s.input_log[start + t] = np.asarray(st.adv.bits)
-            spec_active = (
-                s.spec_on and anchor <= end and anchor > end - self.ring_depth
-            )
-            geom[i] = (start, end, anchor, spec_active)
-        seeds: Dict[int, object] = {}
-        if self._ranker is not None:
-            eligible = [i for i in batch if geom[i][3]]
-            if eligible:
-                t_rank = time.perf_counter()
-                tok_rank = (
-                    push_span("serve_predictor_rank") if measure else None
+        # The per-slot loop is one span; the predictor ranking and the
+        # tree builds are its children, and ``serve_arg_assembly`` (the
+        # series) is its self time: log writes, matches, array fills.
+        sp_rank = NULL_SPAN
+        with self.span(
+            "serve_arg_assembly", series=False, slots=len(batch)
+        ) as sp_loop:
+            # Pass 1 — as-used log writes + anchor geometry for every batched
+            # slot, hoisted ahead of the build loop so the batched predictor
+            # ranking sees all post-write windows in ONE vmapped call.
+            geom: Dict[int, tuple] = {}
+            for i, (load_frame, steps, confirmed, _session) in batch.items():
+                s = self.slots[i]
+                start = s.frame if load_frame is None else load_frame
+                end = start + len(steps)
+                anchor = end if confirmed is None else confirmed + 1
+                # As-used log BEFORE match/build (forward-fill reads anchor-1,
+                # which this very burst may advance).
+                for t, st in enumerate(steps):
+                    s.input_log[start + t] = np.asarray(st.adv.bits)
+                spec_active = (
+                    s.spec_on and anchor <= end
+                    and anchor > end - self.ring_depth
                 )
-                W = self._predictor.weights.window
-                wins = np.full((S, W, P), -1, dtype=np.int32)
-                anchors = np.zeros(S, dtype=np.int32)
-                for i in eligible:
-                    anchors[i] = geom[i][2]
-                    wins[i] = self._predictor.window_indices(
-                        self.slots[i].input_log, geom[i][2], P
-                    )
-                traj_idx, order = self._ranker.rank(wins, anchors)
-                for i in eligible:
-                    seeds[i] = self._predictor.render_seed(
-                        traj_idx[i], order[i]
-                    )
-                if tok_rank is not None:
-                    pop_span(tok_rank)
-                rank_ms = (time.perf_counter() - t_rank) * 1000.0
-                self.last_predictor_rank_ms = rank_ms
-                self.predictor_rank_ms_total += rank_ms
-                self.predictor_rank_dispatches += 1
-                self.metrics.observe("predictor_rank_ms", rank_ms)
-                self.timeseries.observe("predictor_rank_ms", rank_ms)
-        for s in self.slots:
-            i = s.index
-            if i not in batch:
-                # No-op lane: every phase gated off; replay the pending
-                # rollout (if any) so the prev-buffer swap keeps it valid.
-                start_frame_a[i] = s.frame
-                if s.res_anchor is not None:
-                    spec_anchor_a[i] = s.res_anchor
-                    from_live_a[i] = s.res_from_live
-                    bb_a[i] = s.res_bits
-                else:
-                    spec_anchor_a[i] = s.frame
-                continue
-            requests_seg = batch[i]
-            load_frame, steps, confirmed, session = requests_seg
-            start, end, anchor, spec_active = geom[i]
-            n_steps = len(steps)
-            # Branch-commit decision (host-side, zero device syncs).
-            absorb_branch, n_commit = 0, 0
-            missed = False
-            blame_player = blame_frame = None
-            if (
-                load_frame is not None
-                and s.res_anchor is not None
-                and load_frame >= s.res_anchor
-            ):
-                steps_arr = np.stack(
-                    [np.asarray(st.adv.bits) for st in steps]
-                )
-                matched = None
-                if s.native is not None:
-                    matched = s.native.match(
-                        s.res_bits, s.res_anchor, load_frame, steps_arr, F
-                    )
-                else:
-                    needed = []
-                    complete = True
-                    for f in range(s.res_anchor, load_frame):
-                        got = s.input_log.get(f)
-                        if got is None:
-                            complete = False
-                            break
-                        needed.append(got)
-                    if complete:
-                        needed.extend(steps_arr)
-                        matched = match_branch(
-                            s.res_bits, np.stack(needed)[:F]
-                        )
-                if matched is not None:
-                    br, depth = matched
-                    nc = min(depth - (load_frame - s.res_anchor), n_steps)
-                    if nc > 0:
-                        absorb_branch, n_commit = int(br), int(nc)
-                    else:
-                        missed = True
-                        self.spec_misses += 1
-                        self.metrics.count("spec_misses")
-                        self.metrics.count(
-                            "spec_misses", labels={"match_slot": i}
-                        )
-                    if self.ledger.enabled:
-                        # Blame: first corrected input diverging from the
-                        # branch-0 prediction rows (pure NumPy on the
-                        # host-resident branch tensor).
-                        pre = load_frame - s.res_anchor
-                        k = min(n_steps, F - pre)
-                        if k > 0:
-                            div = blame_divergence(
-                                np.asarray(s.res_bits)[0][pre:pre + k],
-                                steps_arr[:k],
+                geom[i] = (start, end, anchor, spec_active)
+            seeds: Dict[int, object] = {}
+            if self._ranker is not None:
+                eligible = [i for i in batch if geom[i][3]]
+                if eligible:
+                    with self.timed_span(
+                        "serve_predictor_rank", slots=len(eligible)
+                    ) as sp_rank:
+                        W = self._predictor.weights.window
+                        wins = np.full((S, W, P), -1, dtype=np.int32)
+                        anchors = np.zeros(S, dtype=np.int32)
+                        for i in eligible:
+                            anchors[i] = geom[i][2]
+                            wins[i] = self._predictor.window_indices(
+                                self.slots[i].input_log, geom[i][2], P
                             )
-                            if div is not None:
-                                blame_player = div[1]
-                                blame_frame = load_frame + div[0]
-            # The next rollout. Speculation is active only when the anchor
-            # lies inside the post-burst ring window (precomputed in pass
-            # 1); otherwise the lane still computes a (discarded) rollout
-            # from the live frontier.
-            if spec_active:
-                if measure:
-                    t_bb = time.perf_counter()
-                    tok_bb = push_span("serve_branch_build")
-                    bb = self._build_branches(
-                        s, anchor, end, session, seeds.get(i)
+                        traj_idx, order = self._ranker.rank(wins, anchors)
+                        for i in eligible:
+                            seeds[i] = self._predictor.render_seed(
+                                traj_idx[i], order[i]
+                            )
+                    self._record_predictor_rank(sp_rank.ms)
+            # Every spec-active slot's next branch tree, in ONE span (a
+            # served frame has hundreds of slots: no span per slot). A
+            # build reads only the slot's as-used log (written in pass 1)
+            # and its session's confirmed inputs — nothing the commit
+            # decisions below change.
+            trees: Dict[int, np.ndarray] = {}
+            to_build = [
+                s for s in self.slots if s.index in batch and geom[s.index][3]
+            ]
+            with self.span(
+                "serve_branch_build", series=False, slots=len(to_build)
+            ) as sp_build:
+                for s in to_build:
+                    i = s.index
+                    _start, end, anchor, _active = geom[i]
+                    trees[i] = self._build_branches(
+                        s, anchor, end, batch[i][3], seeds.get(i)
                     )
-                    pop_span(tok_bb)
-                    bb_ms += (time.perf_counter() - t_bb) * 1000.0
+            for s in self.slots:
+                i = s.index
+                if i not in batch:
+                    # No-op lane: every phase gated off; replay the pending
+                    # rollout (if any) so the prev-buffer swap keeps it valid.
+                    start_frame_a[i] = s.frame
+                    if s.res_anchor is not None:
+                        spec_anchor_a[i] = s.res_anchor
+                        from_live_a[i] = s.res_from_live
+                        bb_a[i] = s.res_bits
+                    else:
+                        spec_anchor_a[i] = s.frame
+                    continue
+                requests_seg = batch[i]
+                load_frame, steps, confirmed, session = requests_seg
+                start, end, anchor, spec_active = geom[i]
+                n_steps = len(steps)
+                # Branch-commit decision (host-side, zero device syncs).
+                absorb_branch, n_commit = 0, 0
+                missed = False
+                blame_player = blame_frame = None
+                if (
+                    load_frame is not None
+                    and s.res_anchor is not None
+                    and load_frame >= s.res_anchor
+                ):
+                    steps_arr = np.stack(
+                        [np.asarray(st.adv.bits) for st in steps]
+                    )
+                    matched = None
+                    if s.native is not None:
+                        matched = s.native.match(
+                            s.res_bits, s.res_anchor, load_frame, steps_arr, F
+                        )
+                    else:
+                        needed = []
+                        complete = True
+                        for f in range(s.res_anchor, load_frame):
+                            got = s.input_log.get(f)
+                            if got is None:
+                                complete = False
+                                break
+                            needed.append(got)
+                        if complete:
+                            needed.extend(steps_arr)
+                            matched = match_branch(
+                                s.res_bits, np.stack(needed)[:F]
+                            )
+                    if matched is not None:
+                        br, depth = matched
+                        nc = min(depth - (load_frame - s.res_anchor), n_steps)
+                        if nc > 0:
+                            absorb_branch, n_commit = int(br), int(nc)
+                        else:
+                            missed = True
+                            self.spec_misses += 1
+                            self.metrics.count("spec_misses")
+                            self.metrics.count(
+                                "spec_misses", labels={"match_slot": i}
+                            )
+                        if self.ledger.enabled:
+                            # Blame: first corrected input diverging from the
+                            # branch-0 prediction rows (pure NumPy on the
+                            # host-resident branch tensor).
+                            pre = load_frame - s.res_anchor
+                            k = min(n_steps, F - pre)
+                            if k > 0:
+                                div = blame_divergence(
+                                    np.asarray(s.res_bits)[0][pre:pre + k],
+                                    steps_arr[:k],
+                                )
+                                if div is not None:
+                                    blame_player = div[1]
+                                    blame_frame = load_frame + div[0]
+                # The next rollout. Speculation is active only when the anchor
+                # lies inside the post-burst ring window (precomputed in pass
+                # 1); otherwise the lane still computes a (discarded) rollout
+                # from the live frontier.
+                if spec_active:
+                    bb = trees[i]
+                    spec_anchor, from_live = anchor, (anchor == end)
                 else:
-                    bb = self._build_branches(
-                        s, anchor, end, session, seeds.get(i)
-                    )
-                spec_anchor, from_live = anchor, (anchor == end)
-            else:
-                bb = self._zero_bb
-                spec_anchor, from_live = end, True
-            # Burst assembly: after a partial commit only the unmatched
-            # tail resimulates, absorb having positioned the state.
-            tail = steps[n_commit:]
-            if n_commit > 0:
-                burst_load, burst_start = None, load_frame + n_commit
-            else:
-                burst_load, burst_start = load_frame, start
-            branch_a[i] = absorb_branch
-            absorb_first_a[i] = load_frame if load_frame is not None else 0
-            absorb_n_a[i] = n_commit
-            prev_anchor_a[i] = s.res_anchor or 0
-            prev_total_a[i] = F if s.res_anchor is not None else 0
-            do_load_a[i] = burst_load is not None
-            load_frame_a[i] = burst_load if burst_load is not None else 0
-            start_frame_a[i] = burst_start
-            n_tail = len(tail)
-            save_mask_a[i, :n_tail] = True
-            adv_mask_a[i, :n_tail] = True
-            for t, st in enumerate(tail):
-                bits_a[i, t] = np.asarray(st.adv.bits)
-                status_a[i, t] = np.asarray(st.adv.status, np.int32)
-            spec_anchor_a[i] = spec_anchor
-            from_live_a[i] = from_live
-            bb_a[i] = bb
-            # bb is per-call fresh from both builders, so storing it for
-            # the replay/match path needs no defensive copy.
-            post[i] = (
-                end, spec_active, anchor if spec_active else None,
-                bb if spec_active else None,
-                from_live, load_frame, n_commit, n_steps, burst_start,
-                n_tail, session, missed, blame_player, blame_frame,
-            )
+                    bb = self._zero_bb
+                    spec_anchor, from_live = end, True
+                # Burst assembly: after a partial commit only the unmatched
+                # tail resimulates, absorb having positioned the state.
+                tail = steps[n_commit:]
+                if n_commit > 0:
+                    burst_load, burst_start = None, load_frame + n_commit
+                else:
+                    burst_load, burst_start = load_frame, start
+                branch_a[i] = absorb_branch
+                absorb_first_a[i] = load_frame if load_frame is not None else 0
+                absorb_n_a[i] = n_commit
+                prev_anchor_a[i] = s.res_anchor or 0
+                prev_total_a[i] = F if s.res_anchor is not None else 0
+                do_load_a[i] = burst_load is not None
+                load_frame_a[i] = burst_load if burst_load is not None else 0
+                start_frame_a[i] = burst_start
+                n_tail = len(tail)
+                save_mask_a[i, :n_tail] = True
+                adv_mask_a[i, :n_tail] = True
+                for t, st in enumerate(tail):
+                    bits_a[i, t] = np.asarray(st.adv.bits)
+                    status_a[i, t] = np.asarray(st.adv.status, np.int32)
+                spec_anchor_a[i] = spec_anchor
+                from_live_a[i] = from_live
+                bb_a[i] = bb
+                # bb is per-call fresh from both builders, so storing it for
+                # the replay/match path needs no defensive copy.
+                post[i] = (
+                    end, spec_active, anchor if spec_active else None,
+                    bb if spec_active else None,
+                    from_live, load_frame, n_commit, n_steps, burst_start,
+                    n_tail, session, missed, blame_player, blame_frame,
+                )
 
-        if tok_loop is not None:
-            pop_span(tok_loop)
-        if measure:
-            # Everything in the loop that is not the branch build is
-            # argument assembly (log writes, match, per-slot array fills).
-            loop_ms = (time.perf_counter() - t_loop) * 1000.0
-            arg_ms = max(0.0, loop_ms - bb_ms - rank_ms)
+        if sp_loop is not NULL_SPAN:
+            bb_ms = sp_build.ms
+            arg_ms = max(0.0, sp_loop.ms - bb_ms - sp_rank.ms)
             self.last_branch_build_ms = bb_ms
             self.last_arg_assembly_ms = arg_ms
             self.metrics.observe("serve_branch_build", bb_ms)
@@ -957,7 +952,7 @@ class BatchedSessionCore:
             if self.attribution is not None
             else contextlib.nullcontext()
         )
-        with self.metrics.timer("serve_dispatch"), dev:
+        with self.span("serve_dispatch"), dev:
             (
                 self.rings, self.states, absorb_cs, burst_cs,
                 self.prev_rings, self.prev_states, _spec_cs,
@@ -1077,209 +1072,198 @@ class BatchedSessionCore:
         post: Dict[int, tuple] = {}
         reports: List[tuple] = []
 
-        measure = self._measure_host
-        t_loop = time.perf_counter() if measure else 0.0
-        tok_loop = push_span("serve_arg_assembly") if measure else None
-        bb_ms = 0.0
-        rank_ms = 0.0
-        nb_ms = 0.0
-        plane.reset_masks()
-        # Pass 1 — SoA staging for ggrs_batch_stage: step bits/status,
-        # anchor geometry, match inputs, window-gather requests. The
-        # Python-side dict update bypasses MirroredLog's per-row ctypes
-        # forward — the stage call lands the same rows in the native
-        # mirror (in per-slot log -> match -> gather order, mirroring
-        # the Python pass structure).
-        geom: Dict[int, tuple] = {}
-        for i, (load_frame, steps, confirmed, _session) in batch.items():
-            s = self.slots[i]
-            start = s.frame if load_frame is None else load_frame
-            end = start + len(steps)
-            anchor = end if confirmed is None else confirmed + 1
-            plane.log_mask[i] = 1
-            plane.starts[i] = start
-            plane.n_steps[i] = len(steps)
-            for t, st in enumerate(steps):
-                arr = np.asarray(st.adv.bits)
-                dict.__setitem__(s.input_log, start + t, arr)
-                plane.steps[i, t] = arr
-                plane.status[i, t] = np.asarray(st.adv.status, np.int32)
-            if (
-                load_frame is not None
-                and s.res_anchor is not None
-                and load_frame >= s.res_anchor
-            ):
-                plane.match_mask[i] = 1
-                plane.res_anchors[i] = s.res_anchor
-                plane.load_frames[i] = load_frame
-                plane.set_res(i, s.res_bits)
-            spec_active = (
-                s.spec_on and anchor <= end and anchor > end - self.ring_depth
-            )
-            if self._ranker is not None and spec_active:
-                plane.win_mask[i] = 1
-                plane.win_anchors[i] = anchor
-            geom[i] = (start, end, anchor, spec_active)
-        with self.tracer.span("serve_native_batch", call="stage"):
-            t_nb = time.perf_counter() if measure else 0.0
-            tok_nb = push_span("serve_native_batch") if measure else None
-            plane.stage(F)
-            if tok_nb is not None:
-                pop_span(tok_nb)
-            if measure:
-                nb_ms += (time.perf_counter() - t_nb) * 1000.0
-        self.native_batch_calls += 1
-        self.metrics.count("native_batch_calls")
-        if self._ranker is not None:
-            eligible = [i for i in batch if geom[i][3]]
-            if eligible:
-                t_rank = time.perf_counter()
-                tok_rank = (
-                    push_span("serve_predictor_rank") if measure else None
-                )
-                anchors = np.zeros(S, dtype=np.int32)
-                el = np.asarray(eligible, dtype=np.intp)
-                anchors[el] = plane.win_anchors[el]
-                # Stale non-eligible window rows are fine: the ranker is
-                # a vmapped lane-independent forward, and only the
-                # eligible rows' outputs are consumed.
-                traj_idx, order = self._ranker.rank(plane.wins, anchors)
-                # render_seed vectorized over the eligible rows — the
-                # same universe gather + dtype cast per slot; the shared
-                # all-ones valid plane lives in the batch plane.
-                uni = self._predictor.universe
-                plane.seed_traj[el] = uni[traj_idx[el]]
-                plane.seed_cand[el] = uni[order[el]]
-                plane.seed_mask[el] = 1
-                if tok_rank is not None:
-                    pop_span(tok_rank)
-                rank_ms = (time.perf_counter() - t_rank) * 1000.0
-                self.last_predictor_rank_ms = rank_ms
-                self.predictor_rank_ms_total += rank_ms
-                self.predictor_rank_dispatches += 1
-                self.metrics.observe("predictor_rank_ms", rank_ms)
-                self.timeseries.observe("predictor_rank_ms", rank_ms)
-        # Pass 2 — commit decisions from the staged match results, then
-        # build-call staging (anchors, known inputs, no-op copies) and
-        # the per-slot scalar fills for the jit arguments.
-        dirty_known: List[int] = []
-        for s in self.slots:
-            i = s.index
-            if i not in batch:
-                start_frame_a[i] = s.frame
-                if s.res_anchor is not None:
-                    spec_anchor_a[i] = s.res_anchor
-                    from_live_a[i] = s.res_from_live
-                    plane.copy_mask[i] = 1
-                    plane.set_res(i, s.res_bits)
-                else:
-                    spec_anchor_a[i] = s.frame
-                continue
-            load_frame, steps, confirmed, session = batch[i]
-            start, end, anchor, spec_active = geom[i]
-            n_steps = len(steps)
-            absorb_branch, n_commit = 0, 0
-            missed = False
-            blame_player = blame_frame = None
-            if plane.match_mask[i]:
-                br = int(plane.out_branch[i])
-                if br >= 0:  # -1 = as-used log gap (the Python no-match)
-                    depth = int(plane.out_depth[i])
-                    nc = min(depth - (load_frame - s.res_anchor), n_steps)
-                    if nc > 0:
-                        absorb_branch, n_commit = br, int(nc)
-                    else:
-                        missed = True
-                        self.spec_misses += 1
-                        self.metrics.count("spec_misses")
-                        self.metrics.count(
-                            "spec_misses", labels={"match_slot": i}
-                        )
-                    if self.ledger.enabled:
-                        pre = load_frame - s.res_anchor
-                        k = min(n_steps, F - pre)
-                        if k > 0:
-                            div = blame_divergence(
-                                np.asarray(s.res_bits)[0][pre:pre + k],
-                                plane.steps[i, :k],
-                            )
-                            if div is not None:
-                                blame_player = div[1]
-                                blame_frame = load_frame + div[0]
-            if spec_active:
-                plane.build_mask[i] = 1
-                plane.anchors[i] = anchor
-                qs_ptr = (
-                    s.native.qset_ptr(session) if session is not None
-                    else None
-                )
-                plane.set_qs(i, qs_ptr)
-                if qs_ptr is None and session is not None and (
-                    getattr(session, "confirmed_span", None) is not None
-                    or getattr(session, "confirmed_input", None) is not None
+        # One span over the host loop; the two C calls and the predictor
+        # ranking are its children. ``native_batch_ms`` is the two calls'
+        # sum, ``serve_branch_build`` the build call, ``serve_arg_assembly``
+        # the loop's time outside build and ranking.
+        sp_rank = NULL_SPAN
+        with self.span(
+            "serve_arg_assembly", series=False, slots=len(batch)
+        ) as sp_loop:
+            plane.reset_masks()
+            # Pass 1 — SoA staging for ggrs_batch_stage: step bits/status,
+            # anchor geometry, match inputs, window-gather requests. The
+            # Python-side dict update bypasses MirroredLog's per-row ctypes
+            # forward — the stage call lands the same rows in the native
+            # mirror (in per-slot log -> match -> gather order, mirroring
+            # the Python pass structure).
+            geom: Dict[int, tuple] = {}
+            for i, (load_frame, steps, confirmed, _session) in batch.items():
+                s = self.slots[i]
+                start = s.frame if load_frame is None else load_frame
+                end = start + len(steps)
+                anchor = end if confirmed is None else confirmed + 1
+                plane.log_mask[i] = 1
+                plane.starts[i] = start
+                plane.n_steps[i] = len(steps)
+                for t, st in enumerate(steps):
+                    arr = np.asarray(st.adv.bits)
+                    dict.__setitem__(s.input_log, start + t, arr)
+                    plane.steps[i, t] = arr
+                    plane.status[i, t] = np.asarray(st.adv.status, np.int32)
+                if (
+                    load_frame is not None
+                    and s.res_anchor is not None
+                    and load_frame >= s.res_anchor
                 ):
-                    # Sessions with a confirmed-inputs surface but no
-                    # native queue set: the Python bulk query fills this
-                    # slot's known rows (re-zeroed after the build).
-                    known, kmask = s.shim._known_inputs(anchor, session)
-                    plane.known[i] = known
-                    plane.kmask[i] = kmask
-                    dirty_known.append(i)
-                spec_anchor, from_live = anchor, (anchor == end)
-            else:
-                spec_anchor, from_live = end, True
-            if n_commit > 0:
-                burst_load, burst_start = None, load_frame + n_commit
-            else:
-                burst_load, burst_start = load_frame, start
-            branch_a[i] = absorb_branch
-            absorb_first_a[i] = load_frame if load_frame is not None else 0
-            absorb_n_a[i] = n_commit
-            prev_anchor_a[i] = s.res_anchor or 0
-            prev_total_a[i] = F if s.res_anchor is not None else 0
-            do_load_a[i] = burst_load is not None
-            load_frame_a[i] = burst_load if burst_load is not None else 0
-            start_frame_a[i] = burst_start
-            n_tail = n_steps - n_commit
-            save_mask_a[i, :n_tail] = True
-            adv_mask_a[i, :n_tail] = True
-            if n_tail:
-                bits_a[i, :n_tail] = plane.steps[i, n_commit:n_steps]
-                status_a[i, :n_tail] = plane.status[i, n_commit:n_steps]
-            spec_anchor_a[i] = spec_anchor
-            from_live_a[i] = from_live
-            # The slot's next in-flight tree is its bb_a row, written by
-            # the build call below — the view is stored now, the bytes
-            # land before the device dispatch reads them.
-            post[i] = (
-                end, spec_active, anchor if spec_active else None,
-                bb_a[i] if spec_active else None,
-                from_live, load_frame, n_commit, n_steps, burst_start,
-                n_tail, session, missed, blame_player, blame_frame,
-            )
-        with self.tracer.span("serve_native_batch", call="build"):
-            t_bb = time.perf_counter() if measure else 0.0
-            tok_bb = push_span("serve_branch_build") if measure else None
-            plane.build(bb_a)
-            if tok_bb is not None:
-                pop_span(tok_bb)
-            if measure:
-                bb_ms = (time.perf_counter() - t_bb) * 1000.0
-                nb_ms += bb_ms
-        self.native_batch_calls += 1
-        self.metrics.count("native_batch_calls")
-        for i in dirty_known:
-            plane.known[i] = 0
-            plane.kmask[i] = 0
+                    plane.match_mask[i] = 1
+                    plane.res_anchors[i] = s.res_anchor
+                    plane.load_frames[i] = load_frame
+                    plane.set_res(i, s.res_bits)
+                spec_active = (
+                    s.spec_on and anchor <= end
+                    and anchor > end - self.ring_depth
+                )
+                if self._ranker is not None and spec_active:
+                    plane.win_mask[i] = 1
+                    plane.win_anchors[i] = anchor
+                geom[i] = (start, end, anchor, spec_active)
+            with self.span(
+                "serve_native_batch", series=False, call="stage",
+                slots=len(batch),
+            ) as sp_stage:
+                plane.stage(F)
+            self.native_batch_calls += 1
+            self.metrics.count("native_batch_calls")
+            if self._ranker is not None:
+                eligible = [i for i in batch if geom[i][3]]
+                if eligible:
+                    with self.timed_span(
+                        "serve_predictor_rank", slots=len(eligible)
+                    ) as sp_rank:
+                        anchors = np.zeros(S, dtype=np.int32)
+                        el = np.asarray(eligible, dtype=np.intp)
+                        anchors[el] = plane.win_anchors[el]
+                        # Stale non-eligible window rows are fine: the ranker
+                        # is a vmapped lane-independent forward, and only the
+                        # eligible rows' outputs are consumed.
+                        traj_idx, order = self._ranker.rank(
+                            plane.wins, anchors
+                        )
+                        # render_seed vectorized over the eligible rows — the
+                        # same universe gather + dtype cast per slot; the
+                        # shared all-ones valid plane lives in the batch plane.
+                        uni = self._predictor.universe
+                        plane.seed_traj[el] = uni[traj_idx[el]]
+                        plane.seed_cand[el] = uni[order[el]]
+                        plane.seed_mask[el] = 1
+                    self._record_predictor_rank(sp_rank.ms)
+            # Pass 2 — commit decisions from the staged match results, then
+            # build-call staging (anchors, known inputs, no-op copies) and
+            # the per-slot scalar fills for the jit arguments.
+            dirty_known: List[int] = []
+            n_build = 0
+            for s in self.slots:
+                i = s.index
+                if i not in batch:
+                    start_frame_a[i] = s.frame
+                    if s.res_anchor is not None:
+                        spec_anchor_a[i] = s.res_anchor
+                        from_live_a[i] = s.res_from_live
+                        plane.copy_mask[i] = 1
+                        plane.set_res(i, s.res_bits)
+                    else:
+                        spec_anchor_a[i] = s.frame
+                    continue
+                load_frame, steps, confirmed, session = batch[i]
+                start, end, anchor, spec_active = geom[i]
+                n_steps = len(steps)
+                absorb_branch, n_commit = 0, 0
+                missed = False
+                blame_player = blame_frame = None
+                if plane.match_mask[i]:
+                    br = int(plane.out_branch[i])
+                    if br >= 0:  # -1 = as-used log gap (the Python no-match)
+                        depth = int(plane.out_depth[i])
+                        nc = min(depth - (load_frame - s.res_anchor), n_steps)
+                        if nc > 0:
+                            absorb_branch, n_commit = br, int(nc)
+                        else:
+                            missed = True
+                            self.spec_misses += 1
+                            self.metrics.count("spec_misses")
+                            self.metrics.count(
+                                "spec_misses", labels={"match_slot": i}
+                            )
+                        if self.ledger.enabled:
+                            pre = load_frame - s.res_anchor
+                            k = min(n_steps, F - pre)
+                            if k > 0:
+                                div = blame_divergence(
+                                    np.asarray(s.res_bits)[0][pre:pre + k],
+                                    plane.steps[i, :k],
+                                )
+                                if div is not None:
+                                    blame_player = div[1]
+                                    blame_frame = load_frame + div[0]
+                if spec_active:
+                    n_build += 1
+                    plane.build_mask[i] = 1
+                    plane.anchors[i] = anchor
+                    qs_ptr = (
+                        s.native.qset_ptr(session) if session is not None
+                        else None
+                    )
+                    plane.set_qs(i, qs_ptr)
+                    if qs_ptr is None and session is not None and (
+                        getattr(session, "confirmed_span", None) is not None
+                        or getattr(session, "confirmed_input", None)
+                        is not None
+                    ):
+                        # Sessions with a confirmed-inputs surface but no
+                        # native queue set: the Python bulk query fills this
+                        # slot's known rows (re-zeroed after the build).
+                        known, kmask = s.shim._known_inputs(anchor, session)
+                        plane.known[i] = known
+                        plane.kmask[i] = kmask
+                        dirty_known.append(i)
+                    spec_anchor, from_live = anchor, (anchor == end)
+                else:
+                    spec_anchor, from_live = end, True
+                if n_commit > 0:
+                    burst_load, burst_start = None, load_frame + n_commit
+                else:
+                    burst_load, burst_start = load_frame, start
+                branch_a[i] = absorb_branch
+                absorb_first_a[i] = load_frame if load_frame is not None else 0
+                absorb_n_a[i] = n_commit
+                prev_anchor_a[i] = s.res_anchor or 0
+                prev_total_a[i] = F if s.res_anchor is not None else 0
+                do_load_a[i] = burst_load is not None
+                load_frame_a[i] = burst_load if burst_load is not None else 0
+                start_frame_a[i] = burst_start
+                n_tail = n_steps - n_commit
+                save_mask_a[i, :n_tail] = True
+                adv_mask_a[i, :n_tail] = True
+                if n_tail:
+                    bits_a[i, :n_tail] = plane.steps[i, n_commit:n_steps]
+                    status_a[i, :n_tail] = plane.status[i, n_commit:n_steps]
+                spec_anchor_a[i] = spec_anchor
+                from_live_a[i] = from_live
+                # The slot's next in-flight tree is its bb_a row, written by
+                # the build call below — the view is stored now, the bytes
+                # land before the device dispatch reads them.
+                post[i] = (
+                    end, spec_active, anchor if spec_active else None,
+                    bb_a[i] if spec_active else None,
+                    from_live, load_frame, n_commit, n_steps, burst_start,
+                    n_tail, session, missed, blame_player, blame_frame,
+                )
+            with self.span(
+                "serve_native_batch", series=False, call="build",
+                slots=n_build,
+            ) as sp_build:
+                plane.build(bb_a)
+            self.native_batch_calls += 1
+            self.metrics.count("native_batch_calls")
+            for i in dirty_known:
+                plane.known[i] = 0
+                plane.kmask[i] = 0
 
-        if tok_loop is not None:
-            pop_span(tok_loop)
-        if measure:
-            # branch_build is the build call's real measured wall time;
-            # everything else in the loop (SoA staging, the stage call,
-            # commit decisions, scalar fills) is argument assembly.
-            loop_ms = (time.perf_counter() - t_loop) * 1000.0
-            arg_ms = max(0.0, loop_ms - bb_ms - rank_ms)
+        if sp_loop is not NULL_SPAN:
+            bb_ms = sp_build.ms
+            nb_ms = sp_stage.ms + bb_ms
+            arg_ms = max(0.0, sp_loop.ms - bb_ms - sp_rank.ms)
             self.last_branch_build_ms = bb_ms
             self.last_arg_assembly_ms = arg_ms
             self.native_batch_ms_total += nb_ms
@@ -1385,9 +1369,7 @@ class BatchedSessionCore:
         # Pending branches were rolled out from pre-repair buffers; drop
         # them so the dispatch skips branch-match and rolls fresh ones.
         s.res_anchor, s.res_bits = None, None
-        with self.metrics.timer("sdc_repair"), self.tracer.span(
-            "sdc_repair", slot=slot, frames=len(steps)
-        ):
+        with self.span("sdc_repair", slot=slot, frames=len(steps)):
             self._dispatch({slot: (base, steps, None, session)})
         post_live = np.asarray(integrity._states_digests(self.states))[slot]
         after = integrity.host_row(self.rings, row, slot=slot)

@@ -57,6 +57,11 @@ import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 from bevy_ggrs_tpu.serve.batch import BatchedSessionCore, BatchedTickExecutor
+from bevy_ggrs_tpu.obs.trace import (
+    Instrumented,
+    attach_process_events,
+    detach_process_events,
+)
 from bevy_ggrs_tpu.serve.faults import (
     RecoveryLane,
     ServerCheckpointer,
@@ -96,7 +101,7 @@ def _supervisable(session) -> bool:
     )
 
 
-class MatchServer:
+class MatchServer(Instrumented):
     def __init__(
         self,
         schedule,
@@ -139,8 +144,6 @@ class MatchServer:
         from bevy_ggrs_tpu.obs.profiler import null_profiler
         from bevy_ggrs_tpu.obs.slo import SlotSLO, WindowSLO
         from bevy_ggrs_tpu.obs.timeseries import null_timeseries
-        from bevy_ggrs_tpu.obs.trace import null_tracer
-        from bevy_ggrs_tpu.utils.metrics import null_metrics
         from bevy_ggrs_tpu.utils.xla_cache import (
             ensure_persistent_compilation_cache,
             install_compile_listeners,
@@ -148,8 +151,7 @@ class MatchServer:
 
         ensure_persistent_compilation_cache()
         install_compile_listeners()
-        self.metrics = metrics if metrics is not None else null_metrics
-        self.tracer = tracer if tracer is not None else null_tracer
+        self._set_sinks(metrics, tracer)
         # Sampling host profiler (obs/profiler.py): reads the serving
         # thread's stacks from its own thread — wire-inert by
         # construction. The server does not start/stop it (the soak
@@ -296,6 +298,11 @@ class MatchServer:
             None if attest_interval is None else max(1, int(attest_interval))
         )
         self.sdc_repairs_total = 0
+        attach_process_events(self)
+
+    def close(self) -> None:
+        """Stop receiving ``gc_pause`` / ``compile`` events."""
+        detach_process_events(self)
 
     def _flat_slot(self, handle: MatchHandle) -> int:
         """Server-wide slot id (group-qualified) — the SLO/metrics key.
@@ -859,7 +866,7 @@ class MatchServer:
         from bevy_ggrs_tpu.integrity import StateFault
 
         for g, core in enumerate(self.groups):
-            with self.tracer.span("attest", group=g):
+            with self.span("attest", group=g):
                 detected = core.attest()
             for slot, bad in detected.items():
                 handle = MatchHandle(g, slot)
@@ -887,7 +894,7 @@ class MatchServer:
             if attest is None:
                 continue
             try:
-                with self.tracer.span(
+                with self.span(
                     "attest", group=handle.group, slot=handle.slot
                 ):
                     attest()
@@ -986,7 +993,7 @@ class MatchServer:
             )
             self._reserved[handle.group].discard(handle.slot)
             admit_budget_left -= 1
-            with self.tracer.span(
+            with self.span(
                 "admit_fast", group=handle.group, slot=handle.slot
             ):
                 self._admit_at(
@@ -1014,80 +1021,90 @@ class MatchServer:
             jitter = actual_off - ideal_off
             worst_jitter = max(worst_jitter, abs(jitter))
             self.metrics.observe("stagger_jitter", jitter)
-            with self.tracer.span(
-                "serve_tick", group=g, matches=len(matches)
-            ), self.metrics.timer("serve_tick"):
+            with self.span(
+                "serve_tick", group=g, frame=self.frames_served,
+                matches=len(matches),
+            ):
                 work = {}
-                for slot, (handle, m) in matches.items():
-                    session = m.session
-                    t_m = self._clock()
-                    try:
-                        sup = m.supervisor
-                        if sup is not None:
-                            sup.tick(t_m)
-                            if not sup.should_advance():
-                                # Lost a desync ballot (or mid-rejoin):
-                                # the state transfer needs a real runner.
+                # The session layer of the group: one span a group tick
+                # (supervisor, poll, local inputs, advance_frame and the
+                # SLO sample of every match; 64 matches a group at S=256,
+                # so no span per match).
+                with self.span(
+                    "serve_sessions", group=g, matches=len(matches)
+                ):
+                    for slot, (handle, m) in matches.items():
+                        session = m.session
+                        t_m = self._clock()
+                        try:
+                            sup = m.supervisor
+                            if sup is not None:
+                                sup.tick(t_m)
+                                if not sup.should_advance():
+                                    # Lost a desync ballot (or mid-rejoin):
+                                    # the state transfer needs a real runner.
+                                    self._fault(
+                                        handle, m, "supervisor_quarantine"
+                                    )
+                                    continue
+                            poll = getattr(
+                                session, "poll_remote_clients", None
+                            )
+                            if poll is not None:
+                                poll()
+                            cur = getattr(session, "current_state", None)
+                            if (
+                                cur is not None
+                                and cur() != SessionState.RUNNING
+                            ):
+                                continue  # still synchronizing: no work yet
+                            frame = core.slots[slot].frame
+                            if m.local_inputs is not None:
+                                for h in session.local_player_handles():
+                                    bits = m.local_inputs(frame, h)
+                                    if sup is not None:
+                                        bits = sup.input_for(h, bits)
+                                    session.add_local_input(h, bits)
+                            requests = session.advance_frame()
+                            conf = getattr(session, "confirmed_frame", None)
+                            confirmed = conf() if conf is not None else None
+                        except PredictionThreshold:
+                            continue  # backpressure, not a fault: no-op frame
+                        except SlotFault as f:
+                            self._fault(handle, m, f.reason, cause=f)
+                            continue
+                        except Exception as e:
+                            self._fault(handle, m, "session_error", cause=e)
+                            continue
+                        elapsed_ms = (self._clock() - t_m) * 1000.0
+                        # SLO sample: deadline hit + rollback depth (every
+                        # AdvanceFrame past the first in a canonical burst is
+                        # a resimulated frame).
+                        depth = max(
+                            0,
+                            sum(
+                                1 for r in requests
+                                if isinstance(r, AdvanceFrame)
+                            ) - 1,
+                        )
+                        self.slo.observe_tick(
+                            self._flat_slot(handle),
+                            deadline_ok=elapsed_ms <= self.watchdog_budget_ms,
+                            rollback_depth=depth,
+                        )
+                        if elapsed_ms > self.watchdog_budget_ms:
+                            if m.fsm.strike(frame):
+                                # Deadline expiry: the requests are already in
+                                # hand — they ride to the lane so session and
+                                # runner frame counters stay converged.
                                 self._fault(
-                                    handle, m, "supervisor_quarantine"
+                                    handle, m, "watchdog_timeout",
+                                    pending=(requests, session),
                                 )
                                 continue
-                        poll = getattr(session, "poll_remote_clients", None)
-                        if poll is not None:
-                            poll()
-                        cur = getattr(session, "current_state", None)
-                        if (
-                            cur is not None
-                            and cur() != SessionState.RUNNING
-                        ):
-                            continue  # still synchronizing: no work yet
-                        frame = core.slots[slot].frame
-                        if m.local_inputs is not None:
-                            for h in session.local_player_handles():
-                                bits = m.local_inputs(frame, h)
-                                if sup is not None:
-                                    bits = sup.input_for(h, bits)
-                                session.add_local_input(h, bits)
-                        requests = session.advance_frame()
-                        conf = getattr(session, "confirmed_frame", None)
-                        confirmed = conf() if conf is not None else None
-                    except PredictionThreshold:
-                        continue  # backpressure, not a fault: no-op frame
-                    except SlotFault as f:
-                        self._fault(handle, m, f.reason, cause=f)
-                        continue
-                    except Exception as e:
-                        self._fault(handle, m, "session_error", cause=e)
-                        continue
-                    elapsed_ms = (self._clock() - t_m) * 1000.0
-                    # SLO sample: deadline hit + rollback depth (every
-                    # AdvanceFrame past the first in a canonical burst is
-                    # a resimulated frame).
-                    depth = max(
-                        0,
-                        sum(
-                            1 for r in requests
-                            if isinstance(r, AdvanceFrame)
-                        ) - 1,
-                    )
-                    self.slo.observe_tick(
-                        self._flat_slot(handle),
-                        deadline_ok=elapsed_ms <= self.watchdog_budget_ms,
-                        rollback_depth=depth,
-                    )
-                    if elapsed_ms > self.watchdog_budget_ms:
-                        if m.fsm.strike(frame):
-                            # Deadline expiry: the requests are already in
-                            # hand — they ride to the lane so session and
-                            # runner frame counters stay converged.
-                            self._fault(
-                                handle, m, "watchdog_timeout",
-                                pending=(requests, session),
-                            )
-                            continue
-                    else:
-                        m.fsm.clear()
-                    work[slot] = (requests, confirmed, session)
+                        else:
+                            m.fsm.clear()
+                        work[slot] = (requests, confirmed, session)
                 while work:
                     try:
                         core.tick(work)
@@ -1124,7 +1141,7 @@ class MatchServer:
                 self._admit_queue.pop(0)
             )
             self._reserved[handle.group].discard(handle.slot)
-            with self.tracer.span(
+            with self.span(
                 "admit_drain", group=handle.group, slot=handle.slot
             ):
                 self._admit_at(
@@ -1153,7 +1170,7 @@ class MatchServer:
             m = self._matches.get(handle)
             if m is None:
                 continue
-            with self.tracer.span(
+            with self.span(
                 "lane_step", group=handle.group, slot=handle.slot
             ):
                 lane.step(now)

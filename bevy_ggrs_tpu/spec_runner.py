@@ -718,7 +718,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
             schedule, self.executor.max_frames, self.num_branches,
             self.spec_frames, mesh=mesh, branch_axis=branch_axis,
             entity_axis=entity_axis, state_template=self.state,
-            session_axis=session_axis,
+            session_axis=session_axis, span=self.span,
         )
         self._key = jax.random.PRNGKey(seed)
         self._result: Optional[SpecResult] = None
@@ -911,17 +911,13 @@ class SpeculativeRollbackRunner(RollbackRunner):
         completed in the frame's idle time — telemetry never blocks the
         tick critical path (the fallback paths keep synchronous reads).
 
-        The whole host-side tick is measured as ``spec_host_dispatch`` —
-        a SpanTracer span and a metrics timer (-> the
-        ``spec_host_dispatch_ms`` Prometheus summary), so host-dispatch
+        The whole host-side tick is the span ``spec_host_dispatch`` (->
+        the ``spec_host_dispatch_ms`` Prometheus summary), so host-dispatch
         budget regressions show up in ``metrics.prom``/trace exports, not
         just bench runs. Device work is asynchronous, so the interval is
         pure orchestration cost: what the 1 ms budget gates."""
-        with self.tracer.span("spec_tick"):
-            with self.metrics.timer("spec_host_dispatch"), self.tracer.span(
-                "spec_host_dispatch"
-            ):
-                self._tick(requests, confirmed_frame, session)
+        with self.span("spec_host_dispatch", frame=self.frame):
+            self._tick(requests, confirmed_frame, session)
 
     def _tick(self, requests, confirmed_frame: int, session=None) -> None:
         self.ticks_total += 1
@@ -981,7 +977,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
                 # Python assembly. None = log gap (the Python
                 # complete=False), which charges no miss.
                 steps_arr = np.stack([np.asarray(s.adv.bits) for s in steps])
-                with self.metrics.timer("match_branch"):
+                with self.span("match_branch"):
                     matched = self._native.match(
                         np.asarray(res.branch_bits), res.start_frame,
                         load_frame, steps_arr, res.num_frames,
@@ -998,7 +994,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
                 if complete:
                     needed.extend(np.asarray(s.adv.bits) for s in steps)
                     needed_arr = np.stack(needed)[: res.num_frames]
-                    with self.metrics.timer("match_branch"):
+                    with self.span("match_branch"):
                         matched = match_branch(
                             np.asarray(res.branch_bits), needed_arr
                         )
@@ -1035,93 +1031,21 @@ class SpeculativeRollbackRunner(RollbackRunner):
             )
             self._gc_log()
             return
-        if self._native is not None and self._sampler is None:
-            # One native call builds the dedup signature AND (unless the
-            # signature deduplicates the tick) the packed branch tensor —
-            # last/known/fingerprint/candidates all resolve inside the C++
-            # core. When the session's queue set is native too, the known
-            # inputs are read in-process and the known_inputs_query phase
-            # disappears from the tick entirely.
-            dedup = anchor < end
-            # Dedup-skip STEADY ticks only (see the Python path below).
-            allow_skip = (
-                dedup
-                and load_frame is None
-                and self._result is not None
-                and self._spec_sig is not None
+        with self.span("spec_tree_build", anchor=anchor):
+            # Dedup-skip STEADY ticks only: a rollback tick already ran
+            # (and charged) the branch match above — delegating it to the
+            # legacy path would re-run the match and double-count
+            # spec_misses; re-dispatching its rollout fused is one
+            # dispatch either way.
+            built = self._next_branch_bits(
+                anchor, end, session, may_skip=load_frame is None
             )
-            qs_ptr = self._native.qset_ptr(session)
-            if qs_ptr is not None:
-                known = known_mask = None
-            else:
-                with self.metrics.timer("known_inputs_query"):
-                    known, known_mask = self._known_inputs(anchor, session)
-            if self._predictor is not None:
-                # Seed folds into the native dedup signature (and, when
-                # not deduplicated, replaces base + candidate ranking).
-                self._native.seed(anchor, self._predictor_seed(anchor))
-            with self.metrics.timer("structured_bits_build"):
-                bits, sig = self._native.build(
-                    anchor, qs_ptr, known, known_mask, allow_skip,
-                    self._spec_sig,
-                )
-            if bits is None:
-                self.spec_dispatches_skipped += 1
-                self.metrics.count("spec_dispatches_skipped")
-                self.handle_requests(requests, session)
-                return
-            if not dedup:
-                sig = None
-        else:
-            last = self._input_log.get(anchor - 1)
-            if last is None:
-                last = self.input_spec.zeros_np(self.num_players)
-            with self.metrics.timer("known_inputs_query"):
-                known, known_mask = self._known_inputs(anchor, session)
-            pseed = self._predictor_seed(anchor)
-            if anchor < end and self._sampler is None:
-                sig = (
-                    anchor, np.asarray(last).tobytes(),
-                    known.tobytes(), known_mask.tobytes(),
-                    self._history_fingerprint(anchor),
-                    b"" if pseed is None else pseed.fold_bytes(),
-                )
-                # Dedup-skip STEADY ticks only: a rollback tick already ran
-                # (and charged) the branch match above — delegating it to
-                # the legacy path would re-run the match and double-count
-                # spec_misses; re-dispatching its rollout fused is one
-                # dispatch either way.
-                if (
-                    load_frame is None
-                    and self._result is not None
-                    and sig == self._spec_sig
-                ):
-                    self.spec_dispatches_skipped += 1
-                    self.metrics.count("spec_dispatches_skipped")
-                    self.handle_requests(requests, session)
-                    return
-            else:
-                sig = None
-            # The next rollout's branch tensor (host-side).
-            if self._sampler is not None:
-                self._key, sub = jax.random.split(self._key)
-                bits = enumerate_branches(
-                    sub, jnp.asarray(last), self.num_branches,
-                    self.spec_frames, sampler=self._sampler,
-                )
-                if known_mask.any():
-                    extra = bits.ndim - 3
-                    mask_b = jnp.asarray(known_mask).reshape(
-                        (1,) + known_mask.shape + (1,) * extra
-                    )
-                    bits = jnp.where(mask_b, jnp.asarray(known)[None], bits)
-                    base = _forward_fill(np.asarray(last), known, known_mask)
-                    bits = bits.at[0].set(jnp.asarray(base))
-            else:
-                with self.metrics.timer("structured_bits_build"):
-                    bits = self._structured_bits(
-                        np.asarray(last), known, known_mask, anchor
-                    )
+        if built is None:
+            self.spec_dispatches_skipped += 1
+            self.metrics.count("spec_dispatches_skipped")
+            self.handle_requests(requests, session)
+            return
+        bits, sig = built
         prev_r, prev_s = self._prev_buffers()
         self._spec_sig = sig
         # Burst assembly: after a partial commit only the unmatched tail
@@ -1141,9 +1065,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
             if tail else np.zeros((0, self.num_players), np.int32)
         )
         self.device_dispatches_total += 1
-        with self.metrics.timer("tick_dispatch"), self.tracer.span(
-            "tick_dispatch"
-        ):
+        with self.span("tick_dispatch", frame=end):
             (
                 self.ring, self.state, absorb_cs, burst_cs,
                 spec_rings, spec_states, spec_cs,
@@ -1231,7 +1153,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
             # an interleaved session-less call.
             return
         pending, self._pending_reports = self._pending_reports, []
-        with self.metrics.timer("checksum_sync"):
+        with self.span("checksum_sync"):
             host = [(np.asarray(arr), rows) for arr, rows in pending]
         for cs_host, rows in host:
             for t, frame in rows:
@@ -1259,12 +1181,46 @@ class SpeculativeRollbackRunner(RollbackRunner):
         if anchor <= self.frame - self.ring.depth:
             self._result = None  # anchor fell out of the ring
             return
+        with self.span("spec_tree_build", anchor=anchor):
+            built = self._next_branch_bits(
+                anchor, self.frame, session, may_skip=True
+            )
+        if built is None:
+            self.spec_dispatches_skipped += 1
+            self.metrics.count("spec_dispatches_skipped")
+            return
+        bits, self._spec_sig = built
+        with self.span("speculate_dispatch"):
+            self._result = self._dispatch_rollout(anchor, bits)
+
+    def _next_branch_bits(
+        self, anchor: int, end: int, session, may_skip: bool
+    ):
+        """The next rollout's branch tensor from frame ``anchor`` (``end``
+        is the frontier after this tick's burst) and its dedup signature,
+        ``(bits, sig)`` — or None when the rollout would repeat the
+        pending one and ``may_skip`` lets the caller skip the dispatch.
+
+        When ``anchor < end`` the anchor state is ring-fixed (a past
+        frame) and the structured tree is deterministic in (anchor, last,
+        known) plus the input-log window it ranks candidates and detects
+        periods from (the history fingerprint), so a rollout from the
+        same signature is the SAME rollout. When ``anchor == end`` the
+        anchor state is the live state, which moves every tick, and a
+        random sampler draws FRESH branches each dispatch, whose
+        compounding hit probability a skip would destroy: no signature in
+        either case."""
+        dedup = anchor < end
         if self._native is not None and self._sampler is None:
-            # Native one-call build (see _tick): signature + branch tensor
-            # in one ctypes call, with the dedup-skip decided in-core.
-            dedup = anchor < self.frame
+            # One native call builds the dedup signature AND (unless the
+            # signature deduplicates the tick) the packed branch tensor —
+            # last/known/fingerprint/candidates all resolve inside the C++
+            # core. When the session's queue set is native too, the known
+            # inputs are read in-process and the known_inputs_query phase
+            # disappears from the tick entirely.
             allow_skip = (
                 dedup
+                and may_skip
                 and self._result is not None
                 and self._spec_sig is not None
             )
@@ -1272,62 +1228,45 @@ class SpeculativeRollbackRunner(RollbackRunner):
             if qs_ptr is not None:
                 known = known_mask = None
             else:
-                with self.metrics.timer("known_inputs_query"):
+                with self.span("known_inputs_query"):
                     known, known_mask = self._known_inputs(anchor, session)
             if self._predictor is not None:
                 # Seed folds into the native dedup signature (and, when
                 # not deduplicated, replaces base + candidate ranking).
                 self._native.seed(anchor, self._predictor_seed(anchor))
-            with self.metrics.timer("structured_bits_build"):
+            with self.span("structured_bits_build"):
                 bits, sig = self._native.build(
                     anchor, qs_ptr, known, known_mask, allow_skip,
                     self._spec_sig,
                 )
             if bits is None:
-                self.spec_dispatches_skipped += 1
-                self.metrics.count("spec_dispatches_skipped")
-                return
-            self._spec_sig = sig if dedup else None
-            with self.metrics.timer("speculate_dispatch"), self.tracer.span(
-                "speculate_dispatch"
-            ):
-                self._result = self._dispatch_rollout(anchor, bits)
-            return
+                return None
+            return bits, (sig if dedup else None)
         last = self._input_log.get(anchor - 1)
         if last is None:
             last = self.input_spec.zeros_np(self.num_players)
-        with self.metrics.timer("known_inputs_query"):
+        with self.span("known_inputs_query"):
             known, known_mask = self._known_inputs(anchor, session)
         pseed = self._predictor_seed(anchor)
-        if anchor < self.frame and self._sampler is None:
-            # The anchor state is ring-fixed (a past frame) and the
-            # structured tree is deterministic in (anchor, last, known)
-            # plus the input-log window it ranks candidates and detects
-            # periods from (folded in as the history fingerprint), so a
-            # rollout from the same signature is the SAME rollout — skip
-            # the redundant device dispatch. (When anchor == self.frame
-            # the anchor state is the live state, which moves every tick;
-            # with a random sampler each dispatch draws FRESH branches,
-            # whose compounding hit probability the skip would destroy —
-            # no dedup in either case.)
+        sig = None
+        if dedup and self._sampler is None:
             sig = (
                 anchor, np.asarray(last).tobytes(),
                 known.tobytes(), known_mask.tobytes(),
                 self._history_fingerprint(anchor),
                 b"" if pseed is None else pseed.fold_bytes(),
             )
-            if self._result is not None and sig == self._spec_sig:
-                self.spec_dispatches_skipped += 1
-                self.metrics.count("spec_dispatches_skipped")
-                return
-            self._spec_sig = sig
-        else:
-            self._spec_sig = None
+            if (
+                may_skip
+                and self._result is not None
+                and sig == self._spec_sig
+            ):
+                return None
         if self._sampler is not None:
             self._key, sub = jax.random.split(self._key)
             bits = enumerate_branches(
-                sub, jnp.asarray(last), self.num_branches, self.spec_frames,
-                sampler=self._sampler,
+                sub, jnp.asarray(last), self.num_branches,
+                self.spec_frames, sampler=self._sampler,
             )
             if known_mask.any():  # pin known values across all branches,
                 # on device — speculate() stays fully asynchronous
@@ -1345,14 +1284,11 @@ class SpeculativeRollbackRunner(RollbackRunner):
                 base = _forward_fill(np.asarray(last), known, known_mask)
                 bits = bits.at[0].set(jnp.asarray(base))
         else:
-            with self.metrics.timer("structured_bits_build"):
+            with self.span("structured_bits_build"):
                 bits = self._structured_bits(
                     np.asarray(last), known, known_mask, anchor
                 )
-        with self.metrics.timer("speculate_dispatch"), self.tracer.span(
-            "speculate_dispatch"
-        ):
-            self._result = self._dispatch_rollout(anchor, bits)
+        return bits, sig
 
     def _commit_full_hit(
         self, load_frame: int, n_commit: int, branch: int, res: SpecResult,
@@ -1361,7 +1297,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
         """The full-hit fast path: one absorb-only dispatch commits the
         matched branch's precomputed frames. See :meth:`tick`."""
         self.device_dispatches_total += 1
-        with self.metrics.timer("spec_commit"):
+        with self.span("spec_commit", frame=load_frame):
             self.ring, self.state, absorb_cs = self._fused.commit_absorb(
                 self.ring, res.rings, res.states, branch, load_frame,
                 n_commit, res.start_frame, res.num_frames,
@@ -1850,7 +1786,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
                 }
             return False
 
-        with self.metrics.timer("spec_commit"):
+        with self.span("spec_commit", frame=load_frame):
             self.device_dispatches_total += 3  # 2 branch gathers + absorb
             spec_ring, spec_state = self._spec.commit(res, branch)
             self.ring, self.state, checksums = _absorb(

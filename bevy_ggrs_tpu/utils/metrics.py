@@ -13,9 +13,11 @@ module adds the quantitative layer the TPU build needs:
 
 All instruments are no-ops through :data:`null_metrics` unless a real
 :class:`Metrics` is installed, so the hot loop pays one attribute lookup
-when disabled. For kernel-level profiles, wrap a run with
-``jax.profiler.trace(logdir)`` — these host-side metrics and the XLA
-profile compose.
+when disabled. The ``<name>_ms`` series of the layer boundaries are
+written by the one span instrument (``obs/trace.py``
+``Instrumented.span``), which also puts each span on the
+``jax.profiler`` trace's clock; :meth:`Metrics.timer` is the bare timer
+for code with no instrumented object at hand.
 """
 
 from __future__ import annotations

@@ -90,6 +90,8 @@ def install_compile_listeners() -> bool:
         return True
     from jax import monitoring
 
+    from bevy_ggrs_tpu.obs.trace import record_compile
+
     # A request's cache verdict event precedes its duration event; carried
     # from one listener to the other.
     verdict: Optional[str] = None
@@ -112,11 +114,16 @@ def install_compile_listeners() -> bool:
         nonlocal verdict
         if event.endswith("/backend_compile_duration"):
             _COUNTERS["backend_compiles"] += 1
-            _COMPILE_EVENTS.append({
+            event = {
                 "ms": float(duration) * 1000.0,
                 "fingerprint": str(kwargs.get("fun_name", "")),
                 "cache": verdict,
-            })
+            }
+            _COMPILE_EVENTS.append(event)
+            # As a program event too (series compile_ms, ggrs/compile on
+            # the trace clock): a run that obtained an executable where it
+            # must not says WHICH program.
+            record_compile(event["fingerprint"], event["ms"], verdict)
             verdict = None
 
     monitoring.register_event_listener(_on_event)

@@ -165,9 +165,10 @@ def main() -> int:
     app.insert_session(session, SessionType.P2P)
     if tracer is not None:
         # One wiring point instruments the whole stack: the session was
-        # built with the tracer; the runner (and its speculative executor,
-        # if any) pick it up here.
-        app.stage.runner.tracer = tracer
+        # built with the tracer; the stage, the runner (and its
+        # speculative executor, if any) pick it up here (sinks are read at
+        # every span).
+        app.stage.tracer = app.stage.runner.tracer = tracer
         spec = getattr(app.stage.runner, "_spec", None)
         if spec is not None:
             spec.tracer = tracer

@@ -23,7 +23,7 @@ def scripted(handle, app):
 
 
 def build_box_app(num_players=2, fps=60, input_fn=None, max_prediction=8,
-                  clock=None, speculation=0, mesh=None):
+                  clock=None, speculation=0, mesh=None, metrics=None):
     def setup(world, app):
         box_game.spawn_players(
             world, num_players, next_id=app.rollback_id_provider.next_id
@@ -49,6 +49,8 @@ def build_box_app(num_players=2, fps=60, input_fn=None, max_prediction=8,
         plugin.with_speculation(speculation)
     if mesh is not None:
         plugin.with_mesh(mesh)
+    if metrics is not None:
+        plugin.with_metrics(metrics)
     return plugin.build()
 
 

@@ -14,7 +14,7 @@ import pytest
 from bevy_ggrs_tpu import obs
 from bevy_ggrs_tpu.chaos import ChaosPlan, ChaosSocket
 from bevy_ggrs_tpu.models import box_game
-from bevy_ggrs_tpu.obs.trace import SpanTracer, null_tracer
+from bevy_ggrs_tpu.obs.trace import NULL_SPAN, SpanTracer, null_tracer
 from bevy_ggrs_tpu.runner import RollbackRunner
 from bevy_ggrs_tpu.session import (
     PlayerType,
@@ -540,11 +540,19 @@ class TestOverheadGuard:
         spans = sum(s["count"] for s in probe.summary().values())
         spans_per_tick = spans / max(probe_ticks, 1)
 
-        # Direct cost of the disabled path at 2x that volume.
+        # Direct cost of the disabled path at 2x that volume: what a
+        # unified site pays with both sinks null (one ``self.span(...)``
+        # call handing out the shared no-op, where there used to be a
+        # null timer AND a null tracer span).
+        off = RollbackRunner(
+            box_game.make_schedule(), box_game.make_world(2).commit(),
+            max_prediction=8, num_players=2, input_spec=box_game.INPUT_SPEC,
+        )
+        assert off.span("x") is NULL_SPAN
         n_ops = int(spans_per_tick * ticks * 2) + 1
         t0 = time.perf_counter()
         for _ in range(n_ops):
-            with null_tracer.span("x"):
+            with off.span("x", frames=1):
                 pass
         null_cost_s = time.perf_counter() - t0
 

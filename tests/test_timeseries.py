@@ -337,8 +337,10 @@ def test_dispatch_decomposes_branch_build_and_arg_assembly():
 
 
 def test_decomposition_off_when_telemetry_off():
+    from bevy_ggrs_tpu.obs.trace import NULL_SPAN
+
     core = make_core(num_slots=2)
-    assert core._measure_host is False
+    assert core.span("serve_arg_assembly") is NULL_SPAN
     s = core.admit()
     drive(core, {s: make_script(seed=1, depth=1, cycles=1)})
     assert core.last_branch_build_ms == 0.0
