@@ -42,7 +42,13 @@ import numpy as np
 from bevy_ggrs_tpu.obs.trace import null_span
 from bevy_ggrs_tpu.rollout import rollout_burst
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
-from bevy_ggrs_tpu.state import SnapshotRing, WorldState, ring_load
+from bevy_ggrs_tpu.state import (
+    SnapshotRing,
+    WorldState,
+    ring_load,
+    ring_put,
+    ring_row_read,
+)
 
 # Memoized jit-argument scalars, shared process-wide. These used to live
 # per-executor instance, which was correct but wasteful under multi-session
@@ -127,29 +133,13 @@ def absorb_branch_frames(
     ``n_frames == 0`` leaves the ring untouched (the returned state is then
     meaningless — callers select it away)."""
 
-    def body(carry, t):
-        ring = carry
+    def body(ring, t):
         f = first_frame + t
         valid = t < n_frames
-        st = ring_load(spec_ring, f)
-        cs = spec_ring.checksums[jnp.remainder(f, spec_ring.depth)]
-        slot = jnp.remainder(f, ring.depth)
-        new_states = jax.tree_util.tree_map(
-            lambda r, s: jnp.where(
-                valid,
-                jax.lax.dynamic_update_index_in_dim(r, s, slot, 0),
-                r,
-            ),
-            ring.states,
-            st,
+        cs = ring_row_read(
+            spec_ring.checksums, jnp.remainder(f, spec_ring.depth)
         )
-        ring = SnapshotRing(
-            states=new_states,
-            frames=jnp.where(valid, ring.frames.at[slot].set(f), ring.frames),
-            checksums=jnp.where(
-                valid, ring.checksums.at[slot].set(cs), ring.checksums
-            ),
-        )
+        ring = ring_put(ring, ring_load(spec_ring, f), f, cs, valid)
         return ring, jnp.where(valid, cs, jnp.uint32(0))
 
     main_ring, checksums = jax.lax.scan(
@@ -289,9 +279,7 @@ class FusedTickExecutor:
         it) is bounded by the copy, not by the next rollout's compute: the
         runner dispatches this first, then the rollout asynchronously into
         the idle frame time."""
-        sel = lambda x: jax.lax.dynamic_index_in_dim(
-            x, branch, 0, keepdims=False
-        )
+        sel = lambda x: ring_row_read(x, branch)
         spec_ring_b = jax.tree_util.tree_map(sel, prev_rings)
         spec_state_b = jax.tree_util.tree_map(sel, prev_states)
         return absorb_branch_frames(
@@ -311,14 +299,9 @@ class FusedTickExecutor:
     ):
         # Phase 1 — absorb the matched branch's precomputed frames
         # (speculation hit). absorb_n == 0 leaves ring/state untouched.
-        sel = lambda x: jax.lax.dynamic_index_in_dim(
-            x, branch, 0, keepdims=False
-        )
-        spec_ring_b = jax.tree_util.tree_map(sel, prev_rings)
-        spec_state_b = jax.tree_util.tree_map(sel, prev_states)
-        ring_a, state_a, absorb_cs = absorb_branch_frames(
-            ring, spec_ring_b, spec_state_b, absorb_first, absorb_n,
-            prev_anchor, prev_total, max_steps=burst_frames,
+        ring_a, state_a, absorb_cs = FusedTickExecutor._absorb_impl(
+            burst_frames, ring, prev_rings, prev_states, branch,
+            absorb_first, absorb_n, prev_anchor, prev_total,
         )
         do_absorb = absorb_n > 0
         keep = lambda a, b: jnp.where(do_absorb, a, b)

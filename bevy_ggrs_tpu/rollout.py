@@ -36,17 +36,6 @@ from bevy_ggrs_tpu.schedule import PlayerInputs, Schedule
 from bevy_ggrs_tpu.state import SnapshotRing, WorldState, checksum, ring_load, ring_save
 
 
-def _masked_ring_save(
-    ring: SnapshotRing, state: WorldState, frame: jnp.ndarray, valid: jnp.ndarray
-) -> Tuple[SnapshotRing, jnp.ndarray]:
-    """ring_save that is a no-op (and yields checksum 0) when ``valid`` is
-    False. Select-based: XLA fuses the per-leaf selects into the update."""
-    new_ring, cs = ring_save(ring, state, frame)
-    keep = lambda new, old: jnp.where(valid, new, old)
-    merged = jax.tree_util.tree_map(keep, new_ring, ring)
-    return merged, jnp.where(valid, cs, jnp.uint32(0))
-
-
 def rollout_burst(
     schedule: Schedule,
     ring: SnapshotRing,
@@ -73,7 +62,8 @@ def rollout_burst(
     def body(carry, xs):
         ring, state, frame = carry
         b, s, sv, adv = xs
-        ring, cs = _masked_ring_save(ring, state, frame, sv)
+        ring, cs = ring_save(ring, state, frame, sv)  # no-op where not sv
+        cs = jnp.where(sv, cs, jnp.uint32(0))
         advanced = schedule(state, PlayerInputs(bits=b, status=s))
         state = jax.tree_util.tree_map(
             lambda new, old: jnp.where(adv, new, old), advanced, state

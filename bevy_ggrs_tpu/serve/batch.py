@@ -7,9 +7,29 @@ module applies the Podracer/Anakin batched-environments shape (PAPERS.md,
 arXiv 2104.06272) to rollback sessions: the fused tick program
 (:meth:`~bevy_ggrs_tpu.fused.FusedTickExecutor._tick_impl` — absorb +
 serial burst + B-branch speculative rollout, every phase gated by traced
-scalars) vmaps cleanly over a leading slot axis, so one compiled
-executable advances S matches — each with its OWN frame counter, rollback
-depth and branch tree — per dispatch.
+scalars) vmaps over a leading slot axis, so one compiled executable
+advances S matches — each with its OWN frame counter, rollback depth and
+branch tree — per dispatch.
+
+One rule keeps that vmap dense: **an index that differs per lane never
+goes into a dynamic slice under the slot vmap.** Every slot has its own
+frame, so ``frame % depth`` and the matched ``branch`` are per-lane
+indices, and jax batches a ``dynamic_update_slice`` / ``dynamic_slice``
+whose index is batched into a ``scatter`` / ``gather``. The TPU compiler
+expands each into a loop over the S slots of a few tiny ops: 27 such
+loops inside the tick's three scans, 46.0 ms a dispatch at S=64 x B=8 x
+F=8 against 0.19 ms for the unvmapped twin with twice the lanes
+(``PERF.md`` section 6, PR 25). So the ring-row and branch accesses
+(``state.py`` ``ring_row_write`` / ``ring_row_read``) carry a batching
+rule: where the index has the batch axis they lower to a select over the
+small static axis (bit-moving, so the bitwise guarantees hold; measured
+0.84 ms a dispatch), and where it has not (the singleton, the
+rollout's vmap over branches) they stay the in-place dynamic slice.
+Nothing here chooses: vmapping the index is what makes the choice. A
+title's own per-lane lookups (box_game's ``inputs.bits[handle]``) are
+the title's. The price is ``depth`` x the row's bytes a save instead of
+one row, nothing for box_game's rows; no served large world has been
+measured (``PERF.md`` section 7).
 
 Design rules that make the batch shape static (one executable, ever):
 

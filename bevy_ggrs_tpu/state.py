@@ -33,6 +33,7 @@ ops only, so it is bit-reproducible under XLA on a given platform.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -521,32 +522,144 @@ def ring_init(state: WorldState, depth: int) -> SnapshotRing:
     )
 
 
+# One definition of "row ``index`` of a small static axis", two lowerings.
+#
+# With ONE index for the whole array (a singleton session, or the rollout's
+# vmap over branches, which all share their session's frame) the access is a
+# dynamic slice: the write is in place and touches one row, whatever the
+# depth or the size of the world. Under a ``vmap`` that batches the INDEX
+# (the slot axis of ``serve/batch.py``: every match has its own frame
+# counter) jax would turn the same op into a ``scatter`` / ``gather``, which
+# the TPU compiler expands into a loop of tiny slice ops over the lanes in
+# every scan step (46.0 ms a dispatch of the served tick at S=64 x B=8,
+# 0.84 ms with selects; PERF.md section 6, PR 25). There the access is a
+# select over the axis instead: dense, elementwise, and exact (a select
+# moves bits; no one-hot multiply, which would turn -0.0 into 0.0 and spread
+# NaN). The choice is made where it can be seen: by the batching rule, from
+# whether the index carries the batch axis. Nothing is configured.
+
+
+def _batched(x, is_batched: bool, axis_size: int):
+    return x if is_batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+
+
+def _clamp(index: jnp.ndarray, n: int) -> jnp.ndarray:
+    # lax's dynamic index counts a negative one from the end and the slice
+    # then clamps its start into range; the select form must pick the same
+    # row for the same index.
+    index = jnp.asarray(index, jnp.int32)
+    return jnp.clip(jnp.where(index < 0, index + n, index), 0, n - 1)
+
+
+@functools.lru_cache(maxsize=None)
+def _row_write_at(axis: int):
+    @jax.custom_batching.custom_vmap
+    def write(stack, row, index, valid):
+        new = jax.lax.dynamic_update_index_in_dim(
+            stack, jnp.expand_dims(row, axis), index, axis
+        )
+        return new if valid is None else jnp.where(valid, new, stack)
+
+    @write.def_vmap
+    def write_vmap(axis_size, in_batched, stack, row, index, valid):
+        stack_b, row_b, index_b, valid_b = in_batched
+        stack = _batched(stack, stack_b, axis_size)
+        row = _batched(row, row_b, axis_size)
+        if not (index_b or valid_b):
+            # Lane-uniform index: still one dynamic row write, one axis in.
+            return _row_write_at(axis + 1)(stack, row, index, valid), True
+        n = stack.shape[axis + 1]
+        index = _batched(_clamp(index, n), index_b, axis_size)
+        hot = jnp.arange(n, dtype=jnp.int32) == index[:, None]  # [N, n]
+        if valid is not None:  # the mask folds into the one-hot: one pass
+            hot = hot & _batched(valid, valid_b, axis_size)[:, None]
+        hot = hot.reshape((axis_size,) + (1,) * axis + (n,)
+                          + (1,) * (stack.ndim - axis - 2))
+        return jnp.where(hot, jnp.expand_dims(row, axis + 1), stack), True
+
+    return write
+
+
+@functools.lru_cache(maxsize=None)
+def _row_read_at(axis: int):
+    @jax.custom_batching.custom_vmap
+    def read(stack, index):
+        return jax.lax.dynamic_index_in_dim(stack, index, axis, keepdims=False)
+
+    @read.def_vmap
+    def read_vmap(axis_size, in_batched, stack, index):
+        stack_b, index_b = in_batched
+        if not index_b:
+            return _row_read_at(axis + 1)(stack, index), True
+        at = axis + 1 if stack_b else axis
+        n = stack.shape[at]
+        row = lambda d: jax.lax.index_in_dim(stack, d, at, keepdims=False)
+        out = _batched(row(0), stack_b, axis_size)
+        index = _clamp(index, n).reshape((axis_size,) + (1,) * (out.ndim - 1))
+        for d in range(1, n):
+            out = jnp.where(index == d, row(d), out)
+        return out, True
+
+    return read
+
+
+def _traced(*xs) -> bool:
+    return any(isinstance(x, jax.core.Tracer) for x in xs)
+
+
+def ring_row_write(
+    stack: jnp.ndarray, row: jnp.ndarray, index: jnp.ndarray,
+    valid: Optional[jnp.ndarray] = None,
+) -> jnp.ndarray:
+    """``stack`` with row ``index`` of its leading axis replaced by ``row``
+    (left as it is where the scalar ``valid`` is False)."""
+    write = _row_write_at(0)
+    if not _traced(index, valid):
+        write = write.fun  # a concrete index cannot differ per lane
+    return write(stack, row, index, valid)
+
+
+def ring_row_read(stack: jnp.ndarray, index: jnp.ndarray) -> jnp.ndarray:
+    """Row ``index`` of ``stack``'s leading axis: a snapshot-ring row by
+    ``frame % depth``, or the matched branch of a ``[B, ...]`` rollout."""
+    read = _row_read_at(0)
+    return (read if _traced(index) else read.fun)(stack, index)
+
+
+def ring_put(
+    ring: SnapshotRing,
+    state: WorldState,
+    frame: jnp.ndarray,
+    cs: jnp.ndarray,
+    valid: Optional[jnp.ndarray] = None,
+) -> SnapshotRing:
+    """Write ``state`` with its checksum ``cs`` into ``frame``'s row (a
+    no-op where ``valid`` is False)."""
+    frame = jnp.asarray(frame, dtype=jnp.int32)
+    slot = jnp.remainder(frame, ring.depth)
+    return SnapshotRing(
+        states=jax.tree_util.tree_map(
+            lambda r, s: ring_row_write(r, s, slot, valid), ring.states, state
+        ),
+        frames=ring_row_write(ring.frames, frame, slot, valid),
+        checksums=ring_row_write(ring.checksums, cs, slot, valid),
+    )
+
+
 def ring_save(
-    ring: SnapshotRing, state: WorldState, frame: jnp.ndarray
+    ring: SnapshotRing, state: WorldState, frame: jnp.ndarray,
+    valid: Optional[jnp.ndarray] = None,
 ) -> Tuple[SnapshotRing, jnp.ndarray]:
-    """Save ``state`` as frame ``frame``; returns (ring, checksum).
+    """Save ``state`` as frame ``frame``; returns (ring, checksum). With
+    ``valid`` (a traced bool) False the ring is left as it is.
 
     The checksum computed here is what the session hands to its saved-state
     cell for desync detection — the byte buffer never leaves the device,
     matching the reference's ``cell.save(frame, None, Some(checksum))``
     (``src/ggrs_stage.rs:282-283``).
     """
-    frame = jnp.asarray(frame, dtype=jnp.int32)
-    slot = jnp.remainder(frame, ring.depth)
     cs = active_checksum(state)
-    new_states = jax.tree_util.tree_map(
-        lambda r, s: jax.lax.dynamic_update_index_in_dim(r, s, slot, 0),
-        ring.states,
-        state,
-    )
-    return (
-        SnapshotRing(
-            states=new_states,
-            frames=ring.frames.at[slot].set(frame),
-            checksums=ring.checksums.at[slot].set(cs),
-        ),
-        cs,
-    )
+    return ring_put(ring, state, frame, cs, valid), cs
 
 
 def ring_load(ring: SnapshotRing, frame: jnp.ndarray) -> WorldState:
@@ -555,8 +668,7 @@ def ring_load(ring: SnapshotRing, frame: jnp.ndarray) -> WorldState:
     prediction window, like the reference's ``frame % len`` indexing)."""
     slot = jnp.remainder(jnp.asarray(frame, dtype=jnp.int32), ring.depth)
     return jax.tree_util.tree_map(
-        lambda r: jax.lax.dynamic_index_in_dim(r, slot, 0, keepdims=False),
-        ring.states,
+        lambda r: ring_row_read(r, slot), ring.states
     )
 
 
