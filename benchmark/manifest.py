@@ -33,6 +33,13 @@ KEYS = {
 DATA_SUFFIXES = (".json", ".jsonl", ".toml", ".txt", ".csv")
 
 
+def toy_files(workload: str, loop_kind: str, top: str = "benchmark") -> list:
+    """Where a cell's toy size (what the CPU rehearsal shrinks it to) may
+    stand, relative to the checkout: the cell's own file first, its loop
+    kind's second."""
+    return [f"{top}/toy/{name}.json" for name in (workload, loop_kind)]
+
+
 def _line(text, limit=200) -> bool:
     return (isinstance(text, str) and 1 <= len(text) <= limit
             and "\n" not in text and "\t" not in text)
@@ -92,15 +99,22 @@ def validate(manifest: dict, root: str = ROOT) -> List[str]:
     files = [c["file"] for c in configs]
     if len(set(files)) != len(files):
         say("two configurations share a file")
+    loop_kinds = {}
     for c in configs:
         if not (_line(c["source"]) and _line(c["why"])):
             say(f"config {c['name']}: source or why")
-        if not (under(c["file"]) and PATH.match(c["file"])
-                and os.path.isfile(os.path.join(root, c["file"]))):
-            say(f"config {c['name']}: file {c['file']}")
         if len(c["reduced"]) > 16 or not all(NAME.match(k)
                                              for k in c["reduced"]):
             say(f"config {c['name']}: reduced")
+        if not (under(c["file"]) and PATH.match(c["file"])
+                and os.path.isfile(os.path.join(root, c["file"]))):
+            say(f"config {c['name']}: file {c['file']}")
+            continue
+        try:
+            with open(os.path.join(root, c["file"]), encoding="utf-8") as f:
+                loop_kinds[c["name"]] = json.load(f)["driver"]
+        except (ValueError, KeyError, TypeError):
+            pass    # not a configuration's file: the tests of its content say so
     config_names = {c["name"] for c in configs}
     used = set()
     pairs = set()
@@ -119,6 +133,12 @@ def validate(manifest: dict, root: str = ROOT) -> List[str]:
                 root, paths[0], "traffic", w["traffic"] + s))
                 for s in DATA_SUFFIXES):
             say(f"cell {w['name']}: no traffic file")
+        kind = loop_kinds.get(w["config"])
+        toys = toy_files(w["name"], kind, paths[0])
+        if kind and not any(os.path.isfile(os.path.join(root, t))
+                            for t in toys):
+            say(f"cell {w['name']}: no toy size for the rehearsal: add "
+                f"{toys[1]} or {toys[0]}")
     if used != config_names:
         say(f"configs no cell uses: {sorted(config_names - used)}")
     four = sum(1 for w in cells if w["chips"] == 4)

@@ -3,9 +3,10 @@
 The profiler writes one plane per device (``/device:TPU:0``, with the lines
 ``XLA Modules`` — one event per executed program — and ``XLA Ops`` — one per
 operation) and one for the host (``/host:CPU``, one line per thread, where
-``jax.profiler.TraceAnnotation`` spans of the benchmark appear under their
-own names, all starting with ``bench/``). Everything is on one clock, in
-nanoseconds.
+``jax.profiler.TraceAnnotation`` spans appear under their own names: the
+benchmark's start with ``bench/``, the program's (``obs/trace.py``, one per
+layer boundary, nested as the calls nest) with ``ggrs/``). Everything is on
+one clock, in nanoseconds.
 
 ``load`` turns the file into a plain :class:`Trace`; the functions below
 reduce a ``Trace`` and never touch jax, so they are tested on the small
@@ -20,7 +21,8 @@ from typing import Dict, List, Optional, Tuple
 
 Interval = Tuple[float, float]           # (start_s, end_s)
 Block = Tuple[float, float, float]       # (start_s, end_s, busy_s inside)
-SPAN_PREFIX = "bench/"
+Span = Tuple[str, float, float]          # (name, start_s, end_s)
+SPAN_PREFIXES = ("bench/", "ggrs/")      # the benchmark's, the program's
 WINDOW_SPAN = "bench/window"
 DROPPED = "Trace Buffers Dropped"
 SHORT_GAP_S = 20e-6
@@ -32,14 +34,16 @@ class Trace:
     are millions of events): per device the runs of operations with no gap
     of 20 us or more between them, and how long operations ran inside each
     run; each operation's self time by name; the executed programs; the
-    benchmark's own host spans; and when the device's trace buffer filled,
-    if it did."""
+    benchmark's and the program's host spans, all together (``spans``, by
+    start) and per host thread (``threads``: spans nest within a thread);
+    and when the device's trace buffer filled, if it did."""
 
-    spans: List[Tuple[str, float, float]]
-    modules: Dict[int, List[Tuple[str, float, float]]]
+    spans: List[Span]
+    modules: Dict[int, List[Span]]
     blocks: Dict[int, List[Block]]
     op_self_s: Dict[int, Dict[str, float]]
     dropped_at: Optional[float] = None
+    threads: List[List[Span]] = dataclasses.field(default_factory=list)
 
 
 def _device_ordinal(plane_name: str) -> Optional[int]:
@@ -125,8 +129,11 @@ def load(xspace) -> Trace:
                             trace.dropped_at = s
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                trace.spans.extend(ev for ev in timed(line)
-                                   if ev[0].startswith(SPAN_PREFIX))
+                mine = [ev for ev in timed(line)
+                        if ev[0].startswith(SPAN_PREFIXES)]
+                if mine:
+                    trace.threads.append(mine)
+                    trace.spans.extend(mine)
     trace.spans.sort(key=lambda e: e[1])
     return trace
 
@@ -171,7 +178,7 @@ def program_time(trace: Trace, pattern: str, window: Interval,
     """Summed device seconds and the number of executions of the programs
     whose name matches ``pattern`` and that ran wholly inside the window —
     and, with ``within_spans``, started while the host was inside one of
-    the benchmark's spans of those names (which tells the programs of the
+    the spans of those names (which tells the programs of the
     client under test from the far end's where both are anonymous). On
     several devices one execution counts once per device."""
     rx = re.compile(pattern)
@@ -209,14 +216,59 @@ def top_ops(trace: Trace, n: int = 10) -> List[list]:
     return [[k, v / devices] for k, v in ranked]
 
 
+def nest(events) -> Tuple[List[tuple], List[tuple]]:
+    """From one thread's spans to (instances, segments): per span instance
+    ``(name, parent, dur_s, self_s)``, a span's self time being what its
+    direct children leave of it; and the thread's timeline cut into disjoint
+    ``(start_s, end_s, innermost name or None)`` pieces. THE definition of
+    "innermost": of the spans open at an instant, the one that started
+    last (of two that start together, the shorter). A child that outlives
+    its parent by a rounding of the clock is cut at the parent's end."""
+    events = sorted(events, key=lambda e: (e[1], -(e[2] - e[1])))
+    instances, segments = [], []
+    stack: List[list] = []      # [name, end, dur, self, parent]
+    cursor = events[0][1] if events else 0.0
+
+    def close_until(t):
+        nonlocal cursor
+        while stack and stack[-1][1] <= t:
+            name, end, dur, own, parent = stack.pop()
+            if end > cursor:
+                segments.append((cursor, end, name))
+                cursor = end
+            instances.append((name, parent, dur, own))
+
+    for name, s, e in events:
+        close_until(s)
+        if s > cursor:
+            segments.append((cursor, s, stack[-1][0] if stack else None))
+            cursor = s
+        if stack:
+            e = min(e, stack[-1][1])
+            stack[-1][3] -= e - s
+        stack.append([name, e, e - s, e - s,
+                      stack[-1][0] if stack else None])
+    close_until(float("inf"))
+    return instances, segments
+
+
+def window_thread(trace: Trace) -> List[Span]:
+    """The spans of the host thread that drove the window (the one that
+    holds ``bench/window``); a hand-made trace has one thread, ``spans``."""
+    for spans in trace.threads:
+        if any(name == WINDOW_SPAN for name, _, _ in spans):
+            return spans
+    return trace.spans
+
+
 def idle_gaps(trace: Trace, window: Interval, n: int = 10) -> List[list]:
     """Idle time of the (first) device inside the window, charged to what
     the host was doing: each gap of 20 us or more between device operations
-    is split over the benchmark's spans that overlap it; what no span covers
-    is ``unattributed``; shorter gaps (the device between two operations of
-    one program) are summed as ``between_ops_under_20us``. The benchmark's
-    spans, the window aside, follow one another and do not nest.
-    [name, seconds], largest first."""
+    goes to the INNERMOST span (``nest``) that the window's thread had open
+    at that instant, the program's ``ggrs/`` spans inside the benchmark's
+    ``bench/`` ones; what no span covers is ``unattributed``; shorter gaps
+    (the device between two operations of one program) are summed as
+    ``between_ops_under_20us``. [name, seconds], largest first."""
     if not trace.blocks:
         return []
     blocks = _clipped(trace.blocks[min(trace.blocks)], window)
@@ -233,18 +285,18 @@ def idle_gaps(trace: Trace, window: Interval, n: int = 10) -> List[list]:
         cursor = max(cursor, e)
     if hi > cursor:
         gaps.append((cursor, hi))
-    spans = sorted((s, e, name) for name, s, e in trace.spans
-                   if name != WINDOW_SPAN)
+    _, segments = nest([ev for ev in window_thread(trace)
+                        if ev[0] != WINDOW_SPAN])
     first = 0
     for a, b in gaps:
-        while first < len(spans) and spans[first][1] <= a:
+        while first < len(segments) and segments[first][1] <= a:
             first += 1
         covered = 0.0
         j = first
-        while j < len(spans) and spans[j][0] < b:
-            s, e, name = spans[j]
+        while j < len(segments) and segments[j][0] < b:
+            s, e, name = segments[j]
             part = min(b, e) - max(a, s)
-            if part > 0:
+            if part > 0 and name is not None:
                 acc[name] = acc.get(name, 0.0) + part
                 covered += part
             j += 1

@@ -10,7 +10,9 @@ and a loop kind found by their names — warms that cell's shapes (set-up),
 measures for ``--seconds``, decides ``correct`` outside the window, and
 prints the contract's one JSON object as its last line: with ``--trace 0``
 the cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics and
-the device's busy time from a ``jax.profiler`` trace of the window.
+the device's busy time from a ``jax.profiler`` trace of the window; its
+last key, ``compared``, holds every number ``correct`` compared beside its
+limit, and the same are the last lines of standard error.
 
 This file knows no cell, configuration, title or metric by name.
 """
@@ -32,6 +34,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HERE = os.path.join(ROOT, "benchmark")
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+
+from benchmark.manifest import toy_files  # noqa: E402
 
 
 def _load(path: str) -> dict:
@@ -63,6 +67,23 @@ def load_cell(workload: str, overrides: dict | None = None):
             _merged(traffic, overrides.get("traffic")))
 
 
+def load_toy(workload: str) -> dict:
+    """The ``overrides`` that shrink a cell to the rehearsal's toy size
+    (tests/benchmark, on the CPU): data beside what it shrinks. A cell
+    whose mix needs a size of its own has ``toy/<cell>.json``; every other
+    cell of a loop kind shares ``toy/<loop kind>.json``."""
+    _, _, config, _ = load_cell(workload)
+    tried = toy_files(workload, config["driver"])
+    for rel in tried:
+        if os.path.isfile(os.path.join(ROOT, rel)):
+            toy = _load(os.path.join(ROOT, rel))
+            return {k: toy[k] for k in ("config", "traffic") if k in toy}
+    raise FileNotFoundError(
+        f"cell {workload!r} has no toy size for the rehearsal: add "
+        f"{tried[1]} (every cell of loop kind {config['driver']!r}) or "
+        f"{tried[0]} (this cell alone)")
+
+
 def metric_entries(manifest: dict, group: str, workload: str) -> list:
     """The metrics of ``end_to_end`` / ``per_layer`` this cell reports."""
     return [m for m in manifest[group]
@@ -91,8 +112,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              overrides: dict | None = None, emit=print):
     """Run the cell; returns (exit code, result dict or None). ``control``
     swaps in one of the title's deliberately wrong schedules;
-    ``require_tpu=False`` and ``overrides`` exist for the CPU tests under
-    tests/benchmark and are reachable from no command-line flag."""
+    ``require_tpu=False`` and ``overrides`` (``load_toy``) exist for the CPU
+    tests under tests/benchmark and are reachable from no command-line
+    flag."""
     manifest, cell, config, traffic = load_cell(workload, overrides)
     marks = {}
 
@@ -271,6 +293,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     }
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # Every number compared beside its limit: the result's last key, and
+    # the last lines of standard error (what the driver's record keeps of
+    # a run that is not correct).
+    result["compared"] = {c.name: {"value": c.value, "limit": c.limit}
+                          for c in comparisons}
+    for c in comparisons:
+        print(f"compared {c.name} value={c.value!r} limit={c.limit!r} "
+              f"{'ok' if c.ok else 'NOT OK'}", file=sys.stderr)
+    sys.stderr.flush()
     emit(json.dumps(result))
     return 0, result
 

@@ -21,14 +21,31 @@ def test_the_committed_manifest_is_valid():
     assert checker.validate(_manifest()) == []
 
 
+# What PR 23 was accepted with. Later PRs append cells and configurations;
+# these stay, in this order, with their configuration, mix and file.
+ACCEPTED_CELLS = [
+    ("server256.synctest", "box_game_server256", "synctest"),
+    ("client.wan", "box_game_p2p_client", "wan"),
+    ("client.lan", "box_game_p2p_client", "lan"),
+    ("server256.quarter", "box_game_server256", "quarter"),
+]
+ACCEPTED_CONFIGS = {
+    "box_game_p2p_client": "benchmark/configs/box_game_p2p_client.json",
+    "box_game_server256": "benchmark/configs/box_game_server256.json",
+}
+
+
 def test_cells_and_configurations_of_the_issue():
     m = _manifest()
     assert m["command"] == ["python3", "benchmark/run.py"]
-    names = [w["name"] for w in m["workloads"]]
-    assert names[:3] == ["server256.synctest", "client.wan", "client.lan"]
-    assert all(w["chips"] == 1 for w in m["workloads"])
-    assert {c["name"] for c in m["configs"]} == {
-        "box_game_p2p_client", "box_game_server256"}
+    first = m["workloads"][:len(ACCEPTED_CELLS)]
+    assert [(w["name"], w["config"], w["traffic"])
+            for w in first] == ACCEPTED_CELLS
+    assert all(w["chips"] == 1 for w in first)
+    files = {c["name"]: c["file"] for c in m["configs"]}
+    for name, file in ACCEPTED_CONFIGS.items():
+        assert files.get(name) == file
+        assert os.path.isfile(os.path.join(ROOT, file))
 
 
 def test_full_check_fits_the_budget_with_24_cells():

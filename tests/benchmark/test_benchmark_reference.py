@@ -20,11 +20,16 @@ def _table(seed, matches, players, frames):
                       size=(matches, players, frames))
 
 
-def _limits(config):
-    with open(os.path.join(ROOT, "benchmark", "configs", config + ".json"),
-              encoding="utf-8") as f:
-        limits = json.load(f)["limits"]
-    return {k: v["limit"] for k, v in limits.items()}
+def _configs():
+    """Every configuration of the manifest, as {name: its file's content}:
+    one a later PR adds is held to its own limits by the test below."""
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
+        entries = json.load(f)["configs"]
+    out = {}
+    for c in entries:
+        with open(os.path.join(ROOT, c["file"]), encoding="utf-8") as f:
+            out[c["name"]] = json.load(f)
+    return out
 
 
 def test_reference_imports_nothing_of_the_program():
@@ -73,18 +78,22 @@ def test_reference_against_the_jax_step_and_each_matchs_own_length():
         assert np.abs(v - want_v[m]).max() <= 1e-6
 
 
-@pytest.mark.parametrize("config", ["box_game_p2p_client",
-                                    "box_game_server256"])
+@pytest.mark.parametrize("config", sorted(_configs()))
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_bfloat16_control_fails_the_limits_and_float32_passes(config, seed):
+    import importlib
+
     from benchmark.inputs import HeldKeys
 
-    limits = _limits(config)
+    cfg = _configs()[config]
+    limits = {k: v["limit"] for k, v in cfg["limits"].items()}
+    ref = importlib.import_module(f"benchmark.reference.{cfg['title']}_np")
+    players = int(cfg["settings"]["num_players"])
     with open(os.path.join(ROOT, "benchmark", "traffic", "wan.json"),
               encoding="utf-8") as f:
         params = json.load(f)["inputs"]
     frames = 150
-    bits = HeldKeys(seed, 16, 2, params).table(frames)[:, :, :frames]
+    bits = HeldKeys(seed, 16, players, params).table(frames)[:, :, :frames]
     n = np.full((16,), frames)
     t32, v32, _ = ref.replay(bits, n)
     again_t, again_v, _ = ref.replay(bits, n)

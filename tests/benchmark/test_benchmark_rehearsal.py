@@ -3,7 +3,9 @@
 A rehearsal of control flow and of the ``correct`` decision, labelled
 ``platform: cpu`` by the result line itself: never a device number. The
 look for a chip is skipped through ``run_cell(require_tpu=False)``, which no
-command-line flag reaches.
+command-line flag reaches. The toy sizes are data: ``benchmark/toy/<cell>.json``
+where a cell has its own, else ``benchmark/toy/<loop kind>.json``
+(``run.load_toy``).
 """
 
 import json
@@ -17,24 +19,6 @@ import pytest
 from benchmark import run
 
 ROOT = run.ROOT
-TOY = {
-    "p2p_pair": {
-        "config": {"settings": {"speculation_branches": 8}},
-        "traffic": {"warmup_ticks": 60, "trace_window_s": 0.5,
-                    "traced_run_s": 1.0},
-    },
-    "match_server": {
-        "config": {"settings": {"capacity": 8, "stagger_groups": 2}},
-        "traffic": {"occupancy": {"admit": 8, "live": 8}, "sample_slots": 2,
-                    "warmup_frames": 4, "trace_window_s": 0.4,
-                    "traced_run_s": 1.0},
-    },
-}
-QUARTER_TOY = {
-    "config": {"settings": {"capacity": 8, "stagger_groups": 2}},
-    "traffic": {"occupancy": {"admit": 8, "live": 4}, "sample_slots": 2,
-                "warmup_frames": 4},
-}
 
 
 def _cells():
@@ -42,17 +26,19 @@ def _cells():
         return json.load(f)["workloads"]
 
 
-def _toy(cell):
-    _, _, config, traffic = run.load_cell(cell)
-    if traffic["name"] == "quarter":
-        return QUARTER_TOY
-    return TOY[config["driver"]]
+def _first_cell_of_every_loop_kind():
+    """A new loop kind is rehearsed traced, under the lower-precision
+    control and with a broken step from the moment its first cell lands."""
+    first = {}
+    for w in _cells():
+        first.setdefault(run.load_cell(w["name"])[2]["driver"], w["name"])
+    return list(first.values())
 
 
 def _run(cell, seed=2**31 + 17, trace=False, control=None, seconds=1.0):
     lines = []
     rc, result = run.run_cell(cell, seed, seconds, trace, control=control,
-                              require_tpu=False, overrides=_toy(cell),
+                              require_tpu=False, overrides=run.load_toy(cell),
                               emit=lines.append)
     assert rc == 0
     assert json.loads(lines[-1]) == json.loads(json.dumps(result))
@@ -60,10 +46,10 @@ def _run(cell, seed=2**31 + 17, trace=False, control=None, seconds=1.0):
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in _cells()])
-def test_cell_rehearsal_end_to_end(cell):
+def test_cell_rehearsal_end_to_end(cell, capfd):
     result, info = _run(cell)
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
     assert result["device"]["platform"] == "cpu"
     assert result["correct"] is True, info
     assert result["attempted"] > 0 and result["failed"] == 0
@@ -77,9 +63,18 @@ def test_cell_rehearsal_end_to_end(cell):
     assert {"window.executables_built", "reference.translation_gap",
             "reference.velocity_gap"} <= {c["name"] for c in compares}
     assert all("limit" in c and "value" in c for c in compares)
+    # The same numbers in the result's line, as its last key, and as the
+    # last lines of standard error.
+    assert result["compared"] == {c["name"]: {"value": c["value"],
+                                              "limit": c["limit"]}
+                                  for c in compares}
+    err = capfd.readouterr().err.strip().splitlines()[-len(compares):]
+    assert [line.split()[:2] for line in err] == [
+        ["compared", c["name"]] for c in compares]
+    assert all(line.endswith(" ok") for line in err)
 
 
-@pytest.mark.parametrize("cell", ["server256.synctest", "client.wan"])
+@pytest.mark.parametrize("cell", _first_cell_of_every_loop_kind())
 def test_cell_rehearsal_traced(cell):
     result, _ = _run(cell, trace=True)
     assert result["correct"] is True
@@ -87,6 +82,7 @@ def test_cell_rehearsal_traced(cell):
     # The profiler is on for the first part of the traced run only.
     assert 0.3 < result["device"]["window_s"] < 0.9
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert list(result)[-1] == "compared"
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
         manifest = json.load(f)
     allowed = {m["name"] for m in manifest["per_layer"]
@@ -101,7 +97,7 @@ def test_cell_rehearsal_traced(cell):
     assert {n for n in host_side if not n.startswith("recovery_ms")} <= got
 
 
-@pytest.mark.parametrize("cell", ["server256.synctest", "client.wan"])
+@pytest.mark.parametrize("cell", _first_cell_of_every_loop_kind())
 def test_lower_precision_control_is_not_correct(cell):
     result, info = _run(cell, control="bf16_state", seconds=2.0)
     failed = {i["name"] for i in info
@@ -112,12 +108,29 @@ def test_lower_precision_control_is_not_correct(cell):
     assert failed and all(n.startswith("reference.") for n in failed)
 
 
-@pytest.mark.parametrize("cell", ["server256.synctest", "client.wan"])
+@pytest.mark.parametrize("cell", _first_cell_of_every_loop_kind())
 def test_broken_step_is_not_correct(cell):
     result, info = _run(cell, control="freeze_last_player")
     assert result["correct"] is False
     assert any(i["info"] == "compare" and not i["ok"]
                and i["name"] == "reference.translation_gap" for i in info)
+
+
+def test_a_cell_without_a_toy_size_names_the_files_to_add(monkeypatch):
+    monkeypatch.setattr(run, "toy_files", lambda cell, kind: [
+        f"benchmark/toy/absent.{cell}.json", f"benchmark/toy/absent.{kind}.json"])
+    with pytest.raises(FileNotFoundError) as err:
+        run.load_toy("client.lan")
+    assert "benchmark/toy/absent.client.lan.json" in str(err.value)
+    assert "benchmark/toy/absent.p2p_pair.json" in str(err.value)
+
+
+def test_a_cells_own_toy_size_comes_before_its_loop_kinds():
+    kind = run.load_toy("server256.synctest")
+    own = run.load_toy("server256.quarter")
+    assert kind["traffic"]["occupancy"] == {"admit": 8, "live": 8}
+    assert own["traffic"]["occupancy"] == {"admit": 8, "live": 4}
+    assert set(kind) == set(own) == {"config", "traffic"}
 
 
 def test_without_a_tpu_nothing_is_run(capsys):

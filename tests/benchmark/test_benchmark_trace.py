@@ -35,6 +35,11 @@ def test_recorded_window_and_spans(recorded):
                  "bench/far_end"):
         assert names.count(span) == 12
     assert names.count(rt.WINDOW_SPAN) == 1
+    # Recorded before the program had spans of its own (PR 24): one thread,
+    # the benchmark's spans only, and they do not nest.
+    assert len(recorded.threads) == 1
+    assert sorted(recorded.threads[0], key=lambda e: e[1]) == recorded.spans
+    assert all(n.startswith("bench/") for n in names)
     assert recorded.dropped_at is None
     assert list(recorded.modules) == [0] and len(recorded.modules[0]) == 72
 
@@ -128,6 +133,87 @@ def test_hand_made_gaps_are_charged_to_host_spans():
     assert gaps["bench/run_frame"] == pytest.approx(0.5 + 0.5)
     assert gaps["bench/final_wait"] == pytest.approx(1.25 + 0.75)
     assert rt.program_time(tr, "tick_impl", win) == (pytest.approx(1.0), 3)
+
+    # The program's spans nest inside the benchmark's: each gap goes to the
+    # innermost span open at that instant, and only what no narrower span
+    # covers stays with its parent.
+    tr.spans += [("ggrs/serve_tick", 0.6, 2.0),
+                 ("ggrs/serve_sessions", 0.6, 1.2),
+                 ("ggrs/serve_dispatch", 1.2, 1.9),
+                 ("ggrs/serve_native_batch", 1.2, 1.4)]
+    nested = dict(rt.idle_gaps(tr, win))
+    assert nested == pytest.approx({
+        "bench/run_frame": 0.1,             # 0.5-0.6, before the group's tick
+        "ggrs/serve_sessions": 0.4,         # 0.6-1.0; busy from 1.0 to 1.5
+        "ggrs/serve_dispatch": 0.4,         # 1.5-1.9
+        "ggrs/serve_tick": 0.1,             # 1.9-2.0: its self time
+        "bench/final_wait": 1.25 + 0.75,
+    })
+    assert "ggrs/serve_native_batch" not in nested   # the device was busy
+    assert sum(nested.values()) == pytest.approx(sum(gaps.values()))
+    # What does not read host spans reads what it read.
+    assert rt.window_of(tr) == win
+    assert rt.busy_seconds(tr, win) == pytest.approx(1.0)
+    assert rt.program_time(tr, "tick_impl", win) == (pytest.approx(1.0), 3)
+    assert rt.program_time(tr, "tick_impl", win, ["bench/run_frame"]) == (
+        pytest.approx(0.75), 2)
+
+
+def _threaded():
+    tr = _hand_made()
+    other = [("ggrs/gc_pause", 0.5, 4.5)]           # a thread of its own
+    tr.threads = [other, list(tr.spans)]
+    tr.spans = sorted(tr.spans + other, key=lambda e: e[1])
+    return tr
+
+
+def test_gaps_are_charged_on_the_thread_that_holds_the_window():
+    tr = _threaded()
+    assert rt.window_thread(tr) is tr.threads[1]
+    gaps = dict(rt.idle_gaps(tr, rt.window_of(tr)))
+    assert "ggrs/gc_pause" not in gaps
+    assert gaps["bench/run_frame"] == pytest.approx(1.0)
+
+
+def test_nest_gives_parents_self_times_and_innermost_segments():
+    instances, segments = rt.nest([
+        ("bench/update", 0.0, 10.0), ("ggrs/stage_update", 1.0, 9.0),
+        ("ggrs/poll", 1.0, 2.0), ("ggrs/tick_dispatch", 3.0, 8.0),
+        ("ggrs/tick_enqueue", 4.0, 8.5),    # outlives its parent: cut at 8
+        ("bench/readable", 12.0, 13.0),
+    ])
+    by_name = {i[0]: i for i in instances}
+    assert by_name["ggrs/poll"][1:] == ("ggrs/stage_update", 1.0, 1.0)
+    assert by_name["ggrs/tick_enqueue"][1:] == ("ggrs/tick_dispatch", 4.0, 4.0)
+    assert by_name["ggrs/tick_dispatch"][1:] == ("ggrs/stage_update", 5.0, 1.0)
+    assert by_name["ggrs/stage_update"][1:] == ("bench/update", 8.0, 2.0)
+    assert by_name["bench/update"][1:] == (None, 10.0, 2.0)
+    assert segments == [
+        (0.0, 1.0, "bench/update"), (1.0, 2.0, "ggrs/poll"),
+        (2.0, 3.0, "ggrs/stage_update"), (3.0, 4.0, "ggrs/tick_dispatch"),
+        (4.0, 8.0, "ggrs/tick_enqueue"), (8.0, 9.0, "ggrs/stage_update"),
+        (9.0, 10.0, "bench/update"), (10.0, 12.0, None),
+        (12.0, 13.0, "bench/readable"),
+    ]
+    assert rt.nest([]) == ([], [])
+
+
+def test_the_tools_innermost_is_the_benchmarks(recorded):
+    """tools/trace_spans.py still carries the reduction this one was moved
+    from (a benchmark PR may not edit it): until it imports this one, the
+    two agree on every set of spans here."""
+    import importlib.util
+
+    path = os.path.join(ROOT, "tools", "trace_spans.py")
+    if not os.path.isfile(path):
+        return
+    spec = importlib.util.spec_from_file_location("trace_spans_tool", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    nested = _hand_made().spans + [("ggrs/serve_tick", 0.6, 2.0),
+                                   ("ggrs/serve_sessions", 0.6, 1.2)]
+    for spans in (nested, recorded.spans, recorded.threads[0], []):
+        assert tool.nest(list(spans)) == rt.nest(list(spans))
 
 
 def test_dropped_trace_buffers_cut_the_window():
