@@ -421,6 +421,13 @@ class BatchedSessionCore(Instrumented):
         # Aggregate counters (per-slot views go through labeled metrics).
         self.ticks_total = 0
         self.device_dispatches_total = 0
+        # Scan steps of the batched tick, exact: every dispatch runs
+        # ``burst_frames`` static steps for each of its ``num_slots`` lanes
+        # (``burst_step_slots_total``); ``burst_steps_total`` is how many
+        # of them a lane's request list asked for (its AdvanceFrames). The
+        # ratio is the share of the tick that is not padding.
+        self.burst_steps_total = 0
+        self.burst_step_slots_total = 0
         self.spec_hits = 0
         self.spec_partial_hits = 0
         self.spec_misses = 0
@@ -967,6 +974,7 @@ class BatchedSessionCore(Instrumented):
         hit/miss counters, ledger entries and deferred checksum rows."""
         branch_a = jit_args[0]
         self.device_dispatches_total += 1
+        self.burst_step_slots_total += self.num_slots * self.burst_frames
         dev = (
             self.attribution.device_wait()
             if self.attribution is not None
@@ -1001,6 +1009,7 @@ class BatchedSessionCore(Instrumented):
             else:
                 s.res_anchor, s.res_bits = None, None
             lab = {"match_slot": i}
+            self.burst_steps_total += n_steps
             self.metrics.count("frames_advanced", n_steps)
             self.metrics.count("frames_advanced", n_steps, labels=lab)
             if load_frame is not None:

@@ -58,6 +58,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from bevy_ggrs_tpu.serve.batch import BatchedSessionCore, BatchedTickExecutor
 from bevy_ggrs_tpu.obs.trace import (
+    NULL_SPAN,
     Instrumented,
     attach_process_events,
     detach_process_events,
@@ -224,6 +225,9 @@ class MatchServer(Instrumented):
             else None
         )
         self.frames_served = 0
+        # Match-frames withheld by a session's back-pressure
+        # (PredictionThreshold): not advanced, not a fault.
+        self.frames_withheld_total = 0
         self.faults_total = 0
         self.readmissions_total = 0
         self.evictions_total = 0
@@ -1032,7 +1036,12 @@ class MatchServer(Instrumented):
                 # so no span per match).
                 with self.span(
                     "serve_sessions", group=g, matches=len(matches)
-                ):
+                ) as sp_sessions:
+                    # The group's poll_remote_clients() calls, summed into
+                    # ONE sample of the series ``serve_poll_ms`` (clock
+                    # reads only while a sink listens).
+                    time_polls = sp_sessions is not NULL_SPAN
+                    poll_s = 0.0
                     for slot, (handle, m) in matches.items():
                         session = m.session
                         t_m = self._clock()
@@ -1051,7 +1060,12 @@ class MatchServer(Instrumented):
                                 session, "poll_remote_clients", None
                             )
                             if poll is not None:
-                                poll()
+                                if time_polls:
+                                    t_p = time.perf_counter()
+                                    poll()
+                                    poll_s += time.perf_counter() - t_p
+                                else:
+                                    poll()
                             cur = getattr(session, "current_state", None)
                             if (
                                 cur is not None
@@ -1069,7 +1083,18 @@ class MatchServer(Instrumented):
                             conf = getattr(session, "confirmed_frame", None)
                             confirmed = conf() if conf is not None else None
                         except PredictionThreshold:
-                            continue  # backpressure, not a fault: no-op frame
+                            # Back-pressure, not a fault: a withheld frame.
+                            # Counted, and sampled by the SLO as any tick
+                            # (its own host time, nothing rolled back).
+                            self.frames_withheld_total += 1
+                            self.metrics.count("frames_withheld")
+                            self.slo.observe_tick(
+                                self._flat_slot(handle),
+                                deadline_ok=(self._clock() - t_m) * 1000.0
+                                <= self.watchdog_budget_ms,
+                                rollback_depth=0,
+                            )
+                            continue
                         except SlotFault as f:
                             self._fault(handle, m, f.reason, cause=f)
                             continue
@@ -1105,6 +1130,8 @@ class MatchServer(Instrumented):
                         else:
                             m.fsm.clear()
                         work[slot] = (requests, confirmed, session)
+                    if time_polls:
+                        self.metrics.observe("serve_poll_ms", poll_s * 1000.0)
                 while work:
                     try:
                         core.tick(work)
