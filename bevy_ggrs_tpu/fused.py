@@ -28,6 +28,13 @@ SpeculativeRollbackRunner.speculate` (with absorb+burst no-op'd) and the
 warmup attestation replays ITS branches through the real serial burst —
 so the program whose states get committed is the program that was proven
 bitwise-equal to serial recovery, not a sibling compilation of it.
+
+What goes into the program's int32 argument (:class:`TickInts`) is decided
+here too, on the host and once for every executor: :func:`match_pending`
+(a rollback against the pending rollout), :func:`plan_tick` (the commit,
+the burst geometry, the next rollout; the one writer of the scalars) and
+:func:`account_rollback` (the counters, the outcome, the ledger entry).
+The singleton runner and both served dispatch paths call them.
 """
 
 from __future__ import annotations
@@ -40,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bevy_ggrs_tpu.obs.trace import null_span
+from bevy_ggrs_tpu.parallel.speculate import match_branch
 from bevy_ggrs_tpu.rollout import rollout_burst
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.state import (
@@ -123,12 +131,221 @@ class TickInts:
     """Layout of the packed tick's one int32 argument: these scalars
     (``do_load`` and ``spec_from_live`` as 0 / 1), then the burst's status
     rows flattened, ``[burst_frames * P]`` from ``STATUS`` on. The absorb
-    program takes the first ``ABSORB`` of them."""
+    program takes the first ``ABSORB`` of them. :func:`plan_tick` is the
+    one writer of the scalars."""
 
     (BRANCH, ABSORB_FIRST, ABSORB_N, PREV_ANCHOR, PREV_TOTAL,
      DO_LOAD, LOAD_FRAME, START_FRAME, N_BURST,
      SPEC_FROM_LIVE, SPEC_ANCHOR, STATUS) = range(12)
     ABSORB = 5
+
+    @staticmethod
+    def zeros(burst_frames: int, num_players: int, lanes: tuple = ()):
+        """A zeroed host row (``lanes``: leading axes, a row a lane)."""
+        return np.zeros(
+            lanes + (TickInts.STATUS + burst_frames * num_players,), np.int32
+        )
+
+    @staticmethod
+    def status(ints, burst_frames: int, num_players: int):
+        """The status rows of ``ints`` as a ``[..., burst_frames, P]``
+        view."""
+        return ints[..., TickInts.STATUS:].reshape(
+            ints.shape[:-1] + (burst_frames, num_players)
+        )
+
+
+def spec_in_window(anchor: int, end: int, ring_depth: int) -> bool:
+    """Whether a rollout anchored at ``anchor`` can be dispatched once the
+    frontier is ``end``: not past it (everything confirmed: nothing to
+    speculate) and not aged out of the ring."""
+    return end - ring_depth < anchor <= end
+
+
+def match_pending(
+    native, input_log, res_bits, res_anchor, res_frames, load_frame, steps,
+    span=null_span,
+):
+    """Which branch of the pending rollout (``res_bits [B, F, P, ...]``
+    from frame ``res_anchor``) the corrected history follows, and how
+    deep: ``(branch, depth)`` with ``depth`` counted from the anchor. The
+    history is the as-used inputs of the frames that survive the rollback
+    (``res_anchor .. load_frame - 1``, from ``input_log``) and then the
+    corrected inputs of ``steps``, cut to the rollout's ``res_frames``.
+    ``None`` when no branch can be asked: no rollback, no pending rollout,
+    a load before its anchor, or a gap in the log (which charges no
+    miss). ``native`` is the lane's native builder (its log mirror walks
+    the pre-span without per-frame Python) or None. The match itself runs
+    inside ``span("match_branch")``: the singleton's tick times it."""
+    if load_frame is None or res_anchor is None or load_frame < res_anchor:
+        return None
+    res_bits = np.asarray(res_bits)
+    corrected = [np.asarray(s.adv.bits) for s in steps]
+    if native is not None:
+        steps_arr = np.stack(corrected)
+        with span("match_branch"):
+            return native.match(
+                res_bits, res_anchor, load_frame, steps_arr, res_frames
+            )
+    needed = []
+    for f in range(res_anchor, load_frame):
+        got = input_log.get(f)
+        if got is None:
+            return None
+        needed.append(got)
+    needed.extend(corrected)
+    needed_arr = np.stack(needed)[:res_frames]
+    with span("match_branch"):
+        return match_branch(res_bits, needed_arr)
+
+
+def plan_commit(matched, load_frame, res_anchor, n_steps: int):
+    """The commit decision: ``(branch, n_commit, missed)`` of a rollback
+    of ``n_steps`` frames from ``load_frame`` whose match against the
+    pending rollout is ``matched`` (``(branch, depth) | None``).
+    ``n_commit`` frames of the replay were precomputed by ``branch``; a
+    match that reaches no replayed frame is a miss."""
+    if matched is None:
+        return 0, 0, False
+    branch, depth = matched
+    n_commit = min(depth - (load_frame - res_anchor), n_steps)
+    if n_commit <= 0:
+        return 0, 0, True
+    return int(branch), int(n_commit), False
+
+
+def plan_tick(
+    row, frame: int, load_frame, n_steps: int, res_anchor, res_frames: int,
+    matched, anchor: int, ring_depth: int, spec_on: bool = True,
+):
+    """One lane's tick, decided on the host: the commit, the burst
+    geometry and the next rollout, written into ``row`` as the
+    :class:`TickInts` scalars (the singleton's vector, lane ``i`` of the
+    server's ``[S, ...]`` array; the status rows are the caller's).
+
+    The lane is at ``frame`` and runs ``n_steps`` (save, advance) steps,
+    from ``load_frame`` when it rolls back. ``res_anchor`` / ``res_frames``
+    name the pending rollout the absorb phase may commit from (None: there
+    is none, or the lane does not tick) and ``matched`` is
+    :func:`match_pending`'s answer. ``anchor`` is where the next rollout
+    starts: the confirmed frame + 1, or, for a lane with no work
+    (``n_steps == 0``), its pending rollout's anchor again, so that the
+    wholesale swap of the branch buffers keeps it. Speculation is active
+    when ``spec_on`` and the anchor is inside the post-burst ring window;
+    an inactive lane still rolls out, discarded, from the live frontier.
+
+    Returns ``(absorb_branch, n_commit, missed, burst_load, burst_start,
+    n_tail, spec_active, spec_anchor, from_live)``."""
+    T = TickInts
+    start = frame if load_frame is None else load_frame
+    end = start + n_steps
+    branch, n_commit, missed = plan_commit(
+        matched, load_frame, res_anchor, n_steps
+    )
+    # After a partial commit only the unmatched tail resimulates, with no
+    # Load: the absorb phase positions the state.
+    if n_commit > 0:
+        burst_load, burst_start = None, load_frame + n_commit
+    else:
+        burst_load, burst_start = load_frame, start
+    n_tail = n_steps - n_commit
+    spec_active = bool(spec_on) and spec_in_window(anchor, end, ring_depth)
+    if spec_active:
+        spec_anchor, from_live = anchor, anchor == end
+    else:
+        spec_anchor, from_live = end, True
+    row[T.BRANCH] = branch
+    row[T.ABSORB_FIRST] = 0 if load_frame is None else load_frame
+    row[T.ABSORB_N] = n_commit
+    row[T.PREV_ANCHOR] = 0 if res_anchor is None else res_anchor
+    row[T.PREV_TOTAL] = 0 if res_anchor is None else res_frames
+    row[T.DO_LOAD] = burst_load is not None
+    row[T.LOAD_FRAME] = 0 if burst_load is None else burst_load
+    row[T.START_FRAME] = burst_start
+    row[T.N_BURST] = n_tail
+    row[T.SPEC_FROM_LIVE] = from_live
+    row[T.SPEC_ANCHOR] = spec_anchor
+    return (
+        branch, n_commit, missed, burst_load, burst_start, n_tail,
+        spec_active, spec_anchor, from_live,
+    )
+
+
+def plan_rollout(row, frame: int, anchor, ring_depth: int):
+    """The plan of a lane at ``frame`` with no work: a tick of no steps
+    with every phase gated off but the rollout from ``anchor``. A served
+    lane that does not tick passes its pending rollout's anchor (the lane
+    has not moved since, so the same live-or-ring source: bitwise the same
+    rollout, which the wholesale swap of the branch buffers would
+    otherwise lose), or None when it has none: a discarded rollout from
+    the live frontier. Nothing is absorbed, so the plan is told of no
+    pending rollout."""
+    return plan_tick(
+        row, frame, None, 0, None, 0, None,
+        frame if anchor is None else anchor, ring_depth,
+    )
+
+
+def account_rollback(
+    owner, load_frame: int, n_steps: int, branch: int, n_commit: int,
+    missed: bool, blame=(None, None), slot: Optional[int] = None,
+) -> str:
+    """Count one executed rollback of ``n_steps`` frames on ``owner`` (the
+    singleton runner or the served core: its totals, its ``metrics`` and
+    its ``ledger``) and return the outcome: ``full`` / ``partial`` when
+    ``n_commit`` frames came from ``branch`` of the pending rollout,
+    ``miss`` when the match reached no frame, ``unmatched`` when no branch
+    could be asked. Committed frames are recovered, never resimulated: only
+    the tail counts as ``rollback_frames``. ``slot`` labels the served
+    core's per-match counters and its ledger entry."""
+    n_tail = n_steps - n_commit
+    if n_commit > 0:
+        outcome = "partial" if n_tail else "full"
+    else:
+        outcome = "miss" if missed else "unmatched"
+        branch = None
+    metrics = owner.metrics
+    # The served core counts these per match too.
+    by_match = ["rollbacks"]
+    owner.rollbacks_total += 1
+    metrics.observe("rollback_depth", n_steps)
+    if n_commit > 0:
+        owner.rollback_frames_recovered_total += n_commit
+        metrics.count("rollback_frames_recovered", n_commit)
+    if outcome == "full":
+        owner.spec_hits += 1
+        by_match.append("spec_hits")
+    elif outcome == "partial":
+        owner.spec_partial_hits += 1
+        metrics.count("spec_partial_hits")
+    elif outcome == "miss":
+        owner.spec_misses += 1
+        by_match.append("spec_misses")
+    for name in by_match:
+        metrics.count(name)
+        if slot is not None:
+            metrics.count(name, labels={"match_slot": slot})
+    if n_tail:
+        owner.rollback_frames_total += n_tail
+        metrics.count("rollback_frames", n_tail)
+    owner.ledger.record(
+        outcome, depth=n_steps, frames_recovered=n_commit,
+        frames_resimulated=n_tail, branch=branch, rank=branch,
+        blame_player=blame[0], blame_frame=blame[1], slot=slot,
+        load_frame=load_frame,
+    )
+    return outcome
+
+
+def wanted_rows(session, first_frame: int, n_frames: int):
+    """``(row, frame)`` of the frames ``first_frame ..`` of one checksum
+    array that ``session`` wants reported (every frame when it does not
+    say)."""
+    wants = getattr(session, "wants_checksum", None)
+    return [
+        (t, first_frame + t) for t in range(n_frames)
+        if wants is None or wants(first_frame + t)
+    ]
 
 
 class PackedTick:
@@ -491,56 +708,34 @@ class FusedTickExecutor:
             out = self._absorb(carry, ints)
         return self.io.count("absorb", (carry, ints), out)
 
-    def run(
-        self,
-        carry,
-        branch: int,
-        absorb_first: int,
-        absorb_n: int,
-        prev_anchor: int,
-        prev_total: int,
-        load_frame: Optional[int],
-        start_frame: int,
-        bits,
-        status,
-        n_burst: int,
-        spec_anchor: int,
-        spec_from_live: bool,
-        branch_bits,
-    ):
+    def run(self, carry, ints, bits, status, branch_bits):
         """Pad the burst to ``burst_frames`` and dispatch the whole tick.
 
         ``carry`` is :meth:`pack`'s form of ``(ring, state, prev_rings,
-        prev_states)``, or what the last call returned. ``bits``/``status``
-        are host ``[n_burst, P, ...]`` arrays (the burst's (save, advance)
-        steps — always the standard pairing here; non-standard bursts take
-        the runner's generic path). ``branch_bits [B, F, P, ...]`` is the
-        next rollout's input tensor. The program is handed the carry and
-        three fresh NumPy arrays: the int32 vector of :class:`TickInts`
-        (every scalar and the padded status rows), the padded ``bits`` and
-        ``branch_bits``. Returns ``(carry, state, cs)`` — the next carry
-        (main ring, live state, this rollout's branch rings and states),
-        the live state as a ``WorldState`` and the checksums
-        (:meth:`cs_host`) — all device-resident, nothing synced.
+        prev_states)``, or what the last call returned. ``ints`` is a
+        :meth:`TickInts.zeros` row that :func:`plan_tick` filled.
+        ``bits``/``status`` are host ``[n_burst, P, ...]`` arrays (the
+        burst's (save, advance) steps — always the standard pairing here;
+        non-standard bursts take the runner's generic path).
+        ``branch_bits [B, F, P, ...]`` is the next rollout's input tensor.
+        The program is handed the carry and three fresh NumPy arrays:
+        ``ints`` with the status rows written behind its scalars, the
+        padded ``bits`` and ``branch_bits``. Returns ``(carry, state, cs)``
+        — the next carry (main ring, live state, this rollout's branch
+        rings and states), the live state as a ``WorldState`` and the
+        checksums (:meth:`cs_host`) — all device-resident, nothing synced.
         """
         with self.span("tick_stage_args"):
-            args = self._stage_args(
-                branch, absorb_first, absorb_n, prev_anchor, prev_total,
-                load_frame, start_frame, bits, status, n_burst,
-                spec_anchor, spec_from_live, branch_bits,
-            )
+            args = self._stage_args(ints, bits, status, branch_bits)
         with self.span("tick_enqueue", program="fused"):
             out = self._fn(carry, *args)
         return self.io.count("fused", (carry, args), out)
 
-    def _stage_args(
-        self, branch, absorb_first, absorb_n, prev_anchor, prev_total,
-        load_frame, start_frame, bits, status, n_burst,
-        spec_anchor, spec_from_live, branch_bits,
-    ) -> tuple:
+    def _stage_args(self, ints, bits, status, branch_bits) -> tuple:
         """The three host arrays of the fused program: plain NumPy, which
         jit's C++ path transfers while it shards the arguments."""
         MF = self.burst_frames
+        n_burst = int(ints[TickInts.N_BURST])
         if n_burst > MF:
             raise ValueError(f"burst of {n_burst} frames exceeds {MF}")
         bb = np.ascontiguousarray(branch_bits)
@@ -549,19 +744,8 @@ class FusedTickExecutor:
                 f"branch_bits {bb.shape[:2]} != "
                 f"({self.num_branches}, {self.spec_frames})"
             )
-        P = bb.shape[2]
-        T = TickInts
-        ints = np.zeros(T.STATUS + MF * P, np.int32)
-        do_load = load_frame is not None
-        ints[:T.STATUS] = (
-            branch, absorb_first, absorb_n, prev_anchor, prev_total,
-            do_load, load_frame if do_load else 0, start_frame, n_burst,
-            bool(spec_from_live), spec_anchor,
-        )
         padded = np.zeros((MF,) + bb.shape[2:], bb.dtype)
         if n_burst:
             padded[:n_burst] = bits
-            ints[T.STATUS:T.STATUS + n_burst * P] = np.asarray(
-                status, np.int32
-            ).reshape(-1)
+            TickInts.status(ints, MF, bb.shape[2])[:n_burst] = status
         return ints, padded, bb

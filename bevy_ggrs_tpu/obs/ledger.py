@@ -81,6 +81,26 @@ def blame_divergence(predicted, corrected) -> Optional[Tuple[int, int]]:
     return int(j), int(p)
 
 
+def rollback_blame(
+    ledger, matched, branch_bits, anchor: int, load_frame: int, steps
+):
+    """``(blame_player, blame_frame)`` for ``ledger``'s entry of a rollback
+    to ``load_frame`` whose corrected ``steps`` (each ``.adv.bits [P,
+    ...]``) were ``matched`` against the rollout ``branch_bits [B, F, P,
+    ...]`` from frame ``anchor``: :func:`blame_divergence` against branch 0
+    over the frames both cover, the frame made absolute. Pure NumPy on the
+    host-resident branch tensor. ``(None, None)`` when the ledger is off
+    (nothing is gathered), when no branch was asked (``matched is None``)
+    or when branch 0 agreed."""
+    if matched is None or not ledger.enabled:
+        return None, None
+    b0 = np.asarray(branch_bits)[0]
+    hit = blame_divergence(
+        b0[load_frame - anchor:], [np.asarray(s.adv.bits) for s in steps]
+    )
+    return (None, None) if hit is None else (hit[1], load_frame + hit[0])
+
+
 class SpeculationLedger:
     """Bounded per-rollback entry ring + persistent aggregate totals.
 

@@ -61,13 +61,21 @@ Design rules that make the batch shape static (one executable, ever):
   (tests/test_batched_sessions.py) compares state bytes, frames and ring
   contents, which is the contract that matters.
 
-Host-side per-slot speculation (branch build, match, input log) reuses the
-singleton implementation verbatim: the native builder is instantiated per
-slot (it owns a per-match C++ input-log mirror) and the pure-Python
-fallback borrows :class:`~bevy_ggrs_tpu.spec_runner.
-SpeculativeRollbackRunner`'s tree-builder methods unbound through
-:class:`_SlotSpecShim` — bit-identical trees by construction, no forked
-logic to drift.
+What a lane's tick IS is decided in ``fused.py``, by the functions the
+singleton's ``tick()`` calls: ``match_pending`` (which branch of the
+pending rollout a rollback follows; the native plane stages the same match
+for all lanes in one C call and hands its answer over in the same form),
+``plan_tick`` (the commit, the burst geometry, the next rollout: the one
+writer of a lane's :class:`~bevy_ggrs_tpu.fused.TickInts` scalars, a no-op
+lane's included) and, once the dispatch is made, ``account_rollback`` (the
+counters, the outcome, the ledger entry). Both host paths here only stage a
+lane's inputs for them and its burst rows behind them.
+
+Per-slot tree building reuses the singleton implementation verbatim: the
+native builder is instantiated per slot (it owns a per-match C++ input-log
+mirror) and the pure-Python fallback borrows :class:`~bevy_ggrs_tpu.
+spec_runner.SpeculativeRollbackRunner`'s tree-builder methods unbound
+through :class:`_SlotSpecShim` — bit-identical trees by construction.
 """
 
 from __future__ import annotations
@@ -80,11 +88,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bevy_ggrs_tpu.fused import IoBuffers, PackedTick, TickInts
+from bevy_ggrs_tpu.fused import (
+    IoBuffers,
+    PackedTick,
+    TickInts,
+    account_rollback,
+    match_pending,
+    plan_rollout,
+    plan_tick,
+    spec_in_window,
+    wanted_rows,
+)
 from bevy_ggrs_tpu.native import spec as native_spec
-from bevy_ggrs_tpu.obs.ledger import blame_divergence
+from bevy_ggrs_tpu.obs.ledger import rollback_blame
 from bevy_ggrs_tpu.obs.trace import NULL_SPAN, Instrumented
-from bevy_ggrs_tpu.parallel.speculate import match_branch
 from bevy_ggrs_tpu.predict.batch import BatchedRanker
 from bevy_ggrs_tpu.predict.model import resolve_predictor
 from bevy_ggrs_tpu.runner import RollbackRunner, _Step
@@ -263,7 +280,7 @@ class _Slot:
 
     __slots__ = (
         "index", "active", "frame", "spec_on", "native", "input_log",
-        "shim", "res_anchor", "res_bits", "res_from_live",
+        "shim", "res_anchor", "res_bits",
     )
 
     def __init__(self, index: int):
@@ -276,7 +293,6 @@ class _Slot:
         self.shim: Optional[_SlotSpecShim] = None
         self.res_anchor: Optional[int] = None
         self.res_bits: Optional[np.ndarray] = None
-        self.res_from_live = True
 
 
 class BatchedSessionCore(Instrumented):
@@ -604,7 +620,6 @@ class BatchedSessionCore(Instrumented):
         s.spec_on = bool(spec_on if ticket is None else ticket.spec_on)
         s.res_anchor = None
         s.res_bits = None
-        s.res_from_live = True
         s.native = native_spec.make_spec_builder(
             self.input_spec, self.num_players, self.num_branches,
             self.spec_frames, self._branch_values,
@@ -807,23 +822,30 @@ class BatchedSessionCore(Instrumented):
         every other slot no-ops (and, if it has a pending rollout, replays
         it bitwise so the wholesale prev-buffer swap preserves it).
 
-        Routes to the native batch plane when it loaded (ONE C call for
-        the per-slot host work, :meth:`_dispatch_native`) or the per-slot
-        Python loop (:meth:`_dispatch_python`) — bitwise identical paths,
-        property-tested in tests/test_native_batch.py.
+        The host work is staged by the native batch plane when it loaded
+        (ONE C call for the per-slot work, :meth:`_dispatch_native`) or by
+        the per-slot Python loop (:meth:`_dispatch_python`) — bitwise
+        identical paths, property-tested in tests/test_native_batch.py.
+        Either returns the three host arrays and the post pass's rows, and
+        the device call is made from here: the staging frame is gone by
+        then, so the first call's trace does not run deeper for its locals
+        (``PERF.md`` §6, PR 29: 5 s of warm-up hung on that).
 
         Atomic on fault: segments are re-validated in a pre-pass (direct
         callers may bypass :meth:`tick`), so a raise can only happen before
         the first input-log write or device dispatch — a sibling slot's
         next-tick output is bitwise unaffected by another slot faulting."""
-        if self._plane is not None:
-            return self._dispatch_native(batch)
-        return self._dispatch_python(batch)
+        stage = (
+            self._dispatch_native if self._plane is not None
+            else self._dispatch_python
+        )
+        self._finish_dispatch(*stage(batch))
 
-    def _dispatch_python(self, batch: Dict[int, tuple]) -> None:
+    def _dispatch_python(self, batch: Dict[int, tuple]) -> tuple:
         """The per-slot host loop (the ``GGRS_NO_NATIVE=1`` reference
         path): log writes, branch matches, window gather and tree builds
-        all run per slot in Python."""
+        all run per slot in Python. Returns :meth:`_finish_dispatch`'s
+        arguments."""
         S, B, F, MF = (
             self.num_slots, self.num_branches, self.spec_frames,
             self.burst_frames,
@@ -831,11 +853,8 @@ class BatchedSessionCore(Instrumented):
         P = self.num_players
         for i, (load_frame, steps, _confirmed, _session) in batch.items():
             self._validate_segment(i, self.slots[i].frame, load_frame, steps)
-        (
-            ints_a, bits_a, bb_a, branch_a, absorb_first_a, absorb_n_a,
-            prev_anchor_a, prev_total_a, do_load_a, load_frame_a,
-            start_frame_a, n_burst_a, from_live_a, spec_anchor_a, status_a,
-        ) = self._host_args()
+        ints_a, bits_a, bb_a = self._host_args()
+        status_a = TickInts.status(ints_a, MF, P)
         # post[slot] -> state updates applied after the dispatch succeeds
         post: Dict[int, tuple] = {}
         reports: List[tuple] = []
@@ -860,9 +879,8 @@ class BatchedSessionCore(Instrumented):
                 # which this very burst may advance).
                 for t, st in enumerate(steps):
                     s.input_log[start + t] = np.asarray(st.adv.bits)
-                spec_active = (
-                    s.spec_on and anchor <= end
-                    and anchor > end - self.ring_depth
+                spec_active = s.spec_on and spec_in_window(
+                    anchor, end, self.ring_depth
                 )
                 geom[i] = (start, end, anchor, spec_active)
             seeds: Dict[int, object] = {}
@@ -909,115 +927,40 @@ class BatchedSessionCore(Instrumented):
                 if i not in batch:
                     # No-op lane: every phase gated off; replay the pending
                     # rollout (if any) so the prev-buffer swap keeps it valid.
-                    start_frame_a[i] = s.frame
-                    if s.res_anchor is not None:
-                        spec_anchor_a[i] = s.res_anchor
-                        from_live_a[i] = s.res_from_live
-                        bb_a[i] = s.res_bits
-                    else:
-                        spec_anchor_a[i] = s.frame
-                    continue
-                requests_seg = batch[i]
-                load_frame, steps, confirmed, session = requests_seg
-                start, end, anchor, spec_active = geom[i]
-                n_steps = len(steps)
-                # Branch-commit decision (host-side, zero device syncs).
-                absorb_branch, n_commit = 0, 0
-                missed = False
-                blame_player = blame_frame = None
-                if (
-                    load_frame is not None
-                    and s.res_anchor is not None
-                    and load_frame >= s.res_anchor
-                ):
-                    steps_arr = np.stack(
-                        [np.asarray(st.adv.bits) for st in steps]
+                    plan_rollout(
+                        ints_a[i], s.frame, s.res_anchor, self.ring_depth
                     )
-                    matched = None
-                    if s.native is not None:
-                        matched = s.native.match(
-                            s.res_bits, s.res_anchor, load_frame, steps_arr, F
-                        )
-                    else:
-                        needed = []
-                        complete = True
-                        for f in range(s.res_anchor, load_frame):
-                            got = s.input_log.get(f)
-                            if got is None:
-                                complete = False
-                                break
-                            needed.append(got)
-                        if complete:
-                            needed.extend(steps_arr)
-                            matched = match_branch(
-                                s.res_bits, np.stack(needed)[:F]
-                            )
-                    if matched is not None:
-                        br, depth = matched
-                        nc = min(depth - (load_frame - s.res_anchor), n_steps)
-                        if nc > 0:
-                            absorb_branch, n_commit = int(br), int(nc)
-                        else:
-                            missed = True
-                            self.spec_misses += 1
-                            self.metrics.count("spec_misses")
-                            self.metrics.count(
-                                "spec_misses", labels={"match_slot": i}
-                            )
-                        if self.ledger.enabled:
-                            # Blame: first corrected input diverging from the
-                            # branch-0 prediction rows (pure NumPy on the
-                            # host-resident branch tensor).
-                            pre = load_frame - s.res_anchor
-                            k = min(n_steps, F - pre)
-                            if k > 0:
-                                div = blame_divergence(
-                                    np.asarray(s.res_bits)[0][pre:pre + k],
-                                    steps_arr[:k],
-                                )
-                                if div is not None:
-                                    blame_player = div[1]
-                                    blame_frame = load_frame + div[0]
-                # The next rollout. Speculation is active only when the anchor
-                # lies inside the post-burst ring window (precomputed in pass
-                # 1); otherwise the lane still computes a (discarded) rollout
-                # from the live frontier.
-                if spec_active:
-                    bb = trees[i]
-                    spec_anchor, from_live = anchor, (anchor == end)
-                else:
-                    bb = self._zero_bb
-                    spec_anchor, from_live = end, True
-                # Burst assembly: after a partial commit only the unmatched
-                # tail resimulates, absorb having positioned the state.
-                tail = steps[n_commit:]
-                if n_commit > 0:
-                    burst_load, burst_start = None, load_frame + n_commit
-                else:
-                    burst_load, burst_start = load_frame, start
-                branch_a[i] = absorb_branch
-                absorb_first_a[i] = load_frame if load_frame is not None else 0
-                absorb_n_a[i] = n_commit
-                prev_anchor_a[i] = s.res_anchor or 0
-                prev_total_a[i] = F if s.res_anchor is not None else 0
-                do_load_a[i] = burst_load is not None
-                load_frame_a[i] = burst_load if burst_load is not None else 0
-                start_frame_a[i] = burst_start
-                n_tail = len(tail)
-                n_burst_a[i] = n_tail
-                for t, st in enumerate(tail):
+                    if s.res_anchor is not None:
+                        bb_a[i] = s.res_bits
+                    continue
+                load_frame, steps, _confirmed, session = batch[i]
+                _start, end, anchor, spec_active = geom[i]
+                # The plan (host-side, zero device syncs).
+                matched = match_pending(
+                    s.native, s.input_log, s.res_bits, s.res_anchor, F,
+                    load_frame, steps,
+                )
+                plan = plan_tick(
+                    ints_a[i], s.frame, load_frame, len(steps), s.res_anchor,
+                    F, matched, anchor, self.ring_depth, s.spec_on,
+                )
+                n_commit = plan[1]
+                for t, st in enumerate(steps[n_commit:]):
                     bits_a[i, t] = np.asarray(st.adv.bits)
                     status_a[i, t] = np.asarray(st.adv.status, np.int32)
-                spec_anchor_a[i] = spec_anchor
-                from_live_a[i] = from_live
-                bb_a[i] = bb
-                # bb is per-call fresh from both builders, so storing it for
-                # the replay/match path needs no defensive copy.
+                # An inactive lane's rollout (from the live frontier) is
+                # discarded: its row stays zeros. bb is per-call fresh from
+                # both builders, so storing it for the replay/match path
+                # needs no defensive copy.
+                bb = trees[i] if spec_active else None
+                if bb is not None:
+                    bb_a[i] = bb
+                blame = rollback_blame(
+                    self.ledger, matched, s.res_bits, s.res_anchor,
+                    load_frame, steps,
+                )
                 post[i] = (
-                    end, spec_active, anchor if spec_active else None,
-                    bb if spec_active else None,
-                    from_live, load_frame, n_commit, n_steps, burst_start,
-                    n_tail, session, missed, blame_player, blame_frame,
+                    end, load_frame, len(steps), session, bb, plan, blame,
                 )
 
         if sp_loop is not NULL_SPAN:
@@ -1030,33 +973,23 @@ class BatchedSessionCore(Instrumented):
             self.timeseries.observe("serve_branch_build_ms", bb_ms)
             self.timeseries.observe("serve_arg_assembly_ms", arg_ms)
 
-        self._finish_dispatch((ints_a, bits_a, bb_a), post, reports)
+        return (ints_a, bits_a, bb_a), post, reports
 
     def _host_args(self) -> tuple:
         """The three host arrays of one dispatch (``BatchedTickExecutor.
-        run``), all lanes no-op'd, and a column view a scalar into the
-        int32 one. Fresh per dispatch (NOT reused buffers): the previous
-        dispatch's ``branch_bits`` rows live on as the slots' in-flight
-        trees (``res_bits`` views) until the post pass replaces them, and
-        the jit argument transfer may still read all three
-        asynchronously."""
+        run``), zeroed: ``plan_tick`` writes every lane's scalars. Fresh per
+        dispatch (NOT reused buffers): the previous dispatch's
+        ``branch_bits`` rows live on as the slots' in-flight trees
+        (``res_bits`` views) until the post pass replaces them, and the jit
+        argument transfer may still read all three asynchronously."""
         S, B, F, MF = (
             self.num_slots, self.num_branches, self.spec_frames,
             self.burst_frames,
         )
-        T, P = TickInts, self.num_players
-        ints = np.zeros((S, T.STATUS + MF * P), np.int32)
-        ints[:, T.SPEC_FROM_LIVE] = 1
-        col = lambda k: ints[:, k]
         return (
-            ints,
+            TickInts.zeros(MF, self.num_players, (S,)),
             np.zeros((S, MF) + self._zero.shape, self._zero.dtype),
             np.zeros((S, B, F) + self._zero.shape, self._zero.dtype),
-            col(T.BRANCH), col(T.ABSORB_FIRST), col(T.ABSORB_N),
-            col(T.PREV_ANCHOR), col(T.PREV_TOTAL), col(T.DO_LOAD),
-            col(T.LOAD_FRAME), col(T.START_FRAME), col(T.N_BURST),
-            col(T.SPEC_FROM_LIVE), col(T.SPEC_ANCHOR),
-            ints[:, T.STATUS:].reshape(S, MF, P),
         )
 
     def _finish_dispatch(
@@ -1065,9 +998,11 @@ class BatchedSessionCore(Instrumented):
     ) -> None:
         """The device dispatch + post-dispatch bookkeeping shared by both
         host paths (per-slot Python loop and native batch plane): run the
-        batched tick, then apply frame counters, rollout metadata,
-        hit/miss counters, ledger entries and deferred checksum rows."""
-        branch_a = jit_args[0][:, TickInts.BRANCH]
+        batched tick, then apply each ticked lane's plan: frame counter,
+        rollout metadata, the rollback's accounting
+        (``fused.account_rollback``) and deferred checksum rows.
+        ``post[slot]`` is ``(end, load_frame, n_steps, session, the lane's
+        next in-flight tree or None, its plan, its blame)``."""
         self.device_dispatches_total += 1
         self.burst_step_slots_total += self.num_slots * self.burst_frames
         dev = (
@@ -1083,15 +1018,16 @@ class BatchedSessionCore(Instrumented):
         self.metrics.observe("tick_io_buffers", self._exec.io.last)
 
         for i, (
-            end, spec_active, res_anchor, res_bits, from_live, load_frame,
-            n_commit, n_steps, burst_start, n_tail, session, missed,
-            blame_player, blame_frame,
+            end, load_frame, n_steps, session, res_bits, plan, blame,
         ) in post.items():
+            (
+                branch, n_commit, missed, _, burst_start, n_tail,
+                spec_active, spec_anchor, _,
+            ) = plan
             s = self.slots[i]
             s.frame = end
             if spec_active:
-                s.res_anchor, s.res_bits = res_anchor, res_bits
-                s.res_from_live = from_live
+                s.res_anchor, s.res_bits = spec_anchor, res_bits
                 # A fresh rollout dispatched for this slot: B×F
                 # speculative device frames. (No-op lane replays are NOT
                 # charged — they are an artifact of the wholesale
@@ -1101,73 +1037,38 @@ class BatchedSessionCore(Instrumented):
                 )
             else:
                 s.res_anchor, s.res_bits = None, None
-            lab = {"match_slot": i}
             self.burst_steps_total += n_steps
             self.metrics.count("frames_advanced", n_steps)
-            self.metrics.count("frames_advanced", n_steps, labels=lab)
+            self.metrics.count(
+                "frames_advanced", n_steps, labels={"match_slot": i}
+            )
             if load_frame is not None:
-                self.rollbacks_total += 1
-                self.metrics.count("rollbacks")
-                self.metrics.count("rollbacks", labels=lab)
-                self.metrics.observe("rollback_depth", n_steps)
-                outcome = (
-                    ("full" if n_commit == n_steps else "partial")
-                    if n_commit > 0
-                    else ("miss" if missed else "unmatched")
+                account_rollback(
+                    self, load_frame, n_steps, branch, n_commit, missed,
+                    blame, slot=i,
                 )
-                self.ledger.record(
-                    outcome, depth=n_steps, frames_recovered=n_commit,
-                    frames_resimulated=n_steps - n_commit,
-                    branch=branch_a[i] if n_commit > 0 else None,
-                    rank=branch_a[i] if n_commit > 0 else None,
-                    blame_player=blame_player, blame_frame=blame_frame,
-                    slot=i, load_frame=load_frame,
-                )
-                if n_commit > 0:
-                    self.rollback_frames_recovered_total += n_commit
-                    self.metrics.count("rollback_frames_recovered", n_commit)
-                    if n_commit == n_steps:
-                        self.spec_hits += 1
-                        self.metrics.count("spec_hits")
-                        self.metrics.count("spec_hits", labels=lab)
-                    else:
-                        self.spec_partial_hits += 1
-                        self.metrics.count("spec_partial_hits")
-                        self.rollback_frames_total += n_tail
-                        self.metrics.count("rollback_frames", n_tail)
-                else:
-                    self.rollback_frames_total += n_steps
-                    self.metrics.count("rollback_frames", n_steps)
             if session is not None and self.report_checksums:
-                wants = getattr(session, "wants_checksum", None)
-                rows_a = [
-                    (i, t, load_frame + t) for t in range(n_commit)
-                    if wants is None or wants(load_frame + t)
-                ]
-                rows_b = [
-                    (i, t, burst_start + t) for t in range(n_tail)
-                    if wants is None or wants(burst_start + t)
-                ]
-                if rows_a:
-                    reports.append(
-                        (cs, 0, [r + (session,) for r in rows_a])
-                    )
-                if rows_b:
-                    reports.append(
-                        (cs, 1, [r + (session,) for r in rows_b])
-                    )
+                for part, first, n in (
+                    (0, load_frame, n_commit), (1, burst_start, n_tail)
+                ):
+                    rows = wanted_rows(session, first, n)
+                    if rows:
+                        reports.append(
+                            (cs, part, [(i,) + r + (session,) for r in rows])
+                        )
             self._gc_log(s)
         self._pending_reports.extend(reports)
 
-    def _dispatch_native(self, batch: Dict[int, tuple]) -> None:
-        """One vmapped dispatch with the per-slot host loop consolidated
+    def _dispatch_native(self, batch: Dict[int, tuple]) -> tuple:
+        """One vmapped dispatch's per-slot host loop consolidated
         into the two batch-plane calls: ``ggrs_batch_stage`` lands every
         slot's as-used log rows, in-flight tree match and predictor
         window gather in ONE C call before the commit decisions, and
         ``ggrs_batch_build`` runs every seeded tree build plus the no-op
         lanes' tree re-use copies straight into the dispatch's jit
         argument buffer. Bitwise identical to :meth:`_dispatch_python`
-        (the C side loops over the same per-slot primitives)."""
+        (the C side loops over the same per-slot primitives), and returns
+        the same."""
         plane = self._plane
         S, B, F, MF = (
             self.num_slots, self.num_branches, self.spec_frames,
@@ -1176,11 +1077,8 @@ class BatchedSessionCore(Instrumented):
         P = self.num_players
         for i, (load_frame, steps, _confirmed, _session) in batch.items():
             self._validate_segment(i, self.slots[i].frame, load_frame, steps)
-        (
-            ints_a, bits_a, bb_a, branch_a, absorb_first_a, absorb_n_a,
-            prev_anchor_a, prev_total_a, do_load_a, load_frame_a,
-            start_frame_a, n_burst_a, from_live_a, spec_anchor_a, status_a,
-        ) = self._host_args()
+        ints_a, bits_a, bb_a = self._host_args()
+        status_a = TickInts.status(ints_a, MF, P)
         post: Dict[int, tuple] = {}
         reports: List[tuple] = []
 
@@ -1222,9 +1120,8 @@ class BatchedSessionCore(Instrumented):
                     plane.res_anchors[i] = s.res_anchor
                     plane.load_frames[i] = load_frame
                     plane.set_res(i, s.res_bits)
-                spec_active = (
-                    s.spec_on and anchor <= end
-                    and anchor > end - self.ring_depth
+                spec_active = s.spec_on and spec_in_window(
+                    anchor, end, self.ring_depth
                 )
                 if self._ranker is not None and spec_active:
                     plane.win_mask[i] = 1
@@ -1268,46 +1165,42 @@ class BatchedSessionCore(Instrumented):
             for s in self.slots:
                 i = s.index
                 if i not in batch:
-                    start_frame_a[i] = s.frame
+                    plan_rollout(
+                        ints_a[i], s.frame, s.res_anchor, self.ring_depth
+                    )
                     if s.res_anchor is not None:
-                        spec_anchor_a[i] = s.res_anchor
-                        from_live_a[i] = s.res_from_live
                         plane.copy_mask[i] = 1
                         plane.set_res(i, s.res_bits)
-                    else:
-                        spec_anchor_a[i] = s.frame
                     continue
-                load_frame, steps, confirmed, session = batch[i]
-                start, end, anchor, spec_active = geom[i]
+                load_frame, steps, _confirmed, session = batch[i]
+                _start, end, anchor, spec_active = geom[i]
                 n_steps = len(steps)
-                absorb_branch, n_commit = 0, 0
-                missed = False
-                blame_player = blame_frame = None
-                if plane.match_mask[i]:
-                    br = int(plane.out_branch[i])
-                    if br >= 0:  # -1 = as-used log gap (the Python no-match)
-                        depth = int(plane.out_depth[i])
-                        nc = min(depth - (load_frame - s.res_anchor), n_steps)
-                        if nc > 0:
-                            absorb_branch, n_commit = br, int(nc)
-                        else:
-                            missed = True
-                            self.spec_misses += 1
-                            self.metrics.count("spec_misses")
-                            self.metrics.count(
-                                "spec_misses", labels={"match_slot": i}
-                            )
-                        if self.ledger.enabled:
-                            pre = load_frame - s.res_anchor
-                            k = min(n_steps, F - pre)
-                            if k > 0:
-                                div = blame_divergence(
-                                    np.asarray(s.res_bits)[0][pre:pre + k],
-                                    plane.steps[i, :k],
-                                )
-                                if div is not None:
-                                    blame_player = div[1]
-                                    blame_frame = load_frame + div[0]
+                # The staged match, as ``match_pending`` answers: -1 = a
+                # gap in the as-used log.
+                matched = None
+                if plane.match_mask[i] and plane.out_branch[i] >= 0:
+                    matched = (
+                        int(plane.out_branch[i]), int(plane.out_depth[i])
+                    )
+                plan = plan_tick(
+                    ints_a[i], s.frame, load_frame, n_steps, s.res_anchor, F,
+                    matched, anchor, self.ring_depth, s.spec_on,
+                )
+                n_commit, n_tail = plan[1], plan[5]
+                if n_tail:
+                    bits_a[i, :n_tail] = plane.steps[i, n_commit:n_steps]
+                    status_a[i, :n_tail] = plane.status[i, n_commit:n_steps]
+                blame = rollback_blame(
+                    self.ledger, matched, s.res_bits, s.res_anchor,
+                    load_frame, steps,
+                )
+                # The slot's next in-flight tree is its bb_a row, written by
+                # the build call below — the view is stored now, the bytes
+                # land before the device dispatch reads them.
+                post[i] = (
+                    end, load_frame, n_steps, session,
+                    bb_a[i] if spec_active else None, plan, blame,
+                )
                 if spec_active:
                     n_build += 1
                     plane.build_mask[i] = 1
@@ -1329,37 +1222,6 @@ class BatchedSessionCore(Instrumented):
                         plane.known[i] = known
                         plane.kmask[i] = kmask
                         dirty_known.append(i)
-                    spec_anchor, from_live = anchor, (anchor == end)
-                else:
-                    spec_anchor, from_live = end, True
-                if n_commit > 0:
-                    burst_load, burst_start = None, load_frame + n_commit
-                else:
-                    burst_load, burst_start = load_frame, start
-                branch_a[i] = absorb_branch
-                absorb_first_a[i] = load_frame if load_frame is not None else 0
-                absorb_n_a[i] = n_commit
-                prev_anchor_a[i] = s.res_anchor or 0
-                prev_total_a[i] = F if s.res_anchor is not None else 0
-                do_load_a[i] = burst_load is not None
-                load_frame_a[i] = burst_load if burst_load is not None else 0
-                start_frame_a[i] = burst_start
-                n_tail = n_steps - n_commit
-                n_burst_a[i] = n_tail
-                if n_tail:
-                    bits_a[i, :n_tail] = plane.steps[i, n_commit:n_steps]
-                    status_a[i, :n_tail] = plane.status[i, n_commit:n_steps]
-                spec_anchor_a[i] = spec_anchor
-                from_live_a[i] = from_live
-                # The slot's next in-flight tree is its bb_a row, written by
-                # the build call below — the view is stored now, the bytes
-                # land before the device dispatch reads them.
-                post[i] = (
-                    end, spec_active, anchor if spec_active else None,
-                    bb_a[i] if spec_active else None,
-                    from_live, load_frame, n_commit, n_steps, burst_start,
-                    n_tail, session, missed, blame_player, blame_frame,
-                )
             with self.span(
                 "serve_native_batch", series=False, call="build",
                 slots=n_build,
@@ -1385,7 +1247,7 @@ class BatchedSessionCore(Instrumented):
             self.timeseries.observe("serve_arg_assembly_ms", arg_ms)
             self.timeseries.observe("native_batch_ms", nb_ms)
 
-        self._finish_dispatch((ints_a, bits_a, bb_a), post, reports)
+        return (ints_a, bits_a, bb_a), post, reports
 
     def _gc_log(self, s: _Slot) -> None:
         horizon = s.frame - self.ring_depth - 64

@@ -48,15 +48,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bevy_ggrs_tpu.fused import FusedTickExecutor, absorb_branch_frames
+from bevy_ggrs_tpu.fused import (
+    FusedTickExecutor,
+    TickInts,
+    absorb_branch_frames,
+    account_rollback,
+    match_pending,
+    plan_commit,
+    plan_rollout,
+    plan_tick,
+    spec_in_window,
+    wanted_rows,
+)
 from bevy_ggrs_tpu.native import spec as native_spec
-from bevy_ggrs_tpu.obs.ledger import blame_divergence
+from bevy_ggrs_tpu.obs.ledger import rollback_blame
 from bevy_ggrs_tpu.predict.model import resolve_predictor
 from bevy_ggrs_tpu.parallel.speculate import (
     SpecResult,
     SpeculativeExecutor,
     enumerate_branches,
-    match_branch,
 )
 from bevy_ggrs_tpu.runner import RollbackRunner, _Step
 from bevy_ggrs_tpu.schedule import Schedule
@@ -983,6 +993,16 @@ class SpeculativeRollbackRunner(RollbackRunner):
         bursts, ticks whose speculation is skipped or disabled) falls back
         to exactly that legacy pair.
 
+        What the tick does is decided on the host by ``fused.py``'s plan,
+        shared with the served tiers (``serve/batch.py``):
+        ``match_pending`` matches a rollback against the pending rollout,
+        ``plan_tick`` turns the match into the commit, the burst geometry
+        and the next rollout and writes the program's ``TickInts`` row, and
+        ``account_rollback`` counts the outcome. The singleton's own choice
+        is the program: a plan that commits every replayed frame runs the
+        absorb-only program on the row's first five values, any other the
+        fused tick.
+
         Checksum reports from the fused paths are DEFERRED one tick:
         wanted checksums queue as device arrays and are read at the start
         of the next tick, by which time the producing program has
@@ -1026,7 +1046,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
         # confirmed, anchor aged out of the ring) run the plain serial
         # executable instead — the fused program would pay the B-branch
         # rollout for nothing.
-        if anchor > end or anchor <= end - self._ring_depth:
+        if not spec_in_window(anchor, end, self._ring_depth):
             self.handle_requests(requests, session)
             self.speculate(confirmed_frame, session)  # records skip reason
             return
@@ -1035,60 +1055,27 @@ class SpeculativeRollbackRunner(RollbackRunner):
         # burst advances. (Idempotent with the fallback paths' logging.)
         for t, s in enumerate(steps):
             self._input_log[start + t] = np.asarray(s.adv.bits)
-        # Branch-commit decision FIRST (host-side, zero device syncs: the
-        # branch tensor was built on the host last tick): a FULL hit takes
-        # the cheapest possible path — one absorb-only dispatch, nothing
-        # else.
+        # The plan FIRST (host-side, zero device syncs: the branch tensor
+        # was built on the host last tick): a FULL hit takes the cheapest
+        # possible path — one absorb-only dispatch, nothing else.
         res = self._result
-        absorb_branch, n_commit = 0, 0
-        missed = False
-        blame_player = blame_frame = None
-        if (
-            load_frame is not None
-            and res is not None
-            and load_frame >= res.start_frame
-        ):
-            matched = None
-            if self._native is not None:
-                # Native corrected-history match: the pre-span as-used
-                # inputs come from the builder's log mirror — no per-frame
-                # Python assembly. None = log gap (the Python
-                # complete=False), which charges no miss.
-                steps_arr = np.stack([np.asarray(s.adv.bits) for s in steps])
-                with self.span("match_branch"):
-                    matched = self._native.match(
-                        np.asarray(res.branch_bits), res.start_frame,
-                        load_frame, steps_arr, res.num_frames,
-                    )
-            else:
-                needed = []
-                complete = True
-                for f in range(res.start_frame, load_frame):
-                    got = self._input_log.get(f)
-                    if got is None:
-                        complete = False
-                        break
-                    needed.append(got)
-                if complete:
-                    needed.extend(np.asarray(s.adv.bits) for s in steps)
-                    needed_arr = np.stack(needed)[: res.num_frames]
-                    with self.span("match_branch"):
-                        matched = match_branch(
-                            np.asarray(res.branch_bits), needed_arr
-                        )
-            if matched is not None:
-                branch, depth = matched
-                nc = min(depth - (load_frame - res.start_frame), n_steps)
-                if nc > 0:
-                    absorb_branch, n_commit = int(branch), int(nc)
-                else:
-                    missed = True
-                    self.spec_misses += 1
-                    self.metrics.count("spec_misses")
-                if self.ledger.enabled:
-                    blame_player, blame_frame = self._ledger_blame(
-                        res, load_frame, steps
-                    )
+        res_bits, res_anchor, res_frames = (
+            (None, None, 0) if res is None
+            else (res.branch_bits, res.start_frame, res.num_frames)
+        )
+        matched = match_pending(
+            self._native, self._input_log, res_bits, res_anchor, res_frames,
+            load_frame, steps, span=self.span,
+        )
+        blame = rollback_blame(
+            self.ledger, matched, res_bits, res_anchor, load_frame, steps
+        )
+        ints = TickInts.zeros(self._fused.burst_frames, self.num_players)
+        plan = plan_tick(
+            ints, self.frame, load_frame, n_steps, res_anchor, res_frames,
+            matched, anchor, self._ring_depth,
+        )
+        absorb_branch, n_commit, missed, _, burst_start, n_tail = plan[:6]
         if n_commit == n_steps and n_commit > 0:
             # FULL hit: the corrected frames were precomputed — ONE
             # absorb-only dispatch (pure copies, no schedule execution)
@@ -1098,117 +1085,61 @@ class SpeculativeRollbackRunner(RollbackRunner):
             # is dispatched: the pending one remains valid — a later
             # rollback prefix-matches it through the as-used input log,
             # and the next steady tick refreshes it fused with its burst.
-            self._commit_full_hit(
-                load_frame, n_commit, absorb_branch, res, steps, session
-            )
-            self.ledger.record(
-                "full", depth=n_steps, frames_recovered=n_commit,
-                branch=absorb_branch, rank=absorb_branch,
-                blame_player=blame_player, blame_frame=blame_frame,
-                load_frame=load_frame,
-            )
-            self._gc_log()
-            return
-        with self.span("spec_tree_build", anchor=anchor):
-            # Dedup-skip STEADY ticks only: a rollback tick already ran
-            # (and charged) the branch match above — delegating it to the
-            # legacy path would re-run the match and double-count
-            # spec_misses; re-dispatching its rollout fused is one
-            # dispatch either way.
-            built = self._next_branch_bits(
-                anchor, end, session, may_skip=load_frame is None
-            )
-        if built is None:
-            self.spec_dispatches_skipped += 1
-            self.metrics.count("spec_dispatches_skipped")
-            self.handle_requests(requests, session)
-            return
-        bits, sig = built
-        carry = self._packed_carry()
-        self._spec_sig = sig
-        # Burst assembly: after a partial commit only the unmatched tail
-        # resimulates, with no Load — the absorb phase positions the state.
-        tail = steps[n_commit:]
-        if n_commit > 0:
-            burst_load, burst_start = None, load_frame + n_commit
+            self.device_dispatches_total += 1
+            with self.span("spec_commit", frame=load_frame):
+                self._carry, self._state, cs = self._fused.commit_absorb(
+                    self._packed_carry(), *ints[:TickInts.ABSORB]
+                )
+            self._ring = None
+            self.metrics.observe("tick_io_buffers", self._fused.io.last)
+            cs_parts = ((cs, None, load_frame, n_commit),)
         else:
-            burst_load, burst_start = load_frame, start
-        zeros = self.input_spec.zeros_np(self.num_players)
-        tail_bits = (
-            np.stack([np.asarray(s.adv.bits) for s in tail])
-            if tail else np.zeros((0,) + zeros.shape, zeros.dtype)
-        )
-        tail_status = (
-            np.stack([np.asarray(s.adv.status) for s in tail])
-            if tail else np.zeros((0, self.num_players), np.int32)
-        )
-        self.device_dispatches_total += 1
-        with self.span("tick_dispatch", frame=end):
-            out = self._fused.run(
-                carry,
-                branch=absorb_branch,
-                absorb_first=load_frame if load_frame is not None else 0,
-                absorb_n=n_commit,
-                prev_anchor=res.start_frame if res is not None else 0,
-                prev_total=res.num_frames if res is not None else 0,
-                load_frame=burst_load, start_frame=burst_start,
-                bits=tail_bits, status=tail_status, n_burst=len(tail),
-                spec_anchor=anchor, spec_from_live=(anchor == end),
-                branch_bits=bits,
+            with self.span("spec_tree_build", anchor=anchor):
+                # Dedup-skip STEADY ticks only: a rollback tick already ran
+                # the branch match above — delegating it to the legacy path
+                # would re-run the match; re-dispatching its rollout fused
+                # is one dispatch either way.
+                built = self._next_branch_bits(
+                    anchor, end, session, may_skip=load_frame is None
+                )
+            if built is None:
+                self.spec_dispatches_skipped += 1
+                self.metrics.count("spec_dispatches_skipped")
+                self.handle_requests(requests, session)
+                return
+            bits, sig = built
+            carry = self._packed_carry()
+            self._spec_sig = sig
+            tail = steps[n_commit:]
+            self.device_dispatches_total += 1
+            with self.span("tick_dispatch", frame=end):
+                out = self._fused.run(
+                    carry, ints,
+                    [np.asarray(s.adv.bits) for s in tail],
+                    [np.asarray(s.adv.status) for s in tail], bits,
+                )
+            self._result = self._carried(out, bits, anchor)
+            # The fused program just dispatched the NEXT rollout's B×F
+            # speculative device frames (the waste-ratio numerator).
+            self.ledger.record_rollout(self.num_branches * self.spec_frames)
+            cs = self._spec_cs
+            cs_parts = (
+                (cs, 0, load_frame, n_commit), (cs, 1, burst_start, n_tail),
             )
-        self._result = self._carried(out, bits, anchor)
-        # The fused program just dispatched the NEXT rollout's B×F
-        # speculative device frames (the waste-ratio numerator).
-        self.ledger.record_rollout(self.num_branches * self.spec_frames)
         self.frame = end
-        # Counters — identical accounting to the legacy pair.
         self.metrics.count("frames_advanced", n_steps)
         if load_frame is not None:
-            self.rollbacks_total += 1
-            self.metrics.count("rollbacks")
-            self.metrics.observe("rollback_depth", n_steps)
-            if n_commit > 0:
-                self.rollback_frames_recovered_total += n_commit
-                self.metrics.count("rollback_frames_recovered", n_commit)
-                if n_commit == n_steps:
-                    self.spec_hits += 1
-                    self.metrics.count("spec_hits")
-                else:
-                    self.spec_partial_hits += 1
-                    self.metrics.count("spec_partial_hits")
-                    self.rollback_frames_total += len(tail)
-                    self.metrics.count("rollback_frames", len(tail))
-            else:
-                self.rollback_frames_total += n_steps
-                self.metrics.count("rollback_frames", n_steps)
-            outcome = (
-                ("full" if n_commit == n_steps else "partial")
-                if n_commit > 0 else ("miss" if missed else "unmatched")
-            )
-            self.ledger.record(
-                outcome, depth=n_steps, frames_recovered=n_commit,
-                frames_resimulated=n_steps - n_commit,
-                branch=absorb_branch if n_commit > 0 else None,
-                rank=absorb_branch if n_commit > 0 else None,
-                blame_player=blame_player, blame_frame=blame_frame,
-                load_frame=load_frame,
+            account_rollback(
+                self, load_frame, n_steps, absorb_branch, n_commit, missed,
+                blame,
             )
         # Checksum reporting: queue only the frames the session wants;
         # the device arrays are read next tick (see docstring).
         if session is not None and self.report_checksums:
-            wants = getattr(session, "wants_checksum", None)
-            report_a = [
-                (t, load_frame + t) for t in range(n_commit)
-                if wants is None or wants(load_frame + t)
-            ]
-            report_b = [
-                (t, burst_start + t) for t in range(len(tail))
-                if wants is None or wants(burst_start + t)
-            ]
-            if report_a:
-                self._pending_reports.append((self._spec_cs, 0, report_a))
-            if report_b:
-                self._pending_reports.append((self._spec_cs, 1, report_b))
+            for out_cs, part, first, n in cs_parts:
+                rows = wanted_rows(session, first, n)
+                if rows:
+                    self._pending_reports.append((out_cs, part, rows))
         self._gc_log()
 
     def flush_reports(self, session) -> None:
@@ -1252,11 +1183,10 @@ class SpeculativeRollbackRunner(RollbackRunner):
             self._result = None  # attestation failed: serial path only
             return
         anchor = confirmed_frame + 1
-        if anchor > self.frame:
-            self._result = None  # fully confirmed: nothing to speculate
-            return
-        if anchor <= self.frame - self._ring_depth:
-            self._result = None  # anchor fell out of the ring
+        if not spec_in_window(anchor, self.frame, self._ring_depth):
+            # Fully confirmed (nothing to speculate), or the anchor fell
+            # out of the ring.
+            self._result = None
             return
         with self.span("spec_tree_build", anchor=anchor):
             built = self._next_branch_bits(
@@ -1368,38 +1298,6 @@ class SpeculativeRollbackRunner(RollbackRunner):
                 )
         return bits, sig
 
-    def _commit_full_hit(
-        self, load_frame: int, n_commit: int, branch: int, res: SpecResult,
-        steps: List[_Step], session,
-    ) -> None:
-        """The full-hit fast path: one absorb-only dispatch commits the
-        matched branch's precomputed frames. See :meth:`tick`."""
-        self.device_dispatches_total += 1
-        with self.span("spec_commit", frame=load_frame):
-            self._carry, self._state, absorb_cs = self._fused.commit_absorb(
-                self._packed_carry(), branch, load_frame,
-                n_commit, res.start_frame, res.num_frames,
-            )
-        self._ring = None
-        self.metrics.observe("tick_io_buffers", self._fused.io.last)
-        self.frame = load_frame + n_commit
-        self.rollbacks_total += 1
-        self.rollback_frames_recovered_total += n_commit
-        self.spec_hits += 1
-        self.metrics.count("rollbacks")
-        self.metrics.count("rollback_frames_recovered", n_commit)
-        self.metrics.count("frames_advanced", n_commit)
-        self.metrics.observe("rollback_depth", len(steps))
-        self.metrics.count("spec_hits")
-        if session is not None and self.report_checksums:
-            wants = getattr(session, "wants_checksum", None)
-            report = [
-                (t, load_frame + t) for t in range(n_commit)
-                if wants is None or wants(load_frame + t)
-            ]
-            if report:
-                self._pending_reports.append((absorb_cs, None, report))
-
     def _prev_buffers(self):
         """The previous rollout's branch-stacked (rings, states) — inputs
         the fused program's absorb phase selects from. When no rollout is
@@ -1442,19 +1340,10 @@ class SpeculativeRollbackRunner(RollbackRunner):
         is the standalone-`speculate()` and attestation entry — the SAME
         compiled program `tick()` runs, so attestation verdicts cover the
         executable live sessions actually commit from."""
-        zeros = self.input_spec.zeros_np(self.num_players)
         self._materialize()  # a pending rollout's trees leave the carry
-        out = self._fused.run(
-            self._packed_carry(),
-            branch=0, absorb_first=0, absorb_n=0, prev_anchor=0,
-            prev_total=0,
-            load_frame=None, start_frame=self.frame,
-            bits=np.zeros((0,) + zeros.shape, zeros.dtype),
-            status=np.zeros((0, self.num_players), np.int32),
-            n_burst=0,
-            spec_anchor=anchor, spec_from_live=(anchor == self.frame),
-            branch_bits=branch_bits,
-        )
+        ints = TickInts.zeros(self._fused.burst_frames, self.num_players)
+        plan_rollout(ints, self.frame, anchor, self._ring_depth)
+        out = self._fused.run(self._packed_carry(), ints, (), (), branch_bits)
         self.device_dispatches_total += 1
         # B×F speculative device frames per rollout (covers speculate(),
         # warmup, and the attestation replays — all branch compute the
@@ -1784,39 +1673,17 @@ class SpeculativeRollbackRunner(RollbackRunner):
 
     # ------------------------------------------------------------------
 
-    def _ledger_blame(self, res: SpecResult, load_frame: int, steps):
-        """``(blame_player, blame_frame)`` for the ledger entry: the first
-        input at which the corrected history diverges from branch 0's
-        prediction rows over the rollback span. Gated on
-        ``ledger.enabled`` at every call site; ``res.branch_bits`` is
-        already host-resident on the match paths, so this is pure NumPy —
-        no device sync. ``(None, None)`` when branch 0 agreed (the
-        rollback came from pre-span history or a session-level prediction
-        the rollout never modeled)."""
-        pre = load_frame - res.start_frame
-        k = min(len(steps), res.num_frames - pre)
-        if k <= 0:
-            return None, None
-        b0 = np.asarray(res.branch_bits)[0]
-        corrected = np.stack(
-            [np.asarray(s.adv.bits) for s in steps[:k]]
-        )
-        hit = blame_divergence(b0[pre:pre + k], corrected)
-        if hit is None:
-            return None, None
-        return hit[1], load_frame + hit[0]
-
     def _try_commit(self, load_frame: int, steps: List[_Step], session) -> bool:
         """Commit a matching branch for a ``[Load, (Save, Advance)*]``
-        burst; returns False (→ serial fallback) when no branch matches."""
+        burst; returns False (→ serial fallback) when no branch matches.
+        The fall-back pair's commit: the match, the commit decision and the
+        accounting are the fused tick's (``fused.py``); the dispatches are
+        the legacy branch gathers + absorb."""
         res = self._result
         if res is None or not steps:
             return False
         anchor = res.start_frame
         n_steps = len(steps)
-        end = load_frame + n_steps  # frame entered after the burst
-        if load_frame < anchor:
-            return False
         # The standard recovery burst is save+advance every step with saves
         # labeled contiguously from the load frame (the ggrs_stage.rs:277
         # invariant); anything else (spectator-style advance-only, or a
@@ -1827,45 +1694,28 @@ class SpeculativeRollbackRunner(RollbackRunner):
             for t, s in enumerate(steps)
         ):
             return False
-        # Required input trajectory from the anchor: as-used inputs for
-        # frames that survived the rollback, then the corrected inputs —
-        # truncated to the rollout's span (frames past it can't be
-        # committed and would shape-mismatch the branch tensor).
-        pre = load_frame - anchor
-        if self._native is not None:
-            steps_arr = np.stack([np.asarray(s.adv.bits) for s in steps])
-            matched = self._native.match(
-                np.asarray(res.branch_bits), anchor, load_frame, steps_arr,
-                res.num_frames,
-            )
-            if matched is None:  # log gap in the pre-span
-                return False
-            branch, depth = matched
-        else:
-            needed = []
-            for f in range(anchor, load_frame):
-                got = self._input_log.get(f)
-                if got is None:
-                    return False
-                needed.append(got)
-            needed.extend(np.asarray(s.adv.bits) for s in steps)
-            needed_arr = np.stack(needed)[: res.num_frames]  # [k, P, ...]
-            branch, depth = match_branch(
-                np.asarray(res.branch_bits), needed_arr
-            )
-        # Frames of the replay the best branch precomputed correctly.
-        n_commit = min(depth - pre, n_steps)
-        if n_commit <= 0:
+        matched = match_pending(
+            self._native, self._input_log, res.branch_bits, anchor,
+            res.num_frames, load_frame, steps,
+        )
+        if matched is None:  # load before the anchor, or a log gap
+            return False
+        branch, n_commit, missed = plan_commit(
+            matched, load_frame, anchor, n_steps
+        )
+        blame = rollback_blame(
+            self.ledger, matched, res.branch_bits, anchor, load_frame, steps
+        )
+        if missed:
             self.spec_misses += 1
             self.metrics.count("spec_misses")
             if self.ledger.enabled:
                 # The serial fallback that follows records THE entry for
                 # this rollback; hand it the causal detail the matcher
                 # just computed (one-shot, consumed by _run_segment).
-                bp, bf = self._ledger_blame(res, load_frame, steps)
                 self._ledger_note = {
-                    "outcome": "miss", "blame_player": bp,
-                    "blame_frame": bf,
+                    "outcome": "miss", "blame_player": blame[0],
+                    "blame_frame": blame[1],
                 }
             return False
 
@@ -1884,52 +1734,24 @@ class SpeculativeRollbackRunner(RollbackRunner):
                 max_steps=self.executor.max_frames,
             )
         if session is not None and self.report_checksums:
-            wants = getattr(session, "wants_checksum", None)
-            report = [
-                t for t in range(n_commit)
-                if wants is None or wants(load_frame + t)
-            ]
+            report = wanted_rows(session, load_frame, n_commit)
             if report:
                 cs_host = np.asarray(checksums)  # [T, 2] lo/hi lanes
-                for t in report:
-                    session.report_checksum(
-                        load_frame + t, combine64(cs_host[t])
-                    )
+                for t, frame in report:
+                    session.report_checksum(frame, combine64(cs_host[t]))
         for t, s in enumerate(steps[:n_commit]):
             self._input_log[load_frame + t] = np.asarray(s.adv.bits)
         self.frame = load_frame + n_commit
-        self.rollbacks_total += 1
-        # Committed frames are NOT added to rollback_frames_total: they were
-        # never resimulated — that is the whole point of the hit.
-        self.rollback_frames_recovered_total += n_commit
-        self.metrics.count("rollbacks")
-        self.metrics.count("rollback_frames_recovered", n_commit)
+        # The tail's frames are counted where they advance (_run_segment).
         self.metrics.count("frames_advanced", n_commit)
-        self.metrics.observe("rollback_depth", n_steps)
-        if self.ledger.enabled:
-            bp, bf = self._ledger_blame(res, load_frame, steps)
-        else:
-            bp = bf = None
-        self.ledger.record(
-            "full" if n_commit == n_steps else "partial",
-            depth=n_steps, frames_recovered=n_commit,
-            frames_resimulated=n_steps - n_commit,
-            branch=int(branch), rank=int(branch),
-            blame_player=bp, blame_frame=bf, load_frame=load_frame,
+        account_rollback(
+            self, load_frame, n_steps, branch, n_commit, False, blame
         )
-        if n_commit == n_steps:
-            self.spec_hits += 1
-            self.metrics.count("spec_hits")
-        else:
+        if n_commit < n_steps:
             # Partial-prefix hit: resimulate only the unmatched tail
             # serially from the committed state (no Load — the state is
             # already positioned at load_frame + n_commit).
-            self.spec_partial_hits += 1
-            self.metrics.count("spec_partial_hits")
-            tail = steps[n_commit:]
-            self.rollback_frames_total += len(tail)
-            self.metrics.count("rollback_frames", len(tail))
-            self._run_segment(None, tail, session)
+            self._run_segment(None, steps[n_commit:], session)
         return True
 
     def _gc_log(self) -> None:

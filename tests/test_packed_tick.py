@@ -211,17 +211,8 @@ def test_packed_tick_equals_tick_impl(seed):
     for i in range(LANES):
         n = int(ints[i, T.N_BURST])
         got = single.run(
-            single.pack(*lane(trees, i)),
-            *(int(ints[i, k]) for k in (
-                T.BRANCH, T.ABSORB_FIRST, T.ABSORB_N, T.PREV_ANCHOR,
-                T.PREV_TOTAL)),
-            load_frame=int(ints[i, T.LOAD_FRAME]) if DO_LOAD[i] else None,
-            start_frame=int(ints[i, T.START_FRAME]),
-            bits=bits[i, :n],
-            status=ints[i, T.STATUS:].reshape(BURST, P)[:n], n_burst=n,
-            spec_anchor=int(ints[i, T.SPEC_ANCHOR]),
-            spec_from_live=bool(ints[i, T.SPEC_FROM_LIVE]),
-            branch_bits=bb[i],
+            single.pack(*lane(trees, i)), ints[i].copy(), bits[i, :n],
+            TickInts.status(ints[i], BURST, P)[:n], bb[i],
         )
         # beyond n_burst the padded steps are masked: zero them on both sides
         pad = ints[i].copy()

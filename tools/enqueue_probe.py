@@ -183,6 +183,7 @@ def variant_d():
 
 
 def real_client(B: int, calls: int):
+    from bevy_ggrs_tpu.fused import TickInts, plan_tick
     from bevy_ggrs_tpu.spec_runner import SpeculativeRollbackRunner
 
     r = SpeculativeRollbackRunner(
@@ -199,12 +200,10 @@ def real_client(B: int, calls: int):
     def call():
         # A steady tick: one (save, advance) step, a fresh rollout.
         f = frame[0]
+        ints = TickInts.zeros(r._fused.burst_frames, P)
+        plan_tick(ints, f, None, 1, f, MAXPRED, None, f + 1, r._ring_depth)
         return r._fused.run(
-            carry[0],
-            branch=0, absorb_first=0, absorb_n=0, prev_anchor=f, prev_total=MAXPRED,
-            load_frame=None, start_frame=f, bits=zeros[None],
-            status=np.zeros((1, P), np.int32), n_burst=1,
-            spec_anchor=f + 1, spec_from_live=True, branch_bits=bb.copy(),
+            carry[0], ints, zeros[None], np.zeros((1, P), np.int32), bb.copy()
         )
 
     def feed(out, args):
