@@ -7,6 +7,14 @@ ctypes wrappers whose surface is identical to the pure-Python
 :class:`~bevy_ggrs_tpu.session.input_queue.InputQueue` / tracker logic they
 replace — sessions are agnostic. Set ``BEVY_GGRS_TPU_NATIVE=0`` to force the
 Python path (parity tests run both).
+
+A session's frame crosses into the core twice at most: a queue set's
+:meth:`advance` is all of ``advance_frame()``'s work on the queues and the
+tracker, :meth:`ingest` all of one ``InputMsg``'s, and both hand back the
+confirmed frame and every queue's last confirmed frame, so the session
+keeps them as fields. The Python plane's two methods are the sequence of
+primitives they replaced, the reference the native plane is held to bitwise
+(``tests/test_session_coarse_calls.py``).
 """
 
 from __future__ import annotations
@@ -109,6 +117,19 @@ def _load():
     lib.ggrs_rt_get_used.restype = ctypes.c_int
     lib.ggrs_rt_discard_before.argtypes = [ctypes.c_void_p, ctypes.c_int32]
     lib.ggrs_rt_discard_before.restype = None
+    # The coarse calls take their buffers as addresses (integers kept by
+    # the queue set that owns the buffers), never a pointer cast a call.
+    vp = ctypes.c_void_p
+    lib.ggrs_qs_advance.argtypes = [
+        vp, vp, ctypes.c_int32, ctypes.c_int32, vp, vp, vp, ctypes.c_int32,
+        ctypes.c_int32, ctypes.c_int32, ctypes.c_int32, vp, vp, vp, vp, vp]
+    lib.ggrs_qs_advance.restype = ctypes.c_int
+    lib.ggrs_qs_ingest.argtypes = [
+        vp, vp, ctypes.c_int, ctypes.c_int32, ctypes.c_int32,
+        ctypes.c_char_p, ctypes.c_int64, vp, vp]
+    lib.ggrs_qs_ingest.restype = None
+    lib.ggrs_native_calls.argtypes = []
+    lib.ggrs_native_calls.restype = ctypes.c_uint64
     i64p = ctypes.POINTER(ctypes.c_int64)
     u64p = ctypes.POINTER(ctypes.c_uint64)
     lib.ggrs_sb_new.argtypes = [
@@ -159,12 +180,30 @@ def available() -> bool:
     return _load() is not None
 
 
+def native_calls() -> Optional[int]:
+    """How many ``ggrs_qs_*`` / ``ggrs_rt_*`` entry points this process has
+    called (counted in the core; nothing a call in Python), or None on the
+    Python plane. The served frame samples it around its session loop."""
+    lib = _load()
+    return None if lib is None else int(lib.ggrs_native_calls())
+
+
 def _u8p(arr: np.ndarray):
     return arr.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8))
 
 
 def _i32p(arr: np.ndarray):
     return arr.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
+
+
+def _frontier(qset) -> Tuple[int, List[int]]:
+    """A queue set's ``(confirmed frame, [each queue's last confirmed
+    frame])`` by the primitives: for the few places that change who is
+    connected (the coarse calls return it themselves)."""
+    return (
+        qset.min_confirmed(qset.disc == NEVER_DISCONNECTED),
+        [q.last_confirmed_frame for q in qset.queues],
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -257,7 +296,7 @@ class _NativeQueueView:
 
 
 class NativeQueueSet:
-    def __init__(self, zero: np.ndarray, delays: Sequence[int]):
+    def __init__(self, zero: np.ndarray, delays: Sequence[int], window: int = 0):
         # NB: np.ascontiguousarray would promote 0-d inputs to 1-d and
         # corrupt the spec shape; reshape(-1) for the byte view instead.
         zero = np.asarray(zero)
@@ -276,6 +315,26 @@ class NativeQueueSet:
         self.queues: List[_NativeQueueView] = [
             _NativeQueueView(self, h) for h in range(self._num_players)
         ]
+        # The coarse calls' buffers, allocated here at their largest size
+        # and never again; `_at` keeps each one's address, which is how it
+        # crosses. `disc` is the session's to write: the frame each player
+        # disconnected at, NEVER_DISCONNECTED while connected.
+        P, shape = self._num_players, self._shape
+        self._window = int(window)
+        self._slots = max(self._delays, default=0) + 1
+        self.disc = np.full((P,), NEVER_DISCONNECTED, dtype=np.int32)
+        self._handles: List[int] = []
+        self._buf = {
+            "disc": self.disc,
+            "handles": np.zeros((P,), np.int32),
+            "local_in": np.zeros((P,) + shape, self._dtype),
+            "local_out": np.zeros((P, self._slots) + shape, self._dtype),
+            "local_mask": np.zeros((P, self._slots), np.uint8),
+            "bits": np.zeros((self._window, P) + shape, self._dtype),
+            "status": np.zeros((self._window, P), np.int32),
+            "ints": np.zeros((4 + P,), np.int32),
+        }
+        self._at = {k: v.ctypes.data for k, v in self._buf.items()}
 
     def _in(self, bits) -> np.ndarray:
         arr = np.asarray(bits, dtype=self._dtype).reshape(self._shape)
@@ -316,6 +375,83 @@ class NativeQueueSet:
         bits = flat.view(self._dtype).reshape((P,) + self._shape)
         return bits, status
 
+    frontier = _frontier
+
+    def advance(
+        self, tracker, frame: int, local_handles: Sequence[int],
+        local_bits: Sequence[np.ndarray], max_prediction: int,
+        resim_from: int, gc_cap: int, echo_locals: bool = False,
+    ):
+        """One frame's advance in one call (``ggrs_qs_advance``): add
+        ``local_bits[i]`` for ``local_handles[i]`` at ``frame``; pick the
+        segment to simulate (from the tracker's first incorrect frame,
+        clamped to ``frame - max_prediction``, when ``tracker`` has one, else
+        from ``resim_from``); gather every frame of it (and record it as
+        used); clear the tracker's mark; discard history before
+        ``min(confirmed frame, gc_cap)``.
+
+        Returns ``(start, load, bits[n, P, ...], status[n, P], stored,
+        confirmed, last_confirmed)``: the segment's first frame, the frame
+        to load (NULL_FRAME: none), its inputs, with ``echo_locals`` each
+        local handle's stored ``(frame, bits)`` for the frames its endpoints
+        are sent, and the frontier. ``bits``, ``status`` and the stored
+        inputs are COPIES of the bound buffers: a caller may keep them."""
+        if max(frame - resim_from, max_prediction) >= self._window:
+            raise _invalid_request(
+                f"a segment from frame {min(resim_from, frame - max_prediction)}"
+                f" to {frame} is more than the {self._window} frames this "
+                f"queue set's buffers were bound for"
+            )
+        if local_handles != self._handles:
+            self._handles = list(local_handles)
+            self._buf["handles"][: len(self._handles)] = self._handles
+        local_in = self._buf["local_in"]
+        n_local = len(local_bits)
+        for i in range(n_local):
+            local_in[i] = local_bits[i]
+        at = self._at
+        rc = _lib.ggrs_qs_advance(
+            self._ptr, None if tracker is None else tracker._ptr, frame,
+            n_local, at["handles"], at["local_in"], at["disc"],
+            max_prediction, resim_from, gc_cap, self._slots,
+            at["local_out"] if echo_locals else None, at["local_mask"],
+            at["bits"], at["status"], at["ints"],
+        )
+        ints = self._buf["ints"].tolist()
+        if rc != 0:
+            raise _invalid_request(f"input for frame {ints[0]} was discarded")
+        start, n, load, confirmed = ints[:4]
+        stored = []
+        if echo_locals:
+            out = self._buf["local_out"][:n_local].copy()
+            mask = self._buf["local_mask"][:n_local].tolist()
+            for i, h in enumerate(self._handles):
+                stored.append([
+                    (frame + s, out[i, s, ...])
+                    for s in range(self._delays[h] + 1) if mask[i][s]
+                ])
+        return (
+            start, load, self._buf["bits"][:n].copy(),
+            self._buf["status"][:n].copy(), stored, confirmed, ints[4:],
+        )
+
+    def ingest(
+        self, tracker, handle: int, start_frame: int, num: int,
+        payload: bytes,
+    ) -> Tuple[int, bool, int, List[int]]:
+        """One ``InputMsg``'s span in one call (``ggrs_qs_ingest``): frames
+        at or under ``handle``'s last confirmed frame are skipped, the
+        contiguous new ones added and noted against ``tracker``, a gap stops
+        the span. Returns ``(frames skipped, stopped at a gap, confirmed,
+        last_confirmed)``."""
+        _lib.ggrs_qs_ingest(
+            self._ptr, None if tracker is None else tracker._ptr, handle,
+            start_frame, num, payload, len(payload), self._at["disc"],
+            self._at["ints"],
+        )
+        ints = self._buf["ints"].tolist()
+        return ints[0], bool(ints[1]), ints[2], ints[3:3 + self._num_players]
+
     def __del__(self):
         try:
             if self._ptr:
@@ -338,6 +474,9 @@ class PyQueueSet:
         self._zero = zero
         self._num_players = len(delays)
         self.queues = [InputQueue(zero, int(d)) for d in delays]
+        self.disc = np.full(
+            (self._num_players,), NEVER_DISCONNECTED, dtype=np.int32
+        )
 
     def discard_before(self, frame: int) -> None:
         for q in self.queues:
@@ -367,6 +506,66 @@ class PyQueueSet:
             else:
                 status[h] = CONFIRMED if is_confirmed else PREDICTED
         return bits, status
+
+    frontier = _frontier
+
+    def advance(
+        self, tracker, frame: int, local_handles: Sequence[int],
+        local_bits: Sequence[np.ndarray], max_prediction: int,
+        resim_from: int, gc_cap: int, echo_locals: bool = False,
+    ):
+        """:meth:`NativeQueueSet.advance` as the sequence of primitives a
+        session used to make, one call a step: the reference."""
+        stored = []
+        for h, b in zip(local_handles, local_bits):
+            q = self.queues[h]
+            target = q.add_local_input(frame, b)
+            if echo_locals:
+                echoed = [(f, q.confirmed(f)) for f in range(frame, target + 1)]
+                stored.append([e for e in echoed if e[1] is not None])
+        load, start = NULL_FRAME, min(resim_from, frame)
+        if tracker is not None and tracker.first_incorrect != NULL_FRAME:
+            load = max(tracker.first_incorrect, frame - max_prediction)
+            start = min(load, frame)
+        n = frame - start + 1
+        bits = np.empty((n, self._num_players) + self._zero.shape,
+                        self._zero.dtype)
+        status = np.empty((n, self._num_players), np.int32)
+        for i in range(n):
+            if tracker is not None and i == n - 1:
+                tracker.clear_first_incorrect()
+            bits[i], status[i] = self.gather(start + i, self.disc)
+            if tracker is not None:
+                tracker.record_used(start + i, bits[i], status[i])
+        frontier = self.frontier()  # a discard does not move it
+        horizon = min(frontier[0], gc_cap)
+        self.discard_before(horizon)
+        if tracker is not None:
+            tracker.discard_before(horizon)
+        return (start, load, bits, status, stored) + frontier
+
+    def ingest(
+        self, tracker, handle: int, start_frame: int, num: int,
+        payload: bytes,
+    ) -> Tuple[int, bool, int, List[int]]:
+        """:meth:`NativeQueueSet.ingest` by the primitives: the reference."""
+        q = self.queues[handle]
+        nbytes = self._zero.nbytes
+        redundant, gap = 0, False
+        for i in range(min(num, len(payload) // nbytes if nbytes else 0)):
+            frame = start_frame + i
+            if frame != q.last_confirmed_frame + 1:
+                if frame <= q.last_confirmed_frame:
+                    redundant += 1
+                    continue  # redundant resend
+                gap = True
+                break  # gap (loss beyond span): wait for the next resend
+            q.add_input(frame, np.frombuffer(
+                payload, self._zero.dtype, self._zero.size, i * nbytes
+            ).reshape(self._zero.shape))
+            if tracker is not None:
+                tracker.note_confirmed(handle, frame, q.confirmed(frame))
+        return (redundant, gap) + self.frontier()
 
 
 # ---------------------------------------------------------------------------
@@ -470,9 +669,11 @@ class PyTracker:
 # ---------------------------------------------------------------------------
 
 
-def make_queue_set(zero: np.ndarray, delays: Sequence[int]):
+def make_queue_set(zero: np.ndarray, delays: Sequence[int], window: int = 0):
+    """``window``: the most frames one :meth:`advance` may gather
+    (``max_prediction + 1``); 0 for a set driven by the primitives alone."""
     if available():
-        return NativeQueueSet(np.asarray(zero), delays)
+        return NativeQueueSet(np.asarray(zero), delays, window)
     return PyQueueSet(np.asarray(zero), delays)
 
 

@@ -9,13 +9,14 @@
 // (input_queue.py semantics), fused input gathering across players for an
 // AdvanceFrame request, and the used-record / first-incorrect-frame tracker
 // that turns late-arriving confirmed inputs into rollback decisions
-// (p2p.py `_note_confirmed`). Python keeps orchestration (timers, events,
+// (the tracker's note_confirmed). Python keeps orchestration (timers, events,
 // socket pump); every per-frame/per-packet state mutation lands here.
 //
 // C ABI only (ctypes binding in native/core.py — no pybind11). All frame
 // numbers are int32; NULL_FRAME == -1 matches session/common.py.
 
 #include <algorithm>
+#include <atomic>
 #include <cstdint>
 #include <cstring>
 #include <deque>
@@ -119,16 +120,94 @@ struct Tracker {
   // predictions are checked against when real inputs arrive.
   std::map<int32_t, std::pair<std::vector<uint8_t>, std::vector<int32_t>>>
       used;
+
+  void record_used(int32_t frame, const uint8_t* bits,
+                   const int32_t* status) {
+    size_t nb = size_t(num_players) * size_t(input_bytes);
+    used[frame] = {std::vector<uint8_t>(bits, bits + nb),
+                   std::vector<int32_t>(status, status + num_players)};
+  }
+
+  // A confirmed input for (handle, frame) arrived; if that frame was
+  // simulated with different non-confirmed bits, mark it first-incorrect.
+  void note_confirmed(int handle, int32_t frame, const uint8_t* bits) {
+    auto it = used.find(frame);
+    if (it == used.end()) return;
+    const auto& [used_bits, used_status] = it->second;
+    if (used_status[size_t(handle)] == STATUS_CONFIRMED) return;
+    const uint8_t* u = used_bits.data() + size_t(handle) * size_t(input_bytes);
+    if (std::memcmp(u, bits, size_t(input_bytes)) != 0) {
+      if (first_incorrect == NULL_FRAME || frame < first_incorrect)
+        first_incorrect = frame;
+    }
+  }
+
+  void discard_before(int32_t frame) {
+    used.erase(used.begin(), used.lower_bound(frame));
+  }
 };
+
+// Highest frame confirmed for every connected player; NULL_FRAME when no
+// player is connected. `connected` (a 0/1 byte a player) and `disc_frames`
+// (INT32_MAX = connected) are the two ways callers say who is; either or
+// both may be null.
+int32_t min_confirmed(const QueueSet* qs, const uint8_t* connected,
+                      const int32_t* disc_frames) {
+  bool any = false;
+  int32_t m = INT32_MAX;
+  for (int h = 0; h < qs->num_players; ++h) {
+    if (connected && !connected[h]) continue;
+    if (disc_frames && disc_frames[h] != INT32_MAX) continue;
+    any = true;
+    m = std::min(m, qs->queues[size_t(h)].last_confirmed);
+  }
+  return any ? m : NULL_FRAME;
+}
+
+// Inputs + status for every player at `frame`; status follows p2p.py
+// `_advance_request`. -1 if any queue had already discarded `frame`.
+int gather(const QueueSet* qs, int32_t frame, const int32_t* disc_frames,
+           uint8_t* out_bits, int32_t* out_status) {
+  for (int h = 0; h < qs->num_players; ++h) {
+    int got = qs->queues[size_t(h)].input(
+        frame, out_bits + size_t(h) * size_t(qs->input_bytes));
+    if (got < 0) return -1;
+    if (disc_frames && frame >= disc_frames[h])
+      out_status[h] = STATUS_DISCONNECTED;
+    else
+      out_status[h] = got ? STATUS_CONFIRMED : STATUS_PREDICTED;
+  }
+  return 0;
+}
+
+// The confirmed frame, then every queue's last confirmed frame: what both
+// coarse calls hand back so that the session never asks for them.
+void write_frontier(const QueueSet* qs, const int32_t* disc_frames,
+                    int32_t* out) {
+  out[0] = min_confirmed(qs, nullptr, disc_frames);
+  for (int h = 0; h < qs->num_players; ++h)
+    out[1 + h] = qs->queues[size_t(h)].last_confirmed;
+}
+
+// Calls of the ggrs_qs_* / ggrs_rt_* entry points by this process: the
+// session plane's crossings of the boundary (ggrs_native_calls reads it).
+std::atomic<uint64_t> g_calls{0};
+inline void crossed() { g_calls.fetch_add(1, std::memory_order_relaxed); }
 
 }  // namespace
 
 extern "C" {
 
+// How many ggrs_qs_* / ggrs_rt_* entry points this process has called.
+uint64_t ggrs_native_calls() {
+  return g_calls.load(std::memory_order_relaxed);
+}
+
 // ---------------------------------------------------------------- QueueSet
 
 void* ggrs_qs_new(int num_players, int input_bytes, const uint8_t* zero,
                   const int32_t* delays) {
+  crossed();
   auto* qs = new QueueSet();
   qs->num_players = num_players;
   qs->input_bytes = input_bytes;
@@ -143,34 +222,43 @@ void* ggrs_qs_new(int num_players, int input_bytes, const uint8_t* zero,
   return qs;
 }
 
-void ggrs_qs_free(void* p) { delete static_cast<QueueSet*>(p); }
+void ggrs_qs_free(void* p) {
+  crossed();
+  delete static_cast<QueueSet*>(p);
+}
 
 int32_t ggrs_qs_last_confirmed(void* p, int handle) {
+  crossed();
   return static_cast<QueueSet*>(p)->queues[size_t(handle)].last_confirmed;
 }
 
 int ggrs_qs_delay(void* p, int handle) {
+  crossed();
   return static_cast<QueueSet*>(p)->queues[size_t(handle)].delay;
 }
 
 int32_t ggrs_qs_add_input(void* p, int handle, int32_t frame,
                           const uint8_t* bits) {
+  crossed();
   return static_cast<QueueSet*>(p)->queues[size_t(handle)].add_input(frame,
                                                                      bits);
 }
 
 int32_t ggrs_qs_add_local(void* p, int handle, int32_t frame,
                           const uint8_t* bits) {
+  crossed();
   return static_cast<QueueSet*>(p)->queues[size_t(handle)].add_local(frame,
                                                                      bits);
 }
 
 int ggrs_qs_confirmed(void* p, int handle, int32_t frame, uint8_t* out) {
+  crossed();
   return static_cast<QueueSet*>(p)->queues[size_t(handle)].confirmed(frame,
                                                                      out);
 }
 
 int ggrs_qs_input(void* p, int handle, int32_t frame, uint8_t* out) {
+  crossed();
   return static_cast<QueueSet*>(p)->queues[size_t(handle)].input(frame, out);
 }
 
@@ -181,6 +269,7 @@ int ggrs_qs_input(void* p, int handle, int32_t frame, uint8_t* out) {
 // per tick became O(P).
 void ggrs_qs_confirmed_span(void* p, int handle, int32_t lo, int32_t n,
                             uint8_t* out, uint8_t* mask) {
+  crossed();
   const Queue& q = static_cast<QueueSet*>(p)->queues[size_t(handle)];
   std::memset(mask, 0, size_t(n));
   if (q.inputs.empty()) return;
@@ -194,15 +283,18 @@ void ggrs_qs_confirmed_span(void* p, int handle, int32_t lo, int32_t n,
 }
 
 void ggrs_qs_discard_before(void* p, int32_t frame) {
+  crossed();
   for (Queue& q : static_cast<QueueSet*>(p)->queues) q.discard_before(frame);
 }
 
 void ggrs_qs_reset(void* p, int handle, int32_t next_frame,
                    const uint8_t* last) {
+  crossed();
   static_cast<QueueSet*>(p)->queues[size_t(handle)].reset(next_frame, last);
 }
 
 void ggrs_qs_last_input(void* p, int handle, uint8_t* out) {
+  crossed();
   const Queue& q = static_cast<QueueSet*>(p)->queues[size_t(handle)];
   std::memcpy(out, q.last_input.data(), size_t(q.input_bytes));
 }
@@ -210,83 +302,61 @@ void ggrs_qs_last_input(void* p, int handle, uint8_t* out) {
 // Highest frame confirmed for every connected player (connected[h] != 0);
 // NULL_FRAME when no player is connected. Mirrors P2PSession.confirmed_frame.
 int32_t ggrs_qs_min_confirmed(void* p, const uint8_t* connected) {
-  auto* qs = static_cast<QueueSet*>(p);
-  bool any = false;
-  int32_t m = INT32_MAX;
-  for (int h = 0; h < qs->num_players; ++h) {
-    if (connected && !connected[h]) continue;
-    any = true;
-    if (qs->queues[size_t(h)].last_confirmed < m)
-      m = qs->queues[size_t(h)].last_confirmed;
-  }
-  return any ? m : NULL_FRAME;
+  crossed();
+  return min_confirmed(static_cast<QueueSet*>(p), connected, nullptr);
 }
 
 // Fused AdvanceFrame assembly: inputs + status for every player at `frame`.
 // disc_frames[h] is the frame the player disconnected at (INT32_MAX when
-// connected); status follows p2p.py `_advance_request`. Returns 0, or -1 if
-// any queue had already discarded `frame` (protocol violation).
+// connected). Returns 0, or -1 if any queue had already discarded `frame`
+// (protocol violation).
 int ggrs_qs_gather(void* p, int32_t frame, const int32_t* disc_frames,
                    uint8_t* out_bits, int32_t* out_status) {
-  auto* qs = static_cast<QueueSet*>(p);
-  for (int h = 0; h < qs->num_players; ++h) {
-    int got = qs->queues[size_t(h)].input(
-        frame, out_bits + size_t(h) * size_t(qs->input_bytes));
-    if (got < 0) return -1;
-    if (disc_frames && frame >= disc_frames[h])
-      out_status[h] = STATUS_DISCONNECTED;
-    else
-      out_status[h] = got ? STATUS_CONFIRMED : STATUS_PREDICTED;
-  }
-  return 0;
+  crossed();
+  return gather(static_cast<QueueSet*>(p), frame, disc_frames, out_bits,
+                out_status);
 }
 
 // ---------------------------------------------------------------- Tracker
 
 void* ggrs_rt_new(int num_players, int input_bytes) {
+  crossed();
   auto* t = new Tracker();
   t->num_players = num_players;
   t->input_bytes = input_bytes;
   return t;
 }
 
-void ggrs_rt_free(void* p) { delete static_cast<Tracker*>(p); }
+void ggrs_rt_free(void* p) {
+  crossed();
+  delete static_cast<Tracker*>(p);
+}
 
 void ggrs_rt_record_used(void* p, int32_t frame, const uint8_t* bits,
                          const int32_t* status) {
-  auto* t = static_cast<Tracker*>(p);
-  size_t nb = size_t(t->num_players) * size_t(t->input_bytes);
-  t->used[frame] = {std::vector<uint8_t>(bits, bits + nb),
-                    std::vector<int32_t>(status, status + t->num_players)};
+  crossed();
+  static_cast<Tracker*>(p)->record_used(frame, bits, status);
 }
 
-// A confirmed input for (handle, frame) arrived; if that frame was simulated
-// with different non-confirmed bits, mark it first-incorrect.
 void ggrs_rt_note_confirmed(void* p, int handle, int32_t frame,
                             const uint8_t* bits) {
-  auto* t = static_cast<Tracker*>(p);
-  auto it = t->used.find(frame);
-  if (it == t->used.end()) return;
-  const auto& [used_bits, used_status] = it->second;
-  if (used_status[size_t(handle)] == STATUS_CONFIRMED) return;
-  const uint8_t* u =
-      used_bits.data() + size_t(handle) * size_t(t->input_bytes);
-  if (std::memcmp(u, bits, size_t(t->input_bytes)) != 0) {
-    if (t->first_incorrect == NULL_FRAME || frame < t->first_incorrect)
-      t->first_incorrect = frame;
-  }
+  crossed();
+  static_cast<Tracker*>(p)->note_confirmed(handle, frame, bits);
 }
 
 int32_t ggrs_rt_first_incorrect(void* p) {
+  crossed();
   return static_cast<Tracker*>(p)->first_incorrect;
 }
 
 void ggrs_rt_clear_first_incorrect(void* p) {
+  crossed();
   static_cast<Tracker*>(p)->first_incorrect = NULL_FRAME;
 }
 
 int ggrs_rt_get_used(void* p, int32_t frame, uint8_t* out_bits,
                      int32_t* out_status) {
+  crossed();
   auto* t = static_cast<Tracker*>(p);
   auto it = t->used.find(frame);
   if (it == t->used.end()) return 0;
@@ -297,8 +367,122 @@ int ggrs_rt_get_used(void* p, int32_t frame, uint8_t* out_bits,
 }
 
 void ggrs_rt_discard_before(void* p, int32_t frame) {
-  auto* t = static_cast<Tracker*>(p);
-  t->used.erase(t->used.begin(), t->used.lower_bound(frame));
+  crossed();
+  static_cast<Tracker*>(p)->discard_before(frame);
+}
+
+// ------------------------------------------------------------ Coarse calls
+//
+// A session crosses into the core at most twice a frame: `advance` is all
+// of advance_frame()'s work on the queues and the tracker, `ingest` all of
+// one InputMsg's. Both loop over the primitives above, in the order the
+// session used to call them one at a time (PyQueueSet.advance / .ingest in
+// core.py are that sequence, and tests/test_session_coarse_calls.py holds
+// the three bitwise equal). Buffers are the caller's, bound once.
+
+// One frame's advance. In order: the local inputs at `frame` (each local
+// handle's stored inputs for frames frame .. frame+delay come back in
+// out_local[i, 0..local_slots), out_local_mask 1 where stored: what the
+// session queues to its endpoints); the segment to simulate, which starts
+// at the tracker's first incorrect frame, clamped to frame - max_prediction,
+// when a tracker is given and has one, else at `resim_from`; gather (+
+// record_used with a tracker) of every frame of the segment into
+// out_bits[n, P, input_bytes] / out_status[n, P]; the tracker's first
+// incorrect frame cleared; history before min(confirmed frame, gc_cap)
+// discarded. out[0] = first frame of the segment, out[1] = n, out[2] = the
+// frame to load (NULL_FRAME: no rollback), out[3] = the confirmed frame,
+// out[4..4+P) = every queue's last confirmed frame. Returns 0, or -1 when a
+// frame of the segment had been discarded (out[0] says which).
+int ggrs_qs_advance(void* qs_v, void* rt_v, int32_t frame, int32_t n_local,
+                    const int32_t* local_handles, const uint8_t* local_bits,
+                    const int32_t* disc_frames, int32_t max_prediction,
+                    int32_t resim_from, int32_t gc_cap, int32_t local_slots,
+                    uint8_t* out_local, uint8_t* out_local_mask,
+                    uint8_t* out_bits, int32_t* out_status, int32_t* out) {
+  crossed();
+  auto* qs = static_cast<QueueSet*>(qs_v);
+  auto* rt = static_cast<Tracker*>(rt_v);
+  const size_t nb = size_t(qs->input_bytes);
+  const size_t P = size_t(qs->num_players);
+
+  for (int32_t i = 0; i < n_local; ++i) {
+    Queue& q = qs->queues[size_t(local_handles[i])];
+    const int32_t target = q.add_local(frame, local_bits + size_t(i) * nb);
+    if (!out_local) continue;
+    for (int32_t s = 0; s < local_slots; ++s) {
+      const size_t at = size_t(i) * size_t(local_slots) + size_t(s);
+      out_local_mask[at] =
+          (frame + s <= target)
+              ? uint8_t(q.confirmed(frame + s, out_local + at * nb))
+              : 0;
+    }
+  }
+
+  int32_t load = NULL_FRAME;
+  int32_t start = std::min(resim_from, frame);
+  if (rt && rt->first_incorrect != NULL_FRAME) {
+    // Deeper than the snapshot ring reaches: roll back as far as
+    // snapshots exist (p2p.py `_advance_frame` says when that happens).
+    load = std::max(rt->first_incorrect, frame - max_prediction);
+    start = std::min(load, frame);
+  }
+  const int32_t n = frame - start + 1;
+  out[1] = n;
+  out[2] = load;
+  for (int32_t i = 0; i < n; ++i) {
+    uint8_t* bits = out_bits + size_t(i) * P * nb;
+    int32_t* status = out_status + size_t(i) * P;
+    // The rollback's frames are gathered, then the tracker's mark is
+    // cleared, then the new frame: the session's own order.
+    if (rt && i == n - 1) rt->first_incorrect = NULL_FRAME;
+    if (gather(qs, start + i, disc_frames, bits, status) != 0) {
+      out[0] = start + i;
+      return -1;
+    }
+    if (rt) rt->record_used(start + i, bits, status);
+  }
+  out[0] = start;
+
+  const int32_t horizon =
+      std::min(min_confirmed(qs, nullptr, disc_frames), gc_cap);
+  for (Queue& q : qs->queues) q.discard_before(horizon);
+  if (rt) rt->discard_before(horizon);
+  write_frontier(qs, disc_frames, out + 3);
+  return 0;
+}
+
+// One InputMsg's span for `handle`: frames at or under the queue's last
+// confirmed frame are skipped (out[0] counts them), the contiguous new ones
+// added and noted against the tracker, and a frame beyond the next one
+// stops the span (out[1] = 1: a gap, the next resend fills it). Only whole
+// inputs of `payload` count. out[2] = the confirmed frame, out[3..3+P) =
+// every queue's last confirmed frame.
+void ggrs_qs_ingest(void* qs_v, void* rt_v, int handle, int32_t start_frame,
+                    int32_t num, const uint8_t* payload, int64_t payload_len,
+                    const int32_t* disc_frames, int32_t* out) {
+  crossed();
+  auto* qs = static_cast<QueueSet*>(qs_v);
+  auto* rt = static_cast<Tracker*>(rt_v);
+  Queue& q = qs->queues[size_t(handle)];
+  const size_t nb = size_t(qs->input_bytes);
+  num = nb ? int32_t(std::min<int64_t>(num, payload_len / int64_t(nb))) : 0;
+  out[0] = 0;
+  out[1] = 0;
+  for (int32_t i = 0; i < num; ++i) {
+    const int32_t f = start_frame + i;
+    if (f <= q.last_confirmed) {
+      ++out[0];
+      continue;
+    }
+    if (f != q.last_confirmed + 1) {
+      out[1] = 1;
+      break;
+    }
+    const uint8_t* bits = payload + size_t(i) * nb;
+    q.add_input(f, bits);
+    if (rt) rt->note_confirmed(handle, f, bits);
+  }
+  write_frontier(qs, disc_frames, out + 2);
 }
 
 }  // extern "C"

@@ -56,6 +56,7 @@ import dataclasses
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from bevy_ggrs_tpu.native.core import native_calls
 from bevy_ggrs_tpu.serve.batch import BatchedSessionCore, BatchedTickExecutor
 from bevy_ggrs_tpu.obs.trace import (
     NULL_SPAN,
@@ -1042,6 +1043,10 @@ class MatchServer(Instrumented):
                     # reads only while a sink listens).
                     time_polls = sp_sessions is not NULL_SPAN
                     poll_s = 0.0
+                    # The loop's crossings into the native session core, a
+                    # live match: series ``serve_session_native_calls``
+                    # (the core counts; None on the Python plane).
+                    calls_0 = native_calls() if time_polls else None
                     for slot, (handle, m) in matches.items():
                         session = m.session
                         t_m = self._clock()
@@ -1132,6 +1137,11 @@ class MatchServer(Instrumented):
                         work[slot] = (requests, confirmed, session)
                     if time_polls:
                         self.metrics.observe("serve_poll_ms", poll_s * 1000.0)
+                    if calls_0 is not None:
+                        self.metrics.observe(
+                            "serve_session_native_calls",
+                            (native_calls() - calls_0) / len(matches),
+                        )
                 while work:
                     try:
                         core.tick(work)

@@ -126,12 +126,22 @@ class P2PSession:
         self._qset = make_queue_set(
             zero,
             [input_delay if h in local_players else 0 for h in range(num_players)],
+            window=self.max_prediction + 1,
         )
         self._queues = self._qset.queues
         self._tracker = make_tracker(num_players, zero)
         self.local_handles = sorted(local_players)
         self._handle_addr: Dict[int, object] = dict(remote_players)
+        self._addr_handles: Dict[object, List[int]] = {}
+        for h, addr in self._handle_addr.items():
+            self._addr_handles.setdefault(addr, []).append(h)
         self._disconnected: Dict[int, int] = {}  # handle -> frame of disconnect
+        # The frontier: the confirmed frame and every queue's last confirmed
+        # frame, as the last coarse call into the queue set (advance, ingest)
+        # returned them. Fields, not calls; _refresh_frontier() recomputes
+        # them where the set of connected handles changes.
+        self._confirmed = NULL_FRAME
+        self._last_confirmed: List[int] = [NULL_FRAME] * self.num_players
 
         rng = np.random.RandomState(seed)
         # Kept for reconnect_peer: replacement endpoints share the session
@@ -200,9 +210,18 @@ class P2PSession:
     def confirmed_frame(self) -> int:
         """Highest frame for which every connected player's input is
         confirmed (local inputs confirm at add time, after input delay)."""
-        return self._qset.min_confirmed(
-            [h not in self._disconnected for h in range(self.num_players)]
-        )
+        return self._confirmed
+
+    def _refresh_frontier(self) -> None:
+        """Recompute the cached frontier from the queues. Called wherever
+        ``_disconnected`` changes (a disconnect, a readmit, a restore) or
+        the queues were written outside the two coarse calls (a restore, the
+        supervisor's rejoin gap-fill), and nowhere else."""
+        disc = self._qset.disc
+        disc[:] = NEVER_DISCONNECTED
+        for h, f in self._disconnected.items():
+            disc[h] = f
+        self._confirmed, self._last_confirmed = self._qset.frontier()
 
     def confirmed_input(self, handle: int, frame: int):
         """The confirmed input of ``handle`` for ``frame``, or None while it
@@ -363,10 +382,10 @@ class P2PSession:
         return min(adv, 0x7FFF)
 
     def _ack_frame_for(self, addr: object) -> int:
-        handles = [h for h, a in self._handle_addr.items() if a == addr]
+        handles = self._addr_handles.get(addr)
         if not handles:
             return NULL_FRAME
-        return min(self._queues[h].last_confirmed_frame for h in handles)
+        return min([self._last_confirmed[h] for h in handles])
 
     def _on_remote_inputs(
         self, sender: object, msg: proto.InputMsg, now: float
@@ -393,23 +412,26 @@ class P2PSession:
                 return
             if sender in self._spectator_addrs:
                 return  # spectators never contribute inputs
-        queue = self._queues[h]
-        for frame, bits in proto.unpack_input_span(
-            msg, np.dtype(self._zero.dtype), self._zero.shape
-        ):
-            if frame != queue.last_confirmed_frame + 1:
-                if frame <= queue.last_confirmed_frame:
-                    self.metrics.count("input_frames_redundant")
-                    continue  # redundant resend
-                self.metrics.count("input_span_gaps")
-                break  # gap (loss beyond span) — wait for next resend
-            queue.add_input(frame, bits)
-            self._note_confirmed(h, frame, queue.confirmed(frame))
+        # The whole span in one call: redundant resends skipped, the
+        # contiguous new frames added and noted against the tracker (a late
+        # input that contradicts a prediction, or a disconnect-freeze later
+        # corrected by a surviving peer's relay, schedules the rollback), a
+        # gap (loss beyond the span) left for the next resend.
+        redundant, gap, self._confirmed, self._last_confirmed = (
+            self._qset.ingest(
+                self._tracker, h, msg.start_frame, msg.num, msg.payload
+            )
+        )
+        if redundant:
+            self.metrics.count("input_frames_redundant", redundant)
+        if gap:
+            self.metrics.count("input_span_gaps")
+        last_confirmed = self._last_confirmed[h]
         if (
             not relayed
             and h in self._disconnected
             and self._endpoints[owner].state == PeerState.RUNNING
-            and queue.last_confirmed_frame >= self._disconnected[h]
+            and last_confirmed >= self._disconnected[h]
         ):
             # Readmit: the owner re-handshook (reconnect_peer) and its OWN
             # confirmed stream reached the disconnect point, so its inputs
@@ -419,6 +441,7 @@ class P2PSession:
             # game systems never read status into state (docs/parity.md),
             # so peers readmitting at different frames stay bitwise equal.
             del self._disconnected[h]
+            self._refresh_frontier()
             self._events.append(
                 SessionEvent(
                     EventKind.PLAYER_REJOINED,
@@ -426,18 +449,10 @@ class P2PSession:
                     data={"handle": h},
                 )
             )
-        if relayed and queue.last_confirmed_frame >= 0:
+        if relayed and last_confirmed >= 0:
             # Relayed handles are outside the piggybacked-ack path: ack
             # explicitly so the relaying survivor can trim its span.
-            self._endpoints[sender].send_input_ack(
-                h, queue.last_confirmed_frame, now
-            )
-
-    def _note_confirmed(self, handle: int, frame: int, bits: np.ndarray) -> None:
-        """A confirmed input arrived; if we already simulated ``frame`` with
-        different bits (a prediction, or a disconnect-freeze later corrected
-        by a surviving peer's relay), schedule a rollback to it."""
-        self._tracker.note_confirmed(handle, frame, bits)
+            self._endpoints[sender].send_input_ack(h, last_confirmed, now)
 
     def _on_peer_disconnected(self, addr: object) -> None:
         """All handles at ``addr`` become disconnected: their inputs freeze
@@ -445,12 +460,13 @@ class P2PSession:
         received different amounts of the dead player's input (loss/latency
         asymmetry), each survivor relays the confirmed tail it holds to the
         others; later-arriving relayed inputs trigger a normal corrective
-        rollback via ``_note_confirmed``, so survivors converge on the
+        rollback via the tracker's ``note_confirmed``, so survivors converge on the
         longest available history instead of desyncing."""
         for h, a in self._handle_addr.items():
             if a == addr and h not in self._disconnected:
                 self._disconnected[h] = self.current_frame
                 self._relay_disconnected_inputs(h)
+        self._refresh_frontier()
 
     def _relay_disconnected_inputs(self, handle: int) -> None:
         queue = self._queues[handle]
@@ -579,6 +595,7 @@ class P2PSession:
         }
         self._last_checksum_sent = int(sd.get("last_checksum_sent", -1))
         self._pending_local.clear()
+        self._refresh_frontier()
         # Local input history must be re-offered to peers: endpoint ack
         # state died with the endpoints, and peers may have missed the
         # in-flight tail. Spans are idempotent receiver-side (stale frames
@@ -701,81 +718,86 @@ class P2PSession:
 
         # Back-pressure (`GGRSError::PredictionThreshold`): refuse to run
         # more than max_prediction frames past the last confirmed input.
-        confirmed = self.confirmed_frame()
-        if self.current_frame - confirmed > self.max_prediction:
+        frame = self.current_frame
+        if frame - self._confirmed > self.max_prediction:
             raise PredictionThreshold(
-                f"frame {self.current_frame} is more than {self.max_prediction} "
-                f"frames past last confirmed {confirmed}"
+                f"frame {frame} is more than {self.max_prediction} "
+                f"frames past last confirmed {self._confirmed}"
             )
 
-        # Commit local inputs (after input delay) and stage them for send.
-        frame = self.current_frame
-        spectators = set(self._spectator_addrs)
+        # Who this frame's local inputs are staged for: every player
+        # endpoint that may still come back. Spectators get the confirmed
+        # fan-out instead; never queue to the dead (unbounded growth).
+        # Reconnect endpoints buffer too (bounded inside queue_input): a
+        # rejoiner's state checkpoint is cut the moment WE serve it, so
+        # every input we produce while its handshake is still in flight
+        # must reach it as a span or the frontier gaps and both sides
+        # deadlock at the prediction window.
+        spectators = self._spectator_addrs
+        peers = [
+            ep for addr, ep in self._endpoints.items()
+            if ep.state != PeerState.DISCONNECTED and addr not in spectators
+        ]
         for h in self.local_handles:
-            target = self._queues[h].add_local_input(frame, self._pending_local[h])
-            for addr, ep in self._endpoints.items():
-                if addr in spectators:
-                    continue  # spectators get the confirmed fan-out instead
-                if ep.state == PeerState.DISCONNECTED:
-                    continue  # never queue to the dead — unbounded growth
-                # Reconnect endpoints buffer too (bounded inside
-                # queue_input): a rejoiner's state checkpoint is cut the
-                # moment WE serve it, so every input we produce while its
-                # handshake is still in flight must reach it as a span or
-                # the frontier gaps and both sides deadlock at the
-                # prediction window.
-                for f in range(
-                    max(0, target - (self._queues[h].delay or 0)), target + 1
-                ):
-                    got = self._queues[h].confirmed(f)
-                    if got is not None:
-                        ep.queue_input(h, f, got)
+            for ep in peers:
                 refill = ep.refill_range(h)
                 if refill is not None:
                     # A corrupted lying-high ack trimmed frames the peer
                     # never received; restore them from our own input
                     # history (bounded by the _gc retention window) so the
-                    # peer's frontier can't gap permanently.
-                    start = max(
-                        refill[0],
-                        0,
-                        self.current_frame - 2 * self.max_prediction - 1,
-                    )
+                    # peer's frontier can't gap permanently. Read BEFORE the
+                    # advance below discards the window's oldest frame; a
+                    # frame at or past the next pending one is queued by the
+                    # advance's own echo, so the pending set comes out as if
+                    # read after it.
+                    start = max(refill[0], 0, frame - 2 * self.max_prediction - 1)
                     for f in range(start, refill[1]):
                         got = self._queues[h].confirmed(f)
                         if got is not None:
                             ep.queue_input(h, f, got)
+
+        # ONE call into the queue set + tracker: commit the local inputs
+        # (after input delay), decide the rollback, gather and record every
+        # frame of the segment, clear the tracker's mark, discard history
+        # that can no longer take part in a rollback (see _gc), and bring
+        # back the frontier.
+        (
+            start, load, bits, status, stored,
+            self._confirmed, self._last_confirmed,
+        ) = self._qset.advance(
+            self._tracker, frame, self.local_handles,
+            [self._pending_local[h] for h in self.local_handles],
+            self.max_prediction, frame,
+            min(frame - 2 * self.max_prediction, self._spectator_floor()),
+            echo_locals=True,
+        )
         self._pending_local.clear()
+        for h, echoed in zip(self.local_handles, stored):
+            for ep in peers:
+                for f, got in echoed:
+                    ep.queue_input(h, f, got)
 
         requests: List[object] = []
-
-        # Rollback: a confirmed input contradicted a prediction.
-        rollback_to = self._tracker.first_incorrect
-        if rollback_to != NULL_FRAME:
-            floor = frame - self.max_prediction
-            if rollback_to < floor:
-                # Deeper than the snapshot ring reaches — possible only
-                # when late inputs contradict a frame we already settled
-                # with a frozen prediction (a readmitted peer that never
-                # actually died). Roll back as far as snapshots exist; the
-                # residual divergence is exactly what desync detection +
-                # the supervisor's state resync repair.
-                rollback_to = floor
+        if load != NULL_FRAME:
+            # Rollback: a confirmed input contradicted a prediction. A load
+            # clamped to frame - max_prediction is deeper than the snapshot
+            # ring reaches: possible only when late inputs contradict a
+            # frame we already settled with a frozen prediction (a
+            # readmitted peer that never actually died). We roll back as
+            # far as snapshots exist; the residual divergence is exactly
+            # what desync detection + the supervisor's state resync repair.
             self.metrics.count("mispredictions")
-            self.metrics.observe("misprediction_depth", frame - rollback_to)
-            requests.append(LoadGameState(rollback_to))
-            for f in range(rollback_to, frame):
-                requests.append(SaveGameState(f))
-                requests.append(self._advance_request(f))
-            self._tracker.clear_first_incorrect()
-
-        # The new frame.
-        requests.append(SaveGameState(frame))
-        requests.append(self._advance_request(frame))
+            self.metrics.observe("misprediction_depth", frame - load)
+            requests.append(LoadGameState(load))
+        # The corrected frames, then the new one.
+        for i in range(len(bits)):
+            requests.append(SaveGameState(start + i))
+            requests.append(AdvanceFrame(bits=bits[i], status=status[i]))
         self.current_frame = frame + 1
 
-        self._fanout_spectators()
-        self._gc()
+        if spectators:
+            self._fanout_spectators()
+            self._gc()  # the fan-out moved the spectators' floor
         return requests
 
     def _advance_request(self, frame: int) -> AdvanceFrame:
@@ -822,11 +844,13 @@ class P2PSession:
                 continue
             cursor = self._spec_sent[addr] + 1
             floor = cursor if floor is None else min(floor, cursor)
-        return floor if floor is not None else 2**31
+        return floor if floor is not None else NEVER_DISCONNECTED
 
     def _gc(self) -> None:
         """Drop history that can no longer participate in a rollback or the
-        spectator fan-out."""
+        spectator fan-out. The advance call discards to this horizon itself
+        (with the spectators' floor as it stood before the fan-out); only a
+        session with spectators comes here, after the fan-out."""
         horizon = min(
             self.confirmed_frame(),
             # Two windows, not one: a quarantined peer replays from a donor

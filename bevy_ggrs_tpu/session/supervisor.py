@@ -561,6 +561,9 @@ class SessionSupervisor:
                 session._tracker.note_confirmed(h, f, frozen)
                 for addr in player_addrs:
                     session._endpoints[addr].queue_input(h, f, frozen)
+        # The queues and the disconnect map were written behind the
+        # session's two coarse calls: its cached frontier is stale.
+        session._refresh_frontier()
         self._freeze_until = (
             session.current_frame
             + _REJOIN_FREEZE_FACTOR * session.max_prediction

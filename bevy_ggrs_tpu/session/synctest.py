@@ -17,7 +17,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bevy_ggrs_tpu.schedule import CONFIRMED, InputSpec
+from bevy_ggrs_tpu.schedule import InputSpec
 from bevy_ggrs_tpu.session.common import (
     InvalidRequest,
     MismatchedChecksum,
@@ -49,8 +49,12 @@ class SyncTestSession:
         self.max_prediction = int(max_prediction)
         self.current_frame = 0
         zero = input_spec.zeros_np(1)[0]
-        self._qset = make_queue_set(zero, [input_delay] * num_players)
+        self._zero = zero
+        self._qset = make_queue_set(
+            zero, [input_delay] * num_players, window=self.max_prediction + 1
+        )
         self._queues = self._qset.queues
+        self._handles = list(range(self.num_players))
         self._pending: Dict[int, np.ndarray] = {}
         self._checksums: Dict[int, int] = {}
 
@@ -67,7 +71,9 @@ class SyncTestSession:
         (`ggrs_stage.rs:186`)."""
         if not 0 <= handle < self.num_players:
             raise InvalidRequest(f"invalid player handle {handle}")
-        self._pending[handle] = np.asarray(bits)
+        self._pending[handle] = np.asarray(
+            bits, dtype=self._zero.dtype
+        ).reshape(self._zero.shape)
 
     def advance_frame(self) -> List[object]:
         """Emit the request list for one simulated frame: the normal step,
@@ -76,34 +82,34 @@ class SyncTestSession:
             missing = set(range(self.num_players)) - set(self._pending)
             raise InvalidRequest(f"missing local input for handles {sorted(missing)}")
         frame = self.current_frame
-        for h, q in enumerate(self._queues):
-            q.add_local_input(frame, self._pending[h])
+        resim = self.check_distance > 0 and frame >= self.check_distance
+        # One call into the queue set: the local inputs, the frames of the
+        # forced rollback and this one gathered (all players are local and
+        # fed each frame, so every status reads CONFIRMED), and the GC of
+        # inputs older than the deepest future rollback.
+        horizon = frame - self.check_distance
+        start, _load, bits, status, _stored, _confirmed, _last = (
+            self._qset.advance(
+                None, frame, self._handles,
+                [self._pending[h] for h in self._handles],
+                self.max_prediction, horizon if resim else frame, horizon,
+            )
+        )
         self._pending.clear()
 
         requests: List[object] = [
             SaveGameState(frame),
-            self._advance_request(frame),
+            AdvanceFrame(bits=bits[-1], status=status[-1]),
         ]
-        if self.check_distance > 0 and frame >= self.check_distance:
-            load_frame = frame - self.check_distance
-            requests.append(LoadGameState(load_frame))
-            for f in range(load_frame, frame + 1):
-                requests.append(SaveGameState(f))
-                requests.append(self._advance_request(f))
+        if resim:
+            requests.append(LoadGameState(start))
+            for i in range(len(bits)):
+                requests.append(SaveGameState(start + i))
+                requests.append(AdvanceFrame(bits=bits[i], status=status[i]))
         self.current_frame = frame + 1
-        # GC: inputs/checksums older than the deepest future rollback.
-        horizon = self.current_frame - self.check_distance - 1
-        self._qset.discard_before(horizon)
         for f in [f for f in self._checksums if f < horizon]:
             del self._checksums[f]
         return requests
-
-    def _advance_request(self, frame: int) -> AdvanceFrame:
-        bits, _ = self._qset.gather(frame)
-        # All players are local and fed each frame, so every input is
-        # confirmed by construction.
-        status = np.full((self.num_players,), CONFIRMED, dtype=np.int32)
-        return AdvanceFrame(bits=bits, status=status)
 
     # -- checkpoint / resume -----------------------------------------------
 
