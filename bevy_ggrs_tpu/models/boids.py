@@ -145,15 +145,18 @@ def flock_system_mxu(state: WorldState, inputs: PlayerInputs) -> WorldState:
     """`flock_system` with the pairwise reductions carried by the MXU
     (:func:`bevy_ggrs_tpu.ops.pairwise.pairwise_force_rows_mxu2`): the
     neighborhood sums become feature-major bf16 matmuls with f32
-    accumulation (hi/lo-split operands, ~4e-4 relative error vs the f32
-    paths), while d2 and the membership masks stay f32 so borderline pairs
-    classify identically on all paths. The path that puts 1k boids x 128
-    branches x 8 frames under one 16 ms render frame (round-4 measured:
-    ~6.0 ms, was 8.5 in round 3 — the XLA row-operand relayout was the
-    gap; see the kernel docstrings). At N >= 4096 the square all-vs-all
+    accumulation (operands split into bf16 terms: two of everything, a
+    third of the positions the separation sum cancels), while d2 and the
+    membership masks stay f32 so borderline pairs classify identically on
+    all paths. Measured on the v5e through the normal path (1,024 boids,
+    128 branches x 8 frames in one fused tick; my chip run, PR 31,
+    `PERF.md` section 6): the tick 7.5 ms of device time, 6.9 ms of it this
+    kernel, against 15.6 ms through `flock_system_pallas`; one step within
+    5e-6 of a plain float32 NumPy reference (before PR 31's two repairs:
+    6.3 ms and 4.4e-5, more for a close pair). At N >= 4096 the square all-vs-all
     shape dispatches to the symmetry-halved triangle kernel
-    (:func:`~bevy_ggrs_tpu.ops.pairwise.pairwise_force_square_mxu_tri`,
-    ~25% faster at 4k and approaching 2x as N grows); below that the
+    (:func:`~bevy_ggrs_tpu.ops.pairwise.pairwise_force_square_mxu_tri`;
+    not measured on this chip); below that the
     block grid is too small to amortize the triangle's col-side work.
     Same session caveat as the other kernels: allclose across paths,
     bitwise only within one — and the two MXU shapes are themselves
@@ -232,7 +235,10 @@ def _pairwise_forces(
     Factored out so the entity-sharded variant can compute row blocks
     against the full (all-gathered) column set.
     """
-    return pairwise_force_rows(pos, vel, pos, vel, active, active)
+    from bevy_ggrs_tpu.ops.pairwise import FORCE_SCOPE
+
+    with jax.named_scope(FORCE_SCOPE):
+        return pairwise_force_rows(pos, vel, pos, vel, active, active)
 
 
 def pairwise_force_rows(
@@ -512,7 +518,11 @@ _KERNELS = {
 def make_schedule(use_pallas: bool = False, kernel: Optional[str] = None,
                   mode: Optional[str] = None) -> Schedule:
     """``kernel``: "xla" (GSPMD-partitionable), "pallas" (VPU-tiled), or
-    "mxu" (matmul reductions — fastest single-chip dense). ``use_pallas``
+    "mxu" (matmul reductions). On the v5e at 1,024 boids, 128 branches x 8
+    frames a fused tick (my chip run, PR 31; `PERF.md` section 6): "mxu"
+    7.5 ms a tick, "pallas" 15.6 ms, and "xla" fails the warm-up
+    attestation there (its vmapped rollout is not bitwise its serial
+    burst), so a session on it runs without speculation. ``use_pallas``
     is the legacy bool for the first two.
 
     ``mode`` selects the interaction structure: "dense" (the O(N²)

@@ -36,6 +36,14 @@ from jax.experimental.pallas import tpu as pltpu
 from bevy_ggrs_tpu.ops.interpret import pallas_interpret
 
 
+# Every dense force path runs under this scope (here and the XLA path of
+# models/boids.py), so a device trace's operation metadata says which
+# operations are the flocking force whatever the compiler names them, and
+# its last part names the Mosaic call in the optimized HLO: the trace shows
+# ``pairwise_force.N`` whichever kernel ran. docs/observability.md lists it.
+FORCE_SCOPE = "ggrs/pairwise_force"
+
+
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
@@ -116,6 +124,7 @@ def _force_kernel(
         "col_block",
     ),
 )
+@jax.named_scope(FORCE_SCOPE)
 def pairwise_force_rows_pallas(
     row_pos: jnp.ndarray,  # [R, 2]
     row_vel: jnp.ndarray,  # [R, 2]
@@ -191,6 +200,11 @@ def pairwise_force_rows_pallas(
 # ---------------------------------------------------------------------------
 
 
+# Rows of the separation feature stack: hi and lo of (active, px, py), and a
+# third term of px and py (see _lane_feats).
+SEP_ROWS = 8
+
+
 def _tcol(row: jnp.ndarray) -> jnp.ndarray:
     """[1, R] lane-major -> [R, 1] sublane-major, inside the kernel.
 
@@ -208,9 +222,12 @@ def _tcol(row: jnp.ndarray) -> jnp.ndarray:
     return jnp.transpose(row, (1, 0))
 
 
-def _pair_masks(rpx, rpy, cpx, cpy, *, neighbor_radius, separation_radius):
+def _pair_masks(rpx, rpy, cpx, cpy, *, neighbor_radius, separation_radius,
+                w_cap=None):
     """Shared mask block of both MXU kernels: pair distances -> the bf16
-    neighbor mask and the hi/lo-split separation weight matrix.
+    neighbor mask and the hi/lo-split separation weight matrix, and with
+    ``w_cap`` the float32 ``(dx, dy, w)`` beside a weight matrix capped at it
+    (see :func:`_close_pair_sums`; ``None`` without).
 
     ``d2`` and the membership compares stay f32 (borderline pairs classify
     identically on every path); ``rsqrt(d2)`` needs no epsilon clamp
@@ -229,9 +246,37 @@ def _pair_masks(rpx, rpy, cpx, cpy, *, neighbor_radius, separation_radius):
         nb & (d2 < jnp.float32(separation_radius) ** 2), inv_d,
         jnp.float32(0.0),
     )
+    uncapped = None
+    if w_cap is not None:
+        uncapped = (dx, dy, w)
+        w = jnp.minimum(w, jnp.float32(w_cap))
     w_hi = w.astype(jnp.bfloat16)
     w_lo = (w - w_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return neigh, w_hi, w_lo
+    return neigh, w_hi, w_lo, uncapped
+
+
+# The separation sum's matmul form, rpx * sum(w) - sum(w * cpx), cancels two
+# float32 numbers of size |p| * w: a pair at distance d costs the force about
+# 2e-8 * |p| / d (measured: 2e-5 at d = 1e-3 for |p| = 8, 3e-4 at 1e-4),
+# where differencing first costs nothing. So the matmuls carry each weight up
+# to CLOSE_W = 1 / (5e-3) only, and what a closer pair's weight has above it
+# goes through the differenced form on the VPU, in the block steps that hold
+# such a pair (about half of them at 1,024 boids; the step then costs a
+# fifth more, PERF.md section 6, PR 31).
+CLOSE_W = 200.0
+
+
+def _close_pair_sums(dx, dy, w, col_active):
+    """``sum_j max(w - CLOSE_W, 0) * (dx, dy)`` per row over the active
+    columns, lane-major ``[2, R]``: the part of the separation sum the
+    capped matmuls leave out, summed as differences. (The matmuls drop an
+    inactive column through its zero features; here it takes the mask.)"""
+    over = jnp.maximum(w - jnp.float32(CLOSE_W), jnp.float32(0.0)) * col_active
+    sums = jnp.concatenate(
+        [jnp.sum(dx * over, axis=1, keepdims=True),
+         jnp.sum(dy * over, axis=1, keepdims=True)], axis=1
+    )  # [R, 2]
+    return jnp.transpose(sums, (1, 0))
 
 
 def _acc_sums(acc_n, acc_w, sl=None, cacc_n=None, cacc_w=None):
@@ -250,17 +295,21 @@ def _acc_sums(acc_n, acc_w, sl=None, cacc_n=None, cacc_w=None):
     svx = row(acc_n, cacc_n, 3) + row(acc_n, cacc_n, 8)
     svy = row(acc_n, cacc_n, 4) + row(acc_n, cacc_n, 9)
     sw = row(acc_w, cacc_w, 0) + row(acc_w, cacc_w, 3)
-    swx = row(acc_w, cacc_w, 1) + row(acc_w, cacc_w, 4)
-    swy = row(acc_w, cacc_w, 2) + row(acc_w, cacc_w, 5)
+    swx = row(acc_w, cacc_w, 1) + row(acc_w, cacc_w, 4) + row(acc_w, cacc_w, 6)
+    swy = row(acc_w, cacc_w, 2) + row(acc_w, cacc_w, 5) + row(acc_w, cacc_w, 7)
     return n, spx, spy, svx, svy, sw, swx, swy
 
 
 def _combine_forces(sums, trpx, trpy, trvx, trvy, tra, *,
-                    w_separation, w_alignment, w_cohesion):
+                    w_separation, w_alignment, w_cohesion, close=None):
     """Shared combine of both MXU kernels: the hi+lo accumulator sums
-    (from :func:`_acc_sums`) -> the [1, R] force components, on lanes."""
+    (from :func:`_acc_sums`) -> the [1, R] force components, on lanes.
+    ``close`` is the ``[2, R]`` accumulator of :func:`_close_pair_sums`."""
     one = jnp.float32(1.0)
     n, spx, spy, svx, svy, sw, swx, swy = sums
+    if close is not None:
+        swx = swx - close[0:1, :]
+        swy = swy - close[1:2, :]
     n_safe = jnp.maximum(n, one)
     has = (n > 0).astype(jnp.float32)
     fx = (
@@ -287,24 +336,32 @@ _DOT_T = functools.partial(
 
 def _lane_feats(px, py, vx, vy, act):
     """Shared host-side prologue: lane-major [1, N] coordinate arrays ->
-    the bf16 hi/lo feature stacks ``(feat_t[10, N], sep_t[6, N])``.
+    the bf16 feature stacks ``(feat_t[10, N], sep_t[SEP_ROWS, N])``.
     Activity multiplies into the features here, so inactive and padded
     columns vanish from every neighborhood sum at zero per-pair cost."""
     f32feat = jnp.concatenate(
         [act, act * px, act * py, act * vx, act * vy], axis=0
     )  # [5, N] f32, feature-major
-    hi, lo = _hi_lo(f32feat)
+    hi, lo, rest = _hi_lo(f32feat)
     feat_t = jnp.concatenate([hi, lo], axis=0)  # [10, N] bf16
-    sep_t = jnp.concatenate([hi[0:3], lo[0:3]], axis=0)  # [6, N] bf16
+    # The separation sum is a cancellation, rpx * sum(w) - sum(w * cpx): what
+    # hi + lo drops of a position (2**-17 of a coordinate up to 8) comes out
+    # times sum(w), 4e-5 of a force on the v5e at 1,024 boids (PERF.md
+    # section 6, PR 31). A third bf16 term of the two position rows takes it
+    # to float32's own rounding, inside the 8-sublane tile the six rows
+    # already filled.
+    lo2 = _hi_lo(rest[1:3])[0]
+    sep_t = jnp.concatenate([hi[0:3], lo[0:3], lo2], axis=0)  # [8, N] bf16
     return feat_t, sep_t
 
 
 def _force_kernel_mxu2(
     trpx, trpy, trvx, trvy, tra,  # row refs [1, R_BLK] f32 (lane-major)
     cpx, cpy,  # col refs [1, C_BLK] f32
-    feat_t, sep_t,  # [10, C_BLK] / [6, C_BLK] bf16 feature blocks
+    feat_t, sep_t,  # [10, C_BLK] / [8, C_BLK] bf16 feature blocks
     fx_out, fy_out,  # [1, R_BLK]
-    acc_n, acc_w,  # VMEM scratch [10, R_BLK] / [6, R_BLK] f32
+    acc_n, acc_w,  # VMEM scratch [10, R_BLK] / [8, R_BLK] f32
+    acc_c,  # VMEM scratch [2, R_BLK] f32: close pairs' differenced sums
     rp_s,  # VMEM scratch [R_BLK, 2] f32: transposed row positions cache
     *,
     neighbor_radius: float,
@@ -344,7 +401,11 @@ def _force_kernel_mxu2(
     separation error reaches percents through the ``rpx·Σw − Σw·cpx``
     cancellation (dropping only the weight's lo term was measured at
     1.5e-3 relative force error for ~0.4 ms — rejected, accuracy class
-    kept). ``d2`` and the membership masks are computed in f32 exactly
+    kept). That cancellation is also why the positions of the separation
+    stack carry a third term (:func:`_lane_feats`) and why a pair closer
+    than ``1 / CLOSE_W`` leaves the matmul form (:func:`_close_pair_sums`):
+    with both, one step stays within a few 1e-6 of a float32 NumPy
+    reference whatever the flock (PR 31). ``d2`` and the membership masks are computed in f32 exactly
     like the XLA/VPU paths, so borderline pairs classify identically on
     all three; only summation rounding differs (allclose, not bitwise —
     the same session contract as the VPU kernel). ``rsqrt(d2)`` is taken
@@ -359,6 +420,7 @@ def _force_kernel_mxu2(
         # transposed rows can stay in vregs — no pl.when, no scratch trip.
         acc_n[...] = jnp.zeros_like(acc_n)
         acc_w[...] = jnp.zeros_like(acc_w)
+        acc_c[...] = jnp.zeros_like(acc_c)
         rpx = _tcol(trpx[...])
         rpy = _tcol(trpy[...])
     else:
@@ -366,6 +428,7 @@ def _force_kernel_mxu2(
         def _reset():
             acc_n[...] = jnp.zeros_like(acc_n)
             acc_w[...] = jnp.zeros_like(acc_w)
+            acc_c[...] = jnp.zeros_like(acc_c)
             rp_s[...] = jnp.concatenate(
                 [_tcol(trpx[...]), _tcol(trpy[...])], axis=1
             )
@@ -373,13 +436,21 @@ def _force_kernel_mxu2(
         rpx = rp_s[:, 0:1]
         rpy = rp_s[:, 1:2]
 
-    neigh, w_hi, w_lo = _pair_masks(
+    neigh, w_hi, w_lo, (dx, dy, w) = _pair_masks(
         rpx, rpy, cpx[...], cpy[...],
         neighbor_radius=neighbor_radius,
         separation_radius=separation_radius,
+        w_cap=CLOSE_W,
     )
     acc_n[...] += _DOT_T(feat_t[...], neigh)  # [10, R_BLK]
     acc_w[...] += _DOT_T(sep_t[...], w_hi) + _DOT_T(sep_t[...], w_lo)
+
+    @pl.when(jnp.max(w) > jnp.float32(CLOSE_W))
+    def _close_pairs():
+        # hi of an activity of 1.0 / 0.0 is the activity itself.
+        acc_c[...] += _close_pair_sums(
+            dx, dy, w, feat_t[0:1, :].astype(jnp.float32)
+        )
 
     @pl.when(cj == n_cols - 1)
     def _combine():
@@ -389,6 +460,7 @@ def _force_kernel_mxu2(
             w_separation=w_separation,
             w_alignment=w_alignment,
             w_cohesion=w_cohesion,
+            close=acc_c[...],
         )
         fx_out[...] = fx
         fy_out[...] = fy
@@ -406,6 +478,7 @@ def _force_kernel_mxu2(
         "col_block",
     ),
 )
+@jax.named_scope(FORCE_SCOPE)
 def pairwise_force_rows_mxu2(
     row_pos: jnp.ndarray,  # [R, 2]
     row_vel: jnp.ndarray,  # [R, 2]
@@ -458,7 +531,7 @@ def pairwise_force_rows_mxu2(
     trow_spec = pl.BlockSpec((1, r_blk), lambda ri, cj: (0, ri))
     col_spec = pl.BlockSpec((1, c_blk), lambda ri, cj: (0, cj))
     feat_spec = pl.BlockSpec((10, c_blk), lambda ri, cj: (0, cj))
-    sep_spec = pl.BlockSpec((6, c_blk), lambda ri, cj: (0, cj))
+    sep_spec = pl.BlockSpec((SEP_ROWS, c_blk), lambda ri, cj: (0, cj))
     out_spec = pl.BlockSpec((1, r_blk), lambda ri, cj: (0, ri))
     kernel = functools.partial(
         _force_kernel_mxu2,
@@ -480,7 +553,8 @@ def pairwise_force_rows_mxu2(
         ],
         scratch_shapes=[
             pltpu.VMEM((10, r_blk), jnp.float32),
-            pltpu.VMEM((6, r_blk), jnp.float32),
+            pltpu.VMEM((SEP_ROWS, r_blk), jnp.float32),
+            pltpu.VMEM((2, r_blk), jnp.float32),
             pltpu.VMEM((r_blk, 2), jnp.float32),
         ],
         interpret=pallas_interpret(),
@@ -489,16 +563,22 @@ def pairwise_force_rows_mxu2(
 
 
 
-def _hi_lo(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``x = hi + lo`` in two bf16 halves. The rounding to bf16 precision
+def _hi_lo(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``x = hi + lo + rest``: two bf16 halves and what they leave (float32,
+    exact, as the difference of a value and its own rounding is). The
+    rounding to bf16 precision
     is a ``reduce_precision``, which XLA must keep: written as a convert
     round trip (``x.astype(bf16).astype(f32)``) the TPU compiler, allowed
     excess precision, folds it to ``x`` and ``lo`` comes out all zero —
     measured on the v5e (chip_smoke.py, PR 21) as force errors of 6e-2 to
     3e0 through the separation term's cancellation. Same values bit for
     bit wherever the round trip was honoured (the CPU)."""
-    hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
-    return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
+    def rounded(v):
+        return jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+
+    hi = rounded(x)
+    lo = rounded(x - hi)
+    return hi.astype(jnp.bfloat16), lo.astype(jnp.bfloat16), x - hi - lo
 
 
 # ---------------------------------------------------------------------------
@@ -509,11 +589,11 @@ def _hi_lo(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 def _force_kernel_tri(
     trpx, trpy, trvx, trvy, tra,  # [1, B0] f32 row blocks (at ri)
     cpx, cpy,  # [1, B0] f32 col blocks (at cj)
-    feat_c, sep_c,  # [10, B0] / [6, B0] bf16 features at cj
-    feat_r, sep_r,  # [10, B0] / [6, B0] bf16 features at ri
+    feat_c, sep_c,  # [10, B0] / [8, B0] bf16 features at cj
+    feat_r, sep_r,  # [10, B0] / [8, B0] bf16 features at ri
     fx_out, fy_out,  # [1, B0] (at ri)
-    acc_n, acc_w,  # row-side scratch [10, B0] / [6, B0] f32
-    cacc_n, cacc_w,  # col-side scratch [10, NB] / [6, NB] f32 (full width)
+    acc_n, acc_w,  # row-side scratch [10, B0] / [8, B0] f32
+    cacc_n, cacc_w,  # col-side scratch [10, NB] / [8, NB] f32 (full width)
     rp_s,  # [B0, 2] f32 transposed row-position cache
     *,
     neighbor_radius: float,
@@ -547,7 +627,12 @@ def _force_kernel_tri(
     combine reads ``acc + cacc[k]``. The diagonal block covers its range
     entirely row-side (every entity there is a row). Accumulation
     regroups float sums vs the general kernel — allclose, not bitwise;
-    same per-session kernel-choice contract as every other path."""
+    same per-session kernel-choice contract as every other path.
+
+    Not here: the general kernel's differenced sums for close pairs
+    (:func:`_close_pair_sums`). Both directions of a block would need
+    them; a pair closer than 5e-3 keeps the matmul form's cancellation
+    (about 2e-8 * |p| / d of a force) until a cell runs N >= 4096."""
     ri = pl.program_id(0)
     cj = pl.program_id(1)
     n_cols = pl.num_programs(1)
@@ -567,7 +652,7 @@ def _force_kernel_tri(
 
     @pl.when(cj >= ri)
     def _compute():
-        neigh, w_hi, w_lo = _pair_masks(
+        neigh, w_hi, w_lo, _ = _pair_masks(
             rp_s[:, 0:1], rp_s[:, 1:2], cpx[...], cpy[...],
             neighbor_radius=neighbor_radius,
             separation_radius=separation_radius,
@@ -613,6 +698,7 @@ def _force_kernel_tri(
         "block",
     ),
 )
+@jax.named_scope(FORCE_SCOPE)
 def pairwise_force_square_mxu_tri(
     pos: jnp.ndarray,  # [N, 2]
     vel: jnp.ndarray,  # [N, 2]
@@ -654,9 +740,9 @@ def pairwise_force_square_mxu_tri(
     trow_spec = pl.BlockSpec((1, b0), lambda ri, cj: (0, ri))
     col_spec = pl.BlockSpec((1, b0), lambda ri, cj: (0, cj))
     feat_c_spec = pl.BlockSpec((10, b0), lambda ri, cj: (0, cj))
-    sep_c_spec = pl.BlockSpec((6, b0), lambda ri, cj: (0, cj))
+    sep_c_spec = pl.BlockSpec((SEP_ROWS, b0), lambda ri, cj: (0, cj))
     feat_r_spec = pl.BlockSpec((10, b0), lambda ri, cj: (0, ri))
-    sep_r_spec = pl.BlockSpec((6, b0), lambda ri, cj: (0, ri))
+    sep_r_spec = pl.BlockSpec((SEP_ROWS, b0), lambda ri, cj: (0, ri))
     out_spec = pl.BlockSpec((1, b0), lambda ri, cj: (0, ri))
     kernel = functools.partial(
         _force_kernel_tri,
@@ -679,9 +765,9 @@ def pairwise_force_square_mxu_tri(
         ],
         scratch_shapes=[
             pltpu.VMEM((10, b0), jnp.float32),
-            pltpu.VMEM((6, b0), jnp.float32),
+            pltpu.VMEM((SEP_ROWS, b0), jnp.float32),
             pltpu.VMEM((10, NB), jnp.float32),
-            pltpu.VMEM((6, NB), jnp.float32),
+            pltpu.VMEM((SEP_ROWS, NB), jnp.float32),
             pltpu.VMEM((b0, 2), jnp.float32),
         ],
         interpret=pallas_interpret(),
