@@ -29,6 +29,13 @@ warmup attestation replays ITS branches through the real serial burst —
 so the program whose states get committed is the program that was proven
 bitwise-equal to serial recovery, not a sibling compilation of it.
 
+One program is the right shape while the rollout is shorter than a call.
+The fused program's outputs, the live state among them, are ready when it
+ends, so a runner whose rollout is long (it measures both at warm-up:
+:func:`split_pays`) dispatches the same three phases as TWO programs, the
+front (:meth:`PackedTick.front`: absorb + burst) and behind it this
+executable with the rollout alone, and reads the state from the first.
+
 What goes into the program's int32 argument (:class:`TickInts`) is decided
 here too, on the host and once for every executor: :func:`match_pending`
 (a rollback against the pending rollout), :func:`plan_tick` (the commit,
@@ -337,6 +344,16 @@ def account_rollback(
     return outcome
 
 
+def split_pays(rollout_device_ms: float, extra_call_ms: float) -> bool:
+    """Whether a tick should go out as TWO programs, absorb + burst first
+    and the next rollout behind it, instead of the one fused program: the
+    second call costs the host ``extra_call_ms`` a tick, and spares whoever
+    reads the live state the ``rollout_device_ms`` that the fused program
+    holds its outputs back for. Both are measured by the runner on its own
+    executables at warm-up."""
+    return rollout_device_ms > extra_call_ms
+
+
 def wanted_rows(session, first_frame: int, n_frames: int):
     """``(row, frame)`` of the frames ``first_frame ..`` of one checksum
     array that ``session`` wants reported (every frame when it does not
@@ -424,6 +441,29 @@ class PackedTick:
             self.carry.pack((ring, state, spec_rings, spec_states)),
             state,
             self.cs.pack((absorb_cs, burst_cs, spec_cs)),
+        )
+
+    def front(self, carry, ints, bits):
+        """``(carry, state, (absorb_cs, burst_cs))`` of a split tick's
+        first program: :meth:`tick` without its rollout. The previous
+        rollout stays in the carry; the program behind it replaces it."""
+        T = TickInts
+        MF = self.burst_frames
+        P = bits.shape[1]
+        ring, state, prev_rings, prev_states = self.carry.unpack(carry)
+        status = ints[T.STATUS:T.STATUS + MF * P].reshape(MF, P)
+        mask = jnp.arange(MF, dtype=jnp.int32) < ints[T.N_BURST]
+        ring, state, absorb_cs, burst_cs = FusedTickExecutor._front_impl(
+            self.schedule, MF,
+            ring, state, prev_rings, prev_states, ints[T.BRANCH],
+            ints[T.ABSORB_FIRST], ints[T.ABSORB_N], ints[T.PREV_ANCHOR],
+            ints[T.PREV_TOTAL],
+            ints[T.DO_LOAD] != 0, ints[T.LOAD_FRAME], ints[T.START_FRAME],
+            bits, status, mask, mask,
+        )
+        return (
+            self.carry.pack((ring, state, prev_rings, prev_states)),
+            state, (absorb_cs, burst_cs),
         )
 
     def absorb(self, carry, ints):
@@ -576,6 +616,16 @@ class FusedTickExecutor:
             self._absorb = jax.jit(absorb)
             self._pack = jax.jit(self.packed.pack)
             self._unpack = jax.jit(self.packed.unpack)
+        # The first program of a split tick: built only for a runner whose
+        # warm-up found the split to pay (:meth:`build_front`).
+        self._front = None
+
+    def build_front(self) -> None:
+        """Make the front program (absorb + burst, :meth:`run_front`): a
+        single-device executor's, anonymous like the fused tick (the
+        benchmark's device-trace readers count both as peer 0's tick
+        programs). It compiles on its first call."""
+        self._front = jax.jit(functools.partial(self.packed.front))
 
     @staticmethod
     def _absorb_impl(
@@ -587,9 +637,13 @@ class FusedTickExecutor:
         matched branch's precomputed frames into the main ring — pure
         copies, no schedule execution. Kept separate from the fused tick
         so the corrected state's READINESS (when a render system can read
-        it) is bounded by the copy, not by the next rollout's compute: the
-        runner dispatches this first, then the rollout asynchronously into
-        the idle frame time."""
+        it) is bounded by the copy, not by the next rollout's compute: a
+        program's outputs are ready when the program ends. The same
+        principle covers every other tick of a runner whose rollout is
+        long (:meth:`_front_impl`: absorb + burst in a program of their
+        own, the rollout dispatched behind it); where the rollout is
+        shorter than one more call, the one fused program stays the
+        cheaper way to the state."""
         sel = lambda x: ring_row_read(x, branch)
         spec_ring_b = jax.tree_util.tree_map(sel, prev_rings)
         spec_state_b = jax.tree_util.tree_map(sel, prev_states)
@@ -597,6 +651,43 @@ class FusedTickExecutor:
             ring, spec_ring_b, spec_state_b, absorb_first, absorb_n,
             prev_anchor, prev_total, max_steps=burst_frames,
         )
+
+    @staticmethod
+    def _front_impl(
+        schedule, burst_frames,
+        ring, state,
+        prev_rings, prev_states, branch,
+        absorb_first, absorb_n, prev_anchor, prev_total,
+        do_load, load_frame, start_frame,
+        bits, status, save_mask, adv_mask,
+    ):
+        """Phases 1 and 2 of :meth:`_tick_impl`, from the same absorb and
+        burst bodies: the front program of a split tick. ``_tick_impl``
+        keeps its own lines (the served tiers trace it, and their set-up
+        time moves with the Python frames under that trace: ``PERF.md``
+        section 7); ``tests/test_split_tick.py`` holds the two to the same
+        bits."""
+        ring_a, state_a, absorb_cs = FusedTickExecutor._absorb_impl(
+            burst_frames, ring, prev_rings, prev_states, branch,
+            absorb_first, absorb_n, prev_anchor, prev_total,
+        )
+        do_absorb = absorb_n > 0
+        keep = lambda a, b: jnp.where(do_absorb, a, b)
+        ring = jax.tree_util.tree_map(keep, ring_a, ring)
+        state = jax.tree_util.tree_map(keep, state_a, state)
+        loaded = ring_load(ring, load_frame)
+        state = jax.tree_util.tree_map(
+            lambda l, s: jnp.where(do_load, l, s), loaded, state
+        )
+        frame0 = jnp.where(
+            do_load,
+            jnp.asarray(load_frame, jnp.int32),
+            jnp.asarray(start_frame, jnp.int32),
+        )
+        ring, state, burst_cs = rollout_burst(
+            schedule, ring, state, frame0, bits, status, save_mask, adv_mask
+        )
+        return ring, state, absorb_cs, burst_cs
 
     @staticmethod
     def _tick_impl(
@@ -730,6 +821,19 @@ class FusedTickExecutor:
         with self.span("tick_enqueue", program="fused"):
             out = self._fn(carry, *args)
         return self.io.count("fused", (carry, args), out)
+
+    def run_front(self, carry, ints, bits, status, branch_bits):
+        """Dispatch a split tick's first program on :meth:`run`'s
+        arguments (``branch_bits`` gives the payload's shape here; the
+        caller hands it to :meth:`run` with :func:`plan_rollout`'s row
+        next, on the carry this returns). Returns ``(carry, state,
+        (absorb_cs, burst_cs))``: the main ring and live state after the
+        burst, the previous rollout still in the carry."""
+        with self.span("tick_stage_args"):
+            args = self._stage_args(ints, bits, status, branch_bits)[:2]
+        with self.span("tick_enqueue", program="front"):
+            out = self._front(carry, *args)
+        return self.io.count("front", (carry, args), out)
 
     def _stage_args(self, ints, bits, status, branch_bits) -> tuple:
         """The three host arrays of the fused program: plain NumPy, which

@@ -58,6 +58,7 @@ from bevy_ggrs_tpu.fused import (
     plan_rollout,
     plan_tick,
     spec_in_window,
+    split_pays,
     wanted_rows,
 )
 from bevy_ggrs_tpu.native import spec as native_spec
@@ -115,6 +116,17 @@ def _absorb(
         main_ring, spec_ring, spec_states, first_frame, n_frames, anchor,
         total_spec, max_steps,
     )
+
+
+def _blocking_ms(call, reps: int = 3) -> float:
+    """Wall time of ``call()`` until its outputs are ready, in ms: the
+    least of ``reps`` (a pause of the host only ever adds)."""
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(call())
+        best = min(best, (time.perf_counter() - t0) * 1e3)
+    return best
 
 
 @dataclasses.dataclass(frozen=True)
@@ -425,24 +437,7 @@ def attest_speculation_safety(
         check_branches = B
     rng = np.random.RandomState(seed)
     real_checked = 0
-    zeros = runner.input_spec.zeros_np(P)
-    # Every element — scalar bitmask or vector field — draws from the
-    # runner's branch-value universe (InputSpec.values / branch_values,
-    # defaulting to 0..15), so the attestation exercises exactly the value
-    # range live speculation enumerates. A vector model whose fields carry
-    # values outside 0..15 was previously attested on a narrower universe
-    # than its branches actually use (round-3 advice #1). An explicitly
-    # empty universe (all branches replay the base prediction) falls back
-    # to the 0..15 draw rather than indexing an empty array.
-    if runner._branch_values:
-        vals = np.asarray(runner._branch_values, dtype=zeros.dtype)
-        bits = vals[
-            rng.randint(0, len(vals), size=(B, runner.spec_frames) + zeros.shape)
-        ]
-    else:
-        bits = rng.randint(
-            0, 16, size=(B, runner.spec_frames) + zeros.shape
-        ).astype(zeros.dtype)
+    bits = _attestation_random_bits(runner, rng)
     # The rollout side runs through the FUSED tick executable (absorb and
     # burst phases no-op'd) — the exact program live ticks commit states
     # from — not a sibling compilation of the vmapped rollout.
@@ -533,6 +528,26 @@ def attest_speculation_safety(
         scanned_proxy_divergence=proxy_divergence,
         real_checked=real_checked, exhaustive=exhaustive,
     )
+
+
+def _attestation_random_bits(
+    runner: "SpeculativeRollbackRunner", rng: np.random.RandomState
+) -> np.ndarray:
+    """The attestation's random branch tensor ``[B, spec_frames, P, ...]``.
+    Every element — scalar bitmask or vector field — draws from the
+    runner's branch-value universe (InputSpec.values / branch_values,
+    defaulting to 0..15), so the attestation exercises exactly the value
+    range live speculation enumerates. A vector model whose fields carry
+    values outside 0..15 was previously attested on a narrower universe
+    than its branches actually use (round-3 advice #1). An explicitly
+    empty universe (all branches replay the base prediction) falls back
+    to the 0..15 draw rather than indexing an empty array."""
+    zeros = runner.input_spec.zeros_np(runner.num_players)
+    shape = (runner.num_branches, runner.spec_frames) + zeros.shape
+    if runner._branch_values:
+        vals = np.asarray(runner._branch_values, dtype=zeros.dtype)
+        return vals[rng.randint(0, len(vals), size=shape)]
+    return rng.randint(0, 16, size=shape).astype(zeros.dtype)
 
 
 def _attestation_structured_bits(
@@ -643,6 +658,11 @@ class SpeculativeRollbackRunner(RollbackRunner):
     _ring = None  # the main ring as a tree, or None while only the carry is
     _state = None
     _result: Optional[SpecResult] = None
+    # Whether a tick goes out as two programs (warm-up decides, once), and
+    # the two times it decided from (None: not measured).
+    _split = False
+    rollout_device_ms: Optional[float] = None
+    extra_call_ms: Optional[float] = None
 
     @property
     def ring(self) -> SnapshotRing:
@@ -807,6 +827,10 @@ class SpeculativeRollbackRunner(RollbackRunner):
             entity_axis=entity_axis, state_template=self.state,
             session_axis=session_axis, span=self.span,
         )
+        # A mesh lays the programs out over devices and the session axis
+        # is a conformance mode of the ONE batched program: both keep the
+        # fused tick, unmeasured.
+        self._may_split = mesh is None and session_axis == 0
         self._key = jax.random.PRNGKey(seed)
         self._result = None
         self._spec_cs = None  # the fused tick's checksum output, as returned
@@ -957,6 +981,71 @@ class SpeculativeRollbackRunner(RollbackRunner):
                 # Under exhaustive mode the proxy's self-disqualification
                 # is moot — every branch was real-checked anyway.
                 self.metrics.count("attestation_degraded")
+        if self._may_split and self.speculation_enabled:
+            self._decide_split(np.asarray(bits))
+
+    def _decide_split(self, bits: np.ndarray) -> None:
+        """Choose, once, how many programs carry a tick (``fused.py``
+        :func:`~bevy_ggrs_tpu.fused.split_pays`) from two times taken here
+        on this runner's compiled executables and shapes: one rollout
+        dispatch, and one call that takes the same carry and runs no
+        rollout (the absorb-only program, committing nothing). The second
+        is what one more call costs; their difference is the device time
+        for which the fused program holds the live state back. A runner
+        that would split builds the front program and holds its burst to
+        the fused program's (:meth:`_front_agrees`)."""
+        fused, carry = self._fused, self._packed_carry()
+        ints = TickInts.zeros(fused.burst_frames, self.num_players)
+        plan_rollout(ints, self.frame, self.frame, self._ring_depth)
+        rollout_ms = _blocking_ms(
+            lambda: fused.run(carry, ints, (), (), bits)
+        )
+        self.extra_call_ms = _blocking_ms(
+            lambda: fused.commit_absorb(carry, 0, 0, 0, 0, self.spec_frames)
+        )
+        self.rollout_device_ms = rollout_ms - self.extra_call_ms
+        self.metrics.observe("rollout_device_ms", self.rollout_device_ms)
+        self.metrics.observe("extra_call_ms", self.extra_call_ms)
+        if not split_pays(self.rollout_device_ms, self.extra_call_ms):
+            return
+        fused.build_front()
+        if self._front_agrees():
+            self._split = True
+        else:
+            self.metrics.count("tick_split_refused")
+
+    def _front_agrees(self, check_branches: int = 8, seed: int = 0x5EED) -> bool:
+        """Speculation is safe because the serial burst and the rollout
+        agree bitwise (:func:`attest_speculation_safety`); a split tick
+        runs the burst in a third executable. Hold it to the fused
+        program's on the attestation's inputs: the first
+        ``check_branches`` rows of its random tensor, each a burst of the
+        attested depth from the live state, must leave the same state and
+        the same checksums, bit for bit. Nothing is adopted."""
+        fused, P = self._fused, self.num_players
+        F = min(self.spec_frames, fused.burst_frames)
+        bits = _attestation_random_bits(self, np.random.RandomState(seed))
+        status = np.zeros((F, P), np.int32)  # CONFIRMED
+        carry = self._packed_carry()
+        same = lambda a, b: np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        ints = TickInts.zeros(fused.burst_frames, P)
+        plan_tick(
+            ints, self.frame, None, F, None, 0, None, self.frame + F,
+            self._ring_depth,
+        )
+        for b in range(min(check_branches, self.num_branches)):
+            _, state, cs = fused.run(carry, ints, bits[b, :F], status, bits)
+            _, f_state, f_cs = fused.run_front(
+                carry, ints, bits[b, :F], status, bits
+            )
+            if not all(
+                same(x, y) for x, y in zip(
+                    jax.tree_util.tree_leaves((state, fused.cs_host(cs)[:2])),
+                    jax.tree_util.tree_leaves((f_state, f_cs)),
+                )
+            ):
+                return False
+        return True
 
     # ------------------------------------------------------------------
 
@@ -984,7 +1073,8 @@ class SpeculativeRollbackRunner(RollbackRunner):
         branch commit, and the NEXT speculative rollout — in ONE device
         dispatch (round-4 verdict item 1: ``handle_requests`` then
         ``speculate`` paid two calls on every steady tick and four on a
-        recovery tick, each a dispatch-floor on the 16.7 ms budget).
+        recovery tick, each a dispatch-floor on the 16.7 ms budget), or in
+        two where the rollout outlasts a call (below).
 
         Semantics are bit-identical to ``handle_requests(requests)``
         followed by ``speculate(confirmed_frame)``: the fused program
@@ -1003,6 +1093,24 @@ class SpeculativeRollbackRunner(RollbackRunner):
         absorb-only program on the row's first five values, any other the
         fused tick.
 
+        **The tick's two shapes.** The fused program's outputs, the live
+        state among them, are ready when the program ends, rollout
+        included. Where the rollout is long that is the wrong order: the
+        state a render system reads was computed in the first fraction of
+        a millisecond and only a FUTURE rollback may read the branches.
+        So :meth:`warmup` measures, on this runner's own executables, the
+        rollout's device time and what one more call costs
+        (``rollout_device_ms``, ``extra_call_ms``) and, where the first
+        exceeds the second (``fused.py`` ``split_pays``), every tick that
+        would run the fused program goes out as TWO: the front program
+        (absorb + burst; ``runner.state`` is ITS output) and, on the carry
+        it returns, the fused executable with the plan of a lane that has
+        no work (``plan_rollout``: the rollout alone). Same plan, same
+        bodies, bitwise the same states, rings, branch buffers and
+        checksums; the series ``tick_programs`` says how many programs a
+        tick dispatched. A mesh-sharded or session-axis runner keeps the
+        one program.
+
         Checksum reports from the fused paths are DEFERRED one tick:
         wanted checksums queue as device arrays and are read at the start
         of the next tick, by which time the producing program has
@@ -1015,7 +1123,11 @@ class SpeculativeRollbackRunner(RollbackRunner):
         just bench runs. Device work is asynchronous, so the interval is
         pure orchestration cost: what the 1 ms budget gates."""
         with self.span("spec_host_dispatch", frame=self.frame):
+            before = self.device_dispatches_total
             self._tick(requests, confirmed_frame, session)
+            self.metrics.observe(
+                "tick_programs", self.device_dispatches_total - before
+            )
 
     def _tick(self, requests, confirmed_frame: int, session=None) -> None:
         self.ticks_total += 1
@@ -1111,13 +1223,26 @@ class SpeculativeRollbackRunner(RollbackRunner):
             carry = self._packed_carry()
             self._spec_sig = sig
             tail = steps[n_commit:]
-            self.device_dispatches_total += 1
+            burst = (
+                [np.asarray(s.adv.bits) for s in tail],
+                [np.asarray(s.adv.status) for s in tail],
+            )
+            self.device_dispatches_total += 2 if self._split else 1
             with self.span("tick_dispatch", frame=end):
-                out = self._fused.run(
-                    carry, ints,
-                    [np.asarray(s.adv.bits) for s in tail],
-                    [np.asarray(s.adv.status) for s in tail], bits,
-                )
+                if self._split:
+                    # Absorb + burst in a program of their own; the fused
+                    # executable then runs the rollout alone, behind it, on
+                    # the carry it returned.
+                    front = self._fused.run_front(carry, ints, *burst, bits)
+                    self.metrics.observe(
+                        "tick_io_buffers", self._fused.io.last
+                    )
+                    carry, burst = front[0], ((), ())
+                    ints = TickInts.zeros(
+                        self._fused.burst_frames, self.num_players
+                    )
+                    plan_rollout(ints, end, anchor, self._ring_depth)
+                out = self._fused.run(carry, ints, *burst, bits)
             self._result = self._carried(out, bits, anchor)
             # The fused program just dispatched the NEXT rollout's B×F
             # speculative device frames (the waste-ratio numerator).
@@ -1126,6 +1251,15 @@ class SpeculativeRollbackRunner(RollbackRunner):
             cs_parts = (
                 (cs, 0, load_frame, n_commit), (cs, 1, burst_start, n_tail),
             )
+            if self._split:
+                # The live state is the FRONT program's, ready when the
+                # burst is done: the rollout program's is a value-identical
+                # pass-through that is ready only when the rollout ends.
+                self._state, (absorb_cs, burst_cs) = front[1:]
+                cs_parts = (
+                    (absorb_cs, None, load_frame, n_commit),
+                    (burst_cs, None, burst_start, n_tail),
+                )
         self.frame = end
         self.metrics.count("frames_advanced", n_steps)
         if load_frame is not None:
@@ -1156,7 +1290,8 @@ class SpeculativeRollbackRunner(RollbackRunner):
             return
         pending, self._pending_reports = self._pending_reports, []
         # An entry is (the fused tick's checksum output, which of its parts:
-        # 0 absorb, 1 burst) or (the absorb program's array, None).
+        # 0 absorb, 1 burst) or (an array of the absorb program or of a
+        # split tick's front program, None).
         with self.span("checksum_sync"):
             host = [
                 (np.asarray(cs) if part is None

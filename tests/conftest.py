@@ -30,3 +30,22 @@ from bevy_ggrs_tpu.utils.xla_cache import (  # noqa: E402
 )
 
 ensure_persistent_compilation_cache()
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def one_program_ticks():
+    """A speculative runner decides at warm-up, from two times it takes on
+    its own executables, whether a tick goes out as one program or as two
+    (``spec_runner.py`` ``_decide_split``). At this suite's toy sizes on a
+    CPU the two times are close, the choice would differ from run to run
+    and every count of dispatches with it: the suite's runners take no
+    times and keep the one program. ``tests/test_split_tick.py`` injects
+    the times it wants."""
+    from bevy_ggrs_tpu import spec_runner
+
+    # A patch of its own: a test may undo() the shared ``monkeypatch``.
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(spec_runner, "_blocking_ms", lambda call, reps=3: 0.0)
+        yield
