@@ -501,13 +501,16 @@ class IoBuffers:
     def __init__(self):
         self._by_program: dict = {}
         self.last = 0  # of the call just made
+        # Bytes of the host arrays that call staged (``tick_stage_bytes``).
+        self.staged_bytes = 0
 
-    def count(self, program: str, args, out):
+    def count(self, program: str, args, out, host=()):
         n = self._by_program.get(program)
         if n is None:
             n = len(jax.tree_util.tree_leaves((args, out)))
             self._by_program[program] = n
         self.last = n
+        self.staged_bytes = sum(a.nbytes for a in host)
         return out
 
 
@@ -551,7 +554,8 @@ class FusedTickExecutor:
         # (None = single-device; see SpeculativeRollbackRunner._prev_buffers).
         self.rings_sharding = None
         self.states_sharding = None
-        # ``io.last``: the series ``tick_io_buffers`` of the owning runner.
+        # ``io.last`` / ``io.staged_bytes``: the series ``tick_io_buffers``
+        # and ``tick_stage_bytes`` of the owning runner.
         self.io = IoBuffers()
         self.packed = PackedTick(
             schedule, self.burst_frames, self.num_branches, self.spec_frames,
@@ -797,7 +801,7 @@ class FusedTickExecutor:
             )
         with self.span("tick_enqueue", program="absorb"):
             out = self._absorb(carry, ints)
-        return self.io.count("absorb", (carry, ints), out)
+        return self.io.count("absorb", (carry, ints), out, host=(ints,))
 
     def run(self, carry, ints, bits, status, branch_bits):
         """Pad the burst to ``burst_frames`` and dispatch the whole tick.
@@ -820,7 +824,7 @@ class FusedTickExecutor:
             args = self._stage_args(ints, bits, status, branch_bits)
         with self.span("tick_enqueue", program="fused"):
             out = self._fn(carry, *args)
-        return self.io.count("fused", (carry, args), out)
+        return self.io.count("fused", (carry, args), out, host=args)
 
     def run_front(self, carry, ints, bits, status, branch_bits):
         """Dispatch a split tick's first program on :meth:`run`'s
@@ -833,7 +837,7 @@ class FusedTickExecutor:
             args = self._stage_args(ints, bits, status, branch_bits)[:2]
         with self.span("tick_enqueue", program="front"):
             out = self._front(carry, *args)
-        return self.io.count("front", (carry, args), out)
+        return self.io.count("front", (carry, args), out, host=args)
 
     def _stage_args(self, ints, bits, status, branch_bits) -> tuple:
         """The three host arrays of the fused program: plain NumPy, which

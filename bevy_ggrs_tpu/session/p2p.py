@@ -54,8 +54,7 @@ from bevy_ggrs_tpu.native.core import (
 )
 from bevy_ggrs_tpu.session.endpoint import PeerEndpoint, PeerState
 from bevy_ggrs_tpu.session.requests import AdvanceFrame, LoadGameState, SaveGameState
-from bevy_ggrs_tpu.obs.trace import null_tracer
-from bevy_ggrs_tpu.utils.metrics import null_metrics
+from bevy_ggrs_tpu.obs.trace import Instrumented
 
 # Upper bound on the AUTO desync-detection interval (frames between
 # checksum reports to peers). The effective default is
@@ -71,9 +70,12 @@ CHECKSUM_SEND_INTERVAL = 16
 SPECTATOR_MAX_LAG = 600
 
 
-class P2PSession:
+class P2PSession(Instrumented):
     """Use :class:`~bevy_ggrs_tpu.session.builder.SessionBuilder` to
-    construct (``start_p2p_session(socket)``)."""
+    construct (``start_p2p_session(socket)``). The spans inside it go to
+    the session's ``tracer`` alone, but ``spectator_fanout`` (series
+    ``spectator_fanout_ms``): what ``advance_frame()`` does for its
+    spectators, which a session without any never opens."""
 
     def __init__(
         self,
@@ -98,8 +100,7 @@ class P2PSession:
         self.num_players = int(num_players)
         self.input_spec = input_spec
         self.socket = socket
-        self.metrics = metrics if metrics is not None else null_metrics
-        self.tracer = tracer if tracer is not None else null_tracer
+        self._set_sinks(metrics, tracer)
         self.max_prediction = int(max_prediction)
         # Desync-detection cadence: "auto" picks the largest interval that
         # still (usually) keeps the divergent frame inside the snapshot
@@ -796,8 +797,9 @@ class P2PSession:
         self.current_frame = frame + 1
 
         if spectators:
-            self._fanout_spectators()
-            self._gc()  # the fan-out moved the spectators' floor
+            with self.span("spectator_fanout", frame=frame):
+                self._fanout_spectators()
+                self._gc()  # the fan-out moved the spectators' floor
         return requests
 
     def _advance_request(self, frame: int) -> AdvanceFrame:

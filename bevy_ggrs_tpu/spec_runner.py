@@ -710,12 +710,20 @@ class SpeculativeRollbackRunner(RollbackRunner):
             )
         return self._carry
 
+    def _observe_io(self) -> None:
+        """What the call just made handed the runtime: its buffers
+        (``tick_io_buffers``) and the bytes of the host arrays among them
+        (``tick_stage_bytes``), one sample a dispatch each."""
+        io = self._fused.io
+        self.metrics.observe("tick_io_buffers", io.last)
+        self.metrics.observe("tick_stage_bytes", io.staged_bytes)
+
     def _carried(self, out, branch_bits, anchor: int) -> SpecResult:
         """Adopt a fused tick's ``(carry, state, cs)``; returns the rollout
         it dispatched, its trees still inside the carry."""
         self._carry, self._state, self._spec_cs = out
         self._ring = None
-        self.metrics.observe("tick_io_buffers", self._fused.io.last)
+        self._observe_io()
         cs = self._spec_cs
         return SpecResult(
             rings=None, states=None,
@@ -1203,7 +1211,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
                     self._packed_carry(), *ints[:TickInts.ABSORB]
                 )
             self._ring = None
-            self.metrics.observe("tick_io_buffers", self._fused.io.last)
+            self._observe_io()
             cs_parts = ((cs, None, load_frame, n_commit),)
         else:
             with self.span("spec_tree_build", anchor=anchor):
@@ -1234,9 +1242,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
                     # executable then runs the rollout alone, behind it, on
                     # the carry it returned.
                     front = self._fused.run_front(carry, ints, *burst, bits)
-                    self.metrics.observe(
-                        "tick_io_buffers", self._fused.io.last
-                    )
+                    self._observe_io()
                     carry, burst = front[0], ((), ())
                     ints = TickInts.zeros(
                         self._fused.burst_frames, self.num_players
