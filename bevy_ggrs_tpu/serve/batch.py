@@ -719,7 +719,8 @@ class BatchedSessionCore(Instrumented):
         confirmed_frame, session)}`` (``confirmed_frame=None`` means fully
         confirmed; ``session`` may be None) — in as few batched dispatches
         as the deepest request list needs (one per Load-delimited segment;
-        the session layer emits single-segment lists, so normally one).
+        every session emits single-segment lists, so normally one: series
+        ``serve_rounds``).
 
         Fault atomicity: every slot's every segment (all rounds) is
         validated up front, so a :class:`SlotFault` escaping this method
@@ -755,6 +756,10 @@ class BatchedSessionCore(Instrumented):
             }
             with self.span("serve_round", round=r, slots=len(batch)):
                 self._dispatch(batch)
+        # The dispatch rounds this group tick ran: 1 when every list was
+        # one Load-delimited segment (every session's, SyncTest included).
+        self.metrics.observe("serve_rounds", rounds)
+        self.timeseries.observe("serve_rounds", rounds)
 
     def flush_reports(self) -> None:
         """Deliver deferred checksum reports (the only device->host sync

@@ -1,14 +1,35 @@
 """SyncTestSession: the determinism harness.
 
-All players are local. Every ``advance_frame`` first takes the normal
-(save, advance) step, then — once ``check_distance`` frames of history exist
-— emits a forced rollback ``check_distance`` frames deep and resimulates up
-to the present with the *same* stored inputs. When the driver re-saves each
-resimulated frame, the session compares the new checksum against the one
-recorded on the original pass; any mismatch raises
-:class:`MismatchedChecksum` — the simulate-vs-resimulate property check the
-reference runs continuously (`/root/reference/examples/box_game/
-box_game_synctest.rs:27-38`; driven by `src/ggrs_stage.rs:163-193`).
+All players are local. Once ``check_distance`` frames of history exist,
+every ``advance_frame`` first emits a forced rollback ``check_distance``
+frames deep, resimulates up to the present with the *same* stored inputs,
+and only then takes the new frame's own (save, advance) step: upstream
+ggrs's order (``SyncTestSession::advance_frame`` in
+``src/sessions/sync_test_session.rs``: ``adjust_gamestate(current_frame -
+check_distance)``, then ``save_current_state()`` + ``AdvanceFrame``). A
+frame is therefore ONE Load-delimited request list,
+
+    [Load(f - d), (Save(g), Advance(g)) for g in f - d .. f]
+
+the list a ``P2PSession`` emits on a rollback, and one dispatch of the
+driver. When the driver re-saves each resimulated frame, the session
+compares the new checksum against the one recorded on the frame's first
+save; any mismatch raises :class:`MismatchedChecksum` — the
+simulate-vs-resimulate property check the reference runs continuously
+(`/root/reference/examples/box_game/box_game_synctest.rs:27-38`; driven by
+`src/ggrs_stage.rs:163-193`). Every frame is saved ``check_distance + 1``
+times (at ticks ``g .. g + d``, each from a snapshot one frame newer than
+the last) and compared ``check_distance`` times; the last of them is the
+re-save of the snapshot just loaded, so ``check_distance`` 1 holds a
+snapshot to its own checksum and nothing else (as upstream's, which saves
+each frame once there): a non-deterministic step needs 2, the default.
+
+What the order does NOT catch: the live state is loaded over before it is
+saved, so a live state changed between two ticks at a frame >=
+``check_distance`` never enters a checksum. Non-determinism of the step
+does, within ``check_distance`` ticks; resident state is the attestation
+sweep's to guard (``serve/server.py`` ``_attest_sweep``,
+``RollbackRunner.attest_and_repair``).
 """
 
 from __future__ import annotations
@@ -76,8 +97,9 @@ class SyncTestSession:
         ).reshape(self._zero.shape)
 
     def advance_frame(self) -> List[object]:
-        """Emit the request list for one simulated frame: the normal step,
-        plus the forced rollback+resimulation once history allows."""
+        """Emit the request list for one simulated frame: the forced
+        rollback + resimulation once history allows, then the frame's own
+        step, as one Load-delimited list."""
         if set(self._pending) != set(range(self.num_players)):
             missing = set(range(self.num_players)) - set(self._pending)
             raise InvalidRequest(f"missing local input for handles {sorted(missing)}")
@@ -97,15 +119,12 @@ class SyncTestSession:
         )
         self._pending.clear()
 
-        requests: List[object] = [
-            SaveGameState(frame),
-            AdvanceFrame(bits=bits[-1], status=status[-1]),
-        ]
-        if resim:
-            requests.append(LoadGameState(start))
-            for i in range(len(bits)):
-                requests.append(SaveGameState(start + i))
-                requests.append(AdvanceFrame(bits=bits[i], status=status[i]))
+        # ``start`` is ``frame`` before history allows a rollback: then the
+        # list is the frame's own (save, advance) alone.
+        requests: List[object] = [LoadGameState(start)] if resim else []
+        for i in range(len(bits)):
+            requests.append(SaveGameState(start + i))
+            requests.append(AdvanceFrame(bits=bits[i], status=status[i]))
         self.current_frame = frame + 1
         for f in [f for f in self._checksums if f < horizon]:
             del self._checksums[f]

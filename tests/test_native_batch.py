@@ -212,6 +212,96 @@ def test_churn_zero_recompiles_on_plane():
 
 
 # ---------------------------------------------------------------------------
+# SyncTest groups: one Load-delimited list a frame, one dispatch a tick
+# ---------------------------------------------------------------------------
+
+PLANES = [
+    pytest.param("native", marks=native),
+    # The whole GGRS_NO_NATIVE=1 route: sessions on PyQueueSet, the core
+    # on _dispatch_python.
+    "python",
+]
+
+
+def _synctest_tick(core, sessions, frame):
+    work = {}
+    for slot, session in sessions.items():
+        for h in range(P):
+            session.add_local_input(h, _inputs_for(slot)(frame, h))
+        work[slot] = (session.advance_frame(), None, session)
+    core.tick(work)
+
+
+@pytest.mark.parametrize("plane", PLANES)
+def test_synctest_group_is_one_dispatch_a_tick(plane, monkeypatch):
+    """A group of SyncTest matches past frame ``check_distance`` makes
+    exactly one device dispatch a ``BatchedSessionCore.tick`` (the forced
+    rollback and the frame's own step are one canonical segment), series
+    ``serve_rounds`` reads 1, the matches' own checksum compares stay
+    silent, and the batched executable is still the one of warm-up."""
+    from bevy_ggrs_tpu.utils.metrics import Metrics
+
+    if plane == "python":
+        monkeypatch.setattr(ncore, "available", lambda: False)
+    metrics = Metrics()
+    core = make_core(num_slots=4, metrics=metrics)
+    assert (core._plane is not None) == (plane == "native")
+    sessions = {core.admit(): _make_session() for _ in range(3)}
+    for f in range(12):
+        d0, t0 = core.device_dispatches_total, core.ticks_total
+        _synctest_tick(core, sessions, f)
+        assert core.ticks_total - t0 == 1
+        assert core.device_dispatches_total - d0 == 1, f
+    core.flush_reports()  # the last tick's compares
+    assert metrics.series["serve_rounds"] == [1] * 12
+    assert all(core.slots[s].frame == 12 for s in sessions)
+    assert core.rollbacks_total == 3 * 10  # every match, every frame >= 2
+    assert core._exec.cache_size() == 1
+
+
+@pytest.mark.parametrize("plane", PLANES)
+def test_two_loads_in_a_list_still_run_as_two_rounds(plane, monkeypatch):
+    """The general path stays: a list that does hold two ``Load``s (none
+    of this repo's sessions emits one since PR 34) is two segments, two
+    rounds, two dispatches, beside a one-segment slot that idles in the
+    second round; the result is what two ticks would have given."""
+    from bevy_ggrs_tpu.utils.metrics import Metrics
+    from tests.test_batched_sessions import (
+        rollback_requests,
+        step_requests,
+    )
+
+    if plane == "python":
+        monkeypatch.setattr(ncore, "available", lambda: False)
+    metrics = Metrics()
+    twice, once = make_core(num_slots=2, metrics=metrics), make_core(
+        num_slots=2)
+    rng = np.random.RandomState(3)
+    bits = [rng.randint(0, 16, size=P) for _ in range(4)]
+    for core in (twice, once):
+        a, b = core.admit(), core.admit()
+        for f in range(3):
+            core.tick({s: (step_requests(f, bits[f]), None, None)
+                       for s in (a, b)})
+    first = rollback_requests(1, bits[1:3])  # Load(1), frames 1, 2
+    second = rollback_requests(2, bits[2:3]) + step_requests(3, bits[3])
+    d0 = twice.device_dispatches_total
+    twice.tick({a: (first + second, None, None),
+                b: (step_requests(3, bits[3]), None, None)})
+    assert twice.device_dispatches_total - d0 == 2
+    assert metrics.series["serve_rounds"][-1] == 2
+    once.tick({a: (first, None, None),
+               b: (step_requests(3, bits[3]), None, None)})
+    once.tick({a: (second, None, None)})
+    for s in (a, b):
+        assert twice.slots[s].frame == once.slots[s].frame == 4
+        assert combine64(checksum(twice.slot_state(s))) == combine64(
+            checksum(once.slot_state(s)))
+    assert np.array_equal(
+        np.asarray(twice.rings.checksums), np.asarray(once.rings.checksums))
+
+
+# ---------------------------------------------------------------------------
 # Slot template pool: pre-warmed admission is bitwise-invisible
 # ---------------------------------------------------------------------------
 

@@ -373,7 +373,7 @@ def test_a_segment_longer_than_the_bound_buffers_is_refused():
 
 
 # ---------------------------------------------------------------------------
-# SyncTest: the same call for its check_distance + 2 gathers
+# SyncTest: the same call for its check_distance + 1 gathers
 
 
 def parent_synctest_requests(qs, frame, check_distance, bits):
@@ -449,6 +449,13 @@ def test_synctest_requests_are_the_parents(
             lists.append(s.advance_frame())
         want = canon_requests(
             parent_synctest_requests(parent, frame, check_distance, bits))
+        if ("LoadGameState", frame - check_distance) in want:
+            # PR 34, upstream's order: the forced rollback comes first and
+            # steps the new frame itself, so the parent's leading (Save,
+            # Advance) of that frame goes and the rest is the parent's.
+            assert want[2] == ("LoadGameState", frame - check_distance)
+            assert want[:2] == want[-2:]
+            want = want[2:]
         assert canon_requests(lists[0]) == want, frame
         assert canon_requests(lists[1]) == want, frame
         for arr, was in kept:

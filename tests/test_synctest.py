@@ -7,6 +7,8 @@ Reference behavior: `examples/box_game/box_game_synctest.rs:27-38` +
 `src/ggrs_stage.rs:163-193`.
 """
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -22,8 +24,9 @@ from bevy_ggrs_tpu.session import (
 from bevy_ggrs_tpu.session.requests import AdvanceFrame, LoadGameState, SaveGameState
 
 
-def make(num_players=2, check_distance=2, input_delay=0, max_prediction=8):
-    session = SyncTestSession(
+def make(num_players=2, check_distance=2, input_delay=0, max_prediction=8,
+         session_cls=SyncTestSession):
+    session = session_cls(
         num_players,
         box_game.INPUT_SPEC,
         check_distance=check_distance,
@@ -57,12 +60,175 @@ def test_request_shape_before_and_after_check_distance():
         for h in range(2):
             session.add_local_input(h, np.uint8(0))
         reqs = session.advance_frame()
-    # Frame 2: forced rollback 2 deep → Save, Advance, Load(0), then 3
-    # (Save, Advance) pairs replaying frames 0..2.
+    # Frame 2: forced rollback 2 deep FIRST, the frame's own step last
+    # (upstream ggrs's order): Load(0), then 3 (Save, Advance) pairs for
+    # frames 0..2, frame 2 stepped once.
     kinds = [type(r) for r in reqs]
-    assert kinds == [SaveGameState, AdvanceFrame, LoadGameState] + [
-        SaveGameState, AdvanceFrame] * 3
-    assert reqs[2].frame == 0
+    assert kinds == [LoadGameState] + [SaveGameState, AdvanceFrame] * 3
+    assert reqs[0].frame == 0
+    assert [r.frame for r in reqs[1::2]] == [0, 1, 2]
+
+
+class CountingSession(SyncTestSession):
+    """Counts what ``report_checksum`` is told, frame by frame."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.reported = {}
+
+    def report_checksum(self, frame, checksum):
+        self.reported[frame] = self.reported.get(frame, 0) + 1
+        super().report_checksum(frame, checksum)
+
+
+DISTANCES = [1, 2, 4, 8]
+
+
+@pytest.mark.parametrize("d", DISTANCES)
+def test_every_list_is_one_segment(d):
+    """One Load-delimited list a frame, whatever the check distance: the
+    frame's own step alone before history allows a rollback, ``d + 1``
+    steps from ``Load(f - d)`` after."""
+    session, _ = make(check_distance=d)
+    for f in range(3 * d + 2):
+        for h in range(2):
+            session.add_local_input(h, np.uint8(f % 16))
+        reqs = session.advance_frame()
+        segs = RollbackRunner._segment(None, reqs)
+        assert len(segs) == 1
+        load, steps = segs[0]
+        if f < d:
+            assert load is None and len(steps) == 1
+        else:
+            assert load == f - d and len(steps) == d + 1
+        start = f if load is None else load
+        assert [st.save_frame for st in steps] == list(
+            range(start, start + len(steps)))
+        assert all(st.adv is not None for st in steps)
+
+
+@pytest.mark.parametrize("d", DISTANCES)
+def test_every_frame_is_reported_d_plus_one_times(d):
+    """Over 40 seeded frames every frame old enough is saved ``d + 1``
+    times (its first save and ``d`` comparisons, upstream's count), and
+    the run ends on the straight-line state."""
+    session, runner = make(check_distance=d, session_cls=CountingSession)
+    sched = box_game.make_schedule()
+    oracle = box_game.make_world(2).commit()
+    rng = np.random.RandomState(d)
+    for _ in range(40):
+        bits = rng.randint(0, 16, size=2).astype(np.uint8)
+        tick(session, runner, bits)
+        oracle = sched(oracle, make_inputs(bits))
+    for f in range(40):
+        # Frame f is saved first at tick f and again at every later tick
+        # that rolls back to f or before it: ticks f + 1 .. f + d, of which
+        # the first d - 1 load nothing yet and the run stops after tick 39.
+        again = range(max(f + 1, d), min(f + d, 39) + 1)
+        assert session.reported[f] == 1 + len(again), f
+        if d <= f <= 39 - d:
+            assert session.reported[f] == d + 1
+    assert runner.frame == 40
+    assert combine64(checksum(runner.state)) == combine64(checksum(oracle))
+
+
+@pytest.mark.parametrize("d", DISTANCES)
+def test_tampered_snapshot_is_caught_at_its_load(d):
+    """A snapshot that no longer is what was saved (the ring row the next
+    tick loads, changed behind the session's back) is re-saved right after
+    its load and hashes differently: caught in that tick, whatever the
+    check distance. (With ``verify_restores`` on, the runner's own restore
+    guard repairs the row first: that is the SDC path, not the harness.)"""
+    session, runner = make(check_distance=d)
+    runner.verify_restores = False
+    for i in range(d + 3):
+        tick(session, runner, np.asarray([i % 16, (i + 5) % 16], np.uint8))
+    ring = runner.ring
+    target = runner.frame - d
+    row = target % ring.depth
+    runner.ring = ring.replace(states=ring.states.replace(components={
+        **ring.states.components,
+        "translation": ring.states.components["translation"].at[row].add(
+            0.001),
+    }))
+    with pytest.raises(MismatchedChecksum) as e:
+        tick(session, runner, np.zeros(2, np.uint8))
+    assert e.value.frame == target
+
+
+@pytest.mark.parametrize("d, caught", [(1, False), (2, True), (4, True),
+                                       (8, True)])
+def test_nondeterministic_step_is_caught_within_d_plus_one_ticks(d, caught):
+    """The step itself made non-deterministic at a frame >= ``d``: a
+    system reads host state the snapshots do not hold, and that state
+    changes between two ticks. The first resimulation after the change
+    re-saves a frame that hashed differently on its first pass.
+
+    ``check_distance`` 1 is PINNED as blind to it, as upstream's is: its
+    one re-save of a frame is of the snapshot just loaded (the test above
+    is all it compares), every resimulated state is saved for the first
+    time. The builder's default is 2."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import io_callback
+
+    from bevy_ggrs_tpu.schedule import Schedule
+
+    host = {"drift": 0.0}
+
+    def leaky_system(state, inputs):
+        del inputs
+        drift = io_callback(
+            lambda: np.float32(host["drift"]),
+            jax.ShapeDtypeStruct((), jnp.float32),
+        )
+        comps = dict(state.components)
+        comps["translation"] = comps["translation"] + drift
+        return state.replace(components=comps)
+
+    session, _ = make(check_distance=d)
+    runner = RollbackRunner(
+        Schedule([box_game.move_cube_system, leaky_system,
+                  box_game.increase_frame_system]),
+        box_game.make_world(2).commit(),
+        max_prediction=8, num_players=2, input_spec=box_game.INPUT_SPEC,
+    )
+    for i in range(d + 3):  # past frame d: every tick rolls back
+        tick(session, runner, np.asarray([i % 16, (i + 5) % 16], np.uint8))
+    host["drift"] = 0.001
+    with (pytest.raises(MismatchedChecksum) if caught
+          else contextlib.nullcontext()):
+        for _ in range(d + 1):
+            tick(session, runner, np.zeros(2, np.uint8))
+    assert (runner.frame < 2 * d + 4) == caught
+
+
+def test_live_state_tampered_between_ticks_is_loaded_over():
+    """PINS a consequence of upstream's order (rollback first, own step
+    last): at a frame >= ``check_distance`` the live state is loaded over
+    before anything saves it, so a live state changed BETWEEN two ticks
+    never enters a checksum and the run ends on the straight-line state.
+    (Before frame ``check_distance`` the list is [Save, Advance] and the
+    tamper IS saved and caught: ``test_synctest_detects_nondeterminism``.)
+    What the harness exists for is a non-deterministic STEP (the test
+    above); resident state is guarded by the attestation sweep
+    (``RollbackRunner.attest_and_repair``, ``MatchServer._attest_sweep``;
+    tests/test_integrity.py)."""
+    session, runner = make(num_players=2, check_distance=2)
+    sched = box_game.make_schedule()
+    oracle = box_game.make_world(2).commit()
+    rng = np.random.RandomState(7)
+    for i in range(12):
+        if i == 6:
+            runner.state = runner.state.replace(components={
+                **runner.state.components,
+                "translation": runner.state.components["translation"] + 0.5,
+            })
+        bits = rng.randint(0, 16, size=2).astype(np.uint8)
+        tick(session, runner, bits)
+        oracle = sched(oracle, make_inputs(bits))
+    assert runner.frame == 12
+    assert combine64(checksum(runner.state)) == combine64(checksum(oracle))
 
 
 def test_synctest_deterministic_game_runs_clean():
