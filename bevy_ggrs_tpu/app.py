@@ -30,6 +30,7 @@ from typing import Callable, List, Optional
 import numpy as np
 
 from bevy_ggrs_tpu.obs.trace import (
+    NULL_SPAN,
     Instrumented,
     attach_process_events,
     detach_process_events,
@@ -257,8 +258,16 @@ class GGRSStage(Instrumented):
             flush = getattr(self.runner, "flush_reports", None)
             if flush is not None:
                 flush(app.session)
-            with self.span("poll"):
-                app.session.poll_remote_clients(now)
+            with self.span("poll") as sp_poll:
+                if sp_poll is NULL_SPAN:
+                    app.session.poll_remote_clients(now)
+                else:
+                    # While a sink listens, the poll's receive and send
+                    # sides as two series, one sample a tick.
+                    parts = [0.0, 0.0]
+                    app.session.poll_remote_clients(now, parts)
+                    self.metrics.observe("poll_recv_ms", parts[0] * 1000.0)
+                    self.metrics.observe("poll_send_ms", parts[1] * 1000.0)
             app.events.extend(app.session.events())
 
         self.accumulator += delta

@@ -76,10 +76,15 @@ _STACKS_LOCK = threading.Lock()  # guards registry insertion only
 
 
 class _StackToken:
-    __slots__ = ("name",)
+    # ``child_ms``: what the spans that closed directly under this one
+    # covered so far (a span's self time is its duration less this);
+    # None for a bare marker (``push_span`` outside a span), which is
+    # nobody's parent: it may overlap its neighbours.
+    __slots__ = ("name", "child_ms")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, child_ms: Optional[float] = None):
         self.name = name
+        self.child_ms = child_ms
 
 
 def _stack_for(ident: Optional[int] = None) -> List["_StackToken"]:
@@ -91,27 +96,34 @@ def _stack_for(ident: Optional[int] = None) -> List["_StackToken"]:
     return stack
 
 
-def push_span(name: str) -> _StackToken:
+def push_span(name: str, child_ms: Optional[float] = None) -> _StackToken:
     """Mark ``name`` as the innermost open span on the calling thread.
-    Returns a token for :func:`pop_span`."""
-    tok = _StackToken(name)
+    Returns a token for :func:`pop_span`. A :class:`_Span` passes 0.0 for
+    ``child_ms`` and is credited its children's durations there."""
+    tok = _StackToken(name, child_ms)
     _stack_for().append(tok)
     return tok
 
 
-def pop_span(token: _StackToken) -> None:
-    """Close a span marker. Tolerates non-LIFO closes (removal by token
-    identity) and double-pops (a missing token is a no-op)."""
+def pop_span(token: _StackToken) -> Optional[_StackToken]:
+    """Close a span marker and return its parent's: the innermost SPAN
+    still open (bare markers are skipped), or None where there is none.
+    Tolerates non-LIFO closes (removal by token identity; no parent is
+    named for one) and double-pops (a missing token is a no-op)."""
     stack = _SPAN_STACKS.get(threading.get_ident())
     if not stack:
-        return
+        return None
     if stack[-1] is token:
         stack.pop()
-        return
+        for parent in reversed(stack):
+            if parent.child_ms is not None:
+                return parent
+        return None
     try:
         stack.remove(token)
     except ValueError:
         pass
+    return None
 
 
 def open_span_stack(thread_ident: int) -> Tuple[str, ...]:
@@ -125,12 +137,13 @@ def open_span_stack(thread_ident: int) -> Tuple[str, ...]:
 
 class _Span:
     """One open span: Metrics series + tracer ring + trace annotation.
-    ``ms`` is the duration once closed, for a site that derives a sum or
-    a self time from its spans."""
+    Once closed, ``ms`` is its duration and ``self_ms`` what the spans
+    that closed directly under it (of any object on the thread) left of
+    it, for a site that writes a sum or a remainder as a series."""
 
     __slots__ = (
         "_metrics", "_tr", "_name", "_args", "_series", "_t0", "_b_us",
-        "_tok", "_ann", "ms",
+        "_tok", "_ann", "ms", "self_ms",
     )
 
     def __init__(self, metrics, tracer, name: str, args, series: bool = True):
@@ -139,13 +152,13 @@ class _Span:
         self._name = name
         self._args = args
         self._series = series
-        self.ms = 0.0
+        self.ms = self.self_ms = 0.0
 
     def __enter__(self):
         name, args = self._name, self._args
         self._ann = ann = TraceAnnotation(TRACE_PREFIX + name, **args)
         ann.__enter__()
-        self._tok = push_span(name)
+        self._tok = push_span(name, 0.0)
         self._t0 = t0 = time.perf_counter()
         if self._tr is not null_tracer:
             self._b_us = self._tr._begin(name, t0, args or None)
@@ -158,7 +171,10 @@ class _Span:
             self._metrics.observe(self._name + "_ms", ms)
         if self._tr is not null_tracer:
             self._tr._end(self._name, t1, self._b_us)
-        pop_span(self._tok)
+        parent = pop_span(self._tok)
+        if parent is not None:
+            parent.child_ms += ms
+        self.self_ms = ms - self._tok.child_ms
         self._ann.__exit__(*exc)
         return False
 
@@ -166,7 +182,7 @@ class _Span:
 class _NullSpan:
     __slots__ = ()
 
-    ms = 0.0
+    ms = self_ms = 0.0
 
     def __enter__(self):
         return self

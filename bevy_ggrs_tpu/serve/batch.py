@@ -464,7 +464,6 @@ class BatchedSessionCore(Instrumented):
             self.burst_frames, self._predictor,
         )
         self.native_batch_calls = 0
-        self.native_batch_ms_total = 0.0
         # Optional AttributionProbe (obs/attribution.py): when a bench
         # attaches one, the executor call is timed as a nested
         # device_wait so backends whose dispatch blocks on the in-flight
@@ -487,11 +486,6 @@ class BatchedSessionCore(Instrumented):
         self.rollbacks_total = 0
         self.rollback_frames_total = 0
         self.rollback_frames_recovered_total = 0
-        # Last dispatch's measured host-work split (docs/serving.md
-        # "Front door"): the known per-slot Python-loop budget, decomposed
-        # so the ROADMAP's native-argument-assembly item has a baseline.
-        self.last_branch_build_ms = 0.0
-        self.last_arg_assembly_ms = 0.0
         self.last_predictor_rank_ms = 0.0
         self.predictor_rank_ms_total = 0.0
         self.predictor_rank_dispatches = 0
@@ -730,25 +724,29 @@ class BatchedSessionCore(Instrumented):
         self.flush_reports()
         per_slot: Dict[int, List[tuple]] = {}
         rounds = 1
-        for slot, (requests, confirmed, session) in work.items():
-            if not self.slots[slot].active:
-                raise RuntimeError(f"slot {slot} is not active")
-            frame = self.slots[slot].frame
-            try:
-                segs = RollbackRunner._segment(None, requests)
-            except TypeError as e:
-                reason = (
-                    "restore_request"
-                    if any(isinstance(r, RestoreGameState) for r in requests)
-                    else "unsupported_request"
-                )
-                raise SlotFault(slot, reason, frame, cause=e) from e
-            for load, steps in segs:
-                frame = self._validate_segment(slot, frame, load, steps)
-            per_slot[slot] = [
-                (load, steps, confirmed, session) for load, steps in segs
-            ]
-            rounds = max(rounds, len(segs))
+        # The pre-pass of the whole group, one span (no span a slot).
+        with self.span("serve_segment", slots=len(work)):
+            for slot, (requests, confirmed, session) in work.items():
+                if not self.slots[slot].active:
+                    raise RuntimeError(f"slot {slot} is not active")
+                frame = self.slots[slot].frame
+                try:
+                    segs = RollbackRunner._segment(None, requests)
+                except TypeError as e:
+                    reason = (
+                        "restore_request"
+                        if any(
+                            isinstance(r, RestoreGameState) for r in requests
+                        )
+                        else "unsupported_request"
+                    )
+                    raise SlotFault(slot, reason, frame, cause=e) from e
+                for load, steps in segs:
+                    frame = self._validate_segment(slot, frame, load, steps)
+                per_slot[slot] = [
+                    (load, steps, confirmed, session) for load, steps in segs
+                ]
+                rounds = max(rounds, len(segs))
         for r in range(rounds):
             batch = {
                 slot: segs[r] for slot, segs in per_slot.items()
@@ -763,22 +761,33 @@ class BatchedSessionCore(Instrumented):
 
     def flush_reports(self) -> None:
         """Deliver deferred checksum reports (the only device->host sync
-        in the serving loop, off the producing dispatch's critical path)."""
+        in the serving loop, off the producing dispatch's critical path):
+        the read is span ``checksum_sync``, the sessions'
+        ``report_checksum`` calls span ``serve_report_delivery``."""
         if not self._pending_reports:
             return
         pending, self._pending_reports = self._pending_reports, []
         # An entry names a dispatch's checksum output and which part of it
         # its rows index (0 absorb, 1 burst); one read a dispatch.
-        with self.span("checksum_sync"):
+        with self.span("checksum_sync") as sp_sync:
             read: Dict[int, tuple] = {}
             host = []
             for cs, part, rows in pending:
                 if id(cs) not in read:
                     read[id(cs)] = self._exec.cs_host(cs)
                 host.append((read[id(cs)][part], rows))
-        for cs_host, rows in host:
-            for slot, t, frame, session in rows:
-                session.report_checksum(frame, combine64(cs_host[slot, t]))
+        # Every entry holds rows (the post pass appends no empty one);
+        # they are counted only for a span somebody reads.
+        n_rows = (
+            sum(len(rows) for _, rows in host)
+            if sp_sync is not NULL_SPAN else 0
+        )
+        with self.span("serve_report_delivery", rows=n_rows):
+            for cs_host, rows in host:
+                for slot, t, frame, session in rows:
+                    session.report_checksum(
+                        frame, combine64(cs_host[slot, t])
+                    )
 
     def _record_predictor_rank(self, rank_ms: float) -> None:
         self.last_predictor_rank_ms = rank_ms
@@ -834,7 +843,9 @@ class BatchedSessionCore(Instrumented):
         Either returns the three host arrays and the post pass's rows, and
         the device call is made from here: the staging frame is gone by
         then, so the first call's trace does not run deeper for its locals
-        (``PERF.md`` §6, PR 29: 5 s of warm-up hung on that).
+        (``PERF.md`` §6, PR 29: 5 s of warm-up hung on that); the post
+        pass (:meth:`_post_dispatch`) runs once the call's frame is gone
+        too.
 
         Atomic on fault: segments are re-validated in a pre-pass (direct
         callers may bypass :meth:`tick`), so a raise can only happen before
@@ -844,7 +855,7 @@ class BatchedSessionCore(Instrumented):
             self._dispatch_native if self._plane is not None
             else self._dispatch_python
         )
-        self._finish_dispatch(*stage(batch))
+        self._post_dispatch(*self._finish_dispatch(*stage(batch)))
 
     def _dispatch_python(self, batch: Dict[int, tuple]) -> tuple:
         """The per-slot host loop (the ``GGRS_NO_NATIVE=1`` reference
@@ -856,21 +867,23 @@ class BatchedSessionCore(Instrumented):
             self.burst_frames,
         )
         P = self.num_players
-        for i, (load_frame, steps, _confirmed, _session) in batch.items():
-            self._validate_segment(i, self.slots[i].frame, load_frame, steps)
-        ints_a, bits_a, bb_a = self._host_args()
-        status_a = TickInts.status(ints_a, MF, P)
         # post[slot] -> state updates applied after the dispatch succeeds
         post: Dict[int, tuple] = {}
         reports: List[tuple] = []
 
-        # The per-slot loop is one span; the predictor ranking and the
-        # tree builds are its children, and ``serve_arg_assembly`` (the
-        # series) is its self time: log writes, matches, array fills.
-        sp_rank = NULL_SPAN
+        # The host work before the call is one span; the predictor ranking
+        # and the tree builds are its children, and ``serve_arg_assembly_ms``
+        # (the series) is its self time: the pre-pass, the three fresh
+        # arrays, log writes, matches, array fills.
         with self.span(
             "serve_arg_assembly", series=False, slots=len(batch)
         ) as sp_loop:
+            for i, (load_frame, steps, _confirmed, _session) in batch.items():
+                self._validate_segment(
+                    i, self.slots[i].frame, load_frame, steps
+                )
+            ints_a, bits_a, bb_a = self._host_args()
+            status_a = TickInts.status(ints_a, MF, P)
             # Pass 1 — as-used log writes + anchor geometry for every batched
             # slot, hoisted ahead of the build loop so the batched predictor
             # ranking sees all post-write windows in ONE vmapped call.
@@ -969,16 +982,25 @@ class BatchedSessionCore(Instrumented):
                 )
 
         if sp_loop is not NULL_SPAN:
-            bb_ms = sp_build.ms
-            arg_ms = max(0.0, sp_loop.ms - bb_ms - sp_rank.ms)
-            self.last_branch_build_ms = bb_ms
-            self.last_arg_assembly_ms = arg_ms
-            self.metrics.observe("serve_branch_build", bb_ms)
-            self.metrics.observe("serve_arg_assembly", arg_ms)
-            self.timeseries.observe("serve_branch_build_ms", bb_ms)
-            self.timeseries.observe("serve_arg_assembly_ms", arg_ms)
+            self._observe_assembly(sp_loop.self_ms, sp_build.ms)
 
         return (ints_a, bits_a, bb_a), post, reports
+
+    def _observe_assembly(
+        self, self_ms: float, build_ms: float,
+        stage_ms: Optional[float] = None,
+    ) -> None:
+        """The host loop's split, one sample a dispatch, the same keys in
+        ``Metrics`` and the rolling ``timeseries``: ``serve_branch_build_ms``
+        (the tree builds), ``native_batch_ms`` (the native plane's two
+        calls, stage + build) and ``serve_arg_assembly_ms``, the SELF time
+        of span ``serve_arg_assembly``: what its children (stage, build,
+        ranking) leave of the loop, so that the series add up to the span."""
+        for sink in (self.metrics, self.timeseries):
+            sink.observe("serve_branch_build_ms", build_ms)
+            sink.observe("serve_arg_assembly_ms", self_ms)
+            if stage_ms is not None:
+                sink.observe("native_batch_ms", stage_ms + build_ms)
 
     def _host_args(self) -> tuple:
         """The three host arrays of one dispatch (``BatchedTickExecutor.
@@ -1000,14 +1022,11 @@ class BatchedSessionCore(Instrumented):
     def _finish_dispatch(
         self, jit_args: tuple, post: Dict[int, tuple],
         reports: List[tuple],
-    ) -> None:
-        """The device dispatch + post-dispatch bookkeeping shared by both
-        host paths (per-slot Python loop and native batch plane): run the
-        batched tick, then apply each ticked lane's plan: frame counter,
-        rollout metadata, the rollback's accounting
-        (``fused.account_rollback``) and deferred checksum rows.
-        ``post[slot]`` is ``(end, load_frame, n_steps, session, the lane's
-        next in-flight tree or None, its plan, its blame)``."""
+    ) -> tuple:
+        """The device dispatch shared by both host paths (per-slot Python
+        loop and native batch plane): run the batched tick. Returns
+        :meth:`_post_dispatch`'s arguments. Few locals ON PURPOSE: the
+        first call's trace runs under this frame (``PERF.md`` §7)."""
         self.device_dispatches_total += 1
         self.burst_step_slots_total += self.num_slots * self.burst_frames
         dev = (
@@ -1021,48 +1040,61 @@ class BatchedSessionCore(Instrumented):
             )
         self._trees = None
         self.metrics.observe("tick_io_buffers", self._exec.io.last)
+        return cs, post, reports
 
-        for i, (
-            end, load_frame, n_steps, session, res_bits, plan, blame,
-        ) in post.items():
-            (
-                branch, n_commit, missed, _, burst_start, n_tail,
-                spec_active, spec_anchor, _,
-            ) = plan
-            s = self.slots[i]
-            s.frame = end
-            if spec_active:
-                s.res_anchor, s.res_bits = spec_anchor, res_bits
-                # A fresh rollout dispatched for this slot: B×F
-                # speculative device frames. (No-op lane replays are NOT
-                # charged — they are an artifact of the wholesale
-                # prev-buffer swap, not new speculative intent.)
-                self.ledger.record_rollout(
-                    self.num_branches * self.spec_frames, slot=i
+    def _post_dispatch(
+        self, cs, post: Dict[int, tuple], reports: List[tuple]
+    ) -> None:
+        """The post-dispatch bookkeeping of one round (span ``serve_post``,
+        one for the whole round): apply each ticked lane's plan: frame
+        counter, rollout metadata, the rollback's accounting
+        (``fused.account_rollback``) and the deferred rows of the
+        dispatch's checksum output ``cs``. ``post[slot]`` is ``(end,
+        load_frame, n_steps, session, the lane's next in-flight tree or
+        None, its plan, its blame)``."""
+        with self.span("serve_post", slots=len(post)):
+            for i, (
+                end, load_frame, n_steps, session, res_bits, plan, blame,
+            ) in post.items():
+                (
+                    branch, n_commit, missed, _, burst_start, n_tail,
+                    spec_active, spec_anchor, _,
+                ) = plan
+                s = self.slots[i]
+                s.frame = end
+                if spec_active:
+                    s.res_anchor, s.res_bits = spec_anchor, res_bits
+                    # A fresh rollout dispatched for this slot: B×F
+                    # speculative device frames. (No-op lane replays are NOT
+                    # charged — they are an artifact of the wholesale
+                    # prev-buffer swap, not new speculative intent.)
+                    self.ledger.record_rollout(
+                        self.num_branches * self.spec_frames, slot=i
+                    )
+                else:
+                    s.res_anchor, s.res_bits = None, None
+                self.burst_steps_total += n_steps
+                self.metrics.count("frames_advanced", n_steps)
+                self.metrics.count(
+                    "frames_advanced", n_steps, labels={"match_slot": i}
                 )
-            else:
-                s.res_anchor, s.res_bits = None, None
-            self.burst_steps_total += n_steps
-            self.metrics.count("frames_advanced", n_steps)
-            self.metrics.count(
-                "frames_advanced", n_steps, labels={"match_slot": i}
-            )
-            if load_frame is not None:
-                account_rollback(
-                    self, load_frame, n_steps, branch, n_commit, missed,
-                    blame, slot=i,
-                )
-            if session is not None and self.report_checksums:
-                for part, first, n in (
-                    (0, load_frame, n_commit), (1, burst_start, n_tail)
-                ):
-                    rows = wanted_rows(session, first, n)
-                    if rows:
-                        reports.append(
-                            (cs, part, [(i,) + r + (session,) for r in rows])
-                        )
-            self._gc_log(s)
-        self._pending_reports.extend(reports)
+                if load_frame is not None:
+                    account_rollback(
+                        self, load_frame, n_steps, branch, n_commit, missed,
+                        blame, slot=i,
+                    )
+                if session is not None and self.report_checksums:
+                    for part, first, n in (
+                        (0, load_frame, n_commit), (1, burst_start, n_tail)
+                    ):
+                        rows = wanted_rows(session, first, n)
+                        if rows:
+                            reports.append((
+                                cs, part,
+                                [(i,) + r + (session,) for r in rows],
+                            ))
+                self._gc_log(s)
+            self._pending_reports.extend(reports)
 
     def _dispatch_native(self, batch: Dict[int, tuple]) -> tuple:
         """One vmapped dispatch's per-slot host loop consolidated
@@ -1080,21 +1112,24 @@ class BatchedSessionCore(Instrumented):
             self.burst_frames,
         )
         P = self.num_players
-        for i, (load_frame, steps, _confirmed, _session) in batch.items():
-            self._validate_segment(i, self.slots[i].frame, load_frame, steps)
-        ints_a, bits_a, bb_a = self._host_args()
-        status_a = TickInts.status(ints_a, MF, P)
         post: Dict[int, tuple] = {}
         reports: List[tuple] = []
 
-        # One span over the host loop; the two C calls and the predictor
-        # ranking are its children. ``native_batch_ms`` is the two calls'
-        # sum, ``serve_branch_build`` the build call, ``serve_arg_assembly``
-        # the loop's time outside build and ranking.
-        sp_rank = NULL_SPAN
+        # One span over the host work before the call (the pre-pass and
+        # the three fresh arrays included); the two C calls and the
+        # predictor ranking are its children. ``native_batch_ms`` is the
+        # two calls' sum, ``serve_branch_build_ms`` the build call and
+        # ``serve_arg_assembly_ms`` the span's self time: what stage, build
+        # and ranking leave of it (:meth:`_observe_assembly`).
         with self.span(
             "serve_arg_assembly", series=False, slots=len(batch)
         ) as sp_loop:
+            for i, (load_frame, steps, _confirmed, _session) in batch.items():
+                self._validate_segment(
+                    i, self.slots[i].frame, load_frame, steps
+                )
+            ints_a, bits_a, bb_a = self._host_args()
+            status_a = TickInts.status(ints_a, MF, P)
             plane.reset_masks()
             # Pass 1 — SoA staging for ggrs_batch_stage: step bits/status,
             # anchor geometry, match inputs, window-gather requests. The
@@ -1239,18 +1274,9 @@ class BatchedSessionCore(Instrumented):
                 plane.kmask[i] = 0
 
         if sp_loop is not NULL_SPAN:
-            bb_ms = sp_build.ms
-            nb_ms = sp_stage.ms + bb_ms
-            arg_ms = max(0.0, sp_loop.ms - bb_ms - sp_rank.ms)
-            self.last_branch_build_ms = bb_ms
-            self.last_arg_assembly_ms = arg_ms
-            self.native_batch_ms_total += nb_ms
-            self.metrics.observe("serve_branch_build", bb_ms)
-            self.metrics.observe("serve_arg_assembly", arg_ms)
-            self.metrics.observe("native_batch_ms", nb_ms)
-            self.timeseries.observe("serve_branch_build_ms", bb_ms)
-            self.timeseries.observe("serve_arg_assembly_ms", arg_ms)
-            self.timeseries.observe("native_batch_ms", nb_ms)
+            self._observe_assembly(
+                sp_loop.self_ms, sp_build.ms, sp_stage.ms
+            )
 
         return (ints_a, bits_a, bb_a), post, reports
 

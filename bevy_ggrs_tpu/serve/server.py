@@ -21,6 +21,9 @@ spectator sessions all fit):
 - ``confirmed_frame()`` (optional) — the speculation anchor; absent means
   fully confirmed every frame (synctest);
 - ``poll_remote_clients()`` (optional) — pumped before input collection;
+  one that also takes ``parts=`` (P2P and spectator sessions) is handed
+  the group's two-slot list while a sink listens, for the poll's receive
+  and send seconds;
 - ``report_checksum(frame, checksum)`` / ``wants_checksum(frame)``
   (optional) — fed from the core's deferred checksum reports;
 - ``checksum_votes`` + ``drain_control`` (optional) — their presence
@@ -53,6 +56,7 @@ per-slot counters carry a ``match_slot`` label; ``slots_active``,
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -101,6 +105,28 @@ def _supervisable(session) -> bool:
     return hasattr(session, "checksum_votes") and hasattr(
         session, "drain_control"
     )
+
+
+_TAKES_PARTS: Dict[type, bool] = {}
+
+
+def _poll_takes_parts(session) -> bool:
+    """Whether the session's ``poll_remote_clients`` has the ``parts``
+    parameter (``P2PSession``'s and ``SpectatorSession``'s do). The
+    contract is a bare ``poll_remote_clients()``: a session written to it
+    is polled as ever and timed from outside, its poll on neither side.
+    Asked only while a sink listens; looked up once a session type."""
+    kind = type(session)
+    takes = _TAKES_PARTS.get(kind)
+    if takes is None:
+        try:
+            takes = "parts" in inspect.signature(
+                kind.poll_remote_clients
+            ).parameters
+        except (AttributeError, TypeError, ValueError):
+            takes = False
+        _TAKES_PARTS[kind] = takes
+    return takes
 
 
 class MatchServer(Instrumented):
@@ -974,7 +1000,20 @@ class MatchServer(Instrumented):
         :class:`SlotFault` from the dispatch itself (pre-mutation, so
         sibling slots are untouched) drops that slot and re-ticks the
         rest. Recovery lanes step after the groups, readmitting or
-        evicting as they resolve."""
+        evicting as they resolve.
+
+        All of it is span ``serve_frame``; what the spans under it
+        (``serve_tick``, ``checksum_sync``, ``serve_report_delivery``,
+        ``admit_*``, ``attest``, ``lane_step``) leave of it is the series
+        ``serve_frame_other_ms``: the frame's own bookkeeping."""
+        with self.span(
+            "serve_frame", frame=self.frames_served, groups=len(self.groups)
+        ) as sp_frame:
+            self._serve_frame()
+        if sp_frame is not NULL_SPAN:
+            self.metrics.observe("serve_frame_other_ms", sp_frame.self_ms)
+
+    def _serve_frame(self) -> None:
         t_wall = time.perf_counter()
         # Fast-path admission drain, TOP of frame: a pre-warmed joiner
         # (initial_state None with a slot template pooled) costs ~a
@@ -1029,7 +1068,7 @@ class MatchServer(Instrumented):
             with self.span(
                 "serve_tick", group=g, frame=self.frames_served,
                 matches=len(matches),
-            ):
+            ) as sp_tick:
                 work = {}
                 # The session layer of the group: one span a group tick
                 # (supervisor, poll, local inputs, advance_frame and the
@@ -1038,17 +1077,25 @@ class MatchServer(Instrumented):
                 with self.span(
                     "serve_sessions", group=g, matches=len(matches)
                 ) as sp_sessions:
-                    # The group's poll_remote_clients() calls, summed into
-                    # ONE sample of the series ``serve_poll_ms`` (clock
-                    # reads only while a sink listens).
-                    time_polls = sp_sessions is not NULL_SPAN
-                    poll_s = 0.0
+                    # What happens once a match is summed a group tick,
+                    # one sample a series, written as the span closes
+                    # (``_observe_session_sums``): the clock is read at the
+                    # five boundaries of a match only while a sink listens,
+                    # and the time stays inside ``ggrs/serve_sessions``.
+                    timed = sp_sessions is not NULL_SPAN
+                    clock = time.perf_counter
+                    sup_s = poll_s = inputs_s = adv_s = slo_s = 0.0
+                    # The polls' receive and send seconds: the session adds
+                    # them in for the caller that asks (``parts``).
+                    poll_parts = [0.0, 0.0] if timed else None
                     # The loop's crossings into the native session core, a
                     # live match: series ``serve_session_native_calls``
                     # (the core counts; None on the Python plane).
-                    calls_0 = native_calls() if time_polls else None
+                    calls_0 = native_calls() if timed else None
                     for slot, (handle, m) in matches.items():
                         session = m.session
+                        if timed:
+                            t_a = clock()
                         t_m = self._clock()
                         try:
                             sup = m.supervisor
@@ -1061,14 +1108,22 @@ class MatchServer(Instrumented):
                                         handle, m, "supervisor_quarantine"
                                     )
                                     continue
+                            if timed:
+                                t_b = clock()
+                                sup_s += t_b - t_a
+                                t_a = t_b
                             poll = getattr(
                                 session, "poll_remote_clients", None
                             )
                             if poll is not None:
-                                if time_polls:
-                                    t_p = time.perf_counter()
-                                    poll()
-                                    poll_s += time.perf_counter() - t_p
+                                if timed:
+                                    if _poll_takes_parts(session):
+                                        poll(parts=poll_parts)
+                                    else:
+                                        poll()
+                                    t_b = clock()
+                                    poll_s += t_b - t_a
+                                    t_a = t_b
                                 else:
                                     poll()
                             cur = getattr(session, "current_state", None)
@@ -1084,9 +1139,17 @@ class MatchServer(Instrumented):
                                     if sup is not None:
                                         bits = sup.input_for(h, bits)
                                     session.add_local_input(h, bits)
+                            if timed:
+                                t_b = clock()
+                                inputs_s += t_b - t_a
+                                t_a = t_b
                             requests = session.advance_frame()
                             conf = getattr(session, "confirmed_frame", None)
                             confirmed = conf() if conf is not None else None
+                            if timed:
+                                t_b = clock()
+                                adv_s += t_b - t_a
+                                t_a = t_b
                         except PredictionThreshold:
                             # Back-pressure, not a fault: a withheld frame.
                             # Counted, and sampled by the SLO as any tick
@@ -1135,13 +1198,21 @@ class MatchServer(Instrumented):
                         else:
                             m.fsm.clear()
                         work[slot] = (requests, confirmed, session)
-                    if time_polls:
-                        self.metrics.observe("serve_poll_ms", poll_s * 1000.0)
+                        if timed:
+                            slo_s += clock() - t_a
                     if calls_0 is not None:
                         self.metrics.observe(
                             "serve_session_native_calls",
                             (native_calls() - calls_0) / len(matches),
                         )
+                if timed:
+                    self._observe_session_sums(sp_sessions.ms, poll_parts, {
+                        "serve_supervisor_ms": sup_s,
+                        "serve_poll_ms": poll_s,
+                        "serve_local_inputs_ms": inputs_s,
+                        "serve_advance_ms": adv_s,
+                        "serve_slo_ms": slo_s,
+                    })
                 while work:
                     try:
                         core.tick(work)
@@ -1162,6 +1233,10 @@ class MatchServer(Instrumented):
                             self._finish_admission(
                                 h, self._pending_first.pop(h)
                             )
+            if sp_tick is not NULL_SPAN:
+                # What ``serve_sessions``, ``serve_segment`` and the rounds
+                # leave of the group's tick.
+                self.metrics.observe("serve_tick_other_ms", sp_tick.self_ms)
         # Slow-path admission drain: immediately AFTER every group issued
         # its dispatch — the tick programs are still in flight on device
         # (dispatch is async), so a joiner's session warm + state build
@@ -1295,6 +1370,25 @@ class MatchServer(Instrumented):
             self.metrics.count("fleet_heartbeats_sent")
         if self.checkpointer is not None:
             self.checkpointer.maybe_save(self)
+
+    def _observe_session_sums(
+        self, span_ms: float, poll_parts: List[float],
+        sums_s: Dict[str, float],
+    ) -> None:
+        """One sample a group tick of each sum of span ``serve_sessions``
+        (the seconds one kind of work took over every match of the group,
+        under the series key given), of the polls' two sides, and of what
+        the sums leave of the span (``serve_sessions_other_ms``: the loop
+        itself, a match that left it early)."""
+        observe = self.metrics.observe
+        for key, seconds in sums_s.items():
+            observe(key, seconds * 1000.0)
+        observe("serve_poll_recv_ms", poll_parts[0] * 1000.0)
+        observe("serve_poll_send_ms", poll_parts[1] * 1000.0)
+        observe(
+            "serve_sessions_other_ms",
+            span_ms - sum(sums_s.values()) * 1000.0,
+        )
 
     # -- telemetry export -----------------------------------------------
 

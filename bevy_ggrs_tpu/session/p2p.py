@@ -265,13 +265,30 @@ class P2PSession(Instrumented):
     # ------------------------------------------------------------------
     # Network pump (`poll_remote_clients`, ggrs_stage.rs:113-119)
 
-    def poll_remote_clients(self, now: Optional[float] = None) -> None:
+    def poll_remote_clients(
+        self, now: Optional[float] = None,
+        parts: Optional[List[float]] = None,
+    ) -> None:
+        """Pump the network once. A caller that wants the poll's two sides
+        timed passes ``parts``, a two-slot list it owns: the seconds of
+        the receive side (``receive_all`` + decode + ``on_message`` +
+        ingest) are added into ``parts[0]``, those of the send side (the
+        endpoints' timers, ``send_pending_inputs``, outbox to socket) into
+        ``parts[1]``. The CALLER decides (``MatchServer`` and ``GGRSStage``
+        ask while their own sink listens), never this session's sinks: a
+        hosted session may hold a ``Metrics`` for its counters in a run
+        nobody traces. With ``parts=None`` the clock is not read."""
         with self.tracer.span("net_poll"):
-            self._poll_remote_clients(now)
+            self._poll_remote_clients(now, parts)
 
-    def _poll_remote_clients(self, now: Optional[float] = None) -> None:
+    def _poll_remote_clients(
+        self, now: Optional[float] = None,
+        parts: Optional[List[float]] = None,
+    ) -> None:
         now = self._clock() if now is None else now
         datagrams_in = 0
+        if parts is not None:
+            t_0 = _time.perf_counter()
         with self.tracer.span("net_recv"):
             for addr, data in self.socket.receive_all():
                 datagrams_in += 1
@@ -289,6 +306,8 @@ class P2PSession(Instrumented):
                         _addr, m, _now
                     ),
                 )
+        if parts is not None:
+            parts[0] += _time.perf_counter() - t_0
         if datagrams_in:
             self.metrics.count("datagrams_in", datagrams_in)
 
@@ -296,6 +315,8 @@ class P2PSession(Instrumented):
         self._maybe_send_checksums(now)
 
         local_adv = self._local_advantage()
+        if parts is not None:
+            t_0 = _time.perf_counter()
         with self.tracer.span("net_send"):
             for addr, ep in self._endpoints.items():
                 before = ep.state
@@ -316,6 +337,8 @@ class P2PSession(Instrumented):
                 for data in ep.outbox:
                     self.socket.send_to(data, addr)
                 ep.outbox.clear()
+        if parts is not None:
+            parts[1] += _time.perf_counter() - t_0
 
         ahead = self.frames_ahead()
         if ahead > 0:

@@ -108,8 +108,19 @@ class SpectatorSession:
 
     # ------------------------------------------------------------------
 
-    def poll_remote_clients(self, now: Optional[float] = None) -> None:
+    def poll_remote_clients(
+        self, now: Optional[float] = None,
+        parts: Optional[List[float]] = None,
+    ) -> None:
+        """Pump the network once. ``parts`` as ``P2PSession``'s: a caller
+        that wants the poll's two sides timed passes a two-slot list it
+        owns; the receive side's seconds (datagrams in, acks, the gap
+        streaks) are added into ``parts[0]``, the send side's (the
+        endpoint's timers, outbox to socket) into ``parts[1]``. With
+        ``parts=None`` the clock is not read."""
         now = self._clock() if now is None else now
+        if parts is not None:
+            t_0 = _time.perf_counter()
         got_inputs = False
         for addr, data in self.socket.receive_all():
             if addr != self.host_addr:
@@ -140,12 +151,17 @@ class SpectatorSession:
                 self._gap_streak[h] += 1
             self._poll_ok[h] = False
             self._poll_gap[h] = False
+        if parts is not None:
+            t_1 = _time.perf_counter()
+            parts[0] += t_1 - t_0
         self._endpoint.poll(now, self.current_frame, 0)
         self._events.extend(self._endpoint.events)
         self._endpoint.events.clear()
         for data in self._endpoint.outbox:
             self.socket.send_to(data, self.host_addr)
         self._endpoint.outbox.clear()
+        if parts is not None:
+            parts[1] += _time.perf_counter() - t_1
 
     def _on_inputs(self, msg: proto.InputMsg) -> None:
         h = msg.handle

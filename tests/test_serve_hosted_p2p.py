@@ -288,6 +288,46 @@ def test_poll_series_has_one_sample_a_group_tick(plane):
                                           series["serve_sessions_ms"]))
 
 
+@pytest.mark.parametrize("plane", PLANES)
+def test_session_sums_add_up_to_the_span_a_group_tick(plane):
+    """Hosted P2P: the five sums of span ``serve_sessions`` (one sample a
+    group tick each) and what they leave (``serve_sessions_other_ms``) are
+    the span; the polls' two sides, which the SERVER asks its sessions for
+    (``poll_remote_clients(parts=...)``), lie inside the polls' sum."""
+    series = served(plane)["metrics"].series
+    spans = series["serve_sessions_ms"]
+    sums = ("serve_supervisor_ms", "serve_poll_ms", "serve_local_inputs_ms",
+            "serve_advance_ms", "serve_slo_ms")
+    for key in sums + ("serve_sessions_other_ms", "serve_poll_recv_ms",
+                       "serve_poll_send_ms", "serve_tick_other_ms"):
+        assert len(series[key]) == len(spans), key
+    # The core's own spans: one a tick() it was called for (none while
+    # every match of the group was still synchronizing).
+    ticks = len(series["serve_rounds"])
+    assert len(series["serve_segment_ms"]) == ticks < len(spans)
+    # (+ 1: warm-up's one dispatch, outside any tick.)
+    assert len(series["serve_post_ms"]) == ticks + 1
+    assert len(series["serve_arg_assembly_ms"]) == ticks + 1
+    for i, span_ms in enumerate(spans):
+        other = series["serve_sessions_other_ms"][i]
+        assert other >= 0.0
+        assert sum(series[k][i] for k in sums) + other == pytest.approx(
+            span_ms, abs=1e-6)
+        recv, send = (series["serve_poll_recv_ms"][i],
+                      series["serve_poll_send_ms"][i])
+        assert 0.0 < recv and 0.0 < send
+        assert recv + send <= series["serve_poll_ms"][i]
+    # Every hosted match has a supervisor, local inputs and an advance.
+    for key in sums:
+        assert min(series[key][-FRAMES:]) > 0.0, key
+    assert all(v >= 0.0 for v in series["serve_tick_other_ms"])
+    # The hosted sessions hold a Metrics of their own (for their counters):
+    # they time nothing by themselves, whoever listens.
+    r = served(plane)
+    for m in (r["host_metrics"], r["peer_metrics"]):
+        assert not any(k.startswith(("poll", "serve_")) for k in m.series)
+
+
 def test_native_plane_and_python_path_agree():
     nat, py = served("native"), served("python")
     assert nat["server"].groups[0]._plane is not None

@@ -167,6 +167,14 @@ class TestExactCounts:
                  for k, v in metrics.series.items()}
         assert grown["stage_update_ms"] == 40
         assert grown["poll_ms"] == 40
+        # The poll's two sides, which the STAGE asks its session for while
+        # its own sink listens: one sample a tick each, inside the span.
+        assert grown["poll_recv_ms"] == grown["poll_send_ms"] == 40
+        for key in ("poll_recv_ms", "poll_send_ms"):
+            assert all(v > 0.0 for v in metrics.series[key])
+        assert all(r + s_ <= p for r, s_, p in zip(
+            metrics.series["poll_recv_ms"], metrics.series["poll_send_ms"],
+            metrics.series["poll_ms"]))
         assert grown["session_advance_ms"] == pair.advance_calls - calls0 > 0
         assert grown["tick_enqueue_ms"] == grown["tick_stage_args_ms"] > 0
         assert grown["spec_tree_build_ms"] > 0
@@ -384,6 +392,231 @@ class TestServedSpans:
             0, len(metrics.series["serve_dispatch_ms"]))
 
 
+# Series written once a group tick of the served frame (PR 35): four new
+# spans, five sums of span ``serve_sessions`` with the polls' two sides,
+# and the remainders.
+GROUP_TICK_SERIES = (
+    "serve_segment_ms", "serve_post_ms", "serve_arg_assembly_ms",
+    "serve_branch_build_ms", "serve_tick_other_ms",
+    "serve_sessions_other_ms", "serve_supervisor_ms", "serve_poll_ms",
+    "serve_poll_recv_ms", "serve_poll_send_ms", "serve_local_inputs_ms",
+    "serve_advance_ms", "serve_slo_ms",
+)
+SESSION_SUMS = (
+    "serve_supervisor_ms", "serve_poll_ms", "serve_local_inputs_ms",
+    "serve_advance_ms", "serve_slo_ms",
+)
+
+
+def _ring_durations_ms(tracer, name):
+    """Durations of every closed span ``name`` in the ring, in order."""
+    out, open_at = [], []
+    for ph, n, ts, _args in tracer._well_formed_events():
+        if n != name:
+            continue
+        if ph == "B":
+            open_at.append(ts)
+        elif ph == "E":
+            out.append((ts - open_at.pop()) / 1000.0)
+    return out
+
+
+class TestServedBooks:
+    """Every stretch of a served frame has a name, and what the names
+    leave over is itself a series (``*_other_ms``)."""
+
+    FRAMES = 6
+
+    @pytest.fixture(scope="class")
+    def run(self):
+        from tests.test_serve_faults import (
+            inputs_for, make_server, make_synctest,
+        )
+
+        metrics, tracer = Metrics(), SpanTracer()
+        srv = make_server(metrics=metrics, tracer=tracer, capacity=4)
+        # Warm-up dispatched once, outside any frame: not counted here.
+        tracer._events.clear()
+        warm = {k: len(v) for k, v in metrics.series.items()}
+        for k in range(3):
+            srv.add_match(make_synctest(), inputs_for(k))
+        groups_live = len({h.group for h in srv._matches})
+        for _ in range(self.FRAMES):
+            srv.run_frame()
+        srv.close()
+        series = {k: v[warm.get(k, 0):] for k, v in metrics.series.items()}
+        return series, tracer, self.FRAMES * groups_live
+
+    def test_one_sample_a_group_tick_or_a_frame(self, run):
+        series, _tracer, group_ticks = run
+        for key in GROUP_TICK_SERIES:
+            assert len(series[key]) == group_ticks, key
+        for key in ("serve_frame_ms", "serve_frame_other_ms"):
+            assert len(series[key]) == self.FRAMES, key
+        # Opened only when there are rows: beside every read of the
+        # checksums, and there is none before a group's first dispatch.
+        assert 0 < len(series["serve_report_delivery_ms"]) == len(
+            series["checksum_sync_ms"]) < group_ticks + 1
+        # One spelling a key: the parent's second ones are gone.
+        assert "serve_arg_assembly" not in series
+        assert "serve_branch_build" not in series
+
+    def test_every_new_span_has_its_parent(self, run):
+        _series, tracer, group_ticks = run
+        family = collections.Counter(_ring_parents(tracer))
+        assert family[("serve_frame", None)] == self.FRAMES
+        assert family[("serve_tick", "serve_frame")] == group_ticks
+        assert family[("serve_segment", "serve_tick")] == group_ticks
+        assert family[("serve_post", "serve_round")] == group_ticks
+        assert family[("serve_arg_assembly", "serve_round")] == group_ticks
+        delivered = family[("serve_report_delivery", "serve_frame")]
+        assert delivered == family[("checksum_sync", "serve_frame")] > 0
+        # One span a group tick or a round, none a match, slot or row.
+        for name, keys in (("serve_frame", {"frame", "groups"}),
+                           ("serve_segment", {"slots"}),
+                           ("serve_post", {"slots"}),
+                           ("serve_report_delivery", {"rows"})):
+            args = [a for ph, n, _, a in tracer._events
+                    if ph == "B" and n == name]
+            assert args and all(set(a) == keys for a in args), name
+
+    def test_remainders_are_remainders(self, run):
+        series, tracer, _ = run
+        for key in ("serve_sessions_other_ms", "serve_tick_other_ms",
+                    "serve_frame_other_ms"):
+            assert all(v >= -1e-9 for v in series[key]), key
+        # serve_sessions = its five sums + other, to rounding.
+        for i, span_ms in enumerate(series["serve_sessions_ms"]):
+            parts = sum(series[k][i] for k in SESSION_SUMS)
+            assert parts + series["serve_sessions_other_ms"][i] == (
+                pytest.approx(span_ms, abs=1e-6))
+        # The polls' two sides lie inside the poll's sum (SyncTest matches
+        # have no poll: all three read 0).
+        for r, s_, p in zip(series["serve_poll_recv_ms"],
+                            series["serve_poll_send_ms"],
+                            series["serve_poll_ms"]):
+            assert 0.0 <= r + s_ <= p + 1e-9
+        # A self time is what the span's children leave: the tick's and the
+        # frame's against the ring (the ring's clock is in whole us).
+        ticks = _ring_durations_ms(tracer, "serve_tick")
+        inside = [a + b + c for a, b, c in zip(
+            _ring_durations_ms(tracer, "serve_sessions"),
+            _ring_durations_ms(tracer, "serve_segment"),
+            _ring_durations_ms(tracer, "serve_round"))]
+        for other, tick, covered in zip(series["serve_tick_other_ms"],
+                                        ticks, inside):
+            assert other == pytest.approx(tick - covered, abs=0.01)
+
+    def test_arg_assembly_is_a_self_time(self, run):
+        """``serve_arg_assembly_ms`` + the native plane's two calls + the
+        ranking fit in the loop's span: the stage call is counted once."""
+        series, tracer, _ = run
+        loops = _ring_durations_ms(tracer, "serve_arg_assembly")
+        native = series.get("native_batch_ms") or [0.0] * len(loops)
+        builds = series["serve_branch_build_ms"]
+        rank = sum(series.get("predictor_rank_ms", []))
+        assert len(loops) == len(series["serve_arg_assembly_ms"])
+        for own, nb, bb, loop in zip(series["serve_arg_assembly_ms"],
+                                     native, builds, loops):
+            assert own >= 0.0
+            assert own + max(nb, bb) + rank <= loop + 0.005
+
+
+class _BarePollSession:
+    """A session written to the server's contract to the letter: a bare
+    ``poll_remote_clients()``, no ``parts`` (everything else a SyncTest
+    session's)."""
+
+    def __init__(self, inner):
+        self._inner = inner
+        self.polls = 0
+
+    def poll_remote_clients(self):
+        self.polls += 1
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
+@pytest.mark.parametrize("sinks", [False, True])
+def test_a_session_with_a_bare_poll_is_served_traced_or_not(sinks):
+    """Listening changes no match's fate: the server hands its ``parts``
+    list only to a poll that takes it; the contract's bare poll is called
+    as ever, its time in ``serve_poll_ms`` and on neither side."""
+    from bevy_ggrs_tpu.serve.faults import SlotHealth
+    from tests.test_serve_faults import (
+        inputs_for, make_server, make_synctest,
+    )
+
+    metrics = Metrics() if sinks else None
+    srv = make_server(metrics=metrics, tracer=SpanTracer() if sinks else None,
+                      capacity=4)
+    session = _BarePollSession(make_synctest())
+    handle = srv.add_match(session, inputs_for(0))
+    before = len(metrics.series["serve_poll_ms"]) if sinks else 0
+    for _ in range(4):
+        srv.run_frame()
+    assert srv.faults_total == 0
+    assert srv.health_of(handle) is SlotHealth.HEALTHY
+    assert session.polls == 4 and session.current_frame >= 4
+    if sinks:
+        polls = metrics.series["serve_poll_ms"][before:]
+        assert len(polls) == 4 and all(v > 0.0 for v in polls)
+        assert metrics.series["serve_poll_recv_ms"][before:] == [0.0] * 4
+        assert metrics.series["serve_poll_send_ms"][before:] == [0.0] * 4
+    srv.close()
+
+
+def test_self_ms_is_what_direct_children_leave():
+    """``_Span.self_ms``: the duration less the spans that closed directly
+    under it, of any object on the thread; a grandchild counts once."""
+    a, b = _Thing(metrics=Metrics()), _Thing(tracer=SpanTracer())
+    with a.span("outer") as outer:
+        with b.span("child") as child:
+            with a.span("grandchild") as grandchild:
+                pass
+        with a.span("child") as second:
+            pass
+    assert grandchild.self_ms == grandchild.ms
+    assert child.self_ms == pytest.approx(child.ms - grandchild.ms)
+    assert outer.self_ms == pytest.approx(outer.ms - child.ms - second.ms)
+    assert 0.0 <= outer.self_ms <= outer.ms
+    assert NULL_SPAN.self_ms == 0.0
+    # A bare marker (the admission path's ``first_frame`` stays open over
+    # whole frames) is nobody's parent: the span around it is credited.
+    with a.span("frame") as frame:
+        marker = obs_trace.push_span("admission_first_frame")
+        with a.span("tick") as tick:
+            pass
+    obs_trace.pop_span(marker)
+    assert frame.self_ms == pytest.approx(frame.ms - tick.ms)
+
+
+def test_trace_spans_tool_charges_idle_by_the_reducers_rule():
+    """The tool's ``charge_idle`` is the benchmark reducer's ``idle_gaps``
+    (one definition): innermost span, short gaps, what no span covers."""
+    import importlib.util
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "trace_spans", os.path.join(root, "tools", "trace_spans.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    from benchmark.reduce import trace as reduce_trace
+
+    assert tool.nest is reduce_trace.nest
+    spans = [("tool/run_frame", 1.0, 5.0), ("ggrs/serve_frame", 1.5, 4.5),
+             ("ggrs/serve_tick", 2.0, 3.0)]
+    blocks = [(0.5, 1.0, 0.5), (2.5, 2.75, 0.2), (4.0, 4.25, 0.25)]
+    idle = tool.charge_idle(blocks, spans, (0.0, 6.0))
+    assert idle == pytest.approx({
+        "unattributed": 0.5 + 1.0, "tool/run_frame": 0.5 + 0.5,
+        "ggrs/serve_frame": 0.5 + 1.0 + 0.25, "ggrs/serve_tick": 0.5 + 0.25,
+        "between_ops_under_20us": 0.05,
+    })
+
+
 @pytest.mark.parametrize("mode", ["client", "server"])
 def test_trace_spans_tool_reads_self_times(mode, tmp_path, monkeypatch):
     """tools/trace_spans.py at a toy size: the table holds the layer
@@ -405,9 +638,13 @@ def test_trace_spans_tool_reads_self_times(mode, tmp_path, monkeypatch):
     else:
         xspace, extra = tool.drive_server(frames=2, capacity=4, groups=2,
                                           warmup=2)
-        want = {"ggrs/serve_tick": "tool/run_frame",
+        want = {"ggrs/serve_frame": "tool/run_frame",
+                "ggrs/serve_tick": "ggrs/serve_frame",
                 "ggrs/serve_sessions": "ggrs/serve_tick",
-                "ggrs/serve_dispatch": "ggrs/serve_round"}
+                "ggrs/serve_segment": "ggrs/serve_tick",
+                "ggrs/serve_dispatch": "ggrs/serve_round",
+                "ggrs/serve_post": "ggrs/serve_round",
+                "ggrs/serve_report_delivery": "ggrs/serve_frame"}
     out = tool.report(xspace, mode, extra)
     rows = {r["span"]: r for r in out["spans"]}
     for name, parent in want.items():

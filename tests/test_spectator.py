@@ -179,3 +179,42 @@ class TestSpectator:
             assert 1 <= len(batch) <= CATCHUP_BURST_CAP
             total += len(batch)
         assert total == session.current_frame
+
+
+def test_the_poll_split_is_the_callers_and_changes_nothing(monkeypatch):
+    """``parts`` as ``P2PSession``'s (PR 35): a caller that passes its
+    two-slot list gets the poll's receive and send seconds added in; with
+    ``parts=None`` the clock is not read; the spectator ends on the same
+    frame either way."""
+    import time
+
+    def run(parts):
+        net = LoopbackNetwork()
+        peers = make_pair(net, spectators=[("spec", 0)])
+        spec_session, spec_runner = make_spectator(net, ("peer", 0))
+        reads = [0]
+        real = time.perf_counter
+
+        def counting():
+            reads[0] += 1
+            return real()
+
+        for _ in range(90):
+            drive(net, peers, scripted_input, 1)
+            with monkeypatch.context() as patch:
+                patch.setattr(time, "perf_counter", counting)
+                spec_session.poll_remote_clients(parts=parts)
+            if spec_session.current_state() == SessionState.RUNNING:
+                try:
+                    spec_runner.handle_requests(
+                        spec_session.advance_frame(), spec_session)
+                except PredictionThreshold:
+                    pass
+        return spec_session.current_frame, reads[0]
+
+    frame_off, reads_off = run(None)
+    parts = [0.0, 0.0]
+    frame_on, reads_on = run(parts)
+    assert frame_on == frame_off > 0
+    assert reads_off == 0 and reads_on == 3 * 90
+    assert parts[0] > 0.0 and parts[1] > 0.0

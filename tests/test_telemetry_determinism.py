@@ -58,7 +58,9 @@ class WireRecorder:
         return getattr(self.inner, name)
 
 
-def run_p2p(telemetry: bool):
+def run_p2p(telemetry: bool, split: bool = False):
+    """``split``: every poll is asked for its two sides
+    (``poll_remote_clients(parts=...)``), as a caller with a sink asks."""
     net = LoopbackNetwork()
     plan = ChaosPlan.generate(11, 3.0, (("peer", 0), ("peer", 1)))
     wires = {0: [], 1: []}
@@ -103,7 +105,7 @@ def run_p2p(telemetry: bool):
     for _ in range(240):
         net.advance(FPS_DT)
         for i, (session, runner) in enumerate(peers):
-            session.poll_remote_clients()
+            session.poll_remote_clients(parts=[0.0, 0.0] if split else None)
             if session.current_state() != SessionState.RUNNING:
                 continue
             for h in session.local_player_handles():
@@ -132,6 +134,130 @@ class TestP2PInert:
         # Same per-frame state checksums and same final states.
         assert on[1] == off[1]
         assert on[2] == off[2]
+
+
+    def test_poll_split_on_vs_off_is_bitwise_identical(self):
+        """The caller's ``parts`` list only reads the clock: same wire
+        bytes, per-frame checksums and final states with it and without."""
+        assert run_p2p(telemetry=False, split=True) == run_p2p(
+            telemetry=False)
+
+
+class _CountingClock:
+    """Stands in for ``time.perf_counter``: counts its reads."""
+
+    def __init__(self):
+        import time
+
+        self.reads = 0
+        self._real = time.perf_counter
+
+    def __call__(self):
+        self.reads += 1
+        return self._real()
+
+
+class TestNullSinksReadNoClock:
+    """With the sinks null the served frame's new names cost nothing: no
+    span object, no clock read a match, none in the session's poll."""
+
+    def _pair(self):
+        net = LoopbackNetwork()
+        sessions = []
+        for me in range(2):
+            builder = (
+                SessionBuilder(box_game.INPUT_SPEC).with_num_players(2)
+            )
+            for h in range(2):
+                builder.add_player(
+                    PlayerType.local() if h == me
+                    else PlayerType.remote(("peer", h)), h,
+                )
+            sessions.append(builder.start_p2p_session(
+                net.socket(("peer", me)), clock=lambda: net.now
+            ))
+        return net, sessions
+
+    def test_poll_reads_the_clock_only_for_a_caller_that_asks(
+        self, monkeypatch
+    ):
+        import time
+
+        net, sessions = self._pair()
+        clock = _CountingClock()
+        monkeypatch.setattr(time, "perf_counter", clock)
+        for _ in range(30):
+            net.advance(FPS_DT)
+            for s in sessions:
+                s.poll_remote_clients()
+        assert clock.reads == 0
+        parts = [0.0, 0.0]
+        net.advance(FPS_DT)
+        sessions[0].poll_remote_clients(parts=parts)
+        assert clock.reads == 4  # the two sides, a pair of reads each
+        assert parts[0] > 0.0 and parts[1] > 0.0
+
+    @pytest.mark.parametrize("sinks", [False, True])
+    def test_per_match_loop_reads_no_clock_beyond_its_pair(
+        self, sinks, monkeypatch
+    ):
+        """Reads of ``time.perf_counter`` and of the server's own clock
+        over served frames, for 1 and for 3 matches in one group: with
+        the sinks null the first does not grow with the matches and the
+        second grows by the watchdog's pair a match; with a sink, a read
+        at each boundary of a match's five kinds of work."""
+        import gc
+        import time
+
+        from bevy_ggrs_tpu.obs import trace as obs_trace
+        from tests.test_serve_faults import (
+            inputs_for, make_server, make_synctest,
+        )
+
+        frames = 4
+
+        def reads(matches):
+            own = _CountingClock()
+            srv = make_server(
+                metrics=Metrics() if sinks else None, clock=own,
+                capacity=4, stagger_groups=1,
+            )
+            for k in range(matches):
+                srv.add_match(make_synctest(), inputs_for(k))
+            srv.run_frame()
+            spans = []
+            real_init = obs_trace._Span.__init__
+
+            def counted_init(span, *a, **kw):
+                spans.append(a[2])
+                real_init(span, *a, **kw)
+
+            perf = _CountingClock()
+            own.reads = 0
+            gc.disable()  # a collection is a span too (``gc_pause``)
+            try:
+                with pytest.MonkeyPatch.context() as patch:
+                    patch.setattr(time, "perf_counter", perf)
+                    patch.setattr(obs_trace._Span, "__init__", counted_init)
+                    for _ in range(frames):
+                        srv.run_frame()
+            finally:
+                gc.enable()
+            srv.close()
+            return perf.reads, own.reads, spans
+
+        perf_1, own_1, spans_1 = reads(1)
+        perf_3, own_3, spans_3 = reads(3)
+        assert own_3 - own_1 == 2 * 2 * frames
+        if not sinks:
+            assert spans_1 == spans_3 == []  # NULL_SPAN at every site
+            assert perf_3 == perf_1
+        else:
+            assert len(spans_3) == len(spans_1)  # none a match
+            assert {"serve_frame", "serve_segment", "serve_post",
+                    "serve_report_delivery"} <= set(spans_3)
+            # A SyncTest match has no poll: five boundaries, not six.
+            assert perf_3 - perf_1 == 5 * 2 * frames
 
 
 def run_p2p_spec(ledger_on: bool):

@@ -13,14 +13,15 @@ plane beside ``XLA Ops``. Prints, and writes to
 
 - per span name: count, parent, median and total duration, and SELF time
   (duration minus what its direct children cover);
-- the device's idle gaps (20 us or more between operations, the benchmark's
-  rule) charged to the INNERMOST span the host was in; the tool's own phases
-  (``tool/sleep``, ``tool/far_end``, ``tool/readable``) name what is outside
-  the program.
+- the device's idle gaps charged to the INNERMOST span the host was in, by
+  the benchmark's reducer (``benchmark/reduce/trace.py`` ``nest`` and
+  ``idle_gaps``: gaps of 20 us or more by span, shorter ones summed as
+  ``between_ops_under_20us``, what no span covers as ``unattributed``); the
+  tool's own phases (``tool/sleep``, ``tool/far_end``, ``tool/readable``)
+  name what is outside the program.
 
-This is how the shared clock is read until the benchmark's reducer loads
-``ggrs/`` spans itself (ROADMAP, queued ``benchmark`` issue). Without a TPU
-the trace has no device plane and only the span table is printed.
+Without a TPU the trace has no device plane and only the span table is
+printed.
 """
 
 from __future__ import annotations
@@ -36,6 +37,11 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+
+# The one definition of "innermost span" and of an idle gap's charge is the
+# benchmark's reducer (jax-free until ``load``); ``nest`` is used from here.
+from benchmark.reduce import trace as reduce_trace  # noqa: E402
+from benchmark.reduce.trace import nest  # noqa: E402
 
 PROGRAM, TOOL = "ggrs/", "tool/"
 WINDOW = "tool/window"
@@ -66,8 +72,6 @@ def host_lines(data):
 def device_blocks(data):
     """Busy blocks [(start_s, end_s, busy_s)] of the first TPU, by the
     benchmark's own reduction (gaps under 20 us stay inside a block)."""
-    from benchmark.reduce import trace as reduce_trace
-
     for plane in sorted(data.planes, key=lambda p: p.name):
         if reduce_trace._device_ordinal(plane.name) is None:
             continue
@@ -80,38 +84,6 @@ def device_blocks(data):
                 )
                 return blocks
     return None
-
-
-def nest(events):
-    """From one thread's annotations to (instances, segments): per span
-    instance ``(name, parent, dur_s, self_s)``, and the timeline cut into
-    disjoint ``(start_s, end_s, innermost name or None)`` pieces."""
-    events = sorted(events, key=lambda e: (e[1], -(e[2] - e[1])))
-    instances, segments = [], []
-    stack = []  # [name, end, dur, self, parent]
-    cursor = events[0][1] if events else 0.0
-
-    def close_until(t):
-        nonlocal cursor
-        while stack and stack[-1][1] <= t:
-            name, end, dur, own, parent = stack.pop()
-            if end > cursor:
-                segments.append((cursor, end, name))
-                cursor = end
-            instances.append((name, parent, dur, own))
-
-    for name, s, e in events:
-        close_until(s)
-        if s > cursor:
-            segments.append((cursor, s, stack[-1][0] if stack else None))
-            cursor = s
-        if stack:
-            e = min(e, stack[-1][1])
-            stack[-1][3] -= e - s
-        stack.append([name, e, e - s, e - s,
-                      stack[-1][0] if stack else None])
-    close_until(float("inf"))
-    return instances, segments
 
 
 def span_table(instances):
@@ -134,34 +106,14 @@ def span_table(instances):
     return rows
 
 
-def charge_idle(blocks, segments, window):
-    """Idle seconds of the device inside ``window`` by innermost span."""
-    lo, hi = window
-    gaps, cursor = [], lo
-    for s, e, _ in blocks:
-        if e <= lo or s >= hi:
-            continue
-        if s > cursor:
-            gaps.append((cursor, min(s, hi)))
-        cursor = max(cursor, e)
-    if hi > cursor:
-        gaps.append((cursor, hi))
-    acc = collections.defaultdict(float)
-    j = 0
-    for a, b in gaps:
-        while j < len(segments) and segments[j][1] <= a:
-            j += 1
-        k, covered = j, 0.0
-        while k < len(segments) and segments[k][0] < b:
-            s, e, name = segments[k]
-            part = min(b, e) - max(a, s)
-            if part > 0:
-                acc[name or "(no span)"] += part
-                covered += part
-            k += 1
-        if (b - a) - covered > 1e-12:
-            acc["(no span)"] += (b - a) - covered
-    return dict(acc)
+def charge_idle(blocks, spans, window):
+    """Idle seconds of the device inside ``window`` by the innermost of
+    one thread's ``spans``: the benchmark's own reduction
+    (``reduce/trace.py`` ``idle_gaps`` over its ``nest``), every row."""
+    trace = reduce_trace.Trace(
+        spans=spans, modules={}, blocks={0: blocks}, op_self_s={}
+    )
+    return dict(reduce_trace.idle_gaps(trace, window, n=len(spans) + 2))
 
 
 def report(xspace, mode, extra):
@@ -174,7 +126,8 @@ def report(xspace, mode, extra):
     main = max(lines.values(),
                key=lambda ev: sum(n.startswith(PROGRAM) for n, _, _ in ev))
     window = next(((s, e) for n, s, e in main if n == WINDOW), None)
-    instances, segments = nest([e for e in main if e[0] != WINDOW])
+    spans = [e for e in main if e[0] != WINDOW]
+    instances, _ = nest(spans)
     rows = span_table(instances)
     out = {"mode": mode, "spans": rows, **extra}
     print(f"{'span':34s} {'parent':26s} {'n':>6s} {'median':>9s} "
@@ -187,7 +140,7 @@ def report(xspace, mode, extra):
     if blocks is None or window is None:
         print("no device plane in this trace (no TPU): idle gaps not charged")
     else:
-        idle = charge_idle(blocks, segments, window)
+        idle = charge_idle(blocks, spans, window)
         total = sum(idle.values())
         busy = (window[1] - window[0]) - total
         out["window_s"] = window[1] - window[0]
