@@ -178,7 +178,9 @@ def match_pending(
     deep: ``(branch, depth)`` with ``depth`` counted from the anchor. The
     history is the as-used inputs of the frames that survive the rollback
     (``res_anchor .. load_frame - 1``, from ``input_log``) and then the
-    corrected inputs of ``steps``, cut to the rollout's ``res_frames``.
+    corrected inputs of ``steps`` (a list of steps, each with
+    ``.adv.bits``, or a segment's ``bits [n, P, ...]`` as it is), cut to
+    the rollout's ``res_frames``.
     ``None`` when no branch can be asked: no rollback, no pending rollout,
     a load before its anchor, or a gap in the log (which charges no
     miss). ``native`` is the lane's native builder (its log mirror walks
@@ -187,9 +189,10 @@ def match_pending(
     if load_frame is None or res_anchor is None or load_frame < res_anchor:
         return None
     res_bits = np.asarray(res_bits)
-    corrected = [np.asarray(s.adv.bits) for s in steps]
+    is_array = isinstance(steps, np.ndarray)
+    corrected = steps if is_array else [np.asarray(s.adv.bits) for s in steps]
     if native is not None:
-        steps_arr = np.stack(corrected)
+        steps_arr = corrected if is_array else np.stack(corrected)
         with span("match_branch"):
             return native.match(
                 res_bits, res_anchor, load_frame, steps_arr, res_frames

@@ -86,8 +86,9 @@ def rollback_blame(
 ):
     """``(blame_player, blame_frame)`` for ``ledger``'s entry of a rollback
     to ``load_frame`` whose corrected ``steps`` (each ``.adv.bits [P,
-    ...]``) were ``matched`` against the rollout ``branch_bits [B, F, P,
-    ...]`` from frame ``anchor``: :func:`blame_divergence` against branch 0
+    ...]``; or a segment's ``bits [n, P, ...]`` as it is) were ``matched``
+    against the rollout ``branch_bits [B, F, P, ...]`` from frame
+    ``anchor``: :func:`blame_divergence` against branch 0
     over the frames both cover, the frame made absolute. Pure NumPy on the
     host-resident branch tensor. ``(None, None)`` when the ledger is off
     (nothing is gathered), when no branch was asked (``matched is None``)
@@ -95,9 +96,9 @@ def rollback_blame(
     if matched is None or not ledger.enabled:
         return None, None
     b0 = np.asarray(branch_bits)[0]
-    hit = blame_divergence(
-        b0[load_frame - anchor:], [np.asarray(s.adv.bits) for s in steps]
-    )
+    if not isinstance(steps, np.ndarray):
+        steps = [np.asarray(s.adv.bits) for s in steps]
+    hit = blame_divergence(b0[load_frame - anchor:], steps)
     return (None, None) if hit is None else (hit[1], load_frame + hit[0])
 
 

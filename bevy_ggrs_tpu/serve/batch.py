@@ -104,12 +104,16 @@ from bevy_ggrs_tpu.obs.ledger import rollback_blame
 from bevy_ggrs_tpu.obs.trace import NULL_SPAN, Instrumented
 from bevy_ggrs_tpu.predict.batch import BatchedRanker
 from bevy_ggrs_tpu.predict.model import resolve_predictor
-from bevy_ggrs_tpu.runner import RollbackRunner, _Step
 from bevy_ggrs_tpu.schedule import Schedule
 from bevy_ggrs_tpu.serve.faults import SlotFault, SlotTicket
-from bevy_ggrs_tpu.session.requests import AdvanceFrame, RestoreGameState
+from bevy_ggrs_tpu.session.requests import Segment, SegmentError
 from bevy_ggrs_tpu.spec_runner import SpeculativeRollbackRunner
-from bevy_ggrs_tpu.state import SnapshotRing, WorldState, combine64, ring_init
+from bevy_ggrs_tpu.state import (
+    SnapshotRing,
+    WorldState,
+    combine64_rows,
+    ring_init,
+)
 
 
 class BatchedTickExecutor:
@@ -301,11 +305,14 @@ class BatchedSessionCore(Instrumented):
 
     The per-slot request protocol matches the singleton runner's canonical
     tick: each slot submits one ``[Load?, (Save, Advance)*]`` segment per
-    round with saves labeled contiguously (the session layer produces
-    exactly this shape). ``RestoreGameState`` and non-standard bursts
+    round with saves labeled contiguously, as the
+    :class:`~bevy_ggrs_tpu.session.requests.Segment` its session made
+    (``advance_segment()``) or as the request list, which :meth:`tick`
+    converts once at entry; everything behind that entry pass reads
+    segments. ``RestoreGameState`` and non-standard bursts
     raise a typed :class:`~bevy_ggrs_tpu.serve.faults.SlotFault` naming
     the offending slot — BEFORE any slot's host or device state is touched
-    (every segment of every slot is validated ahead of the apply loop), so
+    (every segment of every slot is checked ahead of the apply loop), so
     the server can drop the faulted slot, re-tick the rest, and drain the
     match to a singleton recovery lane via :meth:`extract`.
 
@@ -692,61 +699,64 @@ class BatchedSessionCore(Instrumented):
 
     # -- ticking --------------------------------------------------------
 
-    def _validate_segment(
-        self, slot: int, frame: int, load_frame: Optional[int], steps
-    ) -> int:
-        """Canonical-shape check for one segment, BEFORE anything mutates:
-        raises :class:`SlotFault` instead of half-applying a round.
-        Returns the frame the slot would reach."""
-        start = frame if load_frame is None else load_frame
-        if not steps or any(
-            st.adv is None or st.save_frame != start + t
-            for t, st in enumerate(steps)
-        ):
+    def _check_segment(self, slot: int, frame: int, seg: Segment) -> int:
+        """Canonical-shape check for one segment of a slot standing at
+        ``frame``, BEFORE anything mutates: raises :class:`SlotFault`
+        instead of half-applying a round. Returns the frame the slot would
+        reach."""
+        n = len(seg.bits)
+        if n == 0 or seg.start != (frame if seg.load is None else seg.load):
             raise SlotFault(slot, "non_canonical_burst", frame)
-        if len(steps) > self.burst_frames:
+        if n > self.burst_frames:
             raise SlotFault(slot, "burst_overflow", frame)
-        return start + len(steps)
+        return seg.start + n
+
+    def _enter(self, work: Dict[int, tuple]) -> Tuple[Dict[int, list], int]:
+        """:meth:`tick`'s entry pass: every slot's work item as checked
+        segments, ``{slot: [(segment, confirmed, session), ...]}``, and how
+        many items arrived as a session-made :class:`Segment`. A request
+        list is converted HERE, once (``Segment.from_requests``: cut at its
+        Loads, one segment a dispatch round); nothing has been written when
+        this raises."""
+        per_slot: Dict[int, list] = {}
+        direct = 0
+        for slot, (item, confirmed, session) in work.items():
+            s = self.slots[slot]
+            if not s.active:
+                raise RuntimeError(f"slot {slot} is not active")
+            frame = s.frame
+            if isinstance(item, Segment):
+                direct += 1
+                segs = (item,)
+            else:
+                try:
+                    segs = Segment.from_requests(item)
+                except SegmentError as e:
+                    raise SlotFault(slot, e.reason, frame, cause=e) from e
+            for seg in segs:
+                frame = self._check_segment(slot, frame, seg)
+            per_slot[slot] = [(seg, confirmed, session) for seg in segs]
+        return per_slot, direct
 
     def tick(self, work: Dict[int, tuple]) -> None:
-        """Advance every slot named in ``work`` — ``{slot: (requests,
-        confirmed_frame, session)}`` (``confirmed_frame=None`` means fully
-        confirmed; ``session`` may be None) — in as few batched dispatches
-        as the deepest request list needs (one per Load-delimited segment;
-        every session emits single-segment lists, so normally one: series
-        ``serve_rounds``).
+        """Advance every slot named in ``work`` — ``{slot: (segment or
+        request list, confirmed_frame, session)}`` (``confirmed_frame=None``
+        means fully confirmed; ``session`` may be None) — in as few batched
+        dispatches as the deepest work item needs (one per Load-delimited
+        segment; every session emits one, so normally one: series
+        ``serve_rounds``). Series ``serve_segment_direct_share``: the share
+        of the items that came as a session-made segment.
 
         Fault atomicity: every slot's every segment (all rounds) is
-        validated up front, so a :class:`SlotFault` escaping this method
-        guarantees NO slot's state — host or device — changed. The caller
-        may drop the named slot from ``work`` and call again."""
+        checked up front (:meth:`_enter`), so a :class:`SlotFault` escaping
+        this method guarantees NO slot's state — host or device — changed.
+        The caller may drop the named slot from ``work`` and call again."""
         self.ticks_total += 1
         self.flush_reports()
-        per_slot: Dict[int, List[tuple]] = {}
-        rounds = 1
-        # The pre-pass of the whole group, one span (no span a slot).
+        # The entry pass of the whole group, one span (no span a slot).
         with self.span("serve_segment", slots=len(work)):
-            for slot, (requests, confirmed, session) in work.items():
-                if not self.slots[slot].active:
-                    raise RuntimeError(f"slot {slot} is not active")
-                frame = self.slots[slot].frame
-                try:
-                    segs = RollbackRunner._segment(None, requests)
-                except TypeError as e:
-                    reason = (
-                        "restore_request"
-                        if any(
-                            isinstance(r, RestoreGameState) for r in requests
-                        )
-                        else "unsupported_request"
-                    )
-                    raise SlotFault(slot, reason, frame, cause=e) from e
-                for load, steps in segs:
-                    frame = self._validate_segment(slot, frame, load, steps)
-                per_slot[slot] = [
-                    (load, steps, confirmed, session) for load, steps in segs
-                ]
-                rounds = max(rounds, len(segs))
+            per_slot, direct = self._enter(work)
+        rounds = max([1, *map(len, per_slot.values())])
         for r in range(rounds):
             batch = {
                 slot: segs[r] for slot, segs in per_slot.items()
@@ -754,40 +764,60 @@ class BatchedSessionCore(Instrumented):
             }
             with self.span("serve_round", round=r, slots=len(batch)):
                 self._dispatch(batch)
-        # The dispatch rounds this group tick ran: 1 when every list was
+        # The dispatch rounds this group tick ran: 1 when every item was
         # one Load-delimited segment (every session's, SyncTest included).
         self.metrics.observe("serve_rounds", rounds)
         self.timeseries.observe("serve_rounds", rounds)
+        if work:
+            self.metrics.observe(
+                "serve_segment_direct_share", direct / len(work)
+            )
 
     def flush_reports(self) -> None:
         """Deliver deferred checksum reports (the only device->host sync
         in the serving loop, off the producing dispatch's critical path):
-        the read is span ``checksum_sync``, the sessions'
-        ``report_checksum`` calls span ``serve_report_delivery``."""
+        the read is span ``checksum_sync``, the fold to 64 bits (once an
+        array, of the reporting slots' rows, never the rollout's) and the
+        sessions' ``report_checksums`` calls, one a segment part, span
+        ``serve_report_delivery``. A
+        session without ``report_checksums`` is told row by row
+        (``report_checksum``, the rows it wants)."""
         if not self._pending_reports:
             return
         pending, self._pending_reports = self._pending_reports, []
-        # An entry names a dispatch's checksum output and which part of it
-        # its rows index (0 absorb, 1 burst); one read a dispatch.
+        # An entry names a dispatch's checksum output, which part of it
+        # (0 absorb, 1 burst) and the slot's first ``n`` rows there; one
+        # read a dispatch.
         with self.span("checksum_sync") as sp_sync:
             read: Dict[int, tuple] = {}
-            host = []
-            for cs, part, rows in pending:
+            for entry in pending:
+                cs = entry[0]
                 if id(cs) not in read:
                     read[id(cs)] = self._exec.cs_host(cs)
-                host.append((read[id(cs)][part], rows))
         # Every entry holds rows (the post pass appends no empty one);
         # they are counted only for a span somebody reads.
         n_rows = (
-            sum(len(rows) for _, rows in host)
+            sum(entry[4] for entry in pending)
             if sp_sync is not NULL_SPAN else 0
         )
         with self.span("serve_report_delivery", rows=n_rows):
-            for cs_host, rows in host:
-                for slot, t, frame, session in rows:
-                    session.report_checksum(
-                        frame, combine64(cs_host[slot, t])
-                    )
+            # One fold an array, of the rows of the slots that have an
+            # entry there; the entries are served in the order they came.
+            slots_of: Dict[tuple, list] = {}
+            for cs, part, slot, _first, _n, _session in pending:
+                slots_of.setdefault((id(cs), part), []).append(slot)
+            folded = {
+                key: iter(combine64_rows(read[key[0]][key[1]][slots]).tolist())
+                for key, slots in slots_of.items()
+            }
+            for cs, part, _slot, first, n, session in pending:
+                values = next(folded[id(cs), part])[:n]
+                report = getattr(session, "report_checksums", None)
+                if report is not None:
+                    report(first, values)
+                else:
+                    for t, frame in wanted_rows(session, first, n):
+                        session.report_checksum(frame, values[t])
 
     def _record_predictor_rank(self, rank_ms: float) -> None:
         self.last_predictor_rank_ms = rank_ms
@@ -847,10 +877,12 @@ class BatchedSessionCore(Instrumented):
         pass (:meth:`_post_dispatch`) runs once the call's frame is gone
         too.
 
-        Atomic on fault: segments are re-validated in a pre-pass (direct
-        callers may bypass :meth:`tick`), so a raise can only happen before
-        the first input-log write or device dispatch — a sibling slot's
-        next-tick output is bitwise unaffected by another slot faulting."""
+        ``batch`` is ``{slot: (segment, confirmed, session)}``, every
+        segment already checked (:meth:`_check_segment`: by :meth:`tick`'s
+        entry pass, or by the direct caller that built it,
+        :meth:`repair_slot`), so nothing here raises a :class:`SlotFault`:
+        a sibling slot's next-tick output is bitwise unaffected by another
+        slot faulting."""
         stage = (
             self._dispatch_native if self._plane is not None
             else self._dispatch_python
@@ -878,25 +910,22 @@ class BatchedSessionCore(Instrumented):
         with self.span(
             "serve_arg_assembly", series=False, slots=len(batch)
         ) as sp_loop:
-            for i, (load_frame, steps, _confirmed, _session) in batch.items():
-                self._validate_segment(
-                    i, self.slots[i].frame, load_frame, steps
-                )
             ints_a, bits_a, bb_a = self._host_args()
             status_a = TickInts.status(ints_a, MF, P)
             # Pass 1 — as-used log writes + anchor geometry for every batched
             # slot, hoisted ahead of the build loop so the batched predictor
             # ranking sees all post-write windows in ONE vmapped call.
             geom: Dict[int, tuple] = {}
-            for i, (load_frame, steps, confirmed, _session) in batch.items():
+            for i, (seg, confirmed, _session) in batch.items():
                 s = self.slots[i]
-                start = s.frame if load_frame is None else load_frame
-                end = start + len(steps)
+                start = seg.start
+                end = start + len(seg.bits)
                 anchor = end if confirmed is None else confirmed + 1
                 # As-used log BEFORE match/build (forward-fill reads anchor-1,
-                # which this very burst may advance).
-                for t, st in enumerate(steps):
-                    s.input_log[start + t] = np.asarray(st.adv.bits)
+                # which this very burst may advance): views of the segment's
+                # rows, which nobody else writes.
+                for t, row in enumerate(seg.bits):
+                    s.input_log[start + t] = row
                 spec_active = s.spec_on and spec_in_window(
                     anchor, end, self.ring_depth
                 )
@@ -938,7 +967,7 @@ class BatchedSessionCore(Instrumented):
                     i = s.index
                     _start, end, anchor, _active = geom[i]
                     trees[i] = self._build_branches(
-                        s, anchor, end, batch[i][3], seeds.get(i)
+                        s, anchor, end, batch[i][2], seeds.get(i)
                     )
             for s in self.slots:
                 i = s.index
@@ -951,21 +980,22 @@ class BatchedSessionCore(Instrumented):
                     if s.res_anchor is not None:
                         bb_a[i] = s.res_bits
                     continue
-                load_frame, steps, _confirmed, session = batch[i]
+                seg, _confirmed, session = batch[i]
                 _start, end, anchor, spec_active = geom[i]
+                load_frame, n_steps = seg.load, len(seg.bits)
                 # The plan (host-side, zero device syncs).
                 matched = match_pending(
                     s.native, s.input_log, s.res_bits, s.res_anchor, F,
-                    load_frame, steps,
+                    load_frame, seg.bits,
                 )
                 plan = plan_tick(
-                    ints_a[i], s.frame, load_frame, len(steps), s.res_anchor,
+                    ints_a[i], s.frame, load_frame, n_steps, s.res_anchor,
                     F, matched, anchor, self.ring_depth, s.spec_on,
                 )
-                n_commit = plan[1]
-                for t, st in enumerate(steps[n_commit:]):
-                    bits_a[i, t] = np.asarray(st.adv.bits)
-                    status_a[i, t] = np.asarray(st.adv.status, np.int32)
+                n_commit, n_tail = plan[1], plan[5]
+                if n_tail:
+                    bits_a[i, :n_tail] = seg.bits[n_commit:]
+                    status_a[i, :n_tail] = seg.status[n_commit:]
                 # An inactive lane's rollout (from the live frontier) is
                 # discarded: its row stays zeros. bb is per-call fresh from
                 # both builders, so storing it for the replay/match path
@@ -975,10 +1005,10 @@ class BatchedSessionCore(Instrumented):
                     bb_a[i] = bb
                 blame = rollback_blame(
                     self.ledger, matched, s.res_bits, s.res_anchor,
-                    load_frame, steps,
+                    load_frame, seg.bits,
                 )
                 post[i] = (
-                    end, load_frame, len(steps), session, bb, plan, blame,
+                    end, load_frame, n_steps, session, bb, plan, blame,
                 )
 
         if sp_loop is not NULL_SPAN:
@@ -1048,8 +1078,9 @@ class BatchedSessionCore(Instrumented):
         """The post-dispatch bookkeeping of one round (span ``serve_post``,
         one for the whole round): apply each ticked lane's plan: frame
         counter, rollout metadata, the rollback's accounting
-        (``fused.account_rollback``) and the deferred rows of the
-        dispatch's checksum output ``cs``. ``post[slot]`` is ``(end,
+        (``fused.account_rollback``) and, for each part of the dispatch's
+        checksum output ``cs`` the lane's session wants a frame of, ONE
+        deferred entry ``(cs, part, slot, first frame, frames, session)``. ``post[slot]`` is ``(end,
         load_frame, n_steps, session, the lane's next in-flight tree or
         None, its plan, its blame)``."""
         with self.span("serve_post", slots=len(post)):
@@ -1084,15 +1115,15 @@ class BatchedSessionCore(Instrumented):
                         blame, slot=i,
                     )
                 if session is not None and self.report_checksums:
+                    wants = getattr(session, "wants_checksum", None)
                     for part, first, n in (
                         (0, load_frame, n_commit), (1, burst_start, n_tail)
                     ):
-                        rows = wanted_rows(session, first, n)
-                        if rows:
-                            reports.append((
-                                cs, part,
-                                [(i,) + r + (session,) for r in rows],
-                            ))
+                        if n and (
+                            wants is None
+                            or any(map(wants, range(first, first + n)))
+                        ):
+                            reports.append((cs, part, i, first, n, session))
                 self._gc_log(s)
             self._pending_reports.extend(reports)
 
@@ -1124,33 +1155,29 @@ class BatchedSessionCore(Instrumented):
         with self.span(
             "serve_arg_assembly", series=False, slots=len(batch)
         ) as sp_loop:
-            for i, (load_frame, steps, _confirmed, _session) in batch.items():
-                self._validate_segment(
-                    i, self.slots[i].frame, load_frame, steps
-                )
             ints_a, bits_a, bb_a = self._host_args()
             status_a = TickInts.status(ints_a, MF, P)
             plane.reset_masks()
-            # Pass 1 — SoA staging for ggrs_batch_stage: step bits/status,
-            # anchor geometry, match inputs, window-gather requests. The
-            # Python-side dict update bypasses MirroredLog's per-row ctypes
-            # forward — the stage call lands the same rows in the native
-            # mirror (in per-slot log -> match -> gather order, mirroring
-            # the Python pass structure).
+            # Pass 1 — SoA staging for ggrs_batch_stage: step bits/status
+            # (the segment's two arrays, a slice assignment each), anchor
+            # geometry, match inputs, window-gather requests. The
+            # Python-side dict update (views of the segment's rows) bypasses
+            # MirroredLog's per-row ctypes forward — the stage call lands
+            # the same rows in the native mirror (in per-slot log -> match
+            # -> gather order, mirroring the Python pass structure).
             geom: Dict[int, tuple] = {}
-            for i, (load_frame, steps, confirmed, _session) in batch.items():
+            for i, (seg, confirmed, _session) in batch.items():
                 s = self.slots[i]
-                start = s.frame if load_frame is None else load_frame
-                end = start + len(steps)
+                load_frame, start, bits = seg.load, seg.start, seg.bits
+                n_steps = len(bits)
+                end = start + n_steps
                 anchor = end if confirmed is None else confirmed + 1
                 plane.log_mask[i] = 1
                 plane.starts[i] = start
-                plane.n_steps[i] = len(steps)
-                for t, st in enumerate(steps):
-                    arr = np.asarray(st.adv.bits)
-                    dict.__setitem__(s.input_log, start + t, arr)
-                    plane.steps[i, t] = arr
-                    plane.status[i, t] = np.asarray(st.adv.status, np.int32)
+                plane.n_steps[i] = n_steps
+                plane.steps[i, :n_steps] = bits
+                plane.status[i, :n_steps] = seg.status
+                dict.update(s.input_log, zip(range(start, end), bits))
                 if (
                     load_frame is not None
                     and s.res_anchor is not None
@@ -1212,9 +1239,9 @@ class BatchedSessionCore(Instrumented):
                         plane.copy_mask[i] = 1
                         plane.set_res(i, s.res_bits)
                     continue
-                load_frame, steps, _confirmed, session = batch[i]
+                seg, _confirmed, session = batch[i]
                 _start, end, anchor, spec_active = geom[i]
-                n_steps = len(steps)
+                load_frame, n_steps = seg.load, len(seg.bits)
                 # The staged match, as ``match_pending`` answers: -1 = a
                 # gap in the as-used log.
                 matched = None
@@ -1232,7 +1259,7 @@ class BatchedSessionCore(Instrumented):
                     status_a[i, :n_tail] = plane.status[i, n_commit:n_steps]
                 blame = rollback_blame(
                     self.ledger, matched, s.res_bits, s.res_anchor,
-                    load_frame, steps,
+                    load_frame, seg.bits,
                 )
                 # The slot's next in-flight tree is its bb_a row, written by
                 # the build call below — the view is stored now, the bytes
@@ -1352,23 +1379,25 @@ class BatchedSessionCore(Instrumented):
         if not clean_below:
             _fail("no digest-clean snapshot below the corrupt rows")
         base = clean_below[-1]
-        steps = []
+        rows = []
         for f in range(base, s.frame):
             bits = s.input_log.get(f)
             if bits is None:
                 _fail(f"as-used input log does not cover frame {f}")
-            steps.append(_Step(
-                save_frame=f,
-                adv=AdvanceFrame(bits, np.zeros(self.num_players, np.int32)),
-            ))
+            rows.append(bits)
+        replay = Segment(
+            base, base, np.stack(rows),
+            np.zeros((len(rows), self.num_players), np.int32),
+        )
+        self._check_segment(slot, s.frame, replay)
         row = corrupt[0] % self.ring_depth
         before = integrity.host_row(self.rings, row, slot=slot)
         pre_live = np.asarray(integrity._states_digests(self.states))[slot]
         # Pending branches were rolled out from pre-repair buffers; drop
         # them so the dispatch skips branch-match and rolls fresh ones.
         s.res_anchor, s.res_bits = None, None
-        with self.span("sdc_repair", slot=slot, frames=len(steps)):
-            self._dispatch({slot: (base, steps, None, session)})
+        with self.span("sdc_repair", slot=slot, frames=replay.n):
+            self._dispatch({slot: (replay, None, session)})
         post_live = np.asarray(integrity._states_digests(self.states))[slot]
         after = integrity.host_row(self.rings, row, slot=slot)
         post_mask = integrity.attest_ring(self.rings)[slot]
@@ -1376,7 +1405,7 @@ class BatchedSessionCore(Instrumented):
             "slot": slot,
             "corrupt_frames": corrupt,
             "repaired": len(corrupt),
-            "repair_frames": len(steps),
+            "repair_frames": replay.n,
             "bitwise": bool(
                 (pre_live == post_live).all() and not post_mask.any()
             ),
@@ -1387,5 +1416,5 @@ class BatchedSessionCore(Instrumented):
         self.metrics.count("sdc_repaired", len(corrupt))
         if report["bitwise"]:
             self.metrics.count("sdc_repaired_bitwise", len(corrupt))
-        self.metrics.observe("sdc_repair_frames", len(steps))
+        self.metrics.observe("sdc_repair_frames", replay.n)
         return report

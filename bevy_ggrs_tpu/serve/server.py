@@ -17,7 +17,12 @@ spectator sessions all fit):
 
 - ``local_player_handles()`` + ``add_local_input(handle, bits)`` — fed
   from the match's ``local_inputs(frame, handle)`` callback each frame;
-- ``advance_frame() -> [requests]`` — the canonical request list;
+- ``advance_frame() -> [requests]`` — the canonical request list; a
+  session whose class also has ``advance_segment()`` (``SyncTestSession``,
+  ``P2PSession``) is asked for that instead: the same run as one
+  :class:`~bevy_ggrs_tpu.session.requests.Segment`, arrays in place of
+  request objects (a wrapper around such a session, or an instance whose
+  ``advance_frame`` was replaced, is asked through ``advance_frame()``);
 - ``confirmed_frame()`` (optional) — the speculation anchor; absent means
   fully confirmed every frame (synctest);
 - ``poll_remote_clients()`` (optional) — pumped before input collection;
@@ -25,7 +30,9 @@ spectator sessions all fit):
   the group's two-slot list while a sink listens, for the poll's receive
   and send seconds;
 - ``report_checksum(frame, checksum)`` / ``wants_checksum(frame)``
-  (optional) — fed from the core's deferred checksum reports;
+  (optional) — fed from the core's deferred checksum reports, a segment's
+  in one ``report_checksums(first_frame, checksums)`` call where the
+  session has it;
 - ``checksum_votes`` + ``drain_control`` (optional) — their presence
   marks a supervisable P2P session: the server wraps it in a
   :class:`~bevy_ggrs_tpu.session.supervisor.SessionSupervisor` whose
@@ -79,7 +86,7 @@ from bevy_ggrs_tpu.serve.faults import (
     adopt_ticket,
 )
 from bevy_ggrs_tpu.session.common import PredictionThreshold, SessionState
-from bevy_ggrs_tpu.session.requests import AdvanceFrame
+from bevy_ggrs_tpu.session.requests import AdvanceFrame, Segment
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,6 +112,32 @@ def _supervisable(session) -> bool:
     return hasattr(session, "checksum_votes") and hasattr(
         session, "drain_control"
     )
+
+
+def _advance(session):
+    """The session's work for this frame: the :class:`Segment` of
+    ``advance_segment()`` where its CLASS has one, else the request list
+    of ``advance_frame()``. Asked of the class, and only while the
+    instance still has the class's ``advance_frame``: a delegating wrapper
+    (``__getattr__``) or a replaced ``advance_frame`` stands in front of
+    the list, and is asked for it."""
+    segment = getattr(type(session), "advance_segment", None)
+    if segment is None or "advance_frame" in getattr(session, "__dict__", ()):
+        return session.advance_frame()
+    return segment(session)
+
+
+def _advances(item) -> int:
+    """Frames a work item advances: a segment's rows, a list's
+    ``AdvanceFrame``s."""
+    if isinstance(item, Segment):
+        return len(item.bits)
+    return sum(1 for r in item if isinstance(r, AdvanceFrame))
+
+
+def _requests(item) -> List[object]:
+    """A work item as the request list a recovery lane's runner takes."""
+    return item.requests() if isinstance(item, Segment) else item
 
 
 _TAKES_PARTS: Dict[type, bool] = {}
@@ -1143,7 +1176,7 @@ class MatchServer(Instrumented):
                                 t_b = clock()
                                 inputs_s += t_b - t_a
                                 t_a = t_b
-                            requests = session.advance_frame()
+                            item = _advance(session)
                             conf = getattr(session, "confirmed_frame", None)
                             confirmed = conf() if conf is not None else None
                             if timed:
@@ -1171,15 +1204,9 @@ class MatchServer(Instrumented):
                             continue
                         elapsed_ms = (self._clock() - t_m) * 1000.0
                         # SLO sample: deadline hit + rollback depth (every
-                        # AdvanceFrame past the first in a canonical burst is
-                        # a resimulated frame).
-                        depth = max(
-                            0,
-                            sum(
-                                1 for r in requests
-                                if isinstance(r, AdvanceFrame)
-                            ) - 1,
-                        )
+                        # frame past the first of a canonical burst is a
+                        # resimulated frame).
+                        depth = max(0, _advances(item) - 1)
                         self.slo.observe_tick(
                             self._flat_slot(handle),
                             deadline_ok=elapsed_ms <= self.watchdog_budget_ms,
@@ -1192,12 +1219,12 @@ class MatchServer(Instrumented):
                                 # runner frame counters stay converged.
                                 self._fault(
                                     handle, m, "watchdog_timeout",
-                                    pending=(requests, session),
+                                    pending=(_requests(item), session),
                                 )
                                 continue
                         else:
                             m.fsm.clear()
-                        work[slot] = (requests, confirmed, session)
+                        work[slot] = (item, confirmed, session)
                         if timed:
                             slo_s += clock() - t_a
                     if calls_0 is not None:
@@ -1218,11 +1245,11 @@ class MatchServer(Instrumented):
                         core.tick(work)
                         break
                     except SlotFault as f:
-                        requests, _conf, session = work.pop(f.slot)
+                        item, _conf, session = work.pop(f.slot)
                         handle = MatchHandle(g, f.slot)
                         self._fault(
                             handle, self._matches[handle], f.reason,
-                            cause=f, pending=(requests, session),
+                            cause=f, pending=(_requests(item), session),
                         )
                 # Any slot that just rode its first successful dispatch
                 # completes its admission trace: first_frame_served.

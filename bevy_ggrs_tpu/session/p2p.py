@@ -53,7 +53,7 @@ from bevy_ggrs_tpu.native.core import (
     make_tracker,
 )
 from bevy_ggrs_tpu.session.endpoint import PeerEndpoint, PeerState
-from bevy_ggrs_tpu.session.requests import AdvanceFrame, LoadGameState, SaveGameState
+from bevy_ggrs_tpu.session.requests import AdvanceFrame, Segment
 from bevy_ggrs_tpu.obs.trace import Instrumented
 
 # Upper bound on the AUTO desync-detection interval (frames between
@@ -648,6 +648,23 @@ class P2PSession(Instrumented):
         ``GameStateCell::save`` analog). Resimulated frames overwrite —
         only *confirmed* frames are comparable across peers."""
         self._local_checksums[frame] = int(checksum)
+        self._prune_checksums()
+
+    def report_checksums(self, first_frame: int, checksums) -> None:
+        """The checksums of frames ``first_frame ..`` saved in a row (a
+        segment's): those :meth:`wants_checksum` names are stored as by
+        :meth:`report_checksum` and the map is pruned once; the others are
+        dropped."""
+        wanted = {
+            frame: int(checksum)
+            for frame, checksum in enumerate(checksums, first_frame)
+            if self.wants_checksum(frame)
+        }
+        if wanted:
+            self._local_checksums.update(wanted)
+            self._prune_checksums()
+
+    def _prune_checksums(self) -> None:
         horizon = self.confirmed_frame() - 4 * max(self.desync_interval, 1)
         for f in [f for f in self._local_checksums if f < horizon]:
             del self._local_checksums[f]
@@ -730,10 +747,18 @@ class P2PSession(Instrumented):
         ).reshape(self._zero.shape)
 
     def advance_frame(self) -> List[object]:
-        with self.tracer.span("advance_frame"):
-            return self._advance_frame()
+        """The frame's request list: :meth:`advance_segment`'s, one
+        ``[Load?, (Save, Advance)*]`` run."""
+        return self.advance_segment().requests()
 
-    def _advance_frame(self) -> List[object]:
+    def advance_segment(self) -> Segment:
+        """The frame as a :class:`Segment` (the corrected frames of a
+        rollback, then the new one): what a hosting loop takes in place of
+        the request list."""
+        with self.tracer.span("advance_frame"):
+            return self._advance_segment()
+
+    def _advance_segment(self) -> Segment:
         if self.current_state() != SessionState.RUNNING:
             raise NotSynchronized("session is still synchronizing")
         missing = [h for h in self.local_handles if h not in self._pending_local]
@@ -801,7 +826,6 @@ class P2PSession(Instrumented):
                 for f, got in echoed:
                     ep.queue_input(h, f, got)
 
-        requests: List[object] = []
         if load != NULL_FRAME:
             # Rollback: a confirmed input contradicted a prediction. A load
             # clamped to frame - max_prediction is deeper than the snapshot
@@ -812,18 +836,14 @@ class P2PSession(Instrumented):
             # what desync detection + the supervisor's state resync repair.
             self.metrics.count("mispredictions")
             self.metrics.observe("misprediction_depth", frame - load)
-            requests.append(LoadGameState(load))
-        # The corrected frames, then the new one.
-        for i in range(len(bits)):
-            requests.append(SaveGameState(start + i))
-            requests.append(AdvanceFrame(bits=bits[i], status=status[i]))
         self.current_frame = frame + 1
 
         if spectators:
             with self.span("spectator_fanout", frame=frame):
                 self._fanout_spectators()
                 self._gc()  # the fan-out moved the spectators' floor
-        return requests
+        # The corrected frames, then the new one.
+        return Segment(None if load == NULL_FRAME else load, start, bits, status)
 
     def _advance_request(self, frame: int) -> AdvanceFrame:
         disc = [

@@ -47,7 +47,7 @@ from bevy_ggrs_tpu.session.common import (
     serialize_spans,
 )
 from bevy_ggrs_tpu.native.core import make_queue_set
-from bevy_ggrs_tpu.session.requests import AdvanceFrame, LoadGameState, SaveGameState
+from bevy_ggrs_tpu.session.requests import Segment
 
 
 class SyncTestSession:
@@ -99,7 +99,12 @@ class SyncTestSession:
     def advance_frame(self) -> List[object]:
         """Emit the request list for one simulated frame: the forced
         rollback + resimulation once history allows, then the frame's own
-        step, as one Load-delimited list."""
+        step, as one Load-delimited list (:meth:`advance_segment`'s)."""
+        return self.advance_segment().requests()
+
+    def advance_segment(self) -> Segment:
+        """One simulated frame as a :class:`Segment`: what a hosting loop
+        takes in place of the request list."""
         if set(self._pending) != set(range(self.num_players)):
             missing = set(range(self.num_players)) - set(self._pending)
             raise InvalidRequest(f"missing local input for handles {sorted(missing)}")
@@ -118,17 +123,12 @@ class SyncTestSession:
             )
         )
         self._pending.clear()
-
-        # ``start`` is ``frame`` before history allows a rollback: then the
-        # list is the frame's own (save, advance) alone.
-        requests: List[object] = [LoadGameState(start)] if resim else []
-        for i in range(len(bits)):
-            requests.append(SaveGameState(start + i))
-            requests.append(AdvanceFrame(bits=bits[i], status=status[i]))
         self.current_frame = frame + 1
         for f in [f for f in self._checksums if f < horizon]:
             del self._checksums[f]
-        return requests
+        # ``start`` is ``frame`` before history allows a rollback: then the
+        # segment is the frame's own (save, advance) alone, nothing loaded.
+        return Segment(start if resim else None, start, bits, status)
 
     # -- checkpoint / resume -----------------------------------------------
 
@@ -168,9 +168,15 @@ class SyncTestSession:
         """The ``GameStateCell::save`` analog (`ggrs_stage.rs:282-283`): the
         driver reports each saved frame's checksum; a resimulated frame that
         hashes differently than its original save is a desync."""
-        checksum = int(checksum)
-        prev = self._checksums.get(frame)
-        if prev is None:
-            self._checksums[frame] = checksum
-        elif prev != checksum:
-            raise MismatchedChecksum(frame, prev, checksum)
+        self.report_checksums(frame, (checksum,))
+
+    def report_checksums(self, first_frame: int, checksums) -> None:
+        """The checksums of frames ``first_frame ..`` saved in a row (a
+        segment's), compared in order as so many :meth:`report_checksum`
+        calls: raises at the first frame that differs."""
+        seen = self._checksums
+        for frame, checksum in enumerate(checksums, first_frame):
+            checksum = int(checksum)
+            prev = seen.setdefault(frame, checksum)
+            if prev != checksum:
+                raise MismatchedChecksum(frame, prev, checksum)

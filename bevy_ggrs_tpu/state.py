@@ -347,6 +347,13 @@ def combine64(cs) -> int:
     return int(a[0] | (a[1] << np.uint64(32)))
 
 
+def combine64_rows(cs) -> np.ndarray:
+    """:func:`combine64` of every ``uint32[2]`` row of ``cs [..., 2]`` in
+    one pass: ``uint64[...]`` (``.tolist()`` gives the Python ints)."""
+    a = np.asarray(cs, dtype=np.uint64)
+    return a[..., 0] | (a[..., 1] << np.uint64(32))
+
+
 def checksum(state: WorldState) -> jnp.ndarray:
     """Order-insensitive 64-bit checksum of the rollback domain, as two
     uint32 lanes ``[lo, hi]``.
