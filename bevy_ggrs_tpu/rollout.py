@@ -33,7 +33,10 @@ import jax
 import jax.numpy as jnp
 
 from bevy_ggrs_tpu.schedule import PlayerInputs, Schedule
-from bevy_ggrs_tpu.state import SnapshotRing, WorldState, checksum, ring_load, ring_save
+from bevy_ggrs_tpu.state import (
+    SnapshotRing, WorldState, checksum, ring_load, ring_rows_flat,
+    ring_rows_shaped, ring_save,
+)
 
 
 def rollout_burst(
@@ -58,6 +61,8 @@ def rollout_burst(
     the saved checksum at step ``t`` (0 where ``save_mask[t]`` is False).
     """
     start_frame = jnp.asarray(start_frame, dtype=jnp.int32)
+    # Large rows ride the scan flat (``state.py`` ``FLAT_ROW_BYTES``).
+    shaped, ring = ring, ring_rows_flat(ring)
 
     def body(carry, xs):
         ring, state, frame = carry
@@ -73,7 +78,7 @@ def rollout_burst(
     (ring, state, _), checksums = jax.lax.scan(
         body, (ring, state, start_frame), (bits, status, save_mask, adv_mask)
     )
-    return ring, state, checksums
+    return ring_rows_shaped(ring, shaped), state, checksums
 
 
 class RolloutExecutor:

@@ -113,6 +113,7 @@ from bevy_ggrs_tpu.state import (
     WorldState,
     combine64_rows,
     ring_init,
+    ring_row_lowerings,
 )
 
 
@@ -158,6 +159,10 @@ class BatchedTickExecutor:
         # ``io.last``: the series ``tick_io_buffers`` of the cores that
         # share this executor.
         self.io = IoBuffers()
+        # Ring leaves traced flat / shaped so far in this process
+        # (``state.py`` ``FLAT_ROW_BYTES``): what this executable's first
+        # call adds is its own (:meth:`traced_ring_rows`).
+        self._ring_rows0: Optional[Dict[str, int]] = dict(ring_row_lowerings)
         # Cost-observatory hook: when armed, the NEXT dispatch prices the
         # compiled program (cost_analysis/memory_analysis) into
         # utils.xla_cache under this name. Arm it before warmup — the AOT
@@ -225,6 +230,18 @@ class BatchedTickExecutor:
         spec_cs)`` as NumPy arrays with the leading ``[S]`` axis."""
         return self.packed.cs_host(cs)
 
+    def traced_ring_rows(self) -> Dict[str, int]:
+        """``{kind: ring leaves}`` the batched tick was traced with, by the
+        form its bursts carry them in ("flat" / "shaped"); told once, to
+        the core whose warm-up traced the program ({} to every other)."""
+        before, self._ring_rows0 = self._ring_rows0, None
+        if before is None:
+            return {}
+        return {
+            kind: n - before[kind]
+            for kind, n in ring_row_lowerings.items() if n > before[kind]
+        }
+
     def cache_size(self) -> int:
         """Compiled-variant count of the batched tick program (-1 when the
         jit internals don't expose it). 1 after warmup, and it must STAY 1
@@ -250,7 +267,9 @@ class BatchedTickExecutor:
             name, self._cost_name = self._cost_name, None
             xla_cache.record_executable_cost(name, self._fn, *full_args)
             self._captured_name = name
-        return self.io.count("tick", full_args, self._fn(*full_args))
+        return self.io.count(
+            "tick", full_args, self._fn(*full_args), host=full_args[1:]
+        )
 
 
 class _SlotSpecShim:
@@ -553,6 +572,7 @@ class BatchedSessionCore(Instrumented):
         must not trigger a compile (the acceptance contract checked
         against ``compile_counters()``)."""
         self._dispatch({})
+        self._observe_warmup()
         # Identity write: row 0 written back onto itself compiles the
         # admit program without disturbing any occupant.
         self._admit_row(0, self.slot_ring(0), self.slot_state(0))
@@ -1032,6 +1052,21 @@ class BatchedSessionCore(Instrumented):
             if stage_ms is not None:
                 sink.observe("native_batch_ms", stage_ms + build_ms)
 
+    def _observe_warmup(self) -> None:
+        """What the first dispatch fixed for good: the bytes of this
+        group's carried device state (one sample of ``serve_carry_bytes``:
+        states, rings, prev_states, prev_rings as the packed carry) and the
+        form the program's bursts carry their ring rows in (the labelled
+        count ``ring_row_lowering``). Its own frame ON PURPOSE: ``warmup``'s
+        locals lie under the first call's trace (``PERF.md`` section 7)."""
+        self.metrics.observe(
+            "serve_carry_bytes", sum(int(x.nbytes) for x in self._carry)
+        )
+        for kind, n in self._exec.traced_ring_rows().items():
+            self.metrics.count(
+                "ring_row_lowering", n, labels={"kind": kind}
+            )
+
     def _host_args(self) -> tuple:
         """The three host arrays of one dispatch (``BatchedTickExecutor.
         run``), zeroed: ``plan_tick`` writes every lane's scalars. Fresh per
@@ -1070,6 +1105,7 @@ class BatchedSessionCore(Instrumented):
             )
         self._trees = None
         self.metrics.observe("tick_io_buffers", self._exec.io.last)
+        self.metrics.observe("tick_stage_bytes", self._exec.io.staged_bytes)
         return cs, post, reports
 
     def _post_dispatch(

@@ -641,12 +641,17 @@ def ring_put(
     valid: Optional[jnp.ndarray] = None,
 ) -> SnapshotRing:
     """Write ``state`` with its checksum ``cs`` into ``frame``'s row (a
-    no-op where ``valid`` is False)."""
+    no-op where ``valid`` is False). ``ring`` may hold its large rows flat
+    (:func:`ring_rows_flat`): a leaf of ``state`` is then flattened the
+    same way on its way in."""
     frame = jnp.asarray(frame, dtype=jnp.int32)
     slot = jnp.remainder(frame, ring.depth)
     return SnapshotRing(
         states=jax.tree_util.tree_map(
-            lambda r, s: ring_row_write(r, s, slot, valid), ring.states, state
+            lambda r, s: ring_row_write(
+                r, s if s.ndim == r.ndim - 1 else _row_flat(s, 0), slot, valid
+            ),
+            ring.states, state,
         ),
         frames=ring_row_write(ring.frames, frame, slot, valid),
         checksums=ring_row_write(ring.checksums, cs, slot, valid),
@@ -667,6 +672,79 @@ def ring_save(
     """
     cs = active_checksum(state)
     return ring_put(ring, state, frame, cs, valid), cs
+
+
+# The third lowering: WHERE a burst keeps its ring while it writes it.
+#
+# A scan that carries a ring leaf ``[depth, N, k]`` with a narrow last axis
+# (boids' ``position [1024, 2]``) carries it, on the TPU, in the default
+# row-major tiling: ``k`` in the 128 lanes, 64 x the bytes for ``k`` = 2.
+# Nothing while one row is written in place; but under the slot ``vmap`` the
+# write is the select form above, which rewrites the WHOLE ring every scan
+# step: at S=64 x B=8 x F=8 x 1,024 boids 2 x 2.1 GB padded where the data is
+# 2 x 33 MB, 103 ms of a 194 ms dispatch, and 5.4 GB of scratch (my chip run,
+# PR 37; ``PERF.md`` section 6). So for the length of a burst a row of
+# ``FLAT_ROW_BYTES`` or more is kept FLAT, its largest axis last (the order
+# the packed carry and the compiler's own parameter layouts use, so entering
+# and leaving the form is a bitcast or one copy a burst): ``[depth, N * k]``
+# is lane-dense in any tiling, and the select form then moves the data's
+# bytes (1.3 % of the same dispatch). Chosen from the row's bytes alone: a
+# row under one 4 KiB tile (every box_game leaf: 192 bytes at most) stays as
+# it is and its programs with it. Bits are only moved.
+FLAT_ROW_BYTES = 4 << 10
+
+# How many ring leaves each form was traced with (``kind``: "flat" /
+# "shaped"), process-wide; ``serve/batch.py`` reports its executable's
+# share as the labelled count ``ring_row_lowering``.
+ring_row_lowerings: Dict[str, int] = {"flat": 0, "shaped": 0}
+
+
+def _row_flat(x: jnp.ndarray, lead: int) -> jnp.ndarray:
+    """``x[*lead axes, *row]`` with a row of ``FLAT_ROW_BYTES`` or more and
+    two axes or more flattened to ``[*lead axes, n]``, largest axis last;
+    any other leaf as it is."""
+    shape = tuple(x.shape[lead:])
+    n = int(np.prod(shape, dtype=np.int64))
+    if len(shape) < 2 or n * x.dtype.itemsize < FLAT_ROW_BYTES:
+        return x
+    perm = _lanes_last(shape)
+    if perm is not None:
+        x = jnp.transpose(
+            x, tuple(range(lead)) + tuple(lead + a for a in perm)
+        )
+    return x.reshape(x.shape[:lead] + (n,))
+
+
+def ring_rows_flat(ring: SnapshotRing) -> SnapshotRing:
+    """``ring`` with every large row flat (see ``FLAT_ROW_BYTES``): the form
+    a burst's scan carries. :func:`ring_put` writes a state into either
+    form; :func:`ring_rows_shaped` is the way back."""
+    states = jax.tree_util.tree_map(lambda x: _row_flat(x, 1), ring.states)
+    for flat, x in zip(jax.tree_util.tree_leaves(states),
+                       jax.tree_util.tree_leaves(ring.states)):
+        ring_row_lowerings["shaped" if flat is x else "flat"] += 1
+    return ring.replace(states=states)
+
+
+def ring_rows_shaped(ring: SnapshotRing, like: SnapshotRing) -> SnapshotRing:
+    """The inverse of :func:`ring_rows_flat`: ``ring``'s rows in the shapes
+    ``like``'s have."""
+
+    def shaped(x, ref):
+        if x.shape == ref.shape:
+            return x
+        row = tuple(ref.shape[1:])
+        perm = _lanes_last(row)
+        if perm is None:
+            return x.reshape(ref.shape)
+        x = x.reshape((ref.shape[0],) + tuple(row[a] for a in perm))
+        return jnp.transpose(
+            x, (0,) + tuple(1 + int(a) for a in np.argsort(perm))
+        )
+
+    return ring.replace(
+        states=jax.tree_util.tree_map(shaped, ring.states, like.states)
+    )
 
 
 def ring_load(ring: SnapshotRing, frame: jnp.ndarray) -> WorldState:
