@@ -188,9 +188,7 @@ def _flock_step(state: WorldState, inputs: PlayerInputs, pairwise_fn) -> WorldSt
     force = pairwise_fn(pos, vel, active)
 
     # Leader steering (player inputs), box_game-style exclusive keys.
-    num_players = inputs.num_players
-    safe = jnp.clip(leader, 0, num_players - 1)
-    bits = inputs.bits[safe].astype(jnp.uint32)
+    bits = inputs.for_handles(leader).astype(jnp.uint32)
     is_leader = (leader >= 0) & state.alive
     steer_x = (
         ((bits & INPUT_RIGHT) != 0).astype(jnp.float32)

@@ -14,11 +14,17 @@ fusion inside the compiled step, so systems compose sequentially here and
 the compiler extracts the parallelism.
 
 Inputs are positional per player, mirroring the ``PlayerInputs<T>`` resource
-(``ggrs_stage.rs:60-75``): game systems index ``inputs.bits[player_handle]``
-exactly like the reference's ``inputs[p.handle].0`` (``examples/box_game/
-box_game.rs:159``). Each input carries an ``InputStatus`` (confirmed /
-predicted / disconnected — ggrs ``InputStatus`` consumed at
-``ggrs_stage.rs:61``).
+(``ggrs_stage.rs:60-75``): ``inputs.bits[p]`` is player ``p``'s payload, and
+a system whose entities carry a player handle reads each entity's input with
+:meth:`PlayerInputs.for_handles`, the reference's ``inputs[p.handle].0``
+(``examples/box_game/box_game.rs:159``) for a whole handle column at once.
+Indexing ``inputs.bits[handles]`` gives the same values, but the sessions
+vmap a step over branches and the server over slots as well, and jax batches
+that index into an XLA ``gather`` which the TPU runs an entity at a time
+(a fifth of the served 1,024-boid dispatch, ``PERF.md`` section 6, PR 38);
+the helper is a select over the few players and stays dense under any number
+of batch axes. Each input carries an ``InputStatus`` (confirmed / predicted /
+disconnected — ggrs ``InputStatus`` consumed at ``ggrs_stage.rs:61``).
 """
 
 from __future__ import annotations
@@ -80,6 +86,24 @@ class PlayerInputs:
     @property
     def num_players(self) -> int:
         return self.status.shape[0]
+
+    def for_handles(self, handles: jnp.ndarray) -> jnp.ndarray:
+        """Each entity's input by its player handle: ``int32[N]`` handles in,
+        ``bits.dtype[N, *input_shape]`` out, bit for bit
+        ``bits[clip(handles, 0, P - 1)]`` (a handle under 0 reads player 0,
+        one past the last reads the last; the caller masks entities without
+        a player). A select over the P players and not an index: it moves
+        the same integers, and no batch axis turns it into a ``gather``."""
+        last = self.num_players - 1
+        safe = jnp.clip(handles, 0, last)
+        # The mask broadcasts over the trailing axes of a vector input.
+        safe = safe.reshape(safe.shape + (1,) * (self.bits.ndim - 1))
+        out = jnp.broadcast_to(
+            self.bits[last], handles.shape + self.bits.shape[1:]
+        )
+        for p in range(last - 1, -1, -1):
+            out = jnp.where(safe == p, self.bits[p], out)
+        return out
 
 
 def make_inputs(bits, status=None) -> PlayerInputs:

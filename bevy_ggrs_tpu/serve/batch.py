@@ -26,10 +26,16 @@ small static axis (bit-moving, so the bitwise guarantees hold; measured
 0.84 ms a dispatch), and where it has not (the singleton, the
 rollout's vmap over branches) they stay the in-place dynamic slice.
 Nothing here chooses: vmapping the index is what makes the choice. A
-title's own per-lane lookups (box_game's ``inputs.bits[handle]``) are
-the title's. The price is ``depth`` x the row's bytes a save instead of
-one row, nothing for box_game's rows; no served large world has been
-measured (``PERF.md`` section 7).
+title's own per-lane lookups are the title's, and the same disease: an
+entity's input read as ``inputs.bits[handle]`` becomes a ``gather`` of one
+serial step an entity under the slot axis (9.7 of the served 1,024-boid
+dispatch's 48 ms until ``boids`` read it through
+``PlayerInputs.for_handles``, the same select over the small static
+axis; ``PERF.md`` section 6, PR 38; box_game's 16 rows still index).
+The price is ``depth`` x the row's bytes a save instead of
+one row: nothing for box_game's rows, 1.3 % of the served 1,024-boid
+dispatch since a burst carries large rows flat (``PERF.md`` section 6,
+PR 37).
 
 Design rules that make the batch shape static (one executable, ever):
 
