@@ -28,7 +28,8 @@ spectator sessions all fit):
 - ``poll_remote_clients()`` (optional) — pumped before input collection;
   one that also takes ``parts=`` (P2P and spectator sessions) is handed
   the group's two-slot list while a sink listens, for the poll's receive
-  and send seconds;
+  and send seconds, and asked for ``num_endpoints`` (the remote endpoints
+  a poll pumps: series ``serve_endpoints_polled``);
 - ``report_checksum(frame, checksum)`` / ``wants_checksum(frame)``
   (optional) — fed from the core's deferred checksum reports, a segment's
   in one ``report_checksums(first_frame, checksums)`` call where the
@@ -1121,6 +1122,10 @@ class MatchServer(Instrumented):
                     # The polls' receive and send seconds: the session adds
                     # them in for the caller that asks (``parts``).
                     poll_parts = [0.0, 0.0] if timed else None
+                    # The remote endpoints those polls pumped: series
+                    # ``serve_endpoints_polled`` (one far end a hosted duel,
+                    # P - 1 a hosted lobby).
+                    endpoints = 0
                     # The loop's crossings into the native session core, a
                     # live match: series ``serve_session_native_calls``
                     # (the core counts; None on the Python plane).
@@ -1152,6 +1157,7 @@ class MatchServer(Instrumented):
                                 if timed:
                                     if _poll_takes_parts(session):
                                         poll(parts=poll_parts)
+                                        endpoints += session.num_endpoints
                                     else:
                                         poll()
                                     t_b = clock()
@@ -1233,6 +1239,7 @@ class MatchServer(Instrumented):
                             (native_calls() - calls_0) / len(matches),
                         )
                 if timed:
+                    self.metrics.observe("serve_endpoints_polled", endpoints)
                     self._observe_session_sums(sp_sessions.ms, poll_parts, {
                         "serve_supervisor_ms": sup_s,
                         "serve_poll_ms": poll_s,

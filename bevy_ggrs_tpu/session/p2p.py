@@ -208,6 +208,12 @@ class P2PSession(Instrumented):
     def remote_player_handles(self) -> List[int]:
         return sorted(self._handle_addr)
 
+    @property
+    def num_endpoints(self) -> int:
+        """The remote endpoints one ``poll_remote_clients()`` pumps: one a
+        remote peer and one a spectator."""
+        return len(self._endpoints)
+
     def confirmed_frame(self) -> int:
         """Highest frame for which every connected player's input is
         confirmed (local inputs confirm at add time, after input delay)."""

@@ -95,6 +95,8 @@ class SpectatorSession:
     def local_player_handles(self) -> List[int]:
         return []  # spectators never contribute input
 
+    num_endpoints = 1  # the host: what ``poll_remote_clients()`` pumps
+
     def frames_behind_host(self) -> int:
         host_frame = self._endpoint.remote_frame
         return max(0, host_frame - self.current_frame) if host_frame != NULL_FRAME else 0

@@ -544,6 +544,19 @@ def ring_init(state: WorldState, depth: int) -> SnapshotRing:
 # moves bits; no one-hot multiply, which would turn -0.0 into 0.0 and spread
 # NaN). The choice is made where it can be seen: by the batching rule, from
 # whether the index carries the batch axis. Nothing is configured.
+#
+# A READ by select is a chain of one select a row of the axis, each an
+# operation of the program: right for a ring (10 to 14 rows, read in every
+# scan step), wrong for the one read a tick that picks a slot's matched
+# branch out of ``[B, ...]`` once B is in the hundreds (B = 1,024 under 16
+# slots: 20,000 selects over 20 leaves, 200 MB of code and six minutes of
+# compiling; PERF.md section 6, PR 39). Past ``SELECT_ROWS`` rows a batched
+# index reads through a one-hot mask OR-ed over the axis instead: one dense
+# pass a leaf, as the write is. Not the gather jax would make of it: the
+# compiler lays a ``[B, ...]`` rollout out with B in the lanes, and a gather
+# of whole branches wants it transposed (3.9 GB of scratch at that shape).
+
+SELECT_ROWS = 64
 
 
 def _batched(x, is_batched: bool, axis_size: int):
@@ -587,6 +600,25 @@ def _row_write_at(axis: int):
     return write
 
 
+def _row_read_one_hot(stack, index, at: int):
+    """Row ``index[lane]`` of axis ``at`` of ``stack[lane, ...]`` in one
+    dense pass: everything but the lane's row masked to zero bits, then OR-ed
+    over the axis. It moves bits as a select does (no multiply, no sum)."""
+    n = stack.shape[at]
+    hot = jnp.arange(n, dtype=jnp.int32) == _clamp(index, n)[:, None]
+    hot = hot.reshape(
+        (stack.shape[0],) + (1,) * (at - 1) + (n,) + (1,) * (stack.ndim - at - 1)
+    )
+    if stack.dtype == jnp.bool_:
+        return jnp.any(hot & stack, axis=at)
+    word = jnp.dtype(f"uint{8 * stack.dtype.itemsize}")
+    picked = jax.lax.reduce(
+        jnp.where(hot, jax.lax.bitcast_convert_type(stack, word), word.type(0)),
+        word.type(0), jax.lax.bitwise_or, (at,),
+    )
+    return jax.lax.bitcast_convert_type(picked, stack.dtype)
+
+
 @functools.lru_cache(maxsize=None)
 def _row_read_at(axis: int):
     @jax.custom_batching.custom_vmap
@@ -600,6 +632,9 @@ def _row_read_at(axis: int):
             return _row_read_at(axis + 1)(stack, index), True
         at = axis + 1 if stack_b else axis
         n = stack.shape[at]
+        if n > SELECT_ROWS:
+            stack = _batched(stack, stack_b, axis_size)
+            return _row_read_one_hot(stack, index, axis + 1), True
         row = lambda d: jax.lax.index_in_dim(stack, d, at, keepdims=False)
         out = _batched(row(0), stack_b, axis_size)
         index = _clamp(index, n).reshape((axis_size,) + (1,) * (out.ndim - 1))

@@ -27,6 +27,7 @@ from bevy_ggrs_tpu.models import box_game
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.serve.batch import BatchedTickExecutor
 from bevy_ggrs_tpu.state import (
+    SELECT_ROWS,
     HostWorld,
     TypeRegistry,
     ring_init,
@@ -42,6 +43,7 @@ DEPTH = 5  # main ring: max_prediction 4
 SPEC = 3  # speculative ring depth = spec frames
 BRANCHES = 4
 BURST = 6
+LONG = SELECT_ROWS + 6  # an axis read by the one-hot pass (a wide rollout)
 
 # ---------------------------------------------------------------------------
 # A title with no gather of its own (box_game looks its input up by handle)
@@ -259,9 +261,53 @@ def test_per_lane_index_under_vmap_is_a_select():
         assert count(text, "select") > 0
 
 
+def test_per_lane_index_over_a_long_axis_is_one_dense_pass():
+    """Past ``SELECT_ROWS`` rows (a slot's matched branch out of
+    ``[B, ...]``) the read is a one-hot mask OR-ed over the axis: one select
+    and one reduce whatever the axis is long, not a select a row, and not
+    the gather jax would make of a batched index."""
+    idx = i32([0, 1, LONG - 1, 3])
+    for stack_, axes in ((jnp.zeros((LANES, LONG, 4, 3)), (0, 0)),
+                         (jnp.zeros((LONG, 4, 3)), (None, 0))):
+        text = lowered(jax.vmap(ring_row_read, axes), stack_, idx)
+        assert count(text, "gather") == 0 and count(text, "scatter") == 0
+        assert count(text, "dynamic_slice") == 0
+        assert count(text, "reduce") == 1 and count(text, "or") == 1
+        # _clamp's two and the mask's one.
+        assert count(text, "select") <= 3
+    short = lowered(jax.vmap(ring_row_read),
+                    jnp.zeros((LANES, SELECT_ROWS, 4, 3)), idx)
+    assert count(short, "reduce") == 0
+    assert len(re.findall(r"call @_where", short)) >= SELECT_ROWS - 1
+
+
 # ---------------------------------------------------------------------------
 # Values: select form == dynamic form, bit for bit
 # ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", [
+    jnp.float32, jnp.int32, jnp.uint32, jnp.uint8, jnp.bool_,
+])
+@pytest.mark.parametrize("stack_batched", [True, False])
+def test_long_axis_read_matches_per_lane(dtype, stack_batched):
+    """The one-hot pass against the dynamic form, every bit pattern, with
+    indices out of range on both sides (a dynamic slice counts a negative
+    index from the end and clamps), the stack batched or shared."""
+    rng = np.random.default_rng(LONG + stack_batched)
+    shape = ((LANES,) if stack_batched else ()) + (LONG, 3, 2)
+    stack_ = random_like(rng, jnp.zeros(shape, dtype))
+    if dtype == jnp.float32:     # -0.0, NaNs with payloads, infinities
+        stack_ = stack_.at[..., :3, :, :].set(
+            jnp.asarray(SPECIAL[:6].reshape(3, 2)))
+    idx = i32([LONG - 1, -2, 3 * LONG, -7 * LONG])
+    axes = (0 if stack_batched else None, 0)
+    got = jax.jit(jax.vmap(ring_row_read, axes))(stack_, idx)
+    want = stack([
+        jax.jit(ring_row_read)(lane(stack_, i) if stack_batched else stack_,
+                               idx[i])
+        for i in range(LANES)])
+    assert_bits_equal(got, want)
 
 
 @pytest.mark.parametrize("indices", [
