@@ -326,6 +326,18 @@ class _Child:
             frame=int(session.current_frame),
         )
 
+    def _report_moves(self, moved: set) -> None:
+        """The server's re-pack moved these handles' matches: tell the
+        parent where each lives now (it finds a match's checkpoint record
+        by its place)."""
+        for mid, m in self.matches.items():
+            handle = m["handle"]
+            if handle in moved:
+                self._emit(
+                    event="moved", match=mid,
+                    group=handle.group, slot=handle.slot,
+                )
+
     def _cmd_retire_match(self, cmd: dict) -> None:
         mid = int(cmd["match"])
         m = self.matches.pop(mid, None)
@@ -723,6 +735,8 @@ class _Child:
                 if time.monotonic() >= self.outgoing[nonce]["deadline"]:
                     self._abort_outgoing(nonce, "timeout")
             self.server.run_frame()
+            if self.server.repacked:
+                self._report_moves(set(self.server.repacked))
             fs = self.server.frames_served
             if fs - last_status >= self.cfg["status_interval"]:
                 last_status = fs
@@ -1119,6 +1133,10 @@ class ProcFleet:
             self.handles[int(ev["match"])] = (
                 int(ev["group"]), int(ev["slot"]),
             )
+        elif kind == "moved":
+            mid = int(ev["match"])
+            if self.book.get(mid) == sid:
+                self.handles[mid] = (int(ev["group"]), int(ev["slot"]))
         elif kind == "admit_failed":
             self.book.pop(int(ev["match"]), None)
             self.admissions_rejected += 1

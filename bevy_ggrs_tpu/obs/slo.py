@@ -124,6 +124,15 @@ class SlotSLO:
         self._slots.pop(slot, None)
         self._levels.pop(slot, None)
 
+    def move(self, old: int, new: int) -> None:
+        """A tenant moved slots (the server's re-pack): its windows and
+        level follow it, and ``old`` is left without history."""
+        for book in (self._slots, self._levels):
+            kept = book.pop(old, None)
+            book.pop(new, None)
+            if kept is not None:
+                book[new] = kept
+
     # -- reduction -------------------------------------------------------
 
     def _objective(self, name: str) -> float:

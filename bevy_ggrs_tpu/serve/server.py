@@ -63,7 +63,6 @@ per-slot counters carry a ``match_slot`` label; ``slots_active``,
 
 from __future__ import annotations
 
-import dataclasses
 import inspect
 import time
 from typing import Callable, Dict, List, Optional, Tuple
@@ -90,10 +89,30 @@ from bevy_ggrs_tpu.session.common import PredictionThreshold, SessionState
 from bevy_ggrs_tpu.session.requests import AdvanceFrame, Segment
 
 
-@dataclasses.dataclass(frozen=True)
 class MatchHandle:
-    group: int
-    slot: int
+    """The handle of one served match: what ``add_match``, ``enqueue_match``,
+    ``resume_match`` and ``adopt_rejoin`` return, and the match's handle for
+    as long as the server holds it. ``group`` and ``slot`` say where the
+    match lives NOW: the server rewrites them in the step that moves the
+    match (the re-pack at a frame's end), so read them at the time of use
+    and keep no copy. ``==`` and ``hash`` follow IDENTITY, never location:
+    a dict keyed on handles stays sound through a move, and two handles of
+    one place are equal only if they are one object. A handle rebuilt from
+    a record (``MatchHandle(g, s)``, or the bare tuple) names whatever
+    lives at ``(g, s)`` when the server is asked: every method that takes
+    a handle resolves it by location (docs/serving.md "Handles")."""
+
+    __slots__ = ("group", "slot")
+
+    def __init__(self, group: int, slot: int):
+        self.group = int(group)
+        self.slot = int(slot)
+
+    def __iter__(self):
+        return iter((self.group, self.slot))
+
+    def __repr__(self) -> str:
+        return f"MatchHandle(group={self.group}, slot={self.slot})"
 
 
 class _Match:
@@ -277,6 +296,13 @@ class MatchServer(Instrumented):
         self._codec = None
         self._matches: Dict[MatchHandle, _Match] = {}
         self._lanes: Dict[MatchHandle, RecoveryLane] = {}
+        # Where each held handle lives now: a registered match (batched or
+        # on a lane) or a queued admission. The maps above are keyed on the
+        # handle OBJECT (its hash is its identity, so a move re-keys
+        # nothing there); this one is keyed on location and re-keyed in the
+        # step that moves a match (``_move``). A handle rebuilt from a
+        # record finds its match through it (``_held``).
+        self._at: Dict[Tuple[int, int], MatchHandle] = {}
         self._reserved: Dict[int, set] = {g: set() for g in range(G)}
         self.checkpointer = (
             ServerCheckpointer(
@@ -291,6 +317,11 @@ class MatchServer(Instrumented):
         self.frames_withheld_total = 0
         self.faults_total = 0
         self.readmissions_total = 0
+        # The re-pack (``_repack``): matches moved since the server was
+        # built, and the handles the last served frame moved (a fleet child
+        # tells its parent where they live now).
+        self.matches_repacked_total = 0
+        self.repacked: List[MatchHandle] = []
         self.evictions_total = 0
         self.last_recovery_frames: Optional[int] = None
         self.last_stagger_jitter_ms: Optional[float] = None
@@ -373,6 +404,14 @@ class MatchServer(Instrumented):
         """Server-wide slot id (group-qualified) — the SLO/metrics key.
         Distinct from ``handle.slot``, which repeats across groups."""
         return handle.group * self._per_group + handle.slot
+
+    def _held(self, handle) -> Optional[MatchHandle]:
+        """The handle this server gave whatever lives at ``handle``'s
+        (group, slot) NOW — ``handle`` itself when it is the one the
+        caller was given, the match's own for a handle rebuilt from a
+        record or a bare ``(group, slot)`` tuple; None when nothing lives
+        there."""
+        return self._at.get(tuple(handle))
 
     # -- gauges ---------------------------------------------------------
 
@@ -458,7 +497,7 @@ class MatchServer(Instrumented):
         ]
 
     def health_of(self, handle: MatchHandle) -> SlotHealth:
-        return self._matches[handle].fsm.state
+        return self._matches[self._held(handle)].fsm.state
 
     def state_codec(self):
         """The server's StateCodec (relay-tier flat-byte layout), built
@@ -581,7 +620,18 @@ class MatchServer(Instrumented):
             )
         m = _Match(session, local_inputs, fsm, supervisor, bool(spec_on))
         self._matches[handle] = m
+        self._at[tuple(handle)] = handle
         return m
+
+    def _drop(self, handle: MatchHandle) -> None:
+        """Forget a match that left (retired, suspended, evicted): its
+        record, its place and its SLO history. The handle keeps the last
+        location it had."""
+        self._matches.pop(handle, None)
+        self._pending_first.pop(handle, None)
+        if self._at.get(tuple(handle)) is handle:
+            del self._at[tuple(handle)]
+        self._vacate_slo(handle)
 
     def _pick_slot(self) -> MatchHandle:
         # Pack-first: the busiest group that still has room. A group's
@@ -642,6 +692,7 @@ class MatchServer(Instrumented):
         construction off sibling groups' deadlines."""
         handle = self._pick_slot()
         self._reserved[handle.group].add(handle.slot)
+        self._at[tuple(handle)] = handle
         if trace is not None:
             trace.begin("first_frame")
         self._admit_queue.append(
@@ -694,12 +745,16 @@ class MatchServer(Instrumented):
                     trace.begin("first_frame")
 
     def retire_match(self, handle: MatchHandle) -> None:
+        handle = self._held(handle)
+        if handle is None:
+            return  # nothing lives there (retired already)
         # A match retired while still in the admit queue (an abandon that
         # beat its own admission) just releases its reservation.
         for i, pending in enumerate(self._admit_queue):
-            if pending[0] == handle:
+            if pending[0] is handle:
                 del self._admit_queue[i]
                 self._reserved[handle.group].discard(handle.slot)
+                del self._at[tuple(handle)]
                 trace = pending[5]
                 if trace is not None:
                     trace.finish()
@@ -709,9 +764,7 @@ class MatchServer(Instrumented):
             self._reserved[handle.group].discard(handle.slot)
         else:
             self.groups[handle.group].retire(handle.slot)
-        self._matches.pop(handle, None)
-        self._pending_first.pop(handle, None)
-        self._vacate_slo(handle)
+        self._drop(handle)
 
     def suspend_match(self, handle: MatchHandle) -> SlotTicket:
         """Voluntary drain: extract the match's full trajectory state as a
@@ -720,14 +773,16 @@ class MatchServer(Instrumented):
         possibly into a different slot or a different server — and
         continue bitwise. Not valid while the match is on a recovery
         lane."""
+        group, slot = handle
+        handle = self._held(handle)
         if handle in self._lanes:
             raise RuntimeError(
                 f"match {handle} is on a recovery lane; wait for "
                 "readmission or retire it"
             )
-        ticket = self.groups[handle.group].extract(handle.slot)
-        self._matches.pop(handle, None)
-        self._vacate_slo(handle)
+        ticket = self.groups[group].extract(slot)
+        if handle is not None:
+            self._drop(handle)
         return ticket
 
     def _vacate_slo(self, handle: MatchHandle) -> None:
@@ -747,15 +802,17 @@ class MatchServer(Instrumented):
     ) -> MatchHandle:
         """Readmit a suspended (or checkpoint-restored) match from its
         ticket, mid-trajectory. ``handle`` pins the exact (group, slot) —
-        crash-restart re-seeds every match where it lived, keeping
-        user-held handles valid."""
+        crash-restart re-seeds every match where it lived — and a
+        :class:`MatchHandle` given there (the one the match had when it
+        was suspended) is the match's handle again, so what the caller
+        kept stays valid."""
         if ticket is None:
             raise ValueError("resume_match requires a ticket")
+        given = handle if isinstance(handle, MatchHandle) else None
         if handle is not None:
-            handle = MatchHandle(*handle) if isinstance(handle, tuple) else handle
-            if handle.slot in self._reserved[handle.group]:
+            group, slot = handle
+            if slot in self._reserved[group]:
                 raise RuntimeError(f"slot {handle} is reserved")
-            group, slot = handle.group, handle.slot
         else:
             group = max(
                 range(len(self.groups)),
@@ -767,7 +824,8 @@ class MatchServer(Instrumented):
             slot = free[0]
         core = self.groups[group]
         slot = core.admit(slot=slot, ticket=ticket)
-        handle = MatchHandle(group, slot)
+        handle = given if given is not None else MatchHandle(group, slot)
+        handle.group, handle.slot = group, slot
         self._register(handle, session, local_inputs, ticket.spec_on)
         return handle
 
@@ -786,7 +844,8 @@ class MatchServer(Instrumented):
         window."""
         from bevy_ggrs_tpu.session.supervisor import SessionSupervisor
 
-        handle = MatchHandle(*handle) if isinstance(handle, tuple) else handle
+        if not isinstance(handle, MatchHandle):
+            handle = MatchHandle(*handle)
         if self.groups[handle.group].slots[handle.slot].active:
             raise RuntimeError(f"slot {handle} is occupied")
         runner = self._make_lane_runner()
@@ -824,6 +883,78 @@ class MatchServer(Instrumented):
         self.timeseries.observe("admission_ms", total)
         for stage, ms in trace.durations.items():
             self.timeseries.observe(f"admission_{stage}_ms", ms)
+
+    # -- re-packing ------------------------------------------------------
+
+    def _move(self, handle: MatchHandle, m: _Match, group: int, slot: int):
+        """Move a batched match to the free ``(group, slot)``, between two
+        frames: the ``extract`` -> ``admit(ticket=...)`` pair migration
+        rests on (``extract`` flushes the deferred checksum reports before
+        the slot is vacated, as ``retire`` does), with every book re-keyed
+        in this one step. The match keeps its ``_Match`` record — session,
+        ``local_inputs``, health FSM and supervisor, whose runner facade
+        is re-pointed — and the handle it was given, rewritten."""
+        old_flat = self._flat_slot(handle)
+        ticket = self.groups[handle.group].extract(handle.slot)
+        core = self.groups[group]
+        core.admit(slot=slot, ticket=ticket)
+        del self._at[tuple(handle)]
+        handle.group, handle.slot = group, slot
+        self._at[group, slot] = handle
+        flat = self._flat_slot(handle)
+        self.slo.move(old_flat, flat)
+        level = self.slo_levels.pop(old_flat, None)
+        if level is not None:
+            self.slo_levels[flat] = level
+        m.fsm.slot = slot
+        if m.supervisor is not None:
+            m.supervisor.retarget(_SlotRunnerFacade(core, slot))
+
+    def _repack(self, by_group, budget: int) -> List[MatchHandle]:
+        """Off-peak, empty a stagger group so that the next frame skips its
+        dispatch: while the batched matches fit in fewer groups than are
+        hot (``by_group``: the groups this frame ticked), move up to
+        ``budget`` matches — what the frame's admissions left of
+        ``admit_budget`` — out of the hot group with the fewest (ties: the
+        highest index) into the other hot groups' free, unreserved slots,
+        busiest first as :meth:`_pick_slot`. A drain starts, and goes on,
+        only while ALL the source's matches fit there, so it ends in an
+        empty group and holes that do not add up to a group move nothing.
+        Never moved, and a source holding one is not drained this frame: a
+        reserved slot (a recovery lane's, a queued admission's), a match
+        that has not ridden its first dispatch, one whose health is not
+        HEALTHY. In a full server the first comparison is all that runs.
+        Returns the handles of the matches moved."""
+        live = sum(map(len, by_group.values()))
+        if budget <= 0 or -(-live // self._per_group) >= len(by_group):
+            return []
+        counts = {g: core.active_count for g, core in enumerate(self.groups)}
+        hot = [g for g, n in counts.items() if n]
+        if len(hot) < 2:
+            return []
+        src = min(hot, key=lambda g: (counts[g], -g))
+        movers = [
+            (h, m) for h, m in self._matches.items() if h.group == src
+        ]
+        if self._reserved[src] or any(
+            h in self._pending_first or m.fsm.state is not SlotHealth.HEALTHY
+            for h, m in movers
+        ):
+            return []
+        free = {g: self._free_unreserved(g) for g in hot if g != src}
+        if sum(map(len, free.values())) < len(movers):
+            return []
+        movers = movers[:budget]
+        with self.span("serve_repack", group=src, matches=len(movers)):
+            for handle, m in movers:
+                dst = min(
+                    (g for g in free if free[g]),
+                    key=lambda g: (len(free[g]), g),
+                )
+                self._move(handle, m, dst, free[dst].pop(0))
+        self.metrics.count("matches_repacked", len(movers))
+        self.matches_repacked_total += len(movers)
+        return [handle for handle, _m in movers]
 
     # -- fault containment ----------------------------------------------
 
@@ -902,8 +1033,7 @@ class MatchServer(Instrumented):
         m.fsm.to(SlotHealth.EVICTED, reason="recovery_deadline")
         del self._lanes[handle]
         self._reserved[handle.group].discard(handle.slot)
-        self._matches.pop(handle, None)
-        self._vacate_slo(handle)
+        self._drop(handle)
         self.evictions_total += 1
         self.metrics.count("slot_evictions")
         self.metrics.count(
@@ -934,7 +1064,7 @@ class MatchServer(Instrumented):
             with self.span("attest", group=g):
                 detected = core.attest()
             for slot, bad in detected.items():
-                handle = MatchHandle(g, slot)
+                handle = self._at.get((g, slot))
                 m = self._matches.get(handle)
                 if m is None or handle in self._lanes:
                     continue
@@ -1038,8 +1168,10 @@ class MatchServer(Instrumented):
 
         All of it is span ``serve_frame``; what the spans under it
         (``serve_tick``, ``checksum_sync``, ``serve_report_delivery``,
-        ``admit_*``, ``attest``, ``lane_step``) leave of it is the series
-        ``serve_frame_other_ms``: the frame's own bookkeeping."""
+        ``admit_*``, ``serve_repack``, ``attest``, ``lane_step``) leave of
+        it is the series ``serve_frame_other_ms``: the frame's own
+        bookkeeping. Off-peak the admissions are followed by the re-pack
+        (:meth:`_repack`)."""
         with self.span(
             "serve_frame", frame=self.frames_served, groups=len(self.groups)
         ) as sp_frame:
@@ -1080,6 +1212,7 @@ class MatchServer(Instrumented):
                 )
         t0 = self._clock()
         worst_jitter = 0.0
+        dispatched = 0
         by_group: Dict[int, Dict[int, Tuple[MatchHandle, _Match]]] = {}
         for handle, m in self._matches.items():
             if handle in self._lanes:
@@ -1250,19 +1383,20 @@ class MatchServer(Instrumented):
                 while work:
                     try:
                         core.tick(work)
+                        dispatched += 1
                         break
                     except SlotFault as f:
                         item, _conf, session = work.pop(f.slot)
-                        handle = MatchHandle(g, f.slot)
+                        handle, m = matches[f.slot]
                         self._fault(
-                            handle, self._matches[handle], f.reason,
+                            handle, m, f.reason,
                             cause=f, pending=(_requests(item), session),
                         )
                 # Any slot that just rode its first successful dispatch
                 # completes its admission trace: first_frame_served.
                 if work and self._pending_first:
                     for slot in work:
-                        h = MatchHandle(g, slot)
+                        h = matches[slot][0]
                         if h in self._pending_first:
                             self._finish_admission(
                                 h, self._pending_first.pop(h)
@@ -1287,6 +1421,7 @@ class MatchServer(Instrumented):
                 self._admit_queue.pop(0)
             )
             self._reserved[handle.group].discard(handle.slot)
+            admit_budget_left -= 1
             with self.span(
                 "admit_drain", group=handle.group, slot=handle.slot
             ):
@@ -1294,6 +1429,10 @@ class MatchServer(Instrumented):
                     handle, session, local_inputs, initial_state, spec_on,
                     trace,
                 )
+        # The groups that dispatched this frame, and the re-pack that makes
+        # them fewer: admissions first, with what is left of their budget.
+        self.metrics.observe("serve_hot_groups", dispatched)
+        self.repacked = self._repack(by_group, admit_budget_left)
         # Periodic SDC attestation sweep, off the hot path like the lanes:
         # detection within attest_interval frames, self-healing in place.
         if (
@@ -1403,7 +1542,12 @@ class MatchServer(Instrumented):
             self.heartbeats_sent += 1
             self.metrics.count("fleet_heartbeats_sent")
         if self.checkpointer is not None:
-            self.checkpointer.maybe_save(self)
+            if self.repacked:
+                # A checkpoint names a match by where it lives: one taken
+                # before a move would restore it under a place it left.
+                self.checkpointer.save(self)
+            else:
+                self.checkpointer.maybe_save(self)
 
     def _observe_session_sums(
         self, span_ms: float, poll_parts: List[float],

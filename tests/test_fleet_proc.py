@@ -100,6 +100,37 @@ def test_subprocess_server_lifecycle(tmp_path):
     assert not fleet.members[0].process.alive()
 
 
+def test_parent_book_follows_a_repacked_match(tmp_path):
+    """The child's server re-packs its survivors at a frame's end; the
+    child reports each move (event ``moved``), so the parent's ``(group,
+    slot)`` book, which finds a match's checkpoint record, follows."""
+    fleet = ProcFleet(str(tmp_path), base_config=BASE)
+    try:
+        sid = fleet.spawn_server(wait_ready=True)
+        for mid in (21, 22, 23, 24):
+            assert fleet.admit(mid) == sid
+        pump_until(fleet, lambda: len(fleet.handles) == 4,
+                   msg="four matches placed")
+        assert [fleet.handles[m] for m in (21, 22, 23, 24)] == [
+            (0, 0), (0, 1), (1, 0), (1, 1)]
+        # One survivor a group: the second group's moves into the first.
+        assert fleet.retire_match(22) and fleet.retire_match(24)
+        pump_until(fleet, lambda: fleet.handles.get(23) == (0, 1),
+                   msg="the moved match's place reaches the parent")
+        assert fleet.handles == {21: (0, 0), 23: (0, 1)}
+        before = match_frames(fleet, sid).get(23, 0)
+        pump_until(
+            fleet,
+            lambda: match_frames(fleet, sid).get(23, 0) > before + 20
+            and fleet.members[sid].info.slots_active == 2,
+            msg="the moved match keeps serving",
+        )
+        st = fleet.members[sid].status
+        assert st["faults"] == 0 and st["evictions"] == 0
+    finally:
+        fleet.close()
+
+
 # ---------------------------------------------------------------------------
 # The elastic autopilot soak
 # ---------------------------------------------------------------------------

@@ -370,6 +370,12 @@ def run_served_soak(
                 assert {(h.group, h.slot) for h in restored} == set(
                     attachments
                 )
+                # A handle is its match's identity on ONE server: the
+                # rebuilt server hands out its own.
+                at = {(h.group, h.slot): h for h in restored}
+                handle_of.update(
+                    (m, at[h.group, h.slot]) for m, h in handle_of.items()
+                )
                 restore_frame = max(
                     p[0].current_frame for p in ext.values()
                 )

@@ -121,14 +121,9 @@ class _Peer:
         self.runner.handle_requests(requests, s)
 
 
-@functools.lru_cache(maxsize=None)
-def served(plane):
-    """Drive the eight matches once per plane; everything the tests
-    read."""
-    net = LoopbackNetwork(
-        latency=2 * FPS_DT, jitter=1 * FPS_DT, loss=0.03, seed=11
-    )
-    metrics, host_metrics, peer_metrics = Metrics(), Metrics(), Metrics()
+def _server(plane, metrics):
+    """The eight-slot, two-group server of these tests, warmed, on the
+    native batch plane or the per-slot Python path."""
     server = MatchServer(
         box_game.make_schedule(), box_game.make_world(2).commit(),
         MAX_PRED, 2, box_game.INPUT_SPEC,
@@ -139,6 +134,18 @@ def served(plane):
         for core in server.groups:
             core._plane = None  # the GGRS_NO_NATIVE=1 route of _dispatch
     server.warmup()
+    return server
+
+
+@functools.lru_cache(maxsize=None)
+def served(plane):
+    """Drive the eight matches once per plane; everything the tests
+    read."""
+    net = LoopbackNetwork(
+        latency=2 * FPS_DT, jitter=1 * FPS_DT, loss=0.03, seed=11
+    )
+    metrics, host_metrics, peer_metrics = Metrics(), Metrics(), Metrics()
+    server = _server(plane, metrics)
     base = {
         "steps": sum(g.burst_steps_total for g in server.groups),
         "slots": sum(g.burst_step_slots_total for g in server.groups),
@@ -341,3 +348,76 @@ def test_native_plane_and_python_path_agree():
         assert np.array_equal(a, b)
     for (ua, sa, _, _), (ub, sb, _, _) in zip(nat["pairs"], py["pairs"]):
         assert ua == ub and _tree_equal(sa, sb)
+
+
+@pytest.mark.parametrize("plane", PLANES)
+def test_repack_moves_a_hosted_match_in_mid_match(plane):
+    """Off-peak the server re-packs its survivors (``_repack``): a hosted
+    P2P match moved between two frames keeps its ``_Match`` record and its
+    ``SessionSupervisor`` (the same object, its runner facade on the new
+    slot), raises no desync against its far end over the frames after, and
+    the far end sees every frame confirmed in turn."""
+    from bevy_ggrs_tpu.serve.faults import _SlotRunnerFacade
+
+    net = LoopbackNetwork(latency=1 * FPS_DT, jitter=0.0, loss=0.0, seed=5)
+    metrics, host_metrics, peer_metrics = Metrics(), Metrics(), Metrics()
+    server = _server(plane, metrics)
+    hosts, peers, handles = [], [], []
+    for k in range(MATCHES):
+        hosts.append(_session(net, 0, k, host_metrics))
+        peers.append(_Peer(_session(net, 1, k, peer_metrics)))
+        handles.append(server.add_match(
+            hosts[k], lambda frame, h: scripted_input(h, frame)))
+    live = [1, 6]                    # one survivor a group
+
+    def step(ks):
+        net.advance(FPS_DT)
+        for k in ks:
+            peers[k].tick()
+        server.run_frame()
+
+    for _ in range(40):
+        step(range(MATCHES))
+    assert [tuple(handles[k]) for k in live] == [(0, 1), (1, 2)]
+    moved = handles[6]
+    record = server._matches[moved]
+    supervisor = record.supervisor
+    assert supervisor is not None
+    ballots = host_metrics.counters["checksum_ballots"]
+    for k in range(MATCHES):
+        if k not in live:
+            server.retire_match(handles[k])
+    # The evening is over: the next frame's end moves match 6 to group 0.
+    confirmed = []
+    for i in range(90):
+        step(live)
+        confirmed.append(peers[6].session.confirmed_frame())
+        if i == 0:
+            assert tuple(moved) == (0, 0)
+            assert metrics.counters["matches_repacked"] == 1
+    assert metrics.counters["matches_repacked"] == 1
+    assert server.groups[1].active_count == 0
+    assert metrics.series["serve_hot_groups"][-89:] == [1.0] * 89
+    # The same record, session and supervisor, re-pointed.
+    assert server._matches[moved] is record
+    assert record.session is hosts[6] and record.supervisor is supervisor
+    facade = supervisor.runner
+    assert isinstance(facade, _SlotRunnerFacade)
+    assert facade._core is server.groups[0] and facade._slot == 0
+    assert facade.frame == server.groups[0].slots[0].frame
+    # No desync, ballots compared after the move, nothing fenced.
+    assert host_metrics.counters.get("desyncs_flagged", 0) == 0
+    assert peer_metrics.counters.get("desyncs_flagged", 0) == 0
+    assert sum(p.desyncs for p in peers) == 0
+    assert host_metrics.counters["checksum_ballots"] > ballots + 4
+    assert server.faults_total == 0 and server.frames_withheld_total == 0
+    # The far end saw no gap: every frame confirmed, one a tick.
+    assert np.all(np.diff(confirmed) == 1)
+    # ... and holds the served match's state, bit for bit.
+    host, p = hosts[6], peers[6]
+    upto = min(host.confirmed_frame() + 1, host.current_frame - 1,
+               p.session.confirmed_frame() + 1, p.session.current_frame - 1)
+    assert upto > 100
+    core = server.groups[moved.group]
+    assert _tree_equal(ring_load(core.slot_ring(moved.slot), upto),
+                       ring_load(p.runner.ring, upto))
