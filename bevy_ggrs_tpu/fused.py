@@ -55,7 +55,7 @@ import numpy as np
 
 from bevy_ggrs_tpu.obs.trace import null_span
 from bevy_ggrs_tpu.parallel.speculate import match_branch
-from bevy_ggrs_tpu.rollout import rollout_burst
+from bevy_ggrs_tpu.rollout import live_steps, rollout_burst
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.state import (
     OWN_BUFFER_BYTES,
@@ -66,6 +66,12 @@ from bevy_ggrs_tpu.state import (
     ring_put,
     ring_row_read,
 )
+
+# The name of the axis a tick is vmapped over, where it is (the served
+# ``[S]`` slots, the conformance mode's copies): the burst's trip count is
+# reduced over it (``rollout.py`` ``live_steps``).
+LANE_AXIS = "lane"
+
 
 def _session_axis_wrap(fn, session_axis: int):
     """Route a singleton tick through the SESSION-AXIS program: broadcast
@@ -87,7 +93,7 @@ def _session_axis_wrap(fn, session_axis: int):
             ),
             args,
         )
-        out = jax.vmap(fn)(*stacked)
+        out = jax.vmap(fn, axis_name=LANE_AXIS)(*stacked)
         return jax.tree_util.tree_map(lambda x: x[0], out)
 
     return wrapped
@@ -382,15 +388,19 @@ class PackedTick:
     program; the three checksum arrays come out as one. The functions here
     are ``unpack -> _tick_impl / _absorb_impl -> pack``, written for ONE
     session: the server vmaps them over ``[S]`` (the codec keeps leading
-    axes). The live state is also returned as a ``WorldState``, so reading
-    it needs no further dispatch. ``per_leaf`` (a mesh: layouts are per
-    leaf) packs nothing, through the same code."""
+    axes) and says so with ``lane_axis``, the name it gave that ``vmap``'s
+    axis: the burst then runs as many steps as the deepest lane of the
+    dispatch asks for. The live state is also returned as a ``WorldState``,
+    so reading it needs no further dispatch. ``per_leaf`` (a mesh: layouts
+    are per leaf) packs nothing, through the same code."""
 
     def __init__(
         self, schedule: Schedule, burst_frames: int, num_branches: int,
         spec_frames: int, copies: int = 1, per_leaf: bool = False,
+        lane_axis: Optional[str] = None,
     ):
         self.schedule = schedule
+        self.lane_axis = lane_axis
         self.burst_frames = int(burst_frames)
         self.num_branches = int(num_branches)
         self.spec_frames = int(spec_frames)
@@ -439,6 +449,7 @@ class PackedTick:
             bits, status, mask, mask,
             ints[T.SPEC_FROM_LIVE] != 0, ints[T.SPEC_ANCHOR], branch_bits,
             jnp.full((F, P), PREDICTED, dtype=jnp.int32),
+            lane_axis=self.lane_axis,
         )
         return (
             self.carry.pack((ring, state, spec_rings, spec_states)),
@@ -462,7 +473,7 @@ class PackedTick:
             ints[T.ABSORB_FIRST], ints[T.ABSORB_N], ints[T.PREV_ANCHOR],
             ints[T.PREV_TOTAL],
             ints[T.DO_LOAD] != 0, ints[T.LOAD_FRAME], ints[T.START_FRAME],
-            bits, status, mask, mask,
+            bits, status, mask, mask, lane_axis=self.lane_axis,
         )
         return (
             self.carry.pack((ring, state, prev_rings, prev_states)),
@@ -563,6 +574,7 @@ class FusedTickExecutor:
         self.packed = PackedTick(
             schedule, self.burst_frames, self.num_branches, self.spec_frames,
             per_leaf=mesh is not None,
+            lane_axis=LANE_AXIS if self.session_axis > 0 else None,
         )
         # Anonymous on purpose: the device trace knows the client's two
         # programs as ``jit__unknown`` (told from the far end's by the host
@@ -666,7 +678,7 @@ class FusedTickExecutor:
         prev_rings, prev_states, branch,
         absorb_first, absorb_n, prev_anchor, prev_total,
         do_load, load_frame, start_frame,
-        bits, status, save_mask, adv_mask,
+        bits, status, save_mask, adv_mask, lane_axis=None,
     ):
         """Phases 1 and 2 of :meth:`_tick_impl`, from the same absorb and
         burst bodies: the front program of a split tick. ``_tick_impl``
@@ -692,7 +704,8 @@ class FusedTickExecutor:
             jnp.asarray(start_frame, jnp.int32),
         )
         ring, state, burst_cs = rollout_burst(
-            schedule, ring, state, frame0, bits, status, save_mask, adv_mask
+            schedule, ring, state, frame0, bits, status, save_mask, adv_mask,
+            n_run=live_steps(save_mask, adv_mask, lane_axis),
         )
         return ring, state, absorb_cs, burst_cs
 
@@ -705,6 +718,7 @@ class FusedTickExecutor:
         do_load, load_frame, start_frame,
         bits, status, save_mask, adv_mask,
         spec_from_live, spec_anchor, branch_bits, spec_status,
+        lane_axis=None,
     ):
         # Phase 1 — absorb the matched branch's precomputed frames
         # (speculation hit). absorb_n == 0 leaves ring/state untouched.
@@ -718,7 +732,9 @@ class FusedTickExecutor:
         state = jax.tree_util.tree_map(keep, state_a, state)
 
         # Phase 2 — the serial burst: rollback resimulation (do_load), the
-        # unmatched tail after a partial absorb, or the steady advance.
+        # unmatched tail after a partial absorb, or the steady advance; as
+        # many steps as the masks ask for (the deepest lane's, under a
+        # ``vmap`` over ``lane_axis``), the rest of ``burst_frames`` unrun.
         loaded = ring_load(ring, load_frame)
         state = jax.tree_util.tree_map(
             lambda l, s: jnp.where(do_load, l, s), loaded, state
@@ -729,7 +745,8 @@ class FusedTickExecutor:
             jnp.asarray(start_frame, jnp.int32),
         )
         ring, state, burst_cs = rollout_burst(
-            schedule, ring, state, frame0, bits, status, save_mask, adv_mask
+            schedule, ring, state, frame0, bits, status, save_mask, adv_mask,
+            n_run=live_steps(save_mask, adv_mask, lane_axis),
         )
 
         # Phase 3 — the next speculative rollout, anchored on the

@@ -16,7 +16,12 @@ session's desync/synctest signal — reference hands ggrs exactly that,
 Bursts are padded to a fixed ``max_frames`` with a validity mask so every
 burst length hits the same compiled executable (static shapes — no
 per-depth recompiles). Invalid steps are identity: no state advance, no ring
-write, checksum reported as 0.
+write, checksum reported as 0. Still one executable, and the padding costs
+nothing on the device: a burst whose caller hands over its live prefix
+(:func:`live_steps`: one past the last step any mask sets) loops that
+TRACED number of steps, not ``max_frames``; the count is an operand, not a
+shape. The speculative rollouts' masks are all ones over a static depth:
+their trip count is their shape, and they keep the scan.
 
 The save-before-advance ordering and the "save is labeled with the current
 frame" invariant (``ggrs_stage.rs:277``'s ``assert_eq!(self.frame, frame)``)
@@ -34,9 +39,25 @@ import jax.numpy as jnp
 
 from bevy_ggrs_tpu.schedule import PlayerInputs, Schedule
 from bevy_ggrs_tpu.state import (
-    SnapshotRing, WorldState, checksum, ring_load, ring_rows_flat,
-    ring_rows_shaped, ring_save,
+    SnapshotRing, WorldState, checksum, ring_load, ring_row_read,
+    ring_row_write, ring_rows_flat, ring_rows_shaped, ring_save,
 )
+
+
+def live_steps(
+    save_mask: jnp.ndarray,  # bool[max_frames]
+    adv_mask: jnp.ndarray,  # bool[max_frames]
+    lane_axis: Optional[str] = None,
+) -> jnp.ndarray:
+    """One past the last step at which either mask is set: what a burst has
+    to run, every later step being padding. Under a ``vmap`` whose axis is
+    named ``lane_axis`` the answer is the deepest lane's, ONE scalar for
+    the dispatch: a count that differs per lane would batch the loop's
+    predicate, and the loop would then run to the deepest lane all the same
+    and select its whole carry, rings included, a step."""
+    steps = jnp.arange(1, save_mask.shape[0] + 1, dtype=jnp.int32)
+    n = jnp.max(jnp.where(save_mask | adv_mask, steps, 0))
+    return n if lane_axis is None else jax.lax.pmax(n, lane_axis)
 
 
 def rollout_burst(
@@ -48,8 +69,9 @@ def rollout_burst(
     status: jnp.ndarray,  # int32[max_frames, num_players]
     save_mask: jnp.ndarray,  # bool[max_frames]
     adv_mask: jnp.ndarray,  # bool[max_frames]
+    n_run: Optional[jnp.ndarray] = None,  # int32[], from ``live_steps``
 ) -> Tuple[SnapshotRing, WorldState, jnp.ndarray]:
-    """Execute up to ``max_frames`` (save?, advance?) steps as one fused scan.
+    """Execute up to ``max_frames`` (save?, advance?) steps as one fused loop.
 
     Step ``t``: if ``save_mask[t]``, save ``state`` as the current frame into
     the ring; if ``adv_mask[t]``, ``state = schedule(state, inputs[t])`` and
@@ -57,11 +79,17 @@ def rollout_burst(
     Spectators advance without ever saving (`ggrs_stage.rs:195-211` never
     emits saves), hence the separate masks.
 
+    ``n_run`` (:func:`live_steps` of the masks) makes the loop run that many
+    steps and no more: the steps it leaves out are the identity, so every
+    output is bit for bit the full-length scan's. Without it the loop is a
+    ``lax.scan`` over all ``max_frames`` steps (a rollout, whose masks are
+    all ones).
+
     Returns ``(ring, state, checksums[max_frames])`` with ``checksums[t]``
     the saved checksum at step ``t`` (0 where ``save_mask[t]`` is False).
     """
     start_frame = jnp.asarray(start_frame, dtype=jnp.int32)
-    # Large rows ride the scan flat (``state.py`` ``FLAT_ROW_BYTES``).
+    # Large rows ride the loop flat (``state.py`` ``FLAT_ROW_BYTES``).
     shaped, ring = ring, ring_rows_flat(ring)
 
     def body(carry, xs):
@@ -75,9 +103,27 @@ def rollout_burst(
         )
         return (ring, state, frame + adv.astype(jnp.int32)), cs
 
-    (ring, state, _), checksums = jax.lax.scan(
-        body, (ring, state, start_frame), (bits, status, save_mask, adv_mask)
-    )
+    xs = (bits, status, save_mask, adv_mask)
+
+    def step(t, loop):
+        # Row ``t`` by ``state.py``'s row access: one dynamic slice under
+        # the slot ``vmap`` too, since every lane is at the same step.
+        carry, checksums = loop
+        carry, cs = body(
+            carry, jax.tree_util.tree_map(lambda x: ring_row_read(x, t), xs)
+        )
+        return carry, ring_row_write(checksums, cs, t)
+
+    if n_run is None:
+        (ring, state, _), checksums = jax.lax.scan(
+            body, (ring, state, start_frame), xs
+        )
+    else:
+        (ring, state, _), checksums = jax.lax.fori_loop(
+            0, n_run, step,
+            ((ring, state, start_frame),
+             jnp.zeros((save_mask.shape[0], 2), jnp.uint32)),
+        )
     return ring_rows_shaped(ring, shaped), state, checksums
 
 
@@ -140,7 +186,8 @@ class RolloutExecutor:
         frame0 = jnp.where(do_load, jnp.asarray(load_frame, jnp.int32),
                            jnp.asarray(start_frame, jnp.int32))
         return rollout_burst(schedule, ring, state, frame0, bits, status,
-                             save_mask, adv_mask)
+                             save_mask, adv_mask,
+                             n_run=live_steps(save_mask, adv_mask))
 
     def run(
         self,

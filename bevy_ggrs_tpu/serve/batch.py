@@ -95,6 +95,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bevy_ggrs_tpu.fused import (
+    LANE_AXIS,
     IoBuffers,
     PackedTick,
     TickInts,
@@ -149,7 +150,7 @@ class BatchedTickExecutor:
         # by the size of all ``num_slots`` copies of it.
         self.packed = PackedTick(
             schedule, self.burst_frames, self.num_branches,
-            self.spec_frames, copies=self.num_slots,
+            self.spec_frames, copies=self.num_slots, lane_axis=LANE_AXIS,
         )
         # The device trace knows this program as ``jit__tick_impl`` (the
         # benchmark's ``tick_program_ms.serve`` finds it by that name), as
@@ -157,7 +158,7 @@ class BatchedTickExecutor:
         def _tick_impl(carry, ints, bits, branch_bits):
             return self.packed.tick(carry, ints, bits, branch_bits)
 
-        self._fn = jax.jit(jax.vmap(_tick_impl))
+        self._fn = jax.jit(jax.vmap(_tick_impl, axis_name=LANE_AXIS))
         self._admit = jax.jit(self._admit_impl)
         self._pack = jax.jit(self.packed.pack)
         self._unpack = jax.jit(self.packed.unpack)
@@ -505,11 +506,13 @@ class BatchedSessionCore(Instrumented):
         # Aggregate counters (per-slot views go through labeled metrics).
         self.ticks_total = 0
         self.device_dispatches_total = 0
-        # Scan steps of the batched tick, exact: every dispatch runs
-        # ``burst_frames`` static steps for each of its ``num_slots`` lanes
-        # (``burst_step_slots_total``); ``burst_steps_total`` is how many
-        # of them a lane's request list asked for (its AdvanceFrames). The
-        # ratio is the share of the tick that is not padding.
+        # Burst steps of the batched tick, exact: every dispatch runs, for
+        # each of its ``num_slots`` lanes, as many steps as its deepest
+        # lane's burst is long (``burst_step_slots_total``; the program
+        # takes that maximum itself, ``rollout.py`` ``live_steps``);
+        # ``burst_steps_total`` is how many of them a lane's request list
+        # asked for (its AdvanceFrames). The ratio is the share of the
+        # tick that is not padding.
         self.burst_steps_total = 0
         self.burst_step_slots_total = 0
         self.spec_hits = 0
@@ -1099,7 +1102,9 @@ class BatchedSessionCore(Instrumented):
         :meth:`_post_dispatch`'s arguments. Few locals ON PURPOSE: the
         first call's trace runs under this frame (``PERF.md`` §7)."""
         self.device_dispatches_total += 1
-        self.burst_step_slots_total += self.num_slots * self.burst_frames
+        self.burst_step_slots_total += self.num_slots * int(
+            jit_args[0][:, TickInts.N_BURST].max()
+        )
         dev = (
             self.attribution.device_wait()
             if self.attribution is not None
