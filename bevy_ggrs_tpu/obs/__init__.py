@@ -21,7 +21,6 @@ Quick start::
     obs.export_prometheus(metrics, recorder)      # -> text exposition
 """
 
-from .attribution import AttributionProbe, profile_window
 from .forensics import DesyncForensics, desync_report
 from .ledger import (
     SpeculationLedger,
@@ -46,7 +45,6 @@ def export_perfetto(tracer, path=None):
 
 
 __all__ = [
-    "AttributionProbe",
     "DesyncForensics",
     "FlightRecorder",
     "FrameRecord",
@@ -74,6 +72,5 @@ __all__ = [
     "null_profiler",
     "null_timeseries",
     "null_tracer",
-    "profile_window",
     "replay_baseline",
 ]

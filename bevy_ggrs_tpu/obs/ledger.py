@@ -223,7 +223,7 @@ class SpeculationLedger:
         return float(max(self.rank_hist))
 
     def summary(self) -> Dict[str, float]:
-        """The bench-column view: whole-run hit rate, hit-rank
+        """The whole-run view: hit rate, hit-rank
         percentiles, waste ratio, and blame concentration."""
         rb = self.rollbacks
         blamed = sum(self.blame_counts.values())
@@ -459,11 +459,10 @@ def register_policy(name: str):
 
 
 def _replay_configs() -> Dict[str, dict]:
-    """The live paced pairs' model configs (bench.py `_live_model_zoo`
-    shapes) plus the structurally-hard 8p/B=1024 spectator config — the
-    exact configurations the ROADMAP's learned-predictor success metric
-    is defined over. Input scripts are the benches' canonical key cycles
-    (`keys[(frame // 3 + handle) % len(keys)]`)."""
+    """The live paced pairs' model configs plus the structurally-hard
+    8p/B=1024 spectator config — the exact configurations the ROADMAP's
+    learned-predictor success metric is defined over. Input scripts are
+    the canonical key cycles (`keys[(frame // 3 + handle) % len(keys)]`)."""
     from bevy_ggrs_tpu.models import boids, box_game, projectiles
 
     box_keys = [

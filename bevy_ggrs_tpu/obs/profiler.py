@@ -19,9 +19,8 @@ Outputs:
   integer microseconds — ``flamegraph.pl`` or speedscope load it as-is;
 - **per-stage culprit tables** (:meth:`report`): ranked leaf-frame
   self-time per span, the "branch_build_ms is 62% ``_structured_bits``"
-  answer bench rows embed as a compact ``profile`` blob
-  (:meth:`profile_blob`) that ``tools/bench_gate.py`` diffs against the
-  committed baseline when a latency gate trips;
+  answer, and its compact form (:meth:`profile_blob`) that a fleet
+  child's heartbeat carries under ``GGRS_HOST_PROFILE=1``;
 - **a Perfetto counter track** (:meth:`export_perfetto`): stack depth +
   cumulative profiled ms as ``ph:"C"`` events carrying the same
   ``wall_t0`` anchor as SpanTracer exports, so ``obs/merge.py`` aligns
@@ -330,7 +329,7 @@ class HostProfiler:
         return _freeze(root)
 
     def report(self, top_k: Optional[int] = None) -> Dict[str, object]:
-        """Everything the ops report / bench row needs in one dict."""
+        """Everything the ops report needs in one dict."""
         return {
             "samples": self._samples,
             "total_ms": round(self.total_ms, 3),
@@ -343,10 +342,10 @@ class HostProfiler:
         }
 
     def profile_blob(self, top_k: Optional[int] = None) -> Dict[str, object]:
-        """Compact per-stage top-K self-time blob for bench rows — the
-        unit ``tools/bench_gate.py`` diffs for regression attribution.
-        Frame self-times are kept as ms; the gate normalizes to shares so
-        run length cancels."""
+        """Compact per-stage top-K self-time blob (a fleet child's
+        heartbeat carries it). Frame self-times are kept as ms; a reader
+        that compares two runs normalizes to shares of the stage's
+        ``total_ms`` so run length cancels."""
         k = self.top_k if top_k is None else int(top_k)
         stages: Dict[str, Dict[str, object]] = {}
         for stage, per in self._self_ms.items():

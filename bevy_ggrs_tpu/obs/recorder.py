@@ -15,7 +15,7 @@ fine without a supervisor or chaos socket.
 The ring is host-side and bounded (default 4096 records ≈ 68 s at 60 fps),
 so it can stay on in soaks; :meth:`FlightRecorder.export_jsonl` dumps it
 as the CI failure artifact and :meth:`FlightRecorder.rollback_histogram`
-feeds BENCH attribution and the Prometheus snapshot.
+feeds the Prometheus snapshot and the HTML ops report.
 """
 
 from __future__ import annotations

@@ -7,7 +7,6 @@ browser. It assembles what the obs stack already collects:
 - span summaries per component tracer,
 - the flight-recorder tail (last N frames per recorder) and the
   rollback-depth histogram,
-- host/device attribution rows from benches,
 - speculation-ledger branch economics (outcomes, hit ranks, waste,
   per-player blame shares),
 - the raw metrics summary,
@@ -232,17 +231,6 @@ def _recorder_section(recorders: Dict[str, object], tail: int = 40) -> str:
             )
             parts.append(_table(fields, rows, left=0))
     return "".join(parts) or "<p class='small'>no flight-recorder data</p>"
-
-
-def _attribution_section(attribution: Dict[str, dict]) -> str:
-    if not attribution:
-        return "<p class='small'>no attribution rows</p>"
-    keys = sorted({k for row in attribution.values() for k in row})
-    rows = [
-        [name] + [row.get(k, "") for k in keys]
-        for name, row in sorted(attribution.items())
-    ]
-    return _table(["bench"] + keys, rows)
 
 
 def _timeseries_section(timeseries) -> str:
@@ -481,7 +469,6 @@ def build_report(
     slo=None,
     tracers: Optional[Dict[str, object]] = None,
     recorders: Optional[Dict[str, object]] = None,
-    attribution: Optional[Dict[str, dict]] = None,
     metrics=None,
     timeseries=None,
     ledger=None,
@@ -493,7 +480,6 @@ def build_report(
     """Render the report; write it to ``path`` when given. ``slo`` is a
     :class:`~bevy_ggrs_tpu.obs.slo.SlotSLO` or its ``snapshot()`` dict;
     ``tracers`` / ``recorders`` map component name -> object;
-    ``attribution`` maps bench name -> attribution row dict;
     ``timeseries`` is a :class:`~bevy_ggrs_tpu.obs.timeseries.TimeSeries`
     or its ``snapshot()`` dict; ``ledger`` is a
     :class:`~bevy_ggrs_tpu.obs.ledger.SpeculationLedger` or its
@@ -516,11 +502,6 @@ def build_report(
     if slo is not None:
         snap = slo.snapshot() if hasattr(slo, "snapshot") else dict(slo)
         sections.append("<h2>Slot SLO state</h2>" + _slo_section(snap))
-    if attribution:
-        sections.append(
-            "<h2>Device-time attribution</h2>"
-            + _attribution_section(attribution)
-        )
     if timeseries is not None:
         sections.append(
             "<h2>Time series (live windows)</h2>"

@@ -1,7 +1,7 @@
 """Relay tree: tiered spectator fan-out (docs/relay.md, "Relay tree").
 
-A single relay tops out at a few thousand spectators per core
-(bench ``relay_fanout_64spec``); the 100k story is a TREE of relays. The
+A single relay tops out at a few thousand spectators per core; the
+100k story is a TREE of relays. The
 composition is deliberately boring: the Subscribe/StreamDelta/
 StreamKeyframe/StreamAck cursor protocol (wire types 14-17) is
 relay-agnostic, so *a relay can itself be a subscriber*. Each non-root
@@ -32,7 +32,7 @@ Tier contract (per hop):
 
 Lag-vs-depth: ``pump()`` drives links before servers, so one pump moves
 a datagram exactly one tier; added lag is bounded by one pump interval
-per tier (the bench ``relay_tree_1k`` gates <= 2 frames per tier).
+per tier (``tests/test_relay_tree.py`` holds <= 2 frames per tier).
 
 Elastic tiers: :class:`ProcRelayTier` supervises real subprocess relays
 (``python -m bevy_ggrs_tpu.relay.tree '<json>'``, one UDP serve port +

@@ -86,7 +86,6 @@ through :class:`_SlotSpecShim` — bit-identical trees by construction.
 
 from __future__ import annotations
 
-import contextlib
 import os
 from typing import Dict, List, Optional, Tuple
 
@@ -497,12 +496,6 @@ class BatchedSessionCore(Instrumented):
             self.burst_frames, self._predictor,
         )
         self.native_batch_calls = 0
-        # Optional AttributionProbe (obs/attribution.py): when a bench
-        # attaches one, the executor call is timed as a nested
-        # device_wait so backends whose dispatch blocks on the in-flight
-        # computation (XLA:CPU admits one) don't get device execution
-        # billed as host work in the probe's enclosing host window.
-        self.attribution = None
         # Aggregate counters (per-slot views go through labeled metrics).
         self.ticks_total = 0
         self.device_dispatches_total = 0
@@ -1105,12 +1098,7 @@ class BatchedSessionCore(Instrumented):
         self.burst_step_slots_total += self.num_slots * int(
             jit_args[0][:, TickInts.N_BURST].max()
         )
-        dev = (
-            self.attribution.device_wait()
-            if self.attribution is not None
-            else contextlib.nullcontext()
-        )
-        with self.span("serve_dispatch"), dev:
+        with self.span("serve_dispatch"):
             self._carry, self._states, cs = self._exec.run(
                 self._carry, *jit_args
             )

@@ -4,13 +4,13 @@ Cold start for a live session is dominated by XLA compiles: the fused tick
 program, the B-branch speculative rollout, and the warmup probes all
 compile from scratch in every fresh process. The persistent cache (keyed
 by HLO hash, so stale entries are impossible) turns every later process's
-cold start into a disk read; the bench matrix's process-isolated configs
-and a game relaunching on a player's machine hit the same path.
+cold start into a disk read; the benchmark's one-process-a-run cells and
+a game relaunching on a player's machine hit the same path.
 
 :func:`ensure_persistent_compilation_cache` is the ONE place this
 repository chooses a cache directory: ``SessionBuilder`` and
-``MatchServer`` call it on construction, ``bench.py`` and
-``tests/conftest.py`` at import. The directory is placed from outside:
+``MatchServer`` call it on construction, ``tests/conftest.py`` at
+import. The directory is placed from outside:
 ``JAX_COMPILATION_CACHE_DIR`` (or an earlier ``jax.config`` call) wins and
 nothing is set here; otherwise the cache lives at ``.jax_cache`` in the
 checkout this package was imported from — a fixed path whatever the
@@ -55,8 +55,8 @@ def ensure_persistent_compilation_cache() -> str:
 # serving layer's no-recompile-on-churn contract is asserted against:
 # MatchServer admits/retires matches into fixed slots with traced indices,
 # so after warmup `compile_counters()["backend_compiles"]` must not move —
-# tests/test_batched_sessions.py and the serve_batched bench both snapshot
-# it around a churn phase.
+# tests/test_batched_sessions.py snapshots it around a churn phase, and
+# benchmark/run.py around every window (``window.executables_built``).
 #
 # ``backend_compiles`` counts every executable a jit cache miss had to
 # obtain — compiled by the backend OR loaded from the persistent cache
@@ -178,7 +178,7 @@ def compile_summary() -> dict:
 #
 # The monitoring listeners see durations, never executables, so the cost
 # observatory is an explicit capture: callers that own a jitted function
-# (executor warmup, the bench harness) register it once under a stable
+# (executor warmup) register it once under a stable
 # name and this module prices it via the AOT path —
 # ``jitted.lower(*args).compile()`` then ``cost_analysis()`` (flops,
 # bytes accessed) and ``memory_analysis()`` (argument/output/temp/

@@ -196,9 +196,9 @@ def phase_identity(size: Size, ident: dict) -> dict:
 
 def phase_timer_honesty(size: Size, ident: dict) -> dict:
     """Wall time of one known-long program ending in block_until_ready
-    against the same ending in a value-forcing host read. bench.py's
-    K-slope and forced reads exist because the former was once observed
-    returning early; ROADMAP S1/D1 need to know whether it does here."""
+    against the same ending in a value-forcing host read: the former
+    was once observed returning early, and every host-clock metric of
+    the benchmark needs to know whether it does here."""
     import jax
     import jax.numpy as jnp
 
@@ -321,8 +321,8 @@ def phase_singleton_pair(size: Size, ident: dict) -> dict:
 
     t0 = time.perf_counter()
     compiles = Compiles()
-    # The loopback profile bench.py's live cells use: latency 2 frames,
-    # jitter 1, loss 3 %, seed 5.
+    # The loopback profile of ``benchmark/traffic/wan.json``: latency
+    # 2 frames, jitter 1, loss 3 %; seed 5.
     net = LoopbackNetwork(latency=2 * DT, jitter=1 * DT, loss=0.03, seed=5)
     clock = lambda: net.now  # noqa: E731
     metrics = Metrics()

@@ -10,10 +10,15 @@ hits and never dedup-skips); committed state, ring contents and checksum
 reports must not.
 """
 
+import ast
+import inspect
+import textwrap
+
 import numpy as np
 import pytest
 
 from bevy_ggrs_tpu.models import box_game
+from bevy_ggrs_tpu.obs.trace import SpanTracer
 from bevy_ggrs_tpu.runner import RollbackRunner
 from bevy_ggrs_tpu.serve.batch import BatchedSessionCore
 from bevy_ggrs_tpu.serve.server import MatchServer
@@ -230,6 +235,34 @@ def test_admit_retire_zero_recompiles():
         core.retire(s2)
     assert xla_cache.compile_counters()["backend_compiles"] == base
     assert core._exec.cache_size() == cache0 == 1
+
+
+def test_dispatch_enters_one_context():
+    """The device dispatch is timed by ONE instrument: ``_finish_dispatch``
+    enters span ``serve_dispatch`` and nothing beside it (no second clock
+    hooked into the hot path), once a dispatch."""
+    fn = ast.parse(
+        textwrap.dedent(inspect.getsource(BatchedSessionCore._finish_dispatch))
+    )
+    entered = [
+        ast.unparse(item.context_expr)
+        for node in ast.walk(fn) if isinstance(node, ast.With)
+        for item in node.items
+    ]
+    assert entered == ["self.span('serve_dispatch')"]
+
+    tracer = SpanTracer()
+    core = make_core(num_slots=2, tracer=tracer)
+    assert not hasattr(core, "attribution")
+    slot = core.admit()
+    before = tracer.summary().get("serve_dispatch", {}).get("count", 0)
+    dispatched = core.device_dispatches_total
+    drive(core, {slot: make_script(seed=3, depth=2, cycles=1)})
+    assert core.device_dispatches_total > dispatched
+    assert (
+        tracer.summary()["serve_dispatch"]["count"] - before
+        == core.device_dispatches_total - dispatched
+    )
 
 
 def test_checksum_reports_match_serial():

@@ -472,6 +472,31 @@ def test_predictor_on_wire_invisible(monkeypatch):
     assert all(cs_on[f] == cs_off[f] for f in common)
 
 
+def test_predictor_on_pair_full_hits_hold_the_repeat_last_floor(monkeypatch):
+    """A learned ranking must never make live speculation worse than the
+    zero-parameter baseline: a predictor-ON pair under real rollbacks
+    ranks with the predictor, commits whole rollbacks speculatively, and
+    its full-hit rate stands at or above the best ``repeat_last`` rate of
+    the committed counterfactual table (``spec_baseline.json``)."""
+    import json
+
+    monkeypatch.delenv("GGRS_PREDICTOR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "spec_baseline.json")) as f:
+        floor = max(
+            cfg["policies"]["repeat_last"]["full_hit_rate"]
+            for cfg in json.load(f)["configs"].values()
+        )
+    peers, _, events = _run_spec_pair(True, iters=120)
+    assert not any(e.kind == EventKind.DESYNC_DETECTED for e in events)
+    for _, runner in peers:
+        assert runner._predictor is not None
+        assert runner.predictor_rank_builds > 0
+        assert runner.rollbacks_total > 0
+        assert runner.spec_hits > 0
+        assert runner.spec_hits / runner.rollbacks_total >= floor
+
+
 def test_predictor_off_identical_to_unconfigured(monkeypatch):
     """predictor=False and a plain unconfigured runner run the same
     script to bitwise-identical state — the OFF path has zero behavioral

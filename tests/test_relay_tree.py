@@ -213,6 +213,78 @@ class TestRelayTreeExactness:
             )
         assert codec.encode(ref.world()) == specs[2].state_bytes
 
+    def test_live_fanout_lag_per_tier_and_tree_wide_keyframe_cache(self):
+        """The tiered fan-out's bounds, by count, WHILE the match runs:
+        root -> 2 mids -> 4 leaves, three spectators cold-joining each
+        leaf in one burst and three witnesses on the root. A tier adds
+        at most 2 frames of lag (the leaves' p99 over the witnesses',
+        split across the tiers crossed; no link ever trails its parent
+        by more), each leaf encodes its keyframe once and serves the
+        other joiners from the shared cache, and every spectator drains
+        to the publisher's exact bytes."""
+        net = LoopbackNetwork()
+        tree, mids, _, peers, pub = _tree_fixture(net, mids=2)
+        leaves = [
+            tree.add_relay(parent=mid.addr) for mid in mids for _ in range(2)
+        ]
+        assert tree.depth() == 2
+        codec = StateCodec.for_state(box_game.make_world(2).commit())
+        specs = [
+            _make_spec(net, ("spec", i), [leaves[i % 4].addr], codec)
+            for i in range(12)
+        ]
+        witnesses = [
+            _make_spec(net, ("wit", i), [ROOT], codec) for i in range(3)
+        ]
+        everyone = specs + witnesses
+        WARM, SETTLE, FRAMES = 60, 60, 240
+        lag, root_lag, link_lag = [], [], []
+        for tick in range(FRAMES):
+            net.advance(FPS_DT)
+            for peer in peers:
+                sup_step(net, peer, scripted_input)
+            pub.publish(net.now)
+            # Pump after publish: a deployed tree pumps far faster than
+            # the frame loop, and pumping first would add one whole frame
+            # of the harness's own quantization to every tier sample.
+            tree.pump(net.now)
+            if tick < WARM:
+                continue
+            for spec in everyone:  # tick WARM is the cold-join burst
+                spec.poll(net.now)
+            if tick >= WARM + SETTLE:
+                head = pub._prev_frame
+                lag += [max(0, head - s.current_frame) for s in specs]
+                root_lag += [
+                    max(0, head - w.current_frame) for w in witnesses
+                ]
+                link_lag += list(tree.tier_lag().values())
+        assert pub.published_frames > 150
+        added = (
+            np.percentile(lag, 99) - np.percentile(root_lag, 99)
+        ) / tree.depth()
+        assert np.percentile(root_lag, 99) <= 2
+        assert added <= 2
+        assert max(link_lag) <= 2
+
+        rows = {r["relay"]: r for r in tree.topology_rows()}
+        for leaf in leaves:
+            row = rows[repr(leaf.addr)]
+            assert row["subscribers"] == 3
+            assert row["cache_misses"] == 1 and row["cache_hits"] >= 2
+
+        for _ in range(60):  # the match is over: the head is fixed
+            net.advance(FPS_DT)
+            tree.pump(net.now)
+            for session, _, _, _ in peers:
+                session.poll_remote_clients()
+            pub.publish(net.now)
+            for spec in everyone:
+                spec.poll(net.now)
+        for spec in everyone:
+            assert spec.current_frame == pub._prev_frame
+            assert spec.state_bytes == pub._prev
+
     def test_topology_rows_and_report_section(self):
         """topology_rows feeds the ops report's tree section."""
         net = LoopbackNetwork()

@@ -408,6 +408,64 @@ def test_batched_unrepairable_slot_faults_with_slot_index():
     assert exc.value.slot == 0
 
 
+def test_match_server_sweep_heals_every_injection_in_place():
+    """The served lifecycle, by count: sweep-aligned single-bit ring flips
+    into a ``MatchServer``'s live matches are ALL detected by its periodic
+    attestation sweep and ALL repaired bitwise in place; no match is
+    quarantined or evicted and nothing compiles after steady state."""
+    from bevy_ggrs_tpu.serve import SlotHealth
+    from bevy_ggrs_tpu.utils.metrics import Metrics
+    from tests.test_serve_faults import inputs_for, make_server, make_synctest
+
+    S, ATTEST, TARGET = 8, 4, 4
+    metrics = Metrics()
+    server = make_server(metrics=metrics, capacity=S, attest_interval=ATTEST)
+    handles = [
+        server.add_match(make_synctest(), inputs_for(m)) for m in range(S)
+    ]
+    rng = np.random.RandomState(0x5DC)
+
+    def inject(handle):
+        # The row of frame - 3: under the SyncTest reload depth (check
+        # distance 2), so it is never loaded before the sweep reads it,
+        # and resident until this frame's sweep (the ring holds 5 rows).
+        core = server.groups[handle.group]
+        slot = core.slots[handle.slot]
+        rows = np.flatnonzero(
+            np.asarray(core.rings.frames)[handle.slot] == slot.frame - 3
+        )
+        if not slot.active or rows.size == 0:
+            return False
+        core.rings, _ = integrity.flip_ring_bit(
+            core.rings, int(rows[0]), rng, slot=handle.slot
+        )
+        return True
+
+    assert xla_cache.install_compile_listeners()
+    injected = 0
+    for t in range(60):
+        if t == 12:
+            built = xla_cache.compile_counters()["backend_compiles"]
+        if (
+            t >= 16 and injected < TARGET
+            and server.frames_served % ATTEST == 0
+            and inject(handles[(injected * 3) % S])
+        ):
+            injected += 1
+        server.run_frame()
+    assert injected == TARGET
+    c = metrics.counters
+    assert c["sdc_detected"] == injected
+    assert c["sdc_repaired"] == c["sdc_repaired_bitwise"] == injected
+    assert c.get("sdc_unrepairable", 0) == 0
+    # One resimulation span a repair, from the deepest clean snapshot.
+    spans = metrics.series["sdc_repair_frames"]
+    assert len(spans) == injected and min(spans) >= 1
+    assert server.evictions_total == 0
+    assert all(server.health_of(h) is SlotHealth.HEALTHY for h in handles)
+    assert xla_cache.compile_counters()["backend_compiles"] == built
+
+
 # ---------------------------------------------------------------------------
 # Disk: checkpoint corruption -> typed refusal -> newest-clean fallback
 # ---------------------------------------------------------------------------

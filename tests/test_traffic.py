@@ -221,6 +221,60 @@ def test_full_fleet_drops_arrivals_open_loop():
         assert t.t_done is not None
 
 
+def test_admission_churn_faults_no_slot_and_compiles_nothing():
+    """A healthy front door, by count: once the admission path has run
+    once on every (server, group), an open-loop plan of queued admissions
+    and abandons raises no slot fault, rejects nobody and obtains no
+    executable."""
+    from bevy_ggrs_tpu.utils import xla_cache
+
+    assert xla_cache.install_compile_listeners()
+    net = LoopbackNetwork()
+    bal, servers = make_traffic_fleet(net, capacity=8)
+
+    def serve(frames):
+        for _ in range(frames):
+            net.advance(FPS_DT)
+            for srv in servers:
+                srv.run_frame()
+
+    # Steady state: enqueue -> drain -> first dispatch -> retire, on
+    # every group of every server.
+    warm = [
+        (k, g) for k, srv in enumerate(servers)
+        for g in range(len(srv.groups))
+    ]
+    for wid, (k, _) in enumerate(warm, 100_000):
+        bal.place_match(
+            wid, make_synctest(), inputs_for(wid), server_id=k, queue=True,
+        )
+    serve(8)
+    for wid in range(100_000, 100_000 + len(warm)):
+        pl = bal.placements.pop(wid)
+        servers[pl.server_id].retire_match(pl.handle)
+    serve(4)
+    built = xla_cache.compile_counters()["backend_compiles"]
+
+    plan = TrafficPlan.generate(
+        seed=9000, duration=2.0, match_rate=5.0, abandon_rate=1.5,
+        num_players=2, max_join_delay=0.05,
+    )
+    mm = Matchmaker(
+        bal, plan,
+        make_session=lambda a: make_synctest(),
+        make_inputs=lambda a: inputs_for(a.input_seed % 64),
+        clock=lambda: net.now, metrics=Metrics(),
+    )
+    run_traffic(net, mm, servers, 180)
+    assert mm.drained and mm.admissions_rejected == 0
+    assert mm.abandons_applied > 0
+    assert sum(s.admissions_completed for s in servers) >= 6 + len(warm)
+    assert all(
+        s.metrics.counters.get("slot_faults", 0) == 0 for s in servers
+    )
+    assert xla_cache.compile_counters()["backend_compiles"] == built
+
+
 def test_spectators_resolve_against_live_matches():
     net = LoopbackNetwork()
     bal, servers = make_traffic_fleet(net)
