@@ -8,6 +8,8 @@ systems.
   inside the rollback domain, weights as rollback state)
 - ``projectiles`` — dynamic entity lifecycle (in-step spawn/despawn with a
   device-resident rollback-id allocator)
+- ``particles`` — upstream's particle stress test: a hundred births and a
+  hundred deaths a frame (the shared claim's select form)
 """
 
-from bevy_ggrs_tpu.models import boids, box_game, neural_bots, projectiles
+from bevy_ggrs_tpu.models import boids, box_game, neural_bots, particles, projectiles

@@ -19,11 +19,15 @@ changes is what SyncTest/P2P certify:
 
 TPU-native design notes:
 
-- Spawn is a masked scatter: firing players are ranked with a cumulative
-  sum, matched rank-for-rank to free slots (``searchsorted`` over the
-  free-slot prefix sum), and written with out-of-bounds-drop scatters when
-  capacity is exhausted — no data-dependent shapes, so the step stays one
-  fused XLA program under ``lax.scan``/``vmap``.
+- Spawn is a masked select: firing players are ranked with a cumulative
+  sum and matched rank-for-rank to free slots; every free slot reads its own
+  ordinal off the free-slot prefix sum and takes the shot of that rank, and
+  a shot whose rank finds no free slot is dropped when capacity is
+  exhausted — no data-dependent shapes and nothing indexed, so the step
+  stays one fused XLA program under ``lax.scan``/``vmap``. The claim is the
+  shared helper ``ops/lifecycle.py`` ``claim_rows`` (``models/particles.py``
+  is its other caller, with a hundred births a frame), which says why it is
+  a select and not a scatter.
 - The rollback-id allocator is a REGISTERED RESOURCE (``next_rollback_id``):
   rolling back rewinds the allocator with everything else, so a respawned
   projectile gets the same id on resimulation — the id-stability contract of
@@ -46,7 +50,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
-from bevy_ggrs_tpu.ops import neighbor
+from bevy_ggrs_tpu.ops import lifecycle, neighbor
 from bevy_ggrs_tpu.schedule import InputSpec, PlayerInputs, Schedule
 from bevy_ggrs_tpu.state import DEVICE_ID_BASE, HostWorld, TypeRegistry, WorldState
 
@@ -178,10 +182,10 @@ def fire_system(state: WorldState, inputs: PlayerInputs) -> WorldState:
 
     Claim rule (deterministic, shape-static): firing players ranked by
     handle take free slots in ascending slot order; when fewer free slots
-    than firers remain, the highest-ranked firers' shots fizzle (scatters
-    drop out-of-bounds writes).
+    than firers remain, the highest-ranked firers' shots fizzle (the
+    shared claim, :func:`bevy_ggrs_tpu.ops.lifecycle.claim_rows`, drops
+    them). A fizzled shot still restarts its player's cooldown.
     """
-    cap = state.capacity
     num_players = inputs.num_players
     bits = inputs.bits.astype(jnp.uint32)
     cooldown = state.resources["fire_cooldown"]
@@ -205,16 +209,9 @@ def fire_system(state: WorldState, inputs: PlayerInputs) -> WorldState:
     )  # [P]
 
     # Rank firers (0-based among firing players, by handle order) and match
-    # them to free slots in ascending slot order.
-    rank = jnp.cumsum(firing.astype(jnp.int32)) - 1  # [P], valid where firing
-    free = ~state.alive
-    free_prefix = jnp.cumsum(free.astype(jnp.int32))  # [cap]
-    n_free = free_prefix[-1]
-    # slot of the k-th (0-based) free slot = first index with prefix == k+1.
-    slots = jnp.searchsorted(free_prefix, rank + 1, side="left")  # [P]
-    can = firing & (rank < n_free)
-    # Out-of-range target -> scatter drops the write entirely.
-    target = jnp.where(can, slots, cap)  # [P]
+    # them to free slots in ascending slot order: the shared claim
+    # (``ops/lifecycle.py``), each leaf given as a table by player.
+    claim = lifecycle.claim_rows(state.alive, firing)
 
     next_id = state.resources["next_rollback_id"]
     tpos = state.components["position"][turret_slot]  # [P, 2]
@@ -223,27 +220,25 @@ def fire_system(state: WorldState, inputs: PlayerInputs) -> WorldState:
     norm = jnp.sqrt(jnp.sum(taim * taim, axis=1, keepdims=True))
     aim_unit = taim / jnp.maximum(norm, jnp.float32(1e-6))
 
-    alive = state.alive.at[target].set(True, mode="drop")
-    rollback_id = state.rollback_id.at[target].set(
-        next_id + rank, mode="drop"
-    )
+    alive = claim.put(state.alive, True)
+    rollback_id = claim.put(state.rollback_id, next_id + claim.rank)
     comps = dict(state.components)
     pres = dict(state.present)
-    comps["position"] = comps["position"].at[target].set(tpos, mode="drop")
-    comps["velocity"] = comps["velocity"].at[target].set(
-        aim_unit * PROJ_SPEED, mode="drop"
-    )
-    comps["aim"] = comps["aim"].at[target].set(aim_unit, mode="drop")
-    comps["kind"] = comps["kind"].at[target].set(KIND_PROJECTILE, mode="drop")
-    comps["owner"] = comps["owner"].at[target].set(p_range, mode="drop")
-    comps["ttl"] = comps["ttl"].at[target].set(PROJ_TTL, mode="drop")
-    # Mark present ONLY the components written above: a user registry may
+    born = {
+        "position": tpos,
+        "velocity": aim_unit * PROJ_SPEED,
+        "aim": aim_unit,
+        "kind": KIND_PROJECTILE,
+        "owner": p_range,
+        "ttl": PROJ_TTL,
+    }
+    # Mark present ONLY the components written here: a user registry may
     # carry extra components, and flagging them present would expose the
     # slot's previous occupant's stale values to systems and the checksum.
-    for name in ("position", "velocity", "aim", "kind", "owner", "ttl"):
-        pres[name] = pres[name].at[target].set(True, mode="drop")
+    for name, values in born.items():
+        comps[name] = claim.put(comps[name], values)
+        pres[name] = claim.put(pres[name], True)
 
-    spawned = jnp.sum(can.astype(jnp.int32))
     # Every firing player restarts their cooldown — a fizzled (capacity-
     # dropped) shot still counts as having pulled the trigger.
     cd_now = jnp.where(
@@ -258,7 +253,7 @@ def fire_system(state: WorldState, inputs: PlayerInputs) -> WorldState:
         present=pres,
         resources={
             **state.resources,
-            "next_rollback_id": next_id + spawned,
+            "next_rollback_id": next_id + claim.placed,
             "fire_cooldown": cooldown,
         },
     )

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """SyncTest determinism harness for the extension models (boids,
-neural_bots, projectiles) — the box_game CLIs cover reference parity; this
+neural_bots, projectiles, particles) — the box_game CLIs cover reference parity; this
 drives the entity-scaling, MXU, and dynamic-lifecycle model families
 through the same forced-rollback machinery.
 
@@ -8,6 +8,7 @@ through the same forced-rollback machinery.
         --check-distance 5 --frames 120 --kernel mxu
     python examples/model_zoo_synctest.py --model neural_bots --platform tpu
     python examples/model_zoo_synctest.py --model projectiles
+    python examples/model_zoo_synctest.py --model particles --entities 9216
 """
 
 import argparse
@@ -28,7 +29,8 @@ import numpy as np  # noqa: E402
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--model",
-                        choices=["boids", "neural_bots", "projectiles"],
+                        choices=["boids", "neural_bots", "projectiles",
+                                 "particles"],
                         default="boids")
     parser.add_argument("--entities", type=int, default=256)
     parser.add_argument("--num-players", type=int, default=2)
@@ -43,7 +45,7 @@ def main() -> int:
     args = parser.parse_args()
     force_platform(args.platform)
 
-    from bevy_ggrs_tpu.models import boids, neural_bots, projectiles
+    from bevy_ggrs_tpu.models import boids, neural_bots, particles, projectiles
     from bevy_ggrs_tpu.runner import RollbackRunner
     from bevy_ggrs_tpu.session import MismatchedChecksum, SyncTestSession
     from bevy_ggrs_tpu.state import combine64, checksum
@@ -59,6 +61,13 @@ def main() -> int:
         world = projectiles.make_world(
             args.num_players, capacity=args.entities
         )
+    elif args.model == "particles":
+        # Upstream's stress test: 100 births a frame want 8,900 rows; a
+        # smaller world takes as many births as fit its --entities.
+        model = particles
+        rate = max(1, min(particles.RATE, args.entities // 89))
+        schedule = particles.make_schedule(rate)
+        world = particles.make_world(args.num_players, args.entities)
     else:
         model = neural_bots
         schedule = neural_bots.make_schedule()
