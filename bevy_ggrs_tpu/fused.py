@@ -55,7 +55,7 @@ import numpy as np
 
 from bevy_ggrs_tpu.obs.trace import null_span
 from bevy_ggrs_tpu.parallel.speculate import match_branch
-from bevy_ggrs_tpu.rollout import live_steps, rollout_burst
+from bevy_ggrs_tpu.rollout import live_steps, rollout_burst, rollout_steps
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.state import (
     OWN_BUFFER_BYTES,
@@ -65,6 +65,7 @@ from bevy_ggrs_tpu.state import (
     ring_load,
     ring_put,
     ring_row_read,
+    ring_step_load,
 )
 
 # The name of the axis a tick is vmapped over, where it is (the served
@@ -120,10 +121,8 @@ def absorb_branch_frames(
     def body(ring, t):
         f = first_frame + t
         valid = t < n_frames
-        cs = ring_row_read(
-            spec_ring.checksums, jnp.remainder(f, spec_ring.depth)
-        )
-        ring = ring_put(ring, ring_load(spec_ring, f), f, cs, valid)
+        saved, cs = ring_step_load(spec_ring, f, anchor)
+        ring = ring_put(ring, saved, f, cs, valid)
         return ring, jnp.where(valid, cs, jnp.uint32(0))
 
     main_ring, checksums = jax.lax.scan(
@@ -133,7 +132,7 @@ def absorb_branch_frames(
     # State entering `end`: saved in the branch ring unless the replay ran
     # through the rollout's entire span, in which case it's the final state.
     in_ring = end < anchor + total_spec
-    from_ring = ring_load(spec_ring, end)
+    from_ring, _ = ring_step_load(spec_ring, end, anchor)
     state = jax.tree_util.tree_map(
         lambda a, b: jnp.where(in_ring, a, b), from_ring, spec_states
     )
@@ -441,7 +440,7 @@ class PackedTick:
         (
             ring, state, absorb_cs, burst_cs, spec_rings, spec_states, spec_cs,
         ) = FusedTickExecutor._tick_impl(
-            self.schedule, MF, F,
+            self.schedule, MF,
             ring, state, prev_rings, prev_states, ints[T.BRANCH],
             ints[T.ABSORB_FIRST], ints[T.ABSORB_N], ints[T.PREV_ANCHOR],
             ints[T.PREV_TOTAL],
@@ -711,7 +710,7 @@ class FusedTickExecutor:
 
     @staticmethod
     def _tick_impl(
-        schedule, burst_frames, spec_depth,
+        schedule, burst_frames,
         ring, state,
         prev_rings, prev_states, branch,
         absorb_first, absorb_n, prev_anchor, prev_total,
@@ -758,26 +757,11 @@ class FusedTickExecutor:
             ring_load(ring, spec_anchor),
         )
 
-        def fresh_ring(st: WorldState) -> SnapshotRing:
-            stacked = jax.tree_util.tree_map(
-                lambda x: jnp.broadcast_to(x[None], (spec_depth,) + x.shape),
-                st,
+        spec_rings, spec_states, spec_cs = jax.vmap(
+            lambda bb: rollout_steps(
+                schedule, anchor_state, spec_anchor, bb, spec_status
             )
-            return SnapshotRing(
-                states=stacked,
-                frames=jnp.full((spec_depth,), -1, dtype=jnp.int32),
-                checksums=jnp.zeros((spec_depth, 2), dtype=jnp.uint32),
-            )
-
-        mask = jnp.ones((spec_depth,), dtype=jnp.bool_)
-
-        def one_branch(bb):
-            return rollout_burst(
-                schedule, fresh_ring(anchor_state), anchor_state,
-                spec_anchor, bb, spec_status, mask, mask,
-            )
-
-        spec_rings, spec_states, spec_cs = jax.vmap(one_branch)(branch_bits)
+        )(branch_bits)
         return ring, state, absorb_cs, burst_cs, spec_rings, spec_states, spec_cs
 
     # ------------------------------------------------------------------

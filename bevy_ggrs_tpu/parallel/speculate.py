@@ -38,7 +38,7 @@ import numpy as np
 from bevy_ggrs_tpu.obs.trace import Instrumented
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.state import SnapshotRing, WorldState
-from bevy_ggrs_tpu.rollout import rollout_burst
+from bevy_ggrs_tpu.rollout import rollout_steps
 
 # A branch sampler maps (key, last_bits[P, …], B, F) -> bits[B, F, P, …]:
 # the Monte Carlo input tree (survey §7 "branch selection policy").
@@ -202,7 +202,7 @@ class SpeculativeExecutor(Instrumented):
         self.entity_axis = entity_axis
         self._set_sinks(tracer=tracer)
 
-        run = functools.partial(self._run_impl, schedule, self.max_frames)
+        run = functools.partial(self._run_impl, schedule)
         commit = self._commit_impl
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
@@ -246,31 +246,16 @@ class SpeculativeExecutor(Instrumented):
             self._commit = jax.jit(commit)
 
     @staticmethod
-    def _run_impl(schedule, max_frames, state, start_frame, branch_bits, status):
-        """All-branch rollout. Each branch: fresh ring of depth
-        ``max_frames``, then (save, advance) × F — identical semantics to F
-        serial SaveGameState/AdvanceFrame request pairs per branch."""
-        depth = max_frames
-
-        def fresh_ring(st: WorldState) -> SnapshotRing:
-            stacked = jax.tree_util.tree_map(
-                lambda x: jnp.broadcast_to(x[None], (depth,) + x.shape), st
+    def _run_impl(schedule, state, start_frame, branch_bits, status):
+        """All-branch rollout. Each branch: (save, advance) × F from the
+        same state — identical semantics to F serial
+        SaveGameState/AdvanceFrame request pairs per branch, its ring in
+        step order (``rollout.py`` ``rollout_steps``)."""
+        return jax.vmap(
+            lambda bits: rollout_steps(
+                schedule, state, start_frame, bits, status
             )
-            return SnapshotRing(
-                states=stacked,
-                frames=jnp.full((depth,), -1, dtype=jnp.int32),
-                checksums=jnp.zeros((depth, 2), dtype=jnp.uint32),
-            )
-
-        mask = jnp.ones((max_frames,), dtype=jnp.bool_)
-
-        def one_branch(bits):
-            ring = fresh_ring(state)
-            return rollout_burst(
-                schedule, ring, state, start_frame, bits, status, mask, mask
-            )
-
-        return jax.vmap(one_branch)(branch_bits)
+        )(branch_bits)
 
     @staticmethod
     def _commit_impl(tree, branch):
@@ -330,20 +315,27 @@ class SpeculativeExecutor(Instrumented):
 
 
 def merge_rings(main: SnapshotRing, spec: SnapshotRing) -> SnapshotRing:
-    """Overlay the saved slots of ``spec`` (a committed speculative ring)
-    onto the session's persistent ring: slots ``spec`` actually saved
-    (``frames >= 0``) win; untouched slots keep ``main``'s history. Rings
-    must share depth."""
+    """Overlay the frames ``spec`` saved (a committed speculative ring: its
+    rows stand in step order, ``state.py`` ``ring_of_steps``) onto the
+    session's persistent ring: each saved row (``frames >= 0``) goes where
+    its label says, row ``frame % depth``; untouched rows keep ``main``'s
+    history. Rings must share depth."""
     if main.depth != spec.depth:
         raise ValueError(f"ring depth mismatch: {main.depth} != {spec.depth}")
-    take = spec.frames >= 0
+    # hit[r, t]: row ``t`` of ``spec`` holds the frame that belongs in row
+    # ``r`` of ``main``.
+    hit = (spec.frames >= 0) & (
+        jnp.remainder(spec.frames, main.depth)
+        == jnp.arange(main.depth, dtype=jnp.int32)[:, None]
+    )
+    take, src = hit.any(axis=1), hit.argmax(axis=1)
 
     def sel(s, m):
         mask = take.reshape((-1,) + (1,) * (s.ndim - 1))
-        return jnp.where(mask, s, m)
+        return jnp.where(mask, s[src], m)
 
     return SnapshotRing(
         states=jax.tree_util.tree_map(sel, spec.states, main.states),
-        frames=jnp.where(take, spec.frames, main.frames),
-        checksums=jnp.where(take[:, None], spec.checksums, main.checksums),
+        frames=sel(spec.frames, main.frames),
+        checksums=sel(spec.checksums, main.checksums),
     )

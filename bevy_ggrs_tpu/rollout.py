@@ -7,8 +7,8 @@ at a time, each save a deep reflective clone and each advance a full schedule
 run (``/root/reference/src/ggrs_stage.rs:259-306``) — up to ``max_prediction``
 (12) restore+resimulate cycles inside one render frame.
 
-Here the whole burst is ONE device call: ``lax.scan`` over the frame axis of
-a padded input tensor, with the snapshot ring save folded into each step and
+Here the whole burst is ONE device call: a loop over the frame axis of a
+padded input tensor, with the snapshot ring save folded into each step and
 per-frame checksums streamed out. The host only receives the checksums (the
 session's desync/synctest signal — reference hands ggrs exactly that,
 ``ggrs_stage.rs:282-283``); ring and world state never leave HBM.
@@ -20,8 +20,9 @@ write, checksum reported as 0. Still one executable, and the padding costs
 nothing on the device: a burst whose caller hands over its live prefix
 (:func:`live_steps`: one past the last step any mask sets) loops that
 TRACED number of steps, not ``max_frames``; the count is an operand, not a
-shape. The speculative rollouts' masks are all ones over a static depth:
-their trip count is their shape, and they keep the scan.
+shape. A speculative rollout has no masks and no ring to write into: every
+one of its steps is live, its trip count is its shape, and it is a scan
+whose rows leave as ``ys`` (:func:`rollout_steps`).
 
 The save-before-advance ordering and the "save is labeled with the current
 frame" invariant (``ggrs_stage.rs:277``'s ``assert_eq!(self.frame, frame)``)
@@ -39,8 +40,9 @@ import jax.numpy as jnp
 
 from bevy_ggrs_tpu.schedule import PlayerInputs, Schedule
 from bevy_ggrs_tpu.state import (
-    SnapshotRing, WorldState, checksum, ring_load, ring_row_read,
-    ring_row_write, ring_rows_flat, ring_rows_shaped, ring_save,
+    SnapshotRing, WorldState, active_checksum, ring_load, ring_of_steps,
+    ring_row_read, ring_row_write, ring_rows_flat, ring_rows_shaped,
+    ring_save, state_row,
 )
 
 
@@ -69,7 +71,7 @@ def rollout_burst(
     status: jnp.ndarray,  # int32[max_frames, num_players]
     save_mask: jnp.ndarray,  # bool[max_frames]
     adv_mask: jnp.ndarray,  # bool[max_frames]
-    n_run: Optional[jnp.ndarray] = None,  # int32[], from ``live_steps``
+    n_run: jnp.ndarray,  # int32[], from ``live_steps``
 ) -> Tuple[SnapshotRing, WorldState, jnp.ndarray]:
     """Execute up to ``max_frames`` (save?, advance?) steps as one fused loop.
 
@@ -81,9 +83,7 @@ def rollout_burst(
 
     ``n_run`` (:func:`live_steps` of the masks) makes the loop run that many
     steps and no more: the steps it leaves out are the identity, so every
-    output is bit for bit the full-length scan's. Without it the loop is a
-    ``lax.scan`` over all ``max_frames`` steps (a rollout, whose masks are
-    all ones).
+    output is bit for bit what all ``max_frames`` steps give.
 
     Returns ``(ring, state, checksums[max_frames])`` with ``checksums[t]``
     the saved checksum at step ``t`` (0 where ``save_mask[t]`` is False).
@@ -91,40 +91,60 @@ def rollout_burst(
     start_frame = jnp.asarray(start_frame, dtype=jnp.int32)
     # Large rows ride the loop flat (``state.py`` ``FLAT_ROW_BYTES``).
     shaped, ring = ring, ring_rows_flat(ring)
+    xs = (bits, status, save_mask, adv_mask)
 
-    def body(carry, xs):
-        ring, state, frame = carry
-        b, s, sv, adv = xs
+    def step(t, loop):
+        ring, state, frame, checksums = loop
+        # Row ``t`` by ``state.py``'s row access: one dynamic slice under
+        # the slot ``vmap`` too, since every lane is at the same step.
+        b, s, sv, adv = jax.tree_util.tree_map(
+            lambda x: ring_row_read(x, t), xs
+        )
         ring, cs = ring_save(ring, state, frame, sv)  # no-op where not sv
         cs = jnp.where(sv, cs, jnp.uint32(0))
         advanced = schedule(state, PlayerInputs(bits=b, status=s))
         state = jax.tree_util.tree_map(
             lambda new, old: jnp.where(adv, new, old), advanced, state
         )
-        return (ring, state, frame + adv.astype(jnp.int32)), cs
+        return (ring, state, frame + adv.astype(jnp.int32),
+                ring_row_write(checksums, cs, t))
 
-    xs = (bits, status, save_mask, adv_mask)
-
-    def step(t, loop):
-        # Row ``t`` by ``state.py``'s row access: one dynamic slice under
-        # the slot ``vmap`` too, since every lane is at the same step.
-        carry, checksums = loop
-        carry, cs = body(
-            carry, jax.tree_util.tree_map(lambda x: ring_row_read(x, t), xs)
-        )
-        return carry, ring_row_write(checksums, cs, t)
-
-    if n_run is None:
-        (ring, state, _), checksums = jax.lax.scan(
-            body, (ring, state, start_frame), xs
-        )
-    else:
-        (ring, state, _), checksums = jax.lax.fori_loop(
-            0, n_run, step,
-            ((ring, state, start_frame),
-             jnp.zeros((save_mask.shape[0], 2), jnp.uint32)),
-        )
+    ring, state, _, checksums = jax.lax.fori_loop(
+        0, n_run, step,
+        (ring, state, start_frame,
+         jnp.zeros((save_mask.shape[0], 2), jnp.uint32)),
+    )
     return ring_rows_shaped(ring, shaped), state, checksums
+
+
+def rollout_steps(
+    schedule: Schedule,
+    state: WorldState,
+    start_frame: jnp.ndarray,
+    bits: jnp.ndarray,  # [frames, num_players, *input_shape]
+    status: jnp.ndarray,  # int32[frames, num_players]
+) -> Tuple[SnapshotRing, WorldState, jnp.ndarray]:
+    """A speculative rollout: ``frames`` (save, advance) steps from
+    ``state`` at ``start_frame``, every one live: the same states and
+    checksums as that many serial ``SaveGameState`` / ``AdvanceFrame``
+    pairs. Returns ``(ring, state, checksums[frames])`` with the ring in
+    STEP order (``state.py`` ``ring_of_steps``: row ``t`` is the state
+    entering frame ``start_frame + t``).
+
+    No ring is carried: the state entering a step and its checksum leave
+    the loop at the loop's own counter, which every lane of every ``vmap``
+    shares, so each row is written once, as one slice, whoever's frame it
+    is. A large row leaves flat (``state.py`` ``FLAT_ROW_BYTES``: the
+    stacked rows are a loop-carried buffer like a burst's ring) and is
+    shaped back once, after the loop."""
+
+    def body(state, xs):
+        b, s = xs
+        saved = (state_row(state), active_checksum(state))
+        return schedule(state, PlayerInputs(bits=b, status=s)), saved
+
+    final, (rows, checksums) = jax.lax.scan(body, state, (bits, status))
+    return ring_of_steps(rows, state, start_frame, checksums), final, checksums
 
 
 class RolloutExecutor:

@@ -505,7 +505,9 @@ class SnapshotRing:
     Mirrors the reference's ``Vec<WorldSnapshot>`` sized to
     ``max_prediction()`` and indexed ``frame % len`` (``src/ggrs_stage.rs:89,
     169-173, 286, 294``) — but "save" is an indexed device write, not a deep
-    reflective clone, and the whole ring stays in HBM.
+    reflective clone, and the whole ring stays in HBM. (A speculative
+    rollout's branch ring has the same fields and another row order:
+    :func:`ring_of_steps`.)
     """
 
     states: WorldState  # every leaf gains a leading [depth] axis
@@ -728,10 +730,12 @@ def ring_save(
 # it is and its programs with it. Bits are only moved.
 FLAT_ROW_BYTES = 4 << 10
 
-# How many ring leaves each form was traced with (``kind``: "flat" /
-# "shaped"), process-wide; ``serve/batch.py`` reports its executable's
-# share as the labelled count ``ring_row_lowering``.
-ring_row_lowerings: Dict[str, int] = {"flat": 0, "shaped": 0}
+# How many ring leaves each form was traced with, process-wide (``kind``:
+# "flat" / "shaped", a burst's ring by the form its loop carries it in;
+# "step", a rollout's branch ring, whose rows leave the loop in step order:
+# :func:`ring_of_steps`); ``serve/batch.py`` reports its executable's share
+# as the labelled count ``ring_row_lowering``.
+ring_row_lowerings: Dict[str, int] = {"flat": 0, "shaped": 0, "step": 0}
 
 
 def _row_flat(x: jnp.ndarray, lead: int) -> jnp.ndarray:
@@ -761,25 +765,86 @@ def ring_rows_flat(ring: SnapshotRing) -> SnapshotRing:
     return ring.replace(states=states)
 
 
+def _rows_shaped(x: jnp.ndarray, row: Tuple[int, ...]) -> jnp.ndarray:
+    """The inverse of ``_row_flat(x, 1)``: ``x[depth, ...]`` with rows of
+    shape ``row``."""
+    if x.shape[1:] == row:
+        return x
+    perm = _lanes_last(row)
+    if perm is None:
+        return x.reshape(x.shape[:1] + row)
+    x = x.reshape(x.shape[:1] + tuple(row[a] for a in perm))
+    return jnp.transpose(
+        x, (0,) + tuple(1 + int(a) for a in np.argsort(perm))
+    )
+
+
 def ring_rows_shaped(ring: SnapshotRing, like: SnapshotRing) -> SnapshotRing:
     """The inverse of :func:`ring_rows_flat`: ``ring``'s rows in the shapes
     ``like``'s have."""
-
-    def shaped(x, ref):
-        if x.shape == ref.shape:
-            return x
-        row = tuple(ref.shape[1:])
-        perm = _lanes_last(row)
-        if perm is None:
-            return x.reshape(ref.shape)
-        x = x.reshape((ref.shape[0],) + tuple(row[a] for a in perm))
-        return jnp.transpose(
-            x, (0,) + tuple(1 + int(a) for a in np.argsort(perm))
-        )
-
     return ring.replace(
-        states=jax.tree_util.tree_map(shaped, ring.states, like.states)
+        states=jax.tree_util.tree_map(
+            lambda x, ref: _rows_shaped(x, tuple(ref.shape[1:])),
+            ring.states, like.states,
+        )
     )
+
+
+# A ROLLOUT's branch ring is not a ring. It is born empty, saved ``depth``
+# times in a row and read by one reader (``fused.py``
+# ``absorb_branch_frames``), so every row is written exactly once and at a
+# step every lane shares; only the rotation ``start_frame % depth`` would
+# differ from lane to lane. Written at ``frame % depth`` under the slot
+# ``vmap`` each of the ``depth`` saves was the select form above over the
+# WHOLE ``[S, B, depth, ...]`` leaf (7.3 ms of the churning title's 30.9 ms
+# dispatch in one operation, at 80 % of the memory peak; ``PERF.md``
+# section 6, PR 45). So its rows stand in STEP order: row ``t`` holds the
+# state entering frame ``start_frame + t``, the loop hands each out at its
+# own counter (:func:`state_row`, a scan's ``ys``: ``[depth, S, B, n]``, a
+# step's rows one contiguous slice), and the reader, who knows the start
+# frame, looks a frame up at ``frame - start_frame``. Not
+# ``ring_row_write(stack, row, t)`` into a carried ``[S, B, depth, n]``
+# buffer, lane-uniform as that index is: ``depth`` then lies in the
+# sublanes of a tile and the one-row update is a strided write, 12.3 ms a
+# dispatch for the same leaf (39.6 ms the dispatch; same section).
+
+
+def state_row(state: WorldState) -> WorldState:
+    """``state`` as one row of a ring that holds its large rows flat (see
+    ``FLAT_ROW_BYTES``)."""
+    return jax.tree_util.tree_map(lambda x: _row_flat(x, 0), state)
+
+
+def ring_of_steps(
+    rows: WorldState,  # [depth] :func:`state_row`s, stacked in step order
+    like: WorldState,  # one state: the shapes the rows go back to
+    start_frame: jnp.ndarray,
+    checksums: jnp.ndarray,  # uint32[depth, 2]
+) -> SnapshotRing:
+    """The branch ring of a rollout: row ``t`` is the state that entered
+    frame ``start_frame + t``, with its checksum; ``frames`` says so."""
+    depth = checksums.shape[0]
+    ring_row_lowerings["step"] += len(jax.tree_util.tree_leaves(rows))
+    return SnapshotRing(
+        states=jax.tree_util.tree_map(
+            lambda x, ref: _rows_shaped(x, tuple(ref.shape)), rows, like
+        ),
+        frames=jnp.asarray(start_frame, jnp.int32)
+        + jnp.arange(depth, dtype=jnp.int32),
+        checksums=checksums,
+    )
+
+
+def ring_step_load(
+    ring: SnapshotRing, frame: jnp.ndarray, start_frame: jnp.ndarray
+) -> Tuple[WorldState, jnp.ndarray]:
+    """``(state, checksum)`` a rollout from ``start_frame`` saved for
+    ``frame`` (:func:`ring_of_steps`). The caller knows the frame to lie
+    inside the rollout; one past its end reads the last row (the index
+    clamps) and is selected away."""
+    step = jnp.asarray(frame, jnp.int32) - jnp.asarray(start_frame, jnp.int32)
+    read = lambda r: ring_row_read(r, step)
+    return jax.tree_util.tree_map(read, ring.states), read(ring.checksums)
 
 
 def ring_load(ring: SnapshotRing, frame: jnp.ndarray) -> WorldState:

@@ -1,13 +1,13 @@
 """The serial burst runs as many steps as its masks ask for, not its padded
 length (``rollout.py`` ``live_steps`` / ``rollout_burst(n_run=...)``).
 
-- The trimmed loop equals the full-length masked scan bit for bit (ring,
+- The trimmed loop equals the full-length masked loop bit for bit (ring,
   state, checksums) for every burst length ``0..max_frames``, for box_game
   and boids (the XLA force), for a spectator's burst (advance, no save) and
   for a caller's masks with holes in them.
 - Under ``jax.vmap`` with lanes asking different counts the group runs the
   deepest lane's and every lane's outputs are its own; an idle lane beside
-  live ones; the whole batched tick against the parent's program (the scan).
+  live ones; the whole batched tick against the full-length program.
 - Structure: the batched tick's one ``while`` has an unbatched predicate
   (two scalars compared, no reduction over lanes, so no per-lane select of
   the carry), and one executable serves every burst length.
@@ -52,10 +52,12 @@ PLANES = [
 
 @functools.lru_cache(maxsize=None)
 def title(name):
-    """``(schedule, jitted full scan, jitted trimmed loop)`` of a title."""
+    """``(schedule, jitted full-length loop, jitted trimmed loop)`` of a
+    title: all ``MF`` padded steps with the masks deciding, and the live
+    prefix alone."""
     sched = (box_game.make_schedule() if name == "box_game"
              else boids.make_schedule(kernel="xla"))
-    full = jax.jit(functools.partial(rollout_burst, sched))
+    full = jax.jit(lambda *a: rollout_burst(sched, *a, n_run=jnp.int32(MF)))
     trimmed = jax.jit(lambda *a: rollout_burst(
         sched, *a, n_run=live_steps(a[-2], a[-1])))
     return sched, full, trimmed
@@ -76,7 +78,7 @@ def burst_args(name, seed, save, adv):
     for f in range(start - 3, start):
         ring, state, _ = rollout_burst(
             sched, ring, state, f, bits[:1], status[:1],
-            jnp.ones(1, bool), jnp.ones(1, bool))
+            jnp.ones(1, bool), jnp.ones(1, bool), n_run=1)
     return (ring, state, jnp.int32(start), jnp.asarray(bits),
             jnp.asarray(status), jnp.asarray(save, bool),
             jnp.asarray(adv, bool))
@@ -183,7 +185,7 @@ def test_lanes_run_the_deepest_and_keep_their_own(name, counts):
 def test_batched_tick_is_the_parents_program(seed, monkeypatch):
     """The [S]-vmapped packed tick (a miss, a partial hit, a full hit and an
     idle lane: bursts of 4, 2, 0, 0 steps) against ``_tick_impl`` with the
-    burst as the scan it was."""
+    burst at its full padded length."""
     rng = np.random.default_rng(seed)
     sched = box_game.make_schedule()
     trees, ints, bits, bb = packed.lane_arguments(rng)
@@ -199,7 +201,8 @@ def test_batched_tick_is_the_parents_program(seed, monkeypatch):
         front.append(single.run_front(
             single.pack(*lane(trees, i)), ints[i].copy(), bits[i, :n],
             TickInts.status(ints[i], packed.BURST, P)[:n], bb[i]))
-    monkeypatch.setattr(fused, "live_steps", lambda *a: None)
+    monkeypatch.setattr(
+        fused, "live_steps", lambda save, *a: jnp.int32(save.shape[0]))
     want = [packed.direct_tick(sched, lane(trees, i), ints[i], bits[i], bb[i])
             for i in range(packed.LANES)]
     packed.assert_tick_equal(batched, got, stack(want))

@@ -24,6 +24,7 @@ from bevy_ggrs_tpu.fused import (
     absorb_branch_frames,
 )
 from bevy_ggrs_tpu.models import box_game
+from bevy_ggrs_tpu.rollout import rollout_steps
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.serve.batch import BatchedTickExecutor
 from bevy_ggrs_tpu.state import (
@@ -35,6 +36,7 @@ from bevy_ggrs_tpu.state import (
     ring_row_read,
     ring_row_write,
     ring_save,
+    ring_step_load,
 )
 
 P = 2
@@ -472,15 +474,17 @@ def test_special_floats_survive_save_load_absorb_bit_for_bit():
         for i in range(LANES)
     ])
     want = bits_of(states)
-    frames = i32([4, 5, 11, 2])  # rows 4, 0, 1, 2 of the spec ring below
+    frames = i32([4, 5, 11, 2])  # rows 4, 0, 1, 2 of the main ring below
     empty = stack([ring_init(state, DEPTH)] * LANES)
 
-    # save into a branch ring, load it back
-    spec_rings, _ = jax.jit(jax.vmap(ring_save))(
-        stack([ring_init(state, SPEC)] * LANES), states, frames
-    )
-    for x, y in zip(bits_of(jax.jit(jax.vmap(ring_load))(spec_rings, frames)),
-                    want):
+    # a rollout from each lane's frame saves the state that entered it (row
+    # 0 of a branch ring, whatever ``frame % SPEC`` is); load it back
+    rollout = lambda st, f: rollout_steps(  # noqa: E731
+        plain_schedule(), st, f, jnp.zeros((SPEC, P), jnp.uint8),
+        jnp.zeros((SPEC, P), jnp.int32))[0]
+    spec_rings = jax.jit(jax.vmap(rollout))(states, frames)
+    loaded, _ = jax.jit(jax.vmap(ring_step_load))(spec_rings, frames, frames)
+    for x, y in zip(bits_of(loaded), want):
         np.testing.assert_array_equal(x, y)
 
     # absorb that one frame into the main ring, load it from there
@@ -504,7 +508,7 @@ def test_whole_tick_vmapped_matches_per_lane(seed):
     rng = np.random.default_rng(seed)
     sched = box_game.make_schedule()
     state = box_game.make_world(P).commit()
-    tick = functools.partial(FusedTickExecutor._tick_impl, sched, BURST, SPEC)
+    tick = functools.partial(FusedTickExecutor._tick_impl, sched, BURST)
     rings = stack([random_ring(rng, state, DEPTH) for _ in range(LANES)])
     states = stack([state] * LANES)
     prev_rings = stack([

@@ -170,7 +170,7 @@ def direct_tick(sched, trees, ints, bits, bb):
     T = TickInts
     mask = jnp.asarray(np.arange(BURST) < ints[T.N_BURST])
     tick = jax.jit(functools.partial(
-        FusedTickExecutor._tick_impl, sched, BURST, SPEC))
+        FusedTickExecutor._tick_impl, sched, BURST))
     return tick(
         *trees, *(jnp.int32(ints[k]) for k in (
             T.BRANCH, T.ABSORB_FIRST, T.ABSORB_N, T.PREV_ANCHOR, T.PREV_TOTAL)),

@@ -81,6 +81,9 @@ def test_match_server_hosts_boids(kernel, frames, branches):
     # boids-64's rows are under the threshold: its bursts stay shaped.
     assert metrics.counters['ring_row_lowering{kind="shaped"}'] > 0
     assert 'ring_row_lowering{kind="flat"}' not in metrics.counters
+    # ... and its rollouts write every leaf of the branch ring in step order.
+    assert metrics.counters['ring_row_lowering{kind="step"}'] == len(
+        jax.tree_util.tree_leaves(world))
     assert metrics.series["serve_carry_bytes"] and all(
         v > 0 for v in metrics.series["serve_carry_bytes"])
     assert set(metrics.series["tick_stage_bytes"]) == {
@@ -242,7 +245,7 @@ def test_a_burst_with_flat_rows_is_bitwise_the_shaped_burst(lanes, monkeypatch):
     mask = jnp.asarray(rng.integers(0, 2, size=lead + (frames,)).astype(bool))
 
     def burst(*args):
-        return rollout_burst(Schedule([_drift]), *args)
+        return rollout_burst(Schedule([_drift]), *args, n_run=frames)
 
     wrap = (lambda f: jax.jit(jax.vmap(f))) if lanes else jax.jit
     args = (ring, state, start, bits, status, mask, mask)
@@ -250,7 +253,7 @@ def test_a_burst_with_flat_rows_is_bitwise_the_shaped_burst(lanes, monkeypatch):
     flat = wrap(burst)(*args)
     traced = {k: v - before[k]
               for k, v in state_mod.ring_row_lowerings.items()}
-    assert traced == {"flat": 1, "shaped": len(
+    assert traced == {"flat": 1, "step": 0, "shaped": len(
         jax.tree_util.tree_leaves(ring.states)) - 1}
     monkeypatch.setattr(state_mod, "FLAT_ROW_BYTES", 1 << 40)
     shaped = wrap(burst)(*args)

@@ -107,14 +107,32 @@ class TestSpeculativeExecutor:
             np.asarray(ring.frames), np.arange(F, dtype=np.int32)
         )
 
-    def test_merge_rings_overlays_saved_slots(self):
+    @pytest.mark.parametrize("start", [0, F + 3])
+    def test_merge_rings_overlays_saved_slots(self, start):
+        """A branch ring's rows stand in step order; the merge puts each
+        where its ``frames`` label says, ``frame % depth``."""
         schedule, state, bits = setup()
         ex = SpeculativeExecutor(schedule, B, F)
-        result = ex.run(state, 0, bits)
+        result = ex.run(state, start, bits)
         ring, _ = ex.commit(result, 1)
+        np.testing.assert_array_equal(
+            np.asarray(ring.frames), start + np.arange(F))
         main = ring_init(state, F)
         merged = merge_rings(main, ring)
-        np.testing.assert_array_equal(np.asarray(merged.frames), np.asarray(ring.frames))
+        at = (start + np.arange(F)) % F
+        np.testing.assert_array_equal(
+            np.asarray(merged.frames)[at], np.asarray(ring.frames))
+        np.testing.assert_array_equal(
+            np.asarray(merged.checksums)[at], np.asarray(ring.checksums))
+        for m, r in zip(jax.tree_util.tree_leaves(merged.states),
+                        jax.tree_util.tree_leaves(ring.states)):
+            np.testing.assert_array_equal(np.asarray(m)[at], np.asarray(r))
+        # rows the branch ring never saved keep the main ring's history
+        unsaved = ring.replace(frames=ring.frames.at[0].set(-1))
+        kept = merge_rings(main, unsaved)
+        assert int(kept.frames[start % F]) == -1
+        np.testing.assert_array_equal(
+            np.asarray(kept.frames)[at[1:]], np.asarray(ring.frames)[1:])
 
     def test_speculation_covers_confirmed_path(self):
         """The whole point: when confirmed inputs match a branch, committing
