@@ -55,7 +55,12 @@ import numpy as np
 
 from bevy_ggrs_tpu.obs.trace import null_span
 from bevy_ggrs_tpu.parallel.speculate import match_branch
-from bevy_ggrs_tpu.rollout import live_steps, rollout_burst, rollout_steps
+from bevy_ggrs_tpu.rollout import (
+    deepest_lane,
+    live_steps,
+    rollout_burst,
+    rollout_steps,
+)
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.state import (
     OWN_BUFFER_BYTES,
@@ -65,12 +70,14 @@ from bevy_ggrs_tpu.state import (
     ring_load,
     ring_put,
     ring_row_read,
+    ring_row_write,
     ring_step_load,
 )
 
 # The name of the axis a tick is vmapped over, where it is (the served
-# ``[S]`` slots, the conformance mode's copies): the burst's trip count is
-# reduced over it (``rollout.py`` ``live_steps``).
+# ``[S]`` slots, the conformance mode's copies): the trip counts of the
+# absorb and of the burst are reduced over it (``rollout.py``
+# ``deepest_lane``).
 LANE_AXIS = "lane"
 
 
@@ -109,6 +116,7 @@ def absorb_branch_frames(
     anchor: jnp.ndarray,  # spec rollout start frame
     total_spec: jnp.ndarray,  # frames the spec rollout simulated in total
     max_steps: int,
+    n_run: jnp.ndarray,  # int32[], ``deepest_lane(n_frames, ...)``
 ) -> Tuple[SnapshotRing, WorldState, jnp.ndarray]:
     """Copy frames ``first_frame .. first_frame+n_frames-1`` from the
     branch ring into the main ring and return (ring, state-at-end,
@@ -116,17 +124,26 @@ def absorb_branch_frames(
     branch ring's NEXT slot (state entering frame f is saved at f) or the
     rollout's final state when the replay consumed the whole rollout.
     ``n_frames == 0`` leaves the ring untouched (the returned state is then
-    meaningless — callers select it away)."""
+    meaningless — callers select it away).
 
-    def body(ring, t):
+    The loop runs ``n_run`` steps, as the burst does (``rollout.py``
+    ``rollout_burst``): ``n_frames`` for one lane, the deepest lane's under
+    the slot ``vmap``, where a shallower lane's later steps are the
+    identity. ``n_run == 0`` runs no step: the main ring comes back as
+    it went in, untouched by any operation."""
+
+    def step(t, loop):
+        ring, checksums = loop
         f = first_frame + t
         valid = t < n_frames
         saved, cs = ring_step_load(spec_ring, f, anchor)
         ring = ring_put(ring, saved, f, cs, valid)
-        return ring, jnp.where(valid, cs, jnp.uint32(0))
+        cs = jnp.where(valid, cs, jnp.uint32(0))
+        return ring, ring_row_write(checksums, cs, t)
 
-    main_ring, checksums = jax.lax.scan(
-        body, main_ring, jnp.arange(max_steps, dtype=jnp.int32)
+    main_ring, checksums = jax.lax.fori_loop(
+        0, n_run, step,
+        (main_ring, jnp.zeros((max_steps, 2), jnp.uint32)),
     )
     end = first_frame + n_frames  # frame entered after the replay
     # State entering `end`: saved in the branch ring unless the replay ran
@@ -388,8 +405,9 @@ class PackedTick:
     are ``unpack -> _tick_impl / _absorb_impl -> pack``, written for ONE
     session: the server vmaps them over ``[S]`` (the codec keeps leading
     axes) and says so with ``lane_axis``, the name it gave that ``vmap``'s
-    axis: the burst then runs as many steps as the deepest lane of the
-    dispatch asks for. The live state is also returned as a ``WorldState``,
+    axis: the absorb and the burst then each run as many steps as the
+    deepest lane of the dispatch asks for. The live state is also returned
+    as a ``WorldState``,
     so reading it needs no further dispatch. ``per_leaf`` (a mesh: layouts
     are per leaf) packs nothing, through the same code."""
 
@@ -488,6 +506,7 @@ class PackedTick:
             self.burst_frames, ring, prev_rings, prev_states,
             ints[T.BRANCH], ints[T.ABSORB_FIRST], ints[T.ABSORB_N],
             ints[T.PREV_ANCHOR], ints[T.PREV_TOTAL],
+            lane_axis=self.lane_axis,
         )
         return (
             self.carry.pack((ring, state, prev_rings, prev_states)),
@@ -649,7 +668,7 @@ class FusedTickExecutor:
     def _absorb_impl(
         burst_frames,
         ring, prev_rings, prev_states, branch,
-        absorb_first, absorb_n, prev_anchor, prev_total,
+        absorb_first, absorb_n, prev_anchor, prev_total, lane_axis=None,
     ):
         """Absorb-only program for FULL speculation hits: commit the
         matched branch's precomputed frames into the main ring — pure
@@ -661,14 +680,34 @@ class FusedTickExecutor:
         long (:meth:`_front_impl`: absorb + burst in a program of their
         own, the rollout dispatched behind it); where the rollout is
         shorter than one more call, the one fused program stays the
-        cheaper way to the state."""
-        sel = lambda x: ring_row_read(x, branch)
-        spec_ring_b = jax.tree_util.tree_map(sel, prev_rings)
-        spec_state_b = jax.tree_util.tree_map(sel, prev_states)
-        return absorb_branch_frames(
-            ring, spec_ring_b, spec_state_b, absorb_first, absorb_n,
-            prev_anchor, prev_total, max_steps=burst_frames,
-        )
+        cheaper way to the state.
+
+        As the phase of a tick it costs what the deepest lane commits
+        (``lane_axis``: the slot ``vmap``'s axis): the whole phase stands
+        inside one conditional on that scalar. Where somebody commits, the
+        matched branch is read out of ``[B, ...]`` and the copy loop runs
+        the deepest lane's steps; a dispatch in which nobody does passes
+        the ring on and reads nothing of the previous rollout. (Measured
+        beside the narrower form, the read alone inside the conditional
+        and the loop at a trip count of 0 behind it: ``PERF.md`` section 6,
+        PR 46.)"""
+        n_run = deepest_lane(absorb_n, lane_axis)
+
+        def commit():
+            sel = lambda x: ring_row_read(x, branch)
+            rows, final = jax.tree_util.tree_map(sel, (prev_rings, prev_states))
+            return absorb_branch_frames(
+                ring, rows, final, absorb_first, absorb_n,
+                prev_anchor, prev_total, max_steps=burst_frames, n_run=n_run,
+            )
+
+        def skip():  # the state is meaningless, as an empty absorb's is
+            state = jax.tree_util.tree_map(
+                lambda x: jnp.zeros(x.shape[1:], x.dtype), prev_states
+            )
+            return ring, state, jnp.zeros((burst_frames, 2), jnp.uint32)
+
+        return jax.lax.cond(n_run > 0, commit, skip)
 
     @staticmethod
     def _front_impl(
@@ -685,14 +724,13 @@ class FusedTickExecutor:
         time moves with the Python frames under that trace: ``PERF.md``
         section 7); ``tests/test_split_tick.py`` holds the two to the same
         bits."""
-        ring_a, state_a, absorb_cs = FusedTickExecutor._absorb_impl(
+        ring, state_a, absorb_cs = FusedTickExecutor._absorb_impl(
             burst_frames, ring, prev_rings, prev_states, branch,
-            absorb_first, absorb_n, prev_anchor, prev_total,
+            absorb_first, absorb_n, prev_anchor, prev_total, lane_axis,
         )
-        do_absorb = absorb_n > 0
-        keep = lambda a, b: jnp.where(do_absorb, a, b)
-        ring = jax.tree_util.tree_map(keep, ring_a, ring)
-        state = jax.tree_util.tree_map(keep, state_a, state)
+        state = jax.tree_util.tree_map(
+            lambda a, s: jnp.where(absorb_n > 0, a, s), state_a, state
+        )
         loaded = ring_load(ring, load_frame)
         state = jax.tree_util.tree_map(
             lambda l, s: jnp.where(do_load, l, s), loaded, state
@@ -720,15 +758,15 @@ class FusedTickExecutor:
         lane_axis=None,
     ):
         # Phase 1 — absorb the matched branch's precomputed frames
-        # (speculation hit). absorb_n == 0 leaves ring/state untouched.
-        ring_a, state_a, absorb_cs = FusedTickExecutor._absorb_impl(
+        # (speculation hit). absorb_n == 0 leaves the ring as it is (the
+        # absorb's own ``valid``) and the state by the select below.
+        ring, state_a, absorb_cs = FusedTickExecutor._absorb_impl(
             burst_frames, ring, prev_rings, prev_states, branch,
-            absorb_first, absorb_n, prev_anchor, prev_total,
+            absorb_first, absorb_n, prev_anchor, prev_total, lane_axis,
         )
-        do_absorb = absorb_n > 0
-        keep = lambda a, b: jnp.where(do_absorb, a, b)
-        ring = jax.tree_util.tree_map(keep, ring_a, ring)
-        state = jax.tree_util.tree_map(keep, state_a, state)
+        state = jax.tree_util.tree_map(
+            lambda a, s: jnp.where(absorb_n > 0, a, s), state_a, state
+        )
 
         # Phase 2 — the serial burst: rollback resimulation (do_load), the
         # unmatched tail after a partial absorb, or the steady advance; as

@@ -46,20 +46,29 @@ from bevy_ggrs_tpu.state import (
 )
 
 
+def deepest_lane(
+    n: jnp.ndarray, lane_axis: Optional[str] = None
+) -> jnp.ndarray:
+    """The trip count of a loop whose lane asks for ``n`` steps: ``n``
+    itself, and under a ``vmap`` whose axis is named ``lane_axis`` the
+    deepest lane's, ONE scalar for the dispatch: a count that differs per
+    lane would batch the loop's predicate, and the loop would then run to
+    the deepest lane all the same and select its whole carry, rings
+    included, a step."""
+    return n if lane_axis is None else jax.lax.pmax(n, lane_axis)
+
+
 def live_steps(
     save_mask: jnp.ndarray,  # bool[max_frames]
     adv_mask: jnp.ndarray,  # bool[max_frames]
     lane_axis: Optional[str] = None,
 ) -> jnp.ndarray:
     """One past the last step at which either mask is set: what a burst has
-    to run, every later step being padding. Under a ``vmap`` whose axis is
-    named ``lane_axis`` the answer is the deepest lane's, ONE scalar for
-    the dispatch: a count that differs per lane would batch the loop's
-    predicate, and the loop would then run to the deepest lane all the same
-    and select its whole carry, rings included, a step."""
+    to run, every later step being padding (:func:`deepest_lane`'s under
+    the slot ``vmap``)."""
     steps = jnp.arange(1, save_mask.shape[0] + 1, dtype=jnp.int32)
     n = jnp.max(jnp.where(save_mask | adv_mask, steps, 0))
-    return n if lane_axis is None else jax.lax.pmax(n, lane_axis)
+    return deepest_lane(n, lane_axis)
 
 
 def rollout_burst(

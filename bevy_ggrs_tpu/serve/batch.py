@@ -508,6 +508,13 @@ class BatchedSessionCore(Instrumented):
         # tick that is not padding.
         self.burst_steps_total = 0
         self.burst_step_slots_total = 0
+        # The absorb phase's pair, counted alike (``fused.py``
+        # ``_absorb_impl`` runs the deepest lane's ``ABSORB_N`` copy steps
+        # for every lane): frames the lanes committed from their rollouts
+        # (``absorb_steps_total``, = ``rollback_frames_recovered_total``)
+        # and lane-steps run. Both stay 0 in a group that never hits.
+        self.absorb_steps_total = 0
+        self.absorb_step_slots_total = 0
         self.spec_hits = 0
         self.spec_partial_hits = 0
         self.spec_misses = 0
@@ -1095,9 +1102,7 @@ class BatchedSessionCore(Instrumented):
         :meth:`_post_dispatch`'s arguments. Few locals ON PURPOSE: the
         first call's trace runs under this frame (``PERF.md`` §7)."""
         self.device_dispatches_total += 1
-        self.burst_step_slots_total += self.num_slots * int(
-            jit_args[0][:, TickInts.N_BURST].max()
-        )
+        self._count_lane_steps(jit_args[0])
         with self.span("serve_dispatch"):
             self._carry, self._states, cs = self._exec.run(
                 self._carry, *jit_args
@@ -1106,6 +1111,18 @@ class BatchedSessionCore(Instrumented):
         self.metrics.observe("tick_io_buffers", self._exec.io.last)
         self.metrics.observe("tick_stage_bytes", self._exec.io.staged_bytes)
         return cs, post, reports
+
+    def _count_lane_steps(self, ints: np.ndarray) -> None:
+        """What the dispatch's two loops run for ``ints`` (the
+        :class:`TickInts` rows it is handed): ``num_slots x`` the deepest
+        lane's burst, and the same of its absorb."""
+        self.burst_step_slots_total += self.num_slots * int(
+            ints[:, TickInts.N_BURST].max()
+        )
+        steps = self.num_slots * int(ints[:, TickInts.ABSORB_N].max())
+        if steps:
+            self.absorb_step_slots_total += steps
+            self.metrics.count("absorb_step_slots_total", steps)
 
     def _post_dispatch(
         self, cs, post: Dict[int, tuple], reports: List[tuple]
@@ -1140,6 +1157,9 @@ class BatchedSessionCore(Instrumented):
                 else:
                     s.res_anchor, s.res_bits = None, None
                 self.burst_steps_total += n_steps
+                if n_commit:
+                    self.absorb_steps_total += n_commit
+                    self.metrics.count("absorb_steps_total", n_commit)
                 self.metrics.count("frames_advanced", n_steps)
                 self.metrics.count(
                     "frames_advanced", n_steps, labels={"match_slot": i}

@@ -114,7 +114,7 @@ def _absorb(
     fused tick inlines the identical body as its phase 1."""
     return absorb_branch_frames(
         main_ring, spec_ring, spec_states, first_frame, n_frames, anchor,
-        total_spec, max_steps,
+        total_spec, max_steps, n_run=n_frames,
     )
 
 

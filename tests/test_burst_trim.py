@@ -8,9 +8,10 @@ length (``rollout.py`` ``live_steps`` / ``rollout_burst(n_run=...)``).
 - Under ``jax.vmap`` with lanes asking different counts the group runs the
   deepest lane's and every lane's outputs are its own; an idle lane beside
   live ones; the whole batched tick against the full-length program.
-- Structure: the batched tick's one ``while`` has an unbatched predicate
-  (two scalars compared, no reduction over lanes, so no per-lane select of
-  the carry), and one executable serves every burst length.
+- Structure: the batched tick's ``while``s (the absorb's copy loop and the
+  burst) have an unbatched predicate (two scalars compared, no reduction
+  over lanes, so no per-lane select of the carry), and one executable
+  serves every burst length.
 - Counters: ``burst_step_slots_total`` adds ``num_slots x`` the deepest
   lane's burst, alike on both host paths.
 """
@@ -217,15 +218,33 @@ def test_batched_tick_is_the_parents_program(seed, monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def whiles(jaxpr):
-    """Every ``while`` equation of a jaxpr, nested ones included."""
+def equations(jaxpr, primitive):
+    """Every equation of a jaxpr with that primitive, nested ones included."""
     found = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "while":
+        if eqn.primitive.name == primitive:
             found.append(eqn)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found.extend(whiles(sub))
+            found.extend(equations(sub, primitive))
     return found
+
+
+def whiles(jaxpr):
+    return equations(jaxpr, "while")
+
+
+def assert_scalar_trip_count(loop, lanes):
+    """``i < n_run`` on two scalars. A trip count that differed per lane
+    would make the predicate ``[S]``: the loop would then run while any
+    lane asks and select its whole carry by lane."""
+    cond = loop.params["cond_jaxpr"].jaxpr
+    (lt,) = cond.eqns
+    assert lt.primitive.name == "lt"
+    assert [v.aval.shape for v in lt.invars] == [(), ()]
+    assert [v.aval.shape for v in cond.outvars] == [()]
+    body = loop.params["body_jaxpr"].jaxpr
+    batched_carry = [v for v in body.outvars if v.aval.shape[:1] == (lanes,)]
+    assert len(batched_carry) > 10      # rings, (state) and checksums by lane
 
 
 def test_batched_burst_loop_has_one_scalar_trip_count():
@@ -236,22 +255,12 @@ def test_batched_burst_loop_has_one_scalar_trip_count():
     carry = batched.pack(*trees)
     jaxpr = jax.make_jaxpr(batched._fn)(carry, ints, bits, bb).jaxpr
     loops = whiles(jaxpr)
-    # the absorb scan and the rollout are ``scan``s: the one ``while`` is
-    # the burst
-    assert len(loops) == 1
-    (loop,) = loops
-    cond = loop.params["cond_jaxpr"].jaxpr
-    # ``i < n_run`` on two scalars. A trip count that differed per lane
-    # would make the predicate ``[S]``: the loop would then run while any
-    # lane asks and select its whole carry by lane.
-    (lt,) = cond.eqns
-    assert lt.primitive.name == "lt"
-    assert [v.aval.shape for v in lt.invars] == [(), ()]
-    assert [v.aval.shape for v in cond.outvars] == [()]
-    body = loop.params["body_jaxpr"].jaxpr
+    # the rollout is a ``scan``: the two ``while``s are the absorb's copy
+    # loop (``tests/test_absorb_trim.py``) and, behind it, the burst
+    assert len(loops) == 2
     S = packed.LANES
-    batched_carry = [v for v in body.outvars if v.aval.shape[:1] == (S,)]
-    assert len(batched_carry) > 10      # rings, state and checksums by lane
+    for loop in loops:
+        assert_scalar_trip_count(loop, S)
     # the count itself came from a reduction over the lanes, in the program
     flat = str(jaxpr)
     assert "reduce_max" in flat

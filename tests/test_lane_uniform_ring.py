@@ -387,7 +387,7 @@ def test_ring_save_and_load_match_per_lane(frames):
 def _absorb(ring, spec_ring, spec_state, first, n, anchor, total):
     return absorb_branch_frames(
         ring, spec_ring, spec_state, first, n, anchor, total,
-        max_steps=BURST,
+        max_steps=BURST, n_run=n,
     )
 
 

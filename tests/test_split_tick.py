@@ -168,10 +168,12 @@ def test_split_and_fused_runners_hold_the_same_bits(monkeypatch, title, ticks):
     fused = make_runner(monkeypatch, title, FUSES)
     assert split._split and not fused._split
     assert fused._fused._front is None  # a fused runner builds nothing new
-    fronts, wholes, absorbs = [], [], []
+    fronts, wholes, absorbs, fused_absorbs = [], [], [], []
     spy(split._fused, "run_front", fronts)
     spy(split._fused, "commit_absorb", absorbs)
     spy(fused._fused, "run", wholes)
+    spy(fused._fused, "commit_absorb", fused_absorbs)
+    depths = set()  # of the full hits, and of the partial ones
     peers = Peer(11, ticks), Peer(11, ticks)
     built = xla_cache.compile_counters()["backend_compiles"]
     # from here on (the first runner of a process attests, the second finds
@@ -200,9 +202,18 @@ def test_split_and_fused_runners_hold_the_same_bits(monkeypatch, title, ticks):
                 split._fused.cs_host(split._spec_cs)[2], spec_cs)
             assert split.state is fronts[-1][1]
             shapes["split"] += 1
+            # The front's copy loop ran the rows it reports and no more.
+            depths.add(("front", int(np.asarray(absorb_cs).any(axis=1).sum())))
         elif len(absorbs) > n_absorb:
             assert programs == 1  # a full hit: the absorb-only program
             shapes["absorb"] += 1
+            # Both runners took it, to the same carry, state and checksums;
+            # the copy loop ran the hit's depth: the rows behind it are 0.
+            assert same_bits(absorbs[-1], fused_absorbs[-1])
+            rows = np.asarray(absorbs[-1][2]).any(axis=1)
+            depth = int(rows.sum())
+            assert depth > 0 and rows[:depth].all()
+            depths.add(("absorb", depth))
         else:
             shapes["other"] += 1  # a tick both runners took serially
     for name in ("rollbacks_total", "spec_hits", "spec_partial_hits",
@@ -216,6 +227,12 @@ def test_split_and_fused_runners_hold_the_same_bits(monkeypatch, title, ticks):
     # the run saw every kind of tick the issue names
     assert min(split.spec_hits, split.spec_partial_hits, split.spec_misses) > 0
     assert shapes["split"] > ticks // 2 and shapes["absorb"] > 0
+    # hits of more than one depth, no commit at all among the split ticks,
+    # and one executable each for all of them
+    assert len({d for kind, d in depths if kind == "absorb"}) > 1
+    assert ("front", 0) in depths and len(depths) > 3
+    assert split._fused._absorb._cache_size() == 1
+    assert split._fused._front._cache_size() == 1
     for runner, peer in zip((split, fused), peers):
         runner.flush_reports(peer)
     assert peers[0].reports == peers[1].reports and peers[0].reports

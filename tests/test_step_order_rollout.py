@@ -27,9 +27,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bevy_ggrs_tpu.fused import FusedTickExecutor, absorb_branch_frames
+from bevy_ggrs_tpu.fused import (
+    LANE_AXIS,
+    FusedTickExecutor,
+    absorb_branch_frames,
+)
 from bevy_ggrs_tpu.models import box_game, particles
-from bevy_ggrs_tpu.rollout import rollout_steps
+from bevy_ggrs_tpu.rollout import deepest_lane, rollout_steps
 from bevy_ggrs_tpu.schedule import PREDICTED, PlayerInputs
 from bevy_ggrs_tpu.serve.batch import BatchedTickExecutor
 from bevy_ggrs_tpu.state import (
@@ -148,9 +152,11 @@ def test_rollout_under_slot_and_branch_vmaps_matches_serial(anchors):
 # ---------------------------------------------------------------------------
 
 
-def _absorb(ring, spec_ring, spec_state, first, n, anchor, total):
+def _absorb(ring, spec_ring, spec_state, first, n, anchor, total,
+            lane_axis=None):
     return absorb_branch_frames(
-        ring, spec_ring, spec_state, first, n, anchor, total, max_steps=BURST)
+        ring, spec_ring, spec_state, first, n, anchor, total, max_steps=BURST,
+        n_run=deepest_lane(n, lane_axis))
 
 
 # (anchor % F, first_frame - anchor, n_frames): every replay that stays
@@ -193,19 +199,32 @@ def test_absorb_finds_every_frame_of_the_rollout(r, d, n):
     assert_absorbed(jax.jit(_absorb)(*args), want, n)
 
 
+# The copy loop's trip count under a ``vmap``: a count a lane (the loop's
+# predicate batched), or the deepest lane's, one scalar, as the served tick
+# asks for it (``rollout.py`` ``deepest_lane``).
+TRIP_COUNTS = [None, LANE_AXIS]
+
+
+def _absorb_lanes(lane_axis):
+    return jax.jit(jax.vmap(
+        functools.partial(_absorb, lane_axis=lane_axis), axis_name=LANE_AXIS))
+
+
+@pytest.mark.parametrize("lane_axis", TRIP_COUNTS, ids=["per_lane", "deepest"])
 @pytest.mark.parametrize("r", range(F))
-def test_absorb_vmapped_over_every_offset_and_depth(r):
+def test_absorb_vmapped_over_every_offset_and_depth(r, lane_axis):
     cases = [c for c in ABSORBS if c[0] == r]
     args = stack([absorb_case(*c)[0] for c in cases])
-    got = jax.jit(jax.vmap(_absorb))(*args)
+    got = _absorb_lanes(lane_axis)(*args)
     for i, c in enumerate(cases):
         assert_absorbed(lane(got, i), absorb_case(*c)[1], c[2])
 
 
-def test_absorb_vmapped_with_another_rotation_in_every_lane():
+@pytest.mark.parametrize("lane_axis", TRIP_COUNTS, ids=["per_lane", "deepest"])
+def test_absorb_vmapped_with_another_rotation_in_every_lane(lane_axis):
     cases = [(0, 0, F), (1, 1, 2), (2, 0, 0), (1, 2, 1)]
     args = stack([absorb_case(*c)[0] for c in cases])
-    got = jax.jit(jax.vmap(_absorb))(*args)
+    got = _absorb_lanes(lane_axis)(*args)
     for i, c in enumerate(cases):
         assert_absorbed(lane(got, i), absorb_case(*c)[1], c[2])
 
@@ -268,8 +287,9 @@ def tick_lane(anchor, branch, n, m, seed):
     return args, (ring, live, pad(cs[:n]), pad(cs[n:]), next_bits)
 
 
-def _tick(*args):
-    return FusedTickExecutor._tick_impl(plain_schedule(), BURST, *args, STATUS)
+def _tick(*args, lane_axis=None):
+    return FusedTickExecutor._tick_impl(
+        plain_schedule(), BURST, *args, STATUS, lane_axis=lane_axis)
 
 
 def assert_tick_is_serial(got, want, anchor, n, m):
@@ -290,9 +310,12 @@ def test_partial_absorb_then_burst_equals_the_serial_replay(case):
     assert_tick_is_serial(jax.jit(_tick)(*args), want, anchor, n, m)
 
 
-def test_partial_absorb_then_burst_vmapped_over_the_slots():
+@pytest.mark.parametrize("lane_axis", TRIP_COUNTS, ids=["per_lane", "deepest"])
+def test_partial_absorb_then_burst_vmapped_over_the_slots(lane_axis):
     lanes = [tick_lane(*c, seed=i) for i, c in enumerate(TICK_LANES)]
-    got = jax.jit(jax.vmap(_tick))(*stack([a for a, _ in lanes]))
+    tick = functools.partial(_tick, lane_axis=lane_axis)
+    got = jax.jit(jax.vmap(tick, axis_name=LANE_AXIS))(
+        *stack([a for a, _ in lanes]))
     for i, (anchor, _, n, m) in enumerate(TICK_LANES):
         assert_tick_is_serial(lane(got, i), lanes[i][1], anchor, n, m)
 
