@@ -1115,12 +1115,15 @@ class BatchedSessionCore(Instrumented):
     def _count_lane_steps(self, ints: np.ndarray) -> None:
         """What the dispatch's two loops run for ``ints`` (the
         :class:`TickInts` rows it is handed): ``num_slots x`` the deepest
-        lane's burst, and the same of its absorb."""
+        lane's burst, and the same of its absorb, whose depth is also the
+        series ``serve_absorb_depth`` (a sample a dispatch, 0 included)."""
         self.burst_step_slots_total += self.num_slots * int(
             ints[:, TickInts.N_BURST].max()
         )
-        steps = self.num_slots * int(ints[:, TickInts.ABSORB_N].max())
-        if steps:
+        depth = int(ints[:, TickInts.ABSORB_N].max())
+        self.metrics.observe("serve_absorb_depth", depth)
+        if depth:
+            steps = self.num_slots * depth
             self.absorb_step_slots_total += steps
             self.metrics.count("absorb_step_slots_total", steps)
 
