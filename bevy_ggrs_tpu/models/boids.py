@@ -148,12 +148,15 @@ def flock_system_mxu(state: WorldState, inputs: PlayerInputs) -> WorldState:
     accumulation (operands split into bf16 terms: two of everything, a
     third of the positions the separation sum cancels), while d2 and the
     membership masks stay f32 so borderline pairs classify identically on
-    all paths. Measured on the v5e through the normal path (1,024 boids,
-    128 branches x 8 frames in one fused tick; my chip run, PR 31,
-    `PERF.md` section 6): the tick 7.5 ms of device time, 6.9 ms of it this
-    kernel, against 15.6 ms through `flock_system_pallas`; one step within
-    5e-6 of a plain float32 NumPy reference (before PR 31's two repairs:
-    6.3 ms and 4.4e-5, more for a close pair). At N >= 4096 the square all-vs-all
+    all paths. Measured on the v5e through the normal path (`boids1k.wan`:
+    1,024 boids, 128 branches x 8 frames a tick, two programs a tick since
+    PR 32; my chip run of 2026-10-02, PR 49, `PERF.md` section 6): a tick
+    6.4 ms of device time, 5.6 ms of it this kernel (7.7 and 7.0 until
+    PR 49 walked the pair block in strips; 15.6 ms a tick through
+    `flock_system_pallas`, PR 31); under `[64] x [8]` in a served dispatch
+    24.4 of 27.2 ms; one step within 5e-6 of a plain float32 NumPy
+    reference (before PR 31's two repairs: 4.4e-5, more for a close
+    pair). At N >= 4096 the square all-vs-all
     shape dispatches to the symmetry-halved triangle kernel
     (:func:`~bevy_ggrs_tpu.ops.pairwise.pairwise_force_square_mxu_tri`;
     not measured on this chip); below that the

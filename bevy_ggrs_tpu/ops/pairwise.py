@@ -17,10 +17,14 @@ bitwise equal: a session must use one path consistently, same as the
 reference's "all peers must share an architecture" float caveat
 (``/root/reference/examples/README.md:13-18``).
 
-Measured on one TPU chip (50-iter mean): N=4096 single flock 1.7-2.5 ms vs
-2.8 ms XLA; the BASELINE config-4 shape (vmap 128 branches × 1024 boids)
-5.9 ms vs 9.8 ms XLA (~1.6×). Default blocks (512 rows × 1024 cols) keep
-all ~8 live [R, C] f32 intermediates within VMEM.
+Three kernels: the VPU kernel above (:func:`pairwise_force_rows_pallas`),
+the MXU kernel the boids configurations run (:func:`pairwise_force_rows_mxu2`:
+the per-row sums as skinny matmuls, the masks on the VPU) and its
+symmetry-halved triangle form for N >= 4,096. What the chip read of them,
+by PR, shape and date, is in each one's docstring; the sizes below are
+chosen by what the vector unit's 64 registers and four ALU slots take, not
+by what fits VMEM (a [512, 1024] float32 block is 512 registers, and VMEM
+holds dozens of them).
 """
 
 from __future__ import annotations
@@ -260,10 +264,21 @@ def _pair_masks(rpx, rpy, cpx, cpy, *, neighbor_radius, separation_radius,
 # 2e-8 * |p| / d (measured: 2e-5 at d = 1e-3 for |p| = 8, 3e-4 at 1e-4),
 # where differencing first costs nothing. So the matmuls carry each weight up
 # to CLOSE_W = 1 / (5e-3) only, and what a closer pair's weight has above it
-# goes through the differenced form on the VPU, in the block steps that hold
-# such a pair (about half of them at 1,024 boids; the step then costs a
-# fifth more, PERF.md section 6, PR 31).
+# goes through the differenced form on the VPU, in the 128-row strips that
+# hold such a pair. That is rare: in the plain reference's replay of the
+# served cell's matches (tools/force_paths.py --count-only, 4 matches x 140
+# frames from the common spawn) 1.3 % of force evaluations and 0.27 % of
+# strips hold one, and none after the flock has spread (PERF.md section 6,
+# PR 49; "about half of the block steps", PR 31, was a guess from the
+# branch's cost, which was its live ranges and not its being taken).
 CLOSE_W = 200.0
+
+# Rows of a pair block whose masks are built, contracted and forgotten at a
+# time: the lane tile of the lane-major row operands and of the accumulators'
+# row axis (a narrower window of either is not tile-aligned) and the MXU's
+# tile. Nobody's setting: the tests pass other heights to show that a force
+# does not depend on it.
+STRIP_ROWS = 128
 
 
 def _close_pair_sums(dx, dy, w, col_active):
@@ -362,14 +377,15 @@ def _force_kernel_mxu2(
     fx_out, fy_out,  # [1, R_BLK]
     acc_n, acc_w,  # VMEM scratch [10, R_BLK] / [8, R_BLK] f32
     acc_c,  # VMEM scratch [2, R_BLK] f32: close pairs' differenced sums
-    rp_s,  # VMEM scratch [R_BLK, 2] f32: transposed row positions cache
+    wmax_s,  # VMEM scratch [R_BLK / strip, C_BLK] f32: a strip's largest weights
+    close_s,  # SMEM scratch [R_BLK / strip] i32: does the strip hold a close pair
     *,
     neighbor_radius: float,
     separation_radius: float,
     w_separation: float,
     w_alignment: float,
     w_cohesion: float,
-    single_col: bool,
+    strip: int,
 ):
     """The VPU kernel's seven per-row accumulators, restated as two skinny
     matmuls so the MXU carries the reduction:
@@ -387,12 +403,51 @@ def _force_kernel_mxu2(
     k≈10 on the 128-lane axis (92% of the MXU idle — measured SLOWER than
     the VPU kernel); feature-major ``F[k, C] · M[R, C] -> [k, R]`` (both
     operands contract their lane axis) pads k to the 8-sublane tile
-    instead (measured round 4: widening the feature stack 10 -> 32 rows
-    costs ~nothing; the kernel is VPU-mask-bound, not MXU-bound). ALL row
-    operands arrive lane-major [1, R_BLK]; the pair-matrix orientation is
-    produced in-kernel by :func:`_tcol` — once per step when
-    ``single_col`` (the transpose result then lives in vregs), else
-    cached in the ``rp_s`` scratch at each row block's first column step.
+    instead, and ``M`` is the operand the MXU holds still (a transposed
+    push of 1.5 registers a float32 register of pairs: the MXU's slots are
+    7 % used). ALL row operands arrive lane-major [1, R_BLK]; the
+    pair-matrix orientation is produced in-kernel by :func:`_tcol`, a
+    strip at a time, on the otherwise idle transpose unit.
+
+    **The pair block is walked in strips of ``strip`` rows** (PR 49;
+    ``STRIP_ROWS`` = 128, one grid step a 1,024-row world). The kernel is
+    bound by the vector ALUs, by the compiler's own listing at the served
+    shape (``[64] x [8] x 1,024``, v5e, libtpu 0.0.34): a register of 1,024
+    pairs takes 26 ALU operations (5 for ``d2``, 5 compares and mask
+    ANDs, 10 for ``rsqrt`` with its Newton step and special cases, 2 for
+    the select and the cap, 1 for the strip's largest weight, 3 for the
+    hi / lo split) in 4 slots a bundle. Written over the whole [512, 1024]
+    block (until PR 49) every one of those operations was an array of 512
+    registers and Mosaic emits an operation at a time: 6 spilled stores a
+    register of pairs, ALU slots 76 % full, 8.75 bundles a register, and
+    three arrays (``dx``, ``dy``, the uncapped ``w``) alive past the
+    matmuls for a branch taken once in a thousand steps. Strip after
+    strip, in straight-line code, each strip's values die before the next
+    begins: 7.25 bundles a register, ALU slots 90 % full. A strip is the
+    lane tile of the row operands (a narrower window of ``trpx`` is not
+    aligned); narrower strips joined before the matmul cost more than
+    they save (a bfloat16 ``concatenate`` is a repacking: 9.5 bundles a
+    register at 16 rows), and as a ``fori_loop`` nothing overlaps a turn
+    (8.4). On the chip (v5e, 2026-10-02; my chip runs, PR 49; PERF.md
+    section 6): a call over ``[64] x [8]`` worlds of 1,024 boids 3.85 ->
+    3.12 ms (7.5 -> 6.1 us a world), the forces bit for bit the same;
+    ``pairwise_kernel_ms.serve`` 30.27 -> 24.39 ms a served dispatch
+    (``boids256.synctest``), ``pairwise_kernel_ms.client`` 3.48 -> 2.78 ms
+    under ``[B = 128]`` (``boids1k.wan``). A grid step costs ~0.75 us
+    beside its bundles (the same strips as two 512-row steps: 6.8 us a
+    world), so a 1,024-row world is one step; where every world holds a
+    close pair a call is 4.74 -> 4.44 ms.
+
+    **Close pairs.** Each strip leaves its largest uncapped weight a
+    column in ``wmax_s``; after the last strip one flag a strip (does it
+    pass ``CLOSE_W``) goes to ``close_s`` in scalar memory, and only where
+    a flag is set does a second loop visit the strips: a strip that holds
+    such a pair builds its ``dx``, ``dy``, ``w`` again (the same
+    operations on the same operands: the same bits) and adds
+    :func:`_close_pair_sums` to its columns of ``acc_c``. A strip without
+    one would have added zeros, so the forces do not depend on the strip
+    height (``tests/test_ops.py``), nor a row's matmul sums on which strip
+    it rode in (``R`` is not the contracted axis).
 
     Precision: the MXU multiplies bf16 and accumulates f32. The neighbor
     mask is 0/1 (exact in bf16); the weight matrix and the features are
@@ -405,52 +460,67 @@ def _force_kernel_mxu2(
     stack carry a third term (:func:`_lane_feats`) and why a pair closer
     than ``1 / CLOSE_W`` leaves the matmul form (:func:`_close_pair_sums`):
     with both, one step stays within a few 1e-6 of a float32 NumPy
-    reference whatever the flock (PR 31). ``d2`` and the membership masks are computed in f32 exactly
-    like the XLA/VPU paths, so borderline pairs classify identically on
-    all three; only summation rounding differs (allclose, not bitwise —
-    the same session contract as the VPU kernel). ``rsqrt(d2)`` is taken
-    without an epsilon clamp: pairs with ``d2 < 1e-10`` are outside
-    ``nb``, so an inf can never be selected into ``w`` — bitwise
-    identical, one fewer [R, C] VPU op."""
+    reference whatever the flock (PR 31). ``d2`` and the membership masks
+    are computed in f32 exactly like the XLA/VPU paths, so borderline
+    pairs classify identically on all three; only summation rounding
+    differs (allclose, not bitwise — the same session contract as the VPU
+    kernel). ``rsqrt(d2)`` is taken without an epsilon clamp: pairs with
+    ``d2 < 1e-10`` are outside ``nb``, so an inf can never be selected
+    into ``w`` — bitwise identical, one fewer [R, C] VPU op."""
     cj = pl.program_id(1)
     n_cols = pl.num_programs(1)
+    n_strips = trpx.shape[1] // strip
 
-    if single_col:
-        # One column step: accumulators never carry across steps and the
-        # transposed rows can stay in vregs — no pl.when, no scratch trip.
+    @pl.when(cj == 0)
+    def _reset():
         acc_n[...] = jnp.zeros_like(acc_n)
         acc_w[...] = jnp.zeros_like(acc_w)
         acc_c[...] = jnp.zeros_like(acc_c)
-        rpx = _tcol(trpx[...])
-        rpy = _tcol(trpy[...])
-    else:
-        @pl.when(cj == 0)
-        def _reset():
-            acc_n[...] = jnp.zeros_like(acc_n)
-            acc_w[...] = jnp.zeros_like(acc_w)
-            acc_c[...] = jnp.zeros_like(acc_c)
-            rp_s[...] = jnp.concatenate(
-                [_tcol(trpx[...]), _tcol(trpy[...])], axis=1
-            )
 
-        rpx = rp_s[:, 0:1]
-        rpy = rp_s[:, 1:2]
+    def strip_masks(s):
+        rows = pl.ds(pl.multiple_of(s * strip, strip), strip)
+        return rows, _pair_masks(
+            _tcol(trpx[:, rows]), _tcol(trpy[:, rows]), cpx[...], cpy[...],
+            neighbor_radius=neighbor_radius,
+            separation_radius=separation_radius,
+            w_cap=CLOSE_W,
+        )
 
-    neigh, w_hi, w_lo, (dx, dy, w) = _pair_masks(
-        rpx, rpy, cpx[...], cpy[...],
-        neighbor_radius=neighbor_radius,
-        separation_radius=separation_radius,
-        w_cap=CLOSE_W,
-    )
-    acc_n[...] += _DOT_T(feat_t[...], neigh)  # [10, R_BLK]
-    acc_w[...] += _DOT_T(sep_t[...], w_hi) + _DOT_T(sep_t[...], w_lo)
+    # Straight-line code, a strip after a strip: as a fori_loop the same
+    # body is 1,077 bundles a strip against 930 (nothing overlaps a turn).
+    for s in range(n_strips):
+        rows, (neigh, w_hi, w_lo, (_, _, w)) = strip_masks(s)
+        acc_n[:, rows] += _DOT_T(feat_t[...], neigh)  # [10, strip]
+        acc_w[:, rows] += _DOT_T(sep_t[...], w_hi) + _DOT_T(sep_t[...], w_lo)
+        wmax_s[s:s + 1, :] = jnp.max(w, axis=0, keepdims=True)
 
-    @pl.when(jnp.max(w) > jnp.float32(CLOSE_W))
+    # Which strips hold a pair closer than 1 / CLOSE_W: scalars, all read
+    # here in one go (a vector's way to a scalar is ~150 cycles: one after
+    # the other inside the loop below they were 3 us of a taken step, and
+    # taken from ``w`` inside the loop above they cost it 110 bundles a
+    # strip).
+    n_close = jnp.int32(0)
+    for s in range(n_strips):
+        close = (
+            jnp.max(wmax_s[s:s + 1, :]) > jnp.float32(CLOSE_W)
+        ).astype(jnp.int32)
+        close_s[s] = close
+        n_close += close
+
+    @pl.when(n_close > 0)
     def _close_pairs():
         # hi of an activity of 1.0 / 0.0 is the activity itself.
-        acc_c[...] += _close_pair_sums(
-            dx, dy, w, feat_t[0:1, :].astype(jnp.float32)
-        )
+        col_active = feat_t[0:1, :].astype(jnp.float32)
+
+        def strip_close(s, carry):
+            @pl.when(close_s[s] > 0)
+            def _a_close_pair():
+                rows, (_, _, _, (dx, dy, w)) = strip_masks(s)
+                acc_c[:, rows] += _close_pair_sums(dx, dy, w, col_active)
+
+            return carry
+
+        jax.lax.fori_loop(0, n_strips, strip_close, 0)
 
     @pl.when(cj == n_cols - 1)
     def _combine():
@@ -476,6 +546,7 @@ def _force_kernel_mxu2(
         "w_cohesion",
         "row_block",
         "col_block",
+        "strip_rows",
     ),
 )
 @jax.named_scope(FORCE_SCOPE)
@@ -492,22 +563,31 @@ def pairwise_force_rows_mxu2(
     w_separation: float,
     w_alignment: float,
     w_cohesion: float,
-    row_block: int = 512,
+    row_block: int = 1024,
     col_block: int = 1024,
+    strip_rows: int = STRIP_ROWS,
 ) -> jnp.ndarray:
     """Same contract as :func:`pairwise_force_rows_pallas`, reductions on
-    the MXU in feature-major orientation (see :func:`_force_kernel_mxu2`)."""
+    the MXU in feature-major orientation (see :func:`_force_kernel_mxu2`).
+    Rows pad to a multiple of ``STRIP_ROWS``; ``strip_rows`` is a multiple
+    of it that divides the row block (or the whole block, the form before
+    PR 49) and changes no bit of a force (``tests/test_ops.py``)."""
     R, N = row_pos.shape[0], all_pos.shape[0]
-    r_blk = min(row_block, _round_up(R, 8))
+    r_blk = min(row_block, _round_up(R, STRIP_ROWS))
     c_blk = min(col_block, _round_up(N, 128))
     r_pad = _round_up(R, r_blk) - R
     n_pad = _round_up(N, c_blk) - N
+    strip = min(strip_rows, r_blk)
+    if r_blk % strip or strip % STRIP_ROWS:
+        raise ValueError(
+            f"strip_rows={strip_rows} does not tile a row block of {r_blk}"
+        )
 
     def col(v, pad):
         return jnp.pad(v.astype(jnp.float32), (0, pad))
 
     # Every row operand is lane-major; the kernel transposes positions
-    # itself (see _tcol — the XLA relayout this replaces was the whole
+    # itself (see _tcol: the XLA relayout this replaces was the whole
     # 1k-vs-4k config-4 gap).
     trows = [
         col(row_pos[:, 0], r_pad)[None, :],
@@ -540,7 +620,7 @@ def pairwise_force_rows_mxu2(
         w_separation=w_separation,
         w_alignment=w_alignment,
         w_cohesion=w_cohesion,
-        single_col=(grid[1] == 1),
+        strip=strip,
     )
     fx, fy = pl.pallas_call(
         kernel,
@@ -555,7 +635,8 @@ def pairwise_force_rows_mxu2(
             pltpu.VMEM((10, r_blk), jnp.float32),
             pltpu.VMEM((SEP_ROWS, r_blk), jnp.float32),
             pltpu.VMEM((2, r_blk), jnp.float32),
-            pltpu.VMEM((r_blk, 2), jnp.float32),
+            pltpu.VMEM((r_blk // strip, c_blk), jnp.float32),
+            pltpu.SMEM((r_blk // strip,), jnp.int32),
         ],
         interpret=pallas_interpret(),
     )(*trows, *cols, feat_t, sep_t)
@@ -632,7 +713,12 @@ def _force_kernel_tri(
     Not here: the general kernel's differenced sums for close pairs
     (:func:`_close_pair_sums`). Both directions of a block would need
     them; a pair closer than 5e-3 keeps the matmul form's cancellation
-    (about 2e-8 * |p| / d of a force) until a cell runs N >= 4096."""
+    (about 2e-8 * |p| / d of a force) until a cell runs N >= 4096. Nor
+    its strips (PR 49): this kernel still builds a whole [B0, B0] block's
+    masks at once and caches the transposed rows in ``rp_s``. What the two
+    share, unchanged by PR 49: :func:`_pair_masks` (called here without
+    ``w_cap``), :func:`_acc_sums`, :func:`_combine_forces`,
+    :func:`_lane_feats`, ``_DOT_T``."""
     ri = pl.program_id(0)
     cj = pl.program_id(1)
     n_cols = pl.num_programs(1)

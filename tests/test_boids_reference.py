@@ -85,37 +85,56 @@ def test_one_step_of_each_dense_path_against_the_reference(kernel, n):
             assert np.abs(idle_p[0, :2] - want_p[0, :2]).max() > 1e-4
 
 
+# Where the close pairs sit among the MXU kernel's 128-row strips of a
+# 256-row block (``ops/pairwise.py`` ``STRIP_ROWS``): in the first strip, in
+# the second, one boid in each (each boid's row then lies in another strip
+# than its partner's), and a pair in each strip.
+PAIR_ROWS = {"first_strip": [(40, 41)], "second_strip": [(200, 201)],
+             "straddling": [(100, 230)], "a_pair_a_strip": [(40, 41), (200, 201)]}
+# The same four places among 200 boids (padded to 256 rows and columns).
+PADDED_PAIR_ROWS = {"first_strip": [(17, 18)], "second_strip": [(150, 151)],
+                    "straddling": [(17, 150)],
+                    "a_pair_a_strip": [(17, 18), (150, 151)]}
+
+
 @pytest.mark.parametrize("kernel", ["xla", "pallas", "mxu"])
 @pytest.mark.parametrize("distance", [3e-3, 1e-4])
-def test_a_close_pair_far_from_the_origin_keeps_float32(kernel, distance):
+@pytest.mark.parametrize("where", list(PAIR_ROWS))
+def test_a_close_pair_far_from_the_origin_keeps_float32(kernel, distance,
+                                                        where):
     """The matmul form of the separation sum, rpx * sum(w) - sum(w * cpx),
     cancels numbers of size |p| / d: before PR 31 the MXU path read 3e-4 for
     a pair 1e-4 apart at the world's edge. What a weight has above CLOSE_W
-    is summed as differences."""
+    is summed as differences, in the strip that holds the pair's row."""
     pos, vel, bits = _state(256, 31)
-    pos[40] = np.asarray([7.9, -7.7], np.float32)
-    pos[41] = pos[40] + np.asarray([distance, 0.0], np.float32)
+    corner = np.asarray([7.9, -7.7], np.float32)
+    for k, (i, j) in enumerate(PAIR_ROWS[where]):
+        pos[i] = corner * np.asarray([1.0, (-1.0) ** k], np.float32)
+        pos[j] = pos[i] + np.asarray([distance, 0.0], np.float32)
     got_p, got_v, _ = _program_step(kernel, pos, vel, bits)
     want_p, want_v = ref.step(pos[None], vel[None], bits[None])
     decided = ~ref.undecided(pos, MARGIN)
-    assert decided[[40, 41]].all()
+    assert decided[np.ravel(PAIR_ROWS[where])].all()
     assert ref.torus_gap(got_p, want_p[0])[decided].max() <= TOLERANCE[kernel]
     assert np.abs(got_v - want_v[0])[decided].max() <= TOLERANCE[kernel]
 
 
 @pytest.mark.parametrize("kernel", ["pallas", "mxu"])
-def test_a_padded_column_is_nobodys_close_neighbour(kernel):
+@pytest.mark.parametrize("where", list(PADDED_PAIR_ROWS))
+def test_a_padded_column_is_nobodys_close_neighbour(kernel, where):
     """200 boids pad to 256 columns, which sit at the origin with activity
     0: a boid 1e-3 from the origin must not feel them, in the matmuls (zero
     features) or in the close pairs' differenced sums (the mask; a live
-    boid 2e-3 away makes those sums run for its rows)."""
+    boid 2e-3 away makes those sums run for its strip)."""
+    rows = PADDED_PAIR_ROWS[where]
     pos, vel, bits = _state(200, 41)
-    pos[17] = np.asarray([1e-3, 0.0], np.float32)
-    pos[18] = np.asarray([1e-3, 2e-3], np.float32)
+    for k, (i, j) in enumerate(rows):
+        pos[i] = np.asarray([1e-3, 4e-3 * k], np.float32)
+        pos[j] = pos[i] + np.asarray([0.0, 2e-3], np.float32)
     got_p, got_v, _ = _program_step(kernel, pos, vel, bits)
     want_p, want_v = ref.step(pos[None], vel[None], bits[None])
     decided = ~ref.undecided(pos, MARGIN)
-    assert decided[17]
+    assert decided[rows[0][0]]
     assert np.abs(got_v - want_v[0])[decided].max() <= TOLERANCE[kernel]
 
 

@@ -229,6 +229,65 @@ def test_pairwise_mxu_row_subset_and_vmap():
         )
 
 
+def _flock_with_close_pairs(n, seed):
+    """A random flock with three pairs closer than 1 / CLOSE_W (3e-4 to
+    2e-3), spread over the rows, and one inactive boid."""
+    pos, vel, active = (np.asarray(x).copy() for x in _random_flock(n, seed))
+    for k, gap in enumerate((3e-4, 1e-3, 2e-3)):
+        i, j = (k * n) // 3 + 5, n - 7 - 11 * k
+        pos[j] = pos[i] + np.float32([gap, 0.0])
+    active[n // 2 + 1] = 0.0
+    return jnp.asarray(pos), jnp.asarray(vel), jnp.asarray(active)
+
+
+# (boids, rows the call owns, col_block, batch axes): the 1,024-boid world
+# at a size the interpreter takes in a second, the sharded contract (R < N),
+# boid counts that pad rows and columns, two column steps, the two vmaps.
+_STRIP_CASES = {
+    "square_four_strips": (512, (0, 512), 1024, 0),
+    "square_two_strips": (256, (0, 256), 1024, 0),
+    "row_subset_of_a_shard": (512, (128, 384), 1024, 0),
+    "row_subset_one_strip": (512, (384, 512), 1024, 0),
+    "rows_and_columns_pad": (300, (0, 300), 1024, 0),
+    "rows_pad_two_column_steps": (200, (0, 200), 128, 0),
+    "two_column_steps": (512, (0, 512), 256, 0),
+    "under_one_vmap": (256, (0, 256), 1024, 1),
+    "under_two_vmaps": (256, (0, 256), 1024, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(_STRIP_CASES))
+def test_pairwise_mxu_strip_height_changes_no_bit(case):
+    """The pair block is walked in strips of ``STRIP_ROWS`` rows (PR 49):
+    a row's sums contract the same columns in the same order whichever
+    strip it rides in, and the close pairs' sums are taken where a strip
+    holds one, so the strip height changes no bit of any force. Compared
+    with the whole block as one strip (the kernel's form before PR 49)."""
+    from bevy_ggrs_tpu.ops.pairwise import (
+        STRIP_ROWS, pairwise_force_rows_mxu2,
+    )
+
+    n, (r0, r1), col_block, batch = _STRIP_CASES[case]
+    worlds = [_flock_with_close_pairs(n, seed=n + k) for k in range(2 ** batch)]
+    args = [jnp.stack(x).reshape((2,) * batch + x[0].shape)
+            for x in zip(*worlds)]
+
+    def force(strip_rows):
+        def one(p, v, a):
+            return pairwise_force_rows_mxu2(
+                p[r0:r1], v[r0:r1], p, v, a[r0:r1], a, col_block=col_block,
+                strip_rows=strip_rows, **_KPARAMS,
+            )
+
+        for _ in range(batch):
+            one = jax.vmap(one)
+        return np.asarray(one(*args))
+
+    whole, strips = force(1024), force(STRIP_ROWS)
+    assert np.array_equal(whole, strips)
+    assert np.abs(whole).max() > 0.05  # a close pair's force, not zeros
+
+
 @pytest.mark.parametrize("n,blk", [(256, 128), (300, 128), (512, 128)])
 def test_pairwise_tri_matches_xla(n, blk):
     """Triangle kernel (symmetry-halved mask work): same tolerance class
