@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bevy_ggrs_tpu.obs.trace import null_span
+from bevy_ggrs_tpu.obs.trace import device_scope, null_span
 from bevy_ggrs_tpu.parallel.speculate import match_branch
 from bevy_ggrs_tpu.rollout import (
     deepest_lane,
@@ -452,9 +452,12 @@ class PackedTick:
         T = TickInts
         MF, F = self.burst_frames, self.spec_frames
         P = bits.shape[1]
-        ring, state, prev_rings, prev_states = self.carry.unpack(carry)
-        status = ints[T.STATUS:T.STATUS + MF * P].reshape(MF, P)
-        mask = jnp.arange(MF, dtype=jnp.int32) < ints[T.N_BURST]
+        # ``carry_codec``: the program's signature taken apart and put
+        # together again (the carry, the int32 vector, the checksums).
+        with device_scope("carry_codec"):
+            ring, state, prev_rings, prev_states = self.carry.unpack(carry)
+            status = ints[T.STATUS:T.STATUS + MF * P].reshape(MF, P)
+            mask = jnp.arange(MF, dtype=jnp.int32) < ints[T.N_BURST]
         (
             ring, state, absorb_cs, burst_cs, spec_rings, spec_states, spec_cs,
         ) = FusedTickExecutor._tick_impl(
@@ -468,11 +471,12 @@ class PackedTick:
             jnp.full((F, P), PREDICTED, dtype=jnp.int32),
             lane_axis=self.lane_axis,
         )
-        return (
-            self.carry.pack((ring, state, spec_rings, spec_states)),
-            state,
-            self.cs.pack((absorb_cs, burst_cs, spec_cs)),
-        )
+        with device_scope("carry_codec"):
+            return (
+                self.carry.pack((ring, state, spec_rings, spec_states)),
+                state,
+                self.cs.pack((absorb_cs, burst_cs, spec_cs)),
+            )
 
     def front(self, carry, ints, bits):
         """``(carry, state, (absorb_cs, burst_cs))`` of a split tick's
@@ -481,9 +485,10 @@ class PackedTick:
         T = TickInts
         MF = self.burst_frames
         P = bits.shape[1]
-        ring, state, prev_rings, prev_states = self.carry.unpack(carry)
-        status = ints[T.STATUS:T.STATUS + MF * P].reshape(MF, P)
-        mask = jnp.arange(MF, dtype=jnp.int32) < ints[T.N_BURST]
+        with device_scope("carry_codec"):
+            ring, state, prev_rings, prev_states = self.carry.unpack(carry)
+            status = ints[T.STATUS:T.STATUS + MF * P].reshape(MF, P)
+            mask = jnp.arange(MF, dtype=jnp.int32) < ints[T.N_BURST]
         ring, state, absorb_cs, burst_cs = FusedTickExecutor._front_impl(
             self.schedule, MF,
             ring, state, prev_rings, prev_states, ints[T.BRANCH],
@@ -492,26 +497,29 @@ class PackedTick:
             ints[T.DO_LOAD] != 0, ints[T.LOAD_FRAME], ints[T.START_FRAME],
             bits, status, mask, mask, lane_axis=self.lane_axis,
         )
-        return (
-            self.carry.pack((ring, state, prev_rings, prev_states)),
-            state, (absorb_cs, burst_cs),
-        )
+        with device_scope("carry_codec"):
+            return (
+                self.carry.pack((ring, state, prev_rings, prev_states)),
+                state, (absorb_cs, burst_cs),
+            )
 
     def absorb(self, carry, ints):
         """``(carry, state, absorb_cs[burst_frames, 2])`` of the
         absorb-only program; the previous rollout stays in the carry."""
         T = TickInts
-        ring, _, prev_rings, prev_states = self.carry.unpack(carry)
+        with device_scope("carry_codec"):
+            ring, _, prev_rings, prev_states = self.carry.unpack(carry)
         ring, state, absorb_cs = FusedTickExecutor._absorb_impl(
             self.burst_frames, ring, prev_rings, prev_states,
             ints[T.BRANCH], ints[T.ABSORB_FIRST], ints[T.ABSORB_N],
             ints[T.PREV_ANCHOR], ints[T.PREV_TOTAL],
             lane_axis=self.lane_axis,
         )
-        return (
-            self.carry.pack((ring, state, prev_rings, prev_states)),
-            state, absorb_cs,
-        )
+        with device_scope("carry_codec"):
+            return (
+                self.carry.pack((ring, state, prev_rings, prev_states)),
+                state, absorb_cs,
+            )
 
     def pack(self, ring, state, prev_rings, prev_states):
         return self.carry.pack((ring, state, prev_rings, prev_states))
@@ -691,7 +699,6 @@ class FusedTickExecutor:
         beside the narrower form, the read alone inside the conditional
         and the loop at a trip count of 0 behind it: ``PERF.md`` section 6,
         PR 46.)"""
-        n_run = deepest_lane(absorb_n, lane_axis)
 
         def commit():
             sel = lambda x: ring_row_read(x, branch)
@@ -707,7 +714,9 @@ class FusedTickExecutor:
             )
             return ring, state, jnp.zeros((burst_frames, 2), jnp.uint32)
 
-        return jax.lax.cond(n_run > 0, commit, skip)
+        with device_scope("absorb"):
+            n_run = deepest_lane(absorb_n, lane_axis)
+            return jax.lax.cond(n_run > 0, commit, skip)
 
     @staticmethod
     def _front_impl(
@@ -728,22 +737,24 @@ class FusedTickExecutor:
             burst_frames, ring, prev_rings, prev_states, branch,
             absorb_first, absorb_n, prev_anchor, prev_total, lane_axis,
         )
-        state = jax.tree_util.tree_map(
-            lambda a, s: jnp.where(absorb_n > 0, a, s), state_a, state
-        )
-        loaded = ring_load(ring, load_frame)
-        state = jax.tree_util.tree_map(
-            lambda l, s: jnp.where(do_load, l, s), loaded, state
-        )
-        frame0 = jnp.where(
-            do_load,
-            jnp.asarray(load_frame, jnp.int32),
-            jnp.asarray(start_frame, jnp.int32),
-        )
-        ring, state, burst_cs = rollout_burst(
-            schedule, ring, state, frame0, bits, status, save_mask, adv_mask,
-            n_run=live_steps(save_mask, adv_mask, lane_axis),
-        )
+        with device_scope("absorb"):
+            state = jax.tree_util.tree_map(
+                lambda a, s: jnp.where(absorb_n > 0, a, s), state_a, state
+            )
+        with device_scope("burst"):
+            loaded = ring_load(ring, load_frame)
+            state = jax.tree_util.tree_map(
+                lambda l, s: jnp.where(do_load, l, s), loaded, state
+            )
+            frame0 = jnp.where(
+                do_load,
+                jnp.asarray(load_frame, jnp.int32),
+                jnp.asarray(start_frame, jnp.int32),
+            )
+            ring, state, burst_cs = rollout_burst(
+                schedule, ring, state, frame0, bits, status, save_mask,
+                adv_mask, n_run=live_steps(save_mask, adv_mask, lane_axis),
+            )
         return ring, state, absorb_cs, burst_cs
 
     @staticmethod
@@ -764,42 +775,44 @@ class FusedTickExecutor:
             burst_frames, ring, prev_rings, prev_states, branch,
             absorb_first, absorb_n, prev_anchor, prev_total, lane_axis,
         )
-        state = jax.tree_util.tree_map(
-            lambda a, s: jnp.where(absorb_n > 0, a, s), state_a, state
-        )
+        with device_scope("absorb"):
+            state = jax.tree_util.tree_map(
+                lambda a, s: jnp.where(absorb_n > 0, a, s), state_a, state
+            )
 
         # Phase 2 — the serial burst: rollback resimulation (do_load), the
         # unmatched tail after a partial absorb, or the steady advance; as
         # many steps as the masks ask for (the deepest lane's, under a
         # ``vmap`` over ``lane_axis``), the rest of ``burst_frames`` unrun.
-        loaded = ring_load(ring, load_frame)
-        state = jax.tree_util.tree_map(
-            lambda l, s: jnp.where(do_load, l, s), loaded, state
-        )
-        frame0 = jnp.where(
-            do_load,
-            jnp.asarray(load_frame, jnp.int32),
-            jnp.asarray(start_frame, jnp.int32),
-        )
-        ring, state, burst_cs = rollout_burst(
-            schedule, ring, state, frame0, bits, status, save_mask, adv_mask,
-            n_run=live_steps(save_mask, adv_mask, lane_axis),
-        )
+        with device_scope("burst"):
+            loaded = ring_load(ring, load_frame)
+            state = jax.tree_util.tree_map(
+                lambda l, s: jnp.where(do_load, l, s), loaded, state
+            )
+            frame0 = jnp.where(
+                do_load,
+                jnp.asarray(load_frame, jnp.int32),
+                jnp.asarray(start_frame, jnp.int32),
+            )
+            ring, state, burst_cs = rollout_burst(
+                schedule, ring, state, frame0, bits, status, save_mask,
+                adv_mask, n_run=live_steps(save_mask, adv_mask, lane_axis),
+            )
 
         # Phase 3 — the next speculative rollout, anchored on the
         # post-burst frontier: the live state when the anchor IS the new
         # frame, else the ring snapshot of the (older) anchor frame.
-        anchor_state = jax.tree_util.tree_map(
-            lambda live, rg: jnp.where(spec_from_live, live, rg),
-            state,
-            ring_load(ring, spec_anchor),
-        )
-
-        spec_rings, spec_states, spec_cs = jax.vmap(
-            lambda bb: rollout_steps(
-                schedule, anchor_state, spec_anchor, bb, spec_status
+        with device_scope("rollout"):
+            anchor_state = jax.tree_util.tree_map(
+                lambda live, rg: jnp.where(spec_from_live, live, rg),
+                state,
+                ring_load(ring, spec_anchor),
             )
-        )(branch_bits)
+            spec_rings, spec_states, spec_cs = jax.vmap(
+                lambda bb: rollout_steps(
+                    schedule, anchor_state, spec_anchor, bb, spec_status
+                )
+            )(branch_bits)
         return ring, state, absorb_cs, burst_cs, spec_rings, spec_states, spec_cs
 
     # ------------------------------------------------------------------

@@ -236,9 +236,10 @@ def _pairwise_forces(
     Factored out so the entity-sharded variant can compute row blocks
     against the full (all-gathered) column set.
     """
-    from bevy_ggrs_tpu.ops.pairwise import FORCE_SCOPE
+    from bevy_ggrs_tpu.obs.trace import device_scope
+    from bevy_ggrs_tpu.ops.pairwise import FORCE
 
-    with jax.named_scope(FORCE_SCOPE):
+    with device_scope(FORCE):
         return pairwise_force_rows(pos, vel, pos, vel, active, active)
 
 

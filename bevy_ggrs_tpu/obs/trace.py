@@ -46,13 +46,29 @@ import time
 import weakref
 from typing import Dict, List, Optional, Tuple
 
+from jax import named_scope
 from jax.profiler import TraceAnnotation
 
 from bevy_ggrs_tpu.utils.metrics import null_metrics
 
 # Every program span's name on the profiler's host plane starts with this
-# (the benchmark's own spans start with ``bench/``).
+# (the benchmark's own spans start with ``bench/``), and so does every
+# device scope's part of an operation's ``op_name`` (:func:`device_scope`).
 TRACE_PREFIX = "ggrs/"
+
+
+def device_scope(name: str):
+    """``with device_scope("ring_write"):`` around the lines that TRACE a
+    piece of a jitted program: the device-side counterpart of a span.
+    Every operation traced inside carries ``ggrs/<name>`` in its
+    ``op_name``, nested scopes in order, through the compiler's passes and
+    into the optimized module's ``metadata=``; ``utils.xla_cache.
+    record_executable_cost`` reads them back as the map from a compiled
+    operation's name to its scopes (``executable_phases``), which joined
+    with a device trace's operation times is device time by phase. A scope
+    is trace-time metadata: the call costs nothing and the computation is
+    the unscoped one's (docs/observability.md, "Device scopes")."""
+    return named_scope(TRACE_PREFIX + name)
 
 # Event tuples: ("B", name, ts_us, args) / ("E", name, ts_us, None)
 #             / ("I", name, ts_us, args)   (instant)

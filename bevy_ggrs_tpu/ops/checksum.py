@@ -27,6 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bevy_ggrs_tpu import state as state_lib
+from bevy_ggrs_tpu.obs.trace import device_scope
 from bevy_ggrs_tpu.ops.interpret import pallas_interpret
 from bevy_ggrs_tpu.state import WorldState
 
@@ -123,10 +124,11 @@ def _word_matrix(state: WorldState) -> jnp.ndarray:
 
 def checksum_pallas(state: WorldState) -> jnp.ndarray:
     """Drop-in, bit-identical replacement for :func:`state.checksum`."""
-    words_t = _word_matrix(state)
-    alive = state.alive.astype(jnp.uint32)[None, :]
-    total = _entity_hash_sum(words_t, alive)
-    return total + state_lib._resources_checksum(state.resources)
+    with device_scope("checksum"):
+        words_t = _word_matrix(state)
+        alive = state.alive.astype(jnp.uint32)[None, :]
+        total = _entity_hash_sum(words_t, alive)
+        return total + state_lib._resources_checksum(state.resources)
 
 
 def install_pallas_checksum(enable: bool = True) -> None:

@@ -44,6 +44,8 @@ from typing import Callable, Union
 
 import jax.numpy as jnp
 
+from bevy_ggrs_tpu.obs.trace import device_scope
+
 Values = Union[jnp.ndarray, Callable[[jnp.ndarray], jnp.ndarray]]
 
 
@@ -68,27 +70,32 @@ class RowClaim:
 
     def put(self, leaf: jnp.ndarray, values: Values) -> jnp.ndarray:
         """``leaf[capacity, ...]`` with the claimed rows overwritten."""
-        if callable(values):
-            return jnp.where(
-                _lead(self._taken, leaf), values(self._ordinal), leaf
-            )
-        values = jnp.asarray(values, leaf.dtype)
-        if values.ndim == leaf.ndim - 1:  # one value for every birth
-            return jnp.where(_lead(self._taken, leaf), values, leaf)
-        for k in range(self.wants.shape[0]):
-            hit = self._taken & self.wants[k] & (self._ordinal == self.rank[k])
-            leaf = jnp.where(_lead(hit, leaf), values[k], leaf)
-        return leaf
+        with device_scope("claim"):
+            if callable(values):
+                return jnp.where(
+                    _lead(self._taken, leaf), values(self._ordinal), leaf
+                )
+            values = jnp.asarray(values, leaf.dtype)
+            if values.ndim == leaf.ndim - 1:  # one value for every birth
+                return jnp.where(_lead(self._taken, leaf), values, leaf)
+            for k in range(self.wants.shape[0]):
+                hit = (
+                    self._taken & self.wants[k]
+                    & (self._ordinal == self.rank[k])
+                )
+                leaf = jnp.where(_lead(hit, leaf), values[k], leaf)
+            return leaf
 
 
 def claim_rows(alive: jnp.ndarray, wants: jnp.ndarray) -> RowClaim:
     """Claim free rows of ``alive[capacity]`` for the births ``wants[K]``
     wants."""
-    free = ~alive
-    ordinal = jnp.cumsum(free.astype(jnp.int32)) - 1  # [cap]
-    wanting = wants.astype(jnp.int32)
-    taken = free & (ordinal < jnp.sum(wanting))
-    return RowClaim(
-        wants, jnp.cumsum(wanting) - 1, jnp.sum(taken.astype(jnp.int32)),
-        ordinal, taken,
-    )
+    with device_scope("claim"):
+        free = ~alive
+        ordinal = jnp.cumsum(free.astype(jnp.int32)) - 1  # [cap]
+        wanting = wants.astype(jnp.int32)
+        taken = free & (ordinal < jnp.sum(wanting))
+        return RowClaim(
+            wants, jnp.cumsum(wanting) - 1,
+            jnp.sum(taken.astype(jnp.int32)), ordinal, taken,
+        )

@@ -37,15 +37,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from bevy_ggrs_tpu.obs.trace import TRACE_PREFIX, device_scope
 from bevy_ggrs_tpu.ops.interpret import pallas_interpret
 
-
-# Every dense force path runs under this scope (here and the XLA path of
-# models/boids.py), so a device trace's operation metadata says which
-# operations are the flocking force whatever the compiler names them, and
-# its last part names the Mosaic call in the optimized HLO: the trace shows
-# ``pairwise_force.N`` whichever kernel ran. docs/observability.md lists it.
-FORCE_SCOPE = "ggrs/pairwise_force"
+# Every dense force path runs under this device scope (``obs/trace.py``
+# ``device_scope``; here and the XLA path of models/boids.py), so an
+# operation's metadata says it is the flocking force, and the scope's last
+# part names the Mosaic call in the trace: ``pairwise_force.N``.
+FORCE = "pairwise_force"
+FORCE_SCOPE = TRACE_PREFIX + FORCE
 
 
 def _round_up(x: int, m: int) -> int:
@@ -128,7 +128,7 @@ def _force_kernel(
         "col_block",
     ),
 )
-@jax.named_scope(FORCE_SCOPE)
+@device_scope(FORCE)
 def pairwise_force_rows_pallas(
     row_pos: jnp.ndarray,  # [R, 2]
     row_vel: jnp.ndarray,  # [R, 2]
@@ -549,7 +549,7 @@ def _force_kernel_mxu2(
         "strip_rows",
     ),
 )
-@jax.named_scope(FORCE_SCOPE)
+@device_scope(FORCE)
 def pairwise_force_rows_mxu2(
     row_pos: jnp.ndarray,  # [R, 2]
     row_vel: jnp.ndarray,  # [R, 2]
@@ -784,7 +784,7 @@ def _force_kernel_tri(
         "block",
     ),
 )
-@jax.named_scope(FORCE_SCOPE)
+@device_scope(FORCE)
 def pairwise_force_square_mxu_tri(
     pos: jnp.ndarray,  # [N, 2]
     vel: jnp.ndarray,  # [N, 2]

@@ -138,6 +138,12 @@ class Schedule:
         return tuple(self._systems)
 
     def __call__(self, state: WorldState, inputs: PlayerInputs) -> WorldState:
-        for system in self._systems:
-            state = system(state, inputs)
+        # The title's own rules, as one device scope: the only scope that
+        # holds others (a title's ``claim``, the force kernel's own).
+        # (``obs`` imports the sessions, which import this module.)
+        from bevy_ggrs_tpu.obs.trace import device_scope
+
+        with device_scope("schedule"):
+            for system in self._systems:
+                state = system(state, inputs)
         return state

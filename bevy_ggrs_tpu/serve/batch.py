@@ -107,7 +107,7 @@ from bevy_ggrs_tpu.fused import (
 )
 from bevy_ggrs_tpu.native import spec as native_spec
 from bevy_ggrs_tpu.obs.ledger import rollback_blame
-from bevy_ggrs_tpu.obs.trace import NULL_SPAN, Instrumented
+from bevy_ggrs_tpu.obs.trace import NULL_SPAN, Instrumented, null_tracer
 from bevy_ggrs_tpu.predict.batch import BatchedRanker
 from bevy_ggrs_tpu.predict.model import resolve_predictor
 from bevy_ggrs_tpu.schedule import Schedule
@@ -121,6 +121,7 @@ from bevy_ggrs_tpu.state import (
     ring_init,
     ring_row_lowerings,
 )
+from bevy_ggrs_tpu.utils.metrics import null_metrics
 
 
 class BatchedTickExecutor:
@@ -170,10 +171,11 @@ class BatchedTickExecutor:
         # call adds is its own (:meth:`traced_ring_rows`).
         self._ring_rows0: Optional[Dict[str, int]] = dict(ring_row_lowerings)
         # Cost-observatory hook: when armed, the NEXT dispatch prices the
-        # compiled program (cost_analysis/memory_analysis) into
-        # utils.xla_cache under this name. Arm it before warmup — the AOT
-        # lowering's backend compile is then a cache hit of the warmup
-        # compile and lands before any churn counter is snapshotted.
+        # compiled program (cost_analysis/memory_analysis) and keeps its
+        # phase map (which compiled operation lies under which device
+        # scope) in utils.xla_cache under this name. Arm it before warmup:
+        # the AOT lowering and its compile then land before any churn
+        # counter is snapshotted.
         self._cost_name: Optional[str] = None
         self._captured_name: Optional[str] = None
 
@@ -415,13 +417,20 @@ class BatchedSessionCore(Instrumented):
                 self.num_branches, self.spec_frames,
             )
         S, B, F = self.num_slots, self.num_branches, self.spec_frames
-        # Cost observatory opt-in (GGRS_XLA_COST=1): the warmup dispatch
-        # prices the batched tick (flops / bytes / hbm_peak_bytes) into
-        # utils.xla_cache. Opt-in because the AOT lowering re-traces the
-        # program — its backend compile is a persistent-cache hit, but
-        # the trace itself costs seconds at large S.
-        if os.environ.get("GGRS_XLA_COST", "").lower() not in (
-            "", "0", "false"
+        # ONE capture of the compiled tick, armed by an operator
+        # (GGRS_XLA_COST=1) or by a real sink (somebody is tracing): the
+        # warmup dispatch prices the batched tick (flops / bytes /
+        # hbm_peak_bytes) and keeps its phase map (utils.xla_cache
+        # ``executable_phases``: device time by phase, once joined with a
+        # device trace). Opt-in because the AOT lowering re-traces the
+        # program (seconds at large S) and its compile, keyed with this
+        # tree's scopes, is a real one the first time: a core with both
+        # sinks null never lowers twice.
+        if (
+            os.environ.get("GGRS_XLA_COST", "").lower()
+            not in ("", "0", "false")
+            or self.metrics is not null_metrics
+            or self.tracer is not null_tracer
         ):
             self._exec.enable_cost_capture(
                 f"batched_tick_S{S}_B{B}_F{F}"
@@ -672,9 +681,6 @@ class BatchedSessionCore(Instrumented):
             # The borrowed _structured_bits picks this up via getattr;
             # per-dispatch seeds land in _seed_memo (see _dispatch).
             s.shim._predictor = self._predictor
-        self.metrics.count(
-            "matches_admitted" if ticket is None else "matches_readmitted"
-        )
         return slot
 
     def retire(self, slot: int) -> None:
@@ -697,7 +703,6 @@ class BatchedSessionCore(Instrumented):
         s.shim = None
         s.res_anchor = None
         s.res_bits = None
-        self.metrics.count("matches_retired")
 
     def slot_state(self, slot: int) -> WorldState:
         """Device view of one slot's live state (e.g. for handing a match

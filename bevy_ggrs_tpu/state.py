@@ -47,6 +47,16 @@ from flax import struct
 # ``models/projectiles.py``) mint upward from ``DEVICE_ID_BASE``.
 DEVICE_ID_BASE = 1 << 20
 
+
+def _scope(name: str):
+    """``obs/trace.py`` ``device_scope``, found when a program is traced:
+    ``obs`` reads this module while it is imported (forensics), so the
+    name cannot be bound here at import."""
+    from bevy_ggrs_tpu.obs.trace import device_scope
+
+    return device_scope(name)
+
+
 # ---------------------------------------------------------------------------
 # Type registry
 # ---------------------------------------------------------------------------
@@ -490,7 +500,8 @@ def set_checksum_impl(fn: Optional[Callable[[WorldState], jnp.ndarray]]) -> None
 
 def active_checksum(state: WorldState) -> jnp.ndarray:
     fn = _checksum_impl[0]
-    return fn(state) if fn is not None else checksum(state)
+    with _scope("checksum"):
+        return fn(state) if fn is not None else checksum(state)
 
 
 # ---------------------------------------------------------------------------
@@ -660,14 +671,16 @@ def ring_row_write(
     write = _row_write_at(0)
     if not _traced(index, valid):
         write = write.fun  # a concrete index cannot differ per lane
-    return write(stack, row, index, valid)
+    with _scope("ring_write"):
+        return write(stack, row, index, valid)
 
 
 def ring_row_read(stack: jnp.ndarray, index: jnp.ndarray) -> jnp.ndarray:
     """Row ``index`` of ``stack``'s leading axis: a snapshot-ring row by
     ``frame % depth``, or the matched branch of a ``[B, ...]`` rollout."""
     read = _row_read_at(0)
-    return (read if _traced(index) else read.fun)(stack, index)
+    with _scope("ring_read"):
+        return (read if _traced(index) else read.fun)(stack, index)
 
 
 def ring_put(
@@ -747,11 +760,12 @@ def _row_flat(x: jnp.ndarray, lead: int) -> jnp.ndarray:
     if len(shape) < 2 or n * x.dtype.itemsize < FLAT_ROW_BYTES:
         return x
     perm = _lanes_last(shape)
-    if perm is not None:
-        x = jnp.transpose(
-            x, tuple(range(lead)) + tuple(lead + a for a in perm)
-        )
-    return x.reshape(x.shape[:lead] + (n,))
+    with _scope("row_layout"):
+        if perm is not None:
+            x = jnp.transpose(
+                x, tuple(range(lead)) + tuple(lead + a for a in perm)
+            )
+        return x.reshape(x.shape[:lead] + (n,))
 
 
 def ring_rows_flat(ring: SnapshotRing) -> SnapshotRing:
@@ -771,12 +785,13 @@ def _rows_shaped(x: jnp.ndarray, row: Tuple[int, ...]) -> jnp.ndarray:
     if x.shape[1:] == row:
         return x
     perm = _lanes_last(row)
-    if perm is None:
-        return x.reshape(x.shape[:1] + row)
-    x = x.reshape(x.shape[:1] + tuple(row[a] for a in perm))
-    return jnp.transpose(
-        x, (0,) + tuple(1 + int(a) for a in np.argsort(perm))
-    )
+    with _scope("row_layout"):
+        if perm is None:
+            return x.reshape(x.shape[:1] + row)
+        x = x.reshape(x.shape[:1] + tuple(row[a] for a in perm))
+        return jnp.transpose(
+            x, (0,) + tuple(1 + int(a) for a in np.argsort(perm))
+        )
 
 
 def ring_rows_shaped(ring: SnapshotRing, like: SnapshotRing) -> SnapshotRing:

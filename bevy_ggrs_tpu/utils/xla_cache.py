@@ -21,7 +21,8 @@ working directory, so every process of one checkout shares it.
 from __future__ import annotations
 
 import os
-from typing import Dict, List, Optional
+import re
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 _DEFAULT_DIR = os.path.join(
     os.path.dirname(
@@ -174,7 +175,7 @@ def compile_summary() -> dict:
     }
 
 
-# -- per-executable cost/memory analysis --------------------------------
+# -- per-executable cost/memory analysis, and the phase map ---------------
 #
 # The monitoring listeners see durations, never executables, so the cost
 # observatory is an explicit capture: callers that own a jitted function
@@ -183,12 +184,28 @@ def compile_summary() -> dict:
 # ``jitted.lower(*args).compile()`` then ``cost_analysis()`` (flops,
 # bytes accessed) and ``memory_analysis()`` (argument/output/temp/
 # generated-code bytes, summed into ``hbm_peak_bytes``: the number that
-# decides how many lanes fit a device). The AOT compile re-traces, but
-# its backend compile is a persistent-cache hit of the HLO the live jit
-# call already compiled — call it during warmup, before any compile
-# counters are snapshotted for churn gates.
+# decides how many lanes fit a device). From the same compiled object it
+# keeps the PHASE MAP: which instruction of the optimized module was traced
+# under which device scopes (``obs/trace.py`` ``device_scope``), read off
+# the ``metadata={op_name="..."}`` of ``as_text()``. A device trace names
+# an operation by its instruction's name and nothing else, so the map is
+# what turns a trace's operation times into device time by phase
+# (``executable_phases``; the benchmark's ``trace_phase`` reader,
+# ``tools/trace_spans.py server``).
+#
+# The capture's compile keys the persistent cache WITH the module's
+# metadata (``jax_compilation_cache_include_metadata_in_key``, for that one
+# compile): jax's default key drops it, so an executable that another
+# checkout of this program cached before the scopes existed, or with other
+# scopes, is a hit for the live call and carries that checkout's
+# ``op_name``s. XLA's passes do not read metadata: the instruction names
+# this compile yields are those of the executable that runs. Call it during
+# warmup, before any compile counters are snapshotted for churn gates; the
+# AOT lowering re-traces the program (seconds at large S) and a capture's
+# first compile at a new tree is a real one.
 
 _EXEC_COSTS: Dict[str, dict] = {}
+_EXEC_PHASES: Dict[str, dict] = {}
 
 _MEMORY_FIELDS = (
     ("argument_size_in_bytes", "argument_bytes"),
@@ -197,10 +214,153 @@ _MEMORY_FIELDS = (
     ("generated_code_size_in_bytes", "generated_code_bytes"),
 )
 
+_METADATA_IN_KEY = "jax_compilation_cache_include_metadata_in_key"
+
+# One instruction of ``as_text()``: ``  [ROOT ]%name = type opcode(...)``,
+# inside ``[ENTRY ]%computation (...) -> type {`` ... ``}``. The opcode is
+# the first lower-case word before a ``(`` (types hold ``T(8,128)`` tiles
+# and ``S(1)`` memory spaces, in capitals).
+_COMPUTATION = re.compile(r"^(ENTRY )?%?([^\s(]+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([^\s=]+) = (.*)$")
+_OPCODE = re.compile(r"(?<![\w.%-])([a-z][a-z0-9-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(
+    r"\b(?:body|condition|to_apply|calls|true_computation|false_computation)"
+    r"=%?([\w.-]+)"
+)
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_OPERAND = re.compile(r"%([\w.-]+)")
+# Whose called computations run as operations of their own (a fusion's and
+# a reduction's are part of the one operation the trace times).
+_CONTROL_FLOW = frozenset(("while", "conditional", "call"))
+# Never an event of a device trace: they move no data.
+_FREE_OPCODES = frozenset(
+    ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
+)
+
+
+class OpScopes(NamedTuple):
+    """One instruction of an optimized module that can take device time:
+    where it stands and the device scopes it lies under, outermost first
+    (``()``: none); ``own``: whether its own metadata named them."""
+
+    computation: str
+    entry: bool
+    name: str
+    opcode: str
+    scopes: Tuple[str, ...]
+    own: bool = True
+
+
+def _operands(rest: str, start: int) -> List[str]:
+    """The operand names of ``opcode(...)``, ``start`` just past its
+    ``(``."""
+    depth, end = 1, start
+    while depth and end < len(rest):
+        depth += {"(": 1, ")": -1}.get(rest[end], 0)
+        end += 1
+    return _OPERAND.findall(rest[start:end])
+
+
+def op_scopes(hlo_text: str) -> List[OpScopes]:
+    """Every instruction of ``compiled.as_text()`` that a device trace can
+    time (not the inside of a fusion or of a reduction's ``to_apply``, and
+    no parameter, constant, tuple or bitcast), with the ``ggrs/<scope>``
+    parts of its ``op_name`` in order.
+
+    An instruction the compiler made WITHOUT metadata (a layout copy, a
+    prefetch into the faster memory, what a pass re-made of a ``cumsum``)
+    serves the operation it feeds: it takes the scopes of the nearest
+    instruction with metadata of its own that uses its result, in its
+    computation (through tuples and bitcasts; of several, the first in the
+    program's order). One that feeds none (a loop's carried copy, an
+    output's) lies under the scopes of the ``while`` / ``conditional`` that
+    runs its computation, and in the entry computation under none."""
+    from bevy_ggrs_tpu.obs.trace import TRACE_PREFIX
+
+    scope = re.compile(re.escape(TRACE_PREFIX) + r"(\w+)")
+    inside = set()           # computations that are part of one operation
+    caller: Dict[str, tuple] = {}  # computation -> (computation, scopes)
+    rows = []
+    # Per computation, for the walk from an instruction to its users:
+    # name -> own scopes (free opcodes too), name -> users in order.
+    own: Dict[str, Dict[str, tuple]] = {}
+    users: Dict[str, Dict[str, list]] = {}
+    computation, entry = "", False
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            entry, computation = bool(head.group(1)), head.group(2)
+            own[computation], users[computation] = {}, {}
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        op = _OPCODE.search(rest)
+        opcode = op.group(1) if op else ""
+        op_name = _OP_NAME.search(rest)
+        scopes = (tuple(dict.fromkeys(scope.findall(op_name.group(1))))
+                  if op_name else ())
+        own[computation][name] = scopes
+        for operand in (_operands(rest, op.end()) if op else ()):
+            users[computation].setdefault(operand, []).append(name)
+        if opcode in _FREE_OPCODES:
+            continue
+        called = _CALLED.findall(rest)
+        for group in _BRANCHES.findall(rest):
+            called += [c.strip().lstrip("%") for c in group.split(",")]
+        if opcode in _CONTROL_FLOW:
+            for c in called:
+                caller.setdefault(c, (computation, name))
+        else:
+            inside.update(called)
+        rows.append(OpScopes(computation, entry, name, opcode, scopes))
+
+    resolved: Dict[tuple, tuple] = {}
+
+    def lies_under(comp: str, name: str) -> Tuple[str, ...]:
+        key = (comp, name)
+        if key not in resolved:
+            resolved[key] = ()      # a cycle cannot be: the text is a DAG
+            found = own[comp].get(name, ())
+            seen, frontier = {name}, [name]
+            while frontier and not found:   # nearest users first
+                nxt = []
+                for n in frontier:
+                    for user in users[comp].get(n, ()):
+                        if user not in seen:
+                            seen.add(user)
+                            nxt.append(user)
+                found = next((own[comp][u] for u in nxt if own[comp][u]), ())
+                frontier = nxt
+            if not found and comp in caller:
+                found = lies_under(*caller[comp])
+            resolved[key] = found
+        return resolved[key]
+
+    return [
+        r if r.scopes else r._replace(
+            scopes=lies_under(r.computation, r.name), own=False)
+        for r in rows if r.computation not in inside
+    ]
+
+
+def _compile_keyed_with_metadata(lowered):
+    import jax
+
+    before = getattr(jax.config, _METADATA_IN_KEY)
+    jax.config.update(_METADATA_IN_KEY, True)
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update(_METADATA_IN_KEY, before)
+
 
 def record_executable_cost(name: str, jitted, *args, **kwargs) -> dict:
     """Price ``jitted`` (a ``jax.jit`` callable) for call args once under
-    ``name``; later calls with the same name return the cached record.
+    ``name``, and keep its phase map (:func:`executable_phases`); later
+    calls with the same name return the cached record.
     A column the backend does not report (``cost_analysis`` without a
     ``flops`` entry, ``memory_analysis`` returning None) is absent from
     the record; an error lowering or compiling propagates — the live call
@@ -209,7 +369,7 @@ def record_executable_cost(name: str, jitted, *args, **kwargs) -> dict:
     if name in _EXEC_COSTS:
         return dict(_EXEC_COSTS[name])
     out: Dict[str, float] = {}
-    compiled = jitted.lower(*args, **kwargs).compile()
+    compiled = _compile_keyed_with_metadata(jitted.lower(*args, **kwargs))
     ca = compiled.cost_analysis() or {}
     if "flops" in ca:
         out["flops"] = float(ca["flops"])
@@ -221,9 +381,28 @@ def record_executable_cost(name: str, jitted, *args, **kwargs) -> dict:
             out[key] = float(getattr(ma, attr))
         out["hbm_peak_bytes"] = sum(out[key] for _, key in _MEMORY_FIELDS)
     _EXEC_COSTS[name] = out
+    rows = op_scopes(compiled.as_text())
+    _EXEC_PHASES[name] = {
+        "ops": {r.name: r.scopes for r in rows if r.scopes},
+        "inherited": sum(bool(r.scopes) and not r.own for r in rows),
+        "unscoped": sum(not r.scopes for r in rows),
+    }
     return dict(out)
 
 
 def executable_costs() -> Dict[str, dict]:
     """Snapshot of every priced executable: name -> cost record."""
     return {k: dict(v) for k, v in _EXEC_COSTS.items()}
+
+
+def executable_phases() -> Dict[str, dict]:
+    """Snapshot of every captured executable's phase map: name ->
+    ``{"ops": {instruction name: (scope, ...)}, "inherited": n,
+    "unscoped": n}``. ``ops`` holds the instructions that lie under a
+    device scope, outermost scope first: an operation's PHASE is the first
+    one, and it lies under every scope named. ``inherited`` of them had no
+    metadata of their own and took the scopes of the operation they feed or
+    of their loop (:func:`op_scopes`); ``unscoped`` counts the instructions
+    left with none. An executable whose program has no scope has an empty
+    ``ops``: read that as "no map", never as zeros."""
+    return {k: dict(v, ops=dict(v["ops"])) for k, v in _EXEC_PHASES.items()}

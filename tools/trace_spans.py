@@ -3,6 +3,7 @@
 
     python3 tools/trace_spans.py client [--ticks N] [--branches B]
     python3 tools/trace_spans.py server [--frames N] [--capacity S]
+                                        [--title box_game|boids|particles]
 
 Holds a ``jax.profiler`` session around N paced ticks of ``chip_smoke.py``'s
 singleton P2P pair (peer 0 speculating, WAN loopback profile) or N served
@@ -18,7 +19,15 @@ plane beside ``XLA Ops``. Prints, and writes to
   ``idle_gaps``: gaps of 20 us or more by span, shorter ones summed as
   ``between_ops_under_20us``, what no span covers as ``unattributed``); the
   tool's own phases (``tool/sleep``, ``tool/far_end``, ``tool/readable``)
-  name what is outside the program.
+  name what is outside the program;
+- ``server`` only: EVERY device operation of the traced stretch with its
+  phase and its device scopes (``obs/trace.py`` ``device_scope``, read off
+  the compiled tick by ``utils.xla_cache.executable_phases()``: the real
+  sink arms that capture), its self time a dispatch of the batched tick and
+  its share of the program, then the totals by phase and by scope
+  (``device_ops`` / ``device_phases`` / ``device_scopes`` in the JSON): what
+  a dispatch is made of, under names that survive a recompile. The join is
+  the benchmark's (``benchmark/readers/trace_phase.py``).
 
 Without a TPU the trace has no device plane and only the span table is
 printed.
@@ -116,6 +125,80 @@ def charge_idle(blocks, spans, window):
     return dict(reduce_trace.idle_gaps(trace, window, n=len(spans) + 2))
 
 
+TICK_PROGRAM = "^jit__tick_impl"   # the [S]-vmapped batched tick
+TICK_CAPTURE = "batched_tick"      # its name in ``executable_phases()``
+NO_SCOPE = "(no scope; other programs' operations too)"
+GAPS = "(between operations)"
+
+
+def device_ops_table(trace, ops, program=TICK_PROGRAM):
+    """``trace``'s operation self times (a ``reduce_trace.Trace``) joined
+    with the phase map ``ops`` (``{instruction name: (scope, ...)}``), a
+    dispatch of the programs that match ``program``: every operation with
+    its phase (its first scope), the scopes inside it, its ms and its share
+    of the program, and the totals by phase and by scope. None where no
+    such program ran on a device. The join is the benchmark's
+    (``benchmark/readers/trace_phase.py`` ``scoped_ops``)."""
+    from benchmark.readers.trace_phase import scoped_ops
+
+    program_s, n = reduce_trace.program_time(
+        trace, program, (float("-inf"), float("inf")))
+    if not trace.op_self_s or not n:
+        return None
+    per = 1e3 / n   # seconds summed -> ms a dispatch (one a device)
+    program_ms = 1e3 * program_s / n
+    acc = collections.defaultdict(float)
+    under_of = {}
+    for key, seconds, under in scoped_ops(trace, ops):
+        acc[key] += seconds * per
+        under_of[key] = under
+    rows = []
+    phases = collections.defaultdict(float)
+    scopes = collections.defaultdict(float)
+    for key, ms in sorted(acc.items(), key=lambda kv: -kv[1]):
+        under = under_of[key]
+        rows.append({"op": key, "phase": under[0] if under else None,
+                     "scopes": list(under[1:]), "ms": ms,
+                     "share": ms / program_ms})
+        phases[under[0] if under else NO_SCOPE] += ms
+        for name in set(under[1:]):
+            scopes[name] += ms
+    phases[GAPS] = program_ms - sum(acc.values())
+    return {"dispatches": n, "tick_program_ms": program_ms,
+            "device_ops": rows, "device_phases": dict(phases),
+            "device_scopes": dict(scopes)}
+
+
+def device_ops_report(xspace, out) -> None:
+    """Print the served tick's device time by operation, phase and scope,
+    and keep it in ``out``."""
+    from benchmark.readers.trace_phase import phase_map
+
+    ops = phase_map(TICK_CAPTURE)
+    table = device_ops_table(reduce_trace.load(xspace), ops)
+    if table is None:
+        print("no batched tick on a device plane: no operation table")
+        return
+    if not ops:
+        print("the compiled tick holds no device scope: no phase map")
+    out.update(table)
+    program_ms = table["tick_program_ms"]
+    print(f"\n{table['dispatches']} dispatches of the batched tick, "
+          f"{program_ms:.4f} ms each; {len(table['device_ops'])} device "
+          "operations, ms a dispatch:")
+    print(f"{'operation':44s} {'phase':12s} {'scopes':34s} {'ms':>9s} "
+          f"{'share':>7s}")
+    for r in table["device_ops"]:
+        print(f"{r['op']:44s} {str(r['phase'] or '-'):12s} "
+              f"{'/'.join(r['scopes']) or '-':34s} {r['ms']:9.4f} "
+              f"{100 * r['share']:6.2f}%")
+    for title, key in (("by phase", "device_phases"),
+                       ("by scope", "device_scopes")):
+        print(f"\n{title} (ms a dispatch, share of the program):")
+        for name, ms in sorted(table[key].items(), key=lambda kv: -kv[1]):
+            print(f"  {name:40s} {ms:9.4f} {100 * ms / program_ms:6.2f}%")
+
+
 def report(xspace, mode, extra):
     from jax.profiler import ProfileData
 
@@ -161,6 +244,8 @@ def report(xspace, mode, extra):
             print(f"idle inside program spans {inside:.5f} s, of which "
                   f"{100 * idle.get(top, 0.0) / inside:.2f} % in {top} "
                   "itself (no narrower span)")
+        if mode == "server":
+            device_ops_report(xspace, out)
     path = os.path.join(ROOT, "chiprun_out", f"trace_spans_{mode}.json")
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w", encoding="utf-8") as f:
@@ -256,22 +341,47 @@ def drive_client(ticks: int, branches: int, warmup: int):
                     "series_median_ms": series_medians(metrics)}
 
 
-def drive_server(frames: int, capacity: int, groups: int, warmup: int):
+def served_title(name: str):
+    """``(schedule, world, input spec)`` of a title at its served cell's
+    size (``BENCHMARK.json``: box_game, the 1,024-boid flock on the MXU
+    force, upstream's particle stress test)."""
+    import chip_smoke
+
+    if name == "boids":
+        from bevy_ggrs_tpu.models import boids
+
+        return (boids.make_schedule(kernel="mxu"),
+                boids.make_world(1024, chip_smoke.PLAYERS).commit(),
+                boids.INPUT_SPEC)
+    if name == "particles":
+        from bevy_ggrs_tpu.models import particles
+
+        return (particles.make_schedule(),
+                particles.make_world(chip_smoke.PLAYERS).commit(),
+                particles.INPUT_SPEC)
+    from bevy_ggrs_tpu.models import box_game
+
+    return (box_game.make_schedule(),
+            box_game.make_world(chip_smoke.PLAYERS).commit(),
+            box_game.INPUT_SPEC)
+
+
+def drive_server(frames: int, capacity: int, groups: int, warmup: int,
+                 title: str = "box_game"):
     import jax
     import numpy as np
 
     import chip_smoke
-    from bevy_ggrs_tpu.models import box_game
     from bevy_ggrs_tpu.serve.server import MatchServer
     from bevy_ggrs_tpu.session import SessionBuilder
     from bevy_ggrs_tpu.utils.metrics import Metrics
 
     annotate = jax.profiler.TraceAnnotation
     metrics = Metrics()
+    schedule, world, input_spec = served_title(title)
     server = MatchServer(
-        box_game.make_schedule(),
-        box_game.make_world(chip_smoke.PLAYERS).commit(),
-        chip_smoke.WINDOW, chip_smoke.PLAYERS, box_game.INPUT_SPEC,
+        schedule, world,
+        chip_smoke.WINDOW, chip_smoke.PLAYERS, input_spec,
         capacity=capacity, stagger_groups=groups, num_branches=8,
         spec_frames=8, metrics=metrics,
     )
@@ -279,7 +389,7 @@ def drive_server(frames: int, capacity: int, groups: int, warmup: int):
     offsets = np.random.RandomState(5).randint(0, 16, size=capacity)
     for k in range(capacity):
         session = (
-            SessionBuilder(box_game.INPUT_SPEC)
+            SessionBuilder(input_spec)
             .with_num_players(chip_smoke.PLAYERS)
             .with_max_prediction_window(chip_smoke.WINDOW)
             .with_check_distance(2)
@@ -302,7 +412,7 @@ def drive_server(frames: int, capacity: int, groups: int, warmup: int):
     server.close()
     for k, v in metrics.series.items():
         del v[:base.get(k, 0)]
-    return xspace, {"frames": frames, "capacity": capacity,
+    return xspace, {"frames": frames, "capacity": capacity, "title": title,
                     "series_median_ms": series_medians(metrics)}
 
 
@@ -314,6 +424,9 @@ def main(argv=None) -> int:
     p.add_argument("--frames", type=int, default=4)
     p.add_argument("--capacity", type=int, default=256)
     p.add_argument("--groups", type=int, default=4)
+    p.add_argument("--title", default="box_game",
+                   choices=("box_game", "boids", "particles"),
+                   help="server: the title hosted, at its served cell's size")
     p.add_argument("--warmup", type=int, default=None)
     args = p.parse_args(argv)
     from bevy_ggrs_tpu.utils import xla_cache
@@ -327,7 +440,7 @@ def main(argv=None) -> int:
     else:
         xspace, extra = drive_server(
             args.frames, args.capacity, args.groups,
-            4 if args.warmup is None else args.warmup)
+            4 if args.warmup is None else args.warmup, args.title)
     import jax
 
     extra["platform"] = jax.devices()[0].platform
