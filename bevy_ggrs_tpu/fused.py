@@ -58,20 +58,27 @@ from bevy_ggrs_tpu.parallel.speculate import match_branch
 from bevy_ggrs_tpu.rollout import (
     deepest_lane,
     live_steps,
+    rollout_branches,
     rollout_burst,
-    rollout_steps,
+    rollout_form,
 )
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.state import (
+    ONCE,
     OWN_BUFFER_BYTES,
+    SHAPED,
     PackCodec,
     SnapshotRing,
     WorldState,
+    branch_rows_carried,
+    branch_rows_gathered,
+    branch_rows_of,
+    branch_rows_shaped,
     ring_load,
     ring_put,
-    ring_row_read,
     ring_row_write,
     ring_step_load,
+    state_shaped,
 )
 
 # The name of the axis a tick is vmapped over, where it is (the served
@@ -109,7 +116,8 @@ def _session_axis_wrap(fn, session_axis: int):
 
 def absorb_branch_frames(
     main_ring: SnapshotRing,
-    spec_ring: SnapshotRing,  # the matched branch's ring (no branch axis)
+    spec_ring: SnapshotRing,  # the matched branch's ring (no branch axis;
+    # its large rows flat, where the rollout is carried as written)
     spec_states: WorldState,  # the matched branch's final state
     first_frame: jnp.ndarray,  # first replayed frame (the Load target)
     n_frames: jnp.ndarray,  # how many (save, advance) steps were replayed
@@ -151,7 +159,8 @@ def absorb_branch_frames(
     in_ring = end < anchor + total_spec
     from_ring, _ = ring_step_load(spec_ring, end, anchor)
     state = jax.tree_util.tree_map(
-        lambda a, b: jnp.where(in_ring, a, b), from_ring, spec_states
+        lambda a, b: jnp.where(in_ring, a, b),
+        state_shaped(from_ring, spec_states), spec_states,
     )
     return main_ring, state, checksums
 
@@ -403,18 +412,32 @@ class PackedTick:
     burst masks and the all-PREDICTED rollout status are made in the
     program; the three checksum arrays come out as one. The functions here
     are ``unpack -> _tick_impl / _absorb_impl -> pack``, written for ONE
-    session: the server vmaps them over ``[S]`` (the codec keeps leading
-    axes) and says so with ``lane_axis``, the name it gave that ``vmap``'s
-    axis: the absorb and the burst then each run as many steps as the
-    deepest lane of the dispatch asks for. The live state is also returned
-    as a ``WorldState``,
-    so reading it needs no further dispatch. ``per_leaf`` (a mesh: layouts
-    are per leaf) packs nothing, through the same code."""
+    session: the server vmaps them over the slots (:attr:`carry`'s
+    ``axes`` say where each buffer of the carry has that axis) and says so
+    with ``lane_axis``, the name it gave that ``vmap``'s axis: the absorb
+    and the burst then each run as many steps as the deepest lane of the
+    dispatch asks for. The live state is also returned as a
+    ``WorldState``, so reading it needs no further dispatch.
+
+    The previous rollout is carried AS ITS LOOP WROTE IT (:attr:`form`,
+    ``rollout.py`` ``rollout_form``; ``state.py``, "A ROLLOUT's branch ring
+    is not a ring"): a large row step-major and flat, ``[F, B, n]`` here
+    and ``[F, S, B, n]`` in the server's carry, and without the branch axis
+    where no branch's inputs reach the leaf. :meth:`tick` hands the
+    rollout's buffers on as they are, :meth:`front` and :meth:`absorb` pass
+    them through, and the one reader, the absorb, shapes the row it
+    commits. :meth:`pack` / :meth:`unpack` convert from and to the
+    ``[B, F, *row]`` trees everybody off the tick path builds and reads.
+    ``per_leaf`` (a mesh: layouts are per leaf, the branch axis sharded
+    first) packs nothing and keeps that shaped form in the carry too,
+    through the same code. ``inputs`` is one frame's ``[P, *input_shape]``
+    rows (shape and dtype): what the one abstract trace that finds the
+    leaves without a branch axis feeds the schedule."""
 
     def __init__(
         self, schedule: Schedule, burst_frames: int, num_branches: int,
         spec_frames: int, copies: int = 1, per_leaf: bool = False,
-        lane_axis: Optional[str] = None,
+        lane_axis: Optional[str] = None, inputs=None,
     ):
         self.schedule = schedule
         self.lane_axis = lane_axis
@@ -422,8 +445,12 @@ class PackedTick:
         self.num_branches = int(num_branches)
         self.spec_frames = int(spec_frames)
         self._copies = int(copies)
+        self._per_leaf = bool(per_leaf)
         self._own_bytes = 0 if per_leaf else OWN_BUFFER_BYTES
+        self._inputs = inputs
         self.carry: Optional[PackCodec] = None  # bound to the first trees
+        self.form = None  # a kind a state leaf, bound with the carry
+        self._state_like = None
         u32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.uint32)
         MF, B, F = self.burst_frames, self.num_branches, self.spec_frames
         # (absorb_cs, burst_cs, spec_cs)
@@ -434,17 +461,42 @@ class PackedTick:
 
     def bind(self, trees, lead: int = 0) -> PackCodec:
         """The carry's codec, built from the first ``(ring, state,
-        prev_rings, prev_states)`` seen (``lead`` leading batch axes are
-        not part of the template)."""
-        if self.carry is None:
-            self.carry = PackCodec(
-                jax.tree_util.tree_map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape[lead:], x.dtype),
-                    trees,
-                ),
-                self._copies, self._own_bytes,
-            )
+        prev_rings, prev_states)`` seen (``[B, F, *row]`` trees; ``lead``
+        leading batch axes are not part of the template), and with it the
+        form the rollout is carried in."""
+        if self.carry is not None:
+            return self.carry
+        like = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape[lead:], x.dtype), trees
+        )
+        self._state_like = like[1]
+        if not self._per_leaf:
+            self.form = rollout_form(self.schedule, like[1], self._inputs)
+        template = jax.eval_shape(self._carried, *like)
+        own_axes = None
+        if self.form is not None:
+            # A carried leaf is the loop's buffer, slots behind the steps;
+            # a final state without its branch axis keeps the buffer the
+            # branches gave it (the program's signature stays what it was).
+            kinds = jax.tree_util.tree_leaves(self.form)
+            finals = jax.tree_util.tree_leaves(template[3])
+            wide = self.num_branches * self._copies
+            own_axes = [None] * len(jax.tree_util.tree_leaves(like[:2])) + [
+                None if kind == SHAPED else 1 for kind in kinds
+            ] + [None, None] + [  # a ring's ``frames`` and ``checksums``
+                0 if kind == ONCE and x.size * x.dtype.itemsize * wide
+                >= self._own_bytes else None
+                for kind, x in zip(kinds, finals)
+            ]
+        self.carry = PackCodec(
+            template, self._copies, self._own_bytes, own_axes
+        )
         return self.carry
+
+    def _carried(self, ring, state, prev_rings, prev_states):
+        return (ring, state) + branch_rows_carried(
+            prev_rings, prev_states, self.form
+        )
 
     def tick(self, carry, ints, bits, branch_bits):
         """``(carry, state, cs)`` of one whole tick; ``bits`` is the
@@ -469,7 +521,7 @@ class PackedTick:
             bits, status, mask, mask,
             ints[T.SPEC_FROM_LIVE] != 0, ints[T.SPEC_ANCHOR], branch_bits,
             jnp.full((F, P), PREDICTED, dtype=jnp.int32),
-            lane_axis=self.lane_axis,
+            lane_axis=self.lane_axis, form=self.form,
         )
         with device_scope("carry_codec"):
             return (
@@ -496,6 +548,7 @@ class PackedTick:
             ints[T.PREV_TOTAL],
             ints[T.DO_LOAD] != 0, ints[T.LOAD_FRAME], ints[T.START_FRAME],
             bits, status, mask, mask, lane_axis=self.lane_axis,
+            form=self.form,
         )
         with device_scope("carry_codec"):
             return (
@@ -513,7 +566,7 @@ class PackedTick:
             self.burst_frames, ring, prev_rings, prev_states,
             ints[T.BRANCH], ints[T.ABSORB_FIRST], ints[T.ABSORB_N],
             ints[T.PREV_ANCHOR], ints[T.PREV_TOTAL],
-            lane_axis=self.lane_axis,
+            lane_axis=self.lane_axis, form=self.form,
         )
         with device_scope("carry_codec"):
             return (
@@ -522,10 +575,25 @@ class PackedTick:
             )
 
     def pack(self, ring, state, prev_rings, prev_states):
-        return self.carry.pack((ring, state, prev_rings, prev_states))
+        """The carry of four ``[B, F, *row]`` trees (behind any batch axes
+        of their own)."""
+        return self.carry.pack(
+            self._carried(ring, state, prev_rings, prev_states)
+        )
 
     def unpack(self, carry):
-        return self.carry.unpack(carry)
+        """``(ring, state, prev_rings, prev_states)`` of a carry, the
+        rollout as ``[B, F, *row]`` trees whatever carried it."""
+        ring, state, prev_rings, prev_states = self.carry.unpack(carry)
+        return (ring, state) + branch_rows_shaped(
+            prev_rings, prev_states, self.form, self._state_like,
+            self.num_branches,
+        )
+
+    def unpack_main(self, carry):
+        """``(ring, state)`` of a carry: what is read of it day to day,
+        without shaping a rollout nobody asked for."""
+        return self.carry.unpack(carry)[:2]
 
     def cs_host(self, cs):
         """A tick's checksum output on the host: ``(absorb_cs, burst_cs,
@@ -561,7 +629,10 @@ class FusedTickExecutor:
     ``max_frames``); ``num_branches``/``spec_frames`` shape the rollout
     phase. What the program takes and returns is :class:`PackedTick`'s
     packed form: callers hold the CARRY between ticks (:meth:`pack` /
-    :meth:`unpack` convert, one dispatch each, off the tick path). With a
+    :meth:`unpack` convert from and to ``[B, F, *row]`` trees, one dispatch
+    each, off the tick path). ``inputs`` (one frame's ``[P, *input_shape]``
+    rows, shape and dtype) lets the carry drop the branch axis of a leaf
+    no branch's inputs reach. With a
     mesh, the carry is one buffer a leaf: the main ring/state lay out
     entity-sharded, the branch-stacked leaves over the branch axis —
     identical layouts to the separate executors they fuse, so a sharded
@@ -580,6 +651,7 @@ class FusedTickExecutor:
         state_template: Optional[WorldState] = None,
         session_axis: int = 0,
         span=None,
+        inputs=None,
     ):
         # The owning runner's one instrument (its bound ``span``): each
         # dispatch splits into ``tick_stage_args`` (the host arrays) and
@@ -601,6 +673,7 @@ class FusedTickExecutor:
             schedule, self.burst_frames, self.num_branches, self.spec_frames,
             per_leaf=mesh is not None,
             lane_axis=LANE_AXIS if self.session_axis > 0 else None,
+            inputs=inputs,
         )
         # Anonymous on purpose: the device trace knows the client's two
         # programs as ``jit__unknown`` (told from the far end's by the host
@@ -677,6 +750,7 @@ class FusedTickExecutor:
         burst_frames,
         ring, prev_rings, prev_states, branch,
         absorb_first, absorb_n, prev_anchor, prev_total, lane_axis=None,
+        form=None,
     ):
         """Absorb-only program for FULL speculation hits: commit the
         matched branch's precomputed frames into the main ring — pure
@@ -698,11 +772,16 @@ class FusedTickExecutor:
         the ring on and reads nothing of the previous rollout. (Measured
         beside the narrower form, the read alone inside the conditional
         and the loop at a trip count of 0 behind it: ``PERF.md`` section 6,
-        PR 46.)"""
+        PR 46.) ``form`` says how the previous rollout is carried
+        (:class:`PackedTick`; None: ``[B, F, *row]`` trees): the matched
+        branch is picked along a leaf's branch axis where it has one, a
+        leaf without one is taken as it is (``state.py``
+        ``branch_rows_of``)."""
 
         def commit():
-            sel = lambda x: ring_row_read(x, branch)
-            rows, final = jax.tree_util.tree_map(sel, (prev_rings, prev_states))
+            rows, final = branch_rows_of(
+                prev_rings, prev_states, branch, form, lane_axis
+            )
             return absorb_branch_frames(
                 ring, rows, final, absorb_first, absorb_n,
                 prev_anchor, prev_total, max_steps=burst_frames, n_run=n_run,
@@ -710,12 +789,13 @@ class FusedTickExecutor:
 
         def skip():  # the state is meaningless, as an empty absorb's is
             state = jax.tree_util.tree_map(
-                lambda x: jnp.zeros(x.shape[1:], x.dtype), prev_states
+                lambda x: jnp.zeros(x.shape[1:], x.dtype), ring.states
             )
             return ring, state, jnp.zeros((burst_frames, 2), jnp.uint32)
 
         with device_scope("absorb"):
             n_run = deepest_lane(absorb_n, lane_axis)
+            prev_rings = branch_rows_gathered(prev_rings, form, lane_axis)
             return jax.lax.cond(n_run > 0, commit, skip)
 
     @staticmethod
@@ -725,7 +805,7 @@ class FusedTickExecutor:
         prev_rings, prev_states, branch,
         absorb_first, absorb_n, prev_anchor, prev_total,
         do_load, load_frame, start_frame,
-        bits, status, save_mask, adv_mask, lane_axis=None,
+        bits, status, save_mask, adv_mask, lane_axis=None, form=None,
     ):
         """Phases 1 and 2 of :meth:`_tick_impl`, from the same absorb and
         burst bodies: the front program of a split tick. ``_tick_impl``
@@ -735,7 +815,7 @@ class FusedTickExecutor:
         bits."""
         ring, state_a, absorb_cs = FusedTickExecutor._absorb_impl(
             burst_frames, ring, prev_rings, prev_states, branch,
-            absorb_first, absorb_n, prev_anchor, prev_total, lane_axis,
+            absorb_first, absorb_n, prev_anchor, prev_total, lane_axis, form,
         )
         with device_scope("absorb"):
             state = jax.tree_util.tree_map(
@@ -766,14 +846,14 @@ class FusedTickExecutor:
         do_load, load_frame, start_frame,
         bits, status, save_mask, adv_mask,
         spec_from_live, spec_anchor, branch_bits, spec_status,
-        lane_axis=None,
+        lane_axis=None, form=None,
     ):
         # Phase 1 — absorb the matched branch's precomputed frames
         # (speculation hit). absorb_n == 0 leaves the ring as it is (the
         # absorb's own ``valid``) and the state by the select below.
         ring, state_a, absorb_cs = FusedTickExecutor._absorb_impl(
             burst_frames, ring, prev_rings, prev_states, branch,
-            absorb_first, absorb_n, prev_anchor, prev_total, lane_axis,
+            absorb_first, absorb_n, prev_anchor, prev_total, lane_axis, form,
         )
         with device_scope("absorb"):
             state = jax.tree_util.tree_map(
@@ -808,11 +888,10 @@ class FusedTickExecutor:
                 state,
                 ring_load(ring, spec_anchor),
             )
-            spec_rings, spec_states, spec_cs = jax.vmap(
-                lambda bb: rollout_steps(
-                    schedule, anchor_state, spec_anchor, bb, spec_status
-                )
-            )(branch_bits)
+            spec_rings, spec_states, spec_cs = rollout_branches(
+                schedule, anchor_state, spec_anchor, branch_bits,
+                spec_status, form,
+            )
         return ring, state, absorb_cs, burst_cs, spec_rings, spec_states, spec_cs
 
     # ------------------------------------------------------------------

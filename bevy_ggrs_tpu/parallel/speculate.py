@@ -38,7 +38,7 @@ import numpy as np
 from bevy_ggrs_tpu.obs.trace import Instrumented
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.state import SnapshotRing, WorldState
-from bevy_ggrs_tpu.rollout import rollout_steps
+from bevy_ggrs_tpu.rollout import rollout_branches
 
 # A branch sampler maps (key, last_bits[P, …], B, F) -> bits[B, F, P, …]:
 # the Monte Carlo input tree (survey §7 "branch selection policy").
@@ -250,12 +250,11 @@ class SpeculativeExecutor(Instrumented):
         """All-branch rollout. Each branch: (save, advance) × F from the
         same state — identical semantics to F serial
         SaveGameState/AdvanceFrame request pairs per branch, its ring in
-        step order (``rollout.py`` ``rollout_steps``)."""
-        return jax.vmap(
-            lambda bits: rollout_steps(
-                schedule, state, start_frame, bits, status
-            )
-        )(branch_bits)
+        step order and its rows in their own shapes (``rollout.py``
+        ``rollout_branches`` without a form)."""
+        return rollout_branches(
+            schedule, state, start_frame, branch_bits, status
+        )
 
     @staticmethod
     def _commit_impl(tree, branch):

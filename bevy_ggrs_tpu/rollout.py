@@ -40,9 +40,10 @@ import jax.numpy as jnp
 
 from bevy_ggrs_tpu.schedule import PlayerInputs, Schedule
 from bevy_ggrs_tpu.state import (
-    SnapshotRing, WorldState, active_checksum, ring_load, ring_of_steps,
-    ring_row_read, ring_row_write, ring_rows_flat, ring_rows_shaped,
-    ring_save, state_row,
+    ONCE, SHAPED, STEPS, SnapshotRing, WorldState, active_checksum,
+    large_row, ring_load, ring_of_steps, ring_row_lowerings, ring_row_read,
+    ring_row_write, ring_rows_flat, ring_rows_shaped, ring_save, state_row,
+    state_shaped,
 )
 
 
@@ -133,19 +134,21 @@ def rollout_steps(
     bits: jnp.ndarray,  # [frames, num_players, *input_shape]
     status: jnp.ndarray,  # int32[frames, num_players]
 ) -> Tuple[SnapshotRing, WorldState, jnp.ndarray]:
-    """A speculative rollout: ``frames`` (save, advance) steps from
-    ``state`` at ``start_frame``, every one live: the same states and
-    checksums as that many serial ``SaveGameState`` / ``AdvanceFrame``
-    pairs. Returns ``(ring, state, checksums[frames])`` with the ring in
-    STEP order (``state.py`` ``ring_of_steps``: row ``t`` is the state
-    entering frame ``start_frame + t``).
+    """A speculative rollout of ONE branch: ``frames`` (save, advance)
+    steps from ``state`` at ``start_frame``, every one live: the same
+    states and checksums as that many serial ``SaveGameState`` /
+    ``AdvanceFrame`` pairs. Returns ``(ring, state, checksums[frames])``
+    with the ring in STEP order (``state.py`` ``ring_of_steps``: row ``t``
+    is the state entering frame ``start_frame + t``) and AS WRITTEN: a
+    large row flat (``state.py`` ``FLAT_ROW_BYTES``: the stacked rows are a
+    loop-carried buffer like a burst's ring), a small one as it is.
 
     No ring is carried: the state entering a step and its checksum leave
     the loop at the loop's own counter, which every lane of every ``vmap``
     shares, so each row is written once, as one slice, whoever's frame it
-    is. A large row leaves flat (``state.py`` ``FLAT_ROW_BYTES``: the
-    stacked rows are a loop-carried buffer like a burst's ring) and is
-    shaped back once, after the loop."""
+    is, and nothing behind the loop writes it again here. Whoever wants
+    the rows in their own shapes says so (:func:`rollout_branches` without
+    a form; ``state.py`` ``state_shaped`` for one row)."""
 
     def body(state, xs):
         b, s = xs
@@ -153,7 +156,106 @@ def rollout_steps(
         return schedule(state, PlayerInputs(bits=b, status=s)), saved
 
     final, (rows, checksums) = jax.lax.scan(body, state, (bits, status))
-    return ring_of_steps(rows, state, start_frame, checksums), final, checksums
+    return ring_of_steps(rows, start_frame, checksums), final, checksums
+
+
+def rollout_form(
+    schedule: Schedule,
+    state: WorldState,  # shapes and dtypes of one state
+    inputs,  # shape and dtype of one frame's ``[P, *input_shape]`` rows
+):
+    """How a rollout of ``schedule`` from such a state is carried (a kind a
+    state leaf, ``state.py`` ``SHAPED`` / ``STEPS`` / ``ONCE``; None where
+    no row reaches ``FLAT_ROW_BYTES``: the shaped form throughout).
+
+    Whether a leaf depends on the branch is jax's own finding, not the
+    title's word: ONE abstract trace of the rollout under the branch
+    ``vmap``, each final-state leaf (a scan carries a leaf batched or not
+    as a whole: its rows and its end alike) passed through a
+    ``custom_vmap`` identity whose rule is only ever called for a batched
+    operand. Without ``inputs`` the rollout cannot be traced ahead of the
+    carry and every large leaf keeps its branch axis."""
+    leaves, treedef = jax.tree_util.tree_flatten(state)
+    large = [large_row(x) for x in leaves]
+    if not any(large):
+        return None
+    batched = [True] * len(leaves)
+    if inputs is not None:
+        batched = [False] * len(leaves)
+
+        def told(i):
+            probe = jax.custom_batching.custom_vmap(lambda x: x)
+
+            @probe.def_vmap
+            def rule(axis_size, in_batched, x):
+                batched[i] = True
+                return x, True
+
+            return probe
+
+        def finals(state, branch_bits):
+            def final_of(bits):
+                final = rollout_steps(
+                    schedule, state, jnp.int32(0), bits,
+                    jnp.zeros(bits.shape[:2], jnp.int32),
+                )[1]
+                return [
+                    told(i)(x)
+                    for i, x in enumerate(jax.tree_util.tree_leaves(final))
+                ]
+
+            return jax.vmap(final_of)(branch_bits)
+
+        counted = dict(ring_row_lowerings)  # this trace is nobody's program
+        jax.eval_shape(
+            finals, state,
+            jax.ShapeDtypeStruct((2, 2) + tuple(inputs.shape), inputs.dtype),
+        )
+        ring_row_lowerings.update(counted)
+    return jax.tree_util.tree_unflatten(treedef, [
+        SHAPED if not big else STEPS if dep else ONCE
+        for big, dep in zip(large, batched)
+    ])
+
+
+def rollout_branches(
+    schedule: Schedule,
+    state: WorldState,
+    start_frame: jnp.ndarray,
+    branch_bits: jnp.ndarray,  # [B, frames, num_players, *input_shape]
+    status: jnp.ndarray,  # int32[frames, num_players]
+    form=None,  # :func:`rollout_form`'s answer for this schedule and state
+) -> Tuple[SnapshotRing, WorldState, jnp.ndarray]:
+    """:func:`rollout_steps` of every branch of ``branch_bits`` from the
+    same ``state``: ``(rings, states, checksums[B, frames])``.
+
+    With ``form`` the rings and states come in the form the tick carries
+    them between dispatches (``state.py``, "A ROLLOUT's branch ring is not
+    a ring"): the branch ``vmap`` names each leaf's branch axis where the
+    scan's batching rule already put it (a ``STEPS`` leaf ``[frames, B,
+    n]``: the loop's ``ys`` buffer itself) or names none (a ``ONCE`` leaf
+    ``[frames, n]``, its final state ``[*row]``: computed once by the loop,
+    and not broadcast here), so no operation stands between the loop and
+    the caller that writes a large leaf's bytes again. Without, every leaf
+    is ``[B, frames, *row]`` in its own shape (the form a mesh lays out,
+    and every reader off the serving loop is handed)."""
+    one = lambda bits: rollout_steps(schedule, state, start_frame, bits, status)
+    if form is None:
+        rings, states, checksums = jax.vmap(one)(branch_bits)
+        shaped = state_shaped(rings.states, state, lead=2)
+        return rings.replace(states=shaped), states, checksums
+    kinds = jax.tree_util.tree_leaves(form)
+    ring_row_lowerings["carried"] += sum(k != SHAPED for k in kinds)
+    ring_row_lowerings["carried_once"] += sum(k == ONCE for k in kinds)
+    at = {SHAPED: 0, STEPS: 1, ONCE: None}
+    return jax.vmap(one, out_axes=(
+        SnapshotRing(
+            states=jax.tree_util.tree_map(at.get, form), frames=0,
+            checksums=0,
+        ),
+        jax.tree_util.tree_map(lambda kind: None if kind == ONCE else 0, form),
+        0,
+    ))(branch_bits)
 
 
 class RolloutExecutor:

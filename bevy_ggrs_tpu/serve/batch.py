@@ -138,6 +138,7 @@ class BatchedTickExecutor:
         burst_frames: int,
         num_branches: int,
         spec_frames: int,
+        inputs=None,
     ):
         self.schedule = schedule
         self.num_slots = int(num_slots)
@@ -146,22 +147,32 @@ class BatchedTickExecutor:
         self.spec_frames = int(spec_frames)
         # The singleton's packed tick (``fused.py`` :class:`PackedTick`:
         # carry in, carry + live states + one checksum array out), every
-        # argument carrying the slot axis. A leaf keeps a buffer of its own
-        # by the size of all ``num_slots`` copies of it.
+        # argument carrying the slot axis: in front, but for the buffers
+        # of the carry that are a rollout's rows as its loop wrote them,
+        # which have it behind the steps (``packed.carry.axes``). A leaf
+        # keeps a buffer of its own by the size of all ``num_slots`` copies
+        # of it. ``inputs`` is one frame's ``[P, *input_shape]`` rows
+        # (shape and dtype; :class:`PackedTick`).
         self.packed = PackedTick(
             schedule, self.burst_frames, self.num_branches,
             self.spec_frames, copies=self.num_slots, lane_axis=LANE_AXIS,
+            inputs=inputs,
         )
         # The device trace knows this program as ``jit__tick_impl`` (the
         # benchmark's ``tick_program_ms.serve`` finds it by that name), as
         # when ``_tick_impl`` itself was vmapped here.
         def _tick_impl(carry, ints, bits, branch_bits):
-            return self.packed.tick(carry, ints, bits, branch_bits)
+            axes = self.packed.carry.axes
+            return jax.vmap(
+                self.packed.tick, in_axes=(axes, 0, 0, 0),
+                out_axes=(axes, 0, 0), axis_name=LANE_AXIS,
+            )(carry, ints, bits, branch_bits)
 
-        self._fn = jax.jit(jax.vmap(_tick_impl, axis_name=LANE_AXIS))
+        self._fn = jax.jit(_tick_impl)
         self._admit = jax.jit(self._admit_impl)
         self._pack = jax.jit(self.packed.pack)
         self._unpack = jax.jit(self.packed.unpack)
+        self._unpack_main = jax.jit(self.packed.unpack_main)
         self._row = jax.jit(self._row_impl)
         # ``io.last``: the series ``tick_io_buffers`` of the cores that
         # share this executor.
@@ -193,14 +204,17 @@ class BatchedTickExecutor:
         return xla_cache.executable_costs().get(self._captured_name, {})
 
     def _admit_impl(self, carry, slot, new_ring, new_state):
-        rings, states, prev_rings, prev_states = self.packed.unpack(carry)
+        # The codec's own trees: the previous rollout goes through as it
+        # is carried, untouched.
+        codec = self.packed.carry
+        rings, states, prev_rings, prev_states = codec.unpack(carry)
         write = lambda stacked, row: jax.tree_util.tree_map(
             lambda R, r: jax.lax.dynamic_update_index_in_dim(R, r, slot, 0),
             stacked, row,
         )
         states = write(states, new_state)
-        carry = self.packed.pack(
-            write(rings, new_ring), states, prev_rings, prev_states
+        carry = codec.pack(
+            (write(rings, new_ring), states, prev_rings, prev_states)
         )
         return carry, states
 
@@ -230,8 +244,15 @@ class BatchedTickExecutor:
 
     def unpack(self, carry):
         """``(rings, states, prev_rings, prev_states)`` of a carry (one
-        dispatch): for readers off the serving loop."""
+        dispatch): for readers off the serving loop. The previous rollout
+        comes as ``[S, B, F, *row]`` trees, written out for the reader
+        (1.2 GB a group of the churning title, whose carry is half of
+        that): who wants the rings alone asks :meth:`unpack_main`."""
         return self._unpack(carry)
+
+    def unpack_main(self, carry):
+        """``(rings, states)`` of a carry (one dispatch)."""
+        return self._unpack_main(carry)
 
     def cs_host(self, cs):
         """A tick's checksum output on the host: ``(absorb_cs, burst_cs,
@@ -240,7 +261,9 @@ class BatchedTickExecutor:
 
     def traced_ring_rows(self) -> Dict[str, int]:
         """``{kind: ring leaves}`` the batched tick was traced with, by the
-        form its bursts carry them in ("flat" / "shaped"); told once, to
+        form its bursts carry them in ("flat" / "shaped") and its rollout
+        hands them on in ("step"; "carried" / "carried_once": ``state.py``
+        ``ring_row_lowerings``); told once, to
         the core whose warm-up traced the program ({} to every other)."""
         before, self._ring_rows0 = self._ring_rows0, None
         if before is None:
@@ -415,6 +438,7 @@ class BatchedSessionCore(Instrumented):
             self._exec = BatchedTickExecutor(
                 schedule, self.num_slots, self.burst_frames,
                 self.num_branches, self.spec_frames,
+                inputs=input_spec.zeros_np(self.num_players),
             )
         S, B, F = self.num_slots, self.num_branches, self.spec_frames
         # ONE capture of the compiled tick, armed by an operator
@@ -549,9 +573,16 @@ class BatchedSessionCore(Instrumented):
         """``(rings, states, prev_rings, prev_states)`` as trees: ONE
         unpack of the carry (a dispatch, off the serving loop), kept until
         the next dispatch replaces the carry."""
-        if self._trees is None:
+        if self._trees is None or len(self._trees) == 2:
             self._trees = self._exec.unpack(self._carry)
         return self._trees
+
+    def _main_rings(self) -> SnapshotRing:
+        """The main rings alone (``_trees`` then holds ``(rings,
+        states)``): the previous rollout stays as it is carried."""
+        if self._trees is None:
+            self._trees = self._exec.unpack_main(self._carry)
+        return self._trees[0]
 
     def _replace(self, index: int, tree) -> None:
         trees = list(self._unpacked())
@@ -560,7 +591,7 @@ class BatchedSessionCore(Instrumented):
         self._states, self._trees = trees[1], None
 
     rings = property(
-        lambda self: self._unpacked()[0],
+        lambda self: self._main_rings(),
         lambda self, tree: self._replace(0, tree),
     )
     # Every tick returns the live states: reading them never dispatches.
@@ -591,6 +622,7 @@ class BatchedSessionCore(Instrumented):
         against ``compile_counters()``)."""
         self._dispatch({})
         self._observe_warmup()
+        self._exec.unpack(self._carry)  # ``prev_rings``' reader compiles here
         # Identity write: row 0 written back onto itself compiles the
         # admit program without disturbing any occupant.
         self._admit_row(0, self.slot_ring(0), self.slot_state(0))

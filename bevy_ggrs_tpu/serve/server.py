@@ -266,6 +266,7 @@ class MatchServer(Instrumented):
         self._exec = BatchedTickExecutor(
             schedule, per_group, int(max_prediction) + 2, int(num_branches),
             int(spec_frames or max_prediction),
+            inputs=input_spec.zeros_np(int(num_players)),
         )
         self.groups: List[BatchedSessionCore] = [
             BatchedSessionCore(

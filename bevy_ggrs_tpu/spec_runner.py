@@ -834,6 +834,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
             self.spec_frames, mesh=mesh, branch_axis=branch_axis,
             entity_axis=entity_axis, state_template=self.state,
             session_axis=session_axis, span=self.span,
+            inputs=input_spec.zeros_np(self.num_players),
         )
         # A mesh lays the programs out over devices and the session axis
         # is a conformance mode of the ONE batched program: both keep the

@@ -675,10 +675,13 @@ def ring_row_write(
         return write(stack, row, index, valid)
 
 
-def ring_row_read(stack: jnp.ndarray, index: jnp.ndarray) -> jnp.ndarray:
-    """Row ``index`` of ``stack``'s leading axis: a snapshot-ring row by
-    ``frame % depth``, or the matched branch of a ``[B, ...]`` rollout."""
-    read = _row_read_at(0)
+def ring_row_read(
+    stack: jnp.ndarray, index: jnp.ndarray, axis: int = 0
+) -> jnp.ndarray:
+    """Row ``index`` of ``stack``'s axis ``axis``: a snapshot-ring row by
+    ``frame % depth``, or the matched branch of a ``[B, ...]`` rollout (of
+    the ``[F, B, n]`` rows a rollout carries as written: ``axis=1``)."""
+    read = _row_read_at(axis)
     with _scope("ring_read"):
         return (read if _traced(index) else read.fun)(stack, index)
 
@@ -691,16 +694,25 @@ def ring_put(
     valid: Optional[jnp.ndarray] = None,
 ) -> SnapshotRing:
     """Write ``state`` with its checksum ``cs`` into ``frame``'s row (a
-    no-op where ``valid`` is False). ``ring`` may hold its large rows flat
-    (:func:`ring_rows_flat`): a leaf of ``state`` is then flattened the
-    same way on its way in."""
+    no-op where ``valid`` is False). Either side may hold its large rows
+    flat (``FLAT_ROW_BYTES``): a burst's ring (:func:`ring_rows_flat`)
+    meets a shaped state, whose leaf is flattened the same way on its way
+    in; the absorb's main ring meets a row of a rollout (:func:`state_row`,
+    carried as the loop wrote it), which is shaped here, where a frame is
+    absorbed, and nowhere before."""
     frame = jnp.asarray(frame, dtype=jnp.int32)
     slot = jnp.remainder(frame, ring.depth)
+
+    def row_like(r, s):
+        if s.ndim == r.ndim - 1:
+            return s
+        if s.ndim > r.ndim - 1:
+            return _row_flat(s, 0)
+        return _rows_shaped(s, tuple(r.shape[1:]), 0)
+
     return SnapshotRing(
         states=jax.tree_util.tree_map(
-            lambda r, s: ring_row_write(
-                r, s if s.ndim == r.ndim - 1 else _row_flat(s, 0), slot, valid
-            ),
+            lambda r, s: ring_row_write(r, row_like(r, s), slot, valid),
             ring.states, state,
         ),
         frames=ring_row_write(ring.frames, frame, slot, valid),
@@ -746,9 +758,22 @@ FLAT_ROW_BYTES = 4 << 10
 # How many ring leaves each form was traced with, process-wide (``kind``:
 # "flat" / "shaped", a burst's ring by the form its loop carries it in;
 # "step", a rollout's branch ring, whose rows leave the loop in step order:
-# :func:`ring_of_steps`); ``serve/batch.py`` reports its executable's share
-# as the labelled count ``ring_row_lowering``.
-ring_row_lowerings: Dict[str, int] = {"flat": 0, "shaped": 0, "step": 0}
+# :func:`ring_of_steps`; "carried", those of them the tick carries as the
+# loop wrote them, and "carried_once", those of these without a branch
+# axis, in the ring and in the final state alike:
+# :func:`branch_rows_carried`); ``serve/batch.py`` reports its executable's
+# share as the labelled count ``ring_row_lowering``.
+ring_row_lowerings: Dict[str, int] = {
+    "flat": 0, "shaped": 0, "step": 0,
+    "carried": 0, "carried_once": 0,
+}
+
+
+def large_row(x, lead: int = 0) -> bool:
+    """Whether ``x[*lead axes, *row]`` has a row of ``FLAT_ROW_BYTES`` or
+    more (``x``: anything with a shape and a dtype)."""
+    n = int(np.prod(x.shape[lead:], dtype=np.int64))
+    return n * jnp.dtype(x.dtype).itemsize >= FLAT_ROW_BYTES
 
 
 def _row_flat(x: jnp.ndarray, lead: int) -> jnp.ndarray:
@@ -757,7 +782,7 @@ def _row_flat(x: jnp.ndarray, lead: int) -> jnp.ndarray:
     any other leaf as it is."""
     shape = tuple(x.shape[lead:])
     n = int(np.prod(shape, dtype=np.int64))
-    if len(shape) < 2 or n * x.dtype.itemsize < FLAT_ROW_BYTES:
+    if len(shape) < 2 or not large_row(x, lead):
         return x
     perm = _lanes_last(shape)
     with _scope("row_layout"):
@@ -779,18 +804,21 @@ def ring_rows_flat(ring: SnapshotRing) -> SnapshotRing:
     return ring.replace(states=states)
 
 
-def _rows_shaped(x: jnp.ndarray, row: Tuple[int, ...]) -> jnp.ndarray:
-    """The inverse of ``_row_flat(x, 1)``: ``x[depth, ...]`` with rows of
-    shape ``row``."""
-    if x.shape[1:] == row:
+def _rows_shaped(
+    x: jnp.ndarray, row: Tuple[int, ...], lead: int = 1
+) -> jnp.ndarray:
+    """The inverse of ``_row_flat(x, lead)``: ``x[*lead axes, ...]`` with
+    rows of shape ``row``."""
+    if x.shape[lead:] == row:
         return x
     perm = _lanes_last(row)
     with _scope("row_layout"):
         if perm is None:
-            return x.reshape(x.shape[:1] + row)
-        x = x.reshape(x.shape[:1] + tuple(row[a] for a in perm))
+            return x.reshape(x.shape[:lead] + row)
+        x = x.reshape(x.shape[:lead] + tuple(row[a] for a in perm))
         return jnp.transpose(
-            x, (0,) + tuple(1 + int(a) for a in np.argsort(perm))
+            x, tuple(range(lead))
+            + tuple(lead + int(a) for a in np.argsort(perm))
         )
 
 
@@ -822,6 +850,31 @@ def ring_rows_shaped(ring: SnapshotRing, like: SnapshotRing) -> SnapshotRing:
 # buffer, lane-uniform as that index is: ``depth`` then lies in the
 # sublanes of a tile and the one-row update is a strided write, 12.3 ms a
 # dispatch for the same leaf (39.6 ms the dispatch; same section).
+#
+# And it is CARRIED as the loop wrote it. Shaped back after the loop
+# (``[S, B, depth, *row]``, which is what a reader off the serving loop is
+# still handed: :func:`branch_rows_shaped`) every byte of it was written
+# three times a dispatch, the loop's ``ys``, a retiling and the copy that
+# moved ``depth`` behind the slot and branch axes, and a leaf that no
+# branch's inputs reach (seven of the churning title's eight: only its
+# emitter reads an input) was computed once by the loop and then broadcast
+# over the branches: 755 of the 1,057 MB a dispatch wrote were eight
+# identical copies, 4.3 of its 13.5 ms (``PERF.md`` section 6, PR 51). So
+# between dispatches a leaf whose row is ``FLAT_ROW_BYTES`` or more stays
+# the ``ys`` buffer: step-major and flat, ``[depth, S, B, n]`` (kind
+# ``STEPS``; ``[depth, B, n]`` for a singleton), the slot and branch
+# ``vmap``s naming those axes where the scan's batching rule already put
+# them, and WITHOUT a branch axis where jax's own trace of the rollout
+# found none (kind ``ONCE``: ``[depth, S, n]``, its final state ``[S,
+# *row]``; ``rollout.py`` ``rollout_form``). A smaller row keeps the
+# shaped form (kind ``SHAPED``: every box_game leaf, so its programs are
+# what they were), and so does every leaf of a mesh-sharded carry, whose
+# layouts are written for it. Who shapes a row is who reads one: the
+# absorb picks its lane's matched branch out of the carried rows
+# (:func:`branch_rows_of`), reads a step of them flat
+# (:func:`ring_step_load`) and shapes that one row on its way into the
+# main ring (:func:`ring_put`).
+SHAPED, STEPS, ONCE = "shaped", "steps", "once"
 
 
 def state_row(state: WorldState) -> WorldState:
@@ -830,23 +883,208 @@ def state_row(state: WorldState) -> WorldState:
     return jax.tree_util.tree_map(lambda x: _row_flat(x, 0), state)
 
 
+def state_shaped(
+    row: WorldState, like: WorldState, lead: int = 0
+) -> WorldState:
+    """The inverse of :func:`state_row`: ``row``'s leaves (behind ``lead``
+    axes of their own) in the shapes ``like``'s have."""
+    return jax.tree_util.tree_map(
+        lambda x, ref: _rows_shaped(x, tuple(ref.shape), lead), row, like
+    )
+
+
 def ring_of_steps(
     rows: WorldState,  # [depth] :func:`state_row`s, stacked in step order
-    like: WorldState,  # one state: the shapes the rows go back to
     start_frame: jnp.ndarray,
     checksums: jnp.ndarray,  # uint32[depth, 2]
 ) -> SnapshotRing:
     """The branch ring of a rollout: row ``t`` is the state that entered
-    frame ``start_frame + t``, with its checksum; ``frames`` says so."""
+    frame ``start_frame + t``, with its checksum; ``frames`` says so. The
+    rows stay as the loop wrote them, the large ones flat: nothing here
+    touches a leaf's bytes."""
     depth = checksums.shape[0]
     ring_row_lowerings["step"] += len(jax.tree_util.tree_leaves(rows))
     return SnapshotRing(
-        states=jax.tree_util.tree_map(
-            lambda x, ref: _rows_shaped(x, tuple(ref.shape)), rows, like
-        ),
+        states=rows,
         frames=jnp.asarray(start_frame, jnp.int32)
         + jnp.arange(depth, dtype=jnp.int32),
         checksums=checksums,
+    )
+
+
+def _lead_index(lead: int, i: int) -> tuple:
+    return (slice(None),) * lead + (i,)
+
+
+def branch_rows_carried(
+    rings: SnapshotRing, states: WorldState, form
+) -> Tuple[SnapshotRing, WorldState]:
+    """A rollout's ``[*lead, B, F, *row]`` rings and ``[*lead, B, *row]``
+    final states in the form the tick carries them (``form``: a kind a
+    state leaf, or None for the shaped form throughout): a ``STEPS`` leaf
+    ``[F, *lead, B, n]``, a ``ONCE`` leaf ``[F, *lead, n]`` and its final
+    state ``[*lead, *row]``, both from branch 0 (whoever packs trees of
+    their own making gets no more of such a leaf back). The way in for
+    trees built off the serving loop; the tick itself never calls it."""
+    if form is None:
+        return rings, states
+    lead = rings.frames.ndim - 2
+
+    def ring_leaf(kind, x):
+        if kind == SHAPED:
+            return x
+        if kind == ONCE:
+            x = x[_lead_index(lead, 0)]
+        at = lead + (kind == STEPS)  # where the step axis stands
+        return jnp.moveaxis(_row_flat(x, at + 1), at, 0)
+
+    return (
+        rings.replace(
+            states=jax.tree_util.tree_map(ring_leaf, form, rings.states)
+        ),
+        jax.tree_util.tree_map(
+            lambda kind, x: x[_lead_index(lead, 0)] if kind == ONCE else x,
+            form, states,
+        ),
+    )
+
+
+def branch_rows_shaped(
+    rings: SnapshotRing, states: WorldState, form, like: WorldState,
+    num_branches: int,
+) -> Tuple[SnapshotRing, WorldState]:
+    """The inverse of :func:`branch_rows_carried`, for readers off the
+    serving loop: ``[*lead, B, F, *row]`` rings and ``[*lead, B, *row]``
+    states with rows in the shapes ``like``'s leaves have, a ``ONCE`` leaf
+    the same in every branch."""
+    if form is None:
+        return rings, states
+    lead = rings.frames.ndim - 2
+
+    def every_branch(x):
+        return jnp.broadcast_to(
+            jnp.expand_dims(x, lead),
+            x.shape[:lead] + (num_branches,) + x.shape[lead:],
+        )
+
+    def ring_leaf(kind, x, ref):
+        if kind == SHAPED:
+            return x
+        x = jnp.moveaxis(x, 0, lead + (kind == STEPS))
+        if kind == ONCE:
+            x = every_branch(x)
+        return _rows_shaped(x, tuple(ref.shape), lead + 2)
+
+    return (
+        rings.replace(
+            states=jax.tree_util.tree_map(
+                ring_leaf, form, rings.states, like
+            )
+        ),
+        jax.tree_util.tree_map(
+            lambda kind, x: every_branch(x) if kind == ONCE else x,
+            form, states,
+        ),
+    )
+
+
+# vmap-of-cond moves a batched operand's axis to the front IN FRONT of the
+# conditional: for a ``[depth, S, B, n]`` leaf a transposition of the whole
+# ring, every dispatch, hit or no hit. An operand without the batch axis
+# goes in as it is. So in front of the absorb's conditional the carried
+# leaves of all lanes are gathered (``lax.all_gather`` over the slot
+# ``vmap``'s axis at the position the lanes already stand in: under that
+# ``vmap`` the identity), and inside it a lane takes its own rows back
+# through the rule below, which reads the gathered buffer where it lies.
+
+
+def branch_rows_gathered(rings: SnapshotRing, form, lane_axis: Optional[str]):
+    """``rings`` with every carried leaf holding the rows of ALL lanes of
+    the ``vmap`` over ``lane_axis`` (``[depth, S, ...]``): what
+    :func:`branch_rows_of` reads under that ``vmap``."""
+    if form is None or lane_axis is None:
+        return rings
+    return rings.replace(states=jax.tree_util.tree_map(
+        lambda kind, x: x if kind == SHAPED
+        else jax.lax.all_gather(x, lane_axis, axis=1),
+        form, rings.states,
+    ))
+
+
+@functools.lru_cache(maxsize=None)
+def _lane_rows(branched: bool):
+    """``rows(whole, lane, branch)``: lane ``lane``'s ``[depth, n]`` rows
+    out of ``whole[depth, S, B, n]``, its branch ``branch``'s (out of
+    ``whole[depth, S, n]`` where not ``branched``). ``lane`` is the index
+    along the axis being vmapped (``lax.axis_index``), so under that
+    ``vmap`` lane ``s`` reads column ``s``: one select a branch over the
+    buffer as it lies, no gather."""
+
+    @jax.custom_batching.custom_vmap
+    def rows(whole, lane, branch):
+        mine = jax.lax.dynamic_index_in_dim(whole, lane, 1, keepdims=False)
+        if not branched:
+            return mine
+        return jax.lax.dynamic_index_in_dim(mine, branch, 1, keepdims=False)
+
+    @rows.def_vmap
+    def rows_vmap(axis_size, in_batched, whole, lane, branch):
+        whole_b, lane_b, branch_b = in_batched
+        if whole_b or not lane_b or whole.shape[1] != axis_size:
+            raise NotImplementedError(
+                "a lane's rows are read from the gathered rows of the axis "
+                "being vmapped, by that axis's index"
+            )
+        if not branched:
+            return jnp.moveaxis(whole, 1, 0), True
+        n = whole.shape[2]
+        if n > SELECT_ROWS:
+            return _row_read_one_hot(
+                jnp.moveaxis(whole, 1, 0), _batched(branch, branch_b, axis_size), 2
+            ), True
+        branch = _batched(_clamp(branch, n), branch_b, axis_size)
+        hit = branch.reshape((1, axis_size) + (1,) * (whole.ndim - 3))
+        row = lambda d: jax.lax.index_in_dim(whole, d, 2, keepdims=False)
+        picked = row(0)
+        for d in range(1, n):
+            picked = jnp.where(hit == d, row(d), picked)
+        return jnp.moveaxis(picked, 1, 0), True
+
+    return rows
+
+
+def branch_rows_of(
+    rings: SnapshotRing, states: WorldState, branch: jnp.ndarray, form,
+    lane_axis: Optional[str] = None,
+) -> Tuple[SnapshotRing, WorldState]:
+    """Branch ``branch``'s ring and final state out of a rollout's, as the
+    absorb reads them: a leaf with a branch axis picked along it, a leaf
+    without one (``ONCE``) taken as it is; the carried rows stay flat,
+    ``[depth, n]``. Under the slot ``vmap`` (``lane_axis``) the carried
+    leaves are :func:`branch_rows_gathered`'s."""
+    sel = lambda x: ring_row_read(x, branch)
+    if form is None:
+        return jax.tree_util.tree_map(sel, (rings, states))
+
+    def ring_leaf(kind, x):
+        if kind == SHAPED:
+            return sel(x)
+        if lane_axis is not None:
+            with _scope("ring_read"):
+                return _lane_rows(kind == STEPS)(
+                    x, jax.lax.axis_index(lane_axis), branch
+                )
+        return ring_row_read(x, branch, axis=1) if kind == STEPS else x
+
+    return (
+        SnapshotRing(
+            states=jax.tree_util.tree_map(ring_leaf, form, rings.states),
+            frames=sel(rings.frames),
+            checksums=sel(rings.checksums),
+        ),
+        jax.tree_util.tree_map(
+            lambda kind, x: x if kind == ONCE else sel(x), form, states
+        ),
     )
 
 
@@ -854,7 +1092,8 @@ def ring_step_load(
     ring: SnapshotRing, frame: jnp.ndarray, start_frame: jnp.ndarray
 ) -> Tuple[WorldState, jnp.ndarray]:
     """``(state, checksum)`` a rollout from ``start_frame`` saved for
-    ``frame`` (:func:`ring_of_steps`). The caller knows the frame to lie
+    ``frame`` (:func:`ring_of_steps`), the state as the ring holds it (a
+    :func:`state_row`: large rows flat). The caller knows the frame to lie
     inside the rollout; one past its end reads the last row (the index
     clamps) and is selected away."""
     step = jnp.asarray(frame, jnp.int32) - jnp.asarray(start_frame, jnp.int32)
@@ -917,11 +1156,18 @@ class PackCodec:
     function over axis 0 sees the unstacked form. A leaf whose bytes times
     ``copies`` (the batch it will be carried with) reach ``own_buffer_bytes``
     keeps a buffer of its own (see ``OWN_BUFFER_BYTES``); 0 packs nothing
-    (one buffer a leaf: what a mesh-sharded layout needs). ``unpack`` takes
-    NumPy arrays too (host-side reads of a packed result)."""
+    (one buffer a leaf: what a mesh-sharded layout needs). ``own_axes``
+    (an entry a leaf, in tree order; None: the rule above) names the leaves
+    that keep a buffer of their own whatever their size, with the position
+    at which THEIR batch axes stand: a rollout's rows, carried as its loop
+    wrote them, are ``[F, S, ...]`` (position 1), and a packed copy of such
+    a leaf would write its bytes again. :attr:`axes` says, buffer by
+    buffer, where a ``vmap`` over a packed function finds that axis.
+    ``unpack`` takes NumPy arrays too (host-side reads of a packed
+    result)."""
 
     def __init__(self, template, copies: int = 1,
-                 own_buffer_bytes: int = OWN_BUFFER_BYTES):
+                 own_buffer_bytes: int = OWN_BUFFER_BYTES, own_axes=None):
         leaves, self.treedef = jax.tree_util.tree_flatten(template)
         self.shapes = [tuple(x.shape) for x in leaves]
         self.dtypes = [jnp.dtype(x.dtype) for x in leaves]
@@ -929,8 +1175,12 @@ class PackCodec:
         self._groups: Dict[str, int] = {}  # dtype name -> packed elements
         # Per leaf: (dtype name, offset, size), or None for its own buffer.
         self._plan: list = []
-        for n, dt in zip(sizes, self.dtypes):
-            if n * dt.itemsize * copies >= own_buffer_bytes:
+        # Per leaf: where its batch axes stand (0 unless ``own_axes`` says).
+        self._at = [0 if a is None else int(a)
+                    for a in own_axes or [None] * len(leaves)]
+        own = [a is not None for a in own_axes or [None] * len(leaves)]
+        for n, dt, mine in zip(sizes, self.dtypes, own):
+            if mine or n * dt.itemsize * copies >= own_buffer_bytes:
                 self._plan.append(None)
                 continue
             off = self._groups.get(dt.name, 0)
@@ -939,6 +1189,9 @@ class PackCodec:
         self._order = sorted(self._groups)
         self.num_buffers = len(self._order) + self._plan.count(None)
         self._perms = [_lanes_last(s) for s in self.shapes]
+        self.axes = (0,) * len(self._order) + tuple(
+            at for at, plan in zip(self._at, self._plan) if plan is None
+        )
 
     def pack(self, tree) -> Tuple[jnp.ndarray, ...]:
         leaves, treedef = jax.tree_util.tree_flatten(tree)
@@ -946,14 +1199,18 @@ class PackCodec:
             raise ValueError(f"pack: tree {treedef} != template {self.treedef}")
         parts: Dict[str, list] = {name: [] for name in self._order}
         own = []
-        for x, shape, dt, plan, perm in zip(
-            leaves, self.shapes, self.dtypes, self._plan, self._perms
+        for x, shape, dt, plan, perm, at in zip(
+            leaves, self.shapes, self.dtypes, self._plan, self._perms,
+            self._at,
         ):
             lead = x.ndim - len(shape)
-            if lead < 0 or tuple(x.shape[lead:]) != shape or x.dtype != dt:
+            if (
+                lead < 0 or x.dtype != dt
+                or tuple(x.shape[:at] + x.shape[at + lead:]) != shape
+            ):
                 raise ValueError(
-                    f"pack: leaf {x.dtype}{tuple(x.shape)} does not end in "
-                    f"the template's {dt}{shape}"
+                    f"pack: leaf {x.dtype}{tuple(x.shape)} is not the "
+                    f"template's {dt}{shape} with batch axes at {at}"
                 )
             if plan is None:
                 own.append(x)

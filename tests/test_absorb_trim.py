@@ -109,9 +109,11 @@ def full_absorb(main_ring, spec_ring, spec_states, first_frame, n_frames,
 
 def full_absorb_impl(burst_frames, ring, prev_rings, prev_states, branch,
                      absorb_first, absorb_n, prev_anchor, prev_total,
-                     lane_axis=None):
+                     lane_axis=None, form=None):
     """``_absorb_impl`` with the matched branch read whoever commits, the
-    full scan, and the ring selected back where nobody did."""
+    full scan, and the ring selected back where nobody did (over
+    ``[B, F, *row]`` trees: the parent knew no other form)."""
+    assert form is None
     sel = lambda x: ring_row_read(x, branch)       # noqa: E731
     ring_a, state, cs = full_absorb(
         ring, jax.tree_util.tree_map(sel, prev_rings),

@@ -1,6 +1,8 @@
 """The MXU force kernel compiled for a described v5e, at the shapes the
 benchmark's cells run (no chip: the TPU's compiler is installed here and
-compiles for a chip that is described and not attached).
+compiles for a chip that is described and not attached); and the served
+particles tick at a small ``[S] x [B] x F``, for what its outputs are made
+of (PR 51: a rollout's rows leave the program as its loop wrote them).
 
 Interpret mode cannot see what Mosaic refuses: a window of a lane-major
 operand that is not tile-aligned (a 64-row strip of ``trpx`` was refused
@@ -14,13 +16,20 @@ section 2) and every compile happens in this process. Keep such tests in
 this one file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from bevy_ggrs_tpu.models import boids
+from bevy_ggrs_tpu import fused
+from bevy_ggrs_tpu.models import boids, particles
+from bevy_ggrs_tpu.ops import checksum as checksum_ops
 from bevy_ggrs_tpu.ops import pairwise
+from bevy_ggrs_tpu.serve.batch import BatchedSessionCore
+from bevy_ggrs_tpu.state import ONCE
 
 
 @pytest.fixture(scope="module")
@@ -80,3 +89,70 @@ def test_mxu_force_kernel_compiles_for_a_v5e(shape, one_chip, monkeypatch):
     # The trace finds the kernel by the scope's last part (FORCE_SCOPE).
     assert "pairwise_force" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def _served_particles_tick(one_chip):
+    """The ``[2] x [8] x 8`` batched tick of ``particles`` at 4,096 rows,
+    compiled; the kinds its carry gives the state's leaves."""
+    S, B, F = 2, 8, 8
+    core = BatchedSessionCore(
+        particles.make_schedule(7), particles.make_world(2, 4096, 7).commit(),
+        8, 2, particles.INPUT_SPEC, num_slots=S, num_branches=B,
+        spec_frames=F)
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (core._carry,) + tuple(core._host_args()))
+    return core._exec._fn.lower(*args).compile(), core._exec.packed.form
+
+
+def _output_definitions(text):
+    """The defining instruction of every output of the entry computation."""
+    entry = text[text.index("ENTRY"):]
+    defined = dict(re.findall(r"(?m)^\s*(?:ROOT )?%?([\w.\-]+) = (.*)$", entry))
+    root = re.search(r"(?m)^\s*ROOT [^\n]*tuple\(([^\n]*)", entry).group(1)
+    return [defined[name] for name in re.findall(r"%([\w.\-]+)", root)]
+
+
+def test_served_particles_tick_writes_no_branch_invariant_row_twice(
+        one_chip, monkeypatch):
+    """A leaf no branch's inputs reach leaves the compiled tick once, not
+    broadcast over the branches: the outputs shrink by those copies, and no
+    output of a ring leaf's size is a broadcast. The parent's form (every
+    leaf ``[S, B, F, *row]``) beside it, so that the test sees what it
+    says."""
+    monkeypatch.setattr(checksum_ops, "pallas_interpret", lambda: False)
+    S, B, F, rows = 2, 8, 8, 4096
+    change, form = _served_particles_tick(one_chip)
+    monkeypatch.setattr(fused, "rollout_form", lambda *a: None)
+    parent, _ = _served_particles_tick(one_chip)
+
+    state = particles.make_world(2, rows, 7).commit()
+    once = [x for kind, x in zip(jax.tree_util.tree_leaves(form),
+                                 jax.tree_util.tree_leaves(state))
+            if kind == ONCE]
+    assert len(once) == 7
+    copies = sum(x.nbytes for x in once) * S * (B - 1) * (F + 1)
+    saved = (parent.memory_analysis().output_size_in_bytes
+             - change.memory_analysis().output_size_in_bytes)
+    assert saved >= 0.95 * copies
+
+    def broadcasts(compiled, outputs_only):
+        """Instructions that broadcast to a tensor of a whole branch-ring
+        leaf's size (of one carried once, among the outputs)."""
+        text = compiled.as_text()
+        leaf = S * F * rows * (1 if outputs_only else B)
+        lines = _output_definitions(text) if outputs_only else re.findall(
+            r"(?m)^\s*%?[\w.\-]+ = (.*)$", text)
+        found = []
+        for line in lines:
+            shape = re.match(r"\w+\[([\d,]*)\][^ ]* broadcast\(", line)
+            if shape and np.prod(
+                    [int(d) for d in shape.group(1).split(",") if d]) >= leaf:
+                found.append(line)
+        return found
+
+    # At this size the parent packs most of the leaves it broadcast (they
+    # feed a concatenation, not an output): it has them all the same.
+    assert len(broadcasts(parent, False)) == len(once)
+    assert not broadcasts(change, False)
+    assert not broadcasts(change, True)

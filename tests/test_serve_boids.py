@@ -253,7 +253,8 @@ def test_a_burst_with_flat_rows_is_bitwise_the_shaped_burst(lanes, monkeypatch):
     flat = wrap(burst)(*args)
     traced = {k: v - before[k]
               for k, v in state_mod.ring_row_lowerings.items()}
-    assert traced == {"flat": 1, "step": 0, "shaped": len(
+    # (a burst hands no rollout on: none of the rollout's kinds)
+    assert {k: n for k, n in traced.items() if n} == {"flat": 1, "shaped": len(
         jax.tree_util.tree_leaves(ring.states)) - 1}
     monkeypatch.setattr(state_mod, "FLAT_ROW_BYTES", 1 << 40)
     shaped = wrap(burst)(*args)

@@ -16,6 +16,22 @@ Structure: in the lowered ``[S]``-vmapped tick of box_game and of
 ``particles`` at a small size every branch-ring leaf leaves the rollout's
 loop through a ``dynamic_update_slice`` and no loop of the program selects
 over a leaf of that size; the count ``ring_row_lowerings["step"]`` says so.
+
+The CARRIED form (PR 51; ``state.py``, "A ROLLOUT's branch ring is not a
+ring"): between dispatches a large row stays what the rollout's scan wrote,
+step-major and flat, and has no branch axis where jax's trace of the
+rollout found none (``rollout.py`` ``rollout_form``). Values: for
+``particles`` at 4,096 rows (seven of its eight large leaves without a
+branch axis), a boids-like world (every large leaf with one) and box_game
+(no large leaf: the form unchanged), alone and under the slot ``vmap`` with
+another anchor a lane, the whole tick, the split tick's front and the
+absorb-only program return the parent's bits (the twin whose
+``rollout_form`` answers None carries ``[B, F, *row]`` trees, as the
+parent did), the carry unpacked included; ``absorb_branch_frames`` over
+every ``(anchor % F, first_frame - anchor, n_frames)`` reading the carried
+rows against the serial replay. Structure: the rollout loop's buffers ARE
+the program's outputs (no operation of the jaxpr uses them). Counter: what
+a warmed core reports.
 """
 
 import functools
@@ -27,27 +43,44 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bevy_ggrs_tpu import fused
 from bevy_ggrs_tpu.fused import (
     LANE_AXIS,
     FusedTickExecutor,
+    TickInts,
     absorb_branch_frames,
 )
 from bevy_ggrs_tpu.models import box_game, particles
-from bevy_ggrs_tpu.rollout import deepest_lane, rollout_steps
-from bevy_ggrs_tpu.schedule import PREDICTED, PlayerInputs
-from bevy_ggrs_tpu.serve.batch import BatchedTickExecutor
+from bevy_ggrs_tpu.rollout import (
+    deepest_lane,
+    rollout_branches,
+    rollout_form,
+    rollout_steps,
+)
+from bevy_ggrs_tpu.schedule import PREDICTED, PlayerInputs, Schedule
+from bevy_ggrs_tpu.serve.batch import BatchedSessionCore, BatchedTickExecutor
 from bevy_ggrs_tpu.state import (
     FLAT_ROW_BYTES,
+    ONCE,
+    SHAPED,
+    STEPS,
+    HostWorld,
+    SnapshotRing,
+    TypeRegistry,
+    branch_rows_gathered,
+    branch_rows_of,
     ring_init,
     ring_put,
     ring_save,
     ring_step_load,
 )
+from bevy_ggrs_tpu.utils.metrics import Metrics
 from tests.test_lane_uniform_ring import (
     BRANCHES,
     BURST,
     DEPTH,
     LANES,
+    LONG,
     P,
     SPEC,
     SPECIAL,
@@ -56,6 +89,7 @@ from tests.test_lane_uniform_ring import (
     lane,
     plain_schedule,
     plain_world,
+    random_like,
     random_ring,
     stack,
     tick_args,
@@ -383,3 +417,329 @@ def test_served_rollout_writes_each_branch_ring_row_once(name):
     traced = ex.traced_ring_rows()
     assert traced["step"] == len(leaves)
     assert ("flat" in traced) == (name == "particles")     # the burst's ring
+
+
+# ---------------------------------------------------------------------------
+# The carried form (PR 51)
+# ---------------------------------------------------------------------------
+
+INPUTS = np.zeros((P,), np.uint8)  # one frame's input rows
+
+
+def _flock_system(state, inputs):
+    """Every large leaf follows the inputs, as a flock follows its leaders."""
+    push = jnp.sum(inputs.bits.astype(jnp.float32))
+    vel = state.components["vel"] * jnp.float32(0.5) + push
+    pos = state.components["pos"]
+    return state.replace(components={
+        "pos": pos + jnp.pad(vel, ((0, 0), (0, 1))), "vel": vel})
+
+
+@functools.lru_cache(maxsize=None)
+def carried_title(name):
+    """``(schedule, state, kinds of its large leaves)``."""
+    if name == "box_game":
+        return box_game.make_schedule(), box_game.make_world(P).commit(), []
+    if name == "particles":  # bool[4096] is a large row: eight large leaves
+        state = particles.make_world(P, 4096, 7).commit()
+        return particles.make_schedule(7), state, [STEPS] + [ONCE] * 7
+    reg = TypeRegistry()
+    reg.register_component("pos", shape=(3,))
+    reg.register_component("vel", shape=(2,))
+    world = HostWorld(reg, 512)
+    for i in range(500):
+        world.spawn({"pos": [i, -i, 0.5], "vel": [0.25 * i, -1.0]},
+                    rollback_id=i)
+    state = world.commit()
+    special = lambda x: jnp.asarray(    # noqa: E731
+        np.resize(SPECIAL, x.shape).view(np.float32))
+    return Schedule([_flock_system]), state.replace(components={
+        k: special(v) for k, v in state.components.items()}), [STEPS] * 2
+
+
+TITLES = ["particles", "flock", "box_game"]
+
+
+def parents_form(monkeypatch):
+    """From here on a carry is bound to ``[B, F, *row]`` trees throughout:
+    the parent's program (its rows shaped back behind the loop, every leaf
+    broadcast over the branches)."""
+    monkeypatch.setattr(fused, "rollout_form", lambda *a: None)
+
+
+@pytest.mark.parametrize("name", TITLES)
+def test_the_trace_finds_the_leaves_without_a_branch_axis(name):
+    schedule, state, kinds = carried_title(name)
+    form = rollout_form(schedule, state, INPUTS)
+    if not kinds:
+        assert form is None     # no row of 4 KiB: the shaped form throughout
+        return
+    found = [k for k in jax.tree_util.tree_leaves(form) if k != SHAPED]
+    assert sorted(found) == sorted(kinds)
+    if name == "particles":     # only the emitter reads an input
+        assert form.components["position"] == STEPS
+    # without the inputs' shape nothing can be traced: every leaf keeps B
+    blind = jax.tree_util.tree_leaves(rollout_form(schedule, state, None))
+    assert [k for k in blind if k != SHAPED] == [STEPS] * len(kinds)
+
+
+def _two_ticks(ex, state, lanes, plans, seed):
+    """A rollout from each lane's anchor, then a tick that absorbs
+    ``plans[lane] = (branch, d, n, m)``: ``n`` frames of ``branch`` from
+    frame ``anchor + d`` and ``m`` burst steps behind them. Returns what
+    both dispatches returned, the carries unpacked."""
+    rng = np.random.default_rng(seed)
+    lead = (lanes,) if lanes else ()
+    anchors = 20 + 3 * np.arange(max(lanes, 1))
+    carry, ints, bits, bb = tick_args(
+        ex, state, lanes, BURST, BRANCHES, SPEC)
+    bb = rng.integers(0, 16, size=bb.shape).astype(np.uint8)
+    rows = ints.reshape(-1, ints.shape[-1])
+    for row, a in zip(rows, anchors):   # nothing but a rollout from ``a``
+        row[TickInts.START_FRAME] = row[TickInts.SPEC_ANCHOR] = a
+        row[TickInts.SPEC_FROM_LIVE] = 1
+    run = (lambda c, i, b, s: ex.run(c, i, b, s)) if lanes else (
+        lambda c, i, b, s: ex.run(c, i, b[:0], np.zeros((0, P), np.int32), s))
+    first = run(carry, ints, bits, bb)
+    ints2 = np.zeros_like(ints)
+    bits2 = np.zeros_like(bits)
+    rows = ints2.reshape(-1, ints.shape[-1])
+    burst = bits2.reshape((-1,) + bits.shape[-2:])
+    for i, (row, a, (branch, d, n, m)) in enumerate(zip(rows, anchors, plans)):
+        T = TickInts
+        row[[T.BRANCH, T.ABSORB_FIRST, T.ABSORB_N, T.PREV_ANCHOR,
+             T.PREV_TOTAL]] = branch, a + d, n, a, SPEC
+        row[T.START_FRAME], row[T.N_BURST] = a + d + n, m
+        row[T.SPEC_FROM_LIVE], row[T.SPEC_ANCHOR] = 1, a + d + n + m
+        burst[i, :m] = rng.integers(0, 16, size=(m, P))
+    if lanes:
+        second = ex.run(first[0], ints2, bits2, bb[..., ::-1, :, :].copy())
+    else:
+        m = plans[0][3]
+        second = ex.run(first[0], ints2, bits2[:m],
+                        np.zeros((m, P), np.int32), bb[::-1].copy())
+    opened = lambda out: (ex.unpack(out[0]), out[1], ex.cs_host(out[2]))  # noqa
+    return opened(first), opened(second), (first[0], ints2, bits2)
+
+
+# (branch, first_frame - anchor, frames absorbed, burst steps); nobody
+# absorbs in the first: the conditional's other side.
+PLANS = {
+    "nobody": [(0, 0, 0, 1), (0, 0, 0, 2), (0, 0, 0, 0), (0, 0, 0, 1)],
+    "some": [(3, 0, 2, 1), (0, 0, 0, 2), (1, 1, 2, 0), (2, 0, SPEC, 2)],
+}
+
+
+@pytest.mark.parametrize("plan", list(PLANS))
+@pytest.mark.parametrize("lanes", [0, LANES], ids=["alone", "lanes"])
+@pytest.mark.parametrize("name", TITLES)
+def test_carried_tick_returns_the_parents_bits(name, lanes, plan, monkeypatch):
+    schedule, state, kinds = carried_title(name)
+    make = (lambda: BatchedTickExecutor(
+        schedule, LANES, BURST, BRANCHES, SPEC, inputs=INPUTS)) if lanes else (
+        lambda: FusedTickExecutor(
+            schedule, BURST, BRANCHES, SPEC, inputs=INPUTS))
+    plans = PLANS[plan] if lanes else PLANS[plan][-1:]
+    ex = make()
+    got = _two_ticks(ex, state, lanes, plans, seed=len(name))
+    form = ex.packed.form
+    assert sorted(k for k in jax.tree_util.tree_leaves(form)
+                  if k != SHAPED) == sorted(kinds)
+    # the carry holds a carried leaf as the scan wrote it: steps first, the
+    # slots behind them, the branches where the trace found any
+    at = (1,) * len(kinds)
+    assert tuple(a for a in ex.packed.carry.axes if a) == at
+    steps_first = [x.shape for x, a in zip(got[2][0], ex.packed.carry.axes)
+                   if a]
+    lead = (SPEC, lanes) if lanes else (SPEC,)
+    assert all(s[:len(lead)] == lead for s in steps_first)
+    assert sum(len(s) == len(lead) + 1 for s in steps_first) == kinds.count(
+        ONCE)
+    parents_form(monkeypatch)
+    parent = make()
+    want = _two_ticks(parent, state, lanes, plans, seed=len(name))
+    assert parent.packed.form is None
+    assert_bits_equal(got[0], want[0])
+    assert_bits_equal(got[1], want[1])
+
+
+@pytest.mark.parametrize("name", ["particles", "flock"])
+def test_session_axis_mode_reads_the_carried_rows(name):
+    """``GGRS_SESSION_AXIS``'s copies of one session (every argument
+    broadcast in FRONT: the carried rows' lanes are then not where the
+    gather wants them, and are moved) against the plain singleton."""
+    schedule, state, _ = carried_title(name)
+    make = lambda **kw: FusedTickExecutor(    # noqa: E731
+        schedule, BURST, BRANCHES, SPEC, inputs=INPUTS, **kw)
+    got = _two_ticks(make(session_axis=3), state, 0, [(2, 0, 2, 1)], seed=3)
+    want = _two_ticks(make(), state, 0, [(2, 0, 2, 1)], seed=3)
+    assert_bits_equal(got[:2], want[:2])
+
+
+@pytest.mark.parametrize("name", ["particles", "flock"])
+def test_front_and_absorb_programs_return_the_parents_bits(name, monkeypatch):
+    schedule, state, _ = carried_title(name)
+
+    def programs():
+        ex = FusedTickExecutor(schedule, BURST, BRANCHES, SPEC, inputs=INPUTS)
+        ex.build_front()
+        _, _, (carry, ints, bits) = _two_ticks(
+            ex, state, 0, [(2, 1, 2, 1)], seed=5)
+        front = ex.run_front(
+            carry, ints.copy(), bits[:1], np.zeros((1, P), np.int32),
+            np.zeros((BRANCHES, SPEC, P), np.uint8))
+        absorb = ex.commit_absorb(carry, 1, 21, 2, 20, SPEC)
+        return (
+            (ex.unpack(front[0]), front[1], front[2]),
+            (ex.unpack(absorb[0]), absorb[1], absorb[2]),
+        )
+
+    got = programs()
+    parents_form(monkeypatch)
+    assert_bits_equal(got, programs())
+
+
+@functools.lru_cache(maxsize=None)
+def _carried_absorb(name):
+    schedule, state, _ = carried_title(name)
+    form = rollout_form(schedule, state, INPUTS)
+    step = jax.jit(lambda st, b, s: schedule(st, PlayerInputs(bits=b, status=s)))
+    roll = jax.jit(lambda st, a, bb: rollout_branches(
+        schedule, st, a, bb, STATUS, form))
+    absorb = jax.jit(lambda ring, rings, states, branch, *a: _absorb(
+        ring, *branch_rows_of(rings, states, branch, form), *a))
+    return state, form, step, roll, absorb
+
+
+@pytest.mark.parametrize("r,d,n", ABSORBS)
+def test_absorb_reads_every_frame_of_the_carried_rows(r, d, n):
+    """The absorb over the rows as the rollout carries them (flat, a leaf
+    no branch reaches without its branch axis), against the serial replay
+    of the matched branch."""
+    state, form, step, roll, absorb = _carried_absorb(
+        "particles" if (r + d + n) % 2 else "flock")
+    anchor, branch = 7 * F + r, (r + d + n) % BRANCHES
+    rng = np.random.default_rng(100 * r + 10 * d + n)
+    bits = random_bits(rng, BRANCHES)
+    rings, states, cs = roll(state, jnp.int32(anchor), bits)
+    entered = [state]
+    for t in range(F):
+        entered.append(step(entered[-1], bits[branch, t], STATUS[t]))
+    main = random_ring(rng, state, DEPTH)
+    want_ring, put = main, jax.jit(ring_put)
+    for t in range(d, d + n):
+        want_ring = put(
+            want_ring, entered[t], jnp.int32(anchor + t), cs[branch, t])
+    want_cs = np.zeros((BURST, 2), np.uint32)
+    want_cs[:n] = np.asarray(cs)[branch, d:d + n]
+    got = absorb(main, rings, states, i32(branch), i32(anchor + d), i32(n),
+                 i32(anchor), i32(F))
+    assert_absorbed(got, (want_ring, entered[d + n], jnp.asarray(want_cs)), n)
+
+
+@pytest.mark.parametrize("branches", [BRANCHES, LONG], ids=["chain", "one_hot"])
+def test_a_lane_reads_its_rows_out_of_the_gathered_buffer(branches):
+    """``branch_rows_of`` under the slot ``vmap`` (every lane's carried
+    rows gathered, the lane's column read where it lies) against the
+    dynamic slices one lane alone takes; a wide rollout reads through the
+    one-hot pass."""
+    rng = np.random.default_rng(branches)
+    n = FLAT_ROW_BYTES // 4
+    form = plain_world().replace(components={"pos": STEPS}, resources={
+        "tick": ONCE}, alive=SHAPED, rollback_id=SHAPED, present={"pos": SHAPED})
+    rand = lambda *shape: random_like(     # noqa: E731
+        rng, jnp.zeros(shape, jnp.float32))
+    small = lambda *tail: rand(LANES, branches, *tail)    # noqa: E731
+    rings = SnapshotRing(
+        states=form.replace(
+            components={"pos": rand(SPEC, LANES, branches, n)},
+            resources={"tick": rand(SPEC, LANES, n)},
+            alive=small(SPEC, 4), rollback_id=small(SPEC, 4),
+            present={"pos": small(SPEC, 4)}),
+        frames=small(SPEC), checksums=small(SPEC, 2))
+    states = form.replace(
+        components={"pos": small(n)}, resources={"tick": rand(LANES, n)},
+        alive=small(4), rollback_id=small(4), present={"pos": small(4)})
+    picks = i32(rng.integers(0, branches, size=LANES))
+    axes = jax.tree_util.tree_map(lambda k: 0 if k == SHAPED else 1, form)
+    ring_axes = SnapshotRing(states=axes, frames=0, checksums=0)
+
+    def lanes(rings, states, branch):
+        rings = branch_rows_gathered(rings, form, LANE_AXIS)
+        return branch_rows_of(rings, states, branch, form, LANE_AXIS)
+
+    got = jax.jit(jax.vmap(
+        lanes, in_axes=(ring_axes, 0, 0), axis_name=LANE_AXIS))(
+        rings, states, picks)
+    alone = jax.jit(lambda r, s, b: branch_rows_of(r, s, b, form))
+    for i in range(LANES):
+        mine = jax.tree_util.tree_map(
+            lambda a, x: x[i] if a == 0 else x[:, i], ring_axes, rings)
+        assert_bits_equal(lane(got, i), alone(mine, lane(states, i), picks[i]))
+
+
+def _scans(jaxpr, length):
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and eqn.params["length"] == length:
+            found.append((jaxpr, eqn))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _scans(sub, length)
+    return found
+
+
+@pytest.mark.parametrize("name", ["particles", "flock"])
+def test_the_rollout_loops_buffers_are_the_programs_outputs(name):
+    """No transposition, reshape or broadcast (no operation at all) stands
+    between the rollout's loop and the outputs of the lowered
+    ``[S]``-vmapped tick for a leaf carried as written; and no operation of
+    the program but the absorb's conditional reads the carried leaves it
+    is handed."""
+    schedule, state, kinds = carried_title(name)
+    ex = BatchedTickExecutor(
+        schedule, LANES, BURST, BRANCHES, SPEC, inputs=INPUTS)
+    args = tick_args(ex, state, LANES, BURST, BRANCHES, SPEC)
+    outer = jax.make_jaxpr(ex._fn)(*args).jaxpr
+    assert [e.primitive.name for e in outer.eqns] == ["jit"]
+    tick = outer.eqns[0].params["jaxpr"].jaxpr
+    rollout, = [e for j, e in _scans(tick, SPEC) if j is tick]
+    rows = [v for v in rollout.outvars
+            if v.aval.shape[:2] == (SPEC, LANES) and v.aval.ndim > 2
+            and v.aval.shape[-1] * v.aval.dtype.itemsize >= FLAT_ROW_BYTES]
+    assert len(rows) == len(kinds)
+    assert sum(v.aval.ndim == 3 for v in rows) == kinds.count(ONCE)
+    used = {id(v) for e in tick.eqns for v in e.invars}
+    for v in rows:
+        assert id(v) not in used and v in tick.outvars
+    # ... and the carried leaves it takes in go to the conditional alone.
+    carried_in = [v for v, a in zip(tick.invars, ex.packed.carry.axes) if a]
+    assert len(carried_in) == len(kinds)
+    readers = {e.primitive.name for e in tick.eqns
+               for v in e.invars if any(v is c for c in carried_in)}
+    assert readers == {"cond"}
+    traced = ex.traced_ring_rows()
+    assert traced["carried"] == len(kinds)
+    assert traced.get("carried_once", 0) == kinds.count(ONCE)
+
+
+@pytest.mark.parametrize("name", ["particles", "box_game"])
+def test_a_warmed_core_reports_what_it_carries_as_written(name):
+    schedule, state, kinds = carried_title(name)
+    spec = particles.INPUT_SPEC if name == "particles" else box_game.INPUT_SPEC
+    metrics = Metrics()
+    core = BatchedSessionCore(
+        schedule, state, 4, P, spec, num_slots=2, num_branches=BRANCHES,
+        spec_frames=SPEC, metrics=metrics)
+    core.warmup()
+    count = lambda kind: metrics.counters.get(    # noqa: E731
+        'ring_row_lowering{kind="%s"}' % kind, 0)
+    assert count("step") == len(jax.tree_util.tree_leaves(state))
+    assert count("carried") == len(kinds)
+    assert count("carried_once") == kinds.count(ONCE)
+    # off the serving loop the rollout is [S, B, F, *row] trees all the same
+    rows = core.prev_rings.states
+    for x, ref in zip(jax.tree_util.tree_leaves(rows),
+                      jax.tree_util.tree_leaves(state)):
+        assert x.shape == (2, BRANCHES, SPEC) + ref.shape
+    assert metrics.series["serve_carry_bytes"][0] == sum(
+        x.nbytes for x in core._carry)
