@@ -779,13 +779,18 @@ class FusedTickExecutor:
         ``branch_rows_of``)."""
 
         def commit():
-            rows, final = branch_rows_of(
-                prev_rings, prev_states, branch, form, lane_axis
-            )
-            return absorb_branch_frames(
-                ring, rows, final, absorb_first, absorb_n,
-                prev_anchor, prev_total, max_steps=burst_frames, n_run=n_run,
-            )
+            # ``commit``: the matched branch's read and the main ring's
+            # writes, told apart from what the conditional costs a dispatch
+            # in which nobody commits.
+            with device_scope("commit"):
+                rows, final = branch_rows_of(
+                    prev_rings, prev_states, branch, form, lane_axis
+                )
+                return absorb_branch_frames(
+                    ring, rows, final, absorb_first, absorb_n,
+                    prev_anchor, prev_total, max_steps=burst_frames,
+                    n_run=n_run,
+                )
 
         def skip():  # the state is meaningless, as an empty absorb's is
             state = jax.tree_util.tree_map(

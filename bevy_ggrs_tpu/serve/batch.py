@@ -460,6 +460,10 @@ class BatchedSessionCore(Instrumented):
                 f"batched_tick_S{S}_B{B}_F{F}"
             )
         self._template = jax.tree_util.tree_map(jnp.asarray, initial_state)
+        # What a committed frame copies: one ring row, a state's bytes.
+        self.row_bytes = sum(
+            int(x.nbytes) for x in jax.tree_util.tree_leaves(self._template)
+        )
         bcast = lambda prefix: jax.tree_util.tree_map(
             lambda x: jnp.broadcast_to(
                 x.reshape((1,) * len(prefix) + x.shape), prefix + x.shape
@@ -1152,17 +1156,25 @@ class BatchedSessionCore(Instrumented):
     def _count_lane_steps(self, ints: np.ndarray) -> None:
         """What the dispatch's two loops run for ``ints`` (the
         :class:`TickInts` rows it is handed): ``num_slots x`` the deepest
-        lane's burst, and the same of its absorb, whose depth is also the
-        series ``serve_absorb_depth`` (a sample a dispatch, 0 included)."""
-        self.burst_step_slots_total += self.num_slots * int(
-            ints[:, TickInts.N_BURST].max()
-        )
-        depth = int(ints[:, TickInts.ABSORB_N].max())
+        lane's burst, and the same of its absorb. Both depths are series, a
+        sample a dispatch (``serve_burst_depth``; ``serve_absorb_depth``, 0
+        included), and so are the bytes the lanes commit
+        (``serve_absorb_commit_bytes``: their frames x ``row_bytes``, also
+        the count ``absorb_commit_bytes_total``)."""
+        self.metrics.count("serve_dispatches_total")
+        burst = int(ints[:, TickInts.N_BURST].max())
+        self.burst_step_slots_total += self.num_slots * burst
+        self.metrics.observe("serve_burst_depth", burst)
+        commits = ints[:, TickInts.ABSORB_N]
+        depth = int(commits.max())
+        committed = int(commits.sum()) * self.row_bytes
         self.metrics.observe("serve_absorb_depth", depth)
+        self.metrics.observe("serve_absorb_commit_bytes", committed)
         if depth:
             steps = self.num_slots * depth
             self.absorb_step_slots_total += steps
             self.metrics.count("absorb_step_slots_total", steps)
+            self.metrics.count("absorb_commit_bytes_total", committed)
 
     def _post_dispatch(
         self, cs, post: Dict[int, tuple], reports: List[tuple]

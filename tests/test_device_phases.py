@@ -52,14 +52,14 @@ TITLES = {
     "box_game": lambda: (
         box_game.make_schedule(), box_game.make_world(P).commit(),
         box_game.INPUT_SPEC,
-        {"schedule", "ring_write", "ring_read", "checksum"},
+        {"schedule", "ring_write", "ring_read", "checksum", "commit"},
     ),
     # Births claim rows and a row is 72 KiB: kept flat through a burst.
     "particles": lambda: (
         particles.make_schedule(), particles.make_world(P).commit(),
         particles.INPUT_SPEC,
         {"schedule", "ring_write", "ring_read", "checksum", "row_layout",
-         "claim"},
+         "claim", "commit"},
     ),
 }
 
@@ -309,10 +309,11 @@ def test_the_front_and_the_absorb_program_trace_the_same_scopes():
                            {s for r in rows for s in r.scopes[1:]})
     phases, inner = scopes["tick"]
     assert phases == PHASES
-    assert inner == {"schedule", "ring_write", "ring_read", "checksum"}
+    assert inner == {"schedule", "ring_write", "ring_read", "checksum",
+                     "commit"}
     assert scopes["front"] == (PHASES - {"rollout"}, inner)
     assert scopes["absorb"][0] == {"absorb", "carry_codec"}
-    assert scopes["absorb"][1] == {"ring_write", "ring_read"}
+    assert scopes["absorb"][1] == {"ring_write", "ring_read", "commit"}
 
 
 def _computation(text: str) -> str:
