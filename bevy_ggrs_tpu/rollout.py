@@ -41,9 +41,9 @@ import jax.numpy as jnp
 from bevy_ggrs_tpu.schedule import PlayerInputs, Schedule
 from bevy_ggrs_tpu.state import (
     ONCE, SHAPED, STEPS, SnapshotRing, WorldState, active_checksum,
-    large_row, ring_load, ring_of_steps, ring_row_lowerings, ring_row_read,
-    ring_row_write, ring_rows_flat, ring_rows_shaped, ring_save, state_row,
-    state_shaped,
+    in_place_writes, large_row, ring_load, ring_of_steps, ring_row_lowerings,
+    ring_row_read, ring_row_write, ring_rows_flat, ring_rows_shaped,
+    ring_save, row_in_tiles, state_row, state_shaped,
 )
 
 
@@ -99,8 +99,13 @@ def rollout_burst(
     the saved checksum at step ``t`` (0 where ``save_mask[t]`` is False).
     """
     start_frame = jnp.asarray(start_frame, dtype=jnp.int32)
-    # Large rows ride the loop flat (``state.py`` ``FLAT_ROW_BYTES``).
+    # Large rows ride the loop flat, as whole lane tiles where they are such
+    # (``state.py`` ``FLAT_ROW_BYTES``, ``ring_rows_flat``).
     shaped, ring = ring, ring_rows_flat(ring)
+    tiled = sum(
+        row_in_tiles(x, 1) for x in jax.tree_util.tree_leaves(ring.states)
+    )
+    visits = in_place_writes[0]
     xs = (bits, status, save_mask, adv_mask)
 
     def step(t, loop):
@@ -124,6 +129,8 @@ def rollout_burst(
         (ring, state, start_frame,
          jnp.zeros((save_mask.shape[0], 2), jnp.uint32)),
     )
+    if in_place_writes[0] > visits:  # the saves' index was a lane's own
+        ring_row_lowerings["in_place"] += tiled
     return ring_rows_shaped(ring, shaped), state, checksums
 
 

@@ -558,6 +558,9 @@ def ring_init(state: WorldState, depth: int) -> SnapshotRing:
 # NaN). The choice is made where it can be seen: by the batching rule, from
 # whether the index carries the batch axis. Nothing is configured.
 #
+# (A large row under a batched index is a third case, a copy a lane: "The
+# fourth lowering", below.)
+#
 # A READ by select is a chain of one select a row of the axis, each an
 # operation of the program: right for a ring (10 to 14 rows, read in every
 # scan step), wrong for the one read a tick that picks a slot's matched
@@ -603,6 +606,16 @@ def _row_write_at(axis: int):
             return _row_write_at(axis + 1)(stack, row, index, valid), True
         n = stack.shape[axis + 1]
         index = _batched(_clamp(index, n), index_b, axis_size)
+        if axis == 0 and row_in_tiles(stack, 2):
+            # A large row in whole lane tiles: one copy a writing lane.
+            from bevy_ggrs_tpu.ops.ring_write import write_rows_in_place
+
+            writes = (
+                jnp.ones((axis_size,), jnp.int32) if valid is None
+                else _batched(valid, valid_b, axis_size)
+            )
+            in_place_writes[0] += 1
+            return write_rows_in_place(stack, row, index, writes), True
         hot = jnp.arange(n, dtype=jnp.int32) == index[:, None]  # [N, n]
         if valid is not None:  # the mask folds into the one-hot: one pass
             hot = hot & _batched(valid, valid_b, axis_size)[:, None]
@@ -704,11 +717,12 @@ def ring_put(
     slot = jnp.remainder(frame, ring.depth)
 
     def row_like(r, s):
-        if s.ndim == r.ndim - 1:
+        row = tuple(r.shape[1:])
+        if s.shape == row:
             return s
-        if s.ndim > r.ndim - 1:
-            return _row_flat(s, 0)
-        return _rows_shaped(s, tuple(r.shape[1:]), 0)
+        if s.ndim > 1 or row == _row_tiles(s, 0):
+            return _row_tiled(s, 0)
+        return _rows_shaped(s, row, 0)
 
     return SnapshotRing(
         states=jax.tree_util.tree_map(
@@ -755,6 +769,37 @@ def ring_save(
 # it is and its programs with it. Bits are only moved.
 FLAT_ROW_BYTES = 4 << 10
 
+# The fourth lowering: a per-lane write of a LARGE row is a copy, not a pass.
+#
+# The select form reads and writes the whole ``[S, depth, n]`` leaf to
+# change ``S`` rows of it, and does so for a lane whose step is padding to
+# the deepest lane of its group too. Right for box_game's 192-byte rows (it
+# replaced a scatter, above); at the churning title's 72 KB rows it was the
+# largest single operation of the served tick, ``(2 x depth + 1)`` times
+# the bytes that change, every burst step (1.46 ms of a 9.82 ms dispatch
+# behind SyncTest sessions, 3.24 of 15.10 behind a network, where four
+# lane-steps in five are padding; ledger, PR 52). So for the length of a
+# burst a row of ``IN_PLACE_ROW_BYTES`` or more that is whole ``(8, 128)``
+# tiles of a 32-bit type is carried as those tiles, ``[depth, n / 128,
+# 128]`` (one row is then addressable by a DMA; in ``[depth, n]`` the depth
+# lies in a tile's sublanes), and the batching rule of the write answers a
+# batched index or mask over such a ring with ``ops/ring_write.py``: the
+# ring stays where it lies in HBM, aliased to the output, one asynchronous
+# copy a lane that writes and none for a lane that does not. On the chip, a
+# burst step of that title's four such leaves under 64 lanes with the rings
+# in HBM: 0.315 ms by select, 0.047 with every lane saving, 0.013 with 13,
+# 0.008 with none; everything ``ring_write`` in the SyncTest dispatch 1.90
+# -> 0.22 ms, the dispatch 9.82 -> 7.37 (my chip runs, PR 53; ``PERF.md``
+# section 6). Shapes and dtypes alone decide. A ``bool`` row (a DMA takes
+# none), a row that does not tile and every smaller row keep the flat form
+# and the select. The constant is the crossover's: a ring of rows this
+# size under 64 lanes (18 MB and more) is one the compiler leaves in HBM,
+# where a select pays for every byte; the 1,024-boid title's 8 KB rows make
+# 4.7 MB rings that it parks in VMEM for the whole loop, where select and
+# copy both read under the timer's 3 us a step, and the kernel would pin
+# them to HBM and add a copy a burst in and out for every one-axis leaf.
+IN_PLACE_ROW_BYTES = 32 << 10
+
 # How many ring leaves each form was traced with, process-wide (``kind``:
 # "flat" / "shaped", a burst's ring by the form its loop carries it in;
 # "step", a rollout's branch ring, whose rows leave the loop in step order:
@@ -764,9 +809,14 @@ FLAT_ROW_BYTES = 4 << 10
 # :func:`branch_rows_carried`); ``serve/batch.py`` reports its executable's
 # share as the labelled count ``ring_row_lowering``.
 ring_row_lowerings: Dict[str, int] = {
-    "flat": 0, "shaped": 0, "step": 0,
+    "flat": 0, "shaped": 0, "in_place": 0, "step": 0,
     "carried": 0, "carried_once": 0,
 }
+# How often the batching rule of a row write answered with the in-place
+# copy: a loop's own batching rule visits its body more than once, so this
+# counts visits, and ``rollout.py`` ``rollout_burst``, which knows its
+# leaves, turns "visited at all" into the count above.
+in_place_writes = [0]
 
 
 def large_row(x, lead: int = 0) -> bool:
@@ -776,13 +826,33 @@ def large_row(x, lead: int = 0) -> bool:
     return n * jnp.dtype(x.dtype).itemsize >= FLAT_ROW_BYTES
 
 
+def _row_tiles(x, lead: int) -> Optional[Tuple[int, int]]:
+    """``(n / 128, 128)`` where the row of ``x[*lead axes, *row]`` is
+    ``IN_PLACE_ROW_BYTES`` or more and whole lane tiles of a type a DMA takes
+    (``ops/ring_write.py`` ``tiles``); None for any other row."""
+    from bevy_ggrs_tpu.ops.ring_write import tiles
+
+    n = int(np.prod(x.shape[lead:], dtype=np.int64))
+    if n * jnp.dtype(x.dtype).itemsize < IN_PLACE_ROW_BYTES:
+        return None
+    return tiles(n, x.dtype)
+
+
+def row_in_tiles(x, lead: int) -> bool:
+    """Whether the rows of ``x[*lead axes, *row]`` stand as whole lane
+    tiles: a write into such a ring under a ``vmap`` that batches the index
+    is one copy a lane (``_row_write_at``)."""
+    return _row_tiles(x, lead) == tuple(x.shape[lead:])
+
+
 def _row_flat(x: jnp.ndarray, lead: int) -> jnp.ndarray:
     """``x[*lead axes, *row]`` with a row of ``FLAT_ROW_BYTES`` or more and
     two axes or more flattened to ``[*lead axes, n]``, largest axis last;
-    any other leaf as it is."""
+    any other leaf as it is (a row that already is whole lane tiles among
+    them: no form of it differs from its shape, :func:`_row_tiled`)."""
     shape = tuple(x.shape[lead:])
     n = int(np.prod(shape, dtype=np.int64))
-    if len(shape) < 2 or not large_row(x, lead):
+    if len(shape) < 2 or not large_row(x, lead) or shape == _row_tiles(x, lead):
         return x
     perm = _lanes_last(shape)
     with _scope("row_layout"):
@@ -793,11 +863,24 @@ def _row_flat(x: jnp.ndarray, lead: int) -> jnp.ndarray:
         return x.reshape(x.shape[:lead] + (n,))
 
 
+def _row_tiled(x: jnp.ndarray, lead: int) -> jnp.ndarray:
+    """:func:`_row_flat`, and the flat row as whole lane tiles ``[*lead
+    axes, n / 128, 128]`` where it is such (:func:`_row_tiles`): the form a
+    batched write copies in place."""
+    tiles = _row_tiles(x, lead)
+    x = _row_flat(x, lead)
+    if tiles is None or tuple(x.shape[lead:]) == tiles:
+        return x
+    with _scope("row_layout"):
+        return x.reshape(x.shape[:lead] + tiles)
+
+
 def ring_rows_flat(ring: SnapshotRing) -> SnapshotRing:
-    """``ring`` with every large row flat (see ``FLAT_ROW_BYTES``): the form
-    a burst's scan carries. :func:`ring_put` writes a state into either
-    form; :func:`ring_rows_shaped` is the way back."""
-    states = jax.tree_util.tree_map(lambda x: _row_flat(x, 1), ring.states)
+    """``ring`` with every large row flat (see ``FLAT_ROW_BYTES``), as whole
+    lane tiles where it is such: the form a burst's loop carries.
+    :func:`ring_put` writes a state into either form;
+    :func:`ring_rows_shaped` is the way back."""
+    states = jax.tree_util.tree_map(lambda x: _row_tiled(x, 1), ring.states)
     for flat, x in zip(jax.tree_util.tree_leaves(states),
                        jax.tree_util.tree_leaves(ring.states)):
         ring_row_lowerings["shaped" if flat is x else "flat"] += 1

@@ -75,6 +75,9 @@ class Size:
     sync_boids: int
     sync_distance: int
     sync_frames: int
+    # in-place ring write: lanes, and the (rows of 128, dtype) of each leaf
+    ring_lanes: int
+    ring_leaves: tuple
 
 
 # Upstream's sizes: ggrs SessionBuilder defaults, BASELINE.json configs
@@ -88,6 +91,8 @@ FULL = Size(
     rows_n=1024, spec_boids=1024, spec_branches=128, spec_frames=8,
     tri_ns=(4096, 16384), tri_block=1024, grid_n=32768,
     sync_boids=1024, sync_distance=7, sync_frames=120,
+    # particles' position / velocity and ttl / rollback_id under 64 slots
+    ring_lanes=64, ring_leaves=((144, "float32"), (72, "int32")),
 )
 # Same phases, toy sizes: the Pallas interpreter is ~100x slower than
 # Mosaic, and this has to fit inside the tier-1 test budget.
@@ -99,6 +104,7 @@ REHEARSAL = Size(
     rows_n=128, spec_boids=64, spec_branches=4, spec_frames=2,
     tri_ns=(256,), tri_block=128, grid_n=512,
     sync_boids=64, sync_distance=3, sync_frames=12,
+    ring_lanes=4, ring_leaves=((64, "float32"), (64, "int32")),
 )
 
 WINDOW = 8  # ggrs SessionBuilder default max prediction
@@ -873,7 +879,55 @@ def _kernel_checks(size: Size, ident: dict):
                 "frames": runner.frame,
                 "rollbacks": runner.rollbacks_total, "mismatches": 0}
 
+    def ring_write():
+        """``state.ring_row_write`` under a ``vmap`` that batches index and
+        mask, on rings whose rows are whole lane tiles (the in-place copy
+        of ``ops/ring_write.py``), against the select over the same ring
+        with its rows flat: bit for bit, three saves in a loop."""
+        from bevy_ggrs_tpu.state import (
+            in_place_writes, ring_row_read, ring_row_write,
+        )
+
+        depth, lanes = WINDOW + 1, size.ring_lanes
+        rng = np.random.RandomState(seed)
+        slot = jnp.asarray(rng.randint(-depth, 2 * depth, lanes), jnp.int32)
+        valid = jnp.asarray(rng.randint(0, 3, lanes) > 0)  # a mixed mask
+        out = {"lanes": lanes, "saving_lanes": int(valid.sum()), "leaves": []}
+
+        def burst(ring, rows, slot, valid):
+            def step(t, ring):
+                return ring_row_write(ring, ring_row_read(rows, t),
+                                      slot + t, valid)
+
+            return jax.lax.fori_loop(0, 3, step, ring)
+
+        for r, dtype in size.ring_leaves:
+            bits = lambda *shape: jnp.asarray(rng.randint(   # noqa: E731
+                0, 2 ** 32, size=shape, dtype=np.uint64
+            ).astype(np.uint32).view(dtype))
+            ring, rows = bits(lanes, depth, r, 128), bits(lanes, 3, r, 128)
+            args = (ring, rows, slot, valid)
+            flat = (ring.reshape(lanes, depth, -1),
+                    rows.reshape(lanes, 3, -1), slot, valid)
+            visits = in_place_writes[0]
+            got = compiled(jax.vmap(burst), *args)
+            require(in_place_writes[0] > visits,
+                    "the tiled ring's write is not the in-place copy")
+            require(not _uses_mosaic(jax.vmap(burst), *flat),
+                    "the flat ring's write is no select")
+            same = np.array_equal(
+                np.asarray(jax.jit(jax.vmap(burst))(*args)).view(np.uint32),
+                np.asarray(jax.jit(jax.vmap(burst))(*flat)).view(
+                    np.uint32).reshape(ring.shape))
+            require(same, f"{dtype}[{lanes}, {depth}, {r}, 128]: the copy "
+                          "differs from the select")
+            out["leaves"].append({"shape": list(ring.shape), "dtype": dtype,
+                                  "bitwise": same, **got})
+        out["mosaic"] = all(leaf["mosaic"] for leaf in out["leaves"])
+        return out
+
     return [
+        ("ring_write_in_place", ring_write),
         ("checksum_box_game_vmapped", checksum_box_game),
         ("checksum_boids", checksum_boids),
         ("pairwise_rows_vpu", rows_vpu),

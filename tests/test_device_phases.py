@@ -346,6 +346,48 @@ def test_the_scopes_leave_the_computation_alone(monkeypatch):
         assert _computation(scoped[program]) == _computation(bare[program])
 
 
+# The burst's loop of the served particles tick as the TPU's compiler prints
+# it (PR 53; shapes shortened): the ring leaf's save is ONE custom call, the
+# Mosaic kernel of ``ops/ring_write.py``, and the copy that retiles its row
+# has no metadata of its own.
+_BURST_WITH_THE_KERNEL = """\
+HloModule jit__tick_impl, is_scheduled=true
+
+%region_7.94 (arg_tuple.0: (s32[], f32[64,9,144,128])) -> (s32[], f32[64,9,144,128]) {
+  %arg_tuple.0 = (s32[]{:T(128)}, f32[64,9,144,128]{3,2,1,0:T(8,128)}) parameter(0)
+  %get-tuple-element.3338 = f32[64,9,144,128]{3,2,1,0:T(8,128)} get-tuple-element(%arg_tuple.0), index=1
+  %get-tuple-element.2935 = s32[64]{0:T(128)} get-tuple-element(%arg_tuple.0), index=0
+  %copy.965 = f32[64,2,9216]{2,1,0:T(2,128)S(1)} copy(%get-tuple-element.3338)
+  %reshape.1758 = f32[64,144,128]{2,1,0:T(8,128)S(1)} reshape(%copy.965), metadata={op_name="jit(_tick_impl)/vmap(ggrs/burst)/while/body/ggrs/row_layout/reshape" stack_frame_id=70}
+  %ring_write.29 = f32[64,9,144,128]{3,2,1,0:T(8,128)} custom-call(%get-tuple-element.2935, %get-tuple-element.2935, %reshape.1758, %get-tuple-element.3338), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[64]{0}, s32[64]{0}, f32[64,144,128]{2,1,0}, f32[64,9,144,128]{3,2,1,0}}, output_to_operand_aliasing={{}: (3, {})}, metadata={op_name="jit(_tick_impl)/vmap(ggrs/burst)/while/body/ggrs/ring_write/pallas_call" stack_frame_id=109}
+  ROOT %tuple.474 = (s32[]{:T(128)}, f32[64,9,144,128]{3,2,1,0:T(8,128)}) tuple(%get-tuple-element.2935, %ring_write.29)
+}
+
+ENTRY %main.124 (carry: f32[64,9,144,128]) -> f32[64,9,144,128] {
+  %carry = f32[64,9,144,128]{3,2,1,0:T(8,128)} parameter(0)
+  %tuple.1 = (s32[]{:T(128)}, f32[64,9,144,128]{3,2,1,0:T(8,128)}) tuple(%carry, %carry)
+  %while.9 = (s32[]{:T(128)}, f32[64,9,144,128]{3,2,1,0:T(8,128)}) while(%tuple.1), condition=%region_8.1, body=%region_7.94, metadata={op_name="jit(_tick_impl)/vmap(ggrs/burst)/while" stack_frame_id=60}
+  ROOT %get-tuple-element.1 = f32[64,9,144,128]{3,2,1,0:T(8,128)} get-tuple-element(%while.9), index=1
+}
+"""
+
+
+def test_the_in_place_ring_write_maps_to_the_bursts_ring_write():
+    """The kernel that copies a large row in place is one operation of the
+    device trace, ``while.9/ring_write.29``: the map gives it the phase and
+    the scope that ``phase_burst_ms`` / ``scope_ring_write_ms`` read."""
+    rows = {r.name: r for r in xla_cache.op_scopes(_BURST_WITH_THE_KERNEL)}
+    kernel = rows["ring_write.29"]
+    assert kernel.opcode == "custom-call" and kernel.own
+    assert kernel.scopes == ("burst", "ring_write")
+    assert kernel.computation == "region_7.94" and not kernel.entry
+    assert rows["reshape.1758"].scopes == ("burst", "row_layout")
+    # the compiler's own copy serves the operation it feeds
+    assert rows["copy.965"].scopes == ("burst", "row_layout")
+    assert not rows["copy.965"].own
+    assert rows["while.9"].scopes == ("burst",)
+
+
 def test_the_tools_table_of_device_operations():
     """``tools/trace_spans.py``'s join of a trace's operations with the map:
     every operation with its phase and scopes, a dispatch; the totals."""

@@ -2,7 +2,9 @@
 benchmark's cells run (no chip: the TPU's compiler is installed here and
 compiles for a chip that is described and not attached); and the served
 particles tick at a small ``[S] x [B] x F``, for what its outputs are made
-of (PR 51: a rollout's rows leave the program as its loop wrote them).
+of (PR 51: a rollout's rows leave the program as its loop wrote them) and
+for how its bursts save a large row (PR 53: one Mosaic copy a lane, no
+select of a whole ring leaf), beside the box_game tick that must not move.
 
 Interpret mode cannot see what Mosaic refuses: a window of a lane-major
 operand that is not tile-aligned (a 64-row strip of ``trpx`` was refused
@@ -25,11 +27,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from bevy_ggrs_tpu import fused
-from bevy_ggrs_tpu.models import boids, particles
+from bevy_ggrs_tpu.models import boids, box_game, particles
 from bevy_ggrs_tpu.ops import checksum as checksum_ops
 from bevy_ggrs_tpu.ops import pairwise
+from bevy_ggrs_tpu.ops import ring_write
 from bevy_ggrs_tpu.serve.batch import BatchedSessionCore
 from bevy_ggrs_tpu.state import ONCE
+from tests.test_device_phases import _computation
+from tests.test_lane_uniform_ring import parents_write
 
 
 @pytest.fixture(scope="module")
@@ -91,18 +96,32 @@ def test_mxu_force_kernel_compiles_for_a_v5e(shape, one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-def _served_particles_tick(one_chip):
-    """The ``[2] x [8] x 8`` batched tick of ``particles`` at 4,096 rows,
-    compiled; the kinds its carry gives the state's leaves."""
+def _served_tick(one_chip, schedule, state, input_spec):
+    """The ``[2] x [8] x 8`` batched tick of a title, compiled; the kinds
+    its carry gives the state's leaves."""
     S, B, F = 2, 8, 8
     core = BatchedSessionCore(
-        particles.make_schedule(7), particles.make_world(2, 4096, 7).commit(),
-        8, 2, particles.INPUT_SPEC, num_slots=S, num_branches=B,
+        schedule, state, 8, 2, input_spec, num_slots=S, num_branches=B,
         spec_frames=F)
     args = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         (core._carry,) + tuple(core._host_args()))
     return core._exec._fn.lower(*args).compile(), core._exec.packed.form
+
+
+def _served_particles_tick(one_chip, rows=4096):
+    """:func:`_served_tick` of ``particles`` at ``rows`` rows a match."""
+    return _served_tick(
+        one_chip, particles.make_schedule(7),
+        particles.make_world(2, rows, 7).commit(), particles.INPUT_SPEC)
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The kernels of a served tick compiled, as on the chip (the rule
+    reads the default backend, which is the CPU here)."""
+    for ops in (checksum_ops, pairwise, ring_write):
+        monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
 
 
 def _output_definitions(text):
@@ -114,13 +133,12 @@ def _output_definitions(text):
 
 
 def test_served_particles_tick_writes_no_branch_invariant_row_twice(
-        one_chip, monkeypatch):
+        one_chip, mosaic, monkeypatch):
     """A leaf no branch's inputs reach leaves the compiled tick once, not
     broadcast over the branches: the outputs shrink by those copies, and no
     output of a ring leaf's size is a broadcast. The parent's form (every
     leaf ``[S, B, F, *row]``) beside it, so that the test sees what it
     says."""
-    monkeypatch.setattr(checksum_ops, "pallas_interpret", lambda: False)
     S, B, F, rows = 2, 8, 8, 4096
     change, form = _served_particles_tick(one_chip)
     monkeypatch.setattr(fused, "rollout_form", lambda *a: None)
@@ -156,3 +174,71 @@ def test_served_particles_tick_writes_no_branch_invariant_row_twice(
     assert len(broadcasts(parent, False)) == len(once)
     assert not broadcasts(change, False)
     assert not broadcasts(change, True)
+
+
+def _loop_with(text, scope):
+    """The text of the one loop body of ``text`` that holds an operation
+    traced under ``scope``."""
+    names = set(re.findall(r"body=%([\w.\-]+)", text))
+    bodies = [c for c in text.split("\n\n") if scope in c
+              and c.lstrip()[1:].split(" ", 1)[0] in names]
+    assert len(bodies) == 1
+    return bodies[0]
+
+
+def test_served_particles_burst_saves_a_large_row_by_one_copy_a_lane(
+        one_chip, mosaic, monkeypatch):
+    """Inside the burst's loop the four 32-bit leaves are written by the
+    Mosaic kernel, under the scope the trace reads it by; no operation
+    there selects over a whole f32 / s32 ring leaf any more (the ``bool``
+    leaves' select stays), and the program needs no more scratch, within a
+    quarter at this small size, than the parent's form, which is compiled beside it so that the
+    test sees what it says."""
+    S, depth, rows = 2, 9, particles.CAPACITY  # ttl's row: 36 KB
+    change, _ = _served_particles_tick(one_chip, rows)
+    parents_write(monkeypatch)
+    parent, _ = _served_particles_tick(one_chip, rows)
+
+    ring_leaf = re.compile(
+        r"= \(?(?:f32|s32)\[%d,%d,(?:%d|%d|%d,128|%d,128)\]\S* [^\n]*"
+        r"(?:select|fusion)\(" % (S, depth, rows, 2 * rows, rows // 128,
+                                  2 * rows // 128))
+
+    def burst(compiled):
+        body = _loop_with(compiled.as_text(), "ggrs/burst)/while/body")
+        calls = [
+            line for line in body.splitlines() if "tpu_custom_call" in line
+            and "ggrs/ring_write" in line]
+        selects = [
+            line for line in body.splitlines()
+            if "ggrs/ring_write" in line and ring_leaf.search(line)]
+        return calls, selects
+
+    calls, selects = burst(change)
+    assert len(calls) == 4 and not selects
+    for line in calls:
+        assert re.search(
+            r'op_name="[^"]*ggrs/burst\)/while/body/ggrs/ring_write/', line)
+        assert "output_to_operand_aliasing={{}: (3, {})}" in line
+        # ring and output stay in HBM: parked in VMEM ("S(1)") the whole
+        # ring would be copied in and out around every call
+        assert not re.match(r"\s*%\S+ = \S*S\(1\)", line)
+    calls, selects = burst(parent)
+    assert not calls and selects
+    # (8.5 MB beside 7.7 under two slots, where the rings fit the chip's
+    # VMEM either way; 49.6 MB beside 96.7 under the cell's 64)
+    assert (change.memory_analysis().temp_size_in_bytes
+            <= 1.25 * parent.memory_analysis().temp_size_in_bytes)
+
+
+def test_served_box_game_tick_is_the_parents(one_chip, mosaic, monkeypatch):
+    """No box_game row is large: its compiled tick holds no kernel of this
+    package's ring writes and is, operation for operation, the program of
+    the parent's form."""
+    tick = lambda: _served_tick(    # noqa: E731
+        one_chip, box_game.make_schedule(), box_game.make_world(2).commit(),
+        box_game.INPUT_SPEC)[0]
+    change = tick()
+    assert "ring_write/pallas_call" not in change.as_text()
+    parents_write(monkeypatch)
+    assert _computation(tick().as_text()) == _computation(change.as_text())

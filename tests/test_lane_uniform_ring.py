@@ -8,6 +8,12 @@ its own; the singleton keeps ``dynamic_update_slice``. Values: the select
 form equals the dynamic form bit for bit, lane by lane, on random bit
 patterns (NaN payloads, ``-0.0``, ``inf`` included) with a different index
 in every lane.
+
+And the fourth (PR 53): a batched write into a ring whose large rows stand
+as whole lane tiles is one copy a writing lane (``ops/ring_write.py``,
+interpreted here), held to the same dynamic form by the same value tests,
+alone and as a loop's carry, beside the rows that must stay on the select
+(``bool``, a row that does not tile, a small row).
 """
 
 import functools
@@ -18,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bevy_ggrs_tpu import state as state_mod
 from bevy_ggrs_tpu.fused import (
     FusedTickExecutor,
     TickInts,
@@ -28,15 +35,20 @@ from bevy_ggrs_tpu.rollout import rollout_steps
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.serve.batch import BatchedTickExecutor
 from bevy_ggrs_tpu.state import (
+    IN_PLACE_ROW_BYTES,
     SELECT_ROWS,
     HostWorld,
     TypeRegistry,
+    in_place_writes,
     ring_init,
     ring_load,
     ring_row_read,
     ring_row_write,
+    ring_rows_flat,
+    ring_rows_shaped,
     ring_save,
     ring_step_load,
+    row_in_tiles,
 )
 
 P = 2
@@ -312,28 +324,92 @@ def test_long_axis_read_matches_per_lane(dtype, stack_batched):
     assert_bits_equal(got, want)
 
 
+# kind: (row shape, dtype, whether a batched write copies it in place). A row
+# is copied where it is ``IN_PLACE_ROW_BYTES`` or more, whole ``(8, 128)``
+# tiles and of a 32-bit type; everything else keeps the select.
+ROWS = {
+    "small_f32": ((4, 3), jnp.float32, False),
+    "tiles_f32": ((64, 128), jnp.float32, True),
+    "tiles_i32": ((72, 128), jnp.int32, True),
+    "bool": ((256, 128), jnp.bool_, False),  # a DMA takes no bool
+    "hundred": ((100,), jnp.float32, False),
+    "ragged": ((65, 128), jnp.float32, False),  # 32.5 KB, not whole tiles
+    "few_tiles": ((8, 128), jnp.float32, False),  # whole tiles, 4 KB
+}
+assert 64 * 128 * 4 == IN_PLACE_ROW_BYTES
+MASKS = {
+    "unmasked": None,
+    "some": [True, False, True, False],
+    "nobody": [False] * LANES,
+}
+
+
+def random_rows(rng, kind, *lead):
+    """Random bits in a ``[*lead, *row]`` array of ``ROWS[kind]``; a float
+    one starts with the special patterns."""
+    shape, dtype, _ = ROWS[kind]
+    x = random_like(rng, jnp.zeros(lead + shape, dtype))
+    if dtype == jnp.float32:
+        flat = np.array(x).reshape(-1)
+        flat[:SPECIAL.size] = SPECIAL
+        x = jnp.asarray(flat.reshape(x.shape))
+    return x
+
+
 @pytest.mark.parametrize("indices", [
     [0, 1, 2, 3], [4, 4, 0, 0], [3, 0, 4, 1],
     [-1, 5, 9, -7],  # out of range: a dynamic slice clamps, so must a select
 ])
-@pytest.mark.parametrize("masked", [False, True])
-def test_row_write_and_read_match_per_lane(indices, masked):
-    rng = np.random.default_rng(sum(indices) + 17 * masked)
-    stack_ = random_like(rng, jnp.zeros((LANES, DEPTH, 4, 3), jnp.float32))
-    rows = random_like(rng, jnp.zeros((LANES, 4, 3), jnp.float32))
+@pytest.mark.parametrize("masked", sorted(MASKS))
+@pytest.mark.parametrize("kind", sorted(ROWS))
+def test_row_write_and_read_match_per_lane(indices, masked, kind):
+    rng = np.random.default_rng(sum(indices) + 17 * len(masked) + len(kind))
+    stack_ = random_rows(rng, kind, LANES, DEPTH)
+    rows = random_rows(rng, kind, LANES)
     idx = i32(indices)
-    if masked:
-        valid = jnp.asarray([True, False, True, False])
-        got = jax.jit(jax.vmap(ring_row_write))(stack_, rows, idx, valid)
-        want = per_lane(ring_row_write, stack_, rows, idx, valid)
-    else:
-        got = jax.jit(jax.vmap(ring_row_write))(stack_, rows, idx)
-        want = per_lane(ring_row_write, stack_, rows, idx)
-    assert_bits_equal(got, want)
+    args = (stack_, rows, idx)
+    if MASKS[masked] is not None:
+        args += (jnp.asarray(MASKS[masked]),)
+    visits = in_place_writes[0]
+    got = jax.jit(jax.vmap(ring_row_write))(*args)
+    assert (in_place_writes[0] > visits) == ROWS[kind][2]
+    assert row_in_tiles(stack_, 2) == ROWS[kind][2]
+    assert_bits_equal(got, per_lane(ring_row_write, *args))
     assert_bits_equal(
         jax.jit(jax.vmap(ring_row_read))(stack_, idx),
         per_lane(ring_row_read, stack_, idx),
     )
+
+
+@pytest.mark.parametrize("steps", [0, 3, 6])
+@pytest.mark.parametrize("kind", ["tiles_f32", "tiles_i32", "bool", "ragged"])
+def test_row_write_as_a_loops_carry_matches_per_lane(steps, kind):
+    """What a burst does: a loop with a traced trip count whose carry is
+    the ring, every lane saving at its own slot in the steps its own mask
+    sets (a padding lane copies nothing)."""
+    rng = np.random.default_rng(steps + len(kind))
+    stack_ = random_rows(rng, kind, LANES, DEPTH)
+    rows = random_rows(rng, kind, LANES, 6)
+    base = i32([0, 7, -3, 1 << 20])
+    saves = jnp.asarray(rng.integers(0, 2, size=(LANES, 6)).astype(bool))
+    saves = saves.at[2].set(False)  # a lane that only pads
+
+    def burst(stack_, rows, base, saves, n):
+        def step(t, s):
+            return ring_row_write(
+                s, ring_row_read(rows, t), jnp.remainder(base + t, DEPTH),
+                ring_row_read(saves, t))
+
+        return jax.lax.fori_loop(0, n, step, stack_)
+
+    got = jax.jit(jax.vmap(burst, (0, 0, 0, 0, None)))(
+        stack_, rows, base, saves, jnp.int32(steps))
+    want = stack([
+        jax.jit(burst)(*lane((stack_, rows, base, saves), i), jnp.int32(steps))
+        for i in range(LANES)])
+    assert_bits_equal(got, want)
+    if steps == 0:
+        assert_bits_equal(got, stack_)
 
 
 def test_nested_vmap_lanes_over_branches():
@@ -382,6 +458,99 @@ def test_ring_save_and_load_match_per_lane(frames):
     )
     text = lowered(jax.vmap(ring_save), rings, states, f, valid)
     assert count(text, "scatter") == 0 and count(text, "gather") == 0
+
+
+ROWS_OF_WORLD = IN_PLACE_ROW_BYTES // 4  # int32[8192]: 32 KB
+
+
+def tiled_world():
+    """Large leaves of every kind a burst meets: ``[.., 2]`` float32 and
+    int32 rows that are whole lane tiles, a large ``bool`` row, and a large
+    row that does not tile (a resource of 1,100 floats)."""
+    reg = TypeRegistry()
+    reg.register_component("pos", shape=(2,))
+    reg.register_component("tag", shape=(), dtype=jnp.int32)
+    reg.register_resource("odd", np.zeros((100, 11), np.float32))
+    reg.register_resource("tick", jnp.int32(0))
+    world = HostWorld(reg, ROWS_OF_WORLD)  # bool[8192]: a large row
+    for i in range(5):
+        world.spawn({"pos": [i, -i], "tag": i}, rollback_id=i)
+    return world.commit()
+
+
+def parents_write(monkeypatch):
+    """From here on no row counts as lane tiles: a burst carries its large
+    rows flat and a per-lane write into them is the select, which is the
+    parent's program."""
+    monkeypatch.setattr(state_mod, "_row_tiles", lambda x, lead: None)
+
+
+@pytest.mark.parametrize("frames", [
+    [0, 1, 2, 3],
+    [4, 5, 9, 10],  # frame % depth wraps: rows 4, 0, 4, 0
+    [DEPTH * 1000 - 1, DEPTH * 1000, 7, 2 ** 30 + 3],
+])
+@pytest.mark.parametrize("masked", sorted(MASKS))
+def test_save_into_a_bursts_ring_matches_per_lane(frames, masked, monkeypatch):
+    """``ring_save`` into the form a burst carries (large rows flat, as lane
+    tiles where they are such) against the dynamic form on the shaped ring,
+    and against the parent's form of the same."""
+    rng = np.random.default_rng(frames[1] + len(masked))
+    state = tiled_world()
+    rings = stack([random_ring(rng, state, DEPTH) for _ in range(LANES)])
+    states = stack([random_like(rng, state) for _ in range(LANES)])
+    args = (rings, states, i32(frames))
+    if MASKS[masked] is not None:
+        args += (jnp.asarray(MASKS[masked]),)
+
+    def through_burst_form(ring, *a):
+        carried = ring_rows_flat(ring)
+        put, cs = ring_save(carried, *a)
+        return ring_rows_shaped(put, ring), cs
+
+    carried = ring_rows_flat(lane(rings, 0)).states
+    assert carried.components["pos"].shape == (DEPTH, 128, 128)
+    assert carried.components["tag"].shape == (DEPTH, 64, 128)
+    assert carried.alive.shape == (DEPTH, ROWS_OF_WORLD)
+    assert carried.resources["odd"].shape == (DEPTH, 1100)
+    visits = in_place_writes[0]
+    got = jax.jit(jax.vmap(through_burst_form))(*args)
+    # pos, tag and rollback_id: the whole-tile leaves, and nobody else
+    assert in_place_writes[0] - visits == 3
+    assert_bits_equal(got, per_lane(ring_save, *args))
+    parents_write(monkeypatch)
+    assert ring_rows_flat(lane(rings, 0)).states.components[
+        "pos"].shape == (DEPTH, 2 * ROWS_OF_WORLD)
+    assert_bits_equal(got, jax.jit(jax.vmap(through_burst_form))(*args))
+    assert in_place_writes[0] - visits == 3
+
+
+@pytest.mark.parametrize("plan", ["nobody", "some"])
+def test_served_particles_tick_returns_the_parents_bits(plan, monkeypatch):
+    """The batched tick of the churning title: its bursts save four leaves
+    by the in-place copy, and every bit it returns is what the parent's
+    select form returns."""
+    from bevy_ggrs_tpu.models import particles
+    from tests.test_step_order_rollout import INPUTS, PLANS, _two_ticks
+
+    # the cell's own 9,216 rows: ttl and rollback_id are 36 KB
+    schedule = particles.make_schedule(7)
+    state = particles.make_world(P, match_seed=7).commit()
+    make = lambda: BatchedTickExecutor(    # noqa: E731
+        schedule, LANES, BURST, BRANCHES, SPEC, inputs=INPUTS)
+    ex = make()
+    got = _two_ticks(ex, state, LANES, PLANS[plan], seed=53)
+    traced = ex.traced_ring_rows()
+    # position, velocity, ttl, rollback_id; the four bool leaves keep the
+    # select (and, one axis as they are, count as shaped)
+    assert traced["in_place"] == 4 and traced["flat"] == 4
+    parents_write(monkeypatch)
+    parent = make()
+    want = _two_ticks(parent, state, LANES, PLANS[plan], seed=53)
+    traced = parent.traced_ring_rows()
+    assert "in_place" not in traced and traced["flat"] == 2
+    assert_bits_equal(got[0], want[0])
+    assert_bits_equal(got[1], want[1])
 
 
 def _absorb(ring, spec_ring, spec_state, first, n, anchor, total):
