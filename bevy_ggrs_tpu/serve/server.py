@@ -85,7 +85,12 @@ from bevy_ggrs_tpu.serve.faults import (
     _SlotRunnerFacade,
     adopt_ticket,
 )
-from bevy_ggrs_tpu.session.common import PredictionThreshold, SessionState
+from bevy_ggrs_tpu.session.common import (
+    EventKind,
+    PredictionThreshold,
+    SessionEvent,
+    SessionState,
+)
 from bevy_ggrs_tpu.session.requests import AdvanceFrame, Segment
 
 
@@ -116,7 +121,10 @@ class MatchHandle:
 
 
 class _Match:
-    __slots__ = ("session", "local_inputs", "fsm", "supervisor", "spec_on")
+    __slots__ = (
+        "session", "local_inputs", "fsm", "supervisor", "spec_on",
+        "events", "interrupted_at",
+    )
 
     def __init__(self, session, local_inputs, fsm, supervisor, spec_on):
         self.session = session
@@ -124,6 +132,16 @@ class _Match:
         self.fsm = fsm
         self.supervisor = supervisor
         self.spec_on = spec_on
+        # Who drains the session's events: its supervisor's tick where it
+        # has one, else the session's own ``events()`` (None: a session
+        # without events, SyncTest).
+        self.events = (
+            None if supervisor is not None
+            else getattr(session, "events", None)
+        )
+        # The served frame that handed out NETWORK_INTERRUPTED for a peer
+        # that has not been heard since (None: every peer is heard).
+        self.interrupted_at: Optional[int] = None
 
 
 def _supervisable(session) -> bool:
@@ -324,6 +342,17 @@ class MatchServer(Instrumented):
         self.matches_repacked_total = 0
         self.repacked: List[MatchHandle] = []
         self.evictions_total = 0
+        # What the hosted sessions reported in the last served frame, each
+        # event with the handle of its match (``drain_events``), and the
+        # lifecycle's counts: matches retired, events handed out, and the
+        # slot-frames in which a hosted match attempted nothing because its
+        # session was still synchronising, or withheld its frame while a
+        # peer of it was silent past the notify threshold.
+        self._match_events: List[Tuple[MatchHandle, SessionEvent]] = []
+        self.matches_retired_total = 0
+        self.match_events_delivered_total = 0
+        self.slot_frames_syncing_total = 0
+        self.slot_frames_stalled_total = 0
         self.last_recovery_frames: Optional[int] = None
         self.last_stagger_jitter_ms: Optional[float] = None
         # Slot SLO engine (obs/slo.py): per-tick samples reduce to
@@ -343,6 +372,9 @@ class MatchServer(Instrumented):
         self.admit_budget = max(1, int(admit_budget))
         self._admit_queue: List[tuple] = []
         self._pending_first: Dict[MatchHandle, object] = {}
+        # The served frame a queued arrival was enqueued in, until its
+        # first dispatch (series ``sync_frames``).
+        self._enqueued_at: Dict[MatchHandle, int] = {}
         self.admissions_completed = 0
         # Slot template pool (filled by warmup): codec-round-tripped
         # (ring, state) pairs a fresh admission reuses instead of
@@ -630,6 +662,7 @@ class MatchServer(Instrumented):
         location it had."""
         self._matches.pop(handle, None)
         self._pending_first.pop(handle, None)
+        self._enqueued_at.pop(handle, None)
         if self._at.get(tuple(handle)) is handle:
             del self._at[tuple(handle)]
         self._vacate_slo(handle)
@@ -699,6 +732,7 @@ class MatchServer(Instrumented):
         self._admit_queue.append(
             (handle, session, local_inputs, initial_state, spec_on, trace)
         )
+        self._enqueued_at[handle] = self.frames_served
         self.metrics.count("admissions_queued")
         return handle
 
@@ -746,9 +780,16 @@ class MatchServer(Instrumented):
                     trace.begin("first_frame")
 
     def retire_match(self, handle: MatchHandle) -> None:
+        """Free the match's slot and forget the match: its session, its
+        supervisor (whatever that re-armed after a ``DISCONNECTED``: nobody
+        polls the session again), its health and its SLO history. Events
+        of it that nobody drained yet stay in :meth:`drain_events`' list
+        under the handle, which keeps the last location it had."""
         handle = self._held(handle)
         if handle is None:
             return  # nothing lives there (retired already)
+        self.matches_retired_total += 1
+        self.metrics.count("matches_retired")
         # A match retired while still in the admit queue (an abandon that
         # beat its own admission) just releases its reservation.
         for i, pending in enumerate(self._admit_queue):
@@ -756,6 +797,7 @@ class MatchServer(Instrumented):
                 del self._admit_queue[i]
                 self._reserved[handle.group].discard(handle.slot)
                 del self._at[tuple(handle)]
+                self._enqueued_at.pop(handle, None)
                 trace = pending[5]
                 if trace is not None:
                     trace.finish()
@@ -874,6 +916,11 @@ class MatchServer(Instrumented):
         (untraced admissions still count)."""
         self.admissions_completed += 1
         self.metrics.count("admissions_completed")
+        since = self._enqueued_at.pop(handle, None)
+        if since is not None:
+            # Enqueue to the first dispatch (the frame the session turned
+            # RUNNING in), in served frames: the queue and the handshake.
+            self.metrics.observe("sync_frames", self.frames_served - since)
         if trace is None:
             return
         if trace.is_open("first_frame"):
@@ -883,7 +930,62 @@ class MatchServer(Instrumented):
         self.metrics.observe("admission_ms", total)
         self.timeseries.observe("admission_ms", total)
         for stage, ms in trace.durations.items():
+            self.metrics.observe(f"admission_{stage}_ms", ms)
             self.timeseries.observe(f"admission_{stage}_ms", ms)
+
+    # -- what the hosted sessions report -----------------------------------
+
+    def _keep_events(self, handle: MatchHandle, m: _Match, events) -> None:
+        """File a match's events of this served frame under its handle,
+        and keep the one clock the lifecycle's series needs: the served
+        frame a peer was reported silent in (``NETWORK_INTERRUPTED``),
+        until it is heard again or disconnected (series
+        ``disconnect_wait_frames``: from that report to ``DISCONNECTED``).
+        ``WAIT_RECOMMENDATION`` is not kept: it advises the loop that
+        drives a session to skip frames, that loop is this server's, and a
+        healthy server gets one from four matches in ten every frame
+        (kept a frame, they outlive the young collections they used to die
+        in)."""
+        keep = self._match_events
+        for ev in events:
+            kind = ev.kind
+            if kind is EventKind.WAIT_RECOMMENDATION:
+                continue
+            keep.append((handle, ev))
+            if kind is EventKind.NETWORK_INTERRUPTED:
+                m.interrupted_at = self.frames_served
+            elif kind is EventKind.NETWORK_RESUMED:
+                m.interrupted_at = None
+            elif kind is EventKind.DISCONNECTED:
+                if m.interrupted_at is not None:
+                    self.metrics.observe(
+                        "disconnect_wait_frames",
+                        self.frames_served - m.interrupted_at,
+                    )
+                m.interrupted_at = None
+
+    def drain_events(self) -> List[Tuple[MatchHandle, SessionEvent]]:
+        """What the hosted sessions reported in the last served frame, each
+        :class:`~bevy_ggrs_tpu.session.common.SessionEvent` with the handle
+        of its match, in the order the frame met them: the events a
+        match's :class:`~bevy_ggrs_tpu.session.supervisor.SessionSupervisor`
+        drained (the handshake's progress, ``NETWORK_INTERRUPTED``,
+        ``NETWORK_RESUMED``, ``DISCONNECTED``, the supervisor's own; not
+        ``WAIT_RECOMMENDATION``, which is advice to the loop that drives
+        the session: the server's), or ``session.events()`` of a hosted
+        session without one. Call it
+        after :meth:`run_frame`; each event is handed out once, and the
+        next served frame drops what nobody took (the server holds one
+        frame of them). The policy is the caller's: a match whose only
+        remote player is ``DISCONNECTED`` goes on with that player's inputs
+        frozen until :meth:`retire_match` (docs/serving.md "A match
+        ends")."""
+        with self.span("serve_match_events", events=len(self._match_events)):
+            out, self._match_events = self._match_events, []
+            if out:
+                self.match_events_delivered_total += len(out)
+                self.metrics.count("match_events_delivered", len(out))
+        return out
 
     # -- re-packing ------------------------------------------------------
 
@@ -1182,6 +1284,8 @@ class MatchServer(Instrumented):
 
     def _serve_frame(self) -> None:
         t_wall = time.perf_counter()
+        if self._match_events:
+            self._match_events.clear()  # kept one served frame, not taken
         # Fast-path admission drain, TOP of frame: a pre-warmed joiner
         # (initial_state None with a slot template pooled) costs ~a
         # template pop + one small device-admit program, so it drains
@@ -1272,7 +1376,9 @@ class MatchServer(Instrumented):
                         try:
                             sup = m.supervisor
                             if sup is not None:
-                                sup.tick(t_m)
+                                events = sup.tick(t_m)
+                                if events:
+                                    self._keep_events(handle, m, events)
                                 if not sup.should_advance():
                                     # Lost a desync ballot (or mid-rejoin):
                                     # the state transfer needs a real runner.
@@ -1280,6 +1386,10 @@ class MatchServer(Instrumented):
                                         handle, m, "supervisor_quarantine"
                                     )
                                     continue
+                            elif m.events is not None:
+                                events = m.events()
+                                if events:
+                                    self._keep_events(handle, m, events)
                             if timed:
                                 t_b = clock()
                                 sup_s += t_b - t_a
@@ -1304,7 +1414,9 @@ class MatchServer(Instrumented):
                                 cur is not None
                                 and cur() != SessionState.RUNNING
                             ):
-                                continue  # still synchronizing: no work yet
+                                # Still synchronizing: no work yet.
+                                self.slot_frames_syncing_total += 1
+                                continue
                             frame = core.slots[slot].frame
                             if m.local_inputs is not None:
                                 for h in session.local_player_handles():
@@ -1329,6 +1441,9 @@ class MatchServer(Instrumented):
                             # (its own host time, nothing rolled back).
                             self.frames_withheld_total += 1
                             self.metrics.count("frames_withheld")
+                            if m.interrupted_at is not None:
+                                # Waiting out a silent peer, not a burst.
+                                self.slot_frames_stalled_total += 1
                             self.slo.observe_tick(
                                 self._flat_slot(handle),
                                 deadline_ok=(self._clock() - t_m) * 1000.0
