@@ -27,9 +27,11 @@ spectator sessions all fit):
   fully confirmed every frame (synctest);
 - ``poll_remote_clients()`` (optional) — pumped before input collection;
   one that also takes ``parts=`` (P2P and spectator sessions) is handed
-  the group's two-slot list while a sink listens, for the poll's receive
-  and send seconds, and asked for ``num_endpoints`` (the remote endpoints
-  a poll pumps: series ``serve_endpoints_polled``);
+  the group's four-slot list while a sink listens, for the poll's receive
+  and send seconds and, from a session that counts them, the datagrams it
+  received and those parsed in place (series ``serve_poll_direct_share``),
+  and asked for ``num_endpoints`` (the remote endpoints a poll pumps:
+  series ``serve_endpoints_polled``);
 - ``report_checksum(frame, checksum)`` / ``wants_checksum(frame)``
   (optional) — fed from the core's deferred checksum reports, a segment's
   in one ``report_checksums(first_frame, checksums)`` call where the
@@ -1357,9 +1359,10 @@ class MatchServer(Instrumented):
                     timed = sp_sessions is not NULL_SPAN
                     clock = time.perf_counter
                     sup_s = poll_s = inputs_s = adv_s = slo_s = 0.0
-                    # The polls' receive and send seconds: the session adds
-                    # them in for the caller that asks (``parts``).
-                    poll_parts = [0.0, 0.0] if timed else None
+                    # The polls' receive and send seconds, the datagrams
+                    # they received and those parsed in place: the session
+                    # adds them in for the caller that asks (``parts``).
+                    poll_parts = [0.0, 0.0, 0, 0] if timed else None
                     # The remote endpoints those polls pumped: series
                     # ``serve_endpoints_polled`` (one far end a hosted duel,
                     # P - 1 a hosted lobby).
@@ -1671,7 +1674,9 @@ class MatchServer(Instrumented):
     ) -> None:
         """One sample a group tick of each sum of span ``serve_sessions``
         (the seconds one kind of work took over every match of the group,
-        under the series key given), of the polls' two sides, and of what
+        under the series key given), of the polls' two sides, of the share
+        of the datagrams they received that were parsed in place
+        (``serve_poll_direct_share``, where any arrived), and of what
         the sums leave of the span (``serve_sessions_other_ms``: the loop
         itself, a match that left it early)."""
         observe = self.metrics.observe
@@ -1679,6 +1684,11 @@ class MatchServer(Instrumented):
             observe(key, seconds * 1000.0)
         observe("serve_poll_recv_ms", poll_parts[0] * 1000.0)
         observe("serve_poll_send_ms", poll_parts[1] * 1000.0)
+        if poll_parts[2]:
+            observe(
+                "serve_poll_direct_share",
+                100.0 * poll_parts[3] / poll_parts[2],
+            )
         observe(
             "serve_sessions_other_ms",
             span_ms - sum(sums_s.values()) * 1000.0,

@@ -15,6 +15,7 @@ transport's virtual clock drives everything deterministically in tests.
 from __future__ import annotations
 
 import enum
+from bisect import bisect_left, bisect_right
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -61,6 +62,48 @@ class PeerState(enum.Enum):
     DISCONNECTED = "disconnected"
 
 
+class UnackedInputs:
+    """One handle's unacked inputs, kept as the bytes they go out as:
+    ``rows`` holds ``size`` bytes a frame in the order of ``frames``
+    (ascending), so a datagram's payload is a slice of it and an input is
+    serialised once, when it is queued. ``len()`` is the frames held."""
+
+    __slots__ = ("frames", "rows", "size")
+
+    def __init__(self, size: int):
+        self.frames: List[int] = []
+        self.rows = bytearray()
+        self.size = size
+
+    def __len__(self) -> int:
+        return len(self.frames)
+
+    def put(self, frame: int, row: bytes) -> None:
+        if len(row) != self.size:
+            raise ValueError(
+                f"an input of {len(row)} bytes among inputs of {self.size}"
+            )
+        frames = self.frames
+        if not frames or frame > frames[-1]:
+            # The steady state: the next frame (after a gap, a later one).
+            frames.append(frame)
+            self.rows += row
+            return
+        # A refill below the start, a relay behind what is held, an
+        # overwrite: the row goes where its frame sorts.
+        i = bisect_left(frames, frame)
+        at = i * self.size
+        if frames[i] == frame:
+            self.rows[at : at + self.size] = row
+        else:
+            frames.insert(i, frame)
+            self.rows[at:at] = row
+
+    def drop_first(self, n: int) -> None:
+        del self.frames[:n]
+        del self.rows[: n * self.size]
+
+
 class PeerEndpoint:
     def __init__(
         self,
@@ -92,8 +135,8 @@ class PeerEndpoint:
         # rejoin rather than a first join.
         self.reconnecting = False
 
-        # Outgoing input spans, per local handle: frame -> bits (unacked).
-        self._pending_output: Dict[int, Dict[int, np.ndarray]] = {}
+        # Outgoing input spans, per local handle (unacked).
+        self._pending_output: Dict[int, UnackedInputs] = {}
         # Highest frame actually TRANSMITTED per handle: bounds acceptable
         # acks (a peer cannot have received what we never sent).
         self._max_sent: Dict[int, int] = {}
@@ -143,7 +186,9 @@ class PeerEndpoint:
         self.events.append(SessionEvent(kind, addr=self.addr, data=data))
 
     def _send(self, msg: proto.Message, now: float) -> None:
-        data = proto.encode(msg)
+        self._send_bytes(proto.encode(msg), now)
+
+    def _send_bytes(self, data: bytes, now: float) -> None:
         self.metrics.count("datagrams_out")
         self.outbox.append(data)
         self.bytes_sent += len(data)
@@ -207,11 +252,11 @@ class PeerEndpoint:
         now: float,
         on_inputs: Callable[[proto.InputMsg], None],
     ) -> None:
-        self._last_recv = now
-        if self._interrupted and self.state == PeerState.RUNNING:
-            self._interrupted = False
-            self._emit(EventKind.NETWORK_RESUMED)
-
+        if isinstance(msg, proto.InputMsg):
+            self.on_input(now, msg.ack_frame, msg.sender_frame, msg.advantage)
+            on_inputs(msg)
+            return
+        self._note_received(now)
         if isinstance(msg, proto.SyncRequest):
             # Typed refusal on config skew: no reply — the mismatched
             # peer's handshake can never complete against us (and ours
@@ -245,22 +290,6 @@ class PeerEndpoint:
                             "total": NUM_SYNC_ROUNDTRIPS,
                         },
                     )
-        elif isinstance(msg, proto.InputMsg):
-            # Latest claim, NOT a running max: a single corrupted
-            # sender_frame would poison a max() forever (wedging timesync
-            # and catch-up heuristics on a bogus huge frame), while under
-            # plain reordering the dip lasts one datagram. Negative claims
-            # are impossible (frames start at 0) and would flip the local
-            # advantage past the int16 wire field, so drop those outright;
-            # a bogus *positive* claim only zeroes the advantage until the
-            # next genuine message overwrites it.
-            if msg.sender_frame >= 0:
-                self.remote_frame = msg.sender_frame
-            self.remote_advantage = msg.advantage
-            for h in list(self._pending_output):
-                if h not in self._relay_handles:
-                    self._ack(h, msg.ack_frame)
-            on_inputs(msg)
         elif isinstance(msg, proto.InputAck):
             self._ack(msg.handle, msg.ack_frame)
         elif isinstance(msg, proto.QualityReport):
@@ -283,6 +312,35 @@ class PeerEndpoint:
             if len(self.control_inbox) > 256:  # bound if nothing drains
                 del self.control_inbox[:-256]
         # KeepAlive: nothing beyond the last_recv bump.
+
+    def _note_received(self, now: float) -> None:
+        self._last_recv = now
+        if self._interrupted and self.state == PeerState.RUNNING:
+            self._interrupted = False
+            self._emit(EventKind.NETWORK_RESUMED)
+
+    def on_input(
+        self, now: float, ack_frame: int, sender_frame: int, advantage: int
+    ) -> None:
+        """What an ``InputMsg`` means to the endpoint, from its fields (the
+        span itself is the session's): a poll that parsed the datagram in
+        place calls this, ``on_message`` calls it for a decoded one."""
+        self._note_received(now)
+        # Latest claim, NOT a running max: a single corrupted
+        # sender_frame would poison a max() forever (wedging timesync
+        # and catch-up heuristics on a bogus huge frame), while under
+        # plain reordering the dip lasts one datagram. Negative claims
+        # are impossible (frames start at 0) and would flip the local
+        # advantage past the int16 wire field, so drop those outright;
+        # a bogus *positive* claim only zeroes the advantage until the
+        # next genuine message overwrites it.
+        if sender_frame >= 0:
+            self.remote_frame = sender_frame
+        self.remote_advantage = advantage
+        relayed = self._relay_handles
+        for h in self._pending_output:
+            if h not in relayed:
+                self._ack(h, ack_frame)
 
     def note_undecodable(self, data: bytes) -> None:
         """Called with a datagram ``decode`` rejected: if it was OUR magic at
@@ -369,16 +427,26 @@ class PeerEndpoint:
         # input history before its first send and permanently stall the
         # session. Clamp to the transmitted frontier.
         ack_frame = min(ack_frame, self._max_sent.get(handle, -1))
-        for f in [f for f in pending if f <= ack_frame]:
-            del pending[f]
+        frames = pending.frames
+        if frames and frames[0] <= ack_frame:
+            pending.drop_first(bisect_right(frames, ack_frame))
 
     # ------------------------------------------------------------------
 
     def queue_input(
         self, handle: int, frame: int, bits: np.ndarray, relay: bool = False
     ) -> None:
-        pending = self._pending_output.setdefault(handle, {})
-        pending[frame] = np.asarray(bits)
+        self.queue_row(handle, frame, np.asarray(bits).tobytes(), relay)
+
+    def queue_row(
+        self, handle: int, frame: int, row: bytes, relay: bool = False
+    ) -> None:
+        """:meth:`queue_input` for an input already serialised (a session
+        that queues one frame to several peers makes its bytes once)."""
+        pending = self._pending_output.get(handle)
+        if pending is None:
+            pending = self._pending_output[handle] = UnackedInputs(len(row))
+        pending.put(frame, row)
         if relay:
             self._relay_handles.add(handle)
         if self.state != PeerState.RUNNING and len(pending) > MAX_INPUT_SPAN:
@@ -386,10 +454,9 @@ class PeerEndpoint:
             # its buffer would grow as long as the peer stays away. Keep
             # only the newest span's worth: a rejoiner that far behind
             # restores the older history from a state transfer anyway.
-            drop = sorted(pending)[: len(pending) - MAX_INPUT_SPAN]
-            self.metrics.count("input_queue_drops", len(drop))
-            for f in drop:
-                del pending[f]
+            drop = len(pending) - MAX_INPUT_SPAN
+            self.metrics.count("input_queue_drops", drop)
+            pending.drop_first(drop)
 
     def refill_range(self, handle: int) -> Optional[Tuple[int, int]]:
         """``(start, end)`` of frames the peer still claims to need but
@@ -400,7 +467,10 @@ class PeerEndpoint:
         claimed = self._last_ack_rx.get(handle)
         if pending is None or claimed is None:
             return None
-        nxt = min(pending) if pending else self._max_sent.get(handle, -1) + 1
+        nxt = (
+            pending.frames[0] if pending
+            else self._max_sent.get(handle, -1) + 1
+        )
         if claimed + 1 < nxt:
             return claimed + 1, nxt
         return None
@@ -414,28 +484,29 @@ class PeerEndpoint:
         if self.state != PeerState.RUNNING:
             return
         for handle, pending in self._pending_output.items():
-            if not pending:
+            frames = pending.frames
+            held = len(frames)
+            if not held:
                 continue
-            frames = sorted(pending)
-            for i in range(0, len(frames), MAX_INPUT_SPAN):
-                chunk = frames[i : i + MAX_INPUT_SPAN]
-                span = [(f, pending[f]) for f in chunk]
-                start, num, payload = proto.pack_input_span(span)
-                self._send(
-                    proto.InputMsg(
-                        handle=handle,
-                        start_frame=start,
-                        payload=payload,
-                        num=num,
-                        ack_frame=ack_frame,
-                        sender_frame=local_frame,
-                        advantage=local_advantage,
+            # A datagram is a header, a slice of the rows (all of them but
+            # through a long silence) and the trailer: a frame's bytes were
+            # made when it was queued. As the frames are packed, so they
+            # are named: the first one's number and a count, whatever gaps
+            # a relay left between them.
+            for i in range(0, held, MAX_INPUT_SPAN):
+                num = min(MAX_INPUT_SPAN, held - i)
+                rows = pending.rows
+                if num != held:
+                    rows = rows[i * pending.size : (i + num) * pending.size]
+                self._send_bytes(
+                    proto.encode_input(
+                        handle, frames[i], num, rows,
+                        ack_frame, local_frame, local_advantage,
                     ),
                     now,
                 )
-                self._max_sent[handle] = max(
-                    self._max_sent.get(handle, -1), chunk[-1]
-                )
+            if frames[-1] > self._max_sent.get(handle, -1):
+                self._max_sent[handle] = frames[-1]
 
     def force_disconnect(self) -> None:
         """Voluntary disconnect: same state transition + pending clear as

@@ -54,7 +54,7 @@ from bevy_ggrs_tpu.native.core import (
 )
 from bevy_ggrs_tpu.session.endpoint import PeerEndpoint, PeerState
 from bevy_ggrs_tpu.session.requests import AdvanceFrame, Segment
-from bevy_ggrs_tpu.obs.trace import Instrumented
+from bevy_ggrs_tpu.obs.trace import Instrumented, null_tracer
 
 # Upper bound on the AUTO desync-detection interval (frames between
 # checksum reports to peers). The effective default is
@@ -252,11 +252,16 @@ class P2PSession(Instrumented):
         peer and the peer's self-reported advantage."""
         worst = 0
         for ep in self._endpoints.values():
-            if ep.state != PeerState.RUNNING or ep.remote_frame == NULL_FRAME:
-                continue
-            local_adv = self.current_frame - ep.remote_frame
-            worst = max(worst, (local_adv - ep.remote_advantage) // 2)
+            if ep.state == PeerState.RUNNING:
+                worst = max(worst, self._ahead_of(ep))
         return worst
+
+    def _ahead_of(self, ep: PeerEndpoint) -> int:
+        """:meth:`frames_ahead` against one RUNNING peer."""
+        if ep.remote_frame == NULL_FRAME:
+            return 0
+        local_adv = self.current_frame - ep.remote_frame
+        return (local_adv - ep.remote_advantage) // 2
 
     def network_stats(self, handle: int) -> NetworkStats:
         addr = self._handle_addr.get(handle)
@@ -276,81 +281,133 @@ class P2PSession(Instrumented):
         parts: Optional[List[float]] = None,
     ) -> None:
         """Pump the network once. A caller that wants the poll's two sides
-        timed passes ``parts``, a two-slot list it owns: the seconds of
-        the receive side (``receive_all`` + decode + ``on_message`` +
+        timed passes ``parts``, a list it owns: the seconds of
+        the receive side (``receive_all`` + decode + the endpoint +
         ingest) are added into ``parts[0]``, those of the send side (the
         endpoints' timers, ``send_pending_inputs``, outbox to socket) into
         ``parts[1]``. The CALLER decides (``MatchServer`` and ``GGRSStage``
         ask while their own sink listens), never this session's sinks: a
         hosted session may hold a ``Metrics`` for its counters in a run
-        nobody traces. With ``parts=None`` the clock is not read."""
-        with self.tracer.span("net_poll"):
-            self._poll_remote_clients(now, parts)
+        nobody traces. With ``parts=None`` the clock is not read. A caller
+        that passes four slots also gets the datagrams this poll received
+        added into ``parts[2]`` and, into ``parts[3]``, those of them that
+        were ``InputMsg``s parsed in place (:meth:`_poll_receive`)."""
+        if self.tracer is null_tracer:
+            self._poll_remote_clients(now, parts, False)
+        else:
+            with self.tracer.span("net_poll"):
+                self._poll_remote_clients(now, parts, True)
 
     def _poll_remote_clients(
-        self, now: Optional[float] = None,
-        parts: Optional[List[float]] = None,
+        self, now: Optional[float], parts: Optional[List[float]],
+        traced: bool,
     ) -> None:
         now = self._clock() if now is None else now
-        datagrams_in = 0
         if parts is not None:
             t_0 = _time.perf_counter()
-        with self.tracer.span("net_recv"):
-            for addr, data in self.socket.receive_all():
-                datagrams_in += 1
-                ep = self._endpoints.get(addr)
-                if ep is None:
-                    continue  # unknown peer: drop (untrusted input)
-                msg = proto.decode(data)
-                if msg is None:
-                    ep.note_undecodable(data)
-                    continue
-                ep.on_message(
-                    msg,
-                    now,
-                    lambda m, _addr=addr, _now=now: self._on_remote_inputs(
-                        _addr, m, _now
-                    ),
-                )
+        if traced:
+            with self.tracer.span("net_recv"):
+                received, direct = self._poll_receive(now)
+        else:
+            received, direct = self._poll_receive(now)
         if parts is not None:
             parts[0] += _time.perf_counter() - t_0
-        if datagrams_in:
-            self.metrics.count("datagrams_in", datagrams_in)
+            if len(parts) > 2:
+                parts[2] += received
+                parts[3] += direct
+        if received:
+            self.metrics.count("datagrams_in", received)
+            if direct:
+                self.metrics.count("datagrams_in_direct", direct)
 
-        self._check_desync()
+        local_adv = self._check_desync()
         self._maybe_send_checksums(now)
 
-        local_adv = self._local_advantage()
         if parts is not None:
             t_0 = _time.perf_counter()
-        with self.tracer.span("net_send"):
-            for addr, ep in self._endpoints.items():
-                before = ep.state
-                ep.poll(now, self.current_frame, local_adv)
-                if before != PeerState.DISCONNECTED and ep.state == PeerState.DISCONNECTED:
-                    self._on_peer_disconnected(addr)
-                ack = self._ack_frame_for(addr)
-                ep.send_pending_inputs(now, self.current_frame, local_adv, ack)
-                if ep.control_inbox:
-                    self._control_inbox.extend(
-                        (addr, m) for m in ep.control_inbox
-                    )
-                    ep.control_inbox.clear()
-                    if len(self._control_inbox) > 256:
-                        del self._control_inbox[:-256]
-                self._events.extend(ep.events)
-                ep.events.clear()
-                for data in ep.outbox:
-                    self.socket.send_to(data, addr)
-                ep.outbox.clear()
+        if traced:
+            with self.tracer.span("net_send"):
+                ahead = self._poll_send(now, local_adv)
+        else:
+            ahead = self._poll_send(now, local_adv)
         if parts is not None:
             parts[1] += _time.perf_counter() - t_0
 
-        ahead = self.frames_ahead()
         if ahead > 0:
             self._events.append(
                 SessionEvent(EventKind.WAIT_RECOMMENDATION, data={"skip_frames": ahead})
             )
+
+    def _poll_receive(self, now: float):
+        """The poll's receive side. Returns the datagrams received and how
+        many of them took the direct path: a well-formed ``InputMsg`` (the
+        one message a RUNNING peer sends every frame) is parsed where it
+        lies, its trailer verified as for any datagram, and handed to the
+        endpoint and the queue set with no message object between; all else
+        goes through ``proto.decode`` and ``on_message``."""
+        received = direct = 0
+        endpoints = self._endpoints
+        decode_input = proto.decode_input
+        for addr, data in self.socket.receive_all():
+            received += 1
+            ep = endpoints.get(addr)
+            if ep is None:
+                continue  # unknown peer: drop (untrusted input)
+            span = decode_input(data)
+            if span is not None:
+                direct += 1
+                handle, start, num, payload, ack, sender_frame, advantage = span
+                ep.on_input(now, ack, sender_frame, advantage)
+                self._on_remote_inputs(addr, handle, start, num, payload, now)
+                continue
+            msg = proto.decode(data)
+            if msg is None:
+                ep.note_undecodable(data)
+                continue
+            ep.on_message(
+                msg, now,
+                lambda m: self._on_remote_inputs(
+                    addr, m.handle, m.start_frame, m.num, m.payload, now
+                ),
+            )
+        return received, direct
+
+    def _poll_send(self, now: float, local_adv: int) -> int:
+        """The poll's send side: every endpoint's timers, its unacked inputs,
+        what it parked for the session, its outbox to the socket. Returns
+        :meth:`frames_ahead` as it stands once every endpoint was polled
+        (the same walk: nothing it reads moves after an endpoint's turn)."""
+        frame = self.current_frame
+        send_to = self.socket.send_to
+        ahead = 0
+        for addr, ep in self._endpoints.items():
+            before = ep.state
+            ep.poll(now, frame, local_adv)
+            if ep.state == PeerState.RUNNING:
+                ep.send_pending_inputs(
+                    now, frame, local_adv, self._ack_frame_for(addr)
+                )
+                ahead = max(ahead, self._ahead_of(ep))
+            elif (
+                before != PeerState.DISCONNECTED
+                and ep.state == PeerState.DISCONNECTED
+            ):
+                self._on_peer_disconnected(addr)
+            if ep.control_inbox:
+                self._control_inbox.extend(
+                    (addr, m) for m in ep.control_inbox
+                )
+                ep.control_inbox.clear()
+                if len(self._control_inbox) > 256:
+                    del self._control_inbox[:-256]
+            if ep.events:
+                self._events.extend(ep.events)
+                ep.events.clear()
+            if ep.outbox:
+                for data in ep.outbox:
+                    send_to(data, addr)
+                ep.outbox.clear()
+        return ahead
 
     # ------------------------------------------------------------------
     # Supervisor surface (session/supervisor.py)
@@ -399,18 +456,6 @@ class P2PSession(Instrumented):
         self._endpoints[addr] = fresh
         return True
 
-    def _local_advantage(self) -> int:
-        """Our frame advantage over the slowest running peer (sent in input
-        msgs / quality reports for the peer's own frames_ahead)."""
-        adv = 0
-        for ep in self._endpoints.values():
-            if ep.state == PeerState.RUNNING and ep.remote_frame != NULL_FRAME:
-                adv = max(adv, self.current_frame - ep.remote_frame)
-        # The advantage rides an int16 wire field; a remote_frame briefly
-        # seeded by a corrupted datagram must skew timesync, not crash the
-        # encoder.
-        return min(adv, 0x7FFF)
-
     def _ack_frame_for(self, addr: object) -> int:
         handles = self._addr_handles.get(addr)
         if not handles:
@@ -418,9 +463,11 @@ class P2PSession(Instrumented):
         return min([self._last_confirmed[h] for h in handles])
 
     def _on_remote_inputs(
-        self, sender: object, msg: proto.InputMsg, now: float
+        self, sender: object, h: int, start_frame: int, num: int,
+        payload: bytes, now: float,
     ) -> None:
-        h = msg.handle
+        """One ``InputMsg``'s span (``num`` frames of player ``h`` from
+        ``start_frame``, as ``payload`` packs them) from ``sender``."""
         if not 0 <= h < self.num_players or h not in self._handle_addr:
             return
         owner = self._handle_addr[h]
@@ -448,9 +495,7 @@ class P2PSession(Instrumented):
         # corrected by a surviving peer's relay, schedules the rollback), a
         # gap (loss beyond the span) left for the next resend.
         redundant, gap, self._confirmed, self._last_confirmed = (
-            self._qset.ingest(
-                self._tracker, h, msg.start_frame, msg.num, msg.payload
-            )
+            self._qset.ingest(self._tracker, h, start_frame, num, payload)
         )
         if redundant:
             self.metrics.count("input_frames_redundant", redundant)
@@ -703,8 +748,18 @@ class P2PSession(Instrumented):
                 ep.send_checksum(target, cs, now)
         self._last_checksum_sent = target
 
-    def _check_desync(self) -> None:
+    def _check_desync(self) -> int:
+        """The walk of the endpoint table between a poll's two sides: every
+        peer's reported checksums against ours, where it reported any, and,
+        being there, our frame advantage over the slowest running peer
+        (returned: it rides the input messages and quality reports of the
+        send side, for the peer's own ``frames_ahead``)."""
+        frame_now, adv = self.current_frame, 0
         for ep in self._endpoints.values():
+            if ep.state == PeerState.RUNNING and ep.remote_frame != NULL_FRAME:
+                adv = max(adv, frame_now - ep.remote_frame)
+            if not ep.remote_checksums:
+                continue
             for frame in sorted(ep.remote_checksums):
                 if not self._settled(frame):
                     continue  # keep until our own checksum is final
@@ -734,9 +789,14 @@ class P2PSession(Instrumented):
                         )
                     )
                 del ep.remote_checksums[frame]
-        horizon = self.confirmed_frame() - 8 * max(self.desync_interval, 1)
-        for f in [f for f in self._checksum_votes if f < horizon]:
-            del self._checksum_votes[f]
+        if self._checksum_votes:
+            horizon = self._confirmed - 8 * max(self.desync_interval, 1)
+            for f in [f for f in self._checksum_votes if f < horizon]:
+                del self._checksum_votes[f]
+        # The advantage rides an int16 wire field; a remote_frame briefly
+        # seeded by a corrupted datagram must skew timesync, not crash the
+        # encoder.
+        return min(adv, 0x7FFF)
 
     # ------------------------------------------------------------------
     # Input + advance (the protocol heart)
@@ -828,9 +888,10 @@ class P2PSession(Instrumented):
         )
         self._pending_local.clear()
         for h, echoed in zip(self.local_handles, stored):
-            for ep in peers:
-                for f, got in echoed:
-                    ep.queue_input(h, f, got)
+            for f, got in echoed:
+                row = np.asarray(got).tobytes()
+                for ep in peers:
+                    ep.queue_row(h, f, row)
 
         if load != NULL_FRAME:
             # Rollback: a confirmed input contradicted a prediction. A load

@@ -757,22 +757,67 @@ def decode(data: bytes) -> Optional[Message]:
         return None
 
 
-def pack_input_span(
-    frames_bits: List[Tuple[int, np.ndarray]],
-) -> Tuple[int, int, bytes]:
-    """Pack a contiguous ascending span of (frame, bits) into
-    (start_frame, num, payload)."""
-    if not frames_bits:
-        return 0, 0, b""
-    start = frames_bits[0][0]
-    payload = b"".join(np.ascontiguousarray(b).tobytes() for _, b in frames_bits)
-    return start, len(frames_bits), payload
+# One InputMsg datagram without the message object: the frame header and the
+# span's own header as ONE struct (their sizes added, never a literal: the
+# payload starts at ``_INPUT_HEAD.size``), for the endpoint that builds the
+# datagram from its buffer of unacked rows and the poll that parses it where
+# it lies. ``encode`` / ``decode`` / ``InputMsg`` say the same wire the slow
+# way and stay the reference (tests/test_protocol_fuzz.py holds the two
+# together).
+_INPUT_HEAD = struct.Struct(_HDR.format + InputMsg._FMT.format.lstrip("<"))
+# crc32 of any bytes followed by their own little-endian crc32 is this one
+# value, and of no other four bytes in that place: one pass over the whole
+# datagram checks the trailer exactly as ``decode`` does, without a slice.
+_CRC_RESIDUE = zlib.crc32(_CRC.pack(zlib.crc32(b"")))
+
+
+def encode_input(
+    handle: int, start_frame: int, num: int, payload,
+    ack_frame: int, sender_frame: int, advantage: int,
+) -> bytes:
+    """``encode(InputMsg(handle, start_frame, bytes(payload), num,
+    ack_frame, sender_frame, advantage))``, byte for byte, from the fields:
+    ``payload`` is any bytes-like (a ``bytearray`` of rows goes in as it
+    is)."""
+    head = _INPUT_HEAD.pack(
+        MAGIC, VERSION, T_INPUT, handle, start_frame, num,
+        len(payload) // max(num, 1), ack_frame, sender_frame, advantage,
+    )
+    return b"".join(
+        (head, payload, _CRC.pack(zlib.crc32(payload, zlib.crc32(head))))
+    )
+
+
+def decode_input(
+    data: bytes,
+) -> Optional[Tuple[int, int, int, bytes, int, int, int]]:
+    """The fields of a well-formed ``InputMsg`` datagram, ``(handle,
+    start_frame, num, payload, ack_frame, sender_frame, advantage)`` as
+    :func:`decode` would give them, or None for EVERYTHING else (another
+    type, magic or version, a datagram too short for the header, a crc32
+    trailer that does not verify): the caller hands those to
+    :func:`decode`, which sorts them as ever."""
+    end = len(data) - _CRC.size
+    if (
+        end < _INPUT_HEAD.size
+        or data[2] != T_INPUT
+        or data[0] != MAGIC
+        or data[1] != VERSION
+        or zlib.crc32(data) != _CRC_RESIDUE
+    ):
+        return None
+    _, _, _, handle, start, num, size, ack, sender, advantage = (
+        _INPUT_HEAD.unpack_from(data)
+    )
+    payload = data[_INPUT_HEAD.size : min(_INPUT_HEAD.size + num * size, end)]
+    return handle, start, num, payload, ack, sender, advantage
 
 
 def unpack_input_span(
     msg: InputMsg, dtype: np.dtype, shape: Tuple[int, ...]
 ) -> List[Tuple[int, np.ndarray]]:
-    """Inverse of :func:`pack_input_span` for a known input spec."""
+    """The ``(frame, bits)`` rows of an ``InputMsg``'s payload for a known
+    input spec."""
     if msg.num == 0:
         return []
     itemsize = int(np.dtype(dtype).itemsize * int(np.prod(shape, dtype=int) or 1))

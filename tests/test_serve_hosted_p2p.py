@@ -346,6 +346,25 @@ def test_session_sums_add_up_to_the_span_a_group_tick(plane):
         assert not any(k.startswith(("poll", "serve_")) for k in m.series)
 
 
+@pytest.mark.parametrize("plane", PLANES)
+def test_direct_share_is_the_sessions_own_counts(plane):
+    """``serve_poll_direct_share``: of the datagrams a group's polls
+    received, the share parsed in place; a sample a group tick in which
+    one arrived, none while nothing did (the first tick of all)."""
+    r = served(plane)
+    series = r["metrics"].series
+    share = series["serve_poll_direct_share"]
+    assert 0 < len(share) <= len(series["serve_poll_ms"])
+    assert all(0.0 <= v <= 100.0 for v in share)
+    # Handshake legs are decoded (0 %), then play: input messages, with a
+    # quality report or a checksum now and then.
+    assert share[0] == 0.0 and max(share) == 100.0
+    assert sorted(share)[len(share) // 2] > 75.0
+    counters = r["host_metrics"].counters
+    assert 0 < counters["datagrams_in_direct"] < counters["datagrams_in"]
+    assert counters["datagrams_in_direct"] > 0.5 * counters["datagrams_in"]
+
+
 def test_native_plane_and_python_path_agree():
     nat, py = served("native"), served("python")
     assert nat["server"].groups[0]._plane is not None

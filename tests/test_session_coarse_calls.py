@@ -20,6 +20,9 @@ held unchanged across the next two calls: the buffers a native queue set
 fills are bound once, what it hands out is a copy.
 """
 
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -623,3 +626,417 @@ def test_native_call_counter_counts_entry_points():
 def test_python_plane_reports_no_counter(monkeypatch):
     monkeypatch.setattr(ncore, "_load", lambda: None)
     assert ncore.native_calls() is None
+
+
+# ---------------------------------------------------------------------------
+# The input exchange, built and parsed in place: the wire is the parent's
+#
+# A RUNNING endpoint keeps its unacked inputs as the bytes they go out as and
+# a poll parses an incoming ``T_INPUT`` datagram where it lies. Two seeded
+# lobbies live through everything that touches that store (loss, a cut link
+# longer than ``MAX_INPUT_SPAN``, a lying-high ack and the refill that heals
+# it, a disconnect with relay, a reconnect with the supervisor's gap fill, a
+# peer that never comes back, a resume from a checkpoint, a spectator that
+# joins late) and are held, on both planes, to a recording made on the code
+# before the store changed (``tests/input_exchange_gate.json``, written by
+# ``write_gate_recording()`` from a checkout of that commit with this file
+# laid over it): every datagram, event, frontier and ``network_stats``.
+
+GATE_FILE = os.path.join(os.path.dirname(__file__), "input_exchange_gate.json")
+GATE_EVERY = 30  # ticks between two samples of the frontier and the stats
+GATE_CLEAN = 30  # ticks without loss: the lobby forms, then the WAN sets in
+# Addresses of whole numbers: a session makes its endpoints in the order of a
+# set of addresses, and only such a set's order is the same in every process.
+WATCHER = (1, 0)
+
+
+def peer(handle):
+    return (0, handle)
+
+
+GATES = {
+    # A duel with a spectator on peer 0, whose acks are cut for longer than
+    # MAX_INPUT_SPAN frames; a lying-high ack; peer 0 resumed from its own
+    # checkpoint.
+    "duel": dict(
+        players=2, spec="u8", delay=0, window=MAXPRED, ticks=420, seed=23,
+        timeout=4.0, cut=[(WATCHER, peer(0), 100, 260),
+                          (peer(1), peer(0), 60, 72)],
+        lie=(300, 1, 0), resume=340, spectator_at=0,
+    ),
+    # An eight-player lobby (window 12, input delay 2) with a late
+    # spectator: peer 6 dies and rejoins from peer 0's checkpoint, peer 7
+    # dies for good while the survivors' re-armed endpoints buffer for it.
+    "lobby": dict(
+        players=8, spec="u16x2", delay=2, window=12, ticks=470, seed=29,
+        timeout=1.0, cut=[], lie=(50, 3, 2), resume=None, spectator_at=150,
+        dies=[(6, 80, 190), (7, 260, None)],
+    ),
+}
+
+
+class GateNetwork(RecordingNetwork):
+    """Keeps every datagram sent, then drops those a cut link carries."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.cut = set()
+
+    def _send(self, src, dst, msg):
+        self.wire.append((src, dst, bytes(msg)))
+        if (src, dst) not in self.cut:
+            LoopbackNetwork._send(self, src, dst, msg)
+
+
+def run_gate(name, python_plane, monkeypatch):
+    """One gate scenario, sessions only. Returns the recording (plain data,
+    as the JSON file holds it) and the wire."""
+    import hashlib
+
+    from bevy_ggrs_tpu.session.common import EventKind, NotSynchronized
+    from bevy_ggrs_tpu.utils.metrics import Metrics
+
+    cfg = GATES[name]
+    players, delay, window = cfg["players"], cfg["delay"], cfg["window"]
+    shape, dtype = SPECS[cfg["spec"]]
+    input_spec = InputSpec(shape=shape, dtype=dtype)
+    if python_plane:
+        monkeypatch.setattr(ncore, "available", lambda: False)
+    net = GateNetwork(latency=2 * FPS_DT, jitter=FPS_DT, seed=cfg["seed"])
+    clock = lambda: net.now  # noqa: E731
+
+    def builder():
+        return (
+            SessionBuilder(input_spec)
+            .with_num_players(players)
+            .with_max_prediction_window(window)
+            .with_input_delay(delay)
+            .with_disconnect_timeout(cfg["timeout"])
+        )
+
+    def build(me, sock=None):
+        b = builder()
+        for h in range(players):
+            b.add_player(
+                PlayerType.local() if h == me
+                else PlayerType.remote(peer(h)), h)
+        if me == 0:
+            b.add_player(PlayerType.spectator(WATCHER), players + 1)
+        sock = sock if sock is not None else net.socket(peer(me))
+        return b.start_p2p_session(sock, clock=clock, metrics=Metrics()), sock
+
+    sessions, socks = (list(x) for x in zip(*[build(me) for me in range(players)]))
+    assert isinstance(
+        sessions[0]._qset,
+        ncore.PyQueueSet if python_plane else ncore.NativeQueueSet,
+    )
+    spectator = None
+    rng = np.random.RandomState(cfg["seed"])
+    held = [draw(rng, shape, dtype) for _ in range(players)]
+    away = set()
+    dies = {me: (at, back) for me, at, back in cfg.get("dies", [])}
+    rec = dict(events=[], frontier=[], stats=[], wire=[], refills=0,
+               spectator_frames=0, withheld=0)
+    requests = hashlib.sha256()
+    wire_hash, hashed = hashlib.sha256(), 0
+
+    def rejoin(me, donor):
+        """A restarted peer adopts the donor's session checkpoint and fills
+        its own queue with its frozen last input up to the donor's frame,
+        as ``SessionSupervisor`` does once the donor's state has landed
+        (``supervisor.py`` ``_adopt``), here without a runner."""
+        socks[me].receive_all()  # what piled up while the process was gone
+        fresh, _ = build(me, sock=socks[me])
+        fresh.load_state_dict(sessions[donor].state_dict())
+        for h in fresh.local_handles:
+            fresh._disconnected.pop(h, None)
+            q = fresh._queues[h]
+            frozen = np.asarray(q.last_input).copy()
+            for f in range(q.last_confirmed_frame + 1, fresh.current_frame):
+                q.add_input(f, frozen)
+                fresh._tracker.note_confirmed(h, f, frozen)
+                for addr in set(fresh._handle_addr.values()):
+                    fresh._endpoints[addr].queue_input(h, f, frozen)
+        fresh._refresh_frontier()
+        return fresh
+
+    for tick in range(cfg["ticks"]):
+        net.advance(FPS_DT)
+        net.loss = 0.03 if tick >= GATE_CLEAN else 0.0
+        net.cut = {(s, d) for s, d, t0, t1 in cfg["cut"] if t0 <= tick < t1}
+        for me, (at, back) in dies.items():
+            if tick == at:
+                away.add(me)
+            if tick == back:
+                away.discard(me)
+                sessions[me] = rejoin(me, donor=0)
+        if cfg["lie"] and tick == cfg["lie"][0]:
+            _, liar, victim = cfg["lie"]
+            net._send(peer(liar), peer(victim), proto.encode(
+                proto.InputMsg(liar, 0, b"", 0, 1_000_000,
+                               sessions[liar].current_frame, 0)))
+        if cfg["resume"] is not None and tick == cfg["resume"]:
+            sd = sessions[0].state_dict()
+            sessions[0], _ = build(0, sock=socks[0])
+            sessions[0].load_state_dict(sd)
+        if tick == cfg["spectator_at"]:
+            spectator = builder().start_spectator_session(
+                peer(0), net.socket(WATCHER), clock=clock)
+        for me, s in enumerate(sessions):
+            if me in away:
+                continue
+            s.poll_remote_clients()
+            for e in s.events():
+                rec["events"].append(
+                    [tick, me, e.kind.value, repr(e.addr), repr(e.data)])
+                if e.kind == EventKind.DISCONNECTED and e.addr != WATCHER:
+                    s.reconnect_peer(e.addr)  # the supervisor's re-arm
+            if s.current_state() != SessionState.RUNNING:
+                continue
+            rec["refills"] += any(
+                ep.refill_range(me) is not None
+                for ep in s._endpoints.values())
+            if rng.rand() < 0.3:
+                held[me] = draw(rng, shape, dtype)
+            s.add_local_input(me, held[me])
+            try:
+                got = s.advance_frame()
+            except PredictionThreshold:
+                rec["withheld"] += 1
+                continue
+            requests.update(repr((me, canon_requests(got))).encode())
+            for r in got:
+                if isinstance(r, SaveGameState) and s.wants_checksum(r.frame):
+                    s.report_checksum(r.frame, (r.frame * 2654435761) & 0xFFFFFFFF)
+        if spectator is not None:
+            spectator.poll_remote_clients()
+            for e in spectator.events():
+                rec["events"].append(
+                    [tick, "spec", e.kind.value, repr(e.addr), repr(e.data)])
+            try:
+                rec["spectator_frames"] += len(spectator.advance_frame())
+            except (PredictionThreshold, NotSynchronized):
+                pass
+        if tick % GATE_EVERY == GATE_EVERY - 1 or tick == cfg["ticks"] - 1:
+            for src, dst, data in net.wire[hashed:]:
+                wire_hash.update(repr((src, dst, data)).encode())
+            hashed = len(net.wire)
+            rec["wire"].append([tick, hashed, wire_hash.hexdigest()[:16]])
+            for me, s in enumerate(sessions):
+                rec["frontier"].append([
+                    tick, me, s.current_frame, s._confirmed,
+                    list(s._last_confirmed), sorted(s._disconnected.items()),
+                ])
+                for h in s.remote_player_handles():
+                    st = s.network_stats(h)
+                    rec["stats"].append([
+                        tick, me, h, st.ping_ms, st.send_queue_len,
+                        st.kbps_sent, st.local_frames_behind,
+                        st.remote_frames_behind,
+                    ])
+    rec["requests"] = requests.hexdigest()[:16]
+    rec["counters"] = [
+        {k: v for k, v in sorted(s.metrics.counters.items())}
+        for s in sessions
+    ]
+    if python_plane:
+        monkeypatch.undo()
+    # Through JSON, as the file holds it (tuples become lists).
+    return json.loads(json.dumps(rec)), net.wire
+
+
+def write_gate_recording():
+    """Make ``tests/input_exchange_gate.json`` from the code of the checkout
+    this runs in (see the section's head)."""
+    mp = pytest.MonkeyPatch()
+    out = {name: run_gate(name, False, mp)[0] for name in GATES}
+    with open(GATE_FILE, "w") as f:
+        json.dump(out, f, sort_keys=True, separators=(",", ":"))
+        f.write("\n")
+
+
+@pytest.mark.parametrize("plane", ["native", "python"])
+@pytest.mark.parametrize("name", list(GATES))
+def test_input_exchange_is_the_parents_byte_for_byte(name, plane, monkeypatch):
+    from bevy_ggrs_tpu.session.endpoint import MAX_INPUT_SPAN
+
+    rec, wire = run_gate(name, plane == "python", monkeypatch)
+    with open(GATE_FILE) as f:
+        want = json.load(f)[name]
+    # Every input datagram either end sent is what ``proto.encode`` makes of
+    # the same fields.
+    spans, relayed = [], 0
+    for src, dst, data in wire:
+        if data[2] != proto.T_INPUT:
+            continue
+        msg = proto.decode(data)
+        assert isinstance(msg, proto.InputMsg), (src, dst, data)
+        assert proto.encode(msg) == data, (src, dst, msg)
+        spans.append(msg.num)
+        relayed += WATCHER not in (src, dst) and src != peer(msg.handle)
+    # The run reached what it was written to reach.
+    assert len(spans) > 1000
+    assert max(spans) == MAX_INPUT_SPAN  # a pending set in two datagrams
+    assert rec["refills"] > 0 and rec["spectator_frames"] > 100
+    kinds = {e[2] for e in rec["events"]}
+    if name == "duel":
+        assert {"network_interrupted", "network_resumed"} <= kinds
+    else:
+        assert relayed > 0
+        assert {"disconnected", "player_rejoined"} <= kinds
+        assert sum(c.get("input_queue_drops", 0) for c in rec["counters"]) > 0
+    # The recording. A count the parent did not keep is not held to it.
+    new = {"datagrams_in_direct"}
+    got_counters = [
+        {k: v for k, v in c.items() if k not in new} for c in rec["counters"]
+    ]
+    assert got_counters == want["counters"]
+    assert rec["wire"] == want["wire"]  # every datagram, in order
+    for key in ("events", "frontier", "stats", "requests", "refills",
+                "spectator_frames", "withheld"):
+        assert rec[key] == want[key], key
+
+
+class ParentsPending:
+    """An endpoint's unacked inputs and its input datagrams as the code
+    before PR 56 kept and made them (``session/endpoint.py`` at 078939d:
+    a dict of arrays a handle, sorted and serialised again every send)."""
+
+    def __init__(self):
+        self.pending, self.max_sent, self.last_ack, self.relayed = {}, {}, {}, set()
+        self.drops = 0
+
+    def queue(self, handle, frame, bits, relay, running):
+        from bevy_ggrs_tpu.session.endpoint import MAX_INPUT_SPAN
+
+        pending = self.pending.setdefault(handle, {})
+        pending[frame] = np.asarray(bits)
+        if relay:
+            self.relayed.add(handle)
+        if not running and len(pending) > MAX_INPUT_SPAN:
+            drop = sorted(pending)[: len(pending) - MAX_INPUT_SPAN]
+            self.drops += len(drop)
+            for f in drop:
+                del pending[f]
+
+    def ack(self, handle, ack_frame):
+        pending = self.pending.get(handle)
+        if pending is None:
+            return
+        self.last_ack[handle] = ack_frame
+        ack_frame = min(ack_frame, self.max_sent.get(handle, -1))
+        for f in [f for f in pending if f <= ack_frame]:
+            del pending[f]
+
+    def ack_own(self, ack_frame):
+        for h in list(self.pending):
+            if h not in self.relayed:
+                self.ack(h, ack_frame)
+
+    def refill_range(self, handle):
+        pending, claimed = self.pending.get(handle), self.last_ack.get(handle)
+        if pending is None or claimed is None:
+            return None
+        nxt = min(pending) if pending else self.max_sent.get(handle, -1) + 1
+        return (claimed + 1, nxt) if claimed + 1 < nxt else None
+
+    def send(self, local_frame, advantage, ack_frame):
+        from bevy_ggrs_tpu.session.endpoint import MAX_INPUT_SPAN
+
+        out = []
+        for handle, pending in self.pending.items():
+            frames = sorted(pending)
+            for i in range(0, len(frames), MAX_INPUT_SPAN):
+                chunk = frames[i : i + MAX_INPUT_SPAN]
+                payload = b"".join(
+                    np.ascontiguousarray(pending[f]).tobytes() for f in chunk)
+                out.append(proto.encode(proto.InputMsg(
+                    handle, chunk[0], payload, len(chunk), ack_frame,
+                    local_frame, advantage)))
+                self.max_sent[handle] = max(
+                    self.max_sent.get(handle, -1), chunk[-1])
+        return out
+
+
+@pytest.mark.parametrize("spec", list(SPECS))
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_unacked_store_sends_what_the_parents_dict_sent(seed, spec):
+    """The store of rows against the parent's dict of arrays, one random
+    operation at a time: appends, refills below the start, overwrites,
+    gaps, relayed handles, honest and lying acks by both message kinds,
+    the trim of a handshaking endpoint, a disconnect's clear."""
+    from bevy_ggrs_tpu.session.endpoint import (
+        MAX_INPUT_SPAN, PeerEndpoint, PeerState)
+    from bevy_ggrs_tpu.utils.metrics import Metrics
+
+    shape, dtype = SPECS[spec]
+    rng = np.random.RandomState(seed)
+    metrics = Metrics()
+    ep = PeerEndpoint((0, 1), np.random.RandomState(0), metrics=metrics)
+    ep.state = PeerState.RUNNING
+    model = ParentsPending()
+    nxt = {h: 0 for h in range(3)}  # the next frame a handle would queue
+    seen = dict(below=0, overwrite=0, gap=0, chunked=0, refill=0)
+    for step in range(3000):
+        running = ep.state == PeerState.RUNNING
+        op = rng.rand()
+        h = int(rng.choice(3, p=[0.25, 0.25, 0.5]))
+        if op < 0.45:
+            held = sorted(model.pending.get(h, {}))
+            roll = rng.rand()
+            if roll < 0.8 or not held:
+                frame = nxt[h]
+                if rng.rand() < 0.05:
+                    frame += int(rng.randint(1, 4))
+                    seen["gap"] += 1
+                nxt[h] = frame + 1
+            elif roll < 0.9:
+                frame = held[int(rng.randint(0, len(held)))]
+                seen["overwrite"] += 1
+            else:
+                frame = max(0, held[0] - int(rng.randint(1, 5)))
+                seen["below"] += frame < held[0]
+            bits = draw(rng, shape, dtype)
+            relay = h == 2
+            ep.queue_input(h, frame, bits, relay=relay)
+            model.queue(h, frame, bits, relay, running)
+        elif op < 0.65:
+            top = model.max_sent.get(h, -1)
+            ack = int(rng.randint(-1, top + 2)) if rng.rand() < 0.9 else top + 500
+            if rng.rand() < 0.5:
+                ep.on_input(0.0, ack, step, 0)
+                model.ack_own(ack)
+            elif h != 2 or rng.rand() < 0.1:
+                # The relayed handle is acked rarely: its rows pile up past
+                # one datagram's span.
+                ep.on_message(proto.InputAck(h, ack), 0.0, None)
+                model.ack(h, ack)
+        elif op < 0.97:
+            adv = int(rng.randint(-3, 4))
+            ep.send_pending_inputs(0.0, step, adv, step - 2)
+            want = model.send(step, adv, step - 2) if running else []
+            assert ep.outbox == want, step
+            seen["chunked"] += len(want) > len(
+                [p for p in model.pending.values() if p])
+            ep.outbox.clear()
+        elif op < 0.98:
+            # A handshaking endpoint buffers and trims, ~20 steps a spell.
+            ep.state = PeerState.SYNCHRONIZING
+        elif op < 0.9807 and running:
+            ep.force_disconnect()
+            model.pending.clear()
+            ep.state = PeerState.RUNNING  # the next test case, same endpoint
+        if not running and rng.rand() < 0.05:
+            ep.state = PeerState.RUNNING
+        for handle in range(3):
+            assert ep.refill_range(handle) == model.refill_range(handle), step
+            seen["refill"] += model.refill_range(handle) is not None
+        assert ep._max_sent == model.max_sent, step
+        assert ep._last_ack_rx == model.last_ack, step
+        assert {h: len(p) for h, p in ep._pending_output.items()} == {
+            h: len(p) for h, p in model.pending.items()}, step
+        assert ep.stats(0.0, step).send_queue_len == max(
+            (len(p) for p in model.pending.values()), default=0)
+    assert metrics.counters.get("input_queue_drops", 0) == model.drops
+    assert all(seen.values()), seen
+    assert model.drops > 0
