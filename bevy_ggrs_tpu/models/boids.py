@@ -97,7 +97,7 @@ def make_world(
 
 
 def _kernel_params() -> dict:
-    """The five flocking constants every Pallas/MXU kernel call shares —
+    """The five flocking constants every MXU kernel call shares —
     built in one place (read at call time, not import time) so the
     sharded and unsharded paths can never silently diverge on a tuning
     change, which would void the allclose-across-paths contract."""
@@ -121,26 +121,6 @@ def flock_system(state: WorldState, inputs: PlayerInputs) -> WorldState:
     return _flock_step(state, inputs, _pairwise_forces)
 
 
-def flock_system_pallas(state: WorldState, inputs: PlayerInputs) -> WorldState:
-    """`flock_system` with the pairwise interaction tiled through VMEM by the
-    Pallas kernel (:mod:`bevy_ggrs_tpu.ops.pairwise`) instead of XLA's dense
-    [N, N] broadcast. allclose to — but not bitwise-equal with — the XLA
-    path; pick one per session (float caveat, reference
-    ``examples/README.md:13-18``). Under entity-axis sharding this stays
-    CORRECT but not distributed: GSPMD cannot partition a custom call, so
-    it gathers around the kernel — prefer the XLA path (which GSPMD
-    partitions) for entity-sharded runs, the Pallas path for single-chip
-    branch-parallel runs."""
-    from bevy_ggrs_tpu.ops.pairwise import pairwise_force_rows_pallas
-
-    def forces(pos, vel, active):
-        return pairwise_force_rows_pallas(
-            pos, vel, pos, vel, active, active, **_kernel_params()
-        )
-
-    return _flock_step(state, inputs, forces)
-
-
 def flock_system_mxu(state: WorldState, inputs: PlayerInputs) -> WorldState:
     """`flock_system` with the pairwise reductions carried by the MXU
     (:func:`bevy_ggrs_tpu.ops.pairwise.pairwise_force_rows_mxu2`): the
@@ -152,8 +132,9 @@ def flock_system_mxu(state: WorldState, inputs: PlayerInputs) -> WorldState:
     1,024 boids, 128 branches x 8 frames a tick, two programs a tick since
     PR 32; my chip run of 2026-10-02, PR 49, `PERF.md` section 6): a tick
     6.4 ms of device time, 5.6 ms of it this kernel (7.7 and 7.0 until
-    PR 49 walked the pair block in strips; 15.6 ms a tick through
-    `flock_system_pallas`, PR 31); under `[64] x [8]` in a served dispatch
+    PR 49 walked the pair block in strips; 15.6 ms a tick with the sums
+    reduced on the VPU, PR 31: that kernel went in PR 57); under
+    `[64] x [8]` in a served dispatch
     24.4 of 27.2 ms; one step within 5e-6 of a plain float32 NumPy
     reference (before PR 31's two repairs: 4.4e-5, more for a close
     pair). At N >= 4096 the square all-vs-all
@@ -161,7 +142,7 @@ def flock_system_mxu(state: WorldState, inputs: PlayerInputs) -> WorldState:
     (:func:`~bevy_ggrs_tpu.ops.pairwise.pairwise_force_square_mxu_tri`;
     not measured on this chip); below that the
     block grid is too small to amortize the triangle's col-side work.
-    Same session caveat as the other kernels: allclose across paths,
+    The session caveat of every force path: allclose across paths,
     bitwise only within one — and the two MXU shapes are themselves
     distinct float paths, chosen statically by N, so every executable at
     a given world size uses exactly one."""
@@ -414,22 +395,22 @@ def make_sharded_flock_system(mesh, entity_axis: str = "entity",
     unsharded kernel, `tests/test_boids.py::TestShardMapSpeculative`)."""
     from jax.sharding import PartitionSpec as P
 
-    from bevy_ggrs_tpu.ops.pairwise import (
-        pairwise_force_rows_mxu2,
-        pairwise_force_rows_pallas,
-    )
+    from bevy_ggrs_tpu.ops.pairwise import pairwise_force_rows_mxu2
 
-    force_fn = (
-        pairwise_force_rows_mxu2 if kernel == "mxu"
-        else pairwise_force_rows_pallas
-    )
+    if kernel != "mxu":
+        # "xla" needs no shard_map: GSPMD partitions ``make_schedule()``.
+        raise ValueError(
+            f"unknown sharded boids force kernel {kernel!r} (accepted: 'mxu')"
+        )
     params = _kernel_params()
 
     def per_shard(p, v, a):  # p: [N/k, 2] — this shard's rows
         all_p = jax.lax.all_gather(p, entity_axis, axis=0, tiled=True)
         all_v = jax.lax.all_gather(v, entity_axis, axis=0, tiled=True)
         all_a = jax.lax.all_gather(a, entity_axis, axis=0, tiled=True)
-        return force_fn(p, v, all_p, all_v, a, all_a, **params)
+        return pairwise_force_rows_mxu2(
+            p, v, all_p, all_v, a, all_a, **params
+        )
 
     n_shards = mesh.shape[entity_axis]
 
@@ -512,36 +493,36 @@ def make_sharded_schedule(mesh, entity_axis: str = "entity",
 
 _KERNELS = {
     "xla": flock_system,
-    "pallas": flock_system_pallas,
     "mxu": flock_system_mxu,
 }
 
 
-def make_schedule(use_pallas: bool = False, kernel: Optional[str] = None,
-                  mode: Optional[str] = None) -> Schedule:
-    """``kernel``: "xla" (GSPMD-partitionable), "pallas" (VPU-tiled), or
-    "mxu" (matmul reductions). On the v5e at 1,024 boids, 128 branches x 8
-    frames a fused tick (my chip run, PR 31; `PERF.md` section 6): "mxu"
-    7.5 ms a tick, "pallas" 15.6 ms, and "xla" fails the warm-up
-    attestation there (its vmapped rollout is not bitwise its serial
-    burst), so a session on it runs without speculation. ``use_pallas``
-    is the legacy bool for the first two.
+def make_schedule(kernel: str = "xla", mode: Optional[str] = None) -> Schedule:
+    """``kernel``: "xla" (the serial reference, GSPMD-partitionable) or
+    "mxu" (the Pallas kernel, matmul reductions); any other name raises a
+    ``ValueError``. On the v5e at 1,024 boids, 128 branches x 8 frames a
+    fused tick (my chip runs, PR 31 and PR 49; `PERF.md` section 6): "mxu"
+    7.5 then 6.4 ms a tick, and "xla" fails the warm-up attestation there
+    (its vmapped rollout is not bitwise its serial burst), so a session on
+    it runs without speculation.
 
     ``mode`` selects the interaction structure: "dense" (the O(N²)
-    kernels above), "grid" (the O(N·k) neighbor grid — "pallas"/"mxu"
-    kernels route its per-cell compute through the cell-gather kernel,
-    "xla" stays pure XLA), or "auto" (grid at N >= neighbor grid
+    paths above), "grid" (the O(N·k) neighbor grid — "mxu" routes its
+    per-cell compute through the cell-gather kernel, "xla" stays pure
+    XLA), or "auto" (grid at N >= neighbor grid
     threshold). ``None`` keeps the legacy dense default. Resolution
     happens at trace time via :func:`bevy_ggrs_tpu.ops.neighbor.
     resolve_mode` — the ``GGRS_FORCE_MODE`` env var and the
     ``SessionBuilder.with_interaction_mode`` session default override
     ``None``/"auto" (never an explicit "dense"/"grid")."""
-    if kernel is None:
-        kernel = "pallas" if use_pallas else "xla"
+    if kernel not in _KERNELS:
+        raise ValueError(
+            f"unknown boids force kernel {kernel!r} "
+            f"(accepted: {', '.join(map(repr, _KERNELS))})"
+        )
     dense_system = _KERNELS[kernel]
     grid_system = (
-        flock_system_grid_pallas if kernel in ("pallas", "mxu")
-        else flock_system_grid
+        flock_system_grid_pallas if kernel == "mxu" else flock_system_grid
     )
 
     def flock(state: WorldState, inputs: PlayerInputs) -> WorldState:

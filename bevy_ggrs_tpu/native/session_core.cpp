@@ -490,8 +490,8 @@ void ggrs_qs_ingest(void* qs_v, void* rt_v, int handle, int32_t start_frame,
 // ===========================================================================
 // Speculative branch-tree builder / matcher
 //
-// The per-tick speculation host path (spec_runner.py `_candidate_values`,
-// `_extrapolate_base`, `_structured_bits`, the dedup signature, and the
+// The per-tick speculation host path (branch_tree.py `candidate_values`,
+// `extrapolate_base`, `structured_bits`, the dedup signature, and the
 // corrected-history branch match) measured 2.5-5.7 ms of Python/NumPy per
 // tick against the 1 ms host-dispatch budget (round-5 verdict weak #1).
 // This port is BITWISE-IDENTICAL to that Python path — element values are
@@ -698,7 +698,7 @@ int ggrs_sb_build(void* p, void* qs_v, int32_t anchor,
   auto it_last = sb->log.find(anchor - 1);
   if (it_last != sb->log.end()) last = it_last->second.data();
 
-  // known/mask: the _known_inputs confirmed-span query, in-process.
+  // known/mask: the known_inputs confirmed-span query, in-process.
   std::vector<uint8_t> known(size_t(F) * fb);
   std::vector<uint8_t> mask(size_t(F) * size_t(P), 0);
   if (qs_v) {
@@ -722,7 +722,7 @@ int ggrs_sb_build(void* p, void* qs_v, int32_t anchor,
     std::memcpy(mask.data(), mask_in, mask.size());
   }
 
-  // History fingerprint (_history_fingerprint): contiguous <=48-frame
+  // History fingerprint (history_fingerprint): contiguous <=48-frame
   // window ending at anchor-1, crc32-chained over the raw log rows.
   const int32_t L = anchor - 1;
   int32_t wstart = L;
@@ -793,7 +793,7 @@ int ggrs_sb_build(void* p, void* qs_v, int32_t anchor,
     return 0;
   }
 
-  // Periodic extrapolation (_extrapolate_base): smallest period p in 2..16
+  // Periodic extrapolation (extrapolate_base): smallest period p in 2..16
   // over the fingerprint window; prediction for frame g is the logged value
   // at g - p (phase-aligned). Skipped per (player, field) on
   // out-of-universe history, aperiodic or constant-tail sequences.
@@ -805,7 +805,7 @@ int ggrs_sb_build(void* p, void* qs_v, int32_t anchor,
   if (use_seed) {
     // The predictor's autoregressive trajectory replaces the periodic
     // extrapolator as the effective base (known slots re-pinned below,
-    // exactly like the Python hook in _structured_bits). Branch 0
+    // exactly like the Python hook in structured_bits). Branch 0
     // still renders the literal forward-fill prediction.
     predv.resize(size_t(F) * PK);
     for (size_t i = 0; i < size_t(F) * PK; ++i)
@@ -894,7 +894,7 @@ int ggrs_sb_build(void* p, void* qs_v, int32_t anchor,
   int start_b = 1;
   if (has_pred && predv != basev) start_b = 2;
 
-  // History-ranked candidate rows (_candidate_values): recent values
+  // History-ranked candidate rows (candidate_values): recent values
   // first-occurrence over the newest-first <=32-frame log window, then
   // one-button toggles (recently-changed bits first), then the declared
   // universe — deduped and clamped to the universe.

@@ -9,7 +9,8 @@ never *why* it fails. The ledger records one causal entry per rollback:
   matcher already computes — no extra device sync);
 - **rank** — which branch matched. The structured tree enumerates
   candidates rank-major (every slot's best candidate before any slot's
-  second, ``spec_runner._structured_bits``), so the matched branch index
+  second, ``branch_tree.BranchTree.structured_bits``), so the matched
+  branch index
   IS the candidate rank — the signal a learned ranking policy trains
   against;
 - **economics** — frames recovered vs resimulated per rollback, and
@@ -48,12 +49,15 @@ so this module stays import-light for the runner hot path.
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import time
 from collections import Counter, deque
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+
+from bevy_ggrs_tpu.branch_tree import BranchTree, forward_fill
 
 #: Entry outcomes, in reconciliation order (see module docstring).
 OUTCOMES: Tuple[str, ...] = ("full", "partial", "miss", "unmatched")
@@ -425,10 +429,12 @@ null_ledger = _NullLedger()
 # ----------------------------------------------------------------------
 
 #: Pluggable ranking-policy registry. A policy is registered under a
-#: name as a FACTORY: it receives the fresh per-run ``_ReplayBuilder``
-#: (branch-tree geometry + the growing canonical input log; it may
-#: swap the log for a native ``MirroredLog`` or attach a
-#: ``_predictor``) and returns the per-anchor callable
+#: name as a FACTORY: it receives the configuration's
+#: :class:`~bevy_ggrs_tpu.branch_tree.BranchTree` (the class the live
+#: runner and every served slot build their trees with: no world, no
+#: schedule, no executor) and the canonical input log, a ``dict`` the
+#: harness grows a frame at a time (every frame below ``anchor`` is in
+#: it when ``anchor`` is asked for), and returns the per-anchor callable
 #: ``fn(anchor, last, known, mask) -> (bits, n_branches)``. Built-ins:
 #:
 #: - ``current``     — the production structured tree (history-ranked
@@ -442,7 +448,7 @@ null_ledger = _NullLedger()
 #:
 #: Future rankers call :func:`register_policy` instead of editing the
 #: harness.
-#: factory(builder) -> fn(anchor, last, known, mask) -> (bits, n_branches)
+#: factory(tree, log) -> fn(anchor, last, known, mask) -> (bits, n_branches)
 PolicyFactory = Callable[..., Callable]
 
 POLICY_REGISTRY: Dict[str, PolicyFactory] = {}
@@ -503,27 +509,6 @@ def _neural_bots_spec():
     return neural_bots.INPUT_SPEC
 
 
-class _ReplayBuilder:
-    """Host-only stand-in that borrows the runner's unbound branch-tree
-    methods (the `_SlotSpecShim` trick from serve/batch.py) so the
-    harness builds bitwise the SAME tree the live runner dispatches —
-    without constructing a world, schedule, or executor."""
-
-    def __init__(self, input_spec, players, branches, frames, values):
-        from bevy_ggrs_tpu.spec_runner import SpeculativeRollbackRunner as R
-
-        self.input_spec = input_spec
-        self.num_players = int(players)
-        self.num_branches = int(branches)
-        self.spec_frames = int(frames)
-        self._branch_values = list(values)
-        self._input_log: dict = {}
-        self._structured_bits = R._structured_bits.__get__(self)
-        self._candidate_values = R._candidate_values.__get__(self)
-        self._extrapolate_base = R._extrapolate_base.__get__(self)
-        self._history_fingerprint = R._history_fingerprint.__get__(self)
-
-
 def _branch_values_for(input_spec) -> list:
     # The runner ctor's default universe resolution.
     if getattr(input_spec, "values", None):
@@ -535,68 +520,71 @@ def _branch_values_for(input_spec) -> list:
 
 
 @register_policy("current")
-def _policy_current(builder: "_ReplayBuilder"):
+def _policy_current(tree: BranchTree, log: dict):
     """The production structured tree, native builder when it loads."""
     from bevy_ggrs_tpu.native import spec as native_spec
 
     native = native_spec.make_spec_builder(
-        builder.input_spec, builder.num_players, builder.num_branches,
-        builder.spec_frames, builder._branch_values,
+        tree.input_spec, tree.num_players, tree.num_branches,
+        tree.spec_frames, tree.branch_values,
     )
-    if native is not None:
-        builder._input_log = native_spec.MirroredLog(native)
+
+    mirrored = 0  # the native builder ranks from its own mirror of ``log``
 
     def fn(anchor, last, known, mask):
+        nonlocal mirrored
         if native is not None:
+            for f in range(mirrored, anchor):
+                native.log_set(f, log[f])
+            mirrored = anchor
             bits, _ = native.build(anchor, None, known, mask, False, None)
         else:
-            bits = builder._structured_bits(
-                np.asarray(last), known, mask, anchor
+            bits = tree.structured_bits(
+                log, np.asarray(last), known, mask, anchor
             )
-        return np.asarray(bits), builder.num_branches
+        return np.asarray(bits), tree.num_branches
 
     return fn
 
 
 @register_policy("repeat_last")
-def _policy_repeat_last(builder: "_ReplayBuilder"):
+def _policy_repeat_last(tree: BranchTree, log: dict):
     """The single forward-fill branch — the reference engine's whole
     prediction policy, and the learned ranker's floor."""
-    from bevy_ggrs_tpu.spec_runner import _forward_fill
 
     def fn(anchor, last, known, mask):
-        base = _forward_fill(np.asarray(last), known, mask)
+        base = forward_fill(np.asarray(last), known, mask)
         return np.broadcast_to(base, (1,) + base.shape).copy(), 1
 
     return fn
 
 
 @register_policy("learned")
-def _policy_learned(builder: "_ReplayBuilder"):
+def _policy_learned(tree: BranchTree, log: dict):
     """The ``predict/`` tier: the committed int8 MLP artifact bound to
     this config's universe, seeding the same structured tree the live
-    path builds (branch 0 stays repeat-last inside `_structured_bits`)."""
+    path builds (branch 0 stays repeat-last inside ``structured_bits``)."""
     from bevy_ggrs_tpu.predict import InputPredictor, load_default
 
-    spec = builder.input_spec
+    spec = tree.input_spec
     n_field = 1
     if getattr(spec, "shape", ()):
         n_field = int(np.prod(spec.shape, dtype=np.int64))
     bound = InputPredictor(load_default()).bind(
-        builder._branch_values, spec.zeros_np(1).dtype, n_field
+        tree.branch_values, spec.zeros_np(1).dtype, n_field
     )
     if bound is None:
         raise ValueError(
             "learned policy: predictor does not apply to this config "
-            f"(n_field={n_field}, universe={len(builder._branch_values)})"
+            f"(n_field={n_field}, universe={len(tree.branch_values)})"
         )
-    builder._predictor = bound
+    seeded_tree = dataclasses.replace(tree, predictor=bound)
 
     def fn(anchor, last, known, mask):
-        bits = builder._structured_bits(
-            np.asarray(last), known, mask, anchor
+        bits = seeded_tree.structured_bits(
+            log, np.asarray(last), known, mask, anchor
         )
-        return np.asarray(bits), builder.num_branches
+        return np.asarray(bits), tree.num_branches
 
     return fn
 
@@ -642,16 +630,16 @@ def replay_config(
                 f"unknown ranking policy {policy!r} "
                 f"(registered: {', '.join(POLICY_REGISTRY)})"
             )
-        builder = _ReplayBuilder(spec, P, B, F, values)
-        policy_fn = factory(builder)
+        log: dict = {}
+        policy_fn = factory(BranchTree(spec, P, B, F, values), log)
         ledger = SpeculationLedger(capacity=frames + 1)
         full_hits = 0
         anchors = 0
         # Warm 16 frames of history before the first anchor so the
         # recency ranking and period detector see a real log.
-        builder._input_log[0] = frame_input(0)
+        log[0] = frame_input(0)
         for a in range(1, max(2, frames - F)):
-            last = builder._input_log[a - 1]
+            last = log[a - 1]
             bits, n_branches = policy_fn(a, last, known, mask)
             truth = np.stack([frame_input(a + t) for t in range(F)])
             branch, depth = match_branch(np.asarray(bits), truth)
@@ -673,7 +661,7 @@ def replay_config(
                 blame_frame=None if blame is None else a + blame[0],
                 load_frame=a,
             )
-            builder._input_log[a] = frame_input(a)
+            log[a] = frame_input(a)
         s = ledger.summary()
         out[policy] = {
             "anchors": anchors,

@@ -18,7 +18,7 @@ Outputs:
   ``stage;frame;...;leaf`` path with the accumulated self-time in
   integer microseconds — ``flamegraph.pl`` or speedscope load it as-is;
 - **per-stage culprit tables** (:meth:`report`): ranked leaf-frame
-  self-time per span, the "branch_build_ms is 62% ``_structured_bits``"
+  self-time per span, the "branch_build_ms is 62% ``structured_bits``"
   answer, and its compact form (:meth:`profile_blob`) that a fleet
   child's heartbeat carries under ``GGRS_HOST_PROFILE=1``;
 - **a Perfetto counter track** (:meth:`export_perfetto`): stack depth +

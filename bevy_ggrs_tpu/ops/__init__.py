@@ -26,7 +26,6 @@ from bevy_ggrs_tpu.ops.neighbor import (
     resolve_mode,
     set_default_interaction_mode,
 )
-from bevy_ggrs_tpu.ops.pairwise import pairwise_force_rows_pallas
 
 __all__ = [
     "GridConfig",
@@ -37,7 +36,6 @@ __all__ = [
     "grid_stats",
     "install_pallas_checksum",
     "interact",
-    "pairwise_force_rows_pallas",
     "resolve_mode",
     "set_default_interaction_mode",
 ]

@@ -4,7 +4,7 @@ The grid-mode counterpart of :mod:`ops.pairwise`. The XLA grid path
 materializes [C, K, M] pair-term intermediates in HBM; this kernel streams
 the candidate axis through VMEM in ``col_chunk`` slices, keeps the
 ``n_terms`` running sums in VMEM scratch (one [cell_block, K] accumulator
-per term, the idiom of ``ops/pairwise._force_kernel``), and applies
+per term, the idiom of ``ops/pairwise._force_kernel_mxu2``), and applies
 ``PairKernel.combine`` on-chip in the last column step — HBM traffic is
 the gathered operands plus [C, K] outputs, never the pair cube.
 

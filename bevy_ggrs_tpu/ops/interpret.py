@@ -1,6 +1,6 @@
 """Where this package's Pallas kernels run interpreted.
 
-One rule for all six ``pallas_call`` sites (checksum, the three dense
+One rule for all five ``pallas_call`` sites (checksum, the two dense
 pairwise kernels, the grid cell kernel, the in-place ring row write):
 compiled by Mosaic on a TPU, interpreted on any other backend (the CPU
 test mesh). Nothing else flips it — no argument, no environment variable

@@ -1,14 +1,14 @@
-"""Blocked Pallas kernel for all-pairs flocking forces (boids hot op).
+"""Blocked Pallas kernels for all-pairs flocking forces (boids hot op).
 
 The XLA path (:func:`bevy_ggrs_tpu.models.boids.pairwise_force_rows`)
 materializes [R, N]-shaped neighbor masks and broadcast diffs; at the
 BASELINE.md config-4 scale (1k+ boids × branches × frames) those
-intermediates round-trip HBM. This kernel tiles rows × columns through VMEM:
-each (row-block, col-block) step computes the block's pairwise interactions
-entirely on-chip and folds them into seven per-row accumulators (neighbor
-count, separation x/y, velocity sum x/y, position sum x/y) held in VMEM
-scratch; the final column step applies the mean/weight combine and writes
-the force — one HBM read per input element, one write per output.
+intermediates round-trip HBM. The kernels here tile rows × columns through
+VMEM: each (row-block, col-block) step computes the block's pairwise
+interactions entirely on-chip and folds them into per-row neighborhood sums
+(neighbor count, separation x/y, velocity sum x/y, position sum x/y) held
+in VMEM scratch; the final column step applies the mean/weight combine and
+writes the force — one HBM read per input element, one write per output.
 
 The column-block accumulation order is fixed (sequential grid), so results
 are deterministic per platform+shape — the property SyncTest checks — but
@@ -17,14 +17,15 @@ bitwise equal: a session must use one path consistently, same as the
 reference's "all peers must share an architecture" float caveat
 (``/root/reference/examples/README.md:13-18``).
 
-Three kernels: the VPU kernel above (:func:`pairwise_force_rows_pallas`),
-the MXU kernel the boids configurations run (:func:`pairwise_force_rows_mxu2`:
-the per-row sums as skinny matmuls, the masks on the VPU) and its
-symmetry-halved triangle form for N >= 4,096. What the chip read of them,
-by PR, shape and date, is in each one's docstring; the sizes below are
-chosen by what the vector unit's 64 registers and four ALU slots take, not
-by what fits VMEM (a [512, 1024] float32 block is 512 registers, and VMEM
-holds dozens of them).
+Two kernels: the MXU kernel the boids configurations run
+(:func:`pairwise_force_rows_mxu2`: the per-row sums as skinny matmuls, the
+masks on the VPU) and its symmetry-halved triangle form for N >= 4,096. (A
+third, which reduced the sums on the VPU as well, read 15.6 ms a 128 x 8
+tick at N = 1,024 against this one's 6.4 and went in PR 57.) What the chip
+read of them, by PR, shape and date, is in each one's docstring; the sizes
+below are chosen by what the vector unit's 64 registers and four ALU slots
+take, not by what fits VMEM (a [512, 1024] float32 block is 512 registers,
+and VMEM holds dozens of them).
 """
 
 from __future__ import annotations
@@ -50,153 +51,6 @@ FORCE_SCOPE = TRACE_PREFIX + FORCE
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
-
-
-def _force_kernel(
-    rpx, rpy, rvx, rvy, ra,  # row refs: [R_BLK, 1]
-    cpx, cpy, cvx, cvy, ca,  # col refs: [1, C_BLK]
-    fx_out, fy_out,  # [R_BLK, 1]
-    acc_n, acc_sx, acc_sy, acc_vx, acc_vy, acc_px, acc_py,  # VMEM scratch [R_BLK, 1]
-    *,
-    neighbor_radius: float,
-    separation_radius: float,
-    w_separation: float,
-    w_alignment: float,
-    w_cohesion: float,
-):
-    cj = pl.program_id(1)
-    n_cols = pl.num_programs(1)
-
-    @pl.when(cj == 0)
-    def _reset():
-        for ref in (acc_n, acc_sx, acc_sy, acc_vx, acc_vy, acc_px, acc_py):
-            ref[...] = jnp.zeros_like(ref)
-
-    one = jnp.float32(1.0)
-    dx = rpx[...] - cpx[...]  # [R_BLK, C_BLK]
-    dy = rpy[...] - cpy[...]
-    d2 = dx * dx + dy * dy
-    both = ra[...] * ca[...]
-    # Membership tests on d² (identical float values to the XLA path's, so
-    # borderline pairs classify the same); 1/d via one rsqrt — no sqrt or
-    # divide in the inner loop.
-    not_self = one - (d2 < jnp.float32(1e-10)).astype(jnp.float32)
-    neigh = (
-        both
-        * (d2 < jnp.float32(neighbor_radius) ** 2).astype(jnp.float32)
-        * not_self
-    )
-    close = neigh * (d2 < jnp.float32(separation_radius) ** 2).astype(jnp.float32)
-
-    inv_d = jax.lax.rsqrt(jnp.maximum(d2, jnp.float32(1e-12)))
-    acc_n[...] += jnp.sum(neigh, axis=1, keepdims=True)
-    acc_sx[...] += jnp.sum(dx * inv_d * close, axis=1, keepdims=True)
-    acc_sy[...] += jnp.sum(dy * inv_d * close, axis=1, keepdims=True)
-    acc_vx[...] += jnp.sum(cvx[...] * neigh, axis=1, keepdims=True)
-    acc_vy[...] += jnp.sum(cvy[...] * neigh, axis=1, keepdims=True)
-    acc_px[...] += jnp.sum(cpx[...] * neigh, axis=1, keepdims=True)
-    acc_py[...] += jnp.sum(cpy[...] * neigh, axis=1, keepdims=True)
-
-    @pl.when(cj == n_cols - 1)
-    def _combine():
-        n = acc_n[...]
-        n_safe = jnp.maximum(n, one)
-        has = (n > 0).astype(jnp.float32)
-        fx = (
-            jnp.float32(w_separation) * acc_sx[...]
-            + jnp.float32(w_alignment) * (acc_vx[...] / n_safe - rvx[...]) * has
-            + jnp.float32(w_cohesion) * (acc_px[...] / n_safe - rpx[...]) * has
-        )
-        fy = (
-            jnp.float32(w_separation) * acc_sy[...]
-            + jnp.float32(w_alignment) * (acc_vy[...] / n_safe - rvy[...]) * has
-            + jnp.float32(w_cohesion) * (acc_py[...] / n_safe - rpy[...]) * has
-        )
-        fx_out[...] = fx * ra[...]
-        fy_out[...] = fy * ra[...]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "neighbor_radius",
-        "separation_radius",
-        "w_separation",
-        "w_alignment",
-        "w_cohesion",
-        "row_block",
-        "col_block",
-    ),
-)
-@device_scope(FORCE)
-def pairwise_force_rows_pallas(
-    row_pos: jnp.ndarray,  # [R, 2]
-    row_vel: jnp.ndarray,  # [R, 2]
-    all_pos: jnp.ndarray,  # [N, 2]
-    all_vel: jnp.ndarray,  # [N, 2]
-    row_active: jnp.ndarray,  # float[R]
-    all_active: jnp.ndarray,  # float[N]
-    *,
-    neighbor_radius: float,
-    separation_radius: float,
-    w_separation: float,
-    w_alignment: float,
-    w_cohesion: float,
-    row_block: int = 512,
-    col_block: int = 1024,
-) -> jnp.ndarray:
-    """Same contract as :func:`models.boids.pairwise_force_rows` (separation /
-    alignment / cohesion force per row boid from all boids), tiled on-chip."""
-    R, N = row_pos.shape[0], all_pos.shape[0]
-    r_blk = min(row_block, _round_up(R, 8))
-    c_blk = min(col_block, _round_up(N, 128))
-    r_pad = _round_up(R, r_blk) - R
-    n_pad = _round_up(N, c_blk) - N
-
-    # Padded rows carry row_active=0 (force masked to 0); padded cols carry
-    # all_active=0 (excluded from every neighborhood sum).
-    def col(v, pad):
-        return jnp.pad(v.astype(jnp.float32), (0, pad))
-
-    rows = [
-        col(row_pos[:, 0], r_pad)[:, None],
-        col(row_pos[:, 1], r_pad)[:, None],
-        col(row_vel[:, 0], r_pad)[:, None],
-        col(row_vel[:, 1], r_pad)[:, None],
-        col(row_active, r_pad)[:, None],
-    ]
-    cols = [
-        col(all_pos[:, 0], n_pad)[None, :],
-        col(all_pos[:, 1], n_pad)[None, :],
-        col(all_vel[:, 0], n_pad)[None, :],
-        col(all_vel[:, 1], n_pad)[None, :],
-        col(all_active, n_pad)[None, :],
-    ]
-    grid = ((R + r_pad) // r_blk, (N + n_pad) // c_blk)
-    row_spec = pl.BlockSpec((r_blk, 1), lambda ri, cj: (ri, 0))
-    col_spec = pl.BlockSpec((1, c_blk), lambda ri, cj: (0, cj))
-    out_spec = pl.BlockSpec((r_blk, 1), lambda ri, cj: (ri, 0))
-    kernel = functools.partial(
-        _force_kernel,
-        neighbor_radius=neighbor_radius,
-        separation_radius=separation_radius,
-        w_separation=w_separation,
-        w_alignment=w_alignment,
-        w_cohesion=w_cohesion,
-    )
-    fx, fy = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[row_spec] * 5 + [col_spec] * 5,
-        out_specs=[out_spec, out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((R + r_pad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((R + r_pad, 1), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((r_blk, 1), jnp.float32)] * 7,
-        interpret=pallas_interpret(),
-    )(*rows, *cols)
-    return jnp.concatenate([fx[:R], fy[:R]], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -387,8 +241,9 @@ def _force_kernel_mxu2(
     w_cohesion: float,
     strip: int,
 ):
-    """The VPU kernel's seven per-row accumulators, restated as two skinny
-    matmuls so the MXU carries the reduction:
+    """The seven per-row neighborhood sums (neighbor count, separation
+    x/y, velocity sum x/y, position sum x/y) as two skinny matmuls, so the
+    MXU carries the reduction:
 
     - every neighborhood sum is ``Σ_j M_ij · f_j`` for a pair matrix ``M``
       (the 0/1 neighbor mask, or the separation weight ``close·1/d``) and
@@ -401,7 +256,7 @@ def _force_kernel_mxu2(
 
     Orientation is the whole ballgame: ``M[R,C] @ F[C,k]`` puts the tiny
     k≈10 on the 128-lane axis (92% of the MXU idle — measured SLOWER than
-    the VPU kernel); feature-major ``F[k, C] · M[R, C] -> [k, R]`` (both
+    reducing on the VPU); feature-major ``F[k, C] · M[R, C] -> [k, R]`` (both
     operands contract their lane axis) pads k to the 8-sublane tile
     instead, and ``M`` is the operand the MXU holds still (a transposed
     push of 1.5 registers a float32 register of pairs: the MXU's slots are
@@ -461,10 +316,10 @@ def _force_kernel_mxu2(
     than ``1 / CLOSE_W`` leaves the matmul form (:func:`_close_pair_sums`):
     with both, one step stays within a few 1e-6 of a float32 NumPy
     reference whatever the flock (PR 31). ``d2`` and the membership masks
-    are computed in f32 exactly like the XLA/VPU paths, so borderline
-    pairs classify identically on all three; only summation rounding
-    differs (allclose, not bitwise — the same session contract as the VPU
-    kernel). ``rsqrt(d2)`` is taken without an epsilon clamp: pairs with
+    are computed in f32 exactly like the XLA path's, so borderline
+    pairs classify identically on both; only summation rounding
+    differs (allclose, not bitwise: a session uses one path throughout).
+    ``rsqrt(d2)`` is taken without an epsilon clamp: pairs with
     ``d2 < 1e-10`` are outside ``nb``, so an inf can never be selected
     into ``w`` — bitwise identical, one fewer [R, C] VPU op."""
     cj = pl.program_id(1)
@@ -567,8 +422,10 @@ def pairwise_force_rows_mxu2(
     col_block: int = 1024,
     strip_rows: int = STRIP_ROWS,
 ) -> jnp.ndarray:
-    """Same contract as :func:`pairwise_force_rows_pallas`, reductions on
-    the MXU in feature-major orientation (see :func:`_force_kernel_mxu2`).
+    """Same contract as :func:`models.boids.pairwise_force_rows`
+    (separation / alignment / cohesion force per row boid from all boids),
+    tiled on-chip, reductions on the MXU in feature-major orientation (see
+    :func:`_force_kernel_mxu2`).
     Rows pad to a multiple of ``STRIP_ROWS``; ``strip_rows`` is a multiple
     of it that divides the row block (or the whole block, the form before
     PR 49) and changes no bit of a force (``tests/test_ops.py``)."""

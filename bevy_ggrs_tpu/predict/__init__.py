@@ -3,7 +3,10 @@ input-transition MLP that seeds candidate ranking in the speculative
 branch-tree builder.
 
 The tier has three consumers, wired in this order (ROADMAP: "as a third
-policy there FIRST"):
+policy there FIRST"). Each binds the predictor to its configuration's
+:class:`~bevy_ggrs_tpu.branch_tree.BranchTree` (the ``predictor`` field)
+and hands a ranking it already made to the build as
+``structured_bits(seed=)``:
 
 1. the counterfactual replay harness (``obs/ledger.py`` policy
    ``learned``), scored offline against the frozen ``spec_baseline.json``;

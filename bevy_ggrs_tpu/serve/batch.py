@@ -77,11 +77,13 @@ lane's included) and, once the dispatch is made, ``account_rollback`` (the
 counters, the outcome, the ledger entry). Both host paths here only stage a
 lane's inputs for them and its burst rows behind them.
 
-Per-slot tree building reuses the singleton implementation verbatim: the
-native builder is instantiated per slot (it owns a per-match C++ input-log
-mirror) and the pure-Python fallback borrows :class:`~bevy_ggrs_tpu.
-spec_runner.SpeculativeRollbackRunner`'s tree-builder methods unbound
-through :class:`_SlotSpecShim` — bit-identical trees by construction.
+Per-slot tree building is the singleton's: the native builder is
+instantiated per slot (it owns a per-match C++ input-log mirror) and the
+pure-Python fallback is the ONE :class:`~bevy_ggrs_tpu.branch_tree.
+BranchTree` the core holds for all its slots (they share the
+configuration; the log it is handed is the slot's), the class the
+singleton runner builds its own from (``tests/test_branch_tree.py`` holds
+the two to the same bits).
 """
 
 from __future__ import annotations
@@ -93,6 +95,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bevy_ggrs_tpu.branch_tree import BranchTree
 from bevy_ggrs_tpu.fused import (
     LANE_AXIS,
     IoBuffers,
@@ -113,7 +116,6 @@ from bevy_ggrs_tpu.predict.model import resolve_predictor
 from bevy_ggrs_tpu.schedule import Schedule
 from bevy_ggrs_tpu.serve.faults import SlotFault, SlotTicket
 from bevy_ggrs_tpu.session.requests import Segment, SegmentError
-from bevy_ggrs_tpu.spec_runner import SpeculativeRollbackRunner
 from bevy_ggrs_tpu.state import (
     SnapshotRing,
     WorldState,
@@ -303,30 +305,6 @@ class BatchedTickExecutor:
         )
 
 
-class _SlotSpecShim:
-    """Adapter exposing exactly the attributes the singleton runner's
-    branch-tree methods read, so they can run UNBOUND against a per-slot
-    input log. Any drift between batched and singleton trees is therefore
-    impossible short of editing the singleton itself."""
-
-    _structured_bits = SpeculativeRollbackRunner._structured_bits
-    _candidate_values = SpeculativeRollbackRunner._candidate_values
-    _extrapolate_base = SpeculativeRollbackRunner._extrapolate_base
-    _history_fingerprint = SpeculativeRollbackRunner._history_fingerprint
-    _known_inputs = SpeculativeRollbackRunner._known_inputs
-
-    def __init__(
-        self, input_spec, num_players, num_branches, spec_frames,
-        branch_values, input_log,
-    ):
-        self.input_spec = input_spec
-        self.num_players = num_players
-        self.num_branches = num_branches
-        self.spec_frames = spec_frames
-        self._branch_values = branch_values
-        self._input_log = input_log
-
-
 class _Slot:
     """Host-side record of one batch slot: match identity, frame counter,
     per-slot input log / native builder, and the metadata of the pending
@@ -334,7 +312,7 @@ class _Slot:
 
     __slots__ = (
         "index", "active", "frame", "spec_on", "native", "input_log",
-        "shim", "res_anchor", "res_bits",
+        "res_anchor", "res_bits",
     )
 
     def __init__(self, index: int):
@@ -344,7 +322,6 @@ class _Slot:
         self.spec_on = True
         self.native = None
         self.input_log: dict = {}
-        self.shim: Optional[_SlotSpecShim] = None
         self.res_anchor: Optional[int] = None
         self.res_bits: Optional[np.ndarray] = None
 
@@ -521,6 +498,12 @@ class BatchedSessionCore(Instrumented):
         self._ranker = (
             BatchedRanker(self._predictor, self.spec_frames)
             if self._predictor is not None else None
+        )
+        # One tree for every slot: the slots share this configuration,
+        # and the log a build reads is the slot's own.
+        self._tree = BranchTree(
+            self.input_spec, self.num_players, B, F, self._branch_values,
+            self._predictor,
         )
         # Native batched data plane (native/spec.NativeBatchPlane): the
         # whole per-slot host loop — as-used log appends, in-flight tree
@@ -709,14 +692,6 @@ class BatchedSessionCore(Instrumented):
             # mirror, so readmitted slots rank/fingerprint from the same
             # history either way.
             s.input_log.update(ticket.input_log)
-        s.shim = _SlotSpecShim(
-            self.input_spec, self.num_players, self.num_branches,
-            self.spec_frames, self._branch_values, s.input_log,
-        )
-        if self._predictor is not None:
-            # The borrowed _structured_bits picks this up via getattr;
-            # per-dispatch seeds land in _seed_memo (see _dispatch).
-            s.shim._predictor = self._predictor
         return slot
 
     def retire(self, slot: int) -> None:
@@ -736,7 +711,6 @@ class BatchedSessionCore(Instrumented):
         s.active = False
         s.native = None
         s.input_log = {}
-        s.shim = None
         s.res_anchor = None
         s.res_bits = None
 
@@ -899,9 +873,10 @@ class BatchedSessionCore(Instrumented):
     def _build_branches(self, s: _Slot, anchor: int, end: int, session,
                         seed=None):
         """The next rollout's branch tensor for one slot — the singleton
-        builder, verbatim (native when available, else the borrowed
-        structured tree). ``seed`` is this slot's slice of the batched
-        predictor ranking (None when the predictor is off)."""
+        builder, verbatim (native when available, else the core's
+        :class:`BranchTree` over this slot's log). ``seed`` is this slot's
+        slice of the batched predictor ranking (None when the predictor is
+        off or did not rank this slot: the tree then asks it)."""
         if s.native is not None:
             if seed is not None:
                 s.native.seed(anchor, seed)
@@ -911,7 +886,7 @@ class BatchedSessionCore(Instrumented):
             elif session is None:
                 known, known_mask = self._known0, self._mask0
             else:
-                known, known_mask = s.shim._known_inputs(anchor, session)
+                known, known_mask = self._tree.known_inputs(session, anchor)
             bits, _sig = s.native.build(
                 anchor, qs_ptr, known, known_mask, False, None
             )
@@ -922,13 +897,10 @@ class BatchedSessionCore(Instrumented):
         if session is None:
             known, known_mask = self._known0, self._mask0
         else:
-            known, known_mask = s.shim._known_inputs(anchor, session)
-        if getattr(s.shim, "_predictor", None) is not None:
-            # Fresh per-call memo: a stale one (same anchor, pre-burst
-            # window) must never leak into this build.
-            s.shim._seed_memo = (anchor, seed) if seed is not None else None
-        return s.shim._structured_bits(
-            np.asarray(last), known, known_mask, anchor
+            known, known_mask = self._tree.known_inputs(session, anchor)
+        return self._tree.structured_bits(
+            s.input_log, np.asarray(last), known, known_mask, anchor,
+            seed=seed,
         )
 
     def _dispatch(self, batch: Dict[int, tuple]) -> None:
@@ -1392,7 +1364,9 @@ class BatchedSessionCore(Instrumented):
                         # Sessions with a confirmed-inputs surface but no
                         # native queue set: the Python bulk query fills this
                         # slot's known rows (re-zeroed after the build).
-                        known, kmask = s.shim._known_inputs(anchor, session)
+                        known, kmask = self._tree.known_inputs(
+                            session, anchor
+                        )
                         plane.known[i] = known
                         plane.kmask[i] = kmask
                         dirty_known.append(i)

@@ -41,13 +41,13 @@ import dataclasses
 import functools
 import os
 import time
-import zlib
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bevy_ggrs_tpu.branch_tree import BranchTree, forward_fill
 from bevy_ggrs_tpu.fused import (
     FusedTickExecutor,
     TickInts,
@@ -72,29 +72,6 @@ from bevy_ggrs_tpu.parallel.speculate import (
 from bevy_ggrs_tpu.runner import RollbackRunner, _Step
 from bevy_ggrs_tpu.schedule import Schedule
 from bevy_ggrs_tpu.state import SnapshotRing, WorldState, combine64, ring_load
-
-
-def _forward_fill(
-    last: np.ndarray, known: np.ndarray, known_mask: np.ndarray
-) -> np.ndarray:
-    """The session's actual prediction for a rollout span: per player, start
-    from the anchor-1 input and forward-fill the latest confirmed value into
-    unknown frames (a confirmed change inside the span keeps predicting the
-    NEW value afterwards, exactly like the repeat-last queues). Resuming the
-    anchor-1 input after a pinned prefix would diverge from the session's
-    prediction and force two-change branches no tree enumerates.
-
-    ``last[P, ...]``, ``known[F, P, ...]``, ``known_mask[F, P]`` — payload
-    dims beyond ``[F, P]`` are handled (vector inputs).
-    """
-    extra = known.ndim - 2
-    mask = known_mask.reshape(known_mask.shape + (1,) * extra)
-    base = np.empty_like(known)
-    carry = np.array(last, copy=True)
-    for t in range(known.shape[0]):
-        carry = np.where(mask[t], known[t], carry)
-        base[t] = carry
-    return base
 
 
 @functools.partial(jax.jit, static_argnames=("max_steps",))
@@ -410,8 +387,8 @@ def attest_speculation_safety(
        program is a re-compilation of the burst body, so layer 1 keeps a
        foot in the literal serial executable.)
     3. **Structured-tree tensors**: layer 2 repeated on the output of
-       ``_structured_bits`` with synthetic pinned known-input prefixes —
-       the branch shapes real recoveries actually commit.
+       ``BranchTree.structured_bits`` with synthetic pinned known-input
+       prefixes — the branch shapes real recoveries actually commit.
 
     All layers run the runner's real shapes on the live state. The serial
     side runs with CONFIRMED status while the rollout runs all-PREDICTED —
@@ -572,7 +549,7 @@ def _attestation_structured_bits(
         prefix = rng.randint(0, F)  # 0 = fully unknown player
         mask[:prefix, p] = True
         known[:prefix, p] = draw(known[:prefix, p].shape)
-    return runner._structured_bits(last, known, mask)
+    return runner.tree.structured_bits(runner._input_log, last, known, mask)
 
 
 def _scanned_serial_checksums(
@@ -795,7 +772,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
         self.attestation: Optional[AttestationReport] = None
         self.speculation_enabled = True
         # Default branch enumeration is the structured single-change tree
-        # with known-input pinning (_structured_bits) for EVERY input
+        # with known-input pinning (branch_tree.py) for EVERY input
         # shape — scalar bitmasks and vector payloads alike (round-2
         # verdict weak #4: non-scalar inputs previously fell back to the
         # sticky random sampler, whose measured hit rate was 0/35 where
@@ -877,8 +854,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
         # structured tree keeps its heuristic ranking. ``predictor=None``
         # consults GGRS_PREDICTOR (off by default); a custom sampler
         # bypasses the structured builder entirely, so it forces the
-        # predictor off too. The seed memo carries one anchor's seed from
-        # the signature fold to the tree build inside a single tick.
+        # predictor off too.
         shape = tuple(getattr(input_spec, "shape", ()) or ())
         n_field = int(np.prod(shape, dtype=np.int64)) if shape else 1
         self._predictor = (
@@ -888,7 +864,13 @@ class SpeculativeRollbackRunner(RollbackRunner):
             )
             if sampler is None else None
         )
-        self._seed_memo = None
+        # The structured tree (branch_tree.py): a function of this
+        # configuration and the log it is handed, shared in form with
+        # every slot of a served batch and with the native builder above.
+        self.tree = BranchTree(
+            input_spec, self.num_players, self.num_branches,
+            self.spec_frames, self._branch_values, self._predictor,
+        )
         self.predictor_rank_ms_total = 0.0
         self.predictor_rank_builds = 0
         # Deferred checksum reports: (device_cs_array, [(row, frame)]).
@@ -906,9 +888,9 @@ class SpeculativeRollbackRunner(RollbackRunner):
     def _predictor_seed(self, anchor: int):
         """The predictor's branch-tree seed for ``anchor`` (None when no
         predictor is bound). Always recomputed from the CURRENT input log
-        — corrections may rewrite window frames between ticks — and
-        memoized so the two consumers inside one tick (the dedup
-        signature and :meth:`_structured_bits`) share one rollout."""
+        — corrections may rewrite window frames between ticks — once a
+        tick: the dedup signature folds it and the tree build is handed
+        it (``BranchTree.structured_bits(seed=)``)."""
         if self._predictor is None:
             return None
         t0 = time.perf_counter()
@@ -919,7 +901,6 @@ class SpeculativeRollbackRunner(RollbackRunner):
         self.predictor_rank_ms_total += ms
         self.predictor_rank_builds += 1
         self.metrics.observe("predictor_rank_ms", ms)
-        self._seed_memo = (anchor, seed)
         return seed
 
     def invalidate_speculation(self) -> None:
@@ -932,7 +913,6 @@ class SpeculativeRollbackRunner(RollbackRunner):
         self._result = None
         self._spec_sig = None
         self._ledger_note = None
-        self._seed_memo = None
         self._input_log.clear()
         # Reports computed from the pre-restore world must not surface
         # into the post-restore session.
@@ -1379,7 +1359,7 @@ class SpeculativeRollbackRunner(RollbackRunner):
                 known = known_mask = None
             else:
                 with self.span("known_inputs_query"):
-                    known, known_mask = self._known_inputs(anchor, session)
+                    known, known_mask = self.tree.known_inputs(session, anchor)
             if self._predictor is not None:
                 # Seed folds into the native dedup signature (and, when
                 # not deduplicated, replaces base + candidate ranking).
@@ -1396,14 +1376,14 @@ class SpeculativeRollbackRunner(RollbackRunner):
         if last is None:
             last = self.input_spec.zeros_np(self.num_players)
         with self.span("known_inputs_query"):
-            known, known_mask = self._known_inputs(anchor, session)
+            known, known_mask = self.tree.known_inputs(session, anchor)
         pseed = self._predictor_seed(anchor)
         sig = None
         if dedup and self._sampler is None:
             sig = (
                 anchor, np.asarray(last).tobytes(),
                 known.tobytes(), known_mask.tobytes(),
-                self._history_fingerprint(anchor),
+                self.tree.history_fingerprint(self._input_log, anchor),
                 b"" if pseed is None else pseed.fold_bytes(),
             )
             if (
@@ -1431,12 +1411,13 @@ class SpeculativeRollbackRunner(RollbackRunner):
                 # keep predicting the NEW value, not the anchor-1 input the
                 # sampler repeated. Forward-fill per player on the host
                 # (small arrays), write the row on device.
-                base = _forward_fill(np.asarray(last), known, known_mask)
+                base = forward_fill(np.asarray(last), known, known_mask)
                 bits = bits.at[0].set(jnp.asarray(base))
         else:
             with self.span("structured_bits_build"):
-                bits = self._structured_bits(
-                    np.asarray(last), known, known_mask, anchor
+                bits = self.tree.structured_bits(
+                    self._input_log, np.asarray(last), known, known_mask,
+                    anchor, seed=pseed,
                 )
         return bits, sig
 
@@ -1500,318 +1481,6 @@ class SpeculativeRollbackRunner(RollbackRunner):
             # The carry now holds THIS rollout, not the pending one.
             self._carry = None
         return res
-
-    def _known_inputs(self, anchor: int, session):
-        """(known[F, P, ...], mask[F, P]) of inputs already confirmed inside
-        the rollout span. Prefers the session's bulk ``confirmed_span``
-        (one call — one FFI round trip on the native queue — per player)
-        over the per-(frame, player) ``confirmed_input`` getter loop whose
-        O(F x P) Python/ctypes cost was the measured per-tick dispatch
-        overhead (round-3 verdict weak #5)."""
-        F, P = self.spec_frames, self.num_players
-        zeros = self.input_spec.zeros_np(P)
-        known = np.broadcast_to(zeros, (F,) + zeros.shape).copy()
-        mask = np.zeros((F, P), dtype=bool)
-        span = getattr(session, "confirmed_span", None)
-        if span is not None:
-            for h in range(P):
-                vals, m = span(h, anchor, F)
-                if m.any():
-                    known[m, h] = vals[m]
-                    mask[:, h] = m
-            return known, mask
-        getter = getattr(session, "confirmed_input", None)
-        if getter is None:
-            return known, mask
-        for t in range(F):
-            for h in range(P):
-                got = getter(h, anchor + t)
-                if got is not None:
-                    known[t, h] = np.asarray(got)
-                    mask[t, h] = True
-        return known, mask
-
-    def _candidate_values(self, last: np.ndarray):
-        """History-ranked candidate matrix ``(C[P, n_field, R], valid[P,
-        n_field, R])`` for the structured tree: per player/field, the
-        values most likely to be the misprediction, best-first.
-
-        Ranking (round-4 verdict item 2 — the uniform value sweep spent
-        64 branches covering frame-0 changes of a 32-value universe and
-        hit 10% live on projectiles):
-
-        1. values this player RECENTLY used (from the as-used input log,
-           most recent first) — players alternate among a tiny working set
-           (hold-to-move masks, FIRE toggles), so the actual correction is
-           almost always a recent value;
-        2. single-button press/release TRANSITIONS (integer payloads):
-           ``last ^ bit`` for every bit of the universe, recently-toggling
-           bits first — the canonical one-button misprediction, ranked
-           ahead of multi-bit universe combos even when that exact mask
-           has never been used (a brand-new session's first FIRE press
-           must be coverable);
-        3. the declared universe, in order, as the exhaustive tail.
-
-        ``valid`` masks padding (rows are ragged before padding)."""
-        P = self.num_players
-        shape = self.input_spec.shape
-        n_field = int(np.prod(shape, dtype=np.int64)) if shape else 1
-        dtype = self.input_spec.zeros_np(1).dtype
-        universe = np.asarray(self._branch_values, dtype=dtype).reshape(-1)
-        lastf = np.asarray(last).reshape(P, n_field)
-        frames = sorted(self._input_log)[-32:]
-        hist = (
-            np.stack([
-                np.asarray(self._input_log[f]).reshape(P, n_field)
-                for f in frames
-            ])
-            if frames else np.zeros((0, P, n_field), dtype)
-        )
-        integer = np.issubdtype(dtype, np.integer)
-        rows = []
-        max_r = 0
-        for h in range(P):
-            for k in range(n_field):
-                seq = hist[::-1, h, k]  # newest first
-                if seq.size:
-                    _, first = np.unique(seq, return_index=True)
-                    recent = list(seq[np.sort(first)])
-                else:
-                    recent = []
-                toggles = []
-                if integer:
-                    changed = (
-                        int(np.bitwise_or.reduce(
-                            np.bitwise_xor(seq[1:], seq[:-1])
-                        ))
-                        if seq.size >= 2 else 0
-                    )
-                    top = int(max((int(v) for v in universe), default=0))
-                    limit = max(changed, top)
-                    all_bits = []
-                    bit = 1
-                    while bit <= limit:
-                        all_bits.append(bit)
-                        bit <<= 1
-                    ordered = (
-                        [b for b in all_bits if changed & b]
-                        + [b for b in all_bits if not (changed & b)]
-                    )
-                    toggles = [
-                        dtype.type(int(lastf[h, k]) ^ b) for b in ordered
-                    ]
-                # Candidates are CLAMPED to the declared universe: the
-                # warmup attestation samples exactly `_branch_values`, so
-                # a tree must never enumerate a value class attestation
-                # never replayed through the serial executable. (Received
-                # out-of-contract values still appear in the branch-0
-                # base — unavoidable for any prediction policy — but the
-                # tree's own perturbations stay in-contract.)
-                allowed = {
-                    v.item() if hasattr(v, "item") else v for v in universe
-                }
-                row, seen = [], set()
-                for v in [*recent, *toggles, *universe]:
-                    key = v.item() if hasattr(v, "item") else v
-                    if key not in seen and key in allowed:
-                        seen.add(key)
-                        row.append(v)
-                rows.append(row)
-                max_r = max(max_r, len(row))
-        C = np.zeros((P, n_field, max_r), dtype)
-        valid = np.zeros((P, n_field, max_r), bool)
-        for i, row in enumerate(rows):
-            h, k = divmod(i, n_field)
-            C[h, k, : len(row)] = row
-            valid[h, k, : len(row)] = True
-        return C, valid
-
-    def _history_fingerprint(self, anchor: int) -> tuple:
-        """Digest of everything the structured branch tree reads from the
-        input log: the max logged frame (the recency ranking in
-        :meth:`_candidate_values` keys on the latest 32 logged frames) and
-        a hash of the contiguous ≤48-frame window ending at ``anchor - 1``
-        (the periodic-extrapolation input). The dedup signatures fold this
-        in so a SHIFTED history window — same (anchor, last, known) but new
-        log contents — can't pin a stale branch tree."""
-        L = anchor - 1
-        start = L
-        while start - 1 in self._input_log and L - (start - 1) < 48:
-            start -= 1
-        digest = 0
-        for f in range(start, L + 1):
-            got = self._input_log.get(f)
-            if got is not None:
-                digest = zlib.crc32(
-                    np.ascontiguousarray(got).tobytes(), digest
-                )
-        return (max(self._input_log, default=-1), start, digest)
-
-    def _extrapolate_base(
-        self, base: np.ndarray, known: np.ndarray, known_mask: np.ndarray,
-        anchor: int,
-    ) -> Optional[np.ndarray]:
-        """Per-(player, field) PERIODIC extrapolation of the as-used input
-        history — the loop-predictor analog for inputs. Rhythmic play
-        (autorepeat fire, strafe tapping, the benches' key cycles) makes a
-        player's stream exactly periodic; repeat-last then mispredicts at
-        every period boundary, and with several remote players a rollback
-        span contains boundaries from MORE than one of them — a shape no
-        single-change tree covers (the round-4 projectiles 10% live hit
-        rate). Detection: smallest p in 2..16 with ``seq[p:] == seq[:-p]``
-        over a contiguous ≤48-frame window ending at the anchor; the
-        prediction for future frame g is the logged value at ``g - p``
-        (phase-aligned by construction). Returns the extrapolated base
-        with known slots re-pinned, or None when no player/field has a
-        (non-constant) period."""
-        F, P = self.spec_frames, self.num_players
-        shape = self.input_spec.shape
-        n_field = int(np.prod(shape, dtype=np.int64)) if shape else 1
-        L = anchor - 1  # last frozen history frame
-        start = L
-        while start - 1 in self._input_log and L - (start - 1) < 48:
-            start -= 1
-        if L not in self._input_log or L - start + 1 < 8:
-            return None
-        frames = range(start, L + 1)
-        hist = np.stack([
-            np.asarray(self._input_log[f]).reshape(P, n_field)
-            for f in frames
-        ])  # [W, P, K]
-        predf = base.reshape(F, P, n_field).copy()
-        universe = np.asarray(self._branch_values, dtype=hist.dtype).reshape(-1)
-        found = False
-        for h in range(P):
-            for k in range(n_field):
-                seq = hist[:, h, k]
-                # Extrapolation REPLAYS history values as predictions, so a
-                # history containing out-of-contract values (outside the
-                # declared `_branch_values` universe the warmup attestation
-                # sampled) would smuggle them into branch bases. Skip the
-                # (player, field): repeat-last keeps the unavoidable
-                # branch-0 exposure and nothing more.
-                if universe.size and not np.isin(seq, universe).all():
-                    continue
-                n = seq.shape[0]
-                period = 0
-                for p in range(2, min(16, n // 2) + 1):
-                    if np.array_equal(seq[p:], seq[:-p]):
-                        period = p
-                        break
-                if not period or (seq[-period:] == seq[-1]).all():
-                    continue  # aperiodic, or constant (= repeat-last)
-                found = True
-                for t in range(F):
-                    off = (anchor + t) - L
-                    g0 = (anchor + t) - period * (-(-off // period))
-                    predf[t, h, k] = hist[g0 - start, h, k]
-        if not found:
-            return None
-        knownf = np.asarray(known).reshape(F, P, n_field)
-        predf = np.where(known_mask[:, :, None], knownf, predf)
-        return predf.reshape(base.shape)
-
-    def _structured_bits(
-        self, last: np.ndarray, known: np.ndarray, known_mask: np.ndarray,
-        anchor: Optional[int] = None,
-    ) -> np.ndarray:
-        """The default branch tree: branch 0 is the session's own
-        prediction (known inputs pinned, unknowns repeat-last); every
-        further branch changes ONE player's unknown suffix — for vector
-        payloads, one FIELD of it — to one candidate value starting at one
-        frame, the shape of a real misprediction (one player pressed or
-        released one control at one frame and held). Fields beyond the
-        changed one keep the prediction, matching how independent controls
-        (stick axis, button) mispredict one at a time.
-
-        Enumeration order is (candidate-rank, frame, player, field)-major
-        over the history-ranked candidate matrix (:meth:`_candidate_
-        values`): every player/frame slot gets its BEST candidate before
-        any slot gets its second — so a B-branch tree covers the likely
-        transition (e.g. projectiles' FIRE toggle) at EVERY frame of the
-        span instead of exhausting the budget on improbable values at
-        frame 0 (round-4 verdict item 2; the old (frame, value)-major
-        sweep hit 10% live on projectiles' 32-value universe)."""
-        F, P, B = self.spec_frames, self.num_players, self.num_branches
-        shape = self.input_spec.shape  # per-player payload dims, () scalar
-        base = _forward_fill(last, known, known_mask)  # [F, P, *shape]
-        if B <= 1 or not self._branch_values:
-            return np.broadcast_to(base, (B, F, P) + shape).copy()
-        if anchor is None:
-            anchor = max(self._input_log, default=0) + 1
-        # Detected input periodicity replaces repeat-last as the BASE the
-        # tree perturbs: branch 1 is the extrapolated pattern itself (all
-        # players continue their rhythms — covers multi-player period
-        # boundaries in one branch), and the single-change branches model
-        # one player DEVIATING from the pattern. Branch 0 stays the
-        # session's literal forward-fill prediction (the engine must
-        # strictly contain the reference's repeat-last policy).
-        # A bound learned predictor (predict/) replaces BOTH the
-        # periodic extrapolator (its autoregressive trajectory becomes
-        # the effective base) and the recency/toggle candidate ranking
-        # (its first-step logits order the universe). Accessed via
-        # getattr so the borrowed-method hosts (_ReplayBuilder,
-        # _SlotSpecShim) opt in by simply setting `_predictor`.
-        # Branch 0 below stays the literal forward-fill prediction
-        # regardless — recovery is never worse than repeat-last.
-        seeded = None
-        predictor = getattr(self, "_predictor", None)
-        if predictor is not None:
-            memo = getattr(self, "_seed_memo", None)
-            if memo is not None and memo[0] == anchor:
-                seeded = memo[1]  # same tick's signature-fold seed
-            else:
-                seeded = predictor.seed(self._input_log, anchor, F, P)
-        if seeded is not None:
-            knownf = np.asarray(known).reshape(F, P, -1)
-            trajf = seeded.traj.reshape(F, P, -1).astype(
-                base.dtype, copy=True
-            )
-            trajf = np.where(known_mask[:, :, None], knownf, trajf)
-            pred = trajf.reshape(base.shape)
-            if np.array_equal(pred, base):
-                pred = None
-        else:
-            pred = self._extrapolate_base(base, known, known_mask, anchor)
-        eff_base = base if pred is None else pred
-        out = np.broadcast_to(eff_base, (B, F, P) + shape).copy()
-        out[0] = base
-        start_b = 1
-        if pred is not None and not np.array_equal(pred, base):
-            start_b = 2  # out[1] is already the unperturbed extrapolation
-        # Fully vectorized selection (the Python t/h/field/value loop was
-        # O(B·F) per tick — milliseconds at the 1024-branch stress shape,
-        # round-3 verdict weak #5). Eligibility E[r, t, h, field]: the
-        # slot is not pinned, the rank is not padding, and the candidate
-        # differs from the base prediction; flattening E in C order gives
-        # the rank-major enumeration, and the first B-start_b eligible
-        # entries become branches start_b..B-1.
-        if seeded is not None:
-            C, cvalid = seeded.cand, seeded.valid  # [P, K, R]
-        else:
-            C, cvalid = self._candidate_values(last)  # [P, K, R]
-        n_field = C.shape[1]
-        basef = eff_base.reshape(F, P, n_field)
-        free = ~known_mask  # [F, P]
-        cv = C.transpose(2, 0, 1)  # [R, P, K]
-        elig = (
-            free[None, :, :, None]
-            & cvalid.transpose(2, 0, 1)[:, None, :, :]
-            & (cv[:, None, :, :] != basef[None, :, :, :])
-        )  # [R, F, P, K]
-        idx = np.flatnonzero(elig.reshape(-1))[: B - start_b]
-        if idx.size == 0:
-            return out
-        r_i, t_i, h_i, k_i = np.unravel_index(idx, elig.shape)
-        # Each selected branch writes its value over the change player's
-        # unpinned suffix (frames >= t that are not known for that player).
-        suffix = (
-            (np.arange(F)[None, :] >= t_i[:, None]) & free[:, h_i].T
-        )  # [n_sel, F]
-        bb, ff = np.nonzero(suffix)
-        outf = out.reshape(B, F, P, n_field)
-        outf[start_b + bb, ff, h_i[bb], k_i[bb]] = C[h_i[bb], k_i[bb], r_i[bb]]
-        return out
 
     # ------------------------------------------------------------------
 

@@ -65,7 +65,6 @@ class Size:
     # kernels
     checksum_branches: int
     checksum_boids: int
-    rows_n: int
     spec_boids: int
     spec_branches: int
     spec_frames: int
@@ -88,7 +87,7 @@ FULL = Size(
     pair_branches=256, pair_frames=600,
     serve_capacity=256, serve_groups=4, serve_frames=300, serve_churn=16,
     checksum_branches=256, checksum_boids=1024,
-    rows_n=1024, spec_boids=1024, spec_branches=128, spec_frames=8,
+    spec_boids=1024, spec_branches=128, spec_frames=8,
     tri_ns=(4096, 16384), tri_block=1024, grid_n=32768,
     sync_boids=1024, sync_distance=7, sync_frames=120,
     # particles' position / velocity and ttl / rollback_id under 64 slots
@@ -101,7 +100,7 @@ REHEARSAL = Size(
     pair_branches=8, pair_frames=60,
     serve_capacity=8, serve_groups=2, serve_frames=24, serve_churn=2,
     checksum_branches=4, checksum_boids=64,
-    rows_n=128, spec_boids=64, spec_branches=4, spec_frames=2,
+    spec_boids=64, spec_branches=4, spec_frames=2,
     tri_ns=(256,), tri_block=128, grid_n=512,
     sync_boids=64, sync_distance=3, sync_frames=12,
     ring_lanes=4, ring_leaves=((64, "float32"), (64, "int32")),
@@ -693,7 +692,6 @@ def _kernel_checks(size: Size, ident: dict):
     from bevy_ggrs_tpu.ops.checksum import checksum_pallas
     from bevy_ggrs_tpu.ops.pairwise import (
         pairwise_force_rows_mxu2,
-        pairwise_force_rows_pallas,
         pairwise_force_square_mxu_tri,
     )
     from bevy_ggrs_tpu.state import checksum
@@ -743,15 +741,6 @@ def _kernel_checks(size: Size, ident: dict):
         require(np.array_equal(got, np.asarray(jax.jit(checksum)(state))),
                 "checksum differs from XLA")
         return {**out, "boids": size.checksum_boids, "bitwise": True}
-
-    def rows_vpu():
-        pos, vel, act = _flock(size.rows_n)
-        fn = lambda p, v, a: pairwise_force_rows_pallas(  # noqa: E731
-            p, v, p, v, a, a, **params)
-        out = compiled(fn, pos, vel, act)
-        want = _dense_reference(pos, vel, act)
-        out.update(close(fn(pos, vel, act), want, 2e-6, "rows_pallas"))
-        return {**out, "n": size.rows_n}
 
     def rows_mxu():
         pos, vel, act = _flock(size.spec_boids)
@@ -930,7 +919,6 @@ def _kernel_checks(size: Size, ident: dict):
         ("ring_write_in_place", ring_write),
         ("checksum_box_game_vmapped", checksum_box_game),
         ("checksum_boids", checksum_boids),
-        ("pairwise_rows_vpu", rows_vpu),
         ("pairwise_rows_mxu2", rows_mxu),
         ("spec_executor_mxu", spec_mxu),
         *[(f"pairwise_tri_{n}", tri(n)) for n in size.tri_ns],

@@ -35,12 +35,9 @@ def main() -> int:
     parser.add_argument("--entities", type=int, default=256)
     parser.add_argument("--num-players", type=int, default=2)
     parser.add_argument("--check-distance", type=int, default=4)
-    parser.add_argument("--pallas", action="store_true",
-                        help="boids: use the VPU Pallas force kernel")
-    parser.add_argument("--kernel", choices=["xla", "pallas", "mxu"],
-                        default=None,
-                        help="boids force kernel (mxu = matmul reductions, "
-                             "fastest single-chip; overrides --pallas)")
+    parser.add_argument("--kernel", choices=["xla", "mxu"], default="xla",
+                        help="boids force kernel (mxu = the Pallas kernel, "
+                             "matmul reductions: what the chip runs)")
     add_common_args(parser)
     args = parser.parse_args()
     force_platform(args.platform)
@@ -52,8 +49,7 @@ def main() -> int:
 
     if args.model == "boids":
         model = boids
-        schedule = boids.make_schedule(use_pallas=args.pallas,
-                                       kernel=args.kernel)
+        schedule = boids.make_schedule(kernel=args.kernel)
         world = boids.make_world(args.entities, args.num_players)
     elif args.model == "projectiles":
         model = projectiles

@@ -277,7 +277,7 @@ class TestAttestationCache:
         fingerprint must still split them by what the closures capture."""
         calls = []
         self._fresh(monkeypatch, calls)
-        for kernel in ("xla", "pallas"):
+        for kernel in ("xla", "mxu"):
             runner = SpeculativeRollbackRunner(
                 boids.make_schedule(kernel=kernel),
                 boids.make_world(32, 2).commit(),

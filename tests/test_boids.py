@@ -165,7 +165,7 @@ class TestShardMapKernel:
             cs.append(combine64(checksum(runner.state)))
         return cs
 
-    @pytest.mark.parametrize("kernel", ["mxu", "pallas"])
+    @pytest.mark.parametrize("kernel", ["mxu"])
     def test_sharded_kernel_bitwise_vs_unsharded(self, kernel):
         if len(jax.devices()) < 2:
             pytest.skip("needs a multi-device mesh")

@@ -20,7 +20,7 @@ MARGIN = 1e-5
 # capped at CLOSE_W = 200 (2e-8 * 8 * 200 * the weight 0.08 = 2.6e-6): a few
 # 1e-6 whatever the flock, so it gets its own tolerance, two decades under
 # what a bfloat16 state gives (3.9e-3 from positions in 1..2).
-TOLERANCE = {"xla": 2e-6, "pallas": 2e-6, "mxu": 2e-5}
+TOLERANCE = {"xla": 2e-6, "mxu": 2e-5}
 
 
 def _state(n, seed):
@@ -67,7 +67,7 @@ def test_reference_spawn_is_the_programs_world():
     assert list(handles[:3]) == [0, 1, -1]
 
 
-@pytest.mark.parametrize("kernel", ["xla", "pallas", "mxu"])
+@pytest.mark.parametrize("kernel", ["xla", "mxu"])
 @pytest.mark.parametrize("n", [64, 256])
 def test_one_step_of_each_dense_path_against_the_reference(kernel, n):
     for seed in (11, 12):
@@ -97,7 +97,7 @@ PADDED_PAIR_ROWS = {"first_strip": [(17, 18)], "second_strip": [(150, 151)],
                     "a_pair_a_strip": [(17, 18), (150, 151)]}
 
 
-@pytest.mark.parametrize("kernel", ["xla", "pallas", "mxu"])
+@pytest.mark.parametrize("kernel", ["xla", "mxu"])
 @pytest.mark.parametrize("distance", [3e-3, 1e-4])
 @pytest.mark.parametrize("where", list(PAIR_ROWS))
 def test_a_close_pair_far_from_the_origin_keeps_float32(kernel, distance,
@@ -119,7 +119,7 @@ def test_a_close_pair_far_from_the_origin_keeps_float32(kernel, distance,
     assert np.abs(got_v - want_v[0])[decided].max() <= TOLERANCE[kernel]
 
 
-@pytest.mark.parametrize("kernel", ["pallas", "mxu"])
+@pytest.mark.parametrize("kernel", ["mxu"])
 @pytest.mark.parametrize("where", list(PADDED_PAIR_ROWS))
 def test_a_padded_column_is_nobodys_close_neighbour(kernel, where):
     """200 boids pad to 256 columns, which sit at the origin with activity
@@ -185,10 +185,10 @@ def test_torus_gap_wraps_at_the_edge():
     assert ref.torus_gap(a, a * 0).max() == pytest.approx(7.99999, rel=1e-6)
 
 
-@pytest.mark.parametrize("kernel", ["pallas", "mxu"])
+@pytest.mark.parametrize("kernel", ["mxu"])
 def test_unsharded_kernel_under_the_branch_vmap_is_the_serial_burst(kernel):
     """What warm-up attests on the chip, at a small size on the CPU: the
-    ``[B]``-vmapped rollout through the Pallas kernels is bitwise the
+    ``[B]``-vmapped rollout through the Pallas kernel is bitwise the
     serial burst, every branch, structured tree included."""
     from bevy_ggrs_tpu.models import boids
     from bevy_ggrs_tpu.spec_runner import (

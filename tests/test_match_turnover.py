@@ -198,7 +198,7 @@ def test_the_supervisor_rearms_a_dead_peer_and_the_retirement_ends_it(dropped):
     assert server.groups[handle.group].slots[handle.slot].frame == frame + 2
     server.retire_match(handle)
     slot = server.groups[handle.group].slots[handle.slot]
-    assert not slot.active and slot.native is None and slot.shim is None
+    assert not slot.active and slot.native is None
     assert handle not in server._matches and tuple(handle) not in server._at
     assert server.slots_free == 3 and server.matches_retired_total == 1
     # Nobody polls the session again: the re-armed endpoint's handshake

@@ -13,35 +13,16 @@ malformed histories, and a full loopback session, mirroring the
 import numpy as np
 import pytest
 
+from bevy_ggrs_tpu.branch_tree import BranchTree
 from bevy_ggrs_tpu.native import core as ncore
 from bevy_ggrs_tpu.native import spec as native_spec
 from bevy_ggrs_tpu.parallel.speculate import _match_branch_numpy, match_branch
 from bevy_ggrs_tpu.schedule import InputSpec
-from bevy_ggrs_tpu.spec_runner import SpeculativeRollbackRunner, _forward_fill
+from bevy_ggrs_tpu.spec_runner import SpeculativeRollbackRunner
 
 native = pytest.mark.skipif(
     not ncore.available(), reason="native session core did not build"
 )
-
-
-class PyOracle:
-    """The Python builder internals, unbound from the runner: exactly the
-    methods the native core replaces, driven over a bare attribute bag so
-    every trial constructs in microseconds."""
-
-    _candidate_values = SpeculativeRollbackRunner._candidate_values
-    _extrapolate_base = SpeculativeRollbackRunner._extrapolate_base
-    _structured_bits = SpeculativeRollbackRunner._structured_bits
-    _history_fingerprint = SpeculativeRollbackRunner._history_fingerprint
-    _known_inputs = SpeculativeRollbackRunner._known_inputs
-
-    def __init__(self, input_spec, players, branches, frames, values):
-        self.input_spec = input_spec
-        self.num_players = players
-        self.num_branches = branches
-        self.spec_frames = frames
-        self._branch_values = values
-        self._input_log = {}
 
 
 _DTYPES = [np.uint8, np.int8, np.uint16, np.int16, np.int32, np.int64]
@@ -72,16 +53,18 @@ def _rand_case(rng):
         ))
     ) if n_uni else ()
     spec = InputSpec(shape=shape, dtype=dtype)
-    oracle = PyOracle(spec, players, branches, frames, values)
+    # The Python side of every parity check: the tree the native core
+    # replaces, over a plain dict log.
+    tree = BranchTree(spec, players, branches, frames, values)
     nat = native_spec.make_spec_builder(spec, players, branches, frames,
                                         values)
     assert nat is not None
-    return spec, oracle, nat
+    return spec, tree, {}, nat
 
 
-def _fill_log(rng, oracle, nat, lo, hi, gap_p=0.1, periodic=False):
-    spec = oracle.input_spec
-    P = oracle.num_players
+def _fill_log(rng, tree, log, nat, lo, hi, gap_p=0.1, periodic=False):
+    spec = tree.input_spec
+    P = tree.num_players
     base = [
         _rand_payload(rng, spec.zeros_np(P).dtype, (P,) + spec.shape)
         for _ in range(max(1, rng.randint(1, 5)))
@@ -95,13 +78,13 @@ def _fill_log(rng, oracle, nat, lo, hi, gap_p=0.1, periodic=False):
                                (P,) + spec.shape,
                                small=bool(rng.rand() < 0.9))
         )
-        oracle._input_log[f] = bits
+        log[f] = bits
         nat.log_set(f, bits)
 
 
-def _rand_known(rng, oracle):
-    F, P = oracle.spec_frames, oracle.num_players
-    zeros = oracle.input_spec.zeros_np(P)
+def _rand_known(rng, tree):
+    F, P = tree.spec_frames, tree.num_players
+    zeros = tree.input_spec.zeros_np(P)
     known = np.broadcast_to(zeros, (F,) + zeros.shape).copy()
     mask = rng.rand(F, P) < rng.choice([0.0, 0.2, 0.6])
     vals = _rand_payload(rng, zeros.dtype, (F,) + zeros.shape)
@@ -109,31 +92,29 @@ def _rand_known(rng, oracle):
     return known, mask
 
 
-def _py_last(oracle, anchor):
-    last = oracle._input_log.get(anchor - 1)
+def _py_bits(tree, log, known, mask, anchor):
+    last = log.get(anchor - 1)
     if last is None:
-        last = oracle.input_spec.zeros_np(oracle.num_players)
-    return np.asarray(last)
+        last = tree.input_spec.zeros_np(tree.num_players)
+    return tree.structured_bits(log, np.asarray(last), known, mask, anchor)
 
 
 @native
 def test_build_parity_randomized():
     rng = np.random.RandomState(11)
     for trial in range(50):
-        spec, oracle, nat = _rand_case(rng)
+        spec, tree, log, nat = _rand_case(rng)
         hi = int(rng.randint(1, 60))
-        _fill_log(rng, oracle, nat, max(0, hi - 50), hi,
+        _fill_log(rng, tree, log, nat, max(0, hi - 50), hi,
                   periodic=bool(rng.rand() < 0.3))
         # Anchors inside, at, and beyond the logged range.
         anchor = int(rng.randint(0, hi + 10))
-        known, mask = _rand_known(rng, oracle)
+        known, mask = _rand_known(rng, tree)
         got, _sig = nat.build(anchor, None, known, mask, False, None)
-        want = oracle._structured_bits(
-            _py_last(oracle, anchor), known, mask, anchor
-        )
+        want = _py_bits(tree, log, known, mask, anchor)
         assert got.dtype == want.dtype and got.shape == want.shape, trial
         assert np.array_equal(got, want), (
-            trial, spec, oracle.num_players, anchor
+            trial, spec, tree.num_players, anchor
         )
 
 
@@ -143,26 +124,24 @@ def test_build_parity_after_rollback_corrections():
     the ranking/extrapolation must track exactly."""
     rng = np.random.RandomState(12)
     for trial in range(20):
-        spec, oracle, nat = _rand_case(rng)
-        _fill_log(rng, oracle, nat, 0, 40, gap_p=0.0, periodic=True)
+        spec, tree, log, nat = _rand_case(rng)
+        _fill_log(rng, tree, log, nat, 0, 40, gap_p=0.0, periodic=True)
         for _ in range(rng.randint(1, 8)):  # corrections + evictions
             f = int(rng.randint(0, 40))
-            if rng.rand() < 0.5 and f in oracle._input_log:
-                del oracle._input_log[f]
+            if rng.rand() < 0.5 and f in log:
+                del log[f]
                 nat.log_del(f)
             else:
                 bits = _rand_payload(
-                    rng, spec.zeros_np(oracle.num_players).dtype,
-                    (oracle.num_players,) + spec.shape,
+                    rng, spec.zeros_np(tree.num_players).dtype,
+                    (tree.num_players,) + spec.shape,
                 )
-                oracle._input_log[f] = bits
+                log[f] = bits
                 nat.log_set(f, bits)
         anchor = int(rng.randint(30, 45))
-        known, mask = _rand_known(rng, oracle)
+        known, mask = _rand_known(rng, tree)
         got, _ = nat.build(anchor, None, known, mask, False, None)
-        want = oracle._structured_bits(
-            _py_last(oracle, anchor), known, mask, anchor
-        )
+        want = _py_bits(tree, log, known, mask, anchor)
         assert np.array_equal(got, want), trial
 
 
@@ -178,20 +157,19 @@ def test_build_malformed_history_fuzz():
             (list(range(10)), 3),  # anchor INSIDE the logged range
         ]:
             for values in [(), tuple(range(16))]:
-                oracle = PyOracle(spec, players, branches, frames, values)
+                tree = BranchTree(spec, players, branches, frames, values)
+                log = {}
                 nat = native_spec.make_spec_builder(
                     spec, players, branches, frames, values
                 )
                 for f in log_frames:
                     bits = _rand_payload(rng, np.dtype(np.uint8),
                                          (players,))
-                    oracle._input_log[f] = bits
+                    log[f] = bits
                     nat.log_set(f, bits)
-                known, mask = _rand_known(rng, oracle)
+                known, mask = _rand_known(rng, tree)
                 got, _ = nat.build(anchor, None, known, mask, False, None)
-                want = oracle._structured_bits(
-                    _py_last(oracle, anchor), known, mask, anchor
-                )
+                want = _py_bits(tree, log, known, mask, anchor)
                 assert np.array_equal(got, want), (
                     players, branches, log_frames, anchor, values
                 )
@@ -213,10 +191,10 @@ def test_dedup_signature_equivalence_classes():
     Python tuple: identical state skips, any input to the build changing
     (log contents, anchor, known set) rebuilds."""
     rng = np.random.RandomState(14)
-    spec, oracle, nat = _rand_case(rng)
-    _fill_log(rng, oracle, nat, 0, 30, gap_p=0.0)
+    spec, tree, log, nat = _rand_case(rng)
+    _fill_log(rng, tree, log, nat, 0, 30, gap_p=0.0)
     anchor = 30
-    known, mask = _rand_known(rng, oracle)
+    known, mask = _rand_known(rng, tree)
     bits, sig = nat.build(anchor, None, known, mask, False, None)
     assert bits is not None
     # Same state, allow_skip: the native dedup-skip fires.
@@ -226,7 +204,7 @@ def test_dedup_signature_equivalence_classes():
     forced, sig3 = nat.build(anchor, None, known, mask, False, sig)
     assert forced is not None and sig3 == sig
     # A log mutation inside the fingerprint window changes the signature.
-    bump = oracle._input_log[29] ^ np.ones_like(oracle._input_log[29])
+    bump = log[29] ^ np.ones_like(log[29])
     nat.log_set(29, bump)
     rebuilt, sig4 = nat.build(anchor, None, known, mask, True, sig)
     assert rebuilt is not None and sig4 != sig
@@ -241,11 +219,11 @@ def test_match_parity_randomized():
     match_branch, including the log-gap -> no-match contract."""
     rng = np.random.RandomState(15)
     for trial in range(40):
-        spec, oracle, nat = _rand_case(rng)
-        F, P = oracle.spec_frames, oracle.num_players
-        _fill_log(rng, oracle, nat, 0, 30, gap_p=0.15)
+        spec, tree, log, nat = _rand_case(rng)
+        F, P = tree.spec_frames, tree.num_players
+        _fill_log(rng, tree, log, nat, 0, 30, gap_p=0.15)
         anchor = int(rng.randint(0, 25))
-        known, mask = _rand_known(rng, oracle)
+        known, mask = _rand_known(rng, tree)
         bits, _ = nat.build(anchor, None, known, mask, False, None)
         pre = int(rng.randint(0, F))
         load_frame = anchor + pre
@@ -261,7 +239,7 @@ def test_match_parity_randomized():
         got = nat.match(np.asarray(bits), anchor, load_frame, steps, F)
         needed, gap = [], False
         for f in range(anchor, load_frame):
-            entry = oracle._input_log.get(f)
+            entry = log.get(f)
             if entry is None:
                 gap = True
                 break
@@ -307,20 +285,17 @@ def test_mirrored_log_tracks_dict_semantics():
     build-identical to a Python oracle over a plain dict."""
     rng = np.random.RandomState(17)
     spec = InputSpec()
-    oracle = PyOracle(spec, 2, 8, 8, tuple(range(16)))
+    tree = BranchTree(spec, 2, 8, 8, tuple(range(16)))
     nat = native_spec.make_spec_builder(spec, 2, 8, 8, tuple(range(16)))
     log = native_spec.MirroredLog(nat)
     shadow = {}
 
     def check(step):
         assert dict(log) == shadow, step
-        known, mask = _rand_known(rng, oracle)
-        oracle._input_log = dict(shadow)
+        known, mask = _rand_known(rng, tree)
         anchor = max(shadow, default=0) + 1
         got, _ = nat.build(anchor, None, known, mask, False, None)
-        want = oracle._structured_bits(
-            _py_last(oracle, anchor), known, mask, anchor
-        )
+        want = _py_bits(tree, dict(shadow), known, mask, anchor)
         assert np.array_equal(got, want), step
 
     for step in range(60):
@@ -370,7 +345,7 @@ def test_qset_in_process_parity():
         spec = InputSpec(shape=shape, dtype=dtype)
         P, B, F = 2, 16, 8
         values = tuple(range(8))
-        oracle = PyOracle(spec, P, B, F, values)
+        tree, log = BranchTree(spec, P, B, F, values), {}
         nat = native_spec.make_spec_builder(spec, P, B, F, values)
         qset = ncore.NativeQueueSet(np.zeros(shape, dtype), [0] * P)
         session = FakeSession(qset)
@@ -380,16 +355,14 @@ def test_qset_in_process_parity():
                     qset.queues[h].add_local_input(
                         f, _rand_payload(rng, np.dtype(dtype), shape)
                     )
-        _fill_log(rng, oracle, nat, 0, 10, gap_p=0.0)
+        _fill_log(rng, tree, log, nat, 0, 10, gap_p=0.0)
         for anchor in (0, 5, 9, 11, 14):
             qs_ptr = nat.qset_ptr(session)
             assert qs_ptr is not None
             got, sig_q = nat.build(anchor, qs_ptr, None, None, False, None)
-            known, mask = oracle._known_inputs(anchor, session)
+            known, mask = tree.known_inputs(session, anchor)
             host, sig_h = nat.build(anchor, None, known, mask, False, None)
-            want = oracle._structured_bits(
-                _py_last(oracle, anchor), known, mask, anchor
-            )
+            want = _py_bits(tree, log, known, mask, anchor)
             assert sig_q == sig_h, anchor
             assert np.array_equal(got, host), anchor
             assert np.array_equal(got, want), anchor

@@ -13,7 +13,6 @@ import pytest
 from bevy_ggrs_tpu import state as state_lib
 from bevy_ggrs_tpu.models import boids, box_game
 from bevy_ggrs_tpu.ops.checksum import checksum_pallas, install_pallas_checksum
-from bevy_ggrs_tpu.ops.pairwise import pairwise_force_rows_pallas
 from bevy_ggrs_tpu.schedule import make_inputs
 from bevy_ggrs_tpu.state import (
     TypeRegistry,
@@ -105,67 +104,6 @@ _KPARAMS = dict(
     w_alignment=float(boids.W_ALIGNMENT),
     w_cohesion=float(boids.W_COHESION),
 )
-
-
-@pytest.mark.parametrize("n", [64, 200, 300])
-def test_pairwise_kernel_matches_xla(n):
-    pos, vel, active = _random_flock(n, seed=n, inactive_every=7)
-    got = pairwise_force_rows_pallas(
-        pos, vel, pos, vel, active, active, col_block=128, **_KPARAMS
-    )
-    want = boids.pairwise_force_rows(pos, vel, pos, vel, active, active)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
-    # Inactive rows produce exactly zero force.
-    assert not np.any(np.asarray(got)[::7])
-
-
-def test_pairwise_kernel_row_subset():
-    # Sharded use: this shard owns rows 32..64 of a 128-boid flock.
-    pos, vel, active = _random_flock(128, seed=5)
-    got = pairwise_force_rows_pallas(
-        pos[32:64], vel[32:64], pos, vel, active[32:64], active,
-        col_block=128, **_KPARAMS,
-    )
-    want = boids.pairwise_force_rows(
-        pos[32:64], vel[32:64], pos, vel, active[32:64], active
-    )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
-
-
-def test_pairwise_kernel_vmap():
-    batches = [_random_flock(96, seed=s) for s in range(3)]
-    pos = jnp.stack([b[0] for b in batches])
-    vel = jnp.stack([b[1] for b in batches])
-    act = jnp.stack([b[2] for b in batches])
-
-    def one(p, v, a):
-        return pairwise_force_rows_pallas(
-            p, v, p, v, a, a, col_block=128, **_KPARAMS
-        )
-
-    got = jax.vmap(one)(pos, vel, act)
-    for i in range(3):
-        want = boids.pairwise_force_rows(
-            pos[i], vel[i], pos[i], vel[i], act[i], act[i]
-        )
-        np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want), atol=2e-6)
-
-
-def test_flock_pallas_step_close_and_deterministic():
-    state = boids.make_world(200, 2).commit()
-    inputs = make_inputs(jnp.asarray([boids.INPUT_RIGHT, 0], dtype=jnp.uint8))
-    xla_step = boids.make_schedule(use_pallas=False)
-    pallas_step = boids.make_schedule(use_pallas=True)
-    a = xla_step(state, inputs)
-    b = pallas_step(state, inputs)
-    np.testing.assert_allclose(
-        np.asarray(a.components["position"]),
-        np.asarray(b.components["position"]),
-        atol=1e-5,
-    )
-    # Bitwise self-determinism (what SyncTest checks within one path).
-    b2 = pallas_step(state, inputs)
-    assert combine64(checksum(b)) == combine64(checksum(b2))
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,7 @@ Four layers, each with its own witness:
   identical to an unconfigured runner.
 """
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -26,6 +27,7 @@ import sys
 import numpy as np
 import pytest
 
+from bevy_ggrs_tpu.branch_tree import BranchTree
 from bevy_ggrs_tpu.models import box_game
 from bevy_ggrs_tpu.native import core as ncore
 from bevy_ggrs_tpu.native import spec as native_spec
@@ -227,24 +229,6 @@ class TestHandshake:
 # --------------------------------------------------------------------------
 
 
-class _Bag:
-    """The singleton runner's tree builders, unbound (the same borrow
-    the batched serve shim uses)."""
-
-    _candidate_values = SpeculativeRollbackRunner._candidate_values
-    _extrapolate_base = SpeculativeRollbackRunner._extrapolate_base
-    _structured_bits = SpeculativeRollbackRunner._structured_bits
-    _history_fingerprint = SpeculativeRollbackRunner._history_fingerprint
-
-    def __init__(self, spec, players, branches, frames, values):
-        self.input_spec = spec
-        self.num_players = players
-        self.num_branches = branches
-        self.spec_frames = frames
-        self._branch_values = values
-        self._input_log = {}
-
-
 @pytest.mark.skipif(not ncore.available(), reason="native core unavailable")
 def test_seeded_tree_native_python_parity():
     """Randomized: predictor-seeded trees agree bitwise between builders,
@@ -257,7 +241,8 @@ def test_seeded_tree_native_python_parity():
         frames = int(rng.choice([4, 8]))
         branches = int(rng.choice([8, 64]))
         spec = InputSpec(shape=(), dtype=np.uint8, values=tuple(UNIVERSE))
-        bag = _Bag(spec, players, branches, frames, UNIVERSE)
+        tree = BranchTree(spec, players, branches, frames, UNIVERSE)
+        log = {}
         nat = native_spec.make_spec_builder(
             spec, players, branches, frames, UNIVERSE
         )
@@ -276,10 +261,10 @@ def test_seeded_tree_native_python_parity():
             )
             if rng.rand() < 0.1:
                 row = rng.randint(0, 16, size=players).astype(np.uint8)
-            bag._input_log[f] = row
+            log[f] = row
             nat.log_set(f, row)
         anchor = n_log
-        last = bag._input_log.get(anchor - 1)
+        last = log.get(anchor - 1)
         if last is None:
             last = spec.zeros_np(players)
         known = np.zeros((frames, players), dtype=np.uint8)
@@ -289,15 +274,16 @@ def test_seeded_tree_native_python_parity():
             mask[:k, p] = True
             known[:k, p] = rng.randint(0, 16, size=k)
 
-        seed = bound.seed(bag._input_log, anchor, frames, players)
-        py_off = bag._structured_bits(np.asarray(last), known, mask, anchor)
+        seed = bound.seed(log, anchor, frames, players)
+        py_off = tree.structured_bits(
+            log, np.asarray(last), known, mask, anchor
+        )
         nb_off, sig_off = nat.build(anchor, None, known, mask, False, None)
         assert np.array_equal(py_off, nb_off)
 
-        bag._predictor = bound
-        bag._seed_memo = None
-        py_on = bag._structured_bits(np.asarray(last), known, mask, anchor)
-        del bag._predictor
+        py_on = dataclasses.replace(tree, predictor=bound).structured_bits(
+            log, np.asarray(last), known, mask, anchor
+        )
         nat.seed(anchor, seed)
         nb_on, sig_on = nat.build(anchor, None, known, mask, False, None)
         assert np.array_equal(py_on, nb_on)

@@ -228,7 +228,7 @@ def test_structured_base_forward_fills_known_changes():
     known_mask = np.zeros((4, P), bool)
     known[0, 0] = 9  # player 0 confirmed a change to 9 at span frame 0
     known_mask[0, 0] = True
-    bits = spec._structured_bits(last, known, known_mask)
+    bits = spec.tree.structured_bits(spec._input_log, last, known, known_mask)
     # Branch 0: player 0 holds the NEW confirmed value through the suffix;
     # player 1 repeats its anchor input.
     assert bits[0, :, 0].tolist() == [9, 9, 9, 9]
@@ -286,11 +286,11 @@ def _structured_bits_loop_oracle(spec, last, known, known_mask):
     (candidate-rank, frame, player, field)-major over the history-ranked
     candidate rows, skipping pinned slots, rank padding, and values equal
     to the base prediction."""
-    from bevy_ggrs_tpu.spec_runner import _forward_fill
+    from bevy_ggrs_tpu.branch_tree import forward_fill
 
     F, P_, B = spec.spec_frames, spec.num_players, spec.num_branches
     shape = spec.input_spec.shape
-    base = _forward_fill(last, known, known_mask)
+    base = forward_fill(last, known, known_mask)
     out = np.broadcast_to(base, (B, F, P_) + shape).copy()
     rows = _candidates_loop_oracle(spec, last)
     max_r = max(len(r) for r in rows.values())
@@ -343,13 +343,13 @@ def test_structured_bits_vectorized_matches_loop_oracle():
         last = rng.randint(0, 16, (nP,)).astype(np.uint8)
         known = rng.randint(0, 16, (F, nP)).astype(np.uint8)
         mask = rng.rand(F, nP) < 0.4
-        got = spec._structured_bits(last, known, mask)
+        got = spec.tree.structured_bits(spec._input_log, last, known, mask)
         want = _structured_bits_loop_oracle(spec, last, known, mask)
         assert np.array_equal(got, want), (B, F, nP)
         # With as-used history: recency + toggle ranking kicks in.
         for f in range(6):
             spec._input_log[f] = rng.randint(0, 16, (nP,)).astype(np.uint8)
-        got = spec._structured_bits(last, known, mask)
+        got = spec.tree.structured_bits(spec._input_log, last, known, mask)
         want = _structured_bits_loop_oracle(spec, last, known, mask)
         assert np.array_equal(got, want), ("hist", B, F, nP)
         spec._input_log.clear()
@@ -358,7 +358,7 @@ def test_structured_bits_vectorized_matches_loop_oracle():
     last = np.array([1, 2], np.uint8)
     known = np.full((4, P), 5, np.uint8)
     mask = np.ones((4, P), bool)
-    bits = spec._structured_bits(last, known, mask)
+    bits = spec.tree.structured_bits(spec._input_log, last, known, mask)
     assert (bits == bits[0]).all()
 
 
@@ -385,7 +385,7 @@ def test_candidate_ranking_prioritizes_recent_and_toggles():
         bits = np.array([UP | (FIRE if fire else 0), 0], np.uint8)
         spec._input_log[f] = bits
     last = np.array([UP, 0], np.uint8)
-    C, valid = spec._candidate_values(last)
+    C, valid = spec.tree.candidate_values(spec._input_log, last)
     row0 = [int(v) for v in C[0, 0][valid[0, 0]]]
     # Player 0's top candidates are its two recent values; UP|FIRE (the
     # transition from last=UP) ranks in the top two.
@@ -393,7 +393,7 @@ def test_candidate_ranking_prioritizes_recent_and_toggles():
     # The tree therefore covers the FIRE press at every unknown frame:
     known = np.zeros((8, 2), np.uint8)
     mask = np.zeros((8, 2), bool)
-    tree = spec._structured_bits(last, known, mask)
+    tree = spec.tree.structured_bits(spec._input_log, last, known, mask)
     for t in range(8):
         wanted = np.broadcast_to(last, (8, 2)).copy()
         wanted[t:, 0] = UP | FIRE
@@ -405,7 +405,7 @@ def test_candidate_ranking_prioritizes_recent_and_toggles():
 def test_confirmed_span_bulk_query_matches_getter():
     """P2PSession.confirmed_span (one call per player per tick) must agree
     with the per-frame confirmed_input getter on both queue backends —
-    it is what _known_inputs now pins branches with."""
+    it is what BranchTree.known_inputs pins branches with."""
     from tests.test_p2p import FPS_DT, make_pair, scripted_input
     from bevy_ggrs_tpu.session import PredictionThreshold, SessionState
     from bevy_ggrs_tpu.transport.loopback import LoopbackNetwork
@@ -659,7 +659,9 @@ def test_periodic_extrapolation_covers_multi_player_cycles():
     last = spec._input_log[anchor - 1]
     known = np.zeros((8, 2), np.uint8)
     mask = np.zeros((8, 2), bool)
-    tree = spec._structured_bits(last, known, mask, anchor)
+    tree = spec.tree.structured_bits(
+        spec._input_log, last, known, mask, anchor
+    )
     truth = np.array(
         [[scripted(h, anchor + t) for h in range(2)] for t in range(8)],
         np.uint8,
@@ -684,7 +686,7 @@ def test_extrapolation_falls_back_without_periodicity():
     last = spec._input_log[39]
     known = np.zeros((8, 2), np.uint8)
     mask = np.zeros((8, 2), bool)
-    tree = spec._structured_bits(last, known, mask, 40)
+    tree = spec.tree.structured_bits(spec._input_log, last, known, mask, 40)
     base = np.broadcast_to(last, (8, 2))
     assert np.array_equal(tree[0], base)
     assert not np.array_equal(tree[1], tree[0])  # a real change branch

@@ -191,7 +191,7 @@ def test_structured_tree_enumerates_fields_scalar_compatible():
     last = np.zeros((P, 2), np.uint8)
     known = np.zeros((3, P, 2), np.uint8)
     mask = np.zeros((3, P), bool)
-    tree = spec._structured_bits(last, known, mask)
+    tree = spec.tree.structured_bits(spec._input_log, last, known, mask)
     assert tree.shape == (64, 3, P, 2)
     base = tree[0]
     for b in range(1, 64):
@@ -319,7 +319,9 @@ def test_periodic_extrapolation_per_field_vector_inputs():
     last = spec._input_log[anchor - 1]
     known = np.zeros((8, P, 2), np.uint8)
     mask = np.zeros((8, P), bool)
-    tree = spec._structured_bits(last, known, mask, anchor)
+    tree = spec.tree.structured_bits(
+        spec._input_log, last, known, mask, anchor
+    )
     truth = np.array(
         [[[field0(h, anchor + t), 7] for h in range(P)] for t in range(8)],
         np.uint8,

@@ -3,7 +3,7 @@
 (ROADMAP S5 / D2; PERF.md section 6, PR 31's and PR 49's step 0).
 
     python3 tools/force_paths.py [--cell boids1k.wan|boids256.synctest|...]
-                                 [--kernels xla,pallas,mxu] [--branches 128]
+                                 [--kernels xla,mxu] [--branches 128]
                                  [--seconds 10] [--seed 7]
                                  [--close-frames N] [--count-only]
 
@@ -133,7 +133,7 @@ def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--cell", default="boids1k.wan",
                         help="a boids cell of BENCHMARK.json")
-    parser.add_argument("--kernels", default="xla,pallas,mxu")
+    parser.add_argument("--kernels", default="xla,mxu")
     parser.add_argument("--branches", default="",
                         help="comma-separated speculation widths "
                              "(default: the configuration's own)")
