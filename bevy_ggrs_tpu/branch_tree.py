@@ -57,6 +57,41 @@ def forward_fill(
     return base
 
 
+def distinct_prefixes(branch_bits: np.ndarray, lead: int = 0) -> np.ndarray:
+    """How many DISTINCT input prefixes each level of a tree holds:
+    ``int[*lead, F]`` of ``branch_bits[*lead, B, F, P, ...]``, level ``f``
+    counting the branches no lower branch equals on frames ``0..f`` (bit
+    patterns). What a rollout that steps each prefix once has to step
+    (``rollout.py`` ``prefix_classes`` is the device's rule, and a test
+    holds the two equal); the default tree's single changes share their
+    base's prefix up to the frame they change."""
+    bits = np.ascontiguousarray(branch_bits)
+    head = bits.shape[:lead + 2]
+    raw = bits.view(np.uint8).reshape(head + (-1,))
+    differ = (raw[..., :, None, :, :] != raw[..., None, :, :, :]).any(-1)
+    same = ~np.logical_or.accumulate(differ, axis=-1)  # [*lead, B, B, F]
+    lower = np.tri(head[-2], k=-1, dtype=bool)[..., None]
+    return (~(same & lower).any(axis=-2)).sum(axis=-2)
+
+
+def rollout_world_steps(
+    branch_bits: np.ndarray, width: Optional[int], lead: int = 0
+) -> tuple:
+    """``(steps, fill)`` of one dispatch's rollout over the trees
+    ``branch_bits[*lead, B, F, P, ...]``: the world-steps a lane runs (a
+    level ``width`` at a time as often as the deepest lane's distinct
+    prefixes ask for: ``rollout.py`` ``_rollout_shared``) and the share of
+    the lanes' steps that are a lane's own distinct prefixes. ``width``
+    None: a rollout that steps every branch every frame, ``B x F`` and no
+    share."""
+    if width is None:
+        return int(np.prod(branch_bits.shape[lead:lead + 2])), None
+    own = distinct_prefixes(branch_bits, lead)
+    deepest = own.reshape(-1, own.shape[-1]).max(axis=0)
+    steps = int((-(-deepest // width) * width).sum())
+    return steps, float(own.sum()) / (own[..., 0].size * steps)
+
+
 @dataclasses.dataclass(frozen=True)
 class BranchTree:
     """The default (structured) branch tree of one session configuration.

@@ -61,6 +61,7 @@ from bevy_ggrs_tpu.rollout import (
     rollout_branches,
     rollout_burst,
     rollout_form,
+    share_width,
 )
 from bevy_ggrs_tpu.schedule import PREDICTED, Schedule
 from bevy_ggrs_tpu.state import (
@@ -498,6 +499,14 @@ class PackedTick:
             prev_rings, prev_states, self.form
         )
 
+    @property
+    def share_width(self) -> Optional[int]:
+        """``rollout.py`` ``share_width`` of this tick's rollout, once the
+        carry is bound (None: it steps every branch every frame): what the
+        host's count of a dispatch's world-steps goes by (``branch_tree.py``
+        ``rollout_world_steps``)."""
+        return share_width(self.form, self._state_like, self.num_branches)
+
     def tick(self, carry, ints, bits, branch_bits):
         """``(carry, state, cs)`` of one whole tick; ``bits`` is the
         burst padded to ``[burst_frames, P, ...]``."""
@@ -895,7 +904,7 @@ class FusedTickExecutor:
             )
             spec_rings, spec_states, spec_cs = rollout_branches(
                 schedule, anchor_state, spec_anchor, branch_bits,
-                spec_status, form,
+                spec_status, form, lane_axis,
             )
         return ring, state, absorb_cs, burst_cs, spec_rings, spec_states, spec_cs
 

@@ -24,6 +24,20 @@ shape. A speculative rollout has no masks and no ring to write into: every
 one of its steps is live, its trip count is its shape, and it is a scan
 whose rows leave as ``ys`` (:func:`rollout_steps`).
 
+A rollout of a whole TREE has a third shape where a step costs much more
+than moving its row (:func:`share_width`): still that scan over the frames,
+its ``ys`` every branch's row in step order, but a level of it loops over
+the tree's CLASSES and not over its branches. The default tree makes every
+branch a copy of a base up to the frame where one player changes one
+control, so until that frame a branch's states, rows and checksums are the
+base's bit for bit: 55 of 8 x 8 (branch, frame) prefixes are distinct with
+both players free, 421 of 128 x 8. A level steps one world a distinct
+prefix (:func:`prefix_classes`, from ``branch_bits`` alone), a static width
+at a time inside a loop whose trip count is an operand like a burst's, and
+hands every branch its class's result (:func:`_rollout_shared`). The rows,
+states and checksums are the plain ``vmap``'s; a tree with more shared
+structure costs less instead of the same.
+
 The save-before-advance ordering and the "save is labeled with the current
 frame" invariant (``ggrs_stage.rs:277``'s ``assert_eq!(self.frame, frame)``)
 are preserved: step ``t`` saves frame ``start_frame + t`` then advances with
@@ -37,13 +51,15 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from bevy_ggrs_tpu.schedule import PlayerInputs, Schedule
 from bevy_ggrs_tpu.state import (
-    ONCE, SHAPED, STEPS, SnapshotRing, WorldState, active_checksum,
-    in_place_writes, large_row, ring_load, ring_of_steps, ring_row_lowerings,
-    ring_row_read, ring_row_write, ring_rows_flat, ring_rows_shaped,
-    ring_save, row_in_tiles, state_row, state_shaped,
+    FLAT_ROW_BYTES, IN_PLACE_ROW_BYTES, ONCE, SHAPED, STEPS, SnapshotRing,
+    WorldState, active_checksum, in_place_writes, large_row, ring_load,
+    ring_of_steps, ring_row_lowerings, ring_row_read, ring_row_write,
+    ring_rows_flat, ring_rows_shaped, ring_save, row_in_tiles, state_row,
+    state_shaped,
 )
 
 
@@ -166,6 +182,52 @@ def rollout_steps(
     return ring_of_steps(rows, start_frame, checksums), final, checksums
 
 
+def branch_reached(schedule: Schedule, state: WorldState, inputs) -> list:
+    """Which leaves of a state a branch's inputs reach over a rollout, a
+    bool a leaf in tree order: jax's own finding, not the title's word.
+    ONE abstract trace of the rollout under the branch ``vmap``, each
+    final-state leaf (a scan carries a leaf batched or not as a whole: its
+    rows and its end alike) passed through a ``custom_vmap`` identity whose
+    rule is only ever called for a batched operand. ``state`` and
+    ``inputs`` (one frame's ``[P, *input_shape]`` rows) give shapes and
+    dtypes only."""
+    reached = [False] * len(jax.tree_util.tree_leaves(state))
+
+    def told(i):
+        probe = jax.custom_batching.custom_vmap(lambda x: x)
+
+        @probe.def_vmap
+        def rule(axis_size, in_batched, x):
+            reached[i] = True
+            return x, True
+
+        return probe
+
+    def finals(state, branch_bits):
+        def final_of(bits):
+            final = rollout_steps(
+                schedule, state, jnp.int32(0), bits,
+                jnp.zeros(bits.shape[:2], jnp.int32),
+            )[1]
+            return [
+                told(i)(x)
+                for i, x in enumerate(jax.tree_util.tree_leaves(final))
+            ]
+
+        return jax.vmap(final_of)(branch_bits)
+
+    counted = dict(ring_row_lowerings)  # this trace is nobody's program
+    jax.eval_shape(
+        finals,
+        jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state
+        ),
+        jax.ShapeDtypeStruct((2, 2) + tuple(inputs.shape), inputs.dtype),
+    )
+    ring_row_lowerings.update(counted)
+    return reached
+
+
 def rollout_form(
     schedule: Schedule,
     state: WorldState,  # shapes and dtypes of one state
@@ -175,12 +237,8 @@ def rollout_form(
     state leaf, ``state.py`` ``SHAPED`` / ``STEPS`` / ``ONCE``; None where
     no row reaches ``FLAT_ROW_BYTES``: the shaped form throughout).
 
-    Whether a leaf depends on the branch is jax's own finding, not the
-    title's word: ONE abstract trace of the rollout under the branch
-    ``vmap``, each final-state leaf (a scan carries a leaf batched or not
-    as a whole: its rows and its end alike) passed through a
-    ``custom_vmap`` identity whose rule is only ever called for a batched
-    operand. Without ``inputs`` the rollout cannot be traced ahead of the
+    Whether a leaf depends on the branch is :func:`branch_reached`'s
+    finding. Without ``inputs`` the rollout cannot be traced ahead of the
     carry and every large leaf keeps its branch axis."""
     leaves, treedef = jax.tree_util.tree_flatten(state)
     large = [large_row(x) for x in leaves]
@@ -188,41 +246,203 @@ def rollout_form(
         return None
     batched = [True] * len(leaves)
     if inputs is not None:
-        batched = [False] * len(leaves)
-
-        def told(i):
-            probe = jax.custom_batching.custom_vmap(lambda x: x)
-
-            @probe.def_vmap
-            def rule(axis_size, in_batched, x):
-                batched[i] = True
-                return x, True
-
-            return probe
-
-        def finals(state, branch_bits):
-            def final_of(bits):
-                final = rollout_steps(
-                    schedule, state, jnp.int32(0), bits,
-                    jnp.zeros(bits.shape[:2], jnp.int32),
-                )[1]
-                return [
-                    told(i)(x)
-                    for i, x in enumerate(jax.tree_util.tree_leaves(final))
-                ]
-
-            return jax.vmap(final_of)(branch_bits)
-
-        counted = dict(ring_row_lowerings)  # this trace is nobody's program
-        jax.eval_shape(
-            finals, state,
-            jax.ShapeDtypeStruct((2, 2) + tuple(inputs.shape), inputs.dtype),
-        )
-        ring_row_lowerings.update(counted)
+        batched = branch_reached(schedule, state, inputs)
     return jax.tree_util.tree_unflatten(treedef, [
         SHAPED if not big else STEPS if dep else ONCE
         for big, dep in zip(large, batched)
     ])
+
+
+def share_width(form, state: WorldState, num_branches: int) -> Optional[int]:
+    """How many distinct input prefixes one iteration of a level's loop
+    steps where a rollout carried as ``form`` from such a state steps each
+    prefix of its tree once (:func:`_rollout_shared`); None where it steps
+    every branch every frame (the plain ``vmap`` of the scan).
+
+    Decided from shapes alone, here and nowhere else, beside
+    :func:`rollout_form`, which decides the carried form from the same
+    numbers. Sharing moves each world's row twice a level, so it pays
+    where a step costs much more than moving its row: a state with a row
+    of ``FLAT_ROW_BYTES`` or more steps a thousand entities and more a
+    leaf; one with a row of ``IN_PLACE_ROW_BYTES`` or more is a state
+    whose step IS the movement of its rows; one with neither (``form``
+    None) steps a level of branches in the time of a few loop iterations
+    (``PERF.md`` section 6, PR 58). The width is the tree's: 1 at 8
+    branches, about an eighth of the branches above (16 at 128), a
+    divisor of it."""
+    if form is None:
+        return None
+    largest = max(
+        int(np.prod(x.shape, dtype=np.int64)) * jnp.dtype(x.dtype).itemsize
+        for x in jax.tree_util.tree_leaves(state)
+    )
+    if not FLAT_ROW_BYTES <= largest < IN_PLACE_ROW_BYTES:
+        return None
+    most = max(1, num_branches // 8)
+    return max(w for w in range(1, most + 1) if num_branches % w == 0)
+
+
+def prefix_classes(
+    branch_bits: jnp.ndarray,  # [B, frames, num_players, *input_shape]
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The classes of a tree's branches, level by level: branch ``b`` at
+    level ``f`` belongs with the lowest ``b'`` whose bits equal its own on
+    frames ``0..f`` (bit patterns: a ``-0.0`` is not a ``0.0``). Returns
+    ``(order[frames, B], slot[frames, B], n[frames])``: a level has
+    ``n[f]`` classes, ``order[f, k]`` is the representative of class ``k``
+    (classes in the order of their representatives; 0 from ``n[f]`` on),
+    ``slot[f, b]`` the class of branch ``b``. ``branch_tree.py``
+    ``distinct_prefixes`` is the host's twin of the count."""
+    B, F = branch_bits.shape[:2]
+    if not jnp.issubdtype(branch_bits.dtype, jnp.integer):
+        branch_bits = jax.lax.bitcast_convert_type(
+            branch_bits, jnp.dtype(f"uint{8 * branch_bits.dtype.itemsize}")
+        )
+    bits = branch_bits.reshape(B, F, -1)
+    differ = jnp.any(bits[:, None] != bits[None, :], axis=-1)  # [B, B, F]
+    same = jnp.cumsum(differ.astype(jnp.int32), axis=-1) == 0
+    first = jnp.argmax(same, axis=1).astype(jnp.int32)  # [B, F]
+    ids = jnp.arange(B, dtype=jnp.int32)
+    leads = first == ids[:, None]  # [B, F]: b is its class's lowest
+    rank = jnp.cumsum(leads.astype(jnp.int32), axis=0) - 1
+    order = jnp.sum(
+        jnp.where(
+            leads[None] & (rank[None] == ids[:, None, None]),
+            ids[None, :, None], 0,
+        ),
+        axis=1,
+    )  # [K, F]
+    slot = jnp.take_along_axis(rank, first, axis=0)
+    return order.T, slot.T, rank[-1] + 1
+
+
+def _rows_at(rows: jnp.ndarray, index: jnp.ndarray) -> jnp.ndarray:
+    """``rows[index]`` along the leading (branch or class) axis: a lane's
+    own gather under the slot ``vmap``, of large rows flat (``state.py``
+    ``FLAT_ROW_BYTES``: lane-dense whatever the row's own last axis; the
+    way there and back moves no byte in the layout the loops carry)."""
+    flat = jnp.take(jax.vmap(state_row)(rows), index, axis=0, mode="clip")
+    return state_shaped(flat, rows[0], lead=1)
+
+
+def _rollout_shared(
+    schedule, state, start_frame, branch_bits, status, form, W, lane_axis
+):
+    """:func:`rollout_branches` in the carried form, a level stepping one
+    world a class of :func:`prefix_classes`: the frame axis is the scan it
+    is in :func:`rollout_steps` (its ``ys`` the rows every branch enters
+    the frame with, in step order), and a level picks each class's
+    representative row, steps the representatives ``W`` at a time inside
+    a loop that runs as often as the classes ask for (the deepest lane's,
+    under the slot ``vmap``: :func:`deepest_lane`) and hands every branch
+    of a class the chunk stepped its class's result. A leaf no branch's
+    inputs reach (:func:`branch_reached`) has no branch axis in the loop
+    and is stepped once a level. Bit for bit the plain ``vmap``'s rows,
+    states and checksums: a step is a function of its world and its
+    inputs."""
+    B, F = branch_bits.shape[:2]
+    rows, treedef = jax.tree_util.tree_flatten(state)
+    kinds = jax.tree_util.tree_leaves(form)
+    reached = branch_reached(schedule, state, branch_bits[0, 0])
+    if any(k == ONCE and r for k, r in zip(kinds, reached)):
+        raise ValueError("a leaf carried without a branch axis has one")
+    # (a leaf carried with a branch axis keeps it in the loop, reached or
+    # not: a form made without the inputs' shape)
+    dep = [r or k == STEPS for k, r in zip(kinds, reached)]
+    order, slot, n = prefix_classes(branch_bits)
+    trips = deepest_lane(-(-n // W), lane_axis)
+    of = lambda rows, d: [r for r, dd in zip(rows, dep) if dd == d]
+    every = lambda x: jnp.broadcast_to(x, (B,) + x.shape)
+
+    def world_of(with_axis, without):
+        a, b = iter(with_axis), iter(without)
+        return treedef.unflatten([next(a) if d else next(b) for d in dep])
+
+    def step(with_axis, without, bits, st):
+        """The leaves one world leaves a frame with."""
+        # The barriers keep the loop's slicing out of the step's fusions:
+        # which products and sums a backend contracts follows its fusions,
+        # and the rows have to be the serial step's bit for bit.
+        world = jax.lax.optimization_barrier(world_of(with_axis, without))
+        return jax.tree_util.tree_leaves(jax.lax.optimization_barrier(
+            schedule(world, PlayerInputs(bits=bits, status=st))
+        ))
+
+    def level(x, xs):
+        with_axis, without = x
+        bits, st, order_f, slot_f, trips_f = xs
+        heads = [_rows_at(r, order_f) for r in with_axis]
+        head_bits = _rows_at(bits, order_f)
+
+        def chunk(i, leaves):
+            cut = lambda r: jax.lax.dynamic_slice_in_dim(r, i * W, W, 0)
+            new = jax.vmap(lambda rows, b: step(rows, without, b, st))(
+                [cut(r) for r in heads], cut(head_bits)
+            )
+            # Every branch of a class this chunk stepped takes its class's
+            # result: one select over the level's rows where they lie. (A
+            # write of the chunk at ``i`` into ``[B, *row]``, gathered to
+            # the branches behind the loop, puts ``B`` in a tile's sublanes
+            # under the slot ``vmap``: 14.6 us a leaf an iteration on the
+            # chip against this pass's 2-9; ``PERF.md`` section 6, PR 58.)
+            local = slot_f - i * W
+            mine = (local >= 0) & (local < W)
+            return [
+                jnp.where(
+                    mine.reshape((B,) + (1,) * (old.ndim - 1)),
+                    r if W == 1 else _rows_at(r, jnp.clip(local, 0, W - 1)),
+                    old,
+                )
+                for old, r in zip(leaves, of(new, True))
+            ]
+
+        # (every branch's class is stepped by some chunk, so what the loop
+        # starts from is never read: the rows the level was entered with)
+        stepped = jax.lax.fori_loop(0, trips_f, chunk, with_axis)
+        if without:  # any branch's step says what these become
+            once = of(
+                step([r[0] for r in with_axis], without, bits[0], st), False
+            )
+        else:
+            once = []
+        # (the checksums every branch enters with: a pass over the level,
+        # outside the loop, whose iterations are the many)
+        enters = lambda rows: active_checksum(world_of(rows, without))
+        cs = jax.vmap(enters)(with_axis) if with_axis else every(enters([]))
+        return (
+            (stepped, once),
+            (
+                [jax.vmap(state_row)(r) for r in with_axis],
+                state_row(without), cs,
+            ),
+        )
+
+    (ends, once_ends), (steps, once_steps, cs) = jax.lax.scan(
+        level,
+        ([every(r) for r in of(rows, True)], of(rows, False)),
+        (jnp.moveaxis(branch_bits, 1, 0), status, order, slot, trips),
+    )
+    a, b = iter(zip(steps, ends)), iter(zip(once_steps, once_ends))
+    ring_rows, finals = [], []
+    for kind, d in zip(kinds, dep):
+        r, end = next(a) if d else next(b)
+        if kind == SHAPED:  # ``[B, frames, *row]``, as the plain vmap's
+            r = jnp.moveaxis(r, 1, 0) if d else every(r)
+        ring_rows.append(r)
+        finals.append(end if d or kind == ONCE else every(end))
+    ring_row_lowerings["step"] += len(rows)
+    cs = jnp.moveaxis(cs, 1, 0)
+    frames = jnp.asarray(start_frame, jnp.int32) + jnp.arange(
+        F, dtype=jnp.int32
+    )
+    return (
+        SnapshotRing(
+            states=treedef.unflatten(ring_rows), frames=every(frames),
+            checksums=cs,
+        ),
+        treedef.unflatten(finals),
+        cs,
+    )
 
 
 def rollout_branches(
@@ -232,6 +452,7 @@ def rollout_branches(
     branch_bits: jnp.ndarray,  # [B, frames, num_players, *input_shape]
     status: jnp.ndarray,  # int32[frames, num_players]
     form=None,  # :func:`rollout_form`'s answer for this schedule and state
+    lane_axis: Optional[str] = None,
 ) -> Tuple[SnapshotRing, WorldState, jnp.ndarray]:
     """:func:`rollout_steps` of every branch of ``branch_bits`` from the
     same ``state``: ``(rings, states, checksums[B, frames])``.
@@ -243,9 +464,12 @@ def rollout_branches(
     n]``: the loop's ``ys`` buffer itself) or names none (a ``ONCE`` leaf
     ``[frames, n]``, its final state ``[*row]``: computed once by the loop,
     and not broadcast here), so no operation stands between the loop and
-    the caller that writes a large leaf's bytes again. Without, every leaf
-    is ``[B, frames, *row]`` in its own shape (the form a mesh lays out,
-    and every reader off the serving loop is handed)."""
+    the caller that writes a large leaf's bytes again. Where
+    :func:`share_width` says so the same rows come from a scan whose
+    level loops over its classes (:func:`_rollout_shared`; ``lane_axis``
+    names the slot ``vmap``'s axis, as for a burst). Without ``form``,
+    every leaf is ``[B, frames, *row]`` in its own shape (the form a mesh
+    lays out, and every reader off the serving loop is handed)."""
     one = lambda bits: rollout_steps(schedule, state, start_frame, bits, status)
     if form is None:
         rings, states, checksums = jax.vmap(one)(branch_bits)
@@ -254,6 +478,12 @@ def rollout_branches(
     kinds = jax.tree_util.tree_leaves(form)
     ring_row_lowerings["carried"] += sum(k != SHAPED for k in kinds)
     ring_row_lowerings["carried_once"] += sum(k == ONCE for k in kinds)
+    width = share_width(form, state, branch_bits.shape[0])
+    if width is not None:
+        return _rollout_shared(
+            schedule, state, start_frame, branch_bits, status, form, width,
+            lane_axis,
+        )
     at = {SHAPED: 0, STEPS: 1, ONCE: None}
     return jax.vmap(one, out_axes=(
         SnapshotRing(

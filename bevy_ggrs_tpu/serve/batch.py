@@ -95,7 +95,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bevy_ggrs_tpu.branch_tree import BranchTree
+from bevy_ggrs_tpu.branch_tree import BranchTree, rollout_world_steps
 from bevy_ggrs_tpu.fused import (
     LANE_AXIS,
     IoBuffers,
@@ -1115,7 +1115,7 @@ class BatchedSessionCore(Instrumented):
         :meth:`_post_dispatch`'s arguments. Few locals ON PURPOSE: the
         first call's trace runs under this frame (``PERF.md`` §7)."""
         self.device_dispatches_total += 1
-        self._count_lane_steps(jit_args[0])
+        self._count_lane_steps(jit_args[0], jit_args[2])
         with self.span("serve_dispatch"):
             self._carry, self._states, cs = self._exec.run(
                 self._carry, *jit_args
@@ -1125,14 +1125,22 @@ class BatchedSessionCore(Instrumented):
         self.metrics.observe("tick_stage_bytes", self._exec.io.staged_bytes)
         return cs, post, reports
 
-    def _count_lane_steps(self, ints: np.ndarray) -> None:
-        """What the dispatch's two loops run for ``ints`` (the
-        :class:`TickInts` rows it is handed): ``num_slots x`` the deepest
-        lane's burst, and the same of its absorb. Both depths are series, a
-        sample a dispatch (``serve_burst_depth``; ``serve_absorb_depth``, 0
-        included), and so are the bytes the lanes commit
-        (``serve_absorb_commit_bytes``: their frames x ``row_bytes``, also
-        the count ``absorb_commit_bytes_total``)."""
+    def _count_lane_steps(
+        self, ints: np.ndarray, branch_bits: np.ndarray
+    ) -> None:
+        """What the dispatch's loops run for ``ints`` (the
+        :class:`TickInts` rows it is handed) and ``branch_bits`` (its
+        trees): ``num_slots x`` the deepest lane's burst, and the same of
+        its absorb. Both depths are series, a sample a dispatch
+        (``serve_burst_depth``; ``serve_absorb_depth``, 0 included), and so
+        are the bytes the lanes commit (``serve_absorb_commit_bytes``:
+        their frames x ``row_bytes``, also the count
+        ``absorb_commit_bytes_total``). While a sink listens, also the
+        world-steps a lane's rollout runs (``serve_rollout_steps``: the
+        deepest lane's distinct input prefixes level by level where the
+        rollout shares its steps, ``B x F`` where it does not) and the
+        share of them that are a lane's own
+        (``serve_rollout_fill_share``, %, where it shares)."""
         self.metrics.count("serve_dispatches_total")
         burst = int(ints[:, TickInts.N_BURST].max())
         self.burst_step_slots_total += self.num_slots * burst
@@ -1147,6 +1155,13 @@ class BatchedSessionCore(Instrumented):
             self.absorb_step_slots_total += steps
             self.metrics.count("absorb_step_slots_total", steps)
             self.metrics.count("absorb_commit_bytes_total", committed)
+        if self.metrics is not null_metrics:
+            steps, fill = rollout_world_steps(
+                branch_bits, self._exec.packed.share_width, lead=1
+            )
+            self.metrics.observe("serve_rollout_steps", steps)
+            if fill is not None:
+                self.metrics.observe("serve_rollout_fill_share", 100 * fill)
 
     def _post_dispatch(
         self, cs, post: Dict[int, tuple], reports: List[tuple]

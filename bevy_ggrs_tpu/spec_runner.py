@@ -47,7 +47,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bevy_ggrs_tpu.branch_tree import BranchTree, forward_fill
+from bevy_ggrs_tpu.branch_tree import (
+    BranchTree, forward_fill, rollout_world_steps,
+)
 from bevy_ggrs_tpu.fused import (
     FusedTickExecutor,
     TickInts,
@@ -72,6 +74,7 @@ from bevy_ggrs_tpu.parallel.speculate import (
 from bevy_ggrs_tpu.runner import RollbackRunner, _Step
 from bevy_ggrs_tpu.schedule import Schedule
 from bevy_ggrs_tpu.state import SnapshotRing, WorldState, combine64, ring_load
+from bevy_ggrs_tpu.utils.metrics import null_metrics
 
 
 @functools.partial(jax.jit, static_argnames=("max_steps",))
@@ -701,6 +704,16 @@ class SpeculativeRollbackRunner(RollbackRunner):
         self._carry, self._state, self._spec_cs = out
         self._ring = None
         self._observe_io()
+        if self.metrics is not null_metrics:
+            # The world-steps this rollout ran, and the share of them that
+            # are its tree's distinct input prefixes (%: a level's loop
+            # rounds up to its width), where it shares its steps.
+            steps, fill = rollout_world_steps(
+                branch_bits, self._fused.packed.share_width
+            )
+            self.metrics.observe("rollout_steps", steps)
+            if fill is not None:
+                self.metrics.observe("rollout_fill_share", 100 * fill)
         cs = self._spec_cs
         return SpecResult(
             rings=None, states=None,
@@ -971,13 +984,25 @@ class SpeculativeRollbackRunner(RollbackRunner):
                 # is moot — every branch was real-checked anyway.
                 self.metrics.count("attestation_degraded")
         if self._may_split and self.speculation_enabled:
-            self._decide_split(np.asarray(bits))
+            # Timed on the default tree's own shape (every player free
+            # from the anchor): a rollout that shares its steps costs what
+            # its tree's distinct prefixes cost, and the all-alike tensor
+            # above is one prefix a frame.
+            zeros = self.input_spec.zeros_np(self.num_players)
+            known = np.broadcast_to(
+                zeros, (self.spec_frames,) + zeros.shape
+            ).copy()
+            self._decide_split(self.tree.structured_bits(
+                self._input_log, zeros, known,
+                np.zeros((self.spec_frames, self.num_players), bool),
+            ))
 
     def _decide_split(self, bits: np.ndarray) -> None:
         """Choose, once, how many programs carry a tick (``fused.py``
         :func:`~bevy_ggrs_tpu.fused.split_pays`) from two times taken here
         on this runner's compiled executables and shapes: one rollout
-        dispatch, and one call that takes the same carry and runs no
+        dispatch of ``bits`` (the caller's: a tree as a tick builds them),
+        and one call that takes the same carry and runs no
         rollout (the absorb-only program, committing nothing). The second
         is what one more call costs; their difference is the device time
         for which the fused program holds the live state back. A runner

@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from bevy_ggrs_tpu import fused
+from bevy_ggrs_tpu import fused, rollout
 from bevy_ggrs_tpu.models import boids, box_game, particles
 from bevy_ggrs_tpu.ops import checksum as checksum_ops
 from bevy_ggrs_tpu.ops import pairwise
@@ -242,3 +242,28 @@ def test_served_box_game_tick_is_the_parents(one_chip, mosaic, monkeypatch):
     assert "ring_write/pallas_call" not in change.as_text()
     parents_write(monkeypatch)
     assert _computation(tick().as_text()) == _computation(change.as_text())
+
+
+def test_served_boids_tick_steps_a_class_of_the_tree_a_kernel_call(
+        one_chip, mosaic, monkeypatch):
+    """The flock's rollout shares its steps (``rollout.py`` ``share_width``:
+    8 KB leaves): in the compiled ``[2] x [8] x 8`` tick the force kernel
+    stands twice, the burst's and the one of the level's loop, called for
+    one world a lane where the parent's form calls it for all eight
+    branches; the step that says what the leaves no input reaches become
+    keeps no kernel (its force feeds nothing)."""
+    tick = lambda: _served_tick(    # noqa: E731
+        one_chip, boids.make_schedule(kernel="mxu"),
+        boids.make_world(1024, 2).commit(), boids.INPUT_SPEC)[0].as_text()
+    kernel = re.compile(
+        r"%pairwise_force[\w.]* = \(f32\[([\d,]+)\][^\n]*custom-call\(")
+    worlds = lambda text: sorted(    # noqa: E731
+        int(np.prod([int(d) for d in m.split(",")])) // 1024
+        for m in kernel.findall(text))
+    change = tick()
+    assert worlds(change) == [2, 2]
+    rolls = _loop_with(
+        change, "ggrs/rollout)/while/body/closed_call/while/body")
+    assert len(kernel.findall(rolls)) == 1
+    monkeypatch.setattr(rollout, "share_width", lambda *a: None)
+    assert worlds(tick()) == [2, 16]

@@ -74,10 +74,10 @@ def served():
     plans = []      # (ABSORB_N, N_BURST) of every lane, a dispatch
     count = batch.BatchedSessionCore._count_lane_steps
 
-    def recording(core, ints):
+    def recording(core, ints, branch_bits):
         plans.append((ints[:, TickInts.ABSORB_N].copy(),
                       ints[:, TickInts.N_BURST].copy()))
-        return count(core, ints)
+        return count(core, ints, branch_bits)
 
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(match_server_p2p, "time", _Ticks())
@@ -209,9 +209,10 @@ def test_a_hand_made_plan_is_counted_as_it_reads():
         patch.setattr(core, "metrics", sink)
         patch.setattr(core, "burst_step_slots_total", 0)
         patch.setattr(core, "absorb_step_slots_total", 0)
-        core._count_lane_steps(ints)
+        trees = core._host_args()[2]    # every branch of every lane alike
+        core._count_lane_steps(ints, trees)
         ints[:, TickInts.ABSORB_N] = 0
-        core._count_lane_steps(ints)
+        core._count_lane_steps(ints, trees)
         assert core.burst_step_slots_total == 2 * 6 * SLOTS
         assert core.absorb_step_slots_total == 3 * SLOTS
     assert sink.series["serve_burst_depth"] == [6.0, 6.0]
@@ -220,6 +221,14 @@ def test_a_hand_made_plan_is_counted_as_it_reads():
         3.0 * core.row_bytes, 0.0]
     assert sink.counters["serve_dispatches_total"] == 2
     assert sink.counters["absorb_commit_bytes_total"] == 3 * core.row_bytes
+    # the rollout's world-steps a lane: one a frame for trees that share
+    # everything where the rollout shares (``rollout.py`` ``share_width``),
+    # every branch every frame where it does not
+    shares = core._exec.packed.share_width is not None
+    assert sink.series["serve_rollout_steps"] == 2 * [float(
+        core.spec_frames * (1 if shares else core.num_branches))]
+    assert sink.series.get("serve_rollout_fill_share", []) == (
+        [100.0, 100.0] if shares else [])
     assert sink.counters["absorb_step_slots_total"] == 3 * SLOTS
 
 

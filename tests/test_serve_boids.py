@@ -88,6 +88,12 @@ def test_match_server_hosts_boids(kernel, frames, branches):
         v > 0 for v in metrics.series["serve_carry_bytes"])
     assert set(metrics.series["tick_stage_bytes"]) == {
         float(sum(a.nbytes for a in server.groups[0]._host_args()))}
+    # ... each branch every frame (rows under 4 KiB: ``rollout.py``
+    # ``share_width``), and the sink hears that constant a dispatch.
+    assert set(metrics.series["serve_rollout_steps"]) == {branches * WINDOW}
+    assert len(metrics.series["serve_rollout_steps"]) == len(
+        metrics.series["tick_stage_bytes"])
+    assert "serve_rollout_fill_share" not in metrics.series
 
     steps = 0
     for k, h in enumerate(handles):
@@ -124,6 +130,50 @@ def test_match_server_hosts_boids(kernel, frames, branches):
             assert decided.mean() > 0.9
             steps += 1
     assert steps >= matches * (WINDOW - 1)
+
+
+def test_match_server_hosts_a_flock_whose_rollout_shares_its_steps():
+    """512 boids make rows of 4 KiB: the served rollout steps each distinct
+    input prefix of a lane's tree once (``rollout.py`` ``share_width``), the
+    deepest lane's count a level for every lane. The matches stay bitwise
+    the serial singleton, and a listening sink is told the world-steps a
+    dispatch ran and the share of them that were a lane's own."""
+    n, matches, frames, branches = 512, 4, 14, 8
+    schedule = boids.make_schedule()
+    world = boids.make_world(n, P).commit()
+    metrics = Metrics()
+    server = MatchServer(
+        schedule, world, WINDOW, P, boids.INPUT_SPEC, capacity=matches,
+        stagger_groups=1, num_branches=branches, spec_frames=WINDOW,
+        metrics=metrics)
+    server.warmup()
+    core = server.groups[0]
+    assert core._exec.packed.share_width == 1
+    table = np.random.RandomState(11).choice(MASKS, size=(matches, P, frames))
+    feed = lambda k: lambda frame, handle: table[k, handle, frame]  # noqa
+    handles = [server.add_match(_session(), feed(k)) for k in range(matches)]
+    for _ in range(frames):
+        server.run_frame()
+    assert server.faults_total == 0 and server.evictions_total == 0
+    for k, h in enumerate(handles):
+        assert core.slots[h.slot].frame == frames
+        session, oracle = _session(), RollbackRunner(
+            schedule, world, WINDOW, P, boids.INPUT_SPEC)
+        for _ in range(frames):
+            for p in session.local_player_handles():
+                session.add_local_input(p, feed(k)(session.current_frame, p))
+            oracle.handle_requests(session.advance_frame(), session)
+        assert tree_equal(core.slot_state(h.slot), oracle.state)
+        ring = core.slot_ring(h.slot)
+        assert np.array_equal(np.asarray(ring.checksums),
+                              np.asarray(oracle.ring.checksums))
+    steps = metrics.series["serve_rollout_steps"]
+    fill = metrics.series["serve_rollout_fill_share"]
+    assert len(steps) == len(fill) == len(metrics.series["tick_stage_bytes"])
+    # both players free from the anchor: 3 + 5 + 7 + 8 x 5 of 8 x 8
+    assert WINDOW <= min(steps) and max(steps) <= branches * WINDOW
+    assert sorted(steps)[len(steps) // 2] == 55
+    assert all(0 < v <= 100 for v in fill)
 
 
 # ---------------------------------------------------------------------------

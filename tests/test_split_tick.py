@@ -161,6 +161,33 @@ def spy(obj, name, calls):
     setattr(obj, name, wrapped)
 
 
+
+def test_the_split_is_decided_on_a_tree_as_a_tick_builds_them(monkeypatch):
+    """Warm-up times the rollout it decides from on the default tree's own
+    shape, every player free from the anchor, not on the all-alike tensor
+    it compiles with: a rollout that shares its steps (``rollout.py``
+    ``share_width``) runs one world a frame for that one and would look
+    cheaper than any tick's (boids1k.wan stopped splitting and its frame
+    rose 3.9 -> 6.2 ms: ``PERF.md`` section 6, PR 58)."""
+    inject(monkeypatch, *SPLITS)
+    timed = []
+    decide = spec_runner.SpeculativeRollbackRunner._decide_split
+    monkeypatch.setattr(
+        spec_runner.SpeculativeRollbackRunner, "_decide_split",
+        lambda self, bits: (timed.append(np.array(bits)), decide(self, bits)))
+    schedule, state, input_spec = TITLES["box_game"]()
+    r = SpeculativeRollbackRunner(
+        schedule, state, max_prediction=MAXPRED, num_players=P,
+        input_spec=input_spec, num_branches=8, spec_frames=4)
+    r.warmup()
+    tree, = timed
+    assert tree.shape[:3] == (8, 4, P) and r._split
+    # the single changes of a tree with nothing pinned: more distinct
+    # prefixes than frames, fewer than branches x frames
+    from bevy_ggrs_tpu.branch_tree import distinct_prefixes
+    assert 4 < int(distinct_prefixes(tree).sum()) < 8 * 4
+
+
 @pytest.mark.parametrize("title,ticks", [("box_game", 300),
                                          ("boids64_mxu", 160)])
 def test_split_and_fused_runners_hold_the_same_bits(monkeypatch, title, ticks):

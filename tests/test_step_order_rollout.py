@@ -637,6 +637,50 @@ def test_absorb_reads_every_frame_of_the_carried_rows(r, d, n):
     assert_absorbed(got, (want_ring, entered[d + n], jnp.asarray(want_cs)), n)
 
 
+@pytest.mark.parametrize("branch,d,n", [(1, 0, F), (2, 1, 2), (3, 0, 1)])
+def test_absorb_commits_the_serial_rows_of_a_branch_that_shared_its_steps(
+        branch, d, n):
+    """A tree as the default builder makes them: every branch the base up
+    to the frame of its one change, so the flock's rollout (``rollout.py``
+    ``share_width``: rows of 4 KiB and more) stepped ONE world for the
+    frames branches share. What the absorb commits of such a branch, across
+    the frame where it left the base, is the serial replay of its own
+    inputs."""
+    state, form, step, roll, absorb = _carried_absorb("flock")
+    schedule, _, _ = carried_title("flock")
+    from bevy_ggrs_tpu.rollout import prefix_classes, share_width
+    assert share_width(form, state, BRANCHES) == 1
+    anchor = 7 * F + 1
+    base = np.tile(np.array([3, 5], np.uint8), (F, 1))
+    bits = np.stack([base] * BRANCHES)
+    bits[1, F - 1:, 0] = 9      # leaves the base at the last frame
+    bits[2, 1:, 1] = 4          # ... at frame 1
+    bits[3, 0:, 0] = 1          # ... at frame 0
+    _, _, classes = prefix_classes(jnp.asarray(bits))
+    assert np.asarray(classes).tolist() == [2, 3, 4][:F]
+    bits = jnp.asarray(bits)
+    rings, states, cs = roll(state, jnp.int32(anchor), bits)
+    entered = [state]
+    for t in range(F):
+        entered.append(step(entered[-1], bits[branch, t], STATUS[t]))
+    rng = np.random.default_rng(branch)
+    main = random_ring(rng, state, DEPTH)
+    want_ring, put = main, jax.jit(ring_put)
+    for t in range(d, d + n):
+        want_ring = put(
+            want_ring, entered[t], jnp.int32(anchor + t), cs[branch, t])
+    want_cs = np.zeros((BURST, 2), np.uint32)
+    want_cs[:n] = np.asarray(cs)[branch, d:d + n]
+    got = absorb(main, rings, states, i32(branch), i32(anchor + d), i32(n),
+                 i32(anchor), i32(F))
+    assert_absorbed(got, (want_ring, entered[d + n], jnp.asarray(want_cs)), n)
+    # and its checksums are the serial saves' own
+    save = jax.jit(ring_save)
+    for t in range(F):
+        _, c = save(main, entered[t], jnp.int32(anchor + t))
+        assert_bits_equal(cs[branch, t], c)
+
+
 @pytest.mark.parametrize("branches", [BRANCHES, LONG], ids=["chain", "one_hot"])
 def test_a_lane_reads_its_rows_out_of_the_gathered_buffer(branches):
     """``branch_rows_of`` under the slot ``vmap`` (every lane's carried
